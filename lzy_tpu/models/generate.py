@@ -278,8 +278,6 @@ def pp_generate(
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from lzy_tpu.utils.compat import shard_map
-
     from lzy_tpu.models.llama import (
         LlamaStage, RMSNorm, _check_pp_config)
 
@@ -391,7 +389,7 @@ def pp_generate(
 
     stacked_specs = jax.tree_util.tree_map(
         lambda _: P(axis), params["stages"])
-    new_tokens = shard_map(
+    new_tokens = jax.shard_map(
         local, mesh=mesh, in_specs=(stacked_specs, P(), P()),
         out_specs=P(), axis_names={axis},
     )(params["stages"], prompt, rng)

@@ -12,7 +12,8 @@ for the MXU/HBM (SURVEY.md §6 north star):
   sequence lengths;
 - attention switches to ring attention over the ``sp`` axis for
   sequence-parallel long-context training (``lzy_tpu.parallel.ring``), and to
-  the fused Pallas flash kernel on real TPU (``lzy_tpu.ops.flash_attention``).
+  the fused Pallas flash kernel where ``use_flash_kernel`` asks for it
+  (``lzy_tpu.ops.flash_attention``).
 
 No reference counterpart exists (the reference is a workflow platform, not a
 tensor framework — SURVEY.md §2.4); architecture follows the public Llama-3
@@ -88,7 +89,8 @@ class LlamaConfig:
     paged_attention_native: bool = False
     # which native kernel under paged_attention_native: "lax" (portable
     # gather-attention, bit-identical to the legacy path by construction)
-    # or "pallas" (fused block-walk kernel; interpreted off-TPU)
+    # or "pallas" (fused block-walk kernel; Pallas interpreter only — its
+    # layout does not lower for a TPU, see ops/paged_attention.py)
     paged_kernel: str = "lax"
     # int8 per-block KV quantization (paged cache only): pooled K/V are
     # stored int8 with per-position/per-head scale+zero-point sidecars
@@ -241,9 +243,12 @@ class Attention(nn.Module):
 
             out = ulysses_attention(q, k, v, mesh=mesh, causal=True,
                                     segment_ids=segments)
-        elif cfg.use_flash_kernel and t % 128 == 0:
-            # lane-aligned sequences take the Pallas kernel; tiny traces
-            # (init, smoke shapes) fall through to the dense path
+        elif cfg.use_flash_kernel and not (
+                self.is_initializing() and t % 128):
+            # asked for, so taken: a length the kernel cannot serve
+            # (t % 128, VMEM) is refused by flash_attention itself, never
+            # dropped to the reference path in silence. Only init()'s
+            # short dummy trace, whose output is thrown away, goes below.
             from lzy_tpu.ops.flash_attention import flash_attention
 
             out = _batch_sharded_attention(
@@ -508,8 +513,9 @@ class Mlp(nn.Module):
         # in-layer anchors: with fsdp-sharded kernels the partitioner
         # otherwise re-shards the hidden activations onto the model dim
         # and all-gathers [D,T,B] per matmul — 14 gathers/layer, 150 GB
-        # per step at flagship v5e-16 scale (AOT_ANALYSIS); anchoring the
-        # intermediates keeps batch sharded so only WEIGHTS are gathered
+        # per step at flagship v5e-16 scale (a deviceless compile, now
+        # tests/test_aot_topology.py); anchoring the intermediates keeps
+        # batch sharded so only WEIGHTS are gathered
         gate = dense(cfg.d_ff, "gate_proj", ("embed", "mlp"))(x)
         up = dense(cfg.d_ff, "up_proj", ("embed", "mlp"))(x)
         h = _anchor(nn.silu(gate) * up, self.mesh, "batch", "seq", "act_mlp",
@@ -524,7 +530,8 @@ class DecoderLayer(nn.Module):
     under ``nn.remat`` every call argument is traced, and a Mesh object
     cannot be interpreted as an abstract array — remat=True with a mesh
     crashed until the mesh moved to construction time (caught by the AOT
-    compile of the seq-4k bench variant, tpu_evidence/AOT_ANALYSIS.md)."""
+    compile of the seq-4k bench variant; tests/test_aot_topology.py keeps
+    such compiles)."""
 
     cfg: LlamaConfig
     mesh: Any = None
@@ -580,7 +587,8 @@ def _batch_sharded_attention(fn, q, k, v, segments, mesh, rules=None):
     wrapper it REPLICATES the attention operands — at flagship v5e-16
     scale that was 280 all-gathers / 150 GB per step of [B*H, T, D]
     tensors, every chip then computing attention for the full global
-    batch (tpu_evidence/AOT_ANALYSIS.md, op_name attn/while/body).
+    batch (deviceless compile, op_name attn/while/body; the traffic bound
+    in tests/test_aot_topology.py pins it).
     Attention is independent per (batch, head), so mapping those dims is
     exact. Dense path only (``anchor_mesh``); the ring/Ulysses paths and
     the pipeline's manual region do their own thing. The batch/head mesh
@@ -603,16 +611,14 @@ def _batch_sharded_attention(fn, q, k, v, segments, mesh, rules=None):
         return fn(q, k, v, causal=True, segment_ids=segments)
     from jax.sharding import PartitionSpec as P
 
-    from lzy_tpu.utils.compat import shard_map
-
     qkv_spec = P(batch_axes or None, head_axes or None, None, None)
     if segments is None:
-        return shard_map(
+        return jax.shard_map(
             lambda a, b, c: fn(a, b, c, causal=True),
             mesh=mesh, in_specs=(qkv_spec,) * 3, out_specs=qkv_spec,
             check_vma=False,
         )(q, k, v)
-    return shard_map(
+    return jax.shard_map(
         lambda a, b, c, s: fn(a, b, c, causal=True, segment_ids=s),
         mesh=mesh,
         in_specs=(qkv_spec,) * 3 + (P(batch_axes or None, None),),
@@ -627,16 +633,16 @@ def _anchor(x, mesh, *logical_axes, rules=None):
     fsdp mesh the embed table is (vocab, embed->fsdp), and propagating
     that into the residual stream makes XLA batch-all-gather every
     [B,T,V]-shaped intermediate (33 MB each at test size, 34 GB at
-    flagship scale: tpu_evidence/AOT_ANALYSIS.md). ``rules`` is a frozen
-    override tuple (``parallel.sharding.freeze_rules``) so anchors follow
-    the SAME table the params were laid out with instead of silently
-    assuming DEFAULT_RULES."""
+    flagship scale; tests/test_aot_topology.py pins the traffic bound).
+    ``rules`` is a frozen override tuple
+    (``parallel.sharding.freeze_rules``) so anchors follow the SAME table
+    the params were laid out with instead of silently assuming
+    DEFAULT_RULES."""
     if mesh is None or mesh.size == 1:
         return x
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from lzy_tpu.parallel.sharding import spec_for
-    from lzy_tpu.utils.compat import manual_axes_of
+    from lzy_tpu.parallel.sharding import manual_axes, spec_for
 
     spec = spec_for(logical_axes, dict(rules) if rules else None)
     # a rule may name axes the mesh doesn't have (remapped deployments);
@@ -649,7 +655,7 @@ def _anchor(x, mesh, *logical_axes, rules=None):
         return kept if kept else None
 
     spec = PartitionSpec(*(present(e) for e in spec))
-    manual = manual_axes_of(mesh)
+    manual = manual_axes()
     if manual:
         # inside a manual region (the pp pipeline runs the stage body under
         # shard_map): a constraint naming a manual axis is rejected by both
@@ -728,7 +734,7 @@ class Llama(nn.Module):
             # one anchor at the embed is not enough; at flagship scale
             # the partitioner re-shards activations onto the model dim
             # mid-layer and all-gathers [D,T,B] for every matmul (280
-            # gathers / 150 GB per step on v5e-16, AOT_ANALYSIS.md). The
+            # gathers / 150 GB per step on v5e-16 in a deviceless compile). The
             # pp path (LlamaStage) manages its own boundaries.
             x = layer(cfg, mesh=mesh, anchor=True, rules=self.rules,
                       name=f"layer_{i}")(x, positions, segments, page_table)
@@ -1084,7 +1090,7 @@ def _lm_loss(cfg: LlamaConfig, out, tokens, shifted_mask, mesh=None,
         # head is gathered whole ONCE (vocab x embed, ~67 MB bf16 at
         # flagship size) instead of the partitioner keeping its embed dim
         # fsdp-sharded and batch-all-gathering every chunk of the scan —
-        # the 193 GB/step pathology AOT_ANALYSIS caught on v5e-16
+        # the 193 GB/step pathology a deviceless v5e-16 compile showed
         features = _anchor(features, mesh, "batch", "seq", "act_embed",
                            rules=rules)
         # (vocab, None): "act_embed" here would map to the same mesh axis

@@ -1,15 +1,19 @@
 """Shared build-on-demand loader for the C++ engines under ``native/``.
 
-One ``make`` lock for the whole process: the slot engine and the data loader
-build into the same ``native/build`` directory, and two concurrent makes
-racing on shared targets corrupt each other. Failures are cached — retrying
-the compiler on every call would put its timeout on hot paths (VM boot, batch
-assembly).
+``make`` runs once per process before the first load, whatever
+``native/build`` already holds: it is incremental, so an up-to-date tree
+costs a few milliseconds, and a binary is never loaded on trust. One lock
+for the process and one lock file for the machine: the slot engine and the
+data loader build into the same directory, and two concurrent makes racing
+on shared targets corrupt each other (test workers, process workers).
+Failures are cached — retrying the compiler on every call would put its
+timeout on hot paths (VM boot, batch assembly).
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import pathlib
 import subprocess
 import threading
@@ -26,6 +30,23 @@ class NativeUnavailable(RuntimeError):
 
 _lock = threading.Lock()
 _cache: Dict[str, Union[ctypes.CDLL, NativeUnavailable]] = {}
+_made = False
+
+
+def _make() -> None:
+    """Bring ``native/build`` up to date with the sources (once per
+    process; callers hold ``_lock``)."""
+    global _made
+    if _made:
+        return
+    BUILD_DIR.mkdir(exist_ok=True)
+    with open(BUILD_DIR / ".make.lock", "w") as lock_file:
+        fcntl.flock(lock_file, fcntl.LOCK_EX)
+        subprocess.run(
+            ["make", "-C", str(NATIVE_DIR)],
+            check=True, capture_output=True, text=True, timeout=120,
+        )
+    _made = True
 
 
 def load_native_lib(so_name: str) -> ctypes.CDLL:
@@ -43,14 +64,9 @@ def load_native_lib(so_name: str) -> ctypes.CDLL:
             if isinstance(cached, NativeUnavailable):
                 raise cached
             return cached
-        path = BUILD_DIR / so_name
         try:
-            if not path.exists():
-                subprocess.run(
-                    ["make", "-C", str(NATIVE_DIR)],
-                    check=True, capture_output=True, text=True, timeout=120,
-                )
-            lib = ctypes.CDLL(str(path))
+            _make()
+            lib = ctypes.CDLL(str(BUILD_DIR / so_name))
         except (OSError, subprocess.CalledProcessError,
                 subprocess.TimeoutExpired) as e:
             detail = getattr(e, "stderr", "") or str(e)

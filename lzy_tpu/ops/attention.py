@@ -26,14 +26,8 @@ def _match_vma(init, *refs):
     pipeline's pp axis with fsdp/tp auto), q/k/v are device-varying over the
     manual axes while a plain ``jnp.zeros`` is invariant — the scan's vma
     type check rejects that mix unless the init is pcast up front."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        # older jax: no varying-over-manual-axes types, nothing to align
-        return init
-    vma = frozenset().union(
-        *(getattr(jax.typeof(r), "vma", frozenset()) for r in refs)
-    )
-    missing = vma - getattr(jax.typeof(init), "vma", frozenset())
+    vma = frozenset().union(*(jax.typeof(r).vma for r in refs))
+    missing = vma - jax.typeof(init).vma
     if missing:
         init = lax.pcast(init, tuple(missing), to="varying")
     return init

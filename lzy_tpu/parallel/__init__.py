@@ -10,6 +10,7 @@ from lzy_tpu.parallel.sharding import (
 )
 from lzy_tpu.parallel.train import (
     PEAK_TFLOPS,
+    chip_peak_tflops,
     TrainState,
     make_eval_step,
     make_train_step,
@@ -33,6 +34,7 @@ __all__ = [
     "spec_for",
     "tree_shardings",
     "PEAK_TFLOPS",
+    "chip_peak_tflops",
     "TrainState",
     "make_eval_step",
     "make_train_step",

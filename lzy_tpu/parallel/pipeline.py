@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from lzy_tpu.utils.compat import shard_map
 
 
 def pipeline_apply(
@@ -181,7 +180,7 @@ def pipeline_apply(
         manual = {axis, seq_axis}
         x_spec = P(None, None, seq_axis)
     out_specs = (x_spec, P()) if with_aux else x_spec
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(param_specs, x_spec),

@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from lzy_tpu.utils.compat import inside_manual, shard_map
+
+from lzy_tpu.parallel.sharding import inside_manual
 
 _NEG_INF = -1e30
 
@@ -166,6 +167,6 @@ def ring_attention(
         # partitioners reject re-binding an axis a parent manual region
         # holds (sdy verifier error; GSPMD crash).
         return fn(*args)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=q_spec, check_vma=False,
     )(*args)

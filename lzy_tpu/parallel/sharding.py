@@ -112,3 +112,17 @@ def shard_tree(tree: Any, mesh: Mesh, logical_tree: Any,
     """Device-put a pytree with shardings derived from logical axes."""
     shardings = tree_shardings(mesh, logical_tree, rules)
     return jax.device_put(tree, shardings)
+
+
+def manual_axes() -> set:
+    """Mesh axes bound as manual (``shard_map``) at this trace point. A
+    sharding constraint naming one is rejected by the partitioner, and a
+    nested ``shard_map`` cannot re-bind it, so anchors strip these axes
+    and ring/Ulysses attention run their per-shard body directly."""
+    ctx = jax.sharding.get_abstract_mesh()
+    return set() if ctx.empty else set(ctx.manual_axes)
+
+
+def inside_manual(axis: str) -> bool:
+    """True when tracing inside a manual region that binds ``axis``."""
+    return axis in manual_axes()

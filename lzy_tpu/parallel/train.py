@@ -208,14 +208,27 @@ def make_eval_step(
 
 # -- MFU accounting ------------------------------------------------------------
 
-# dense peak TFLOP/s per chip, bf16 (public figures)
+#: dense bf16 peak TFLOP/s of one chip, keyed by the ``device_kind`` JAX
+#: reports (Google Cloud TPU documentation, the page of each generation).
+#: The one table of peaks: a kind that is not in it is an error, never a
+#: default, and there is no row for a CPU.
 PEAK_TFLOPS = {
-    "v4": 275.0,
-    "v5e": 197.0,
-    "v5p": 459.0,
-    "v6e": 918.0,
-    "cpu": 0.1,          # placeholder so tests can exercise the math
+    "TPU v4": 275.0,
+    "TPU v5 lite": 197.0,     # v5e
+    "TPU v5": 459.0,          # v5p
+    "TPU v6 lite": 918.0,     # v6e
 }
+
+
+def chip_peak_tflops(device_kind: str) -> float:
+    """Peak of the chip JAX reports as ``device_kind``."""
+    try:
+        return PEAK_TFLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s on record for device kind {device_kind!r} "
+            f"(known: {sorted(PEAK_TFLOPS)}); add it to "
+            f"parallel.train.PEAK_TFLOPS with its source") from None
 
 
 def transformer_flops_per_token(n_params: int) -> float:
@@ -223,9 +236,12 @@ def transformer_flops_per_token(n_params: int) -> float:
     return 6.0 * n_params
 
 
-def mfu(tokens_per_s: float, n_params: int, n_chips: int,
-        chip: str = "v5e", flops_per_token: Optional[float] = None) -> float:
+def mfu(tokens_per_s: float, n_params: int, n_chips: int, *,
+        peak_tflops: float,
+        flops_per_token: Optional[float] = None) -> float:
+    """Model FLOP/s utilization against ``peak_tflops`` per chip (from
+    :func:`chip_peak_tflops` for a measured run; tests of the arithmetic pass
+    a number)."""
     fpt = flops_per_token if flops_per_token is not None else transformer_flops_per_token(n_params)
     achieved = tokens_per_s * fpt
-    peak = PEAK_TFLOPS[chip] * 1e12 * n_chips
-    return achieved / peak
+    return achieved / (peak_tflops * 1e12 * n_chips)

@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from lzy_tpu.utils.compat import inside_manual, shard_map
+
+from lzy_tpu.parallel.sharding import inside_manual
 
 
 def ulysses_attention(
@@ -99,6 +100,6 @@ def ulysses_attention(
                 "renumber per-chunk); unpack or drop sp from the pipeline "
                 "mesh")
         return fn(*args)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=q_spec, check_vma=False,
     )(*args)

@@ -136,7 +136,5 @@ class LocalRuntime(Runtime):
     @staticmethod
     def _store_exception(workflow: "LzyWorkflow", call: "LzyCall", e: BaseException) -> None:
         tb = "".join(traceback.format_exception(type(e), e, e.__traceback__))
-        from lzy_tpu.utils.compat import add_exception_note
-
-        add_exception_note(e, f"[remote traceback]\n{tb}")
+        e.add_note(f"[remote traceback]\n{tb}")
         workflow.snapshot.put(call.exception_entry_id, e)

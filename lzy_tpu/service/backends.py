@@ -111,15 +111,25 @@ class ProcessVmBackend(VmBackend):
     """Each VM is a real OS process running ``lzy_tpu.rpc.worker_main`` — its
     own interpreter and JAX runtime, talking to the control plane over gRPC
     (the local analog of the reference's one-worker-binary-per-VM model, and
-    the template a cloud backend follows with pods instead of processes)."""
+    the template a cloud backend follows with pods instead of processes).
+
+    One process for each chip: workers run on the CPU unless the caller's
+    environment names a platform, and ``worker_platform`` overrides even
+    that — a control plane that serves a model in-process holds the chip,
+    so it passes ``"cpu"`` and its workers never ask for it. Each worker
+    logs the platform it was given when it registers. Who owns the chip
+    when an ``@op(tpu=...)`` runs under ``--backend process`` on a TPU host
+    is an open question (ROADMAP)."""
 
     def __init__(self, *, control_address_factory: Callable[[], str],
                  storage_uri: str, spill_root: Optional[str] = None,
-                 extra_pythonpath: Optional[str] = None):
+                 extra_pythonpath: Optional[str] = None,
+                 worker_platform: Optional[str] = None):
         self._control_address_factory = control_address_factory
         self._storage_uri = storage_uri
         self._spill_root = spill_root
         self._extra_pythonpath = extra_pythonpath
+        self._worker_platform = worker_platform
         self._procs: Dict[str, "object"] = {}
         self._lock = threading.Lock()
         self.allocator = None
@@ -141,7 +151,10 @@ class ProcessVmBackend(VmBackend):
         if env.get("PYTHONPATH"):
             pypath.append(env["PYTHONPATH"])
         env["PYTHONPATH"] = os.pathsep.join(pypath)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        if self._worker_platform:
+            env["JAX_PLATFORMS"] = self._worker_platform
+        else:
+            env.setdefault("JAX_PLATFORMS", "cpu")
         bootstrap = _bootstrap_token(self.allocator, vm)
         if bootstrap:
             # via env, not argv: tokens must not show up in `ps`; and a

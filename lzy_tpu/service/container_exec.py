@@ -46,9 +46,7 @@ def main(argv=None) -> int:
         result = payload["func"](*payload["args"], **payload["kwargs"])
     except BaseException as e:  # noqa: BLE001 — shipped back to the host
         tb = traceback.format_exc()
-        from lzy_tpu.utils.compat import add_exception_note
-
-        add_exception_note(e, f"[container traceback]\n{tb}")
+        e.add_note(f"[container traceback]\n{tb}")
         try:
             blob = cloudpickle.dumps(e)
         except Exception:

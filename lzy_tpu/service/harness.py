@@ -187,6 +187,11 @@ class InProcessCluster:
                 storage_uri=storage_uri,
                 spill_root=p2p_spill_root,
                 extra_pythonpath=worker_pythonpath,
+                # a plane that serves a model in-process holds the chip;
+                # its workers must not ask for it (docs/deployment.md)
+                worker_platform="cpu" if (
+                    inference_service is not None
+                    or inference_factory is not None) else None,
             )
         else:
             self.backend = ThreadVmBackend(

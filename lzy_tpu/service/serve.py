@@ -24,31 +24,9 @@ Example (GKE):
 from __future__ import annotations
 
 import argparse
-import os
 import signal
 import sys
 import threading
-
-
-def _enable_compile_cache() -> None:
-    """Persistent XLA compilation cache for the serving process (the
-    warm-start half that survives restarts). Until now only the test
-    tier enabled it (tests/conftest.py); a production server re-paid
-    every decode/prefill/verify compile on each boot — directly on the
-    first requests' TTFT. Cache entries are keyed on the HLO +
-    compile-options hash, so executables (and numerics) are unchanged;
-    ``LZY_JAX_CACHE_DIR`` overrides the location. Must run before the
-    first jit compilation, hence before any engine is built."""
-    cache_dir = os.environ.get("LZY_JAX_CACHE_DIR", "/tmp/lzy_jax_cache")
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # the default min-compile-time (1s) would skip most decode-step
-        # programs of small/medium configs — cache everything
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # noqa: BLE001 — older jax without the knobs
-        pass
 
 
 def main(argv=None) -> int:
@@ -358,7 +336,12 @@ def main(argv=None) -> int:
                 doc = _json.load(fh)
         tenants = TenantTable.from_doc(doc, default=default)
     if warm_start:
-        _enable_compile_cache()
+        # the warm start's persistent half: a restarted server reads its
+        # decode/prefill/verify programs back instead of compiling them on
+        # the first requests' TTFT (must precede the first compile)
+        from lzy_tpu.utils.jaxenv import enable_compile_cache
+
+        enable_compile_cache()
 
     if args.gateway_journal and not (args.gateway or args.disagg):
         parser.error("--gateway-journal needs a fleet front "
@@ -536,6 +519,10 @@ def main(argv=None) -> int:
             print(f"gateway journal recovery failed ({e}); serving "
                   f"with a fresh control plane", flush=True)
 
+    if args.serve_model:
+        from lzy_tpu.utils.jaxenv import device_line
+
+        print(f"serving engines on {device_line()}", flush=True)
     server = cluster.serve(args.port)
     model = f", model={args.serve_model}" if args.serve_model else ""
     if args.gateway:

@@ -140,6 +140,7 @@ class WorkerAgent:
         self._env_realizer = None          # built lazily (isolated mode only)
         self._env_lock = threading.RLock()
         self._mounts: Dict[str, Dict[str, Any]] = {}   # name -> {path, read_only}
+        self._devices_logged = False
         self._hb_thread = threading.Thread(
             target=self._heartbeat_loop, args=(heartbeat_period_s,),
             name=f"hb-{vm_id}", daemon=True,
@@ -344,6 +345,7 @@ class WorkerAgent:
                         )
                     else:
                         result = func(*args, **kwargs)
+                        self._log_op_devices()
 
             n_out = len(task.outputs)
             outputs = (result if n_out > 1 and isinstance(result, tuple)
@@ -591,10 +593,27 @@ class WorkerAgent:
         func = getattr(obj, "func", None)
         return func if callable(func) else obj
 
-    def _store_exception(self, task: TaskDesc, e: BaseException, tb: str) -> str:
-        from lzy_tpu.utils.compat import add_exception_note
+    def _log_op_devices(self) -> None:
+        """Say once which devices this worker's ops compute on, after the
+        first op that brought JAX up. Not at registration: querying the
+        devices there would take the chip before a gang worker reached
+        ``jax.distributed.initialize``. Without this line an
+        ``@op(tpu=...)`` body that a CPU-pinned process worker runs computes
+        on the CPU and nothing says so."""
+        if self._devices_logged or "jax" not in sys.modules:
+            return
+        from jax._src import xla_bridge
 
-        add_exception_note(e, f"[remote traceback from {self.vm_id}]\n{tb}")
+        if not xla_bridge.backends_are_initialized():
+            return
+        self._devices_logged = True
+        from lzy_tpu.utils.jaxenv import device_line
+
+        _LOG.warning("worker %s ops compute on %s", self.vm_id,
+                     device_line())
+
+    def _store_exception(self, task: TaskDesc, e: BaseException, tb: str) -> str:
+        e.add_note(f"[remote traceback from {self.vm_id}]\n{tb}")
         import cloudpickle
 
         try:

@@ -1595,8 +1595,10 @@ class PagedInferenceEngine(InferenceEngine):
         kv_tier=None,
         **kwargs,
     ):
+        from lzy_tpu.ops.interpret import resolve as pallas_interpreted
         from lzy_tpu.ops.paged_attention import (
-            DISPATCHES, QUANT_BLOCKS_RESIDENT, default_kernel)
+            DISPATCHES, QUANT_BLOCKS_RESIDENT, default_kernel,
+            lower_pallas_for_tpu)
         from lzy_tpu.serving.kv_cache import RadixCache, blocks_for_bytes
 
         base = decode_config(cfg)
@@ -1613,10 +1615,11 @@ class PagedInferenceEngine(InferenceEngine):
         self._page = page_size
         self._pages_per_seq = base.max_seq_len // page_size
         self._kv_quant = kv_quant
-        # kernel selection ladder (docs/serving.md): the fused Pallas
-        # program where the hardware has one, the lax gather-attention
-        # (bit-identical oracle) elsewhere, and "legacy" — the original
-        # gather-back-to-dense read — when native_attention is off
+        # kernel selection (docs/serving.md): "auto" is the kernel that
+        # compiles for a TPU (the lax gather-attention today), "pallas"
+        # is taken at the caller's word and checked below, and "legacy"
+        # — the original gather-back-to-dense read — serves when
+        # native_attention is off
         self._native = bool(native_attention)
         if not self._native:
             if kernel != "auto":
@@ -1657,6 +1660,15 @@ class PagedInferenceEngine(InferenceEngine):
         if kv_blocks < 2:
             raise ValueError(f"kv_blocks must be >= 2, got {kv_blocks}")
         self._kv_blocks = kv_blocks
+        if self.kernel_path == "pallas" and not pallas_interpreted(None):
+            # no silent drop to the interpreter or to lax: what the TPU
+            # lowering refuses, it refuses here, before a pool exists
+            lower_pallas_for_tpu(
+                batch=slots, n_heads=base.n_heads,
+                n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
+                n_blocks=kv_blocks, page_size=page_size,
+                pages_per_seq=self._pages_per_seq, dtype=base.dtype,
+                quantized=kv_quant is not None)
         self.kv = RadixCache(kv_blocks, page_size)
         # tiered KV cache (serving/kv_tier.py): radix eviction DEMOTES
         # block payloads to pinned host RAM (and onward to storage)

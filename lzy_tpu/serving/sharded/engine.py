@@ -90,11 +90,6 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
             raise ValueError(
                 "kernel='pallas' cannot serve sharded: the fused kernel is "
                 "a custom call GSPMD cannot partition; use kernel='lax'")
-        if kwargs.get("native_attention") and \
-                kwargs.get("kernel", "auto") == "auto":
-            # default_kernel() may pick pallas on TPU hosts — pin the
-            # partitionable gather kernel instead of failing at dispatch
-            kwargs["kernel"] = "lax"
         self._mesh = mesh
         self._tp = tp
         self.gang_size = tp

@@ -9,10 +9,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import jax
 
-from lzy_tpu.utils.compat import request_cpu_devices
-
 jax.config.update("jax_platforms", "cpu")
-request_cpu_devices(8)
+jax.config.update("jax_num_cpu_devices", 8)
 
 
 def make_lzy():

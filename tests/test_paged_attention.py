@@ -150,6 +150,18 @@ class TestKernelBitExactness:
                                 dtype=dtype, quant=side)
             p = paged_attention(q, kp, vp, pt, pos, kernel="pallas",
                                 dtype=dtype, quant=side, interpret=True)
+            if quant and dtype == jnp.float32:
+                # the one case that is not bitwise: the dequantised float32
+                # values go through a [T*G, L] x [L, D] contraction whose
+                # summation order the CPU backend picks per program, and
+                # interpreted Pallas and op-by-op lax are two programs
+                # (seen: 1.2e-7 on outputs of order 1). Four float32 ulps
+                # at the output's scale; bf16 rounds the difference away.
+                a, p = np.asarray(a), np.asarray(p)
+                atol = 4 * np.finfo(np.float32).eps * max(
+                    1.0, float(np.abs(a).max()))
+                np.testing.assert_allclose(p, a, rtol=0, atol=atol)
+                continue
             assert bool(jnp.array_equal(a, p)), \
                 f"pallas != lax at dtype={dtype} quant={quant}"
 

@@ -170,9 +170,17 @@ class TestRingAttention:
 
 def test_mfu_math():
     # 1000 tok/s on a 1B model over 16 v5e chips
-    val = mfu(1000.0, 1_000_000_000, 16, chip="v5e")
+    val = mfu(1000.0, 1_000_000_000, 16, peak_tflops=197.0)
     assert 0 < val < 1
     np.testing.assert_allclose(val, 6e12 / (197e12 * 16), rtol=1e-6)
+
+
+def test_peak_is_looked_up_by_device_kind_and_unknown_kinds_fail():
+    from lzy_tpu.parallel.train import chip_peak_tflops
+
+    assert chip_peak_tflops("TPU v5 lite") == 197.0
+    with pytest.raises(ValueError, match="no peak"):
+        chip_peak_tflops("cpu")
 
 
 class TestUlyssesAttention:

@@ -18,6 +18,7 @@ import signal
 import socket
 import subprocess
 import sys
+import tempfile
 import time
 
 import pytest
@@ -60,28 +61,38 @@ def _free_port() -> int:
 
 
 def _spawn_serve(args, *, timeout_s: float = 30.0):
-    """Start serve.py exactly as the container does; wait for readiness."""
+    """Start serve.py exactly as the container does; wait for readiness.
+    Its output goes to a file, not a pipe: nobody reads a pipe once the
+    banner is seen, and a server that logs past the pipe's 64 KiB then
+    blocks in write() with a request in flight (XLA logs a line of 3 KiB
+    for every program it reads back from the compile cache)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [REPO_ROOT, TESTS_DIR] +
         ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     env.setdefault("JAX_PLATFORMS", "cpu")
+    log = tempfile.NamedTemporaryFile(
+        "w", prefix="serve-", suffix=".log", delete=False)
     proc = subprocess.Popen(
         [sys.executable, "-m", "lzy_tpu.service.serve", *args],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env,
+        stdout=log, stderr=subprocess.STDOUT, env=env,
     )
+    log.close()
+    proc.log_path = log.name
     deadline = time.monotonic() + timeout_s
-    banner = ""
-    while time.monotonic() < deadline:
-        line = proc.stdout.readline()
-        if not line:
-            break
-        banner += line
-        if "serving on" in line:
+    while time.monotonic() < deadline and proc.poll() is None:
+        banner = _output(proc)
+        if "serving on" in banner:
             return proc, banner
+        time.sleep(0.1)
     proc.kill()
-    raise AssertionError(f"serve.py never became ready; output:\n{banner}"
-                         f"{proc.stdout.read() if proc.stdout else ''}")
+    raise AssertionError(
+        f"serve.py never became ready; output:\n{_output(proc)}")
+
+
+def _output(proc) -> str:
+    with open(proc.log_path) as f:
+        return f.read()
 
 
 @pytest.fixture(scope="module")
@@ -136,7 +147,7 @@ class TestServeEntrypoint:
         proc, _, _ = served
         proc.send_signal(signal.SIGTERM)
         rc = proc.wait(30)
-        out = proc.stdout.read()
+        out = _output(proc)
         assert rc == 0, f"non-zero exit {rc}; output tail:\n{out[-2000:]}"
         assert "shutting down" in out
 
