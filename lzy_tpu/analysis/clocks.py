@@ -42,6 +42,12 @@ ALLOWLIST: Dict[str, str] = {
         "VirtualClock's real-time backstop/stall-limit polls are "
         "deliberately wall-clock (they detect participants stuck "
         "OUTSIDE the virtual clock)",
+    "lzy_tpu/utils/trace.py":
+        "the span recorder's stamps are wall time by contract: they are "
+        "compared with Request's stamps under the system clock and tied "
+        "to the profiler's clock by an anchor that embeds "
+        "time.monotonic_ns(); a virtual clock would place spans nowhere "
+        "on a device trace (callers stamp through trace.now())",
     "lzy_tpu/utils/ids.py":
         "wall-clock millis embedded in generated ids for sortability/"
         "debuggability — id entropy, never scheduling; a virtual clock "

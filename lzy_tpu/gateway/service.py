@@ -35,6 +35,7 @@ from lzy_tpu.gateway.router import PrefixAffinityRouter
 from lzy_tpu.serving.scheduler import (
     AdmissionError, DEFAULT_TENANT, PromptTooLong, QuotaExceeded,
     any_to_tokens, quota_error, shed_error)
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.log import get_logger
 from lzy_tpu.utils.metrics import REGISTRY
 
@@ -313,103 +314,111 @@ class GatewayService:
         id); without one, a journal-backed gateway births a fresh unary
         record — settled with a typed status by recovery if the process
         dies before the reply."""
-        if self.journal is not None:
-            CHAOS.hit("gateway.crash")
-        if self.kv_index is not None:
-            self._kvtier_tls.meta = {}   # fresh per call (failovers restage)
-        resumed = resume_tokens is not None
-        subject = self._auth(token) if not resumed else None
-        from lzy_tpu.rpc.core import Unavailable
+        with trace.span(trace.GATEWAY_GENERATE) as root:
+            if self.journal is not None:
+                CHAOS.hit("gateway.crash")
+            if self.kv_index is not None:
+                # fresh per call (failovers restage)
+                self._kvtier_tls.meta = {}
+            resumed = resume_tokens is not None
+            subject = self._auth(token) if not resumed else None
+            from lzy_tpu.rpc.core import Unavailable
 
-        jrid = journal_rid
-        try:
-            if not resumed:
-                tenant = self._resolve_tenant(subject, tenant)
-            else:
-                tenant = tenant or DEFAULT_TENANT
-            prompt = any_to_tokens(prompt)
-            self._check_prompt_len(prompt, int(max_new_tokens))
-            if not resumed:
-                policy = self._slo_admit(tenant, prompt)
-                if policy is not None:
-                    priority = policy.effective_priority(priority)
-            if self._draining:
-                raise self._shed_error(
-                    Unavailable,
-                    "gateway is draining; retry another endpoint",
-                    reason="draining", retry_after_s=None)
-            # streaming session workers (liveness is not None) bypass
-            # the waiter cap: they are dedicated threads bounded by the
-            # session manager's max_sessions, and gating them here
-            # would cap streams at the waiter count while starving
-            # unary callers for each stream's whole lifetime
-            gated = liveness is None
-            if gated and not self._waiters.acquire(blocking=False):
-                raise self._shed_error(
-                    Unavailable,
-                    "all gateway waiter threads are busy; retry later",
-                    reason="waiters_busy", retry_after_s=0.25)
-            if self.journal is not None and jrid is None:
-                # unary birth (streamed calls carry the stream manager's
-                # record id), BELOW the draining/waiter shed gates: a
-                # fast-rejected request never ran and its reply is
-                # synchronous — journaling it would turn the cheap shed
-                # path into a per-rejection disk write under exactly
-                # the overload it absorbs. LEAN on purpose — a unary
-                # request can only ever be settled as orphaned on
-                # recovery (its reply channel dies with this process),
-                # so the record carries the identity the auditor needs
-                # and NOT the prompt/token payload
-                jrid = self.journal.record_birth(
-                    prompt=(), max_new_tokens=int(max_new_tokens),
-                    greedy=greedy, tenant=tenant, priority=priority,
-                    session=session, deadline_s=deadline_s,
-                    timeout_s=timeout_s, streamed=False,
-                    subject_id=subject.id if subject is not None
-                    else None)
-            with self._lock:
-                self._inflight += 1
+            jrid = journal_rid
             try:
-                reply = self._generate(prompt,
-                                       int(max_new_tokens),
-                                       timeout_s=timeout_s or 120.0,
-                                       deadline_s=deadline_s,
-                                       greedy=greedy,
-                                       tenant=tenant,
-                                       priority=priority,
-                                       session=session,
-                                       stream=stream,
-                                       liveness=liveness,
-                                       resume_tokens=resume_tokens,
-                                       journal_rid=jrid)
-            finally:
+                if not resumed:
+                    tenant = self._resolve_tenant(subject, tenant)
+                else:
+                    tenant = tenant or DEFAULT_TENANT
+                prompt = any_to_tokens(prompt)
+                self._check_prompt_len(prompt, int(max_new_tokens))
+                if not resumed:
+                    policy = self._slo_admit(tenant, prompt)
+                    if policy is not None:
+                        priority = policy.effective_priority(priority)
+                if self._draining:
+                    raise self._shed_error(
+                        Unavailable,
+                        "gateway is draining; retry another endpoint",
+                        reason="draining", retry_after_s=None)
+                # streaming session workers (liveness is not None) bypass
+                # the waiter cap: they are dedicated threads bounded by the
+                # session manager's max_sessions, and gating them here
+                # would cap streams at the waiter count while starving
+                # unary callers for each stream's whole lifetime
+                gated = liveness is None
+                if gated and not self._waiters.acquire(blocking=False):
+                    raise self._shed_error(
+                        Unavailable,
+                        "all gateway waiter threads are busy; retry later",
+                        reason="waiters_busy", retry_after_s=0.25)
+                if self.journal is not None and jrid is None:
+                    # unary birth (streamed calls carry the stream manager's
+                    # record id), BELOW the draining/waiter shed gates: a
+                    # fast-rejected request never ran and its reply is
+                    # synchronous — journaling it would turn the cheap shed
+                    # path into a per-rejection disk write under exactly
+                    # the overload it absorbs. LEAN on purpose — a unary
+                    # request can only ever be settled as orphaned on
+                    # recovery (its reply channel dies with this process),
+                    # so the record carries the identity the auditor needs
+                    # and NOT the prompt/token payload
+                    jrid = self.journal.record_birth(
+                        prompt=(), max_new_tokens=int(max_new_tokens),
+                        greedy=greedy, tenant=tenant, priority=priority,
+                        session=session, deadline_s=deadline_s,
+                        timeout_s=timeout_s, streamed=False,
+                        subject_id=subject.id if subject is not None
+                        else None)
+                if root:
+                    # everything up to here is admission: auth, tenant,
+                    # prompt check, SLO, the shed gates, the journal
+                    trace.note(tenant=tenant, prompt_tokens=len(prompt))
+                    trace.emit(trace.GATEWAY_ADMIT, root.start,
+                               trace.now())
                 with self._lock:
-                    self._inflight -= 1
-                if gated:
-                    self._waiters.release()
-            if self.journal is not None and jrid is not None \
-                    and journal_rid is None:
-                # settle the unary record we birthed (streamed records
-                # are settled by the session manager, which also owns
-                # the reply metadata); lean like the birth — status
-                # only, no token payload
-                self.journal.finish(jrid, reply.get("status", "ok"))
-            return reply
-        except BaseException as e:
-            from lzy_tpu.durable.failures import InjectedCrash
+                    self._inflight += 1
+                try:
+                    reply = self._generate(prompt,
+                                           int(max_new_tokens),
+                                           timeout_s=timeout_s or 120.0,
+                                           deadline_s=deadline_s,
+                                           greedy=greedy,
+                                           tenant=tenant,
+                                           priority=priority,
+                                           session=session,
+                                           stream=stream,
+                                           liveness=liveness,
+                                           resume_tokens=resume_tokens,
+                                           journal_rid=jrid)
+                finally:
+                    with self._lock:
+                        self._inflight -= 1
+                    if gated:
+                        self._waiters.release()
+                if self.journal is not None and jrid is not None \
+                        and journal_rid is None:
+                    # settle the unary record we birthed (streamed records
+                    # are settled by the session manager, which also owns
+                    # the reply metadata); lean like the birth — status
+                    # only, no token payload
+                    self.journal.finish(jrid, reply.get("status", "ok"))
+                return reply
+            except BaseException as e:
+                from lzy_tpu.durable.failures import InjectedCrash
 
-            if self.journal is not None and jrid is not None \
-                    and journal_rid is None \
-                    and not isinstance(e, InjectedCrash):
-                # a real process death runs no except blocks: the
-                # injected stand-in must leave the record live for
-                # recovery to settle with its typed status
-                self.journal.finish(
-                    jrid, "error", error=f"{type(e).__name__}: {e}")
-            from lzy_tpu.channels.token_stream import fail_if_touched
+                if self.journal is not None and jrid is not None \
+                        and journal_rid is None \
+                        and not isinstance(e, InjectedCrash):
+                    # a real process death runs no except blocks: the
+                    # injected stand-in must leave the record live for
+                    # recovery to settle with its typed status
+                    self.journal.finish(
+                        jrid, "error", error=f"{type(e).__name__}: {e}")
+                from lzy_tpu.channels.token_stream import fail_if_touched
 
-            fail_if_touched(stream, e)
-            raise
+                fail_if_touched(stream, e)
+                raise
 
     def _shed_error(self, exc_type, msg: str, *, reason: str,
                     retry_after_s: Optional[float]):
@@ -498,30 +507,36 @@ class GatewayService:
                     "routed_by": route[1] if route else None,
                     "failovers": failovers, **self._reply_extras()}
             effective_prompt = prompt + emitted
-            replica, routed_by, req = self._submit_routed(
-                effective_prompt, remaining,
-                t0=t0, deadline_s=deadline_s,
-                exclude=tried_after_failure, greedy=greedy,
-                tenant=tenant, priority=priority, session=session,
-                liveness=liveness)
-            route = (replica.id, routed_by)
-            if self.journal is not None and journal_rid is not None:
-                self.journal.record_attempt(journal_rid, replica.id)
-            if stream is not None:
-                # the fence is the stream position: this attempt's tokens
-                # land at len(emitted) + i, so a resumed attempt continues
-                # the channel exactly where the dead one stopped
-                from lzy_tpu.channels.token_stream import attach_request
+            with trace.span(trace.GATEWAY_ATTEMPT) as attempt:
+                replica, routed_by, req = self._submit_routed(
+                    effective_prompt, remaining,
+                    t0=t0, deadline_s=deadline_s,
+                    exclude=tried_after_failure, greedy=greedy,
+                    tenant=tenant, priority=priority, session=session,
+                    liveness=liveness)
+                route = (replica.id, routed_by)
+                if attempt:
+                    trace.note(replica=replica.id, routed_by=routed_by,
+                               failover=failovers, request=req.id)
+                if self.journal is not None and journal_rid is not None:
+                    self.journal.record_attempt(journal_rid, replica.id)
+                if stream is not None:
+                    # the fence is the stream position: this attempt's
+                    # tokens land at len(emitted) + i, so a resumed
+                    # attempt continues the channel exactly where the
+                    # dead one stopped
+                    from lzy_tpu.channels.token_stream import attach_request
 
-                attach_request(stream, req, len(emitted))
-            if not req.wait(timeout=max(0.0,
-                                        wall_deadline - self._clock.now())):
-                req.cancel()
-                # no outcome will ever be recorded for this dispatch:
-                # a half-open probe claim must not outlive it
-                self.fleet.health.release_probe(replica.id)
-                raise TimeoutError(
-                    f"request {req.id} not finished within {timeout_s}s")
+                    attach_request(stream, req, len(emitted))
+                if not req.wait(timeout=max(
+                        0.0, wall_deadline - self._clock.now())):
+                    req.cancel()
+                    # no outcome will ever be recorded for this dispatch:
+                    # a half-open probe claim must not outlive it
+                    self.fleet.health.release_probe(replica.id)
+                    raise TimeoutError(
+                        f"request {req.id} not finished within "
+                        f"{timeout_s}s")
             if first_ttft_ms is None and req.first_token_at is not None:
                 first_ttft_ms = round(
                     1000 * (req.first_token_at - t0), 3)
@@ -672,6 +687,7 @@ class GatewayService:
         # the ordinary routed path and the lease is lazily dropped.
         pinned = self._fused_pin(session) if session is not None else None
         while loads:
+            t_route = trace.now() if trace.ON else 0.0
             rid, reason = self.router.choose(prompt, loads,
                                              session=session,
                                              pinned=pinned)
@@ -702,6 +718,10 @@ class GatewayService:
             engine_deadline = self._remaining_deadline(t0, deadline_s)
             if engine_deadline is not None:
                 engine_deadline = max(0.001, engine_deadline)
+            if trace.ON:
+                # the router's pick, the breaker's claim, the staging
+                trace.emit(trace.GATEWAY_ROUTE, t_route, trace.now(),
+                           replica=rid, routed_by=reason)
             try:
                 CHAOS.hit("gateway.dispatch")
                 req = replica.engine.submit(

@@ -38,6 +38,7 @@ import dataclasses
 from typing import Any, Dict, List, Optional, Sequence
 
 from lzy_tpu.chaos.faults import CHAOS
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.backoff import RetryPolicy
 from lzy_tpu.utils.clock import SYSTEM_CLOCK
 from lzy_tpu.utils.ids import gen_id
@@ -288,10 +289,12 @@ def llm_generate_batch(prompts, gen_params, model_digest,
         key = tuple(int(t) for t in p) if dedupable else ("row", i)
         row_keys.append(key)
         unique.setdefault(key, list(p))
-    results = sched.map(
-        lambda p: llm_generate(p, gen_params, model_digest,
-                               conversation, runtime_opts),
-        list(unique.values()))
+    with trace.span(trace.LLM_BATCH, rows=len(prompts),
+                    deduplicated=len(prompts) - len(unique)):
+        results = sched.map(
+            lambda p: llm_generate(p, gen_params, model_digest,
+                                   conversation, runtime_opts),
+            list(unique.values()))
     by_key = dict(zip(unique.keys(), results))
     out, adopted = [], set()
     for key in row_keys:

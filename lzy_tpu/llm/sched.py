@@ -49,6 +49,7 @@ import os
 import threading
 from typing import Any, Dict, List, Optional
 
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.log import get_logger
 
 _LOG = get_logger(__name__)
@@ -68,6 +69,15 @@ def _flag(name: str, default: bool) -> bool:
     if raw is None:
         return default
     return raw.strip().lower() not in ("0", "false", "no", "off", "")
+
+
+def _traced_row(fn, item, parent, handed: float):
+    """One row of :meth:`WorkflowScheduler.map` on its pool thread:
+    ``llm.row`` runs from the hand-over, ``llm.row.pool_wait`` is the part
+    before the function started."""
+    with trace.span(trace.LLM_ROW, parent=parent, start=handed):
+        trace.emit(trace.LLM_ROW_POOL_WAIT, handed, trace.now())
+        return fn(item)
 
 
 class _InFlight:
@@ -154,7 +164,15 @@ class WorkflowScheduler:
         too); the first exception propagates after all rows settle."""
         if not items:
             return []
-        futures = [self._plane().submit(fn, item) for item in items]
+        if trace.ON:
+            # each item carries the caller's open span and the time it was
+            # handed over: the row's wait for one of the pool's threads
+            parent = trace.context()
+            futures = [self._plane().submit(_traced_row, fn, item, parent,
+                                            trace.now())
+                       for item in items]
+        else:
+            futures = [self._plane().submit(fn, item) for item in items]
         results, first_err = [], None
         for fut in futures:
             try:
@@ -206,7 +224,8 @@ class WorkflowScheduler:
                 stream=stream)
 
         if not (self.dedup and greedy is True and stream is None):
-            return call()
+            with trace.span(trace.LLM_DISPATCH, role="solo"):
+                return call()
         # the dedup identity mirrors the op cache key: prompt + the
         # output-determining params + model digest, plus the SLO
         # identity (a follower must not ride a reply another tenant's
@@ -227,7 +246,8 @@ class WorkflowScheduler:
                     leader = False
             if leader:
                 try:
-                    entry.reply = call()
+                    with trace.span(trace.LLM_DISPATCH, role="leader"):
+                        entry.reply = call()
                 except BaseException as e:
                     entry.error = e
                     raise
@@ -242,11 +262,14 @@ class WorkflowScheduler:
                 return entry.reply
             # follower: adopt the leader's terminal reply without ever
             # touching the fleet
-            if not entry.done.wait(timeout_s if timeout_s
-                                   else _FOLLOWER_WAIT_S):
+            with trace.span(trace.LLM_DISPATCH, role="follower"):
+                adopted = entry.done.wait(timeout_s if timeout_s
+                                          else _FOLLOWER_WAIT_S)
+            if not adopted:
                 # the leader outlived our budget — stop waiting and
                 # dispatch for ourselves (no dedup credit)
-                return call()
+                with trace.span(trace.LLM_DISPATCH, role="solo"):
+                    return call()
             reply = entry.reply
             if entry.error is None and isinstance(reply, dict) \
                     and reply.get("status") == "ok":

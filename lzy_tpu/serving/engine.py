@@ -51,6 +51,7 @@ batch decode one token per step from the same rng draw order as before.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -72,10 +73,12 @@ from lzy_tpu.serving.spec import (
     DRAFT_TRUNCATED as _SPEC_TRUNCATED, NgramProposer,
     PROPOSED as _SPEC_PROPOSED, TOKENS_PER_STEP as _SPEC_TPS,
     VERIFY_STEPS as _SPEC_STEPS)
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.log import get_logger
 from lzy_tpu.utils.metrics import REGISTRY
 
 _LOG = get_logger(__name__)
+_loop_ids = itertools.count(1)
 
 
 class PoolCorruption(RuntimeError):
@@ -88,11 +91,13 @@ class PoolCorruption(RuntimeError):
 _TTFT = REGISTRY.histogram(
     "lzy_inference_ttft_seconds",
     "submit-to-first-token latency (includes queueing and prefill)",
-    buckets=(0.005, 0.02, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0, 60.0))
+    buckets=(0.02, 0.05, 0.1, 0.2, 0.3, 0.5, 0.75, 1.0, 1.5, 2.0, 3.0,
+             5.0, 10.0, 60.0))
 _STEP = REGISTRY.histogram(
     "lzy_inference_decode_step_seconds",
     "one jitted decode step over the slot batch",
-    buckets=(0.001, 0.005, 0.02, 0.05, 0.1, 0.25, 1.0, 5.0))
+    buckets=(0.001, 0.005, 0.01, 0.02, 0.03, 0.04, 0.05, 0.065, 0.08,
+             0.1, 0.25, 1.0, 5.0))
 _TOKENS = REGISTRY.counter(
     "lzy_inference_tokens_total", "generated tokens (all requests)")
 _REQUESTS = REGISTRY.counter(
@@ -123,13 +128,22 @@ _PREFILL_ROUNDS = REGISTRY.counter(
 # decode-round scheduling (docs/serving.md "Decode-round scheduling"):
 # each round dispatches ONE fused device program and takes ONE
 # device->host fence — the contract the transfer-count regression test
-# pins. Phase timers cover the round's anatomy: ``plan`` (host work
-# before the dispatch), ``overlap`` (host work run while the device
+# pins. Phase timers cover the whole loop, at the boundaries of the
+# ``engine.*`` spans (utils/trace.py). Before the decode half: ``kv_io``
+# (cross-replica KV imports/exports, parked chains), ``reap`` (cancelled
+# requests), ``admit`` (pop, verdict, prefill staging: radix match, block
+# allocation, eviction), ``prefill`` (one budgeted prefill advance, less
+# ``prefill_fence``: the wait for the first token in the round that
+# finishes a prompt, observed in those rounds alone). The decode half:
+# ``plan`` (host work before the dispatch), ``dispatch`` (input upload and
+# the program's enqueue), ``overlap`` (host work run while the device
 # computes), ``fence`` (the single blocking transfer), ``emit`` (token
-# delivery + batched accounting after the fence).
+# delivery + batched accounting after the fence). And ``park``: the
+# loop's wait when a round found nothing to do.
 _ROUND_PHASE = REGISTRY.histogram(
     "lzy_engine_round_phase_seconds",
-    "decode-round phase wall time (phase=plan|overlap|fence|emit)",
+    "engine-loop phase wall time (phase=kv_io|reap|admit|prefill|"
+    "prefill_fence|plan|dispatch|overlap|fence|emit|park)",
     buckets=(0.0001, 0.0005, 0.001, 0.005, 0.02, 0.05, 0.25, 1.0))
 _ROUND_FENCES = REGISTRY.counter(
     "lzy_engine_round_fences_total",
@@ -370,6 +384,10 @@ class InferenceEngine:
         self._prefill_jobs: List[_PrefillJob] = []
         self._next_prefill = 0
         self.prefill_rounds = 0         # public: interleave observability
+        # what the round's decode half did, for the engine.round span
+        self._round_kind: Optional[str] = None
+        self._round_rows = self._round_emitted = 0
+        self._prefill_wait = 0.0        # this round's prefill fence
         # per-tenant SLO state: policy table (WFQ weights, queue caps, KV
         # quotas) and terminal accounting for the scoped stats surface
         self.tenants = tenants
@@ -684,19 +702,53 @@ class InferenceEngine:
         runs a decode step for the resident rows — bounded inter-token
         latency for them, bounded time-to-first-chunk for newly staged
         short prompts (jobs rotate round-robin)."""
-        if CHAOS.armed is not None and (
-                self.queue.depth() or self._prefill_jobs
-                or any(r is not None for r in self._active)):
-            # chaos boundary, hit only on rounds with real work so a
-            # parked loop's idle spins don't consume the fault schedule.
-            # The armed check comes FIRST: disarmed (production) rounds
-            # must not pay the queue-lock probe in the hottest loop
-            CHAOS.hit("engine.step")
-        self._reap_cancelled()
-        admitted = self._admit()
-        progressed = self._advance_prefill()
-        stepped = self._decode()
-        return admitted or progressed or stepped
+        now = self._clock.now
+        self._round_kind = None
+        self._prefill_wait = 0.0
+        with trace.span(trace.ENGINE_ROUND) as rnd:
+            t0 = now()
+            with trace.span(trace.ENGINE_KV_IO):
+                serviced = self._service_io()
+            t1 = now()
+            if CHAOS.armed is not None and (
+                    self.queue.depth() or self._prefill_jobs
+                    or any(r is not None for r in self._active)):
+                # chaos boundary, hit only on rounds with real work so a
+                # parked loop's idle spins don't consume the fault
+                # schedule. The armed check comes FIRST: disarmed
+                # (production) rounds must not pay the queue-lock probe in
+                # the hottest loop
+                CHAOS.hit("engine.step")
+            with trace.span(trace.ENGINE_REAP):
+                self._reap_cancelled()
+            t2 = now()
+            with trace.span(trace.ENGINE_ADMIT):
+                admitted = self._admit()
+            t3 = now()
+            with trace.span(trace.ENGINE_PREFILL):
+                progressed = self._advance_prefill()
+            t4 = now()
+            stepped = self._decode()
+            # observed after the round's fence, like the decode half's
+            _ROUND_PHASE.observe(t1 - t0, phase="kv_io")
+            _ROUND_PHASE.observe(t2 - t1, phase="reap")
+            _ROUND_PHASE.observe(t3 - t2, phase="admit")
+            wait = self._prefill_wait
+            _ROUND_PHASE.observe(t4 - t3 - wait, phase="prefill")
+            if wait:        # only a round that finished a prompt has one
+                _ROUND_PHASE.observe(wait, phase="prefill_fence")
+            worked = serviced or admitted or progressed or stepped
+            if rnd and stepped:
+                trace.note(kind=self._round_kind, rows=self._round_rows,
+                           emitted=self._round_emitted)
+            elif rnd:
+                trace.note(kind="prefill_only" if worked else "idle")
+        return worked
+
+    def _service_io(self) -> bool:
+        """Round work ahead of the reap and the admissions; the paged
+        engine services cross-replica KV I/O and parked chains here."""
+        return False
 
     def _reap_cancelled(self) -> None:
         """Free slots whose waiter abandoned the request (client
@@ -785,6 +837,8 @@ class InferenceEngine:
         True iff a prefill job was staged."""
         self.queue.pop_request(req)
         req.phase = "prefill"
+        if req.admitted_at is None:
+            req.admitted_at = self._clock.now()
         try:
             job = self._stage_prefill(slot, req)
         except PoolCorruption:
@@ -797,6 +851,10 @@ class InferenceEngine:
             req.finish(error=f"{type(e).__name__}: {e}")
             return False
         self._prefill_jobs.append(job)
+        if trace.ON:
+            trace.note(request=req.id, prompt_tokens=len(req.prompt),
+                       prefix_hit_tokens=job.matched,
+                       blocks=len(job.table))
         return True
 
     def _commit_admission_plan(self) -> Optional[bool]:
@@ -896,6 +954,7 @@ class InferenceEngine:
             self._abort_prefill_job(job)
             self._finish_cancelled(req)
             return True
+        chunks0, tokens0 = job.next_chunk, job.done
         try:
             finished = self._advance_prefill_round(job)
         except PoolCorruption:
@@ -911,6 +970,9 @@ class InferenceEngine:
             return True
         self.prefill_rounds += 1
         _PREFILL_ROUNDS.inc()
+        if trace.ON:
+            trace.note(request=req.id, chunks=job.next_chunk - chunks0,
+                       tokens=job.done - tokens0, finished=finished)
         if finished:
             self._drop_prefill_job(job)
         else:
@@ -982,8 +1044,19 @@ class InferenceEngine:
             return big.at[slot].set(small[0])
 
         self._cache = jax.tree_util.tree_map(ins, self._cache, cache)
-        self._finish_prefill(slot, req, int(first[0]))
+        self._finish_prefill(slot, req, self._prefill_fence(first))
         return True
+
+    def _prefill_fence(self, first) -> int:
+        """The prefill's one blocking transfer: the first token, and with
+        it the wait for every chunk still queued on the device. Timed
+        apart from the ``prefill`` phase: here the loop waits for the
+        device, not the device for the loop."""
+        t0 = self._clock.now()
+        with trace.span(trace.ENGINE_PREFILL_FENCE):
+            token = int(first[0])
+        self._prefill_wait = self._clock.now() - t0
+        return token
 
     def _finish_prefill(self, slot: int, req: Request, first: int) -> None:
         """Shared prefill tail: record TTFT, emit the first token, and
@@ -1100,33 +1173,38 @@ class InferenceEngine:
         if not any(r is not None for r in self._active):
             return False
         t_plan = self._clock.now()
-        if not self._pre_decode():
-            return False
-        plan = self._spec_plan()
+        with trace.span(trace.ENGINE_DECODE_PLAN):
+            if not self._pre_decode():
+                return False
+            plan = self._spec_plan()
         if plan is not None:
             return self._decode_verify(plan, t_plan)
         t0 = self._clock.now()
-        (self._payload, self._pos_dev, self._cur_dev,
-         self._rng) = self._run_decode_step()
+        with trace.span(trace.ENGINE_DECODE_DISPATCH):
+            (self._payload, self._pos_dev, self._cur_dev,
+             self._rng) = self._run_decode_step()
         t1 = self._clock.now()
-        self._overlap_window()
+        with trace.span(trace.ENGINE_DECODE_OVERLAP):
+            self._overlap_window()
         t2 = self._clock.now()
-        nxt = self._fetch(self._cur_dev)   # the round's ONE fence
+        with trace.span(trace.ENGINE_DECODE_FENCE):
+            nxt = self._fetch(self._cur_dev)   # the round's ONE fence
         t3 = self._clock.now()
         dt = t3 - t0
-        _STEP.observe(dt)
-        self._post_decode_step()
-        emitted = rows = 0
-        for slot, req in enumerate(self._active):
-            if req is None:
-                continue
-            self._emit(slot, req, int(nxt[slot]), active=True)
-            emitted += 1
-            rows += 1
-        self._note_decode_round(emitted, rows, dt)
-        _BUSY.set(float(sum(r is not None for r in self._active)))
-        self._note_round_phases("decode", t0 - t_plan, t2 - t1, t3 - t2,
-                                self._clock.now() - t3)
+        with trace.span(trace.ENGINE_DECODE_EMIT):
+            _STEP.observe(dt)
+            self._post_decode_step()
+            emitted = rows = 0
+            for slot, req in enumerate(self._active):
+                if req is None:
+                    continue
+                self._emit(slot, req, int(nxt[slot]), active=True)
+                emitted += 1
+                rows += 1
+            self._note_decode_round(emitted, rows, dt)
+            _BUSY.set(float(sum(r is not None for r in self._active)))
+            self._note_round_phases("decode", t0 - t_plan, t1 - t0, t2 - t1,
+                                    t3 - t2, self._clock.now() - t3)
         return True
 
     # -- speculative decode (serving/spec.py) ------------------------------
@@ -1194,22 +1272,33 @@ class InferenceEngine:
         could surface."""
         t0 = self._clock.now()
         gamma = self.spec_tokens
-        prop = np.zeros((self.slots, gamma), np.int32)
-        plen = np.zeros((self.slots,), np.int32)
-        for slot, p in plan.items():
-            prop[slot, :len(p)] = p
-            plen[slot] = len(p)
-        (self._payload, packed, self._cur_dev, self._pos_dev,
-         self._rng) = self._run_verify_step(jnp.asarray(prop),
-                                            jnp.asarray(plen))
+        with trace.span(trace.ENGINE_DECODE_DISPATCH):
+            prop = np.zeros((self.slots, gamma), np.int32)
+            plen = np.zeros((self.slots,), np.int32)
+            for slot, p in plan.items():
+                prop[slot, :len(p)] = p
+                plen[slot] = len(p)
+            (self._payload, packed, self._cur_dev, self._pos_dev,
+             self._rng) = self._run_verify_step(jnp.asarray(prop),
+                                                jnp.asarray(plen))
         t1 = self._clock.now()
-        self._overlap_window()
+        with trace.span(trace.ENGINE_DECODE_OVERLAP):
+            self._overlap_window()
         t2 = self._clock.now()
-        packed = self._fetch(packed)       # the round's ONE fence
+        with trace.span(trace.ENGINE_DECODE_FENCE):
+            packed = self._fetch(packed)   # the round's ONE fence
         t3 = self._clock.now()
         dt = t3 - t0
-        _STEP.observe(dt)
+        with trace.span(trace.ENGINE_DECODE_EMIT):
+            _STEP.observe(dt)
+            self._verify_emit(plan, packed, gamma, dt)
+            self._note_round_phases("verify", t0 - t_plan, t1 - t0, t2 - t1,
+                                    t3 - t2, self._clock.now() - t3)
+        return True
 
+    def _verify_emit(self, plan: dict, packed, gamma: int,
+                     dt: float) -> None:
+        """The verify round after its fence: unpack, advance, emit."""
         # unpack per-row emit lists from the packed matrix (host-side
         # indexing only — no further device traffic)
         emit: dict = {}
@@ -1253,18 +1342,17 @@ class InferenceEngine:
         _SPEC_STEPS.inc()
         self._note_decode_round(emitted, rows, dt)
         _BUSY.set(float(sum(r is not None for r in self._active)))
-        self._note_round_phases("verify", t0 - t_plan, t2 - t1, t3 - t2,
-                                self._clock.now() - t3)
-        return True
 
     def _note_round_phases(self, kind: str, plan_dt: float,
-                           overlap_dt: float, fence_dt: float,
-                           emit_dt: float) -> None:
+                           dispatch_dt: float, overlap_dt: float,
+                           fence_dt: float, emit_dt: float) -> None:
         """Round anatomy telemetry, observed AFTER the fence (the device
         is already idle — these lock-taking observes never sit between
         dispatch and transfer)."""
         _ROUNDS.inc(kind=kind)
+        self._round_kind = kind
         _ROUND_PHASE.observe(plan_dt, phase="plan")
+        _ROUND_PHASE.observe(dispatch_dt, phase="dispatch")
         _ROUND_PHASE.observe(overlap_dt, phase="overlap")
         _ROUND_PHASE.observe(fence_dt, phase="fence")
         _ROUND_PHASE.observe(emit_dt, phase="emit")
@@ -1278,6 +1366,7 @@ class InferenceEngine:
         self.decode_steps += 1
         self.decode_rows += rows
         self.decode_tokens += emitted
+        self._round_rows, self._round_emitted = rows, emitted
         _TPS.set(emitted / dt if dt > 0 else 0.0)
         if self.spec_tokens:
             if self.spec_proposed:
@@ -1405,15 +1494,22 @@ class InferenceEngine:
         self._stop.clear()
 
         def loop():
+            # so that a profile's host plane shows the loop on a line of
+            # its own, where its ``engine.*`` annotations name idle gaps
+            trace.name_thread(f"lzy-engine-{next(_loop_ids)}")
             try:
                 while not self._stop.is_set():
                     if not self.step():
                         # all slots drained and the queue is empty: park
                         # until the next submit instead of spinning the
                         # device
-                        self._clock.wait(self.queue.work_available,
-                                         timeout=0.5)
-                        self.queue.work_available.clear()
+                        t0 = self._clock.now()
+                        with trace.span(trace.ENGINE_PARK):
+                            self._clock.wait(self.queue.work_available,
+                                             timeout=0.5)
+                            self.queue.work_available.clear()
+                        _ROUND_PHASE.observe(self._clock.now() - t0,
+                                             phase="park")
             except BaseException:  # noqa: BLE001 — engine-fatal
                 # a step()-level failure (device OOM, a poisoned compile) is
                 # engine-fatal, not request-scoped: without this the daemon
@@ -1924,12 +2020,15 @@ class PagedInferenceEngine(InferenceEngine):
         # prefix, map to the scratch block, and are masked garbage by
         # construction — allocating coverage for them would waste up to
         # bucket_width/page blocks per short request
+        evicted = self.kv.evictions
         try:
             owned = self.kv.allocate(blocks_for(t0, self._page)
                                      - len(blocks))
         except Exception:
             self.kv.release(blocks)   # roll back the match refs
             raise
+        if trace.ON:
+            trace.note(evicted=self.kv.evictions - evicted)
         # NOTE: the slot's row of self._tables stays scratch until the
         # job completes — decode rounds interleaved with this prefill
         # must see the reserved slot as idle (its garbage writes land on
@@ -1998,7 +2097,7 @@ class PagedInferenceEngine(InferenceEngine):
         self._slot_blocks[slot] = list(table)
         self._admissions += 1
         self._admit_seq[slot] = self._admissions
-        self._finish_prefill(slot, req, int(first[0]))
+        self._finish_prefill(slot, req, self._prefill_fence(first))
         return True
 
     def _abort_prefill_job(self, job: _PrefillJob) -> None:
@@ -2012,14 +2111,14 @@ class PagedInferenceEngine(InferenceEngine):
 
     # -- tiered KV cache (serving/kv_tier.py) --------------------------------
 
-    def step(self) -> bool:
+    def _service_io(self) -> bool:
         """Paged scheduling round: service cross-replica KV I/O (queued
         imports + export requests) strictly before the base round's
-        admissions, then run it — an import queued before a submit is
-        always resident by the time that request prefills."""
+        admissions — an import queued before a submit is always resident
+        by the time that request prefills."""
         serviced = self._service_kv_io()
         self._sweep_parked()
-        return super().step() or serviced
+        return serviced
 
     def _demote_block(self, chain, block: int, origin) -> None:
         """``RadixCache.on_evict`` hook (single-victim form): one block
@@ -2557,6 +2656,9 @@ class PagedInferenceEngine(InferenceEngine):
             key=lambda s: self._admit_seq[s])
         req = self._active[victim]
         _LOG.warning("kv block pool exhausted: preempting %s", req.id)
+        if trace.ON:
+            trace.event(trace.ENGINE_PREEMPT, request=req.id,
+                        blocks=len(self._slot_blocks[victim]))
         _REQUESTS.inc(status="preempted")
         TENANT_REQUESTS.inc(tenant=req.tenant, status="preempted")
         self._tenant_count(req.tenant, "requests_preempted")

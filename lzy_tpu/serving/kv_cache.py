@@ -35,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 _BLOCKS = REGISTRY.gauge(
@@ -338,6 +339,8 @@ class RadixCache:
                 "available() promised an evictable block"
             victims.append(victim)
         if victims:
+            if trace.ON:
+                trace.event(trace.KV_EVICT, blocks=len(victims))
             self._offer_demotions(victims)
             for victim in victims:
                 self.pool.release_to_free(victim.block)

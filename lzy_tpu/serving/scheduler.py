@@ -32,6 +32,7 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
 from lzy_tpu.chaos.faults import CHAOS
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.clock import SYSTEM_CLOCK
 from lzy_tpu.utils.metrics import REGISTRY
 
@@ -211,8 +212,14 @@ class Request:
         self.deadline: Optional[float] = (
             self.submitted_at + float(deadline_s)
             if deadline_s is not None else None)
+        #: when the engine popped it from the queue into a slot: what
+        #: splits queue wait from prefill inside the time to first token
+        self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
+        #: the submitting thread's open span (utils/trace.py), carried to
+        #: the engine loop: ``engine.request`` is written under it
+        self._trace_parent = trace.context() if trace.ON else None
         #: optional per-token hook (``channels.token_stream.attach_request``
         #: wires a stream here): called by the engine loop after every
         #: emission with this request; the engine guards it — a consumer
@@ -285,7 +292,28 @@ class Request:
         self.error = error
         self.status = status or ("ok" if error is None else "error")
         self.finished_at = self._clock.now()
+        if trace.ON and not self._done.is_set():
+            self._trace_finish()
         self._done.set()
+
+    def _trace_finish(self) -> None:
+        """``engine.request`` and its three children, once, from the
+        stamps: queued until admitted, prefill until the first token,
+        decode until finished. A request that ended early has only the
+        children it reached, the last of them running to the end."""
+        end = self.finished_at
+        admitted = end if self.admitted_at is None else self.admitted_at
+        first = end if self.first_token_at is None else self.first_token_at
+        ctx = trace.emit(trace.ENGINE_REQUEST, self.submitted_at, end,
+                         parent=self._trace_parent, request=self.id,
+                         status=self.status, tokens=len(self.tokens),
+                         prompt_tokens=len(self.prompt))
+        for name, a, b in (
+                (trace.ENGINE_REQUEST_QUEUED, self.submitted_at, admitted),
+                (trace.ENGINE_REQUEST_PREFILL, admitted, first),
+                (trace.ENGINE_REQUEST_DECODE, first, end)):
+            if b > a:
+                trace.emit(name, a, b, parent=ctx)
 
     def wait(self, timeout: Optional[float] = None) -> bool:
         """Block until finished (any terminal status); True if it did."""

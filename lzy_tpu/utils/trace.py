@@ -1,9 +1,43 @@
-"""JAX profiler integration (SURVEY §5.1 tracing).
+"""Tracing: one span recorder for the serving path, and the JAX profiler.
 
-The reference relies on its Java services' logging/tracing; the TPU build's
-equivalent observability question is "where did the step time go on the
-chip" — answered by the XLA profiler. This module makes profiling a
-platform feature rather than a notebook trick:
+Two questions, one module. "Where did the step time go on the chip" is
+answered by the XLA profiler; "what was the host doing meanwhile, and where
+did a request wait" by spans the program records itself. Both are switched
+on the same way, so a profile carries the spans with no new switch.
+
+**The recorder.** :func:`span` (a context manager), :func:`event` (an
+instant), :func:`emit` (a span written afterwards from stamps the program
+already holds) and :func:`note` (attributes for the thread's open span).
+Off, which is the default, each is one test of the module's ``ON`` and a
+return: ``span()`` hands back one shared no-op object (falsy, so
+``if sp:`` guards work done only for the record). On, a span records ``(name, start, end,
+thread, id, parent, request, attrs)`` on ``time.monotonic()`` (the clock of
+``SYSTEM_CLOCK.now()`` and of ``Request``'s stamps) into a bounded buffer
+in memory; what the bound dropped is counted, and nothing is written until
+the holder drains it. The parent is the thread's open span; across a
+thread hop it is carried: :func:`context` on one side, ``parent=`` on the
+other. Spans of one request share ``request``, the id of their root.
+
+- :func:`recording` turns the recorder on for a block and yields it;
+- :func:`profiled` captures a profiler trace around a block, turns the
+  recorder on too (so does ``LZY_PROFILE=1`` on an op, below), and on the
+  way out drains it into ``<logdir>/spans.jsonl``: a header line (the
+  clock, the count, what the bound dropped), then one record a line. The
+  file is uploaded with the trace's other artifacts.
+
+**One clock with the device trace.** The engine loop's spans
+(``LOOP_SPANS``) also open a ``jax.profiler.TraceAnnotation`` of the same
+name, so they land in the trace's ``/host:CPU`` plane on the profiler's
+clock, where a reader names the device's idle gaps by them. Names are
+constants of ``[a-z0-9_.]``, at most 40 characters: ids and sizes are
+attributes. Request-scoped spans are never annotations (they last seconds
+and would cover every gap). When the recorder starts, and at the first
+``engine.round`` of every second, an instant annotation
+``lzy.clock.<time.monotonic_ns()>`` is emitted: its start on the
+profiler's clock less the number in its name is what to add to a record's
+stamp (``spans.jsonl``) to place it in the trace.
+
+**The profiler.**
 
 - :func:`profiled` — capture a trace around any code region, optionally
   uploading the TensorBoard-ready artifacts to workflow storage, so traces
@@ -19,16 +53,295 @@ platform feature rather than a notebook trick:
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
+import json
 import os
 import tempfile
-from typing import Iterator, Optional
+import threading
+import time
+from typing import Any, Iterator, List, NamedTuple, Optional, Tuple
 
 from lzy_tpu.utils.log import get_logger
 
 _LOG = get_logger(__name__)
 
 PROFILE_ENV = "LZY_PROFILE"
+
+
+# -- span names: constants, never built from ids or sizes ---------------------
+
+ENGINE_ROUND = "engine.round"
+ENGINE_KV_IO = "engine.kv_io"
+ENGINE_REAP = "engine.reap"
+ENGINE_ADMIT = "engine.admit"
+ENGINE_PREFILL = "engine.prefill"
+ENGINE_PREFILL_FENCE = "engine.prefill.fence"
+ENGINE_DECODE_PLAN = "engine.decode.plan"
+ENGINE_DECODE_DISPATCH = "engine.decode.dispatch"
+ENGINE_DECODE_OVERLAP = "engine.decode.overlap"
+ENGINE_DECODE_FENCE = "engine.decode.fence"
+ENGINE_DECODE_EMIT = "engine.decode.emit"
+ENGINE_PARK = "engine.park"
+#: the engine loop's spans: the only ones that are profiler annotations too
+LOOP_SPANS = frozenset({
+    ENGINE_ROUND, ENGINE_KV_IO, ENGINE_REAP, ENGINE_ADMIT, ENGINE_PREFILL,
+    ENGINE_PREFILL_FENCE, ENGINE_DECODE_PLAN, ENGINE_DECODE_DISPATCH, ENGINE_DECODE_OVERLAP,
+    ENGINE_DECODE_FENCE, ENGINE_DECODE_EMIT, ENGINE_PARK})
+ENGINE_PREEMPT = "engine.preempt"           # event
+KV_EVICT = "kv.evict"                       # event
+ENGINE_REQUEST = "engine.request"
+ENGINE_REQUEST_QUEUED = "engine.request.queued"
+ENGINE_REQUEST_PREFILL = "engine.request.prefill"
+ENGINE_REQUEST_DECODE = "engine.request.decode"
+GATEWAY_GENERATE = "gateway.generate"
+GATEWAY_ADMIT = "gateway.admit"
+GATEWAY_ROUTE = "gateway.route"
+GATEWAY_ATTEMPT = "gateway.attempt"
+LLM_BATCH = "llm.batch"
+LLM_ROW = "llm.row"
+LLM_ROW_POOL_WAIT = "llm.row.pool_wait"
+LLM_DISPATCH = "llm.dispatch"
+CLOCK_ANCHOR = "lzy.clock."
+#: what ``profiled()`` leaves beside the trace: the recorder's records
+SPANS_FILE = "spans.jsonl"
+
+#: the recorder's one switch: read it as ``trace.ON``, never import it by
+#: name (``recording()`` rebinds it)
+ON = False
+
+DEFAULT_MAXLEN = 1 << 17
+
+
+class Record(NamedTuple):
+    """One closed span (``end > start``) or one event (``end == start``).
+    ``parent`` is a span id or None; ``request`` the id of the root of the
+    tree it belongs to (None on the engine loop's spans)."""
+    name: str
+    start: float
+    end: float
+    thread: str
+    id: int
+    parent: Optional[int]
+    request: Optional[int]
+    attrs: dict
+
+
+class Recorder:
+    """The bounded buffer. ``drain()`` hands out what is held and empties
+    it; ``dropped`` counts what the bound has pushed out."""
+
+    def __init__(self, maxlen: int = DEFAULT_MAXLEN):
+        self._buf: collections.deque = collections.deque(maxlen=maxlen)
+        self._lock = threading.Lock()
+        self.dropped = 0
+
+    def _add(self, record: Record) -> None:
+        with self._lock:
+            if len(self._buf) == self._buf.maxlen:
+                self.dropped += 1
+            self._buf.append(record)
+
+    def drain(self) -> List[Record]:
+        with self._lock:
+            out = list(self._buf)
+            self._buf.clear()
+        return out
+
+
+class _Noop:
+    """What ``span()`` returns while the recorder is off: one object for
+    every call, falsy, inert."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def __bool__(self):
+        return False
+
+
+NOOP = _Noop()
+#: the recorder's clock, for stamps handed to ``span(start=)`` and ``emit``
+now = time.monotonic
+_ids = itertools.count(1)
+_tls = threading.local()
+_recorder: Optional[Recorder] = None
+_holders = 0
+_switch = threading.Lock()
+_next_anchor = 0.0
+
+
+class _Span:
+    __slots__ = ("name", "attrs", "start", "id", "parent", "request",
+                 "_outer", "_annotation", "_recorder")
+
+    def __init__(self, name: str, parent, start, attrs: dict):
+        self.name, self.attrs, self.start = name, attrs, start
+        self.parent, self.request = parent if parent else (None, None)
+        self._annotation = None
+
+    def __enter__(self):
+        self._recorder = _recorder
+        self.id = next(_ids)
+        self._outer = getattr(_tls, "open", None)
+        if self.parent is None and self._outer is not None:
+            self.parent, self.request = self._outer.id, self._outer.request
+        loop = self.name in LOOP_SPANS
+        if self.request is None and not loop:
+            self.request = self.id            # the root of a request's tree
+        _tls.open = self
+        if self.start is None:
+            self.start = time.monotonic()
+        if loop:
+            if self.name is ENGINE_ROUND and self.start >= _next_anchor:
+                anchor()
+            self._annotation = _annotation(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        end = time.monotonic()
+        _tls.open = self._outer
+        rec = self._recorder
+        if rec is not None:
+            rec._add(Record(self.name, self.start, end,
+                            threading.current_thread().name, self.id,
+                            self.parent, self.request, self.attrs))
+        return False
+
+    def __bool__(self):
+        return True
+
+
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation`` or None: tracing never fails the
+    traced computation."""
+    global _annotation_cls
+    try:
+        if _annotation_cls is None:
+            import jax
+
+            _annotation_cls = jax.profiler.TraceAnnotation
+        a = _annotation_cls(name)
+        a.__enter__()
+        return a
+    except Exception:  # noqa: BLE001 — observability is best-effort
+        return None
+
+
+def span(name: str, parent: Optional[Tuple[int, Any]] = None,
+         start: Optional[float] = None, **attrs):
+    """``with span(NAME) as sp:``: a span around the block, a child of the
+    thread's open span (or of ``parent``, a :func:`context` carried from
+    another thread). ``start`` backdates it to a stamp already taken."""
+    if not ON:
+        return NOOP
+    return _Span(name, parent, start, attrs)
+
+
+def _here() -> Tuple[Optional[int], Any]:
+    outer = getattr(_tls, "open", None)
+    return (outer.id, outer.request) if outer is not None else (None, None)
+
+
+def emit(name: str, start: float, end: float,
+         parent: Optional[Tuple[int, Any]] = None, **attrs):
+    """A span written afterwards from two stamps of ``time.monotonic()``,
+    under ``parent`` or the thread's open span. Returns its
+    :func:`context` (for children written the same way)."""
+    if not ON:
+        return None
+    rec = _recorder
+    up, request = parent if parent is not None else _here()
+    sid = next(_ids)
+    if request is None:
+        request = sid
+    if rec is not None:
+        rec._add(Record(name, start, end, threading.current_thread().name,
+                        sid, up, request, attrs))
+    return sid, request
+
+
+def event(name: str, **attrs) -> None:
+    """An instant, under the thread's open span."""
+    if ON:
+        now = time.monotonic()
+        emit(name, now, now, **attrs)
+
+
+def note(**attrs) -> None:
+    """Attributes for the thread's open span (call under ``if trace.ON``
+    on a hot path: the keyword dict is built before the test here)."""
+    outer = getattr(_tls, "open", None) if ON else None
+    if outer is not None:
+        outer.attrs.update(attrs)
+
+
+def context() -> Optional[Tuple[int, Any]]:
+    """``(span id, request)`` of the thread's open span, to hand to
+    another thread's ``span(..., parent=)``; None when off or outside."""
+    here = _here() if ON else (None, None)
+    return here if here[0] is not None else None
+
+
+def anchor() -> None:
+    """Ties the two clocks: an instant annotation whose name holds
+    ``time.monotonic_ns()`` as read where the profiler stamps it."""
+    global _next_anchor
+    ns = time.monotonic_ns()
+    _next_anchor = ns / 1e9 + 1.0
+    a = _annotation(CLOCK_ANCHOR + str(ns))
+    if a is not None:
+        a.__exit__(None, None, None)
+
+
+def name_thread(name: str) -> None:
+    """Give the calling thread an OS-level name (Linux; 15 bytes are
+    kept). A profile's host plane has one line a thread, named by this:
+    Python's threads are all ``python3`` otherwise, and whoever opens the
+    profile has to guess which of them is the engine's loop.
+    Best-effort."""
+    try:
+        import ctypes
+
+        prctl = ctypes.CDLL(None).prctl
+        prctl.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                          ctypes.c_ulong, ctypes.c_ulong]
+        prctl.restype = ctypes.c_int
+        prctl(15, name.encode()[:15], 0, 0, 0)       # PR_SET_NAME
+    except (OSError, AttributeError):   # not Linux: a name is not worth
+        pass                            # a failure
+
+
+@contextlib.contextmanager
+def recording(maxlen: int = DEFAULT_MAXLEN) -> Iterator[Recorder]:
+    """Turn the recorder on for the block; yields it. Nested holders
+    share one recorder, and the last one out turns it off."""
+    global ON, _recorder, _holders
+    with _switch:
+        if _holders == 0:
+            _recorder = Recorder(maxlen)
+        _holders += 1
+        rec = _recorder
+        ON = True
+    anchor()
+    try:
+        yield rec
+    finally:
+        with _switch:
+            _holders -= 1
+            if _holders == 0:
+                ON = False
+                _recorder = None
 
 
 def profile_enabled(env_vars) -> bool:
@@ -44,10 +357,12 @@ def profiled(logdir: Optional[str] = None, *,
              storage=None) -> Iterator[str]:
     """Capture a JAX/XLA profiler trace around the block.
 
-    Yields the local trace directory. With ``upload_prefix`` + ``storage``
-    (a StorageClient), every produced artifact is uploaded under that prefix
-    after capture — profiling must never fail the traced computation, so
-    capture/upload errors are logged and swallowed.
+    Yields the local trace directory; the span recorder is on inside the
+    block (:func:`recording`) and drained into ``spans.jsonl`` there when
+    the block ends. With ``upload_prefix`` + ``storage`` (a StorageClient),
+    every produced artifact is uploaded under that prefix after capture —
+    profiling must never fail the traced computation, so capture/upload
+    errors are logged and swallowed.
     """
     import jax
 
@@ -59,15 +374,19 @@ def profiled(logdir: Optional[str] = None, *,
     except Exception as e:  # noqa: BLE001 — observability is best-effort
         _LOG.warning("profiler start failed: %r", e)
     try:
-        yield logdir
+        # the program's spans ride along: the engine loop's as annotations
+        # in this trace's host plane, all of them in spans.jsonl beside it
+        with recording() as rec:
+            yield logdir
     finally:
         if started:
             try:
                 jax.profiler.stop_trace()
             except Exception as e:  # noqa: BLE001
                 _LOG.warning("profiler stop failed: %r", e)
-            if upload_prefix and storage is not None:
-                _upload_dir(storage, logdir, upload_prefix)
+        _write_spans(rec, logdir)
+        if upload_prefix and storage is not None:
+            _upload_dir(storage, logdir, upload_prefix)
 
 
 def annotate_step(step: int, name: str = "train"):
@@ -76,6 +395,25 @@ def annotate_step(step: int, name: str = "train"):
     import jax
 
     return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+def _write_spans(rec: Recorder, logdir: str) -> None:
+    """Drain ``rec`` into ``<logdir>/spans.jsonl``: one header object
+    (``clock``, ``anchor``, ``records``, ``dropped``), then one object a
+    record with :class:`Record`'s fields. Stamps are seconds of
+    ``time.monotonic()``; an anchor annotation in the trace's host plane
+    places them on the profiler's clock."""
+    records = rec.drain()
+    try:
+        os.makedirs(logdir, exist_ok=True)
+        with open(os.path.join(logdir, SPANS_FILE), "w") as f:
+            f.write(json.dumps({
+                "clock": "time.monotonic", "anchor": CLOCK_ANCHOR,
+                "records": len(records), "dropped": rec.dropped}) + "\n")
+            for r in records:
+                f.write(json.dumps(r._asdict(), default=str) + "\n")
+    except Exception as e:  # noqa: BLE001 — observability is best-effort
+        _LOG.warning("writing %s failed: %r", SPANS_FILE, e)
 
 
 def _upload_dir(storage, local_dir: str, prefix: str) -> int:

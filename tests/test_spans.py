@@ -1,0 +1,438 @@
+"""The span recorder (``lzy_tpu/utils/trace.py``) and the spans the serving
+path records with it: the engine loop's round and its phases, a request from
+the gateway through the engine, the ``llm`` entry pool's rows."""
+
+import json
+import re
+import threading
+import time
+
+import jax
+import pytest
+
+from lzy_tpu import llm
+from lzy_tpu.gateway import GatewayService, PrefixAffinityRouter, ReplicaFleet
+from lzy_tpu.llm.sched import WorkflowScheduler
+from lzy_tpu.models import llama, unbox
+from lzy_tpu.serving import PagedInferenceEngine
+from lzy_tpu.utils import trace
+from lzy_tpu.utils.metrics import REGISTRY
+
+PAGE = 8
+OLD_PHASES = ("plan", "overlap", "fence", "emit")
+NEW_PHASES = ("kv_io", "reap", "admit", "prefill", "prefill_fence",
+              "dispatch", "park")
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = llama.LlamaConfig.tiny(vocab_size=64)
+    boxed, _ = llama.init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, unbox(boxed)
+
+
+@pytest.fixture
+def gateway(tiny_model):
+    cfg, params = tiny_model
+    fleet = ReplicaFleet(lambda: PagedInferenceEngine(
+        cfg, params, slots=2, page_size=PAGE))
+    gw = GatewayService(fleet, router=PrefixAffinityRouter(PAGE),
+                        model_name="tiny")
+    fleet.add_replica()
+    yield gw
+    llm.configure(None)
+    gw.close()
+
+
+def _by_name(records):
+    out = {}
+    for r in records:
+        out.setdefault(r.name, []).append(r)
+    return out
+
+
+def _phase_sums():
+    return {phase: float(v) for phase, v in re.findall(
+        r'lzy_engine_round_phase_seconds_sum\{phase="(\w+)"\} (\S+)',
+        REGISTRY.exposition())}
+
+
+class TestRecorder:
+    def test_off_records_nothing_and_span_is_the_shared_noop(self):
+        assert trace.ON is False
+        a, b = trace.span(trace.ENGINE_ADMIT), trace.span("anything", k=1)
+        assert a is b is trace.NOOP and not a
+        with a as sp:
+            assert sp is a
+        trace.event(trace.KV_EVICT, blocks=1)
+        trace.note(ignored=1)
+        assert trace.emit("x", 0.0, 1.0) is None
+        assert trace.context() is None
+        with trace.recording() as rec:
+            assert rec.drain() == []      # nothing leaked in from before
+
+    def test_nesting_sets_parent_request_and_attrs(self):
+        with trace.recording() as rec:
+            with trace.span(trace.GATEWAY_GENERATE, tenant="t") as root:
+                with trace.span(trace.GATEWAY_ATTEMPT) as child:
+                    trace.note(replica="r1")
+                    trace.event(trace.KV_EVICT, blocks=2)
+                    leaf = trace.emit(trace.GATEWAY_ROUTE, 1.0, 2.0)
+            with trace.span(trace.GATEWAY_GENERATE):
+                pass
+            recs = _by_name(rec.drain())
+        first, second = recs[trace.GATEWAY_GENERATE]
+        attempt, = recs[trace.GATEWAY_ATTEMPT]
+        evict, = recs[trace.KV_EVICT]
+        route, = recs[trace.GATEWAY_ROUTE]
+        assert first.parent is None and first.request == first.id == root.id
+        assert first.attrs == {"tenant": "t"}
+        assert attempt.parent == first.id and attempt.request == first.id
+        assert attempt.attrs == {"replica": "r1"} and attempt.id == child.id
+        assert evict.parent == attempt.id and evict.start == evict.end
+        assert (route.id, route.request) == leaf
+        assert route.parent == attempt.id and (route.start, route.end) == (1, 2)
+        assert second.request == second.id != first.id
+        assert first.start <= attempt.start <= attempt.end <= first.end
+        assert first.thread == threading.current_thread().name
+
+    def test_buffer_is_bounded_and_counts_what_it_dropped(self):
+        with trace.recording(maxlen=4) as rec:
+            for i in range(10):
+                trace.event(trace.KV_EVICT, i=i)
+            assert rec.dropped == 6
+            kept = rec.drain()
+            assert [r.attrs["i"] for r in kept] == [6, 7, 8, 9]
+            assert rec.drain() == [] and rec.dropped == 6
+
+    def test_nested_holders_share_one_recorder(self):
+        with trace.recording() as outer:
+            with trace.recording() as inner:
+                assert inner is outer
+            assert trace.ON is True
+            trace.event(trace.KV_EVICT)
+            assert len(outer.drain()) == 1
+        assert trace.ON is False
+
+    def test_threads_share_the_recorder_without_losing_a_record(self):
+        """More threads than cores on a shortened switch interval: every
+        span is kept, ids are unique, and a thread's nesting is its own."""
+        import os
+        import sys
+
+        workers, each = 4 * (os.cpu_count() or 2), 200
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            with trace.recording(maxlen=4 * workers * each) as rec:
+                def work():
+                    for _ in range(each):
+                        with trace.span(trace.GATEWAY_GENERATE) as outer:
+                            with trace.span(trace.GATEWAY_ATTEMPT) as inner:
+                                assert trace.context() == (inner.id,
+                                                           outer.id)
+
+                threads = [threading.Thread(target=work)
+                           for _ in range(workers)]
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    t.join(timeout=60)
+                assert not any(t.is_alive() for t in threads)
+                recs = rec.drain()
+                assert rec.dropped == 0
+        finally:
+            sys.setswitchinterval(interval)
+        assert len(recs) == 2 * workers * each
+        assert len({r.id for r in recs}) == len(recs)
+        by_id = {r.id: r for r in recs}
+        for r in recs:
+            if r.name == trace.GATEWAY_ATTEMPT:
+                assert by_id[r.parent].thread == r.thread
+                assert r.request == r.parent
+            else:
+                assert r.parent is None and r.request == r.id
+
+    def test_profiled_leaves_the_records_beside_the_trace(self, tmp_path):
+        """What ``profiled()`` does with the recorder on its way out: a
+        header with the count and what the bound dropped, then a record a
+        line, request-scoped spans included."""
+        with trace.recording(maxlen=3) as rec:
+            with trace.span(trace.GATEWAY_GENERATE, tenant="a") as root:
+                trace.event(trace.ENGINE_PREEMPT, request="r1", blocks=2)
+                for _ in range(2):
+                    with trace.span(trace.GATEWAY_ATTEMPT, replica=object()):
+                        pass
+            trace._write_spans(rec, str(tmp_path / "t"))
+            assert rec.drain() == []
+        head, *lines = [json.loads(line) for line in
+                        open(tmp_path / "t" / trace.SPANS_FILE)]
+        assert head == {"clock": "time.monotonic", "records": 3,
+                        "anchor": "lzy.clock.", "dropped": 1}
+        assert [r["name"] for r in lines] == [
+            trace.GATEWAY_ATTEMPT, trace.GATEWAY_ATTEMPT,
+            trace.GATEWAY_GENERATE]
+        assert set(lines[0]) == set(trace.Record._fields)
+        assert lines[0]["parent"] == lines[2]["id"] == root.id
+        assert lines[2]["attrs"] == {"tenant": "a"}
+        assert isinstance(lines[0]["attrs"]["replica"], str)
+
+    def test_loop_span_names_fit_a_gap_label(self):
+        names = {v for k, v in vars(trace).items()
+                 if k.isupper() and isinstance(v, str) and k != "PROFILE_ENV"}
+        assert trace.LOOP_SPANS < names
+        for name in names - {trace.CLOCK_ANCHOR}:
+            assert re.fullmatch(r"[a-z0-9_.]{1,40}", name), name
+
+
+class TestThreadHops:
+    def test_parent_is_carried_across_the_workflow_pool(self):
+        sched = WorkflowScheduler(backend=None, max_workers=2)
+        seen = []
+
+        def row(x):
+            seen.append((threading.current_thread().name, trace.context()))
+            time.sleep(0.01)
+            return x * 2
+
+        try:
+            with trace.recording() as rec:
+                with trace.span(trace.LLM_BATCH, rows=4) as batch:
+                    assert sched.map(row, [1, 2, 3, 4]) == [2, 4, 6, 8]
+                recs = _by_name(rec.drain())
+        finally:
+            sched.close()
+        rows, waits = recs[trace.LLM_ROW], recs[trace.LLM_ROW_POOL_WAIT]
+        assert len(rows) == len(waits) == 4
+        by_id = {r.id: r for r in rows}
+        for r in rows:
+            assert r.parent == batch.id and r.request == batch.id
+            assert r.thread.startswith("lzy-wfsched")
+        for w in waits:
+            owner = by_id[w.parent]
+            assert w.start == owner.start and w.end <= owner.end
+        # four rows on two threads: two of them waited for a thread
+        assert sum(w.end - w.start > 0.005 for w in waits) >= 2
+        assert all(ctx[0] in by_id for _, ctx in seen)
+        # off: the function is handed to the pool as it is
+        sched2 = WorkflowScheduler(backend=None, max_workers=1)
+        try:
+            assert sched2.map(lambda x: trace.context(), [1]) == [None]
+        finally:
+            sched2.close()
+
+    def test_parent_is_carried_from_the_client_thread_to_the_loop(
+            self, tiny_model):
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
+        eng.start()
+        try:
+            with trace.recording() as rec:
+                with trace.span(trace.GATEWAY_ATTEMPT) as attempt:
+                    req = eng.submit([5, 9, 3, 7], max_new_tokens=3)
+                    assert req.wait(60)
+                recs = _by_name(rec.drain())
+        finally:
+            eng.close()
+        request, = recs[trace.ENGINE_REQUEST]
+        assert request.parent == attempt.id
+        assert request.request == attempt.request
+        assert request.thread == "inference-engine"
+        assert request.attrs["request"] == req.id
+        assert req.submitted_at <= req.admitted_at <= req.first_token_at
+
+
+class TestRequestPath:
+    def test_gateway_attempt_and_engine_request_nest_and_add_up(
+            self, gateway):
+        gateway.generate([1, 2, 3], max_new_tokens=2, greedy=True)  # compile
+        with trace.recording() as rec:
+            reply = gateway.generate([5, 9, 3, 7, 1, 2, 8, 4, 6],
+                                     max_new_tokens=5, greedy=True)
+            recs = _by_name(rec.drain())
+        assert len(reply["tokens"]) == 5
+        root, = recs[trace.GATEWAY_GENERATE]
+        admit, = recs[trace.GATEWAY_ADMIT]
+        route, = recs[trace.GATEWAY_ROUTE]
+        attempt, = recs[trace.GATEWAY_ATTEMPT]
+        request, = recs[trace.ENGINE_REQUEST]
+        assert root.attrs == {"tenant": "default", "prompt_tokens": 9}
+        assert admit.parent == root.id and attempt.parent == root.id
+        assert route.parent == attempt.id and request.parent == attempt.id
+        assert attempt.attrs["request"] == reply["request_id"]
+        for inner, outer in ((admit, root), (route, attempt),
+                             (request, attempt), (attempt, root)):
+            assert outer.start <= inner.start <= inner.end <= outer.end
+            assert inner.request == root.id
+        parts = [recs[n][0] for n in (trace.ENGINE_REQUEST_QUEUED,
+                                      trace.ENGINE_REQUEST_PREFILL,
+                                      trace.ENGINE_REQUEST_DECODE)]
+        assert all(p.parent == request.id for p in parts)
+        assert parts[0].start == request.start
+        assert parts[0].end == parts[1].start
+        assert parts[1].end == parts[2].start and parts[2].end == request.end
+        assert sum(p.end - p.start for p in parts) == pytest.approx(
+            request.end - request.start, abs=1e-9)
+        # the loop's own spans are not request-scoped
+        assert all(r.request is None for r in recs[trace.ENGINE_ROUND])
+        staged = [r for r in recs[trace.ENGINE_ADMIT] if r.attrs]
+        assert [r.attrs["prompt_tokens"] for r in staged] == [9]
+        assert staged[0].attrs["request"] == reply["request_id"]
+
+    def test_batch_rows_run_under_llm_batch(self, gateway):
+        llm.configure(gateway)
+        prompts = [[5, 9, 3], [7, 2, 8, 1], [5, 9, 3]]
+        with trace.recording() as rec:
+            out = llm.generate_batch(prompts, max_new_tokens=3, greedy=True,
+                                     cache=False)
+            recs = _by_name(rec.drain())
+        assert [len(g.tokens) for g in out] == [3, 3, 3]
+        batch, = recs[trace.LLM_BATCH]
+        assert batch.attrs == {"rows": 3, "deduplicated": 1}
+        rows = recs[trace.LLM_ROW]
+        assert len(rows) == 2 and all(r.parent == batch.id for r in rows)
+        assert len(recs[trace.LLM_ROW_POOL_WAIT]) == 2
+        row_ids = {r.id for r in rows}
+        dispatches = recs[trace.LLM_DISPATCH]
+        assert {d.parent for d in dispatches} == row_ids
+        # each row's gateway call hangs under its dispatch, in its tree
+        for g in recs[trace.GATEWAY_GENERATE]:
+            assert g.parent in {d.id for d in dispatches}
+            assert g.request == batch.id
+
+
+class TestEngineLoop:
+    def test_children_tile_the_round(self, tiny_model):
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
+        warm = eng.submit([1, 2, 3], max_new_tokens=2)
+        while not warm.done:
+            eng.step()
+        reqs = [eng.submit([5, 9, 3, 7, 1, 2, 8, 4, 6], max_new_tokens=120),
+                eng.submit([11, 12, 13, 14, 15], max_new_tokens=120)]
+        with trace.recording() as rec:
+            for _ in range(50):
+                eng.step()
+            recs = rec.drain()
+        assert rec.dropped == 0
+        rounds = [r for r in recs if r.name == trace.ENGINE_ROUND]
+        assert len(rounds) == 50 and not any(r.done for r in reqs)
+        assert {r.attrs["kind"] for r in rounds} == {"decode"}
+        assert all(r.attrs["rows"] >= 1 for r in rounds)
+        covered = sum(r.end - r.start for r in recs
+                      if r.parent in {x.id for x in rounds})
+        whole = sum(r.end - r.start for r in rounds)
+        assert covered >= 0.95 * whole
+        names = {r.name for r in recs if r.parent == rounds[-1].id}
+        assert names == trace.LOOP_SPANS - {
+            trace.ENGINE_ROUND, trace.ENGINE_PARK,
+            trace.ENGINE_PREFILL_FENCE}
+        eng.close()
+
+    def test_idle_round_and_park_are_named(self, tiny_model):
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=1, page_size=PAGE)
+        before = _phase_sums().get("park", 0.0)
+        with trace.recording() as rec:
+            assert eng.step() is False
+            eng.start()
+            deadline = time.monotonic() + 10
+            while _phase_sums().get("park", 0.0) == before:
+                assert time.monotonic() < deadline
+                eng.queue.work_available.set()
+                time.sleep(0.01)
+            eng.close()
+            recs = _by_name(rec.drain())
+        assert recs[trace.ENGINE_ROUND][0].attrs == {"kind": "idle"}
+        assert recs[trace.ENGINE_PARK]
+        assert all(r.parent is None for r in recs[trace.ENGINE_PARK])
+
+    def test_new_phase_labels_and_the_old_four_unchanged(self, tiny_model):
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
+        req = eng.submit([5, 9, 3, 7], max_new_tokens=6)
+        before = _phase_sums()
+        t0 = time.monotonic()
+        with trace.recording() as rec:
+            while not req.done:
+                eng.step()
+            recs = _by_name(rec.drain())
+        elapsed = time.monotonic() - t0
+        after = _phase_sums()
+        text = REGISTRY.exposition()
+        for phase in NEW_PHASES[:-1] + OLD_PHASES:
+            assert f'lzy_engine_round_phase_seconds_count{{phase="{phase}"}}' \
+                in text
+            assert after[phase] > before.get(phase, 0.0)
+        # each phase's sum is its spans' time, give or take the two clock
+        # reads that bracket a span: the four old labels as before
+        span_of = {"plan": trace.ENGINE_DECODE_PLAN,
+                   "dispatch": trace.ENGINE_DECODE_DISPATCH,
+                   "overlap": trace.ENGINE_DECODE_OVERLAP,
+                   "fence": trace.ENGINE_DECODE_FENCE,
+                   "prefill_fence": trace.ENGINE_PREFILL_FENCE,
+                   "admit": trace.ENGINE_ADMIT}
+        grown = {p: after[p] - before.get(p, 0.0) for p in after}
+        for phase, name in span_of.items():
+            spans = sum(r.end - r.start for r in recs[name])
+            assert grown[phase] == pytest.approx(spans, abs=2e-3), phase
+        # the wait for the first token is a child of engine.prefill and a
+        # label of its own: ``prefill`` is the rest of the advance
+        fence, = recs[trace.ENGINE_PREFILL_FENCE]
+        assert fence.parent in {r.id for r in recs[trace.ENGINE_PREFILL]}
+        assert grown["prefill"] + grown["prefill_fence"] == pytest.approx(
+            sum(r.end - r.start for r in recs[trace.ENGINE_PREFILL]),
+            abs=2e-3)
+        total = sum(after[p] - before.get(p, 0.0)
+                    for p in NEW_PHASES[:-1] + OLD_PHASES)
+        assert 0.5 * elapsed < total <= elapsed
+        eng.close()
+
+    def test_preemption_fires_one_event(self, tiny_model):
+        """The pool-exhaustion scenario of ``test_kv_cache``: 7 usable
+        blocks, two growing requests, the younger is preempted."""
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE,
+                                   kv_blocks=8)
+        a = eng.submit([5, 9, 3, 7, 1, 2, 8, 4, 6], max_new_tokens=30)
+        b = eng.submit([11, 12, 13, 14, 15, 16, 17], max_new_tokens=30)
+        with trace.recording() as rec:
+            for _ in range(120):
+                if a.done and b.done:
+                    break
+                eng.step()
+            recs = _by_name(rec.drain())
+        assert a.error is None and "preempted" in b.error
+        event, = recs[trace.ENGINE_PREEMPT]
+        assert event.attrs["request"] == b.id and event.attrs["blocks"] > 0
+        plan = {r.id for r in recs[trace.ENGINE_DECODE_PLAN]}
+        assert event.parent in plan          # during block growth
+        status = {r.attrs["request"]: r.attrs["status"]
+                  for r in recs[trace.ENGINE_REQUEST]}
+        assert status == {a.id: "ok", b.id: "error"}
+        eng.close()
+
+    def test_eviction_fires_an_event_under_admit(self, tiny_model):
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=1, page_size=PAGE,
+                                   kv_blocks=8)              # 7 usable
+        with trace.recording() as rec:
+            for lo in (0, 16, 32, 48):
+                # 24 tokens: three full blocks stay cached when it ends
+                r = eng.submit(list(range(lo, lo + 24)), max_new_tokens=2)
+                while not r.done:
+                    eng.step()
+            recs = _by_name(rec.drain())
+        evictions = recs[trace.KV_EVICT]
+        assert evictions and eng.kv.evictions == sum(
+            e.attrs["blocks"] for e in evictions)
+        # a prompt's blocks are evicted for at admission, a row's next
+        # block during the decode plan's growth
+        admits = {r.id: r for r in recs[trace.ENGINE_ADMIT]}
+        plans = {r.id for r in recs[trace.ENGINE_DECODE_PLAN]}
+        at_admit = [e for e in evictions if e.parent in admits]
+        assert at_admit and all(e.parent in plans for e in evictions
+                                if e not in at_admit)
+        for e in at_admit:
+            assert admits[e.parent].attrs["evicted"] == e.attrs["blocks"]
+        eng.close()

@@ -1,12 +1,15 @@
 """JAX profiler integration (SURVEY §5.1): trace capture, step annotation,
 and op-level profiling whose artifacts land in workflow storage."""
 
+import json
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from lzy_tpu import op
 from lzy_tpu.service import InProcessCluster
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.trace import annotate_step, profiled
 
 
@@ -21,6 +24,67 @@ class TestProfiled:
                     for r, _, fs in os.walk(logdir) for f in fs]
         assert produced, "no trace artifacts captured"
 
+    def test_capture_carries_the_engine_loop_spans_and_a_clock_anchor(
+            self, tmp_path):
+        """``profiled()`` turns the span recorder on: the engine loop's
+        spans land in the capture's host plane as annotations, with an
+        anchor that ties the profiler's clock to ``time.monotonic()``."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        from lzy_tpu.models import llama, unbox
+        from lzy_tpu.serving import InferenceEngine
+
+        cfg = llama.LlamaConfig.tiny(vocab_size=64)
+        params = unbox(llama.init_params(cfg, jax.random.PRNGKey(0))[0])
+        eng = InferenceEngine(cfg, params, slots=2)
+        warm = eng.submit([1, 2], max_new_tokens=2)
+        while not warm.done:
+            eng.step()
+        assert trace.ON is False
+        with profiled(str(tmp_path / "trace")) as logdir:
+            assert trace.ON is True
+            eng.start()
+            req = eng.submit([5, 9, 3], max_new_tokens=4)
+            assert req.wait(60)
+            eng.close()
+        assert trace.ON is False
+        # the recorder was drained into spans.jsonl beside the trace
+        head, *records = [json.loads(line) for line in
+                          open(f"{logdir}/{trace.SPANS_FILE}")]
+        assert head["records"] == len(records) and head["dropped"] == 0
+        assert {trace.ENGINE_REQUEST_QUEUED, trace.ENGINE_PREFILL_FENCE} \
+            <= {r["name"] for r in records}
+        rounds = [r for r in records if r["name"] == trace.ENGINE_ROUND
+                  and r["thread"] == "inference-engine"]
+        path, = glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb")
+        lines = [(line.name, [(float(e.start_ns), float(e.duration_ns),
+                               e.name) for e in line.events])
+                 for plane in ProfileData.from_file(path).planes
+                 if plane.name == "/host:CPU" for line in plane.lines]
+        # the loop's thread has a name of its own, so its line is not one
+        # of many called ``python3``
+        host, = [events for name, events in lines
+                 if name.startswith("lzy-engine-")]
+        names = {n for _, _, n in host}
+        assert {trace.ENGINE_ROUND, trace.ENGINE_PREFILL,
+                trace.ENGINE_DECODE_FENCE} <= names
+        assert not any(n.startswith(("gateway.", "engine.request"))
+                       for n in names)
+        # the anchors place a record's stamp on the profiler's clock: each
+        # round's annotation starts where its record says
+        offsets = sorted(
+            s - int(n[len(trace.CLOCK_ANCHOR):])
+            for _, events in lines for s, _, n in events
+            if n.startswith(trace.CLOCK_ANCHOR))
+        assert offsets
+        offset = offsets[len(offsets) // 2]
+        starts = sorted(s for s, _, n in host if n == trace.ENGINE_ROUND)
+        assert len(starts) == len(rounds) > 0
+        for annotated, r in zip(starts, rounds):
+            assert abs(annotated - (r["start"] * 1e9 + offset)) < 1e6
+
     def test_upload_to_storage(self, tmp_path):
         from lzy_tpu.storage.mem import MemStorageClient
 
@@ -28,7 +92,9 @@ class TestProfiled:
         with profiled(str(tmp_path / "t"), upload_prefix="mem://traces/x",
                       storage=client):
             float(jax.jit(lambda x: x * 2)(jnp.ones(8)).sum())
-        assert list(client.list("mem://traces/x")), "no artifacts uploaded"
+        uploaded = list(client.list("mem://traces/x"))
+        assert uploaded, "no artifacts uploaded"
+        assert any(u.endswith("/" + trace.SPANS_FILE) for u in uploaded)
 
 
 @op
@@ -52,6 +118,7 @@ class TestOpLevelProfiling:
             traces = [u for u in c.storage_client.list(
                 f"file://{tmp_path}/storage") if "/traces/" in u]
             assert traces, "op-level profiling produced no stored artifacts"
+            assert any(u.endswith(trace.SPANS_FILE) for u in traces)
         finally:
             c.shutdown()
 
