@@ -290,8 +290,8 @@ def phase_kernels(args) -> dict:
     from lzy_tpu.ops.chunked_ce import chunked_cross_entropy
     from lzy_tpu.ops.flash_attention import flash_attention
     from lzy_tpu.ops.paged_attention import (
-        KVQuant, default_kernel, lower_pallas_for_tpu, paged_attention,
-        quantize_kv)
+        KVQuant, default_kernel, kernel_path, lower_pallas_for_tpu,
+        paged_attention, quantize_kv)
 
     base = LlamaConfig.llama3_8b()
     h, kv, d = base.n_heads, base.n_kv_heads, base.head_dim
@@ -391,7 +391,8 @@ def phase_kernels(args) -> dict:
            jax.jit(chunked_cross_entropy)(feats, head, labels), dense_nll)
 
     # the paged read that serves: what "auto" resolves to, decode (T = 1) and
-    # verify (T = 5), pages of 16 and 64, float and int8 pools
+    # verify (T = 5), pages of 16 and 64, float and int8 pools; each check
+    # is named for the path the call takes (int8 pools are read by lax)
     kernel = default_kernel()
     rng = np.random.default_rng(args.seed)
     batch = 8
@@ -419,28 +420,75 @@ def phase_kernels(args) -> dict:
                     side = KVQuant(ksc, kzp, vsc, vzp)
                 read = jax.jit(lambda *a, side=side: paged_attention(
                     *a, kernel=kernel, dtype=jnp.bfloat16, quant=side))
+                path = kernel_path(kernel, t=t, quantized=quantized)
                 _check(errors,
-                       f"paged_{kernel}_page{page}_t{t}"
+                       f"paged_{path}_page{page}_t{t}"
                        f"_{'int8' if quantized else 'bf16'}",
                        read(qp, kp, vp, table, pos),
                        dense_paged(qp, kp, vp, table, pos, side))
 
-    # the Pallas paged kernel: say what the TPU lowering says of it
-    pallas_paged = "lowers"
-    try:
-        lower_pallas_for_tpu(
-            batch=batch, n_heads=h, n_kv_heads=kv, head_dim=d, n_blocks=513,
-            page_size=16, pages_per_seq=512, dtype=jnp.bfloat16)
-    except ValueError as e:
-        pallas_paged = "refused by the lowering: " + str(e).split(".")[0]
-    if (kernel == "pallas") != (pallas_paged == "lowers"):
-        raise AssertionError(
-            f"'auto' resolves to {kernel!r} but the Pallas kernel "
-            f"{pallas_paged}")
+    # the Pallas decode kernel lowers, and "auto" is it
+    lower_pallas_for_tpu(
+        batch=batch, n_heads=h, n_kv_heads=kv, head_dim=d, n_blocks=513,
+        page_size=16, pages_per_seq=512, dtype=jnp.bfloat16)
+    if kernel != "pallas":
+        raise AssertionError(f"'auto' resolves to {kernel!r}, not to the "
+                             f"Pallas decode kernel")
     return {"widths": {"heads": h, "kv_heads": kv, "head_dim": d},
             "tolerance": KERNEL_TOL, "errors": errors,
             "flash_lowered_as": "tpu_custom_call", "paged_auto": kernel,
-            "paged_pallas": pallas_paged}
+            "paged_pallas": "lowers",
+            "paged_decode": _paged_decode_at_serving_shapes(args, h, kv, d)}
+
+
+def _paged_decode_at_serving_shapes(args, h: int, kv: int, d: int) -> dict:
+    """The decode read as a serving replica runs it: 32 slots, tables of 256
+    pages of 16, a 7168-page pool (1.75 GiB each for K and V: one layer's
+    share of a 7 GiB pool does not exist apart, so this is 4 layers' worth),
+    contexts log-normal around 600 tokens with a third of the slots idle.
+    The Pallas kernel against the lax read: largest absolute difference, and
+    the time of each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from lzy_tpu.ops.paged_attention import paged_attention
+
+    slots, pages, page, n_blocks = 32, 256, 16, 7168
+    rng = np.random.default_rng(args.seed + 27)
+    lens = np.clip(rng.lognormal(np.log(600), 0.8, slots), 1,
+                   pages * page).astype(np.int32)
+    lens[rng.permutation(slots)[: slots // 3]] = 1        # idle: position 0
+    lens[0] = pages * page                                # one full table
+    table = np.zeros((slots, pages), np.int32)
+    free = rng.permutation(np.arange(1, n_blocks))
+    for row, n in enumerate(-(-lens // page)):
+        if lens[row] > 1:
+            table[row, :n], free = free[:n], free[n:]
+    keys = jax.random.split(jax.random.PRNGKey(args.seed + 27), 3)
+    k_pool = jax.random.normal(keys[0], (n_blocks, page, kv, d), jnp.bfloat16)
+    v_pool = jax.random.normal(keys[1], (n_blocks, page, kv, d), jnp.bfloat16)
+    q = jax.random.normal(keys[2], (slots, 1, h, d), jnp.bfloat16)
+    operands = (q, k_pool, v_pool, jnp.asarray(table),
+                jnp.asarray(lens[:, None] - 1))
+    out, ms = {}, {}
+    for name in ("pallas", "lax"):
+        read = jax.jit(lambda *a, name=name: paged_attention(
+            *a, kernel=name, dtype=jnp.bfloat16))
+        out[name] = jax.block_until_ready(read(*operands))
+        t0 = time.monotonic()
+        for _ in range(20):
+            got = read(*operands)
+        jax.block_until_ready(got)
+        ms[name] = round((time.monotonic() - t0) / 20 * 1e3, 4)
+    diff = float(np.abs(np.asarray(out["pallas"], np.float32)
+                        - np.asarray(out["lax"], np.float32)).max())
+    if not diff <= KERNEL_TOL:
+        raise AssertionError(
+            f"paged decode kernel differs from lax by {diff} at serving "
+            f"shapes (tolerance {KERNEL_TOL})")
+    return {"live_tokens": int(lens.sum()), "max_abs_diff": diff,
+            "pallas_ms": ms["pallas"], "lax_ms": ms["lax"]}
 
 
 # -- phase: serve -------------------------------------------------------------

@@ -89,8 +89,9 @@ class LlamaConfig:
     paged_attention_native: bool = False
     # which native kernel under paged_attention_native: "lax" (portable
     # gather-attention, bit-identical to the legacy path by construction)
-    # or "pallas" (fused block-walk kernel; Pallas interpreter only — its
-    # layout does not lower for a TPU, see ops/paged_attention.py)
+    # or "pallas" (the decode kernel: live pages by DMA, online softmax;
+    # decode-sized windows over float pools, lax for the rest — see
+    # ops/paged_attention.py)
     paged_kernel: str = "lax"
     # int8 per-block KV quantization (paged cache only): pooled K/V are
     # stored int8 with per-position/per-head scale+zero-point sidecars
@@ -425,13 +426,13 @@ class Attention(nn.Module):
             if cfg.paged_attention_native:
                 # native read path: attention computed THROUGH the page
                 # table (ops/paged_attention) — decode, prefill chunks
-                # and the [B, gamma+1] speculative verify all run this
-                # one fused program; the dense [B, L, ...] copy of the
-                # pool below never exists. "lax" is bit-identical to the
-                # legacy gather by construction; "pallas" is the fused
-                # kernel (tested bit-exact against lax in interpret
-                # mode). int8 pools dequantize inside the kernel's block
-                # loop.
+                # and the [B, gamma+1] speculative verify all make this
+                # one call; the dense [B, L, ...] copy of the pool below
+                # never exists. "lax" is bit-identical to the legacy
+                # gather by construction; "pallas" is the decode kernel
+                # (within a written tolerance of float32 attention) for
+                # decode and verify windows over float pools, and lax
+                # for prefill chunks and int8 pools.
                 out = paged_attention(
                     q, cache_k.value, cache_v.value, page_table, pos,
                     kernel=cfg.paged_kernel, dtype=cfg.dtype, quant=kvq)

@@ -26,3 +26,15 @@ def set_interpret(on: bool) -> None:
 def resolve(interpret: Optional[bool]) -> bool:
     """A call's own ``interpret=`` wins; ``None`` takes the process's."""
     return _process_wide if interpret is None else bool(interpret)
+
+
+def tpu_params(interpret: Optional[bool]):
+    """What a kernel that moves data by DMA and waits on semaphores hands
+    to ``pallas_call(interpret=...)``: the TPU interpreter, which models
+    HBM, VMEM, DMAs and semaphores (the generic one knows none of them).
+    Scratch memory starts as NaN there, as it may on the chip."""
+    if not resolve(interpret):
+        return False
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.InterpretParams()

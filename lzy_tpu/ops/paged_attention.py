@@ -15,28 +15,38 @@ memory-bound to begin with. This module is the native read path:
     einsums, same mask, same softmax, same dtypes), so its output is
     bit-identical to the legacy path and, transitively, to the dense
     engine and the ``generate()`` oracle. It is kept forever as the
-    portable oracle the Pallas kernel is tested against.
-  * ``kernel="pallas"`` — a fused Pallas program (one grid cell per
-    ``(batch row, kv head)``, following ``ops/flash_attention.py``
-    structure) that walks the row's blocks with dynamic page-table
-    loads: the ``[B, L, kv, d]`` dense copy of the pool never exists,
-    and dequantization of int8 blocks happens inside the block loop —
-    the fusion GPUOS argues transparent runtimes owe their users
-    (PAPERS.md). It runs under the Pallas interpreter only. The TPU
-    lowering refuses its layout: the pool is blocked ``(n, page, 1, d)``
-    out of ``(n, page, kv, d)``, and a block's last two dimensions must
-    be multiples of (8, 128) or the array's own; the pool's per-head
-    slice is also staged whole into VMEM per grid cell
-    (:data:`VMEM_BUDGET_BYTES`). A legal layout needs the pool left in
-    HBM and its blocks fetched by DMA — the kernel ROADMAP S2 writes in
-    this one's place. Until then ``"auto"`` resolves to ``"lax"``
-    (:func:`default_kernel`) and an engine asked for ``"pallas"``
-    outside the interpreter fails at construction with the lowering's
-    own message (:func:`lower_pallas_for_tpu`).
+    portable path and the oracle. Its cost is the table's width: it
+    gathers ``pages_per_seq`` pages for every row, live or not.
+  * ``kernel="pallas"`` — the decode kernel (ROADMAP S2), the one
+    ``"auto"`` resolves to (:func:`default_kernel`). The pools stay in
+    HBM in their own ``[n_blocks, page, KV, D]`` layout
+    (``memory_space=ANY``; a page of all KV heads is one contiguous
+    ``[page * KV, D]`` tile-aligned slab, a free reshape); the page
+    table and the positions arrive by scalar prefetch; one grid cell
+    per batch row copies that row's ``ceil(len / page)`` pages, and no
+    more, into VMEM by DMA, a block of pages in flight while the block
+    before it is scored, and folds them into an online softmax. An idle
+    slot (position 0, zeroed table) reads one page. The trip count is
+    dynamic: one compiled program serves every context length. What it
+    costs follows the live context, not ``max_seq_len``.
 
-  The speculative verify forward (``serving/spec.py``) is the same call
-  with ``T = gamma+1`` query positions — proposal scoring, cache write
-  and attention run as ONE program per round.
+    It takes the programs whose shape it is written for
+    (:func:`kernel_path`): ``T <= MAX_Q_TOKENS`` query positions a row
+    over a float pool, which is plain decode and the speculative verify
+    window (``T = gamma + 1``, the same q tile ``T`` times taller).
+    Wider windows (prefill chunks) and int8 pools are read by lax under
+    the same ``kernel="pallas"``; the engine labels
+    ``lzy_kernel_dispatch_total{path}`` with the path each program took.
+    The sharded engine (GSPMD cannot partition the custom call) resolves
+    ``"auto"`` to ``"lax"`` itself.
+
+    Online softmax reorders the sums, so the kernel is not bit-identical
+    to lax: both are judged against float32 attention within
+    :data:`TOLERANCE` (ROADMAP D4). Off the TPU it runs under the Pallas
+    TPU interpreter (``ops/interpret.py``), which models the DMAs and
+    semaphores; an engine built for it outside the interpreter lowers it
+    for a TPU at construction (:func:`lower_pallas_for_tpu`) and fails
+    there, in the lowering's own words, if the shapes cannot be served.
 
 - :func:`quantize_kv` / :func:`dequantize_kv` — per-position, per-head
   asymmetric int8 quantization of KV vectors (scale/zero-point sidecars
@@ -63,11 +73,20 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
 from lzy_tpu.utils.metrics import REGISTRY
 
 _NEG_INF = -1e30
+
+#: the written tolerance of the read paths against float32 attention over
+#: the same pool values (ROADMAP D4): the largest absolute difference,
+#: relative to the reference's largest magnitude or 1, by compute dtype.
+#: bfloat16 carries one rounding of the probabilities and one of the
+#: output (seen on the chip at serving shapes: 0.003 for T = 5, 0.0006
+#: for T = 1); float32 only the order of the sums.
+TOLERANCE = {"bfloat16": 2e-2, "float32": 1e-5}
 
 DISPATCHES = REGISTRY.counter(
     "lzy_kernel_dispatch_total",
@@ -97,10 +116,9 @@ def note_dequant_error(err: float, alpha: float = 0.2) -> float:
 
 def default_kernel() -> str:
     """The kernel ``"auto"`` resolves to: the one that compiles for a TPU
-    at serving shapes. That is the lax gather-attention on every platform
-    — the Pallas kernel's block layout does not lower (module docstring),
-    and a kernel that cannot serve is never picked for the caller."""
-    return "lax"
+    at serving shapes and reads no more than the live context, which is
+    the Pallas decode kernel."""
+    return "pallas"
 
 
 class KVQuant(NamedTuple):
@@ -149,7 +167,7 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, zp: jax.Array,
                   dtype: Any) -> jax.Array:
     """Inverse of :func:`quantize_kv`; ``scale``/``zp`` broadcast over
     the trailing head dim. One formula shared by every read path (legacy
-    gather, lax oracle, Pallas block loop), and — because the scale is a
+    gather, lax oracle), and — because the scale is a
     power of two — one whose value is independent of how the compiler
     fuses it, so the quantized paths can never diverge from EACH OTHER,
     only boundedly from fp."""
@@ -194,165 +212,187 @@ def _lax_paged_attention(q, k_pool, v_pool, page_table, positions, *,
     return jnp.einsum("bkgtl,blkd->btkgd", p, vals)
 
 
-# -- pallas kernel ---------------------------------------------------------------
+# -- pallas decode kernel ----------------------------------------------------------
+
+#: the widest query window the kernel takes: plain decode (T == 1) and
+#: the speculative verify window (T == gamma + 1) share one q tile of
+#: ``T * H`` rows. Prefill chunks are wider and stay on the lax path.
+MAX_Q_TOKENS = 8
+
+#: pool rows, ``(position, kv head)`` pairs, scored per compute block:
+#: 8 pages of 16 positions x 8 kv heads. One block is two ``[1024, D]``
+#: buffers (K and V), double-buffered.
+_BLOCK_ROWS = 1024
 
 
-def _pallas_kernel(*refs, page, pages, t, g, d, scale, dtype, quant):
-    """One ``(batch row, kv head)`` grid cell: walk the row's page table,
-    score every pooled position against the cell's ``[T, G, D]`` query
-    tile, softmax over the full visible row, and contract with the
-    gathered values — K/V are read straight out of the pool by block id
-    (dynamic ``pl.ds`` loads), never materialized in the dense layout.
-    int8 pools dequantize per block inside the loop.
-
-    Numerics discipline: scores accumulate in f32 (``dot_general`` with
-    ``preferred_element_type``), the softmax is the max-shift/exp/sum
-    sequence ``jax.nn.softmax`` lowers to, and the value contraction
-    runs on ``dtype`` operands over the full L axis — the same op
-    shapes-modulo-batching as the lax oracle, which is what keeps
-    interpret-mode output bit-identical to it (asserted by
-    tests/test_paged_attention.py)."""
-    if quant:
-        (q_ref, k_ref, v_ref, ks_ref, kz_ref, vs_ref, vz_ref, pt_ref,
-         pos_ref, o_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, pt_ref, pos_ref, o_ref = refs
-        ks_ref = kz_ref = vs_ref = vz_ref = None
-    L = pages * page
-    qf = q_ref[0, :, 0].astype(jnp.float32).reshape(t * g, d)
-
-    def load_block(ref, s_ref, z_ref, j):
-        row = pt_ref[0, j]
-        blk = ref[pl.ds(row, 1), :, 0, :][0]            # [page, D]
-        if s_ref is None:
-            return blk
-        sc = s_ref[pl.ds(row, 1), :, 0][0]              # [page]
-        zp = z_ref[pl.ds(row, 1), :, 0][0]
-        return dequantize_kv(blk, sc, zp, dtype)
-
-    def score_body(j, carry):
-        k_blk = load_block(k_ref, ks_ref, kz_ref, j).astype(jnp.float32)
-        # scale AFTER the dot, exactly where the lax oracle applies it
-        # (d**-0.5 is not a power of two for every head dim, so the
-        # placement is visible in the last ulp)
-        s_j = lax.dot_general(
-            qf, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # [T*G, page]
-        return lax.dynamic_update_slice(carry, s_j, (0, j * page))
-
-    s = lax.fori_loop(0, pages, score_body,
-                      jnp.zeros((t * g, L), jnp.float32))
-
-    # causal visibility: query at (row position) sees pooled slots
-    # l <= its absolute position; rows of the tile are t-major over g
-    pos_row = jnp.repeat(pos_ref[0, :], g)              # [T*G]
-    cols = lax.broadcasted_iota(jnp.int32, (t * g, L), 1)
-    s = jnp.where(cols <= pos_row[:, None], s, _NEG_INF)
-    # jax.nn.softmax's exact op order: max-shift, exp, normalize
-    m = jnp.max(s, axis=-1, keepdims=True)
-    unnorm = jnp.exp(s - m)
-    p = (unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)).astype(dtype)
-
-    def gather_body(j, carry):
-        v_blk = load_block(v_ref, vs_ref, vz_ref, j)
-        return lax.dynamic_update_slice(carry, v_blk, (j * page, 0))
-
-    vals = lax.fori_loop(
-        0, pages, gather_body, jnp.zeros((L, d), dtype))
-    out = lax.dot_general(p, vals, (((1,), (0,)), ((), ())))
-    o_ref[0, :, 0] = out.reshape(t, g, d).astype(o_ref.dtype)
+def kernel_path(kernel: str, *, t: int, quantized: bool) -> str:
+    """The path a ``paged_attention(kernel=kernel)`` call takes at this
+    shape: asking for ``"pallas"`` gets the kernel for a decode-sized
+    query window over a float pool, and the lax path for everything else
+    (prefill chunks, int8 pools). The engine labels
+    ``lzy_kernel_dispatch_total`` with the same answer."""
+    if kernel == "pallas" and (t > MAX_Q_TOKENS or quantized):
+        return "lax"
+    return kernel
 
 
-#: per-grid-cell VMEM budget the staged operands must fit (conservative
-#: for every current TPU generation). The kernel stages the pool's
-#: PER-HEAD slice into VMEM per (batch row, kv head) cell — fine at
-#: bench/test scale, but an HBM-sized pool (--serve-kv-pool-mb) would
-#: either fail Mosaic compilation or move more bytes than the legacy
-#: gather; until the scalar-prefetch DMA variant lands (ROADMAP S2)
-#: the guard turns that into a clear boot-time error (warmup AOT-compiles
-#: the decode program) instead of a mid-serving engine death.
-VMEM_BUDGET_BYTES = 48 << 20
+def _decode_kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, *, t, heads, kv_heads, page,
+                   pages_per_seq, block_pages, scale):
+    """One batch row per grid cell. The row's ``[T*H, D]`` query tile is
+    scored against the row's live pages only: ``ceil(len / page)`` pages
+    are copied from the HBM pool by DMA, ``block_pages`` at a time into
+    one of two VMEM buffers (the next block's copies are in flight while
+    this one is scored), and folded into a running max / sum / weighted
+    value (online softmax).
+
+    A page arrives as ``[page * KV, D]``: the pool's own layout, rows
+    ordered ``(position, kv head)``. Every query head is scored against
+    every row and a block-diagonal mask keeps its own kv head's: the
+    contraction stays one ``[T*H, D] x [D, rows]`` matmul per block with
+    no relayout of the page, at ``KV`` times the arithmetic, on a read
+    that the HBM bounds.
+
+    Numerics: scores and the running max / sum in float32, scaled after
+    the dot, probabilities cast to the pool's dtype before the value
+    contraction (as the lax path does), the sum of the float32
+    probabilities divides the float32 accumulator once at the end."""
+    b = pl.program_id(0)
+    m_rows, d = q_ref.shape
+    rows = page * kv_heads
+    cols = block_pages * rows
+    g = heads // kv_heads
+
+    # per query row (t-major over heads): the last position it sees
+    row_t = lax.div(lax.broadcasted_iota(jnp.int32, (m_rows, 1), 0), heads)
+    last = pos_ref[b * t]
+    pos_rows = jnp.full((m_rows, 1), last, jnp.int32)
+    for ti in range(1, t):
+        p_ti = pos_ref[b * t + ti]
+        pos_rows = jnp.where(row_t == ti, p_ti, pos_rows)
+        last = jnp.maximum(last, p_ti)
+    n_pages = lax.div(jnp.maximum(last + page, 0), page)
+    n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
+
+    col = lax.broadcasted_iota(jnp.int32, (m_rows, cols), 1)
+    row = lax.broadcasted_iota(jnp.int32, (m_rows, cols), 0)
+    own_head = lax.rem(col, kv_heads) == lax.div(lax.rem(row, heads), g)
+    col_pos = lax.div(col, kv_heads)
+
+    @pl.when(b == 0)
+    def _():
+        # a partial block leaves rows of the buffer unwritten; their
+        # probabilities are 0, and 0 x whatever VMEM held must be 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    def for_pages(j, slot, op):
+        for i in range(block_pages):
+            @pl.when(j * block_pages + i < n_pages)
+            def _():
+                pid = pt_ref[b * pages_per_seq + j * block_pages + i]
+                dst = pl.ds(i * rows, rows)
+                op(pltpu.make_async_copy(
+                    k_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]))
+                op(pltpu.make_async_copy(
+                    v_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
+
+    for_pages(0, 0, lambda c: c.start())
+
+    def body(j, carry):
+        m, l, acc = carry
+        slot = lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_blocks)
+        def _():
+            for_pages(j + 1, 1 - slot, lambda c: c.start())
+
+        for_pages(j, slot, lambda c: c.wait())
+        s = lax.dot_general(
+            q_ref[...], k_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [T*H, cols]
+        visible = own_head & (col_pos <= pos_rows - j * (block_pages * page))
+        s = jnp.where(visible, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + lax.dot_general(
+            p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    _, l, acc = lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.full((m_rows, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((m_rows, 1), jnp.float32),
+         jnp.zeros((m_rows, d), jnp.float32)))
+    # a row that sees nothing (position -1) reads nothing and returns 0
+    o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("dtype", "interpret", "block_rows"))
 def _pallas_paged_attention(q, k_pool, v_pool, page_table, positions, *,
-                            dtype, quant: Optional[KVQuant],
-                            interpret: Optional[bool]):
+                            dtype, interpret: bool,
+                            block_rows: int = _BLOCK_ROWS):
+    """jitted so that a model's layers, which all make this call at one
+    shape, trace and lower the kernel once a program, not once a layer
+    (a second of Python each: set-up time on every start of a replica)."""
     b, t, h, d = q.shape
     n, page, kv_heads, _ = k_pool.shape
     pages = page_table.shape[1]
-    g = h // kv_heads
-    interpret = _interpret.resolve(interpret)
-    L = pages * page
-    staged = 2 * n * page * d * k_pool.dtype.itemsize      # k+v head slice
-    if quant is not None:
-        staged += 4 * n * page * 4                         # f32 sidecars
-    staged += (t * g * L + L * d + t * g * d) * 4          # scores/vals/q
-    if not interpret and staged > VMEM_BUDGET_BYTES:
-        raise ValueError(
-            f"paged-attention pallas kernel would stage ~{staged >> 20} "
-            f"MiB per grid cell (pool of {n} blocks x page {page} x head "
-            f"dim {d}) — beyond the {VMEM_BUDGET_BYTES >> 20} MiB VMEM "
-            f"budget. Shrink the pool or use kernel='lax' until the "
-            f"HBM-resident DMA variant lands (ROADMAP S2).")
-    qg = q.reshape(b, t, kv_heads, g, d)
-
-    pool_spec = pl.BlockSpec((n, page, 1, d), lambda bi, ki: (0, 0, ki, 0))
-    side_spec = pl.BlockSpec((n, page, 1), lambda bi, ki: (0, 0, ki))
-    in_specs = [
-        pl.BlockSpec((1, t, 1, g, d), lambda bi, ki: (bi, 0, ki, 0, 0)),
-        pool_spec, pool_spec,
-    ]
-    operands = [qg, k_pool, v_pool]
-    if quant is not None:
-        in_specs += [side_spec] * 4
-        operands += [quant.k_scale, quant.k_zp, quant.v_scale, quant.v_zp]
-    in_specs += [
-        pl.BlockSpec((1, pages), lambda bi, ki: (bi, 0)),
-        pl.BlockSpec((1, t), lambda bi, ki: (bi, 0)),
-    ]
-    operands += [page_table.astype(jnp.int32), positions.astype(jnp.int32)]
+    rows = page * kv_heads
+    block_pages = max(1, min(pages, block_rows // rows))
     kernel = functools.partial(
-        _pallas_kernel, page=page, pages=pages, t=t, g=g, d=d,
-        scale=d ** -0.5, dtype=dtype, quant=quant is not None)
+        _decode_kernel, t=t, heads=h, kv_heads=kv_heads, page=page,
+        pages_per_seq=pages, block_pages=block_pages, scale=d ** -0.5)
+    tile = pl.BlockSpec((None, t * h, d), lambda bi, *_: (bi, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
-        grid=(b, kv_heads),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, t, 1, g, d),
-                               lambda bi, ki: (bi, 0, ki, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, kv_heads, g, d), dtype),
-        interpret=interpret,
-    )(*operands)
-    return out
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[tile, pool, pool],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages * rows, d), k_pool.dtype),
+                pltpu.VMEM((2, block_pages * rows, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, t * h, d), dtype),
+        # the V buffer is zeroed by the first cell and kept by the rest
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret.tpu_params(interpret),
+        name="paged_decode_attention",
+    )(positions.astype(jnp.int32).reshape(-1),
+      page_table.astype(jnp.int32).reshape(-1),
+      q.astype(k_pool.dtype).reshape(b, t * h, d),
+      # a page is contiguous in the pool: [page, KV, D] -> [page*KV, D]
+      k_pool.reshape(n, rows, d), v_pool.reshape(n, rows, d))
+    return out.reshape(b, t, kv_heads, h // kv_heads, d)
 
 
 def lower_pallas_for_tpu(*, batch: int, n_heads: int, n_kv_heads: int,
                          head_dim: int, n_blocks: int, page_size: int,
-                         pages_per_seq: int, dtype: Any,
-                         quantized: bool = False, t: int = 1) -> None:
+                         pages_per_seq: int, dtype: Any, t: int = 1) -> None:
     """Lower the Pallas kernel for a TPU at these shapes, with no device
-    and no compile, and let the lowering's error out. An engine asked for
-    ``kernel="pallas"`` calls this when it is built: what the TPU would
-    refuse at the first request is refused at construction, in the
-    lowering's own words."""
+    and no compile, and let the lowering's error out. An engine whose
+    decode step takes the kernel calls this when it is built: what the
+    TPU would refuse at the first request is refused at construction, in
+    the lowering's own words."""
     sds = jax.ShapeDtypeStruct
-    pool = sds((n_blocks, page_size, n_kv_heads, head_dim),
-               jnp.int8 if quantized else dtype)
-    quant = None
-    if quantized:
-        side = sds((n_blocks, page_size, n_kv_heads), jnp.float32)
-        quant = KVQuant(side, side, side, side)
+    pool = sds((n_blocks, page_size, n_kv_heads, head_dim), dtype)
 
-    def read(q, k_pool, v_pool, page_table, positions, quant):
-        return paged_attention(q, k_pool, v_pool, page_table, positions,
-                               kernel="pallas", dtype=dtype, quant=quant,
-                               interpret=False)
+    def read(q, k_pool, v_pool, page_table, positions):
+        return _pallas_paged_attention(
+            q, k_pool, v_pool, page_table, positions,
+            dtype=jnp.dtype(dtype), interpret=False)
 
     jax.jit(read).trace(
         sds((batch, t, n_heads, head_dim), dtype), pool, pool,
         sds((batch, pages_per_seq), jnp.int32), sds((batch, t), jnp.int32),
-        quant,
     ).lower(lowering_platforms=("tpu",))
 
 
@@ -382,10 +422,10 @@ def paged_attention(
     - ``positions``: ``[B, T]`` int32 absolute positions of the queries
       (the causal mask: pooled slot ``l`` is visible iff
       ``l <= position``);
-    - ``kernel``: ``"lax"`` (the path that serves, bit-identical to the
-      legacy gather path) or ``"pallas"`` (fused; interpreter only, see
-      the module docstring — ``interpret=None`` takes the process's
-      ``ops.interpret`` setting);
+    - ``kernel``: ``"lax"`` (portable, bit-identical to the legacy
+      gather path) or ``"pallas"`` (the decode kernel for the shapes
+      :func:`kernel_path` gives it, lax for the rest; ``interpret=None``
+      takes the process's ``ops.interpret`` setting);
     - ``dtype``: compute/output dtype (defaults to the pool dtype; int8
       pools must pass the model's activation dtype).
 
@@ -396,13 +436,13 @@ def paged_attention(
         if quant is not None:
             raise ValueError("quantized pools need an explicit dtype")
         dtype = k_pool.dtype
-    if kernel == "lax":
-        return _lax_paged_attention(
-            q, k_pool, v_pool, page_table, positions, dtype=dtype,
-            quant=quant)
-    if kernel == "pallas":
+    if kernel not in ("lax", "pallas"):
+        raise ValueError(
+            f"unknown paged-attention kernel {kernel!r}; known: lax, pallas")
+    if kernel_path(kernel, t=q.shape[1], quantized=quant is not None) \
+            == "pallas":
         return _pallas_paged_attention(
-            q, k_pool, v_pool, page_table, positions, dtype=dtype,
-            quant=quant, interpret=interpret)
-    raise ValueError(
-        f"unknown paged-attention kernel {kernel!r}; known: lax, pallas")
+            q, k_pool, v_pool, page_table, positions, dtype=jnp.dtype(dtype),
+            interpret=_interpret.resolve(interpret))
+    return _lax_paged_attention(
+        q, k_pool, v_pool, page_table, positions, dtype=dtype, quant=quant)

@@ -138,9 +138,10 @@ def main(argv=None) -> int:
     parser.add_argument("--serve-kernel",
                         choices=("auto", "pallas", "lax"), default="auto",
                         help="kernel under --serve-native-attention: "
-                             "pallas (fused, TPU), lax (portable, "
-                             "bit-identical to the legacy gather), auto "
-                             "picks by platform")
+                             "pallas (decode kernel: reads the live "
+                             "context only), lax (portable, bit-identical "
+                             "to the legacy gather), auto is pallas, and "
+                             "lax under --serve-mesh")
     parser.add_argument("--serve-spec", action="store_true",
                         help="draft-free speculative decoding: n-gram "
                              "prompt lookup proposes up to --spec-tokens "

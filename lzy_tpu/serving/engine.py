@@ -1712,10 +1712,12 @@ class PagedInferenceEngine(InferenceEngine):
         self._pages_per_seq = base.max_seq_len // page_size
         self._kv_quant = kv_quant
         # kernel selection (docs/serving.md): "auto" is the kernel that
-        # compiles for a TPU (the lax gather-attention today), "pallas"
-        # is taken at the caller's word and checked below, and "legacy"
-        # — the original gather-back-to-dense read — serves when
-        # native_attention is off
+        # compiles for a TPU (the Pallas decode kernel), "pallas" is
+        # taken at the caller's word and checked below, and "legacy" —
+        # the original gather-back-to-dense read — serves when
+        # native_attention is off. The kernel takes the programs whose
+        # shape it is written for (ops.paged_attention.kernel_path) and
+        # leaves the rest to lax: kernel_path is the decode step's.
         self._native = bool(native_attention)
         if not self._native:
             if kernel != "auto":
@@ -1724,10 +1726,15 @@ class PagedInferenceEngine(InferenceEngine):
                 raise ValueError(
                     f"kernel={kernel!r} requires native_attention=True "
                     f"(without it the legacy gather path serves)")
-            self.kernel_path = "legacy"
+            self._paged_kernel = "lax"
         else:
-            self.kernel_path = default_kernel() if kernel == "auto" \
+            if kernel == "pallas" and kv_quant is not None:
+                raise ValueError(
+                    "kernel='pallas' reads float pools only; an int8 pool "
+                    "is served by kernel='lax' (or 'auto')")
+            self._paged_kernel = default_kernel() if kernel == "auto" \
                 else kernel
+        self.kernel_path = self._path_of(1)
         self._dispatches = DISPATCHES
         # the resident gauge is process-global and this process may run
         # several quantized pools (disagg: prefill + decode); each engine
@@ -1763,8 +1770,7 @@ class PagedInferenceEngine(InferenceEngine):
                 batch=slots, n_heads=base.n_heads,
                 n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
                 n_blocks=kv_blocks, page_size=page_size,
-                pages_per_seq=self._pages_per_seq, dtype=base.dtype,
-                quantized=kv_quant is not None)
+                pages_per_seq=self._pages_per_seq, dtype=base.dtype)
         self.kv = RadixCache(kv_blocks, page_size)
         # tiered KV cache (serving/kv_tier.py): radix eviction DEMOTES
         # block payloads to pinned host RAM (and onward to storage)
@@ -1826,13 +1832,22 @@ class PagedInferenceEngine(InferenceEngine):
 
     # -- construction --------------------------------------------------------
 
+    def _path_of(self, t: int) -> str:
+        """The read path of a program with ``t`` query positions a row:
+        the label of its ``lzy_kernel_dispatch_total`` count."""
+        from lzy_tpu.ops.paged_attention import kernel_path
+
+        if not self._native:
+            return "legacy"
+        return kernel_path(self._paged_kernel, t=t,
+                           quantized=self._kv_quant is not None)
+
     def _build_decode_path(self, base: LlamaConfig) -> None:
         pcfg = dataclasses.replace(
             base, decode_paged=True, kv_page_size=self._page,
             kv_pages=self._kv_blocks,
             paged_attention_native=self._native,
-            paged_kernel=self.kernel_path if self._native else "lax",
-            kv_quant=self._kv_quant)
+            paged_kernel=self._paged_kernel, kv_quant=self._kv_quant)
         slots, pages = self.slots, self._pages_per_seq
         self._model = Llama(pcfg)
         dummy_pt = jnp.zeros((slots, pages), jnp.int32)
@@ -2067,7 +2082,7 @@ class PagedInferenceEngine(InferenceEngine):
                 # one program dispatch per CHUNK (a budgeted round may
                 # run several) — the dispatch counter must agree with
                 # the decode/verify paths' one-inc-per-program rule
-                self._dispatches.inc(path=self.kernel_path)
+                self._dispatches.inc(path=self._path_of(tokens.shape[1]))
                 return self._prefill_step(
                     c, self.params, tokens, pt,
                     jnp.asarray(take - 1, jnp.int32))
@@ -2690,7 +2705,7 @@ class PagedInferenceEngine(InferenceEngine):
 
     def _run_verify_step(self, prop, prop_len):
         cur, pos, mask = self._device_inputs()
-        self._dispatches.inc(path=self.kernel_path)
+        self._dispatches.inc(path=self._path_of(self.spec_tokens + 1))
         return self._verify_step(self._payload, self.params, cur, prop,
                                  prop_len, pos, self._page_table_dev(),
                                  mask, self._rng)

@@ -90,6 +90,10 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
             raise ValueError(
                 "kernel='pallas' cannot serve sharded: the fused kernel is "
                 "a custom call GSPMD cannot partition; use kernel='lax'")
+        if kwargs.get("native_attention") \
+                and kwargs.get("kernel", "auto") == "auto":
+            # for the same reason a gang's "auto" is the lax read
+            kwargs["kernel"] = "lax"
         self._mesh = mesh
         self._tp = tp
         self.gang_size = tp
@@ -140,8 +144,7 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
             base, decode_paged=True, kv_page_size=self._page,
             kv_pages=self._kv_blocks,
             paged_attention_native=self._native,
-            paged_kernel=self.kernel_path if self._native else "lax",
-            kv_quant=self._kv_quant)
+            paged_kernel=self._paged_kernel, kv_quant=self._kv_quant)
         slots, pages = self.slots, self._pages_per_seq
         self._model = Llama(pcfg, rules=SERVE_RULES)
         dummy_pt = jnp.zeros((slots, pages), jnp.int32)

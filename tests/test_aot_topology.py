@@ -10,8 +10,9 @@ The CPU dryrun proves the sharded step executes; these tests prove the
   activation resharding cliff blows straight through that bound);
 - the Pallas kernels compile at Llama-3-8B widths with ``interpret=False``:
   flash attention forward and backward up to the longest length its wrapper
-  accepts, the paged-attention read ``"auto"`` resolves to, and one paged
-  decode step of a two-layer model at full width.
+  accepts, the paged-attention read ``"auto"`` resolves to (the Pallas decode
+  kernel over float pools, lax over int8), and one paged decode step of a
+  two-layer model at full width.
 
 Interpret mode cannot see a block shape the TPU lowering refuses or a kernel
 that runs out of VMEM; these compiles can, at about two seconds each and no
@@ -218,35 +219,49 @@ def test_paged_attention_auto_compiles(t, quantized, one_chip):
     ).compile()
 
 
-def test_pallas_paged_kernel_is_refused_and_auto_avoids_it(monkeypatch):
-    """The finding ROADMAP S2 records: the kernel's ``(n, page, 1, d)`` pool
-    block does not lower for a TPU. So ``"auto"`` is not it, and an engine
-    asked for it outside the interpreter fails when it is built, in the
-    lowering's own words, never dropping to the interpreter or to lax."""
+@pytest.mark.parametrize("shape", ["8b_page16", "8b_page64", "benchmark"])
+def test_pallas_paged_kernel_lowers_and_auto_is_it(shape, monkeypatch):
+    """ROADMAP S2's kernel: the pool stays in HBM and pages are fetched by
+    DMA, so the lowering has no pool block to refuse. It lowers at the 8B
+    shapes with pages of 16 and 64 and at the benchmark's (32 slots, the 7
+    GiB pool's 7168 pages, tables of 256 pages), ``"auto"`` is it, and an
+    engine built with ``"auto"`` outside the interpreter reports it: the
+    lowering ran when the engine was built."""
     from lzy_tpu.ops import interpret
     from lzy_tpu.ops.paged_attention import (
         default_kernel, lower_pallas_for_tpu)
     from lzy_tpu.serving import PagedInferenceEngine
 
-    assert default_kernel() == "lax"
-    for page in (16, 64):
-        with pytest.raises(ValueError, match="last two dimensions"):
-            lower_pallas_for_tpu(
-                batch=8, n_heads=_H, n_kv_heads=_KV, head_dim=_D,
-                n_blocks=513, page_size=page,
-                pages_per_seq=_8B.max_seq_len // page, dtype=jnp.bfloat16)
+    assert default_kernel() == "pallas"
+    batch, n_blocks, page, pages = {
+        "8b_page16": (8, 513, 16, _8B.max_seq_len // 16),
+        "8b_page64": (8, 513, 64, _8B.max_seq_len // 64),
+        "benchmark": (32, 7168, 16, 256),
+    }[shape]
+    lower_pallas_for_tpu(
+        batch=batch, n_heads=_H, n_kv_heads=_KV, head_dim=_D,
+        n_blocks=n_blocks, page_size=page, pages_per_seq=pages,
+        dtype=jnp.bfloat16)
     cfg = llama.LlamaConfig.tiny()
     params = unbox(llama.init_params(cfg, jax.random.PRNGKey(0))[0])
     monkeypatch.setattr(interpret, "_process_wide", False)
-    with pytest.raises(ValueError, match="last two dimensions"):
-        PagedInferenceEngine(cfg, params, slots=2, native_attention=True,
-                             kernel="pallas")
+    engine = PagedInferenceEngine(cfg, params, slots=2, page_size=page,
+                                  native_attention=True, kernel="auto")
+    try:
+        assert engine.kernel_path == "pallas"
+        assert engine.stats().kernel_path == "pallas"
+    finally:
+        engine.close()
 
 
-def test_paged_decode_step_compiles_at_full_width(one_chip):
+def test_paged_decode_step_compiles_at_full_width(one_chip, monkeypatch):
     """One decode step of the engine ``chip_smoke.py`` serves, two layers
-    deep, shapes only: parameters from ``jax.eval_shape``."""
+    deep, shapes only: parameters from ``jax.eval_shape``. The kernel is
+    compiled, not interpreted, as on the chip."""
+    from lzy_tpu.ops import interpret
     from lzy_tpu.serving import PagedInferenceEngine
+
+    monkeypatch.setattr(interpret, "_process_wide", False)
 
     cfg = dataclasses.replace(_8B, n_layers=2, param_dtype=jnp.bfloat16)
     params = jax.eval_shape(
@@ -270,7 +285,7 @@ def test_paged_decode_step_compiles_at_full_width(one_chip):
     finally:
         engine.close()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
-    # "auto" is the lax gather-attention, so no Pallas kernel is meant to be
-    # in this program; the day S2's kernel lands, this flips
-    assert engine.kernel_path == "lax"
-    assert "tpu_custom_call" not in compiled.as_text()
+    # "auto" is the Pallas decode kernel: it is in this program, and the
+    # gather of every row's whole table is not
+    assert engine.kernel_path == "pallas"
+    assert "tpu_custom_call" in compiled.as_text()

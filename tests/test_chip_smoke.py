@@ -39,7 +39,7 @@ def tiny():
 def test_serve_phase_rehearsal(tiny):
     out = chip_smoke.phase_serve(_args(), tiny, slots=2, pool={},
                                  new_tokens=8)
-    assert out["kernel_path"] == "lax"
+    assert out["kernel_path"] == "pallas"
     assert out["cached_prompt_tokens_B"] >= 64
     assert set(out["verdicts"].values()) == {"identical"}
     assert len(out["verdicts"]) == 10

@@ -4,21 +4,24 @@ Three layers of coverage, matching the module's correctness contract
 (``lzy_tpu/ops/paged_attention.py``, docs/serving.md "Native paged
 attention & KV quantization"):
 
-- **Op-level bit-exactness sweeps**: the lax gather-attention fallback
-  and the Pallas kernel (interpret mode on CPU) must produce EXACTLY the
-  same bytes across page sizes, ragged per-row lengths, scratch-block
-  idle rows, chunk widths (1-token decode, gamma+1 verify windows,
-  prefill chunks), dtypes, and quantization on/off. "Close" is not a
-  pass: the serving stack's oracle chain (paged == dense == generate())
-  is built on bit-identity, and the native path joins that chain.
+- **Op-level sweeps**: the lax gather-attention reproduces the legacy
+  read bit for bit (same ops in the same order), and stays the portable
+  oracle. The Pallas decode kernel (TPU interpreter on the CPU: DMAs,
+  semaphores, scratch memory that starts as NaN) reorders the sums
+  (online softmax), so it is judged against a float32 reference within
+  the module's written tolerance, across page sizes, ragged per-row
+  lengths, scratch-block idle rows, decode and verify windows and
+  dtypes, and at the lengths that break such kernels.
 - **Model/engine-level oracle tests**: a ``PagedInferenceEngine`` with
-  ``native_attention=True`` must be bit-identical to the solo
-  ``generate()`` oracle — greedy and sampled, speculation on and off —
-  because the lax kernel reproduces the legacy gather math op for op.
+  ``native_attention=True, kernel="lax"`` must be bit-identical to the
+  solo ``generate()`` oracle — greedy and sampled, speculation on and
+  off. Through the kernel (``"auto"``), logits lie within the tolerance
+  and greedy tokens are the oracle's except at a logit tie.
 - **int8 bounded divergence**: quantized output is intentionally NOT
   bit-identical; what IS asserted: the per-element dequantization error
   bound (one optimal-scale quantization step), kernel-independence of
-  quantized output (legacy == lax == pallas on the same int8 pool),
+  quantized output (legacy == lax on the same int8 pool; the kernel
+  leaves int8 pools to lax),
   greedy-match rate against the fp oracle over long continuations, pool
   integrity (no leaked/corrupted blocks under quantization), and the 2x
   block-count win at a fixed pool byte budget.
@@ -35,8 +38,9 @@ from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import decode_config, generate, init_cache
 from lzy_tpu.models.llama import Llama, LlamaConfig
 from lzy_tpu.ops.paged_attention import (
-    DEQUANT_ERROR_EWMA, KVQuant, default_kernel, dequantize_kv,
-    note_dequant_error, paged_attention, quantize_kv)
+    DEQUANT_ERROR_EWMA, DISPATCHES, MAX_Q_TOKENS, TOLERANCE, KVQuant,
+    default_kernel, dequantize_kv, kernel_path, note_dequant_error,
+    paged_attention, quantize_kv)
 from lzy_tpu.serving import PagedInferenceEngine
 from lzy_tpu.serving.kv_cache import (
     blocks_for_bytes, kv_block_bytes, kv_quant_sidecar_bytes)
@@ -63,9 +67,63 @@ def _drive(eng, *reqs, rounds=400):
     raise AssertionError("requests did not finish")
 
 
-def _metric_value(metric) -> float:
-    """Sum over all label combinations of a process-registry metric."""
-    return sum(metric._values.values())
+def _metric_value(metric, **labels) -> float:
+    """Sum over the label combinations of a process-registry metric
+    that carry ``labels`` (all of them when none is given)."""
+    want = set(labels.items())
+    return sum(v for key, v in metric._values.items()
+               if want <= set(key))
+
+
+def _reference(q, k_pool, v_pool, pt, pos):
+    """Float32 attention over each row's gathered pages, one (row,
+    position, head) at a time: nothing shared with the code under test.
+    A query at position -1 sees nothing and reads as zeros."""
+    q, k_pool, v_pool = (np.asarray(x, np.float32)
+                         for x in (q, k_pool, v_pool))
+    pt, pos = np.asarray(pt), np.asarray(pos)
+    b, t, h, d = q.shape
+    kv = k_pool.shape[2]
+    g = h // kv
+    out = np.zeros((b, t, kv, g, d), np.float32)
+    for bi in range(b):
+        keys = k_pool[pt[bi]].reshape(-1, kv, d)
+        vals = v_pool[pt[bi]].reshape(-1, kv, d)
+        for ti in range(t):
+            n = pos[bi, ti] + 1
+            for head in range(h if n > 0 else 0):
+                s = keys[:n, head // g] @ q[bi, ti, head] * d ** -0.5
+                p = np.exp(s - s.max())
+                out[bi, ti, head // g, head % g] = \
+                    (p / p.sum()) @ vals[:n, head // g]
+    return out
+
+
+def _assert_within_tolerance(got, want, dtype, what=""):
+    """The written tolerance (``ops.paged_attention.TOLERANCE``): the
+    largest absolute difference, relative to the reference's largest
+    magnitude or 1."""
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all(), f"non-finite output {what}"
+    err = np.abs(got - want).max() / max(1.0, np.abs(want).max())
+    assert err <= TOLERANCE[jnp.dtype(dtype).name], \
+        f"{what}: error {err:.3g} at {jnp.dtype(dtype).name}"
+
+
+def _assert_same_or_tie(cfg, params, prompt, got, want, tol=0.05):
+    """Greedy tokens equal, or at the first difference the two tokens'
+    logits under the full (uncached) forward are within ``tol``: a tie,
+    after which the continuations legitimately part."""
+    assert len(got) == len(want)
+    for i, (a, b) in enumerate(zip(got, want)):
+        if a == b:
+            continue
+        seen = jnp.asarray([list(prompt) + list(want[:i])], jnp.int32)
+        logits = np.asarray(
+            Llama(cfg).apply({"params": params}, seen)[0, -1], np.float32)
+        assert abs(logits[a] - logits[b]) <= tol, \
+            f"token {i}: {a} != {b}, logits {logits[a]} vs {logits[b]}"
+        return
 
 
 # -- quantizer units ---------------------------------------------------------
@@ -136,66 +194,135 @@ def _random_case(rng, *, page, pages, b, t, kv, g, d, dtype, quant):
     return q, k_pool, v_pool, jnp.asarray(pt), pos, quant_side
 
 
-class TestKernelBitExactness:
+def _edge_case(name, *, page=8, pages=6, block_pages=4):
+    """Per-row context lengths at which such kernels break; a length of
+    n puts the query at position n - 1 and owns ceil(n / page) pages."""
+    L = pages * page
+    return {
+        "idle_slot": [1, 1, 1],                  # position 0, scratch table
+        "length_0": [0, 5, 0],                   # a query that sees nothing
+        "length_1": [1, 2, 1],
+        "page_boundary": [page, 2 * page, 3 * page],
+        "first_of_a_page": [page + 1, 2 * page + 1, 1],
+        "block_boundary": [block_pages * page, block_pages * page + 1,
+                           block_pages * page - 1],
+        "full_table": [L, L, L],
+        "very_different": [1, L, 3],
+    }[name]
+
+
+class TestDecodeKernel:
     @pytest.mark.parametrize("page,pages", [(4, 8), (8, 4), (16, 3)])
     @pytest.mark.parametrize("t", [1, 5])
-    @pytest.mark.parametrize("quant", [False, True])
-    def test_pallas_interpret_equals_lax(self, page, pages, t, quant):
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_pallas_interpret_matches_float32_reference(
+            self, page, pages, t, dtype):
         rng = np.random.default_rng(page * 100 + t)
-        for dtype in (jnp.bfloat16, jnp.float32):
-            q, kp, vp, pt, pos, side = _random_case(
-                rng, page=page, pages=pages, b=3, t=t, kv=2, g=2, d=16,
-                dtype=dtype, quant=quant)
-            a = paged_attention(q, kp, vp, pt, pos, kernel="lax",
-                                dtype=dtype, quant=side)
-            p = paged_attention(q, kp, vp, pt, pos, kernel="pallas",
-                                dtype=dtype, quant=side, interpret=True)
-            if quant and dtype == jnp.float32:
-                # the one case that is not bitwise: the dequantised float32
-                # values go through a [T*G, L] x [L, D] contraction whose
-                # summation order the CPU backend picks per program, and
-                # interpreted Pallas and op-by-op lax are two programs
-                # (seen: 1.2e-7 on outputs of order 1). Four float32 ulps
-                # at the output's scale; bf16 rounds the difference away.
-                a, p = np.asarray(a), np.asarray(p)
-                atol = 4 * np.finfo(np.float32).eps * max(
-                    1.0, float(np.abs(a).max()))
-                np.testing.assert_allclose(p, a, rtol=0, atol=atol)
-                continue
-            assert bool(jnp.array_equal(a, p)), \
-                f"pallas != lax at dtype={dtype} quant={quant}"
+        dtype = jnp.dtype(dtype)
+        q, kp, vp, pt, pos, _ = _random_case(
+            rng, page=page, pages=pages, b=3, t=t, kv=2, g=2, d=16,
+            dtype=dtype, quant=False)
+        want = _reference(q, kp, vp, pt, pos)
+        p = paged_attention(q, kp, vp, pt, pos, kernel="pallas",
+                            dtype=dtype, interpret=True)
+        assert p.shape == want.shape and p.dtype == dtype
+        _assert_within_tolerance(p, want, dtype, "pallas")
+        # the oracle of the rest of the suite is held to the same judge
+        a = paged_attention(q, kp, vp, pt, pos, kernel="lax", dtype=dtype)
+        _assert_within_tolerance(a, want, dtype, "lax")
 
-    def test_exact_under_jit_and_odd_head_dim(self):
-        # the engine runs the op inside jitted programs; fusion must not
-        # perturb the identity (d=24: a head dim whose softmax scale is
-        # not a power of two)
+    @pytest.mark.parametrize("case", [
+        "idle_slot", "length_0", "length_1", "page_boundary",
+        "first_of_a_page", "block_boundary", "full_table",
+        "very_different"])
+    def test_lengths_that_break_such_kernels(self, case):
+        """kv=2 and page=8 make a page 16 pool rows; a compute block of 64
+        rows is 4 pages, so 6-page tables run one full and one partial
+        block, double-buffered."""
+        import importlib
+
+        kernel = importlib.import_module(
+            "lzy_tpu.ops.paged_attention")._pallas_paged_attention
+        page, pages, kv, g, d = 8, 6, 2, 2, 16
+        lens = np.asarray(_edge_case(case, page=page, pages=pages))
+        rng = np.random.default_rng(len(case))
+        b = len(lens)
+        n = b * pages + 1
+        dtype = jnp.bfloat16
+        q = jnp.asarray(rng.standard_normal((b, 1, kv * g, d)), dtype)
+        kp = jnp.asarray(rng.standard_normal((n, page, kv, d)), dtype)
+        vp = jnp.asarray(rng.standard_normal((n, page, kv, d)), dtype)
+        pt = np.zeros((b, pages), np.int32)
+        ids = rng.permutation(np.arange(1, n))
+        for row, owned in enumerate(-(-lens // page)):
+            if lens[row] > 1:                    # idle rows keep scratch
+                pt[row, :owned], ids = ids[:owned], ids[owned:]
+        pos = jnp.asarray(lens[:, None] - 1, jnp.int32)
+        got = kernel(q, kp, vp, jnp.asarray(pt), pos, dtype=dtype,
+                     interpret=True, block_rows=64)
+        _assert_within_tolerance(got, _reference(q, kp, vp, pt, pos),
+                                 dtype, case)
+        if case == "length_0":
+            assert not np.asarray(got, np.float32)[[0, 2]].any()
+
+    def test_within_tolerance_under_jit_and_odd_head_dim(self):
+        # the engine runs the op inside jitted programs (d=24: a head dim
+        # whose softmax scale is not a power of two; g=3: a group size
+        # that is not one either)
         import functools
 
         rng = np.random.default_rng(7)
-        q, kp, vp, pt, pos, side = _random_case(
+        q, kp, vp, pt, pos, _ = _random_case(
             rng, page=8, pages=4, b=2, t=3, kv=2, g=3, d=24,
-            dtype=jnp.bfloat16, quant=True)
-        f_lax = jax.jit(functools.partial(
-            paged_attention, kernel="lax", dtype=jnp.bfloat16, quant=side))
+            dtype=jnp.bfloat16, quant=False)
         f_pal = jax.jit(functools.partial(
             paged_attention, kernel="pallas", dtype=jnp.bfloat16,
-            quant=side, interpret=True))
-        assert bool(jnp.array_equal(f_lax(q, kp, vp, pt, pos),
-                                    f_pal(q, kp, vp, pt, pos)))
+            interpret=True))
+        _assert_within_tolerance(f_pal(q, kp, vp, pt, pos),
+                                 _reference(q, kp, vp, pt, pos),
+                                 jnp.bfloat16)
 
-    def test_pallas_rejects_vmem_oversized_pools(self):
-        """An HBM-sized pool must fail the pallas path at TRACE time
-        with an actionable error (warmup AOT-compiles, so this lands at
-        boot), not as a Mosaic compile failure mid-serving."""
-        big = jax.ShapeDtypeStruct((200_000, 64, 2, 128), jnp.bfloat16)
-        q = jax.ShapeDtypeStruct((1, 1, 4, 128), jnp.bfloat16)
-        pt = jax.ShapeDtypeStruct((1, 16), jnp.int32)
-        pos = jax.ShapeDtypeStruct((1, 1), jnp.int32)
-        with pytest.raises(ValueError, match="VMEM"):
-            jax.eval_shape(
-                lambda *a: paged_attention(*a, kernel="pallas",
-                                           interpret=False),
-                q, big, big, pt, pos)
+    def test_pool_far_larger_than_the_vmem(self):
+        """The pool stays in HBM and only the live pages move: 48 MiB of
+        K and V (three times a core's VMEM) behind rows that own 1, 3 and
+        40 pages at the far end of the pool."""
+        page, pages, kv, g, d = 16, 40, 2, 2, 128
+        n = 3072
+        rng = np.random.default_rng(11)
+        dtype = jnp.bfloat16
+        kp = jnp.asarray(rng.standard_normal((n, page, kv, d)), dtype)
+        vp = jnp.asarray(rng.standard_normal((n, page, kv, d)), dtype)
+        assert kp.nbytes + vp.nbytes >= 48 << 20
+        q = jnp.asarray(rng.standard_normal((3, 1, kv * g, d)), dtype)
+        lens = np.asarray([1, 3 * page - 2, pages * page])
+        pt = np.zeros((3, pages), np.int32)
+        pt[1, :3] = [n - 1, 7, n - 2]
+        pt[2] = n - 3 - np.arange(pages)
+        pos = jnp.asarray(lens[:, None] - 1, jnp.int32)
+        got = paged_attention(q, kp, vp, jnp.asarray(pt), pos,
+                              kernel="pallas", interpret=True)
+        _assert_within_tolerance(got, _reference(q, kp, vp, pt, pos), dtype)
+
+    def test_kernel_takes_decode_shapes_and_leaves_the_rest_to_lax(self):
+        assert kernel_path("pallas", t=1, quantized=False) == "pallas"
+        assert kernel_path("pallas", t=MAX_Q_TOKENS, quantized=False) \
+            == "pallas"
+        assert kernel_path("pallas", t=MAX_Q_TOKENS + 1,
+                           quantized=False) == "lax"
+        assert kernel_path("pallas", t=1, quantized=True) == "lax"
+        assert kernel_path("lax", t=1, quantized=False) == "lax"
+        # and the call does as the label says: a prefill-wide window and
+        # an int8 pool come back as the lax read's very bytes
+        rng = np.random.default_rng(5)
+        for t, quant in ((MAX_Q_TOKENS + 1, False), (1, True)):
+            q, kp, vp, pt, pos, side = _random_case(
+                rng, page=4, pages=8, b=2, t=t, kv=2, g=2, d=16,
+                dtype=jnp.bfloat16, quant=quant)
+            a, p = (paged_attention(q, kp, vp, pt, pos, kernel=k,
+                                    dtype=jnp.bfloat16, quant=side,
+                                    interpret=True)
+                    for k in ("lax", "pallas"))
+            assert bool(jnp.array_equal(a, p))
 
     def test_unknown_kernel_and_missing_dtype_rejected(self):
         rng = np.random.default_rng(3)
@@ -213,7 +340,9 @@ class TestModelPathBitExactness:
     """The three read paths of ``Attention._decode_step`` — legacy
     gather, native lax, native pallas — through the REAL model forward:
     prefill chunks, 1-token decode, and a gamma+1 verify window over
-    interleaved per-row positions."""
+    interleaved per-row positions. lax is the legacy read bit for bit;
+    the kernel (all three windows are decode-sized here) within the
+    written tolerance."""
 
     def _run_path(self, tiny_model, **over):
         cfg0, params = tiny_model
@@ -248,17 +377,20 @@ class TestModelPathBitExactness:
         for a, b in zip(legacy, native):
             assert bool(jnp.array_equal(a, b))
 
-    def test_native_pallas_bit_identical_to_legacy(self, tiny_model):
+    def test_native_pallas_logits_within_tolerance_of_legacy(
+            self, tiny_model):
         legacy = self._run_path(tiny_model)
         native = self._run_path(tiny_model, paged_attention_native=True,
                                 paged_kernel="pallas")
         for a, b in zip(legacy, native):
-            assert bool(jnp.array_equal(a, b))
+            _assert_within_tolerance(b, np.asarray(a, np.float32),
+                                     tiny_model[0].dtype, "logits")
 
     def test_quantized_output_is_kernel_independent(self, tiny_model):
         """int8 output diverges boundedly from fp but must NOT depend on
-        which kernel read the pool — legacy gather+dequant, lax, and
-        pallas all dequantize with the same (FMA-invariant) formula."""
+        which path read the pool — legacy gather+dequant and lax
+        dequantize with the same (FMA-invariant) formula, and a model
+        asked for the kernel reads an int8 pool through lax."""
         ql = self._run_path(tiny_model, kv_quant="int8")
         qn = self._run_path(tiny_model, kv_quant="int8",
                             paged_attention_native=True,
@@ -327,29 +459,72 @@ class TestNativeEngineOracle:
             eng.close()
 
     def test_native_pallas_spec_greedy_matches_oracle(self, tiny_model):
+        """The verify window (gamma + 1 = 4 positions a row) is the same
+        q tile as plain decode: tokens are the oracle's, or part from it
+        at a logit tie."""
         cfg, params = tiny_model
         want = [_oracle_tokens(cfg, params, p, 12) for p in self.PROMPTS]
         eng = PagedInferenceEngine(cfg, params, slots=4, page_size=8,
                                    native_attention=True, kernel="pallas",
                                    spec_tokens=3)
         try:
+            before = _metric_value(DISPATCHES, path="pallas")
             reqs = [eng.submit(p, max_new_tokens=12)
                     for p in self.PROMPTS]
             _drive(eng, *reqs)
-            assert [r.tokens for r in reqs] == want
+            for p, r, w in zip(self.PROMPTS, reqs, want):
+                _assert_same_or_tie(cfg, params, p, r.tokens, w)
             assert eng.stats().kernel_path == "pallas"
+            assert _metric_value(DISPATCHES, path="pallas") > before
         finally:
             eng.close()
 
+    def test_auto_is_the_kernel_and_counts_dispatches_by_path(
+            self, tiny_model):
+        """``"auto"`` serves decode through the Pallas kernel and prefill
+        chunks through lax, and ``lzy_kernel_dispatch_total`` says so: a
+        silent fall-back of decode to lax would show under ``lax``.
+        Greedy tokens are ``kernel="lax"``'s except at a logit tie."""
+        cfg, params = tiny_model
+        prompts = [list(range(1, 21)), [31, 9] * 9]
+
+        def run(kernel):
+            eng = PagedInferenceEngine(cfg, params, slots=2, page_size=8,
+                                       native_attention=True,
+                                       kernel=kernel)
+            try:
+                seen = {path: _metric_value(DISPATCHES, path=path)
+                        for path in ("pallas", "lax", "legacy")}
+                reqs = [eng.submit(p, max_new_tokens=self.N)
+                        for p in prompts]
+                _drive(eng, *reqs)
+                counts = {path: _metric_value(DISPATCHES, path=path) - n
+                          for path, n in seen.items()}
+                return eng.stats().kernel_path, counts, \
+                    [r.tokens for r in reqs]
+            finally:
+                eng.close()
+
+        path, counts, auto = run("auto")
+        assert path == default_kernel() == "pallas"
+        # two prompts of 20 and 18 tokens: one 32-wide chunk each
+        assert counts["lax"] == 2 and counts["legacy"] == 0
+        assert counts["pallas"] >= self.N - 1
+        path, counts, lax = run("lax")
+        assert path == "lax" and counts["pallas"] == 0
+        for p, a, b in zip(prompts, auto, lax):
+            _assert_same_or_tie(cfg, params, p, a, b)
+
     def test_native_sampled_matches_legacy_engine(self, tiny_model):
-        """Sampled rows share the engine-wide rng stream; the native
+        """Sampled rows share the engine-wide rng stream; the native lax
         path must not perturb a single draw."""
         cfg, params = tiny_model
 
         def sample_with(native):
             eng = PagedInferenceEngine(
                 cfg, params, slots=3, page_size=8, temperature=0.8,
-                seed=11, native_attention=native)
+                seed=11, native_attention=native,
+                kernel="lax" if native else "auto")
             try:
                 reqs = [eng.submit(p, max_new_tokens=10)
                         for p in self.PROMPTS]
@@ -407,6 +582,10 @@ class TestNativeEngineOracle:
         # misconfiguration, not a preference
         with pytest.raises(ValueError, match="native_attention"):
             PagedInferenceEngine(cfg, params, kernel="pallas")
+        # so is the kernel over a pool it does not read
+        with pytest.raises(ValueError, match="int8"):
+            PagedInferenceEngine(cfg, params, native_attention=True,
+                                 kernel="pallas", kv_quant="int8")
 
     def test_serve_flags_validated(self):
         from lzy_tpu.service.serve import main

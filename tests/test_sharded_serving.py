@@ -189,6 +189,20 @@ class TestConstruction:
             ShardedPagedInferenceEngine(cfg, params, tp=2,
                                         kernel="pallas")
 
+    def test_auto_kernel_is_lax_in_a_gang(self, tiny_model):
+        """The Pallas decode kernel is a custom call GSPMD cannot
+        partition: a gang's ``"auto"`` is the lax read, where a solo
+        engine's is the kernel."""
+        cfg, params = tiny_model
+        eng = ShardedPagedInferenceEngine(
+            cfg, params, tp=2, slots=2, page_size=PAGE,
+            native_attention=True, kernel="auto")
+        try:
+            assert eng.kernel_path == "lax"
+            assert eng.stats().kernel_path == "lax"
+        finally:
+            eng.close()
+
 
 class TestBitIdentity:
     def test_greedy_matches_oracle_and_single_engine(
