@@ -114,6 +114,44 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    # -- what a serving engine asks of a configuration (models/serving.py):
+    # methods and no field --------------------------------------------------
+
+    def serving_config(self) -> "LlamaConfig":
+        """This configuration with every training-only feature cleared."""
+        from lzy_tpu.models.generate import decode_config
+
+        return decode_config(self)
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that write the paged pool: what sizes it."""
+        return self.n_layers
+
+    def dense_models(self):
+        """``(decode model with a [slots] index, batch-1 prefill model)``
+        of the dense engine."""
+        return (Llama(dataclasses.replace(self, decode_slot_index=True)),
+                Llama(self))
+
+    def paged_model(self, *, page_size: int, kv_pages: int, native: bool,
+                    kernel: str, kv_quant: Optional[str], **module_kw):
+        """The module a paged engine runs, for decode rounds and batch-1
+        prefill alike. ``module_kw`` goes to the module (the sharded
+        engine's rule table)."""
+        return Llama(dataclasses.replace(
+            self, decode_paged=True, kv_page_size=page_size,
+            kv_pages=kv_pages, paged_attention_native=native,
+            paged_kernel=kernel, kv_quant=kv_quant), **module_kw)
+
+    def kernel_paths(self, t: int) -> tuple:
+        """``lzy_kernel_dispatch_total{path}`` labels beside the attention
+        read's own: this family has no other kernel."""
+        return ()
+
+    def check_kernels(self, *, slots: int) -> None:
+        """Nothing to lower beside the attention read."""
+
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
         return LlamaConfig()
@@ -371,15 +409,11 @@ class Attention(nn.Module):
             if cfg.decode_paged:
                 if page_table is None:
                     raise ValueError("decode_paged forward needs page_table")
-                page = cfg.kv_page_size
-                # scatter each (row, position) into its pool block; rows own
-                # their tail blocks exclusively, so real positions never
-                # collide — idle rows (pos 0, zeroed table) land on the
-                # reserved scratch block 0 and write only garbage over
-                # garbage
-                rows = jnp.take_along_axis(page_table, pos // page, axis=1)
-                offs = (pos % page).reshape(-1)
-                rows = rows.reshape(-1)
+                from lzy_tpu.ops.paged_attention import paged_scatter_index
+
+                # scatter each (row, position) into its pool block
+                rows, offs = paged_scatter_index(page_table, pos,
+                                                 cfg.kv_page_size)
                 flat_k = k.astype(cfg.dtype).reshape(b * t, kv_heads, d)
                 flat_v = v.astype(cfg.dtype).reshape(b * t, kv_heads, d)
                 if quant:
@@ -694,6 +728,12 @@ def _embed_lookup(table, tokens, *, one_hot: bool):
 
 class Llama(nn.Module):
     cfg: LlamaConfig
+    #: the kind of each cache leaf, by its name (``models/serving.py``):
+    #: every leaf but ``index`` is keys and values (or their int8 scales),
+    #: paged under the paged engine, a row a slot under the dense one
+    CACHE_KINDS = {"index": "index"}
+    #: the counters a ``stats`` collection would feed: this model sows none
+    STATS = ()
     #: frozen sharding-rule overrides (``parallel.sharding.freeze_rules``);
     #: threads the ACTIVE rule table into every activation anchor so a
     #: deployment with remapped rules doesn't get DEFAULT_RULES anchors

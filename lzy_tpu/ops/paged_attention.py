@@ -178,6 +178,19 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, zp: jax.Array,
 # -- lax oracle ------------------------------------------------------------------
 
 
+def paged_scatter_index(page_table: jax.Array, positions: jax.Array,
+                        page_size: int):
+    """Where each ``(row, position)`` of a ``[B, T]`` chunk lands in a
+    pool: ``(block ids, offsets)``, both ``[B * T]``, for
+    ``pool.at[blocks, offsets].set(values)``. Rows own their tail blocks
+    exclusively, so real positions never collide; an idle row (position 0,
+    zeroed table) and a position past a row's allocated blocks land on the
+    reserved scratch block 0 and write garbage over garbage. The one cache
+    write every model with a paged pool shares."""
+    blocks = jnp.take_along_axis(page_table, positions // page_size, axis=1)
+    return blocks.reshape(-1), (positions % page_size).reshape(-1)
+
+
 def _lax_paged_attention(q, k_pool, v_pool, page_table, positions, *,
                          dtype, quant: Optional[KVQuant]):
     """Gather-attention in EXACTLY the legacy op sequence. This is the

@@ -2,7 +2,11 @@
 
 The decode hot loop is ONE jitted step over a ``[slots, ...]`` KV cache
 whose per-row positions live in a ``[slots]`` cache index
-(``LlamaConfig.decode_slot_index``). Requests are admitted mid-flight:
+(``decode_slot_index`` of ``models/llama.py``'s configuration). Which
+modules a configuration runs is asked of the configuration object, and what
+kinds of cache leaves they keep of the module class (the protocol is
+written down in ``models/serving.py``): this file names no model. Requests
+are admitted mid-flight:
 
 - **prefill on arrival**: the prompt runs through the model as batch-1
   bucketed chunks (``models.generate.batched_prefill`` — one forward pass
@@ -60,10 +64,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from lzy_tpu.chaos.faults import CHAOS, CRASH, DELAY, ERROR, SLOW
+from lzy_tpu.models import serving
 from lzy_tpu.models.generate import (
-    _set_cache_index, decode_config, init_cache, make_prefill_step,
-    prefill_plan, sample_token)
-from lzy_tpu.models.llama import Llama, LlamaConfig
+    _set_cache_index, init_cache, make_prefill_step, prefill_plan,
+    sample_token)
 from lzy_tpu.serving.scheduler import (
     AdmissionError, PromptTooLong, Request, RequestQueue)
 from lzy_tpu.serving.tenancy import (
@@ -172,6 +176,21 @@ _PARKED_RELEASED = REGISTRY.counter(
     "(reason=repark|ttl|pressure|explicit|shutdown)")
 
 
+# per-slot state (models/serving.py, cache-leaf kind ``state``): a prefill
+# job of a model with state leaves starts from a zeroed batch-1 row and the
+# engine splices it into the slot's row when the prompt is done
+_STATE_RESETS = REGISTRY.counter(
+    "lzy_state_slots_reset_total",
+    "per-slot state rows started from zero for a newly admitted request "
+    "(models with state cache leaves)")
+
+
+class StateLeavesUnsupported(ValueError):
+    """A mechanism that shares, moves or rewinds cache by index and pages
+    was asked of a model whose cache has per-slot state leaves. The message
+    names the mechanism."""
+
+
 @dataclasses.dataclass
 class _PrefillJob:
     """One admitted request's in-progress prefill. With a
@@ -198,6 +217,10 @@ class _PrefillJob:
     # budget exists to keep short)
     tokens_dev: Any = None          # [1, len] prompt / suffix ids
     pt_dev: Any = None              # paged: [1, pages] page table
+    # state leaves (paged engine, models with per-slot state): the job's
+    # own batch-1 rows, carried from chunk to chunk — the slot's rows in
+    # the decode tree are not touched until the prompt is done
+    state: Any = None
 
 
 @dataclasses.dataclass
@@ -280,7 +303,7 @@ class InferenceEngine:
 
     def __init__(
         self,
-        cfg: LlamaConfig,
+        cfg: Any,
         params: Any,
         *,
         slots: int = 4,
@@ -305,7 +328,7 @@ class InferenceEngine:
         if prefill_budget is not None and prefill_budget < 1:
             raise ValueError(
                 f"prefill_budget must be >= 1, got {prefill_budget}")
-        base = decode_config(cfg)
+        base = cfg.serving_config()
         if spec_tokens + 1 >= base.max_seq_len:
             raise ValueError(
                 f"spec_tokens ({spec_tokens}) must leave room in "
@@ -423,16 +446,15 @@ class InferenceEngine:
         _SLOTS.set(float(slots))
         _BUSY.set(0.0)
 
-    def _build_decode_path(self, base: LlamaConfig) -> None:
+    def _build_decode_path(self, base: Any) -> None:
         """Construct models, caches and jitted steps (the paged engine
         overrides this with its pooled-cache counterparts)."""
         slots = self.slots
-        # decode model: [slots] per-row cache positions
-        self._model = Llama(dataclasses.replace(base, decode_slot_index=True))
+        # decode model: [slots] per-row cache positions; prefill model:
+        # batch-1, scalar index (what batched_prefill writes)
+        self._model, self._prefill_model = base.dense_models()
         self._adopt_cache(init_cache(lambda: self._model.init(
             jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32))))
-        # prefill model: batch-1, scalar index (what batched_prefill writes)
-        self._prefill_model = Llama(base)
         self._prefill_step = make_prefill_step(self._prefill_model)
         # abstract cache shapes ONCE: tracing the full model init on every
         # admission would sit directly on the TTFT path
@@ -496,9 +518,17 @@ class InferenceEngine:
         never round-tripped through a step at all."""
         flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
         self._cache_treedef = treedef
-        self._leaf_is_index = [self._is_index(p) for p, _ in flat]
+        self._leaf_kinds = [serving.leaf_kind(self._model, p)
+                            for p, _ in flat]
+        self._leaf_is_index = [k == serving.INDEX for k in self._leaf_kinds]
         self._payload = [leaf for (p, leaf), idx
                          in zip(flat, self._leaf_is_index) if not idx]
+        # which payload leaves are per-slot state ([slots, ...], one row a
+        # slot), by their place in the payload; the rest are keys and values
+        payload_kinds = [k for k in self._leaf_kinds if k != serving.INDEX]
+        self._state_at = [i for i, k in enumerate(payload_kinds)
+                          if k == serving.STATE]
+        self._has_state = bool(self._state_at)
 
     def _assemble_cache(self, payload, index_leaf):
         """Full cache tree from payload leaves + ONE index value placed
@@ -1092,6 +1122,16 @@ class InferenceEngine:
         _ROUND_FENCES.inc()
         return np.asarray(arr)
 
+    def _round_out(self):
+        """What the round's fence fetches: the next tokens (a model whose
+        layers sow counts returns them packed behind the tokens)."""
+        return self._cur_dev
+
+    def _note_model_stats(self, fetched: np.ndarray) -> np.ndarray:
+        """Split what the fence fetched into the ``[slots]`` tokens and
+        the model's counts, and add the counts to their counters."""
+        return fetched
+
     def _device_inputs(self):
         """The per-round jit inputs, device-resident across rounds.
         ``_cur_dev``/``_pos_dev`` are normally the previous step's own
@@ -1188,11 +1228,14 @@ class InferenceEngine:
             self._overlap_window()
         t2 = self._clock.now()
         with trace.span(trace.ENGINE_DECODE_FENCE):
-            nxt = self._fetch(self._cur_dev)   # the round's ONE fence
+            # the round's ONE fence: the tokens, and behind them whatever
+            # counts the model's layers carried out of the step
+            nxt = self._fetch(self._round_out())
         t3 = self._clock.now()
         dt = t3 - t0
         with trace.span(trace.ENGINE_DECODE_EMIT):
             _STEP.observe(dt)
+            nxt = self._note_model_stats(nxt)
             self._post_decode_step()
             emitted = rows = 0
             for slot, req in enumerate(self._active):
@@ -1676,7 +1719,7 @@ class PagedInferenceEngine(InferenceEngine):
 
     def __init__(
         self,
-        cfg: LlamaConfig,
+        cfg: Any,
         params: Any,
         *,
         slots: int = 4,
@@ -1697,7 +1740,7 @@ class PagedInferenceEngine(InferenceEngine):
             lower_pallas_for_tpu)
         from lzy_tpu.serving.kv_cache import RadixCache, blocks_for_bytes
 
-        base = decode_config(cfg)
+        base = cfg.serving_config()
         if page_size < 1 or base.max_seq_len % page_size:
             raise ValueError(
                 f"page_size ({page_size}) must divide max_seq_len "
@@ -1754,7 +1797,7 @@ class PagedInferenceEngine(InferenceEngine):
             kv_blocks = blocks_for_bytes(
                 kv_pool_bytes, page_size=page_size,
                 n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
-                n_layers=base.n_layers, dtype=base.dtype,
+                n_layers=base.kv_layers, dtype=base.dtype,
                 kv_quant=kv_quant)
         if kv_blocks is None:
             # dense-equivalent HBM by default (+1 scratch); pass less to
@@ -1771,6 +1814,7 @@ class PagedInferenceEngine(InferenceEngine):
                 n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
                 n_blocks=kv_blocks, page_size=page_size,
                 pages_per_seq=self._pages_per_seq, dtype=base.dtype)
+            base.check_kernels(slots=slots)
         self.kv = RadixCache(kv_blocks, page_size)
         # tiered KV cache (serving/kv_tier.py): radix eviction DEMOTES
         # block payloads to pinned host RAM (and onward to storage)
@@ -1828,7 +1872,41 @@ class PagedInferenceEngine(InferenceEngine):
         # per-row cached-token counts live in the base engine's _pos
         self._admit_seq = np.zeros((slots,), np.int64)  # admission order
         self._admissions = 0
+        self._packed_out = None      # tokens + model counts of this round
+        self._stat_counters: tuple = ()
+        self._dispatch_paths: dict = {}   # positions a row -> path labels
         super().__init__(cfg, params, slots=slots, **kwargs)
+        if self._has_state:
+            self._refuse_for_state()
+
+    def _refuse_for_state(self) -> None:
+        """A model with per-slot state leaves (``models/serving.py``):
+        what shares a prefix is turned off, what moves or rewinds cache by
+        index and pages is refused, by name."""
+        if self.spec_tokens > 0:
+            raise StateLeavesUnsupported(
+                f"speculative decoding (spec_tokens={self.spec_tokens}): a "
+                f"rejected draft is rewound by moving an index, and a "
+                f"per-slot state that has consumed it cannot be rewound")
+        if self.kv_tier is not None:
+            raise StateLeavesUnsupported(
+                "the tiered KV cache (kv_host_tier_bytes / kv_storage_tier "
+                "/ kv_tier): a demoted prefix is pages without the state "
+                "that belongs after them")
+        # a matched prefix would skip prefill for tokens whose recurrent
+        # state nobody kept: every match is 0 tokens and finished prompts
+        # are not inserted
+        self.kv.reuse = False
+        _LOG.info(
+            "%s keeps per-slot state: the radix prefix cache is off "
+            "(every match is 0 tokens, finished prompts are not inserted)",
+            type(self._model).__name__)
+
+    def _refuse_call_for_state(self, mechanism: str) -> None:
+        if self._has_state:
+            raise StateLeavesUnsupported(
+                f"{mechanism}: it moves or pins pages by a prefix's tokens, "
+                f"and this model's per-slot state is not in any page")
 
     # -- construction --------------------------------------------------------
 
@@ -1842,29 +1920,40 @@ class PagedInferenceEngine(InferenceEngine):
         return kernel_path(self._paged_kernel, t=t,
                            quantized=self._kv_quant is not None)
 
-    def _build_decode_path(self, base: LlamaConfig) -> None:
-        pcfg = dataclasses.replace(
-            base, decode_paged=True, kv_page_size=self._page,
-            kv_pages=self._kv_blocks,
-            paged_attention_native=self._native,
-            paged_kernel=self._paged_kernel, kv_quant=self._kv_quant)
+    def _build_decode_path(self, base: Any) -> None:
         slots, pages = self.slots, self._pages_per_seq
-        self._model = Llama(pcfg)
+        # one module for decode rounds and batch-1 prefill: prefill reuses
+        # the SAME pool arrays with a batch-1 index (and, where the model
+        # has them, the job's own batch-1 state rows)
+        self._model = self._prefill_model = base.paged_model(
+            page_size=self._page, kv_pages=self._kv_blocks,
+            native=self._native, kernel=self._paged_kernel,
+            kv_quant=self._kv_quant)
         dummy_pt = jnp.zeros((slots, pages), jnp.int32)
         self._adopt_cache(init_cache(lambda: self._model.init(
             jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
             page_table=dummy_pt)))
-        # prefill reuses the SAME pool arrays with a batch-1 index; only
-        # the index leaves differ between the two cache trees
-        self._prefill_model = Llama(pcfg)
+        self._build_steps()
 
+    def _build_steps(self) -> None:
+        """The jitted prefill, decode and verify programs over
+        ``self._model``. A model with state leaves is also told which
+        positions are real (``valid_len``): a padded prefill chunk ends at
+        its last real token, an idle slot (zeroed page table: block 0 is
+        the scratch block no row owns) has none."""
         import functools
+
+        self._stat_counters = tuple(type(self._model).STATS)
+        has_state, has_stats = self._has_state, bool(self._stat_counters)
+        mutable = ["cache", "stats"] if has_stats else ["cache"]
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def prefill_step(cache, params, tokens, page_table, last_idx):
+            real = {"valid_len": jnp.reshape(last_idx + 1, (1,))} \
+                if has_state else {}
             logits, updated = self._prefill_model.apply(
                 {"params": params, "cache": cache}, tokens,
-                page_table=page_table, mutable=["cache"])
+                page_table=page_table, mutable=["cache"], **real)
             last = jax.lax.dynamic_index_in_dim(
                 logits, last_idx, axis=1, keepdims=False)
             return updated["cache"], last
@@ -1874,14 +1963,33 @@ class PagedInferenceEngine(InferenceEngine):
         def decode_step(payload, params, cur, pos, page_table,
                         greedy_mask, rng):
             cache = self._assemble_cache(payload, pos)
+            real = {"valid_len": (page_table[:, 0] != 0).astype(jnp.int32)} \
+                if has_state else {}
             logits, updated = self._model.apply(
                 {"params": params, "cache": cache}, cur[:, None],
-                page_table=page_table, mutable=["cache"])
+                page_table=page_table, mutable=mutable, **real)
             nxt, rng = self._pick_next(logits[:, -1], greedy_mask, rng)
             payload, new_pos = self._split_cache(updated["cache"])
-            return payload, new_pos, nxt, rng
+            if not has_stats:
+                return payload, new_pos, nxt, rng
+            # the layers' counts ride behind the tokens in the one array
+            # the round's fence fetches
+            counts = sum(jax.tree_util.tree_leaves(updated["stats"]))
+            return payload, new_pos, nxt, rng, jnp.concatenate(
+                [nxt, counts.astype(jnp.int32)])
 
         self._decode_step = jax.jit(decode_step, donate_argnums=(0,))
+
+        if has_state:
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def splice_state(rows, job_rows, slot):
+                """Each state leaf's row ``slot`` becomes the job's
+                batch-1 row, in place."""
+                return [jax.lax.dynamic_update_slice_in_dim(
+                    big, small, slot, axis=0)
+                    for big, small in zip(rows, job_rows)]
+
+            self._splice_state = splice_state
 
         def verify_step(payload, params, cur, prop, prop_len, pos,
                         page_table, greedy_mask, rng):
@@ -1906,25 +2014,52 @@ class PagedInferenceEngine(InferenceEngine):
 
     # -- cache-tree plumbing -------------------------------------------------
 
-    def _pool_to_prefill(self, start: int):
+    def _pool_to_prefill(self, start: int, job: Optional[_PrefillJob] = None):
         """The decode cache tree re-skinned for a batch-1 prefill: pool
         k/v leaves move over unchanged (they are ABOUT to be donated —
         ``self._cache`` must not be touched until ``_merge_prefill``
-        replaces them), index leaves become ``[1]`` at ``start``."""
-        return jax.tree_util.tree_map_with_path(
-            lambda path, leaf: jnp.full((1,), start, jnp.int32)
-            if self._is_index(path) else leaf,
-            self._cache)
+        replaces them), index leaves become ``[1]`` at ``start``, and a
+        state leaf becomes the JOB's own batch-1 row: a state row belongs
+        to one slot, and the decode rounds interleaved with this prefill
+        see that slot as idle."""
+        state = iter(job.state) if self._has_state else None
+        leaves, payload = [], iter(self._payload)
+        for kind in self._leaf_kinds:
+            if kind == serving.INDEX:
+                leaves.append(jnp.full((1,), start, jnp.int32))
+                continue
+            leaf = next(payload)
+            leaves.append(next(state) if kind == serving.STATE else leaf)
+        return jax.tree_util.tree_unflatten(self._cache_treedef, leaves)
 
-    def _merge_prefill(self, pre_cache, slot: int, length: int) -> None:
-        """Fold a finished prefill back into the decode tree: pool k/v
+    def _merge_prefill(self, pre_cache, job: _PrefillJob,
+                       finished: bool) -> None:
+        """Fold a prefill round back into the decode tree: pool k/v
         leaves are taken from the prefill output (the decode tree's were
-        donated). Index state needs no splice — the ``_cache`` setter
-        discards the prefill tree's batch-1 index leaves and the host
-        ``_pos`` mirror (set by ``_finish_prefill``; 0 while the job is
-        mid-flight) is the single source of truth for positions."""
-        del slot, length
-        self._cache = pre_cache
+        donated). Index state needs no splice — the host ``_pos`` mirror
+        (set by ``_finish_prefill``; 0 while the job is mid-flight) is the
+        single source of truth for positions. State leaves stay with the
+        job until its prompt is done; then its batch-1 rows are spliced
+        into the slot's rows of the decode tree."""
+        if not self._has_state:
+            self._cache = pre_cache
+            return
+        leaves = jax.tree_util.tree_leaves(pre_cache)
+        job.state = [leaf for leaf, kind in zip(leaves, self._leaf_kinds)
+                     if kind == serving.STATE]
+        payload = [leaf for leaf, kind in zip(leaves, self._leaf_kinds)
+                   if kind != serving.INDEX]
+        for i in self._state_at:
+            payload[i] = self._payload[i]   # the decode tree keeps its rows
+        if finished:
+            with trace.span(trace.ENGINE_PREFILL_STATE):
+                rows = self._splice_state(
+                    [payload[i] for i in self._state_at], job.state,
+                    jnp.asarray(job.slot, jnp.int32))
+            for i, row in zip(self._state_at, rows):
+                payload[i] = row
+            job.state = None
+        self._payload = payload
 
     # -- admission / prefill -------------------------------------------------
 
@@ -2048,8 +2183,15 @@ class PagedInferenceEngine(InferenceEngine):
         # job completes — decode rounds interleaved with this prefill
         # must see the reserved slot as idle (its garbage writes land on
         # block 0), never on the job's half-written real blocks
+        state = None
+        if self._has_state:
+            # a reused slot starts from zero state: the job's own rows
+            state = [jnp.zeros((1,) + self._payload[i].shape[1:],
+                               self._payload[i].dtype)
+                     for i in self._state_at]
+            _STATE_RESETS.inc()
         return _PrefillJob(req=req, slot=slot, plan=plan, matched=matched,
-                           table=blocks + owned)
+                           table=blocks + owned, state=state)
 
     def _advance_prefill_round(self, job: _PrefillJob) -> bool:
         """One budgeted round of a PAGED prefill. The pool k/v leaves are
@@ -2073,7 +2215,7 @@ class PagedInferenceEngine(InferenceEngine):
             # chaos boundary: an injected error here is exactly a device
             # call dying mid-prefill — engine-fatal by construction
             CHAOS.hit("engine.prefill")
-            cache = self._pool_to_prefill(job.matched + job.done)
+            cache = self._pool_to_prefill(job.matched + job.done, job)
             if job.tokens_dev is None:
                 job.tokens_dev = jnp.asarray(
                     [req.prompt[job.matched:]], jnp.int32)
@@ -2082,7 +2224,7 @@ class PagedInferenceEngine(InferenceEngine):
                 # one program dispatch per CHUNK (a budgeted round may
                 # run several) — the dispatch counter must agree with
                 # the decode/verify paths' one-inc-per-program rule
-                self._dispatches.inc(path=self._path_of(tokens.shape[1]))
+                self._count_dispatch(tokens.shape[1])
                 return self._prefill_step(
                     c, self.params, tokens, pt,
                     jnp.asarray(take - 1, jnp.int32))
@@ -2090,10 +2232,10 @@ class PagedInferenceEngine(InferenceEngine):
             cache, finished = self._run_prefill_chunks(
                 job, cache, job.tokens_dev, run_chunk)
             if not finished:
-                self._merge_prefill(cache, job.slot, 0)
+                self._merge_prefill(cache, job, False)
                 return False
             first, self._rng = self._pick_first(job.last, req)
-            self._merge_prefill(cache, job.slot, t0)
+            self._merge_prefill(cache, job, True)
         except Exception as e:  # noqa: BLE001 — see PoolCorruption
             raise PoolCorruption(
                 f"paged prefill died mid-flight for {req.id}: "
@@ -2342,6 +2484,7 @@ class PagedInferenceEngine(InferenceEngine):
         :meth:`request_kv_export` — and the whole surface is advisory:
         False (nothing cached, timeout, shutdown) degrades the caller
         to the ordinary routed path."""
+        self._refuse_call_for_state("parking a conversation's chain")
         if self._closed:
             return False
         if self._thread is None:
@@ -2429,6 +2572,7 @@ class PagedInferenceEngine(InferenceEngine):
         """Enqueue a transferred prefix (``KVBlockExport``); applied
         between engine steps, strictly before admissions. Queue BEFORE
         submitting the request that wants it."""
+        self._refuse_call_for_state("KV import")
         with self._kv_io_lock:
             self._pending_imports.append(export)
         self.queue.work_available.set()     # wake a parked loop
@@ -2466,6 +2610,7 @@ class PagedInferenceEngine(InferenceEngine):
         buffers). Returns None on timeout, shutdown, or nothing cached
         — the caller (the gateway's cross-replica import) degrades to
         a local re-prefill."""
+        self._refuse_call_for_state("KV export")
         if self._closed:
             return None
         if self._thread is None:
@@ -2697,11 +2842,43 @@ class PagedInferenceEngine(InferenceEngine):
             self._pt_dev = jnp.array(self._tables)
         return self._pt_dev
 
+    def _count_dispatch(self, t: int) -> None:
+        """One program over ``t`` positions a row was dispatched: its
+        attention read's path, and the paths of the model's own kernels."""
+        paths = self._dispatch_paths.get(t)
+        if paths is None:
+            paths = self._dispatch_paths[t] = (
+                self._path_of(t),) + tuple(self.cfg.kernel_paths(t))
+        for path in paths:
+            self._dispatches.inc(path=path)
+
     def _run_decode_step(self):
         cur, pos, mask = self._device_inputs()
-        self._dispatches.inc(path=self.kernel_path)
-        return self._decode_step(self._payload, self.params, cur, pos,
-                                 self._page_table_dev(), mask, self._rng)
+        self._count_dispatch(1)
+        out = self._decode_step(self._payload, self.params, cur, pos,
+                                self._page_table_dev(), mask, self._rng)
+        if self._stat_counters:
+            *out, self._packed_out = out
+        return out
+
+    def _round_out(self):
+        return self._cur_dev if self._packed_out is None \
+            else self._packed_out
+
+    def _note_model_stats(self, fetched: np.ndarray) -> np.ndarray:
+        if self._packed_out is None:
+            return fetched
+        self._packed_out = None
+        counts = [int(n) for n in fetched[self.slots:]]
+        for counter, n in zip(self._stat_counters, counts):
+            counter.inc(n)
+        if trace.ON:
+            # on the round's emit span: what this very round's rows did,
+            # each count under its counter's name
+            trace.note(rows=sum(r is not None for r in self._active),
+                       model_stats={c.name: n for c, n in zip(
+                           self._stat_counters, counts)})
+        return fetched[:self.slots]
 
     def _run_verify_step(self, prop, prop_len):
         cur, pos, mask = self._device_inputs()
@@ -2714,6 +2891,14 @@ class PagedInferenceEngine(InferenceEngine):
         pt = jax.ShapeDtypeStruct((self.slots, self._pages_per_seq),
                                   jnp.int32)
         step.lower(payload, self.params, *mids, pt, mask, rng).compile()
+        if self._has_state and step is self._decode_step:
+            # the splice of a finished prefill's state rows, one program
+            # for every slot
+            rows = [payload[i] for i in self._state_at]
+            self._splice_state.lower(
+                rows, [jax.ShapeDtypeStruct((1,) + r.shape[1:], r.dtype)
+                       for r in rows],
+                jax.ShapeDtypeStruct((), jnp.int32)).compile()
 
     # -- speculative decode over the block pool -------------------------------
 

@@ -186,6 +186,11 @@ class RadixCache:
         self.evictions = 0
         self.hit_tokens = 0
         self.lookup_tokens = 0
+        # False for a model that keeps per-slot state beside its pages
+        # (models/serving.py): a matched prefix would skip prefill for
+        # tokens whose state nobody kept, so every lookup finds nothing
+        # (and still counts as a lookup) and nothing is inserted
+        self.reuse = True
         # tier hooks (serving/kv_tier.py): ``on_evict(chain_tokens,
         # block, origin)`` fires BEFORE an evicted leaf's block returns
         # to the free list — the engine's demotion hook gathers the
@@ -220,6 +225,8 @@ class RadixCache:
         and the export path."""
         node = self._root
         out: List[_Node] = []
+        if not self.reuse:
+            return out
         for chunk in self._chunks(tokens):
             child = node.children.get(chunk)
             if child is None:
@@ -279,6 +286,8 @@ class RadixCache:
         ``origin`` tags NEWLY created nodes with the remote producer of
         their KV (a disagg prefill replica id); existing nodes keep their
         provenance (whoever computed the resident bytes)."""
+        if not self.reuse:
+            return 0
         self._clock += 1
         node = self._root
         created = 0

@@ -9,6 +9,7 @@ devices; one dead host fails over the whole gang.
 
 from lzy_tpu.serving.sharded.engine import (
     GangHostDead,
+    NoPartitionRules,
     ShardedPagedInferenceEngine,
 )
 from lzy_tpu.serving.sharded.partition import (
@@ -20,6 +21,7 @@ from lzy_tpu.serving.sharded.partition import (
 
 __all__ = [
     "GangHostDead",
+    "NoPartitionRules",
     "SERVE_RULES",
     "ShardedPagedInferenceEngine",
     "pool_leaf_sharding",

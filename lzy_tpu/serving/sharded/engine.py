@@ -35,7 +35,6 @@ chunked prefill, speculation) is inherited verbatim. The contract:
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import threading
 from typing import Any, List, Optional, Tuple
@@ -46,11 +45,16 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from lzy_tpu.models.generate import init_cache
-from lzy_tpu.models.llama import Llama, LlamaConfig
+from lzy_tpu.models.llama import LlamaConfig
 from lzy_tpu.serving.engine import PagedInferenceEngine
 from lzy_tpu.serving.sharded import metrics as _m
 from lzy_tpu.serving.sharded.partition import (
     SERVE_RULES, pool_leaf_sharding, serve_mesh_for, shard_params)
+
+
+class NoPartitionRules(ValueError):
+    """The sharded engine was given a model family it has no partition
+    rules for."""
 
 
 class GangHostDead(RuntimeError):
@@ -70,6 +74,12 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
 
     def __init__(self, cfg: LlamaConfig, params: Any, *,
                  mesh: Optional[Mesh] = None, tp: int = 2, **kwargs):
+        if not isinstance(cfg, LlamaConfig):
+            raise NoPartitionRules(
+                f"the sharded engine (ShardedPagedInferenceEngine) "
+                f"partitions models/llama.py's pool and projections over "
+                f"tp (SERVE_RULES, pool_leaf_sharding) and has no rule for "
+                f"{type(cfg).__name__}'s modules")
         if mesh is None:
             mesh = serve_mesh_for(tp)
         tp = int(mesh.shape["tp"])
@@ -140,13 +150,11 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
         # pages). Donation only buys back HBM, so it stays TPU/GPU-only.
         donate = {"donate_argnums": (0,)} \
             if mesh.devices.flat[0].platform != "cpu" else {}
-        pcfg = dataclasses.replace(
-            base, decode_paged=True, kv_page_size=self._page,
-            kv_pages=self._kv_blocks,
-            paged_attention_native=self._native,
-            paged_kernel=self._paged_kernel, kv_quant=self._kv_quant)
         slots, pages = self.slots, self._pages_per_seq
-        self._model = Llama(pcfg, rules=SERVE_RULES)
+        self._model = self._prefill_model = base.paged_model(
+            page_size=self._page, kv_pages=self._kv_blocks,
+            native=self._native, kernel=self._paged_kernel,
+            kv_quant=self._kv_quant, rules=SERVE_RULES)
         dummy_pt = jnp.zeros((slots, pages), jnp.int32)
         # init meshless (anchors no-op without a mesh), THEN place: the
         # pool shards on kv_heads, index leaves and params replicate
@@ -161,7 +169,6 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
         self._adopt_cache(cache)
         self.params = shard_params(self.params, mesh)
         self._payload_shardings = [leaf.sharding for leaf in self._payload]
-        self._prefill_model = Llama(pcfg, rules=SERVE_RULES)
 
         @functools.partial(jax.jit, **donate)
         def prefill_step(cache, params, tokens, page_table, last_idx):
@@ -240,7 +247,7 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
                 np.array(self._tables), self._repl)
         return self._pt_dev
 
-    def _pool_to_prefill(self, start: int):
+    def _pool_to_prefill(self, start: int, job=None):
         """Same re-skin as the paged base, with the batch-1 index leaves
         committed replicated so the donated prefill cache tree is
         uniformly mesh-placed. A FRESH buffer per index leaf — the whole

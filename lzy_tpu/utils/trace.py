@@ -78,6 +78,10 @@ ENGINE_REAP = "engine.reap"
 ENGINE_ADMIT = "engine.admit"
 ENGINE_PREFILL = "engine.prefill"
 ENGINE_PREFILL_FENCE = "engine.prefill.fence"
+# a child of engine.prefill, not a phase of its own (no phase label, no
+# profiler annotation): the splice of a finished prefill's per-slot state
+# rows into the decode tree (models with state cache leaves)
+ENGINE_PREFILL_STATE = "engine.prefill.state"
 ENGINE_DECODE_PLAN = "engine.decode.plan"
 ENGINE_DECODE_DISPATCH = "engine.decode.dispatch"
 ENGINE_DECODE_OVERLAP = "engine.decode.overlap"

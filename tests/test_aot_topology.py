@@ -289,3 +289,40 @@ def test_paged_decode_step_compiles_at_full_width(one_chip, monkeypatch):
     # gather of every row's whole table is not
     assert engine.kernel_path == "pallas"
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the Nemotron-H serving kernels at published widths ----------------------
+
+@pytest.mark.parametrize("rows", [64, 8], ids=["decode", "chunk8"])
+def test_grouped_experts_compiles_at_published_widths(rows, one_chip):
+    """128 held experts of 1024 x 2688 in bfloat16: a decode round's 64
+    rows and the narrowest prefill chunk."""
+    from lzy_tpu.ops import grouped_experts as gexp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    jax.jit(lambda x, a, b, w: gexp.grouped_experts(
+        x, a, b, w, interpret=False)).lower(
+        sds((rows, 1024), jnp.bfloat16),
+        sds((128, 1024, 2688), jnp.bfloat16),
+        sds((128, 2688, 1024), jnp.bfloat16),
+        sds((rows, 128), jnp.float32)).compile()
+
+
+def test_ssm_state_update_compiles_at_published_widths(one_chip):
+    """64 slots x 128 heads x 64 x 128 float32 of state, updated in place:
+    the donated state is the output's buffer (no second 268 MB copy)."""
+    from lzy_tpu.ops import mamba2
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda s, x, dt, a, b, c: mamba2.ssm_state_update(
+            s, x, dt, a, b, c, interpret=False),
+        donate_argnums=(0,)).lower(
+        sds((64, 128, 64, 128)), sds((64, 128, 64)), sds((64, 128)),
+        sds((128,)), sds((64, 8, 128)), sds((64, 8, 128))).compile()
+    state_bytes = 64 * 128 * 64 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes // 8

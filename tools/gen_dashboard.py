@@ -44,6 +44,10 @@ def registry_metrics():
     # native paged-attention kernels: dispatches by path, quantized
     # blocks resident, dequant-error EWMA (lzy_kernel_*)
     import lzy_tpu.ops.paged_attention  # noqa: F401
+    # a model with routed experts and per-slot state: assignments, held
+    # assignments, experts touched / held a decode round (lzy_moe_*);
+    # state rows zeroed for a new request is the engine's (lzy_state_*)
+    import lzy_tpu.models.nemotron_h  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
