@@ -139,9 +139,9 @@ class DisaggGatewayService(GatewayService):
         self._meta()["kv_used_from"] = getattr(req, "kv_prefilled_by",
                                                None)
 
-    def _reply_extras(self) -> dict:
+    def _reply_extras(self, gated: bool = True) -> dict:
         meta = self._meta()
-        out = super()._reply_extras()
+        out = super()._reply_extras(gated)
         out.update({
             # the prefill replica whose KV the final serving attempt
             # actually USED (its imported blocks matched at prefill) —

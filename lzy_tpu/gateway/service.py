@@ -34,7 +34,7 @@ from lzy_tpu.gateway.fleet import ReplicaFleet
 from lzy_tpu.gateway.router import PrefixAffinityRouter
 from lzy_tpu.serving.scheduler import (
     AdmissionError, DEFAULT_TENANT, PromptTooLong, QuotaExceeded,
-    any_to_tokens, quota_error, shed_error)
+    any_to_tokens, plane_capacity, quota_error, shed_error)
 from lzy_tpu.utils import trace
 from lzy_tpu.utils.log import get_logger
 from lzy_tpu.utils.metrics import REGISTRY
@@ -145,6 +145,7 @@ class GatewayService:
         self.slo = slo
         self._max_failovers = max_failovers
         self._tick_period_s = tick_period_s
+        self._max_waiters = int(max_waiters)
         self._waiters = threading.BoundedSemaphore(max_waiters)
         self._failovers = 0
         self._finished = 0
@@ -442,6 +443,7 @@ class GatewayService:
 
         t0 = self._clock.now()
         wall_deadline = t0 + timeout_s
+        gated = liveness is None        # it holds one of the unary waiters
         fence = (self.fence_auditor.session(prompt)
                  if self.fence_auditor is not None else None)
         # fenced: already streamed tokens. A crash-recovery resubmission
@@ -485,7 +487,7 @@ class GatewayService:
                     "model": self.model_name,
                     "replica": route[0] if route else None,
                     "routed_by": route[1] if route else None,
-                    "failovers": failovers, **self._reply_extras()}
+                    "failovers": failovers, **self._reply_extras(gated)}
             deadline_left = self._remaining_deadline(t0, deadline_s)
             if deadline_left is not None and deadline_left <= 0:
                 # the client deadline ran out between attempts: finish
@@ -505,7 +507,7 @@ class GatewayService:
                     "model": self.model_name,
                     "replica": route[0] if route else None,
                     "routed_by": route[1] if route else None,
-                    "failovers": failovers, **self._reply_extras()}
+                    "failovers": failovers, **self._reply_extras(gated)}
             effective_prompt = prompt + emitted
             with trace.span(trace.GATEWAY_ATTEMPT) as attempt:
                 replica, routed_by, req = self._submit_routed(
@@ -622,7 +624,7 @@ class GatewayService:
                 "replica": route[0],
                 "routed_by": route[1],
                 "failovers": failovers,
-                **self._reply_extras(),
+                **self._reply_extras(gated),
             }
         # emitted already covers max_new_tokens (failover landed exactly
         # on the boundary): the stream is complete
@@ -638,7 +640,7 @@ class GatewayService:
                 "replica": route[0] if route else None,
                 "routed_by": route[1] if route else None,
                 "failovers": failovers,
-                **self._reply_extras()}
+                **self._reply_extras(gated)}
 
     @staticmethod
     def _client_gone(liveness) -> bool:
@@ -1074,12 +1076,18 @@ class GatewayService:
             self._kvtier_meta()["kv_used_from"] = getattr(
                 req, "kv_prefilled_by", None)
 
-    def _reply_extras(self) -> dict:
+    def _reply_extras(self, gated: bool = True) -> dict:
         """Extra route metadata merged into every reply — subclasses
         extend (the disagg gateway adds ``prefilled_by`` /
         ``kv_transfer_ms``); unknown reply fields are preserved by older
-        clients (proto3 rule). With the global KV index on, replies
-        carry the cross-replica import provenance: ``kv_import_from``
+        clients (proto3 rule). Every reply says what the plane holds as
+        this call saw it (``scheduler.plane_capacity``): ``plane_slots``,
+        the READY replicas' slots, and for a ``gated`` call (no
+        ``liveness``: it held one of the unary waiters)
+        ``plane_admits``, the waiter cap, which also bounds the slots. A
+        caller that keeps many calls in flight sizes itself by them
+        (``llm/sched.py``'s window of rows). With the global KV index on,
+        replies carry the cross-replica import provenance: ``kv_import_from``
         is the sibling whose KV the serving attempt actually USED (its
         imported blocks matched at prefill — None when the attempt hit
         purely-local KV or re-prefilled), ``kv_import_staged_from`` the
@@ -1088,15 +1096,21 @@ class GatewayService:
         under pool pressure silently re-prefills), ``kv_import_tier``
         the rung the source exported from, and ``kv_import_ms`` the
         staging latency."""
+        # one walk over the replicas a reply, on the caller's thread:
+        # stats() reads counters and takes no lock a round holds
+        out = plane_capacity(
+            sum(r.engine.stats().slots for r in self.fleet.replicas()),
+            self._max_waiters if gated else None)
         if self.kv_index is None:
-            return {}
+            return out
         meta = self._kvtier_meta()
-        return {
+        out.update({
             "kv_import_from": meta.get("kv_used_from"),
             "kv_import_staged_from": meta.get("kv_import_staged_from"),
             "kv_import_tier": meta.get("kv_import_tier"),
             "kv_import_ms": meta.get("kv_import_ms"),
-        }
+        })
+        return out
 
     def _note_failover(self) -> None:
         with self._lock:

@@ -204,9 +204,12 @@ class EngineBackend:
         if req.first_token_at is not None:
             ttft_ms = round(1000 * (req.first_token_at
                                     - req.submitted_at), 3)
+        from lzy_tpu.serving.scheduler import plane_capacity
+
         return {"request_id": req.id, "tokens": list(req.tokens),
                 "status": req.status or "ok", "ttft_ms": ttft_ms,
-                "model": self.model_name}
+                "model": self.model_name,
+                **plane_capacity(self.engine.stats().slots)}
 
 
 _lock = threading.Lock()

@@ -83,3 +83,29 @@ SPECULATIONS = REGISTRY.counter(
     "lzy_wfsched_speculations_total",
     "speculative next-step prefills, by outcome "
     "(outcome=ok|miss|timeout|error|no_lease)")
+
+# -- the window of rows in flight (WorkflowScheduler.map) ---------------------
+
+#: rows ``llm.generate_batch`` may keep inside the backend at once: 16
+#: until a reply has said what the plane holds, then what follows from
+#: its ``plane_slots`` / ``plane_admits``
+ROW_WINDOW = REGISTRY.gauge(
+    "lzy_wfsched_row_window",
+    "width in force of the window of generate_batch rows in flight")
+
+#: rows inside the window now (over the width for a while after the
+#: plane reported fewer slots: rows in flight are never cancelled)
+ROWS_IN_FLIGHT = REGISTRY.gauge(
+    "lzy_wfsched_rows_in_flight",
+    "generate_batch rows inside the window (handed to the backend)")
+
+#: seconds a row waited between its hand-over and its entry, by the
+#: width in force when it entered — the always-on twin of the
+#: ``llm.row.pool_wait`` span. A row that entered at once observes
+#: exactly 0: the share above the ``le="0.0"`` bucket is how often the
+#: window held a row back
+ROW_WINDOW_WAIT = REGISTRY.histogram(
+    "lzy_wfsched_row_window_wait_seconds",
+    "seconds a generate_batch row waited for the window, by the width "
+    "in force at its entry (window=<rows>)",
+    buckets=(0.0, 0.001, 0.01, 0.1, 1.0, 10.0, 60.0, 600.0))

@@ -5,7 +5,7 @@ Every ``llm.generate`` body used to dispatch straight at the resolved
 backend — one call, one route, one engine request, however many
 concurrent workflow runs were asking. This module is the seam the
 workflow→serving traffic flows through instead, and it is serving-aware
-in three composing ways:
+in four composing ways:
 
 - **Admission fan-in + in-flight dedup** (:meth:`WorkflowScheduler.
   dispatch`): calls from different concurrent workflow runs coalesce
@@ -37,6 +37,33 @@ in three composing ways:
   prefill); wrong speculations are released uncounted as cache
   pollution once the pin lapses.
 
+- **A window of rows in flight** (:meth:`WorkflowScheduler.map`, what
+  ``llm.generate_batch`` rides): rows are handed over in the order they
+  were asked for, first come first served over every concurrent batch
+  of the process, and ``width`` of them are inside the backend at once,
+  a blocked thread each. The width is read from the replies: every
+  reply of a plane that knows of it carries ``plane_slots`` (the ready
+  replicas' slots as that call saw them) and, for a call the front
+  gates, ``plane_admits`` (its unary waiter cap, which also bounds the
+  slots): ``serving.scheduler.plane_capacity``, added by both gateways,
+  ``InferenceService`` and ``EngineBackend``, and carried untouched by
+  ``RpcInferenceClient`` and by any proxy that forwards ``generate``.
+  Width = the slots plus as many rows again queued behind them (a
+  freed slot then finds its next row in the engine's queue, where WFQ,
+  deadlines and the router see it), never over ``plane_admits``. Until
+  a reply has carried the field the width is 16 — what the fixed pool
+  of threads this replaced allowed, and ``GatewayService``'s default
+  waiter cap — so an old plane or a test double is served as before. A
+  later reply that reports fewer slots (a replica drained or lost)
+  narrows it: rows inside stay, new ones wait. A shed row
+  (``waiters_busy``, an ``AdmissionError``) is an exception, carries
+  no reply, and so never widens it; ``DISPATCH_RETRIES_POLICY`` retries
+  it as before. No option, flag or variable sets the width. Gauges
+  ``lzy_wfsched_row_window`` / ``lzy_wfsched_rows_in_flight``;
+  histogram ``lzy_wfsched_row_window_wait_seconds{window=<width>}``
+  (the always-on twin of the ``llm.row.pool_wait`` span; a row that
+  entered at once observes 0).
+
 Flags (read at scheduler construction — i.e. per ``llm.configure``):
 ``LZY_WFSCHED_DEDUP``, ``LZY_WFSCHED_FUSE``, ``LZY_WFSCHED_SPECULATE``
 (all default on), ``LZY_WFSCHED_PARK_TTL_S`` (gateway default when
@@ -45,10 +72,13 @@ unset).
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
-from typing import Any, Dict, List, Optional
+from collections import deque
+from typing import Any, Deque, Dict, List, Optional
 
+from lzy_tpu.llm import metrics
 from lzy_tpu.utils import trace
 from lzy_tpu.utils.log import get_logger
 
@@ -71,13 +101,50 @@ def _flag(name: str, default: bool) -> bool:
     return raw.strip().lower() not in ("0", "false", "no", "off", "")
 
 
-def _traced_row(fn, item, parent, handed: float):
-    """One row of :meth:`WorkflowScheduler.map` on its pool thread:
-    ``llm.row`` runs from the hand-over, ``llm.row.pool_wait`` is the part
-    before the function started."""
-    with trace.span(trace.LLM_ROW, parent=parent, start=handed):
-        trace.emit(trace.LLM_ROW_POOL_WAIT, handed, trace.now())
-        return fn(item)
+#: rows in flight until a reply has said what the plane holds: the size of
+#: the thread pool this window replaced, which is ``GatewayService``'s
+#: default waiter cap — an old plane, or a test double, is served as before
+_DEFAULT_WINDOW = 16
+#: rows queued behind each slot the plane reports, so that a freed slot
+#: finds its next row in the engine's queue (where WFQ, deadlines and the
+#: router see it) and not behind a thread hand-over. Measured on the chip
+#: at 0, 0.5 and 1 (PERF.md section 6, PR 30)
+_BACKLOG_PER_SLOT = 1.0
+
+
+class _Row:
+    """One row of :meth:`WorkflowScheduler.map`, from its hand-over to
+    its result."""
+
+    __slots__ = ("fn", "item", "parent", "traced", "handed", "waited",
+                 "width", "done", "result", "error")
+
+    def __init__(self, fn, item, parent, traced: bool, handed: float):
+        self.fn, self.item = fn, item
+        #: the caller's open span and whether the recorder was on at the
+        #: hand-over: ``llm.row`` runs from ``handed``
+        self.parent, self.traced, self.handed = parent, traced, handed
+        #: set at entry: seconds waited for the window, and its width then
+        self.waited, self.width = 0.0, 0
+        self.done = threading.Event()
+        self.result: Any = None
+        self.error: Optional[BaseException] = None
+
+    def run(self) -> None:
+        try:
+            if self.traced:
+                with trace.span(trace.LLM_ROW, parent=self.parent,
+                                start=self.handed):
+                    # the part before the function started
+                    trace.emit(trace.LLM_ROW_POOL_WAIT, self.handed,
+                               trace.now())
+                    self.result = self.fn(self.item)
+            else:
+                self.result = self.fn(self.item)
+        except BaseException as e:  # noqa: BLE001 — re-raised by map()
+            self.error = e
+        finally:
+            self.done.set()
 
 
 class _InFlight:
@@ -104,8 +171,7 @@ class WorkflowScheduler:
                  dedup: Optional[bool] = None,
                  fuse: Optional[bool] = None,
                  speculate: Optional[bool] = None,
-                 park_ttl_s: Optional[float] = None,
-                 max_workers: int = 16):
+                 park_ttl_s: Optional[float] = None):
         self.backend = backend
         self.dedup = _flag("LZY_WFSCHED_DEDUP", True) \
             if dedup is None else bool(dedup)
@@ -118,7 +184,6 @@ class WorkflowScheduler:
             park_ttl_s = float(raw) if raw else None
         #: None = the gateway's own default TTL
         self.park_ttl_s = park_ttl_s
-        self._max_workers = max(1, int(max_workers))
         self._lock = threading.Lock()
         self._inflight: Dict[tuple, _InFlight] = {}
         #: session -> in-flight fusion future (park + speculative
@@ -129,23 +194,18 @@ class WorkflowScheduler:
         self._parks = 0
         self._speculations = 0
         self._closed = False
-        # two pools, deliberately: batch fan-out rides the (bounded)
-        # plane pool, fusion/speculation tasks ride their own small one
-        # — a saturating generate_batch must not queue a speculation
-        # behind itself and then wait on it from dispatch()
-        self._pool = None
+        #: the window of rows in flight (:meth:`map`): rows handed over
+        #: and not yet let in, in hand-over order over every concurrent
+        #: batch; how many are inside; how many may be
+        self._waiting: Deque[_Row] = deque()
+        self._rows_in_flight = 0
+        self._width = _DEFAULT_WINDOW
+        self._row_threads = itertools.count()
+        # fusion/speculation tasks ride a small pool of their own — a
+        # saturating generate_batch must not queue a speculation behind
+        # itself and then wait on it from dispatch()
         self._fuse_pool = None
-
-    # -- the plane ------------------------------------------------------------
-
-    def _plane(self):
-        with self._lock:
-            if self._pool is None:
-                from concurrent.futures import ThreadPoolExecutor
-
-                self._pool = ThreadPoolExecutor(
-                    self._max_workers, thread_name_prefix="lzy-wfsched")
-            return self._pool
+        metrics.ROW_WINDOW.set(self._width)
 
     def _fusion_pool(self):
         with self._lock:
@@ -156,34 +216,94 @@ class WorkflowScheduler:
                     4, thread_name_prefix="lzy-wfsched-fuse")
             return self._fuse_pool
 
+    # -- the window of rows in flight -----------------------------------------
+
     def map(self, fn, items: List[Any]) -> List[Any]:
-        """Order-preserving fan-out over the shared plane pool — what
-        ``llm.generate_batch`` rides instead of a private per-call
-        thread pool. Items run ``fn`` concurrently (each lands back in
+        """Order-preserving fan-out through the window — what
+        ``llm.generate_batch`` rides. Rows are handed over in the order
+        they were asked for, first come first served over every
+        concurrent batch of the process, and ``width`` of them run ``fn``
+        at once, each on a thread of its own (each lands back in
         :meth:`dispatch`, so in-flight dedup applies within the fan-out
         too); the first exception propagates after all rows settle."""
         if not items:
             return []
-        if trace.ON:
-            # each item carries the caller's open span and the time it was
-            # handed over: the row's wait for one of the pool's threads
-            parent = trace.context()
-            futures = [self._plane().submit(_traced_row, fn, item, parent,
-                                            trace.now())
-                       for item in items]
-        else:
-            futures = [self._plane().submit(fn, item) for item in items]
+        # each row carries the caller's open span and the time it was
+        # handed over: its wait for the window is llm.row.pool_wait
+        traced = trace.ON
+        parent = trace.context() if traced else None
+        handed = trace.now()
+        rows = [_Row(fn, item, parent, traced, handed) for item in items]
+        with self._lock:
+            self._waiting.extend(rows)
+            entering = self._enter_locked(handed)
+        self._launch(entering)
         results, first_err = [], None
-        for fut in futures:
-            try:
-                results.append(fut.result())
-            except BaseException as e:  # noqa: BLE001 — re-raised below
-                results.append(None)
-                if first_err is None:
-                    first_err = e
+        for row in rows:
+            row.done.wait()
+            results.append(row.result)
+            if row.error is not None and first_err is None:
+                first_err = row.error
         if first_err is not None:
             raise first_err
         return results
+
+    def _enter_locked(self, now: float, everything: bool = False
+                      ) -> List[_Row]:
+        """Rows the window lets in now, in hand-over order (the caller
+        holds the lock, and launches them after releasing it)."""
+        entering: List[_Row] = []
+        while self._waiting and (everything
+                                 or self._rows_in_flight < self._width):
+            row = self._waiting.popleft()
+            row.waited, row.width = max(0.0, now - row.handed), self._width
+            self._rows_in_flight += 1
+            entering.append(row)
+        return entering
+
+    def _launch(self, rows: List[_Row]) -> None:
+        for row in rows:
+            threading.Thread(
+                target=self._run_rows, args=(row,), daemon=True,
+                name=f"lzy-wfsched-{next(self._row_threads)}").start()
+
+    def _run_rows(self, row: Optional[_Row]) -> None:
+        """A row's thread: runs it, then the row its leaving lets in (a
+        blocked thread a row and no executor: no thread idles between
+        rows, and none is left to shut down)."""
+        while row is not None:
+            metrics.ROWS_IN_FLIGHT.add(1)
+            metrics.ROW_WINDOW_WAIT.observe(row.waited,
+                                            window=str(row.width))
+            row.run()
+            metrics.ROWS_IN_FLIGHT.add(-1)
+            with self._lock:
+                self._rows_in_flight -= 1
+                entering = self._enter_locked(trace.now())
+            row = entering.pop(0) if entering else None
+            self._launch(entering)
+
+    def _follow(self, reply: Any) -> None:
+        """The window's width follows what the plane says it holds
+        (``scheduler.plane_capacity``, in every reply of a plane that
+        knows of it): its slots plus a backlog behind them, within what
+        it admits without shedding. A reply without the field changes
+        nothing, nor does an error: a shed row never widens the window.
+        A smaller report narrows it: rows inside stay, new ones wait."""
+        slots = reply.get("plane_slots") if isinstance(reply, dict) else None
+        if type(slots) is not int or slots < 1:
+            return
+        width = slots + int(_BACKLOG_PER_SLOT * slots)
+        admits = reply.get("plane_admits")
+        if type(admits) is int and admits >= 1:
+            width = min(width, admits)
+        if width == self._width:
+            return
+        with self._lock:
+            self._width = width
+            entering = self._enter_locked(trace.now())
+        metrics.ROW_WINDOW.set(width)
+        self._launch(entering)
 
     # -- admission fan-in + in-flight dedup -----------------------------------
 
@@ -200,8 +320,6 @@ class WorkflowScheduler:
         calls dedup against identical in-flight twins; everything else
         passes straight through (one call, one engine request — exactly
         the pre-scheduler contract)."""
-        from lzy_tpu.llm import metrics
-
         if session is not None:
             # fused ordering: if this conversation's speculative prefill
             # is still running, wait briefly — the speculation IS this
@@ -212,7 +330,7 @@ class WorkflowScheduler:
             self._dispatches += 1
 
         def call() -> dict:
-            return self.backend.generate(
+            reply = self.backend.generate(
                 prompt_tokens,
                 max_new_tokens=max_new_tokens,
                 timeout_s=timeout_s,
@@ -222,6 +340,8 @@ class WorkflowScheduler:
                 priority=priority,
                 session=session,
                 stream=stream)
+            self._follow(reply)
+            return reply
 
         if not (self.dedup and greedy is True and stream is None):
             with trace.span(trace.LLM_DISPATCH, role="solo"):
@@ -310,8 +430,6 @@ class WorkflowScheduler:
         priority. Returns the in-flight future (tests drain it), or
         None when fusion does not apply. Never blocks the op body and
         never raises."""
-        from lzy_tpu.llm import metrics
-
         if not self.fuse or session is None or self._closed:
             return None
         svc = getattr(self.backend, "service", None)
@@ -337,8 +455,6 @@ class WorkflowScheduler:
 
     def _fuse_step(self, svc, session: str, tokens: List[int],
                    tenant: Optional[str]) -> bool:
-        from lzy_tpu.llm import metrics
-
         try:
             if self.park_ttl_s is not None:
                 ok = svc.park_conversation(session, tokens,
@@ -403,15 +519,21 @@ class WorkflowScheduler:
                 "parks": self._parks,
                 "speculations": self._speculations,
                 "spec_inflight": len(self._spec),
+                "row_window": self._width,
+                "rows_in_flight": self._rows_in_flight,
+                "rows_waiting": len(self._waiting),
             }
 
     def close(self) -> None:
+        """Ends fusion and lets every waiting row in at once: a batch
+        handed over before a reconfigure is not held behind rows of a
+        plane that may never answer (nothing in flight is cancelled)."""
         with self._lock:
             self._closed = True
-            pools = [p for p in (self._pool, self._fuse_pool)
-                     if p is not None]
-            self._pool = self._fuse_pool = None
-        for pool in pools:
+            pool, self._fuse_pool = self._fuse_pool, None
+            entering = self._enter_locked(trace.now(), everything=True)
+        self._launch(entering)
+        if pool is not None:
             pool.shutdown(wait=False)
 
 

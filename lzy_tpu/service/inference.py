@@ -19,7 +19,7 @@ from typing import Any, Optional
 
 from lzy_tpu.serving.scheduler import (
     AdmissionError, DEFAULT_TENANT, PromptTooLong, QuotaExceeded,
-    any_to_tokens)
+    any_to_tokens, plane_capacity)
 from lzy_tpu.utils.log import get_logger
 
 _LOG = get_logger(__name__)
@@ -53,6 +53,7 @@ class InferenceService:
         self.model_name = model_name
         self.iam = iam        # harness wires the cluster's IAM in here
         self.slo = slo
+        self._max_waiters = int(max_waiters)
         self._waiters = threading.BoundedSemaphore(max_waiters)
         #: streaming front (InferStream/InferStreamPoll/InferCancel):
         #: chunked long-poll token delivery with liveness reaping,
@@ -188,9 +189,13 @@ class InferenceService:
         ttft_ms = None
         if req.first_token_at is not None:
             ttft_ms = round(1000 * (req.first_token_at - req.submitted_at), 3)
+        # what the plane holds, as the gateways' replies say it: a caller
+        # with many calls in flight sizes itself by it (llm/sched.py)
         return {"request_id": req.id, "tokens": tokens,
                 "status": req.status or "ok",
-                "ttft_ms": ttft_ms, "model": self.model_name}
+                "ttft_ms": ttft_ms, "model": self.model_name,
+                **plane_capacity(self.engine.stats().slots,
+                                 self._max_waiters if gated else None)}
 
     def stats(self, *, token: Optional[str] = None) -> dict:
         """Engine stats. Scoped per subject: the operator (no IAM, or

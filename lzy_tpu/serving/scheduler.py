@@ -129,6 +129,21 @@ def shed_error(exc_type, msg: str, *, reason: str,
     return err
 
 
+def plane_capacity(slots: int, waiters: Optional[int] = None) -> dict:
+    """What a reply says of the plane that built it, for a caller that
+    sizes its own concurrency by it (``llm/sched.py``'s window of rows):
+    ``plane_slots``, the decode slots that can work at once, and — for a
+    caller the front gates — ``plane_admits``, the calls it takes at once
+    before it sheds (``waiters_busy``), which also bounds the slots. A
+    caller with a ``liveness`` probe is not gated (``waiters`` None). ONE
+    owner for the field names: the gateways, the single-engine front and
+    ``llm.EngineBackend`` all build them here."""
+    if waiters is None:
+        return {"plane_slots": int(slots)}
+    return {"plane_slots": min(int(slots), int(waiters)),
+            "plane_admits": int(waiters)}
+
+
 def quota_error(msg: str, *, tenant: str, reason: str,
                 retry_after_s: Optional[float] = None,
                 counted: bool = True) -> QuotaExceeded:

@@ -19,6 +19,15 @@ from lzy_tpu.utils.jaxenv import enable_compile_cache  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
+# The Pallas TPU interpreter's callbacks dispatch JAX work of their own
+# (interpret_pallas_call.py `store` iterates a jax.Array). Under the CPU
+# client's asynchronous dispatch, an eager caller that goes on dispatching
+# while such a callback runs can deadlock with it: the whole tier-1 run, six
+# workers, hung at 95% in tests/test_paged_attention.py or
+# tests/test_nemotron_h.py in four runs of four (PR 30; one of them the
+# parent's tree), and ran through in 256 s with dispatch on the calling
+# thread. Results are the same; nothing waits on another thread's queue.
+jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 # No TPU in this tier: the Pallas kernels run under the interpreter, because
 # the tests ask for it here (a test of the TPU path passes interpret=False).

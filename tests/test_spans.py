@@ -187,35 +187,59 @@ class TestRecorder:
 
 class TestThreadHops:
     def test_parent_is_carried_across_the_workflow_pool(self):
-        sched = WorkflowScheduler(backend=None, max_workers=2)
-        seen = []
+        # a window of two, as a reply gives it, and four rows held on an
+        # event: two of them wait for the window, and no clock is asked
+        sched = WorkflowScheduler(backend=None)
+        sched._follow({"plane_slots": 2, "plane_admits": 2})
+        assert sched.stats()["row_window"] == 2
+        seen, inside = [], threading.Semaphore(0)
+        hold = threading.Event()
 
         def row(x):
             seen.append((threading.current_thread().name, trace.context()))
-            time.sleep(0.01)
+            inside.release()
+            assert hold.wait(30)
             return x * 2
 
+        out = []
         try:
             with trace.recording() as rec:
-                with trace.span(trace.LLM_BATCH, rows=4) as batch:
-                    assert sched.map(row, [1, 2, 3, 4]) == [2, 4, 6, 8]
+                def batch():
+                    with trace.span(trace.LLM_BATCH, rows=4) as sp:
+                        out.append((sp.id, sched.map(row, [1, 2, 3, 4])))
+
+                t = threading.Thread(target=batch)
+                t.start()
+                assert inside.acquire(timeout=30)
+                assert inside.acquire(timeout=30)
+                # two rows are inside and held; the other two wait
+                assert sched.stats()["rows_waiting"] == 2
+                assert not inside.acquire(blocking=False)
+                hold.set()
+                t.join(30)
                 recs = _by_name(rec.drain())
         finally:
+            hold.set()
             sched.close()
+        (batch_id, results), = out
+        assert results == [2, 4, 6, 8]
         rows, waits = recs[trace.LLM_ROW], recs[trace.LLM_ROW_POOL_WAIT]
         assert len(rows) == len(waits) == 4
         by_id = {r.id: r for r in rows}
         for r in rows:
-            assert r.parent == batch.id and r.request == batch.id
+            assert r.parent == batch_id and r.request == batch_id
             assert r.thread.startswith("lzy-wfsched")
         for w in waits:
             owner = by_id[w.parent]
             assert w.start == owner.start and w.end <= owner.end
-        # four rows on two threads: two of them waited for a thread
-        assert sum(w.end - w.start > 0.005 for w in waits) >= 2
+        # four rows through a window of two: two of them waited, and
+        # entered only once a row had left (one clock, no threshold)
+        first_out = min(r.end for r in rows)
+        assert sum(w.end >= first_out for w in waits) == 2
+        assert sum(w.end < first_out for w in waits) == 2
         assert all(ctx[0] in by_id for _, ctx in seen)
-        # off: the function is handed to the pool as it is
-        sched2 = WorkflowScheduler(backend=None, max_workers=1)
+        # off: the function runs with no span around it
+        sched2 = WorkflowScheduler(backend=None)
         try:
             assert sched2.map(lambda x: trace.context(), [1]) == [None]
         finally:

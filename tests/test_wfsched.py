@@ -577,3 +577,421 @@ class TestEnginePark:
         assert b.error is None and c.error is None
         assert b.result(0) == _oracle_tokens(cfg, params, pb, 7)
         assert c.result(0) == _oracle_tokens(cfg, params, pc, 1)
+
+
+# -- the window of rows in flight (WorkflowScheduler.map) ---------------------
+
+class _WindowBackend:
+    """Fake serving plane for the window: counts the rows inside
+    ``generate`` and holds each on the condition until the test lets its
+    prompt go (no sleeps). Replies carry the capacity fields it is given;
+    a prompt in ``shed`` is refused once, as a full gateway refuses."""
+
+    def __init__(self, slots=None, admits=None):
+        self.slots, self.admits = slots, admits
+        self.inside, self.peak = 0, 0
+        self.entered = []            # first token of each prompt, in order
+        self.shed = set()
+        self.open_all = False
+        self._let_go = set()
+        self._cv = threading.Condition()
+
+    def model_digest(self):
+        return "window-digest"
+
+    def generate(self, prompt, **kw):
+        key = prompt[0]
+        with self._cv:
+            if key in self.shed:
+                self.shed.discard(key)
+                from lzy_tpu.serving.scheduler import AdmissionError
+
+                raise AdmissionError("waiters_busy")
+            self.inside += 1
+            self.peak = max(self.peak, self.inside)
+            self.entered.append(key)
+            self._cv.notify_all()
+            ok = self._cv.wait_for(
+                lambda: self.open_all or key in self._let_go, 30)
+            self.inside -= 1
+            reply = {"tokens": [key], "status": "ok"}
+            if self.slots is not None:
+                reply["plane_slots"] = self.slots
+            if self.admits is not None:
+                reply["plane_admits"] = self.admits
+            self._cv.notify_all()
+        if not ok:
+            raise TimeoutError("the test never let the row go")
+        return reply
+
+    def let_go(self, *keys):
+        with self._cv:
+            self._let_go.update(keys)
+            self._cv.notify_all()
+
+    def let_all_go(self):
+        with self._cv:
+            self.open_all = True
+            self._cv.notify_all()
+
+    def wait_entered(self, n):
+        with self._cv:
+            assert self._cv.wait_for(lambda: len(self.entered) >= n, 30), \
+                f"{len(self.entered)} rows entered, {n} expected"
+
+
+def _map_in_thread(sched, keys, out):
+    """``sched.map`` over one-token prompts, on a thread of its own."""
+    def run():
+        try:
+            out.append(sched.map(
+                lambda k: sched.dispatch([k], max_new_tokens=1)["tokens"],
+                list(keys)))
+        except BaseException as e:  # noqa: BLE001 — asserted by the test
+            out.append(e)
+
+    t = threading.Thread(target=run)
+    t.start()
+    return t
+
+
+def _width_for(slots):
+    from lzy_tpu.llm import sched as sched_mod
+
+    return slots + int(sched_mod._BACKLOG_PER_SLOT * slots)
+
+
+class TestRowWindow:
+    def test_a_plane_that_reports_nothing_gets_sixteen(self):
+        backend = _WindowBackend()
+        sched = WorkflowScheduler(backend)
+        out = []
+        t = _map_in_thread(sched, range(20), out)
+        backend.wait_entered(16)
+        st = sched.stats()
+        assert (st["row_window"], st["rows_in_flight"],
+                st["rows_waiting"]) == (16, 16, 4)
+        backend.let_all_go()
+        t.join(30)
+        assert out == [[[k] for k in range(20)]]
+        assert backend.peak == 16 and sched.stats()["row_window"] == 16
+
+    def test_width_follows_the_first_reply_that_reports_and_shrinks(self):
+        backend = _WindowBackend(slots=16)
+        sched = WorkflowScheduler(backend)
+        wide = _width_for(16)
+        out = []
+        t = _map_in_thread(sched, range(wide + 8), out)
+        backend.wait_entered(16)
+        assert sched.stats()["row_window"] == 16
+        backend.let_go(0)                      # the first reply: 16 slots
+        backend.wait_entered(wide + 1)         # the row left, width entered
+        assert _wait_until(
+            lambda: sched.stats()["rows_in_flight"] == wide)
+        assert sched.stats()["row_window"] == wide
+        assert sched.stats()["rows_waiting"] == 7 and backend.peak <= wide
+        # a replica is lost: the next reply reports 2 slots. Rows inside
+        # stay inside, new ones wait until the window has room again
+        backend.slots = 2
+        narrow = _width_for(2)
+        backend.let_go(1)
+        assert _wait_until(lambda: sched.stats()["row_window"] == narrow)
+        backend.let_go(*range(2, 10))
+        assert _wait_until(
+            lambda: sched.stats()["rows_in_flight"] == wide - 9)
+        assert len(backend.entered) == wide + 1
+        assert backend.inside == wide - 9
+        assert sched.stats()["rows_waiting"] == 7
+        backend.let_all_go()
+        t.join(30)
+        assert out == [[[k] for k in range(wide + 8)]]
+
+    @pytest.mark.parametrize("slots,admits,width", [
+        (32, 16, 16),         # the default gateway: gains nothing, sheds
+        (8, 8, 8),            # nothing; a small front narrows the window
+        (4, 256, None),       # None: slots plus their backlog
+    ])
+    def test_width_stays_within_what_the_plane_admits(self, slots, admits,
+                                                      width):
+        backend = _WindowBackend(slots=slots, admits=admits)
+        sched = WorkflowScheduler(backend)
+        backend.let_all_go()
+        assert sched.map(lambda k: sched.dispatch(
+            [k], max_new_tokens=1)["tokens"], [7]) == [[7]]
+        assert sched.stats()["row_window"] == (
+            _width_for(slots) if width is None else width)
+
+    @pytest.mark.parametrize("bad", [0, -3, True, "32", 2.5, None])
+    def test_a_field_that_is_no_count_changes_nothing(self, bad):
+        backend = _WindowBackend(slots=bad)
+        sched = WorkflowScheduler(backend)
+        backend.let_all_go()
+        sched.dispatch([1], max_new_tokens=1)
+        assert sched.stats()["row_window"] == 16
+
+    def test_a_shed_row_is_retried_and_never_widens_the_window(self):
+        from lzy_tpu.llm import metrics
+
+        backend = _WindowBackend()
+        backend.shed = {3, 5}
+        backend.let_all_go()
+        llm.configure(backend)
+        retries = metrics.DISPATCH_RETRIES._values.get((), 0.0)
+        gens = llm.generate_batch([[k] for k in range(8)],
+                                  max_new_tokens=1, cache=False)
+        assert [g.tokens for g in gens] == [[k] for k in range(8)]
+        assert metrics.DISPATCH_RETRIES._values[()] == retries + 2
+        from lzy_tpu.llm.sched import current_scheduler
+
+        assert current_scheduler().stats()["row_window"] == 16
+        assert backend.peak <= 16
+
+    def test_rows_of_concurrent_batches_enter_in_hand_over_order(self):
+        backend = _WindowBackend()
+        sched = WorkflowScheduler(backend)
+        a, b, c = range(10), range(100, 110), range(200, 204)
+        outs = [[], [], []]
+        ta = _map_in_thread(sched, a, outs[0])
+        backend.wait_entered(10)
+        tb = _map_in_thread(sched, b, outs[1])
+        backend.wait_entered(16)               # b is handed over whole
+        tc = _map_in_thread(sched, c, outs[2])
+        assert _wait_until(lambda: sched.stats()["rows_waiting"] == 8)
+        assert set(backend.entered[:10]) == set(a)
+        assert set(backend.entered[10:]) == set(b[:6])
+        for i, key in enumerate(list(a[:8])):  # one leaves, one enters
+            backend.let_go(key)
+            backend.wait_entered(17 + i)
+        assert backend.entered[16:] == list(b[6:]) + list(c)
+        assert backend.peak == 16
+        backend.let_all_go()
+        for t in (ta, tb, tc):
+            t.join(30)
+        assert [o[0] for o in outs] == [[[k] for k in keys]
+                                        for keys in (a, b, c)]
+
+    def test_first_exception_propagates_after_all_rows_settle(self):
+        sched = WorkflowScheduler(backend=None)
+        hold, failed, settled = threading.Event(), threading.Event(), []
+
+        def row(i):
+            try:
+                if i == 1:
+                    raise ValueError("row 1")
+                if i == 3:
+                    failed.set()
+                    raise KeyError("row 3")
+                assert hold.wait(30)
+                return i
+            finally:
+                settled.append(i)
+
+        out = []
+
+        def run():
+            try:
+                out.append(sched.map(row, list(range(5))))
+            except BaseException as e:  # noqa: BLE001
+                out.append((e, sorted(settled)))
+
+        t = threading.Thread(target=run)
+        t.start()
+        assert failed.wait(30)
+        assert t.is_alive() and not out        # rows 0, 2, 4 are held
+        hold.set()
+        t.join(30)
+        (err, seen), = out
+        assert isinstance(err, ValueError) and seen == [0, 1, 2, 3, 4]
+
+    def test_close_releases_waiting_rows(self):
+        backend = _WindowBackend()
+        sched = WorkflowScheduler(backend)
+        out = []
+        t = _map_in_thread(sched, range(20), out)
+        backend.wait_entered(16)
+        assert sched.stats()["rows_waiting"] == 4
+        sched.close()
+        backend.wait_entered(20)               # over the width: released
+        assert backend.inside == 20 and sched.stats()["rows_waiting"] == 0
+        backend.let_all_go()
+        t.join(30)
+        assert out == [[[k] for k in range(20)]]
+
+    def test_window_holds_under_many_batches_and_a_moving_width(self):
+        """Time-bounded stress: more batches than cores, a short switch
+        interval, the reported slots changing between replies. No row
+        is lost, none runs twice, and once the width has settled no more
+        rows are inside than it allows."""
+        import sys
+
+        lock = threading.Lock()
+        state = {"inside": 0, "peak_settled": 0, "calls": 0}
+
+        class Plane:
+            slots = 2
+
+            def model_digest(self):
+                return "stress"
+
+            def generate(self, prompt, **kw):
+                with lock:
+                    state["inside"] += 1
+                    state["calls"] += 1
+                    if state["calls"] > 400:      # the width is 4 by now
+                        state["peak_settled"] = max(state["peak_settled"],
+                                                    state["inside"])
+                    slots = 2 if state["calls"] > 300 else \
+                        (1, 3, 5)[state["calls"] % 3]
+                with lock:
+                    state["inside"] -= 1
+                return {"tokens": [prompt[0]], "status": "ok",
+                        "plane_slots": slots}
+
+        sched = WorkflowScheduler(Plane())
+        outs, old = {}, sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            def batch(b):
+                keys = [b * 100 + i for i in range(25)]
+                outs[b] = (keys, sched.map(lambda k: sched.dispatch(
+                    [k], max_new_tokens=1)["tokens"][0], keys))
+
+            threads = [threading.Thread(target=batch, args=(b,))
+                       for b in range(24)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(old)
+        assert len(outs) == 24
+        assert all(keys == got for keys, got in outs.values())
+        assert state["calls"] == 600
+        assert state["peak_settled"] <= _width_for(2)
+        st = sched.stats()
+        assert st["row_window"] == _width_for(2)
+        assert st["rows_waiting"] == 0
+        assert _wait_until(lambda: sched.stats()["rows_in_flight"] == 0)
+
+    def test_counters_follow_the_window(self):
+        from lzy_tpu.llm import metrics
+
+        def count(window):
+            key = (("window", str(window)),)
+            counts = metrics.ROW_WINDOW_WAIT._counts.get(key)
+            # (rows, rows that entered at once)
+            return (counts[-1], counts[0]) if counts else (0, 0)
+
+        backend = _WindowBackend()
+        sched = WorkflowScheduler(backend)
+        assert metrics.ROW_WINDOW._values[()] == 16
+        before, in_flight = count(16), \
+            metrics.ROWS_IN_FLIGHT._values.get((), 0.0)
+        out = []
+        t = _map_in_thread(sched, range(18), out)
+        backend.wait_entered(16)
+        assert _wait_until(lambda: metrics.ROWS_IN_FLIGHT._values[()]
+                           == in_flight + 16)
+        backend.let_all_go()
+        t.join(30)
+        rows, at_once = count(16)
+        # 18 rows through a window of 16: two of them waited
+        assert rows - before[0] == 18 and at_once - before[1] == 16
+        assert _wait_until(lambda: metrics.ROWS_IN_FLIGHT._values[()]
+                           == in_flight)
+        backend.slots = 4
+        sched.dispatch([1], max_new_tokens=1)
+        assert metrics.ROW_WINDOW._values[()] == _width_for(4)
+
+
+def _reply_surface(kind, cfg, params):
+    """(generate, close, ready slots, waiter cap or None) of one surface
+    that answers ``llm`` calls."""
+    if kind == "gateway":
+        def factory():
+            return PagedInferenceEngine(cfg, params, slots=2,
+                                        page_size=PAGE)
+
+        fleet = ReplicaFleet(factory)
+        gw = GatewayService(fleet, router=PrefixAffinityRouter(PAGE),
+                            model_name="tiny", max_waiters=3)
+        for _ in range(2):
+            fleet.add_replica()
+        return gw.generate, gw.close, 4, 3
+    if kind == "disagg":
+        from lzy_tpu.gateway.disagg import DisaggGatewayService
+        from lzy_tpu.serving.disagg import DecodeEngine, PrefillEngine
+
+        decode = ReplicaFleet(
+            lambda: DecodeEngine(cfg, params, slots=2, page_size=PAGE),
+            replica_prefix="decode")
+        prefill = ReplicaFleet(
+            lambda: PrefillEngine(cfg, params, slots=2, page_size=PAGE),
+            replica_prefix="prefill")
+        gw = DisaggGatewayService(
+            decode, prefill, page_size=PAGE,
+            router=PrefixAffinityRouter(PAGE),
+            prefill_router=PrefixAffinityRouter(PAGE),
+            prefill_replicas=1, model_name="tiny", max_waiters=3)
+        for _ in range(2):
+            decode.add_replica()
+        prefill.add_replica()
+        return gw.generate, gw.close, 4, 3
+    engine = PagedInferenceEngine(cfg, params, slots=3, page_size=PAGE)
+    engine.start()
+    if kind == "inference_service":
+        from lzy_tpu.service.inference import InferenceService
+
+        svc = InferenceService(engine, model_name="tiny", max_waiters=2)
+        return svc.generate, svc.close, 3, 2
+    backend = llm.EngineBackend(engine, model_name="tiny")
+    return backend.generate, engine.close, 3, None
+
+
+class TestPlaneCapacityInReplies:
+    @pytest.mark.parametrize("kind", ["gateway", "disagg",
+                                      "inference_service",
+                                      "engine_backend"])
+    def test_every_reply_says_what_the_plane_holds(self, tiny_model, kind):
+        cfg, params = tiny_model
+        generate, close, slots, waiters = _reply_surface(kind, cfg, params)
+        try:
+            reply = generate([5, 9, 3, 7], max_new_tokens=2, greedy=True)
+            assert reply["status"] == "ok" and len(reply["tokens"]) == 2
+            if waiters is None:
+                assert reply["plane_slots"] == slots
+                assert "plane_admits" not in reply
+                return
+            # a gated call: the ready replicas' slots, bounded by the
+            # waiter cap, and the cap itself
+            assert reply["plane_slots"] == min(slots, waiters)
+            assert reply["plane_admits"] == waiters
+            # a caller with a liveness probe holds no waiter
+            reply = generate([5, 9, 3, 7], max_new_tokens=2, greedy=True,
+                             liveness=lambda: True)
+            assert reply["plane_slots"] == slots
+            assert "plane_admits" not in reply
+            # the window takes it from the reply, through a proxy that
+            # only forwards generate
+            sched = WorkflowScheduler(_ForwardOnly(generate))
+            sched.dispatch([5, 9, 3, 7], max_new_tokens=2, greedy=True)
+            assert sched.stats()["row_window"] == min(
+                _width_for(min(slots, waiters)), waiters)
+        finally:
+            close()
+
+
+class _ForwardOnly:
+    """What the benchmark's proxy is to ``llm.configure``: ``generate``
+    and a digest, no ``stats()``, ``fleet`` or ``engine``."""
+
+    def __init__(self, generate):
+        self._generate = generate
+
+    def model_digest(self):
+        return "forwarded"
+
+    def generate(self, prompt, **kw):
+        return self._generate(prompt, **{k: v for k, v in kw.items()
+                                         if v is not None})
