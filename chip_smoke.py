@@ -14,11 +14,9 @@ share the persistent compile cache (``lzy_tpu/utils/jaxenv.py``).
 - ``kernels``: the Pallas flash forward and backward and the paged-attention
   read at Llama-3-8B widths (32 heads, 8 KV heads, head size 128), each
   against a dense reference under a written tolerance.
-- ``serve``: ``PagedInferenceEngine`` (``native_attention=True``,
-  ``kernel="auto"``, radix cache) behind ``GatewayService``, driven through
-  ``llm.generate`` inside a workflow; greedy tokens against
-  ``models.generate.generate``; then the same requests through the engine's
-  default (legacy) read path.
+- ``serve``: ``PagedInferenceEngine`` (``kernel="auto"``, radix cache)
+  behind ``GatewayService``, driven through ``llm.generate`` inside a
+  workflow; greedy tokens against ``models.generate.generate``.
 - ``train``: an ``@op`` that takes five SPMD train steps with the flash
   kernels and the fused cross-entropy.
 - ``control-plane``: the deployable binary, ``python -m lzy_tpu.service.serve``
@@ -627,11 +625,10 @@ def _judge(cfg, params, name: str, prompt, tokens, reference, pad_to: int,
             f"{LOGIT_TIE_TOL}")
 
 
-def _engine_factory(cfg, params, *, native: bool, slots: int, page_size: int,
-                    pool: dict, gang: int = 0):
+def _engine_factory(cfg, params, *, slots: int, page_size: int, pool: dict,
+                    gang: int = 0):
     def factory():
-        kw = dict(slots=slots, page_size=page_size,
-                  native_attention=native, kernel="auto", **pool)
+        kw = dict(slots=slots, page_size=page_size, kernel="auto", **pool)
         if gang:
             from lzy_tpu.serving.sharded import ShardedPagedInferenceEngine
 
@@ -707,40 +704,26 @@ def phase_serve(args, cfg=None, *, slots: int = 4, page_size: int = 16,
 
     plan = _request_plan(cfg.vocab_size, args.seed, new_tokens)
     common = dict(seed=args.seed, page_size=page_size, slots=slots, pool=pool)
-    native = _serve_once(cfg, params, plan, "native", native=True, **common)
-    if native["kernel_path"] == "legacy":
-        raise AssertionError("native_attention=True served the legacy path")
-    if native["cached_prompt_tokens_B"] < 64:
+    served = _serve_once(cfg, params, plan, "served", **common)
+    if served["cached_prompt_tokens_B"] < 64:
         raise AssertionError(
             f"B shares 64 prompt tokens with A but the radix cache served "
-            f"{native['cached_prompt_tokens_B']}")
+            f"{served['cached_prompt_tokens_B']}")
 
     verdicts: dict = {}
     for name in ("A", "B", "C1", "C2", "D"):
-        prompt, tokens, _ = native[name]
-        _judge(cfg, params, f"native/{name} vs generate()", prompt, tokens,
+        prompt, tokens, _ = served[name]
+        _judge(cfg, params, f"{name} vs generate()", prompt, tokens,
                _oracle(cfg, params, prompt, new_tokens), pad_to, verdicts)
 
-    # the read path every --serve-paged user gets by default
-    legacy = _serve_once(cfg, params, plan, "legacy", native=False, **common)
-    for name in ("A", "B", "C1", "D"):
-        prompt, tokens, _ = legacy[name]
-        _judge(cfg, params, f"legacy/{name} vs native", prompt, tokens,
-               native[name][1], pad_to, verdicts)
-    # step 2's prompt holds step 1's reply, so it is its own request
-    prompt, tokens, _ = legacy["C2"]
-    _judge(cfg, params, "legacy/C2 vs generate()", prompt, tokens,
-           _oracle(cfg, params, prompt, new_tokens), pad_to, verdicts)
-
     result.update({
-        "kernel_path": native["kernel_path"],
-        "requests": 10, "new_tokens": new_tokens,
-        "cached_prompt_tokens_B": native["cached_prompt_tokens_B"],
-        "conversation_step2_routed_by": native["C2_routed_by"],
+        "kernel_path": served["kernel_path"],
+        "requests": 5, "new_tokens": new_tokens,
+        "cached_prompt_tokens_B": served["cached_prompt_tokens_B"],
+        "conversation_step2_routed_by": served["C2_routed_by"],
         "verdicts": verdicts, "logit_tie_tolerance": LOGIT_TIE_TOL,
-        "pool_bytes": sum(native["pool_bytes_per_device"].values()),
-        "native": _speeds(native, new_tokens),
-        "legacy": _speeds(legacy, new_tokens),
+        "pool_bytes": sum(served["pool_bytes_per_device"].values()),
+        **_speeds(served, new_tokens),
     })
     return result
 
@@ -847,7 +830,11 @@ def phase_control_plane(args) -> dict:
     cmd = [sys.executable, "-m", "lzy_tpu.service.serve",
            "--db", os.path.join(work, "meta.db"),
            "--storage-uri", f"file://{work}/storage", "--port", str(port),
-           "--serve-model", "tiny", "--gateway", "--serve-paged",
+           "--serve-model", "tiny", "--gateway",
+           # the toy model's heads are 16 wide, and the decode kernel's
+           # page slices need the lane width (128): ``auto`` would lower
+           # at construction and be refused by the compiler at warm-up
+           "--serve-kernel", "lax",
            "--replicas", "2", "--serve-slots", "2"]
     t0 = time.monotonic()
     with open(log_path, "w") as log:
@@ -911,8 +898,7 @@ def phase_gang(args, cfg=None, *, slots: int = 4, page_size: int = 16,
     pool = pool if pool is not None else {"kv_pool_bytes": 2 << 30}
     params = _init_params(cfg, args.seed)
     plan = _request_plan(cfg.vocab_size, args.seed, new_tokens)
-    common = dict(seed=args.seed, page_size=page_size, slots=slots, pool=pool,
-                  native=True)
+    common = dict(seed=args.seed, page_size=page_size, slots=slots, pool=pool)
     solo = _serve_once(cfg, params, plan, "solo", **common)
     gang = _serve_once(cfg, params, plan, "gang", gang=tp, **common)
     for what in ("params_bytes_per_device", "pool_bytes_per_device"):

@@ -43,7 +43,7 @@ PAGE = 8
 
 def build_gateway():
     """A 2-replica paged fleet behind one gateway — the in-process twin
-    of ``serve.py --gateway --serve-paged``."""
+    of ``serve.py --gateway``."""
     import jax as _jax
 
     from lzy_tpu.gateway import (

@@ -59,7 +59,7 @@ _TENANT_COUNTERS = ("requests_finished", "tokens_generated",
 @dataclasses.dataclass
 class Replica:
     id: str
-    engine: object                      # InferenceEngine-compatible
+    engine: object                      # PagedInferenceEngine-compatible
     state: str = READY
     vm_ids: List[str] = dataclasses.field(default_factory=list)
     created_ts: float = dataclasses.field(default_factory=time.time)

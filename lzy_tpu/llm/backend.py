@@ -151,7 +151,7 @@ class ServiceBackend:
 
 
 class EngineBackend:
-    """Wrap a raw in-process engine (``InferenceEngine`` or subclass)
+    """Wrap a raw in-process engine (``PagedInferenceEngine`` or subclass)
     for ``LocalRuntime`` dev loops: no gateway, no routing metadata —
     ``submit`` + wait shaped into the reply dict the op layer reads."""
 

@@ -25,7 +25,7 @@ What is modeled rather than computed:
 
 The numbers that come out are capacity-model numbers — TTFT and
 inter-token latency under the *scheduling* dynamics — not kernel
-benchmarks; ``bench.py`` owns those.
+benchmarks; ``benchmark/`` owns those.
 """
 
 from __future__ import annotations

@@ -67,11 +67,6 @@ class LlamaConfig:
     # every position in one call, and the engine rolls the cache index
     # back over rejected positions afterwards
     decode: bool = False
-    # per-row cache positions: the cache "index" is [B] instead of a scalar,
-    # so every batch row decodes at its own sequence position — what the
-    # continuous-batching engine (lzy_tpu/serving) needs to admit and retire
-    # requests mid-decode without draining the batch
-    decode_slot_index: bool = False
     # paged KV cache: k/v live in a SHARED pool of [kv_pages, kv_page_size,
     # heads, dim] blocks instead of a dense [B, max_seq_len, ...] row per
     # batch slot; each forward pass takes a per-row page table (block ids in
@@ -81,17 +76,11 @@ class LlamaConfig:
     decode_paged: bool = False
     kv_page_size: int = 16
     kv_pages: int = 0
-    # native paged-attention read path (ops/paged_attention.py): attention
-    # reads K/V directly through the page table — the dense [B, L, ...]
-    # copy of the pool is never materialized. False keeps the legacy
-    # gather-back-to-dense path (bit-identical to the dense engine, and
-    # the oracle the native kernels are tested against).
-    paged_attention_native: bool = False
-    # which native kernel under paged_attention_native: "lax" (portable
-    # gather-attention, bit-identical to the legacy path by construction)
-    # or "pallas" (the decode kernel: live pages by DMA, online softmax;
-    # decode-sized windows over float pools, lax for the rest — see
-    # ops/paged_attention.py)
+    # how attention reads the pool through the page table
+    # (ops/paged_attention.py): "lax" (portable gather-attention, the same
+    # sums in the same order as the dense cache's read below) or "pallas"
+    # (the decode kernel: live pages by DMA, online softmax; decode-sized
+    # windows over float pools, lax for the rest)
     paged_kernel: str = "lax"
     # int8 per-block KV quantization (paged cache only): pooled K/V are
     # stored int8 with per-position/per-head scale+zero-point sidecars
@@ -128,21 +117,24 @@ class LlamaConfig:
         """Layers that write the paged pool: what sizes it."""
         return self.n_layers
 
-    def dense_models(self):
-        """``(decode model with a [slots] index, batch-1 prefill model)``
-        of the dense engine."""
-        return (Llama(dataclasses.replace(self, decode_slot_index=True)),
-                Llama(self))
-
-    def paged_model(self, *, page_size: int, kv_pages: int, native: bool,
-                    kernel: str, kv_quant: Optional[str], **module_kw):
-        """The module a paged engine runs, for decode rounds and batch-1
+    def paged_model(self, *, page_size: int, kv_pages: int, kernel: str,
+                    kv_quant: Optional[str], native: bool = True,
+                    **module_kw):
+        """The module the engine runs, for decode rounds and batch-1
         prefill alike. ``module_kw`` goes to the module (the sharded
         engine's rule table)."""
+        # tombstone: benchmark/models/nemotron_h.py passes ``native=True``
+        # to this protocol method; the benchmark issue that takes it out
+        # there (ROADMAP B2) removes the keyword here
+        if not native:
+            raise ValueError(
+                "native=False: the gather read (the pool copied back into "
+                "a dense [B, L, KV, D] layout) is gone; kernel='lax' reads "
+                "through the page table and gives the same bits")
         return Llama(dataclasses.replace(
             self, decode_paged=True, kv_page_size=page_size,
-            kv_pages=kv_pages, paged_attention_native=native,
-            paged_kernel=kernel, kv_quant=kv_quant), **module_kw)
+            kv_pages=kv_pages, paged_kernel=kernel, kv_quant=kv_quant),
+            **module_kw)
 
     def kernel_paths(self, t: int) -> tuple:
         """``lzy_kernel_dispatch_total{path}`` labels beside the attention
@@ -324,32 +316,28 @@ class Attention(nn.Module):
         scored in one pass, logits come back for every position, and the
         caller rewinds the per-row index over rejected positions (the
         garbage K/V they wrote sits beyond the rewound index, invisible
-        to the causal mask and overwritten before it could surface). With
-        ``cfg.decode_slot_index`` the cache index is ``[B]`` and every
-        row reads/writes at its own position (continuous batching).
-        Caller contract for per-row chunks: ``index + T`` must stay
-        within ``max_seq_len`` for every live row — the dense row write
-        is a ``dynamic_update_slice`` (clamps the start, overwriting real
+        to the causal mask and overwritten before it could surface).
+        Caller contract for chunks: ``index + T`` must stay within
+        ``max_seq_len`` for every live row — the dense write is a
+        ``dynamic_update_slice`` (clamps the start, overwriting real
         positions) and the paged scatter clamps the page lookup into the
-        row's last block; the serving engines fall back to 1-token steps
+        row's last block; the serving engine falls back to 1-token steps
         when any row is that close to the edge.
 
         With ``cfg.decode_paged`` the k/v caches are a SHARED pool of
-        ``[kv_pages, kv_page_size, ...]`` blocks and ``page_table``
-        (``[B, max_seq_len // kv_page_size]`` block ids) maps each row's
-        positions onto pool rows: writes scatter to
-        ``(table[b, pos//page], pos%page)``, reads gather the row's blocks
-        back into position order — after which the score/mask/softmax code
-        is shared with the dense path, which is what keeps the two paths
-        bit-identical (the paged gather reproduces the dense layout
-        exactly; garbage in padded/unwritten slots is masked to a 0.0
-        softmax weight the same way in both). With
-        ``cfg.paged_attention_native`` the read side skips the dense
-        gather entirely and computes attention THROUGH the page table
-        (``ops/paged_attention``; kernel per ``cfg.paged_kernel``), and
-        with ``cfg.kv_quant`` the pools store int8 with scale/zero-point
-        sidecar cache leaves (quantize on scatter-write, dequantize on
-        read — every read path uses the same formula)."""
+        ``[kv_pages, kv_page_size, ...]`` blocks, the index is ``[B]``
+        (every row reads and writes at its own position: continuous
+        batching) and ``page_table`` (``[B, max_seq_len //
+        kv_page_size]`` block ids) maps each row's positions onto pool
+        rows: writes scatter to ``(table[b, pos//page], pos%page)``, and
+        attention is computed THROUGH the page table
+        (``ops/paged_attention``; kernel per ``cfg.paged_kernel``) — no
+        dense copy of the pool exists. With ``cfg.kv_quant`` the pools
+        store int8 with scale/zero-point sidecar cache leaves (quantize
+        on scatter-write, dequantize on read — every read path uses the
+        same formula). Without ``cfg.decode_paged`` the cache is a dense
+        ``[B, L, ...]`` row a batch row under one scalar index: what
+        ``models/generate.py``, the oracle, runs."""
         cfg = self.cfg
         h, kv_heads, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         L = cfg.max_seq_len
@@ -358,10 +346,10 @@ class Attention(nn.Module):
         if quant and cfg.kv_quant != "int8":
             raise ValueError(
                 f"unknown kv_quant {cfg.kv_quant!r}; known: int8")
-        if (quant or cfg.paged_attention_native) and not cfg.decode_paged:
+        if quant and not cfg.decode_paged:
             raise ValueError(
-                "kv_quant / paged_attention_native require decode_paged "
-                "(the dense cache has no page table to read through)")
+                "kv_quant requires decode_paged (the dense cache has no "
+                "pool to quantize)")
         quant_side = None
         if cfg.decode_paged:
             if cfg.kv_pages < 2 or L % cfg.kv_page_size:
@@ -394,9 +382,8 @@ class Attention(nn.Module):
             cache_v = self.variable(
                 "cache", "v", jnp.zeros, (b, L, kv_heads, d), cfg.dtype
             )
-            idx_shape = (b,) if cfg.decode_slot_index else ()
             index = self.variable(
-                "cache", "index", lambda: jnp.zeros(idx_shape, jnp.int32)
+                "cache", "index", lambda: jnp.zeros((), jnp.int32)
             )
         i = index.value
         starts = i if i.ndim else jnp.broadcast_to(i, (b,))      # [B]
@@ -432,15 +419,6 @@ class Attention(nn.Module):
                 else:
                     cache_k.value = cache_k.value.at[rows, offs].set(flat_k)
                     cache_v.value = cache_v.value.at[rows, offs].set(flat_v)
-            elif i.ndim:
-                # per-row positions: each batch row lands at its own start
-                row_write = jax.vmap(
-                    lambda c, kv_chunk, start: jax.lax.dynamic_update_slice(
-                        c, kv_chunk, (start, 0, 0)))
-                cache_k.value = row_write(
-                    cache_k.value, k.astype(cfg.dtype), starts)
-                cache_v.value = row_write(
-                    cache_v.value, v.astype(cfg.dtype), starts)
             else:
                 cache_k.value = jax.lax.dynamic_update_slice(
                     cache_k.value, k.astype(cfg.dtype), (0, i, 0, 0)
@@ -452,52 +430,32 @@ class Attention(nn.Module):
 
         if cfg.decode_paged:
             from lzy_tpu.ops.paged_attention import (
-                KVQuant, dequantize_kv, paged_attention)
+                KVQuant, paged_attention)
 
             kvq = None
             if quant:
                 kvq = KVQuant(*(var.value for var in quant_side))
-            if cfg.paged_attention_native:
-                # native read path: attention computed THROUGH the page
-                # table (ops/paged_attention) — decode, prefill chunks
-                # and the [B, gamma+1] speculative verify all make this
-                # one call; the dense [B, L, ...] copy of the pool below
-                # never exists. "lax" is bit-identical to the legacy
-                # gather by construction; "pallas" is the decode kernel
-                # (within a written tolerance of float32 attention) for
-                # decode and verify windows over float pools, and lax
-                # for prefill chunks and int8 pools.
-                out = paged_attention(
-                    q, cache_k.value, cache_v.value, page_table, pos,
-                    kernel=cfg.paged_kernel, dtype=cfg.dtype, quant=kvq)
-                # gather head-sharded attention output BEFORE o_proj: the
-                # merged head dim is o_proj's contraction dim, and letting
-                # the partitioner keep it sharded would psum partial
-                # matmul products (a float reduction-order change — the
-                # sharded engine's bit-identity contract forbids it)
-                out = _anchor(out.reshape(b, t, h * d), self.anchor_mesh,
-                              "batch", "seq", "act_attn_out",
-                              rules=self.rules)
-                return self._o_proj(out)
-            # legacy path: gather the row's blocks back into position
-            # order: [B, P, page, KV, D] → [B, L, KV, D] — the dense
-            # layout, so everything below is literally the dense code
-            # path (bit-identical numerics); int8 pools dequantize right
-            # after the gather (same per-element formula as the native
-            # kernels, so quantized output is kernel-independent)
-            keys = cache_k.value[page_table]
-            vals = cache_v.value[page_table]
-            if quant:
-                keys = dequantize_kv(
-                    keys, kvq.k_scale[page_table], kvq.k_zp[page_table],
-                    cfg.dtype)
-                vals = dequantize_kv(
-                    vals, kvq.v_scale[page_table], kvq.v_zp[page_table],
-                    cfg.dtype)
-            keys = keys.reshape(b, L, kv_heads, d)
-            vals = vals.reshape(b, L, kv_heads, d)
-        else:
-            keys, vals = cache_k.value, cache_v.value
+            # attention computed THROUGH the page table
+            # (ops/paged_attention): decode, prefill chunks and the
+            # [B, gamma+1] speculative verify all make this one call.
+            # "lax" makes the dense read's sums below in the same order
+            # (bit-identical to it); "pallas" is the decode kernel
+            # (within a written tolerance of float32 attention) for
+            # decode and verify windows over float pools, and lax for
+            # prefill chunks and int8 pools.
+            out = paged_attention(
+                q, cache_k.value, cache_v.value, page_table, pos,
+                kernel=cfg.paged_kernel, dtype=cfg.dtype, quant=kvq)
+            # gather head-sharded attention output BEFORE o_proj: the
+            # merged head dim is o_proj's contraction dim, and letting
+            # the partitioner keep it sharded would psum partial
+            # matmul products (a float reduction-order change — the
+            # sharded engine's bit-identity contract forbids it)
+            out = _anchor(out.reshape(b, t, h * d), self.anchor_mesh,
+                          "batch", "seq", "act_attn_out",
+                          rules=self.rules)
+            return self._o_proj(out)
+        keys, vals = cache_k.value, cache_v.value
 
         # GQA without jnp.repeat: grouping q as [B, T, KV, G, D] lets the
         # einsum broadcast the shared KV head instead of materializing a
@@ -519,7 +477,7 @@ class Attention(nn.Module):
         s = jnp.where(visible, s, -1e30)
         p = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
         out = jnp.einsum("bkgtl,blkd->btkgd", p, vals)
-        # same contraction-dim gather as the native path above: replicate
+        # same contraction-dim gather as the paged read above: replicate
         # the merged head dim before o_proj so no psum-of-partials ever
         # enters the decode forward
         out = _anchor(out.reshape(b, t, h * d), self.anchor_mesh,

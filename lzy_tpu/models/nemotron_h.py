@@ -141,22 +141,20 @@ class NemotronHConfig:
         """No training-only feature to clear."""
         return self
 
-    def dense_models(self):
-        raise ValueError(
-            f"{type(self).__name__} is served by PagedInferenceEngine: the "
-            f"dense engine gives every slot a whole row of keys and values "
-            f"and knows no other kind of cache")
-
-    def paged_model(self, *, page_size: int, kv_pages: int, native: bool,
-                    kernel: str, kv_quant: Optional[str]):
+    def paged_model(self, *, page_size: int, kv_pages: int, kernel: str,
+                    kv_quant: Optional[str], native: bool = True):
         if kv_quant is not None:
             raise ValueError(
                 "kv_quant: this model's paged pool is float (int8 pools "
                 "are models/llama.py's)")
+        # tombstone: benchmark/models/nemotron_h.py passes ``native=True``;
+        # the benchmark issue that takes it out there (ROADMAP B2) removes
+        # the keyword here
         if not native:
             raise ValueError(
-                "this model reads its pool through ops/paged_attention "
-                "only: pass native_attention=True")
+                "native=False: the gather read (the pool copied back into "
+                "a dense [B, L, KV, D] layout) is gone; kernel='lax' reads "
+                "through the page table")
         return NemotronH(dataclasses.replace(
             self, decode_paged=True, kv_page_size=page_size,
             kv_pages=kv_pages, paged_kernel=kernel))

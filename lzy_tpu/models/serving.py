@@ -9,11 +9,9 @@ the configuration object and by the module class it builds;
 the attention kernel's shapes), and:
 
 - ``serving_config()``: itself with every training-only feature cleared;
-- ``paged_model(page_size=, kv_pages=, native=, kernel=, kv_quant=)``: the
-  module a paged engine runs, for decode rounds and batch-1 prefill alike;
-- ``dense_models()``: ``(decode model with a [slots] index, batch-1 prefill
-  model)`` of the dense engine, which gives every slot a whole row of keys
-  and values (a family with another kind of cache raises ``ValueError``);
+- ``paged_model(page_size=, kv_pages=, kernel=, kv_quant=)``: the module
+  the engine runs, for decode rounds and batch-1 prefill alike, reading its
+  pool through the page table (``ops/paged_attention.py``);
 - ``kv_layers``: layers that keep keys and values in the paged pool, what a
   byte budget for the pool is divided by;
 - ``kernel_paths(t)``: ``lzy_kernel_dispatch_total{path}`` labels of a

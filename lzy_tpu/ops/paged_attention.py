@@ -1,24 +1,22 @@
-"""Native paged-attention decode kernel + int8 KV-block quantization.
+"""The paged-attention read + int8 KV-block quantization.
 
-The paged serving path (``serving/kv_cache.py`` + ``models/llama.py``)
-historically paid for its bit-identity guarantee twice per decode step:
-K/V writes scatter through the page table, and then every row's blocks
-are gathered BACK into the dense ``[B, L, kv, d]`` layout before the
-dense attention code runs — doubling HBM traffic on a path that is
-memory-bound to begin with. This module is the native read path:
+The serving path (``serving/kv_cache.py`` + the models) writes K/V by
+scattering through the page table; this module is how attention reads them
+back, the one read path of the serving engine:
 
 - :func:`paged_attention` — attention computed *through* the page table.
   Two kernels behind one signature:
 
   * ``kernel="lax"`` — a pure ``jax.lax`` gather-attention whose op
-    sequence reproduces the legacy gather→dense math EXACTLY (same
-    einsums, same mask, same softmax, same dtypes), so its output is
-    bit-identical to the legacy path and, transitively, to the dense
-    engine and the ``generate()`` oracle. It is kept forever as the
-    portable path and the oracle. Its cost is the table's width: it
-    gathers ``pages_per_seq`` pages for every row, live or not.
+    sequence reproduces the dense cache's read in ``models/llama.py``
+    EXACTLY (same einsums, same mask, same softmax, same dtypes), so its
+    output is bit-identical to the ``generate()`` oracle's. It is kept as
+    the portable path, the sharded gang's read and the tests' bit-exact
+    reference. Its cost is the table's width: it gathers
+    ``pages_per_seq`` pages for every row, live or not.
   * ``kernel="pallas"`` — the decode kernel (ROADMAP S2), the one
-    ``"auto"`` resolves to (:func:`default_kernel`). The pools stay in
+    ``"auto"`` resolves to on a TPU (:func:`default_kernel`). The pools
+    stay in
     HBM in their own ``[n_blocks, page, KV, D]`` layout
     (``memory_space=ANY``; a page of all KV heads is one contiguous
     ``[page * KV, D]`` tile-aligned slab, a free reshape); the page
@@ -61,7 +59,7 @@ memory-bound to begin with. This module is the native read path:
 
 Dispatch counts by kernel path, quantized blocks resident, and the
 dequant-error EWMA are exported via ``lzy_tpu.utils.metrics.REGISTRY``
-(``lzy_kernel_*``) and surfaced through ``EngineStats`` and ``bench.py``.
+(``lzy_kernel_*``) and surfaced through ``EngineStats``.
 """
 
 from __future__ import annotations
@@ -90,7 +88,7 @@ TOLERANCE = {"bfloat16": 2e-2, "float32": 1e-5}
 
 DISPATCHES = REGISTRY.counter(
     "lzy_kernel_dispatch_total",
-    "paged-attention dispatches by kernel path (pallas/lax/legacy)")
+    "paged-attention dispatches by kernel path (pallas/lax)")
 QUANT_BLOCKS_RESIDENT = REGISTRY.gauge(
     "lzy_kernel_kv_quant_blocks_resident",
     "int8-quantized KV blocks currently holding live data (summed over "
@@ -105,7 +103,7 @@ _ewma_state = {"value": None}
 
 def note_dequant_error(err: float, alpha: float = 0.2) -> float:
     """Fold one observed dequantization error (mean absolute, host-side)
-    into the exported EWMA. Callers are the bench quant probes and tests
+    into the exported EWMA. Callers are quantization probes and tests
     — the hot path never reads quantized values back to the host."""
     prev = _ewma_state["value"]
     cur = float(err) if prev is None else (1 - alpha) * prev + alpha * err
@@ -115,10 +113,12 @@ def note_dequant_error(err: float, alpha: float = 0.2) -> float:
 
 
 def default_kernel() -> str:
-    """The kernel ``"auto"`` resolves to: the one that compiles for a TPU
-    at serving shapes and reads no more than the live context, which is
-    the Pallas decode kernel."""
-    return "pallas"
+    """The kernel ``"auto"`` resolves to, by the platform JAX runs on. On
+    a TPU: the Pallas decode kernel, which compiles there at serving
+    shapes and reads no more than the live context. Anywhere else: lax,
+    the portable read. Never the interpreter (``ops/interpret.py``): a
+    test that wants the kernel off the TPU asks for ``"pallas"`` by name."""
+    return "pallas" if jax.default_backend() == "tpu" else "lax"
 
 
 class KVQuant(NamedTuple):
@@ -166,11 +166,10 @@ def quantize_kv(x: jax.Array):
 def dequantize_kv(q: jax.Array, scale: jax.Array, zp: jax.Array,
                   dtype: Any) -> jax.Array:
     """Inverse of :func:`quantize_kv`; ``scale``/``zp`` broadcast over
-    the trailing head dim. One formula shared by every read path (legacy
-    gather, lax oracle), and — because the scale is a
-    power of two — one whose value is independent of how the compiler
-    fuses it, so the quantized paths can never diverge from EACH OTHER,
-    only boundedly from fp."""
+    the trailing head dim. One formula for every read of an int8 pool,
+    and — because the scale is a power of two — one whose value is
+    independent of how the compiler fuses it, so quantized reads can
+    never diverge from EACH OTHER, only boundedly from fp."""
     return (q.astype(jnp.float32) * scale[..., None]
             + zp[..., None]).astype(dtype)
 
@@ -193,11 +192,12 @@ def paged_scatter_index(page_table: jax.Array, positions: jax.Array,
 
 def _lax_paged_attention(q, k_pool, v_pool, page_table, positions, *,
                          dtype, quant: Optional[KVQuant]):
-    """Gather-attention in EXACTLY the legacy op sequence. This is the
-    bit-exactness anchor: ``models/llama.py``'s legacy branch runs these
-    same ops inline against the dense engine's shared math, so any
-    change here must keep the einsum forms, mask constant, softmax call
-    and dtype casts literally identical."""
+    """Gather-attention in EXACTLY the op sequence of the dense cache's
+    read. This is the bit-exactness anchor: ``models/llama.py``'s dense
+    decode branch (what ``models/generate.py``, the oracle, runs) makes
+    these same ops over its ``[B, L, KV, D]`` rows, so any change here
+    must keep the einsum forms, mask constant, softmax call and dtype
+    casts literally identical."""
     b, t, h, d = q.shape
     kv_heads = k_pool.shape[2]
     pages = page_table.shape[1]
@@ -435,8 +435,8 @@ def paged_attention(
     - ``positions``: ``[B, T]`` int32 absolute positions of the queries
       (the causal mask: pooled slot ``l`` is visible iff
       ``l <= position``);
-    - ``kernel``: ``"lax"`` (portable, bit-identical to the legacy
-      gather path) or ``"pallas"`` (the decode kernel for the shapes
+    - ``kernel``: ``"lax"`` (portable, bit-identical to the dense
+      cache's read) or ``"pallas"`` (the decode kernel for the shapes
       :func:`kernel_path` gives it, lax for the rest; ``interpret=None``
       takes the process's ``ops.interpret`` setting);
     - ``dtype``: compute/output dtype (defaults to the pool dtype; int8

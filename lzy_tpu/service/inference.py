@@ -30,7 +30,7 @@ MODEL_CONFIGS = ("tiny", "llama3_8b", "llama3_70b")
 
 
 class InferenceService:
-    """Thin RPC-facing wrapper over an :class:`InferenceEngine`.
+    """Thin RPC-facing wrapper over a :class:`PagedInferenceEngine`.
 
     ``max_waiters`` bounds how many RPC handler threads may BLOCK in
     ``generate`` at once: the control plane's gRPC pool is shared with the
@@ -250,24 +250,6 @@ def _build_engine_parts(model: str, *, checkpoint: Optional[str],
     return cfg, params
 
 
-def _check_paged_only(paged: bool, *, kv_quant, native_attention,
-                      kernel, kv_pool_bytes=None,
-                      kv_host_tier_bytes=None,
-                      kv_storage_tier=None) -> None:
-    """The dense engine has no page table to read through: silently
-    building it while the caller asked for quantization or the native
-    kernel would serve dense fp attention with no error and no stats
-    signal (kv_quant/kernel_path are None-filtered out of the wire doc).
-    serve.py validates its flags; the library surface must too."""
-    if not paged and (kv_quant is not None or native_attention
-                      or kernel != "auto" or kv_pool_bytes is not None
-                      or kv_host_tier_bytes is not None
-                      or kv_storage_tier is not None):
-        raise ValueError(
-            "kv_quant / native_attention / kernel / kv_pool_bytes / "
-            "kv_host_tier_bytes / kv_storage_tier require paged=True")
-
-
 def _build_kv_storage_tier(kv_storage_tier, page_size: int):
     """Resolve the ``--kv-storage-tier`` value: a URI becomes ONE shared
     ``StorageKVTier`` (every replica in the process spills to — and
@@ -295,12 +277,10 @@ def build_gateway_service(
     checkpoint: Optional[str] = None,
     seed: int = 0,
     prefill_chunk: int = 64,
-    paged: bool = False,
     page_size: int = 16,
     kv_blocks: Optional[int] = None,
     kv_pool_bytes: Optional[int] = None,
     kv_quant: Optional[str] = None,
-    native_attention: bool = False,
     kernel: str = "auto",
     kv_host_tier_bytes: Optional[int] = None,
     kv_storage_tier=None,
@@ -326,7 +306,7 @@ def build_gateway_service(
     ``max_replicas`` (defaults: ``replicas`` .. ``2 * replicas``).
 
     ``kv_host_tier_bytes``/``kv_storage_tier`` build the tiered KV cache
-    behind each paged replica (``--kv-host-tier-mb``/``--kv-storage-tier``;
+    behind each replica (``--kv-host-tier-mb``/``--kv-storage-tier``;
     docs/serving.md "Tiered KV cache"); ``kv_global_index`` turns on the
     gateway's fleet-global prefix index + cross-replica import (default:
     on exactly when a tier is configured).
@@ -350,52 +330,39 @@ def build_gateway_service(
 
     ``serve_mesh`` (``--serve-mesh N``) makes every replica a GANG: a
     ``ShardedPagedInferenceEngine`` running the forwards tensor-sharded
-    over a 1×N mesh (requires ``paged=True``; output stays bit-identical
-    to single-device — docs/serving.md "Sharded replicas"). Health and
+    over a 1×N mesh (output stays bit-identical to single-device —
+    docs/serving.md "Sharded replicas"). Health and
     recovery treat the gang as one replica: one dead host fails over the
     whole gang.
     """
     from lzy_tpu.gateway import (
         Autoscaler, GatewayService, PrefixAffinityRouter, ReplicaFleet,
         RoundRobinRouter)
-    from lzy_tpu.serving import InferenceEngine, PagedInferenceEngine
+    from lzy_tpu.serving import PagedInferenceEngine
 
     if replicas < 1:
         raise ValueError(f"replicas must be >= 1, got {replicas}")
     if routing not in ("prefix", "rr"):
         raise ValueError(f"unknown routing {routing!r}; use prefix or rr")
-    _check_paged_only(paged, kv_quant=kv_quant,
-                      native_attention=native_attention, kernel=kernel,
-                      kv_pool_bytes=kv_pool_bytes,
-                      kv_host_tier_bytes=kv_host_tier_bytes,
-                      kv_storage_tier=kv_storage_tier)
-    if serve_mesh is not None and not paged:
-        raise ValueError("serve_mesh (sharded gang replicas) requires "
-                         "paged=True — the sharded engine is paged-only")
     cfg, params = _build_engine_parts(model, checkpoint=checkpoint,
                                       seed=seed)
-    common = dict(slots=slots, max_queue=max_queue, eos_token=eos_token,
-                  prefill_chunk=prefill_chunk, seed=seed,
-                  spec_tokens=spec_tokens, prefill_budget=prefill_budget,
-                  tenants=tenants)
-    storage_tier = _build_kv_storage_tier(kv_storage_tier, page_size)
+    engine_kw = dict(
+        slots=slots, max_queue=max_queue, eos_token=eos_token,
+        prefill_chunk=prefill_chunk, seed=seed, spec_tokens=spec_tokens,
+        prefill_budget=prefill_budget, tenants=tenants,
+        page_size=page_size, kv_blocks=kv_blocks,
+        kv_pool_bytes=kv_pool_bytes, kv_quant=kv_quant, kernel=kernel,
+        kv_host_tier_bytes=kv_host_tier_bytes,
+        kv_storage_tier=_build_kv_storage_tier(kv_storage_tier, page_size))
 
     def engine_factory():
-        paged_kw = dict(
-            page_size=page_size, kv_blocks=kv_blocks,
-            kv_pool_bytes=kv_pool_bytes, kv_quant=kv_quant,
-            native_attention=native_attention, kernel=kernel,
-            kv_host_tier_bytes=kv_host_tier_bytes,
-            kv_storage_tier=storage_tier)
         if serve_mesh is not None:
             from lzy_tpu.serving.sharded import ShardedPagedInferenceEngine
 
             engine = ShardedPagedInferenceEngine(
-                cfg, params, tp=serve_mesh, **paged_kw, **common)
-        elif paged:
-            engine = PagedInferenceEngine(cfg, params, **paged_kw, **common)
+                cfg, params, tp=serve_mesh, **engine_kw)
         else:
-            engine = InferenceEngine(cfg, params, **common)
+            engine = PagedInferenceEngine(cfg, params, **engine_kw)
         if warm_start:
             engine.warmup()
         return engine
@@ -421,15 +388,12 @@ def build_gateway_service(
                            or kv_storage_tier is not None)
     kv_index = None
     if kv_global_index:
-        if not paged:
-            raise ValueError("kv_global_index requires paged=True "
-                             "(there are no KV blocks to import)")
         from lzy_tpu.gateway.kv_index import GlobalKVIndex
 
         kv_index = GlobalKVIndex(page_size)
     service = GatewayService(
         fleet,
-        router=router_cls(page_size if paged else prefill_chunk),
+        router=router_cls(page_size),
         autoscaler=autoscaler,
         model_name=model,
         slo=slo,
@@ -469,7 +433,6 @@ def build_disagg_gateway_service(
     kv_blocks: Optional[int] = None,
     kv_pool_bytes: Optional[int] = None,
     kv_quant: Optional[str] = None,
-    native_attention: bool = False,
     kernel: str = "auto",
     kv_host_tier_bytes: Optional[int] = None,
     kv_storage_tier=None,
@@ -525,8 +488,7 @@ def build_disagg_gateway_service(
                   prefill_chunk=prefill_chunk, seed=seed,
                   page_size=page_size, kv_blocks=kv_blocks,
                   kv_pool_bytes=kv_pool_bytes, kv_quant=kv_quant,
-                  native_attention=native_attention, kernel=kernel,
-                  kv_host_tier_bytes=kv_host_tier_bytes,
+                  kernel=kernel, kv_host_tier_bytes=kv_host_tier_bytes,
                   kv_storage_tier=_build_kv_storage_tier(
                       kv_storage_tier, page_size),
                   prefill_budget=prefill_budget, tenants=tenants)
@@ -612,12 +574,10 @@ def build_inference_service(
     checkpoint: Optional[str] = None,
     seed: int = 0,
     prefill_chunk: int = 64,
-    paged: bool = False,
     page_size: int = 16,
     kv_blocks: Optional[int] = None,
     kv_pool_bytes: Optional[int] = None,
     kv_quant: Optional[str] = None,
-    native_attention: bool = False,
     kernel: str = "auto",
     kv_host_tier_bytes: Optional[int] = None,
     kv_storage_tier=None,
@@ -635,16 +595,16 @@ def build_inference_service(
     drills; real deployments pass an Orbax export
     (``parallel.orbax_interop.export_orbax``) of the matching config.
 
-    ``paged=True`` serves from the paged KV-cache pool with radix prefix
-    caching (``serving.PagedInferenceEngine``): ``kv_blocks`` blocks of
-    ``page_size`` tokens shared by all slots (default: the dense
-    equivalent — size it below that to overcommit HBM, above to grow the
-    prefix cache; docs/serving.md has the tradeoffs).
-    ``native_attention=True`` reads KV through the page table in one
-    fused program (``kernel``: pallas/lax/auto) instead of gathering
-    blocks back to the dense layout; ``kv_quant="int8"`` halves pooled
-    KV bytes (~2x blocks at fixed HBM, boundedly-divergent output) —
-    docs/serving.md "Native paged attention & KV quantization".
+    The engine (``serving.PagedInferenceEngine``) serves from the paged
+    KV-cache pool with radix prefix caching: ``kv_blocks`` blocks of
+    ``page_size`` tokens shared by all slots (default: ``max_seq_len``
+    tokens a slot — size it below that to overcommit HBM, above to grow
+    the prefix cache; docs/serving.md has the tradeoffs). Attention reads
+    KV through the page table (``kernel``: auto/pallas/lax — ``auto`` is
+    the Pallas decode kernel on a TPU and the portable lax read anywhere
+    else); ``kv_quant="int8"`` halves pooled KV bytes (~2x blocks at
+    fixed HBM, boundedly-divergent output) — docs/serving.md "Paged
+    attention & KV quantization".
 
     ``spec_tokens`` > 0 enables draft-free speculative decoding
     (``serving/spec.py``): up to that many prompt-lookup draft tokens
@@ -661,37 +621,25 @@ def build_inference_service(
     + KV quotas in the engine (docs/serving.md "Multi-tenant SLO
     serving").
     """
-    from lzy_tpu.serving import InferenceEngine, PagedInferenceEngine
+    from lzy_tpu.serving import PagedInferenceEngine
 
-    _check_paged_only(paged, kv_quant=kv_quant,
-                      native_attention=native_attention, kernel=kernel,
-                      kv_pool_bytes=kv_pool_bytes,
-                      kv_host_tier_bytes=kv_host_tier_bytes,
-                      kv_storage_tier=kv_storage_tier)
-    if serve_mesh is not None and not paged:
-        raise ValueError("serve_mesh (sharded gang replicas) requires "
-                         "paged=True — the sharded engine is paged-only")
     cfg, params = _build_engine_parts(model, checkpoint=checkpoint,
                                       seed=seed)
-    common = dict(slots=slots, max_queue=max_queue, eos_token=eos_token,
-                  prefill_chunk=prefill_chunk, seed=seed,
-                  spec_tokens=spec_tokens, prefill_budget=prefill_budget,
-                  tenants=tenants)
-    paged_kw = dict(
+    engine_kw = dict(
+        slots=slots, max_queue=max_queue, eos_token=eos_token,
+        prefill_chunk=prefill_chunk, seed=seed, spec_tokens=spec_tokens,
+        prefill_budget=prefill_budget, tenants=tenants,
         page_size=page_size, kv_blocks=kv_blocks,
-        kv_pool_bytes=kv_pool_bytes, kv_quant=kv_quant,
-        native_attention=native_attention, kernel=kernel,
+        kv_pool_bytes=kv_pool_bytes, kv_quant=kv_quant, kernel=kernel,
         kv_host_tier_bytes=kv_host_tier_bytes,
         kv_storage_tier=_build_kv_storage_tier(kv_storage_tier, page_size))
     if serve_mesh is not None:
         from lzy_tpu.serving.sharded import ShardedPagedInferenceEngine
 
-        engine: InferenceEngine = ShardedPagedInferenceEngine(
-            cfg, params, tp=serve_mesh, **paged_kw, **common)
-    elif paged:
-        engine = PagedInferenceEngine(cfg, params, **paged_kw, **common)
+        engine: PagedInferenceEngine = ShardedPagedInferenceEngine(
+            cfg, params, tp=serve_mesh, **engine_kw)
     else:
-        engine = InferenceEngine(cfg, params, **common)
+        engine = PagedInferenceEngine(cfg, params, **engine_kw)
     if warm_start:
         engine.warmup()
     if start:

@@ -71,18 +71,13 @@ def main(argv=None) -> int:
                              "shed with UNAVAILABLE)")
     parser.add_argument("--serve-eos-token", type=int, default=None,
                         help="token id that terminates generation early")
-    parser.add_argument("--serve-paged", action="store_true",
-                        help="serve from the paged KV-cache pool with radix "
-                             "prefix caching (shared blocks instead of a "
-                             "dense cache row per slot; docs/serving.md)")
     parser.add_argument("--serve-page-size", type=int, default=64,
-                        help="tokens per KV block under --serve-paged "
+                        help="tokens per KV block of the paged pool "
                              "(must divide the model's max_seq_len)")
     parser.add_argument("--serve-kv-blocks", type=int, default=None,
-                        help="KV block pool size under --serve-paged "
-                             "(default: the dense equivalent; smaller "
-                             "overcommits HBM, larger grows the prefix "
-                             "cache)")
+                        help="KV block pool size (default: max_seq_len "
+                             "tokens a slot; smaller overcommits HBM, "
+                             "larger grows the prefix cache)")
     parser.add_argument("--serve-kv-pool-mb", type=int, default=None,
                         help="size the KV block pool by payload byte "
                              "budget instead of --serve-kv-blocks: "
@@ -92,15 +87,15 @@ def main(argv=None) -> int:
                              "the blocks")
     parser.add_argument("--serve-kv-quant", choices=("int8",),
                         default=None,
-                        help="KV-block quantization under --serve-paged: "
+                        help="KV-block quantization: "
                              "int8 stores pooled K/V at half the bytes "
                              "(~2x resident blocks at fixed HBM; output "
                              "boundedly diverges from fp — docs/"
-                             "serving.md 'Native paged attention & KV "
+                             "serving.md 'Paged attention & KV "
                              "quantization')")
     parser.add_argument("--kv-host-tier-mb", type=int, default=None,
-                        help="tiered KV cache under --serve-paged/--disagg: "
-                             "radix-cache eviction DEMOTES block payloads "
+                        help="tiered KV cache: radix-cache eviction "
+                             "DEMOTES block payloads "
                              "to this much pinned host RAM (LRU) instead "
                              "of dropping them; admission promotes them "
                              "back — warm prefixes survive HBM pressure "
@@ -123,25 +118,19 @@ def main(argv=None) -> int:
                         help="serve every replica as a GANG: the "
                              "prefill/decode/verify forwards run "
                              "tensor-sharded over a 1xN device mesh "
-                             "(requires --serve-paged; composes with "
-                             "--gateway — health/recovery treat the "
+                             "(composes with --gateway — "
+                             "health/recovery treat the "
                              "gang as one replica, one dead host fails "
                              "over the whole gang). Output is "
                              "bit-identical to single-device serving "
                              "(docs/serving.md 'Sharded replicas')")
-    parser.add_argument("--serve-native-attention", action="store_true",
-                        help="native paged-attention read path under "
-                             "--serve-paged: attention reads K/V through "
-                             "the page table in one fused program "
-                             "instead of gathering blocks back to the "
-                             "dense layout each step")
     parser.add_argument("--serve-kernel",
                         choices=("auto", "pallas", "lax"), default="auto",
-                        help="kernel under --serve-native-attention: "
-                             "pallas (decode kernel: reads the live "
-                             "context only), lax (portable, bit-identical "
-                             "to the legacy gather), auto is pallas, and "
-                             "lax under --serve-mesh")
+                        help="how attention reads the pool through the "
+                             "page table: pallas (decode kernel: reads "
+                             "the live context only; compiles for a TPU), "
+                             "lax (portable), auto is pallas on a TPU and "
+                             "lax anywhere else and under --serve-mesh")
     parser.add_argument("--serve-spec", action="store_true",
                         help="draft-free speculative decoding: n-gram "
                              "prompt lookup proposes up to --spec-tokens "
@@ -277,29 +266,13 @@ def main(argv=None) -> int:
         parser.error("--disagg requires --serve-model")
     if args.disagg and args.gateway:
         parser.error("--disagg IS a gateway mode; pass one or the other")
-    if (args.serve_kv_quant or args.serve_native_attention
-            or args.serve_kernel != "auto"
-            or args.serve_kv_pool_mb is not None
-            or args.kv_host_tier_mb is not None
-            or args.kv_storage_tier is not None) \
-            and not (args.serve_paged or args.disagg):
-        parser.error("--serve-kv-quant/--serve-native-attention/"
-                     "--serve-kernel/--serve-kv-pool-mb/"
-                     "--kv-host-tier-mb/--kv-storage-tier need the paged "
-                     "cache (--serve-paged or --disagg)")
-    if args.serve_kernel != "auto" and not args.serve_native_attention:
-        parser.error("--serve-kernel picks the --serve-native-attention "
-                     "kernel; without it the legacy path serves")
     if args.serve_kv_pool_mb is not None and args.serve_kv_blocks is not None:
         parser.error("pass --serve-kv-blocks or --serve-kv-pool-mb, "
                      "not both")
     if args.serve_mesh is not None:
         if args.serve_mesh < 2:
             parser.error("--serve-mesh needs N >= 2 (a 1-device mesh is "
-                         "just --serve-paged)")
-        if not args.serve_paged:
-            parser.error("--serve-mesh requires --serve-paged (the "
-                         "sharded engine serves from the paged pool)")
+                         "the engine without it)")
         if args.disagg:
             parser.error("--serve-mesh does not compose with --disagg "
                          "yet; use --gateway")
@@ -384,7 +357,6 @@ def main(argv=None) -> int:
                 kv_blocks=args.serve_kv_blocks,
                 kv_pool_bytes=kv_pool_bytes,
                 kv_quant=args.serve_kv_quant,
-                native_attention=args.serve_native_attention,
                 kernel=args.serve_kernel,
                 kv_host_tier_bytes=kv_host_tier_bytes,
                 kv_storage_tier=args.kv_storage_tier,
@@ -413,12 +385,10 @@ def main(argv=None) -> int:
                 max_queue=args.serve_queue,
                 eos_token=args.serve_eos_token,
                 checkpoint=args.model_checkpoint,
-                paged=args.serve_paged,
                 page_size=args.serve_page_size,
                 kv_blocks=args.serve_kv_blocks,
                 kv_pool_bytes=kv_pool_bytes,
                 kv_quant=args.serve_kv_quant,
-                native_attention=args.serve_native_attention,
                 kernel=args.serve_kernel,
                 kv_host_tier_bytes=kv_host_tier_bytes,
                 kv_storage_tier=args.kv_storage_tier,
@@ -441,12 +411,10 @@ def main(argv=None) -> int:
             max_queue=args.serve_queue,
             eos_token=args.serve_eos_token,
             checkpoint=args.model_checkpoint,
-            paged=args.serve_paged,
             page_size=args.serve_page_size,
             kv_blocks=args.serve_kv_blocks,
             kv_pool_bytes=kv_pool_bytes,
             kv_quant=args.serve_kv_quant,
-            native_attention=args.serve_native_attention,
             kernel=args.serve_kernel,
             kv_host_tier_bytes=kv_host_tier_bytes,
             kv_storage_tier=args.kv_storage_tier,

@@ -7,15 +7,16 @@ decode slot busy (the two mechanisms the Gemma-on-TPU study credits with
 most TPU serving throughput: single-pass prefill and continuous batching).
 
 - ``scheduler``: request admission — a bounded FIFO with backpressure.
-- ``engine``: the fixed-capacity slot batch. New requests are prefilled
-  (one forward pass per bucketed prompt chunk, not one per token) into a
-  fresh batch-1 cache and spliced into a free slot of the live decode
-  batch; finished slots free on EOS/limit; one jitted decode step advances
-  every active slot at once and the loop idles when all slots drain.
+- ``engine``: ``PagedInferenceEngine``, the fixed-capacity slot batch over
+  a paged KV pool. New requests are prefilled (one forward pass per
+  bucketed prompt chunk, not one per token) into the pool's blocks and
+  take a free slot of the live decode batch; finished slots free on
+  EOS/limit; one jitted decode step advances every active slot at once and
+  the loop idles when all slots drain.
 - ``kv_cache``: the paged KV block pool + ref-counted radix prefix tree
-  behind ``PagedInferenceEngine`` — per-request page tables instead of
-  dense per-slot rows, prefill skipped for cached prompt prefixes, LRU
-  eviction of unreferenced blocks under memory pressure.
+  behind the engine — per-request page tables, prefill skipped for cached
+  prompt prefixes, LRU eviction of unreferenced blocks under memory
+  pressure.
 - ``spec``: draft-free speculative decoding — n-gram prompt-lookup
   proposals verified by one batched multi-position forward; greedy rows
   emit up to ``spec_tokens+1`` tokens per decode step, bit-identical to
@@ -24,7 +25,7 @@ most TPU serving throughput: single-pass prefill and continuous batching).
 - ``tenancy``: the multi-tenant SLO layer — tenant policies (priority
   tiers, token-bucket rate limits, KV-block quotas, queue caps) enforced
   at admission, weighted fair queueing in the scheduler, chunked-prefill
-  interleaving in the engines so one tenant's 32k-token prompt cannot
+  interleaving in the engine so one tenant's 32k-token prompt cannot
   starve another tenant's token stream.
 - ``streams``: server-streamed delivery over the RPC plane — chunked
   long-poll frames whose position IS the gateway failover fence, with
@@ -36,8 +37,7 @@ Expose over the control plane with ``lzy_tpu.service.inference`` (the
 ``--serve-model`` flag of ``lzy_tpu.service.serve``).
 """
 
-from lzy_tpu.serving.engine import (
-    EngineStats, InferenceEngine, PagedInferenceEngine)
+from lzy_tpu.serving.engine import EngineStats, PagedInferenceEngine
 from lzy_tpu.serving.kv_cache import (
     BlockPool, KVCacheStats, NoFreeBlocks, RadixCache)
 from lzy_tpu.serving.kv_tier import HostKVTier, StorageKVTier
@@ -56,7 +56,6 @@ __all__ = [
     "DecodeEngine",
     "EngineStats",
     "HostKVTier",
-    "InferenceEngine",
     "KVCacheStats",
     "NgramProposer",
     "NoFreeBlocks",

@@ -1,26 +1,43 @@
-"""Continuous-batching inference engine: a fixed slot batch over one model.
+"""The serving engine: continuous batching over a paged KV pool.
 
-The decode hot loop is ONE jitted step over a ``[slots, ...]`` KV cache
-whose per-row positions live in a ``[slots]`` cache index
-(``decode_slot_index`` of ``models/llama.py``'s configuration). Which
-modules a configuration runs is asked of the configuration object, and what
-kinds of cache leaves they keep of the module class (the protocol is
-written down in ``models/serving.py``): this file names no model. Requests
-are admitted mid-flight:
+One class, :class:`PagedInferenceEngine`, serves ``generate``-style
+requests from a fixed batch of ``slots`` rows over one model. Which modules
+a configuration runs is asked of the configuration object, and what kinds
+of cache leaves they keep of the module class (the protocol is written down
+in ``models/serving.py``): this file names no model.
 
-- **prefill on arrival**: the prompt runs through the model as batch-1
-  bucketed chunks (``models.generate.batched_prefill`` — one forward pass
-  per chunk, not per token), producing the request's first token and a
-  fresh ``[1, L, ...]`` cache that is spliced into a free slot of the live
-  batch between decode steps. A request admitted mid-decode starts
-  generating on the very next step — nobody waits for the running batch to
-  drain.
-- **slot free on EOS**: a finished row leaves its slot immediately; the
-  slot's cache rows are fully overwritten by the next insertion and the
-  causal mask never lets a new request see a predecessor's keys (index is
-  reset on free), so tokens cannot leak across requests.
-- **all-done early exit**: with every slot idle the loop parks on the
-  queue's event instead of spinning the device.
+- **One pool, page tables, a radix tree** (``serving/kv_cache.py``): keys
+  and values live in one pool of ``page_size``-token blocks shared by all
+  slots; a request holds a page table and commits HBM page by page as it
+  grows. Prompts are matched against a ref-counted radix tree of cached
+  blocks, so only the unmatched suffix is prefilled; admission is budgeted
+  against free + evictable blocks, eviction removes unreferenced cached
+  blocks (LRU), and a squeeze on decode growth preempts the YOUNGEST row
+  (a clean ``preempted`` error), never corrupts one.
+- **One read path decision, made by the code**: attention reads the pool
+  through the page table (``ops/paged_attention.py``). ``kernel="auto"``
+  is, on a TPU, the Pallas decode kernel for the programs whose shape it
+  is written for (``kernel_path``: decode and verify windows over a float
+  pool) and the portable ``lax`` read for the rest (prefill chunks, int8
+  pools); ``"lax"`` is also what ``"auto"`` is off the TPU, the sharded
+  gang's read, and the tests' bit-exact reference against
+  ``models/generate.py``.
+- **Prefill on arrival, in budgeted chunks**: a prompt's suffix runs
+  through the model as batch-1 bucketed chunks against the same pool, at
+  most ``prefill_budget`` tokens a scheduling round, interleaved with the
+  resident rows' decode steps; the finished job's slot starts generating
+  on the very next step. A model with per-slot ``state`` leaves carries
+  the job's own batch-1 rows between chunks.
+- **One jitted step a round, one fence**: the decode hot loop is ONE jitted
+  step over the ``[slots]`` rows, whose positions live in one ``[slots]``
+  vector; a finished row leaves its slot immediately (its blocks go back to
+  the pool or stay cached in the tree), and with every slot idle the loop
+  parks on the queue's event instead of spinning the device.
+- **Deadlines, tenants, KV I/O**: per-request deadlines and dead clients
+  evict mid-decode with a ``cancelled`` status; WFQ, queue caps and KV
+  quotas come from a ``TenantTable``; cross-replica KV import / export,
+  the host and storage tiers and parked conversation chains are serviced
+  between rounds on the scheduling thread.
 
 Sampling is engine-wide (greedy by default). Under ``temperature>0`` the
 rng stream is shared by the whole batch, so a request's sampled tokens
@@ -31,13 +48,7 @@ TTFT, generated tokens, decode step latency, queue depth and slot
 occupancy are exported via ``lzy_tpu.utils.metrics.REGISTRY`` (scraped by
 ``/metrics`` on both the console and the metrics server).
 
-:class:`PagedInferenceEngine` (below) swaps the dense per-slot cache rows
-for a shared paged block pool with radix prefix caching
-(``lzy_tpu/serving/kv_cache.py``): prefill runs only the unmatched prompt
-suffix, admission is budgeted against blocks instead of raw slots, and
-per-request deadlines evict mid-decode with a ``cancelled`` status.
-
-With ``spec_tokens > 0`` both engines run **draft-free speculative
+With ``spec_tokens > 0`` the engine runs **draft-free speculative
 decoding** (``lzy_tpu/serving/spec.py``): an n-gram prompt-lookup
 proposer drafts up to ``spec_tokens`` continuation tokens per greedy row,
 ONE multi-position verify forward scores all of them (``[slots,
@@ -45,11 +56,15 @@ spec_tokens+1]`` query positions — a fixed width, so exactly one extra
 compiled program), and the longest proposal prefix matching the model's
 own argmax is accepted — up to ``spec_tokens+1`` tokens per decode step,
 bit-identical to non-speculative greedy decode by construction. Rejected
-positions are rolled back: the per-row cache index rewinds, and the
-paged engine additionally returns any wholly-rejected growth block to
-the pool (refcounted/resident blocks are never touched), so a failed
-speculation is invisible to the radix cache. Sampled rows in the same
-batch decode one token per step from the same rng draw order as before.
+positions are rolled back: the per-row cache index rewinds and any
+wholly-rejected growth block returns to the pool (refcounted/resident
+blocks are never touched), so a failed speculation is invisible to the
+radix cache. Sampled rows in the same batch decode one token per step
+from the same rng draw order as before.
+
+``serving/sharded`` (a gang over a mesh) and ``serving/disagg`` (prefill
+and decode pools) subclass the engine and change where arrays live or what
+a finished prefill does; the scheduler is theirs unchanged.
 """
 
 from __future__ import annotations
@@ -65,9 +80,7 @@ import numpy as np
 
 from lzy_tpu.chaos.faults import CHAOS, CRASH, DELAY, ERROR, SLOW
 from lzy_tpu.models import serving
-from lzy_tpu.models.generate import (
-    _set_cache_index, init_cache, make_prefill_step, prefill_plan,
-    sample_token)
+from lzy_tpu.models.generate import init_cache, prefill_plan, sample_token
 from lzy_tpu.serving.scheduler import (
     AdmissionError, PromptTooLong, Request, RequestQueue)
 from lzy_tpu.serving.tenancy import (
@@ -89,8 +102,7 @@ class PoolCorruption(RuntimeError):
     """A device call failed AFTER the shared KV block pool's buffers were
     donated into it — the pool is gone, so the failure is engine-fatal
     (the loop's death handler fails all outstanding requests), never
-    request-scoped like a dense prefill failure (whose donated cache was
-    private to the request)."""
+    request-scoped like a staging failure (which touched nothing shared)."""
 
 _TTFT = REGISTRY.histogram(
     "lzy_inference_ttft_seconds",
@@ -206,18 +218,17 @@ class _PrefillJob:
     plan: list                      # [(start, take, width)] over suffix
     next_chunk: int = 0
     done: int = 0                   # suffix tokens already prefilled
-    cache: Any = None               # dense: private [1, ...] cache
     last: Any = None                # logits at the last real position
-    matched: int = 0                # paged: radix-matched prompt prefix
-    table: list = dataclasses.field(default_factory=list)  # paged blocks
+    matched: int = 0                # radix-matched prompt prefix
+    table: list = dataclasses.field(default_factory=list)  # pool blocks
     # device arrays invariant for the job's lifetime, uploaded once on
     # the first round (a 32k prompt at budget 256 runs ~128 rounds —
     # re-uploading the prompt and page table every round would repeat
     # the host-to-device transfer on the decode-interleaved path the
     # budget exists to keep short)
     tokens_dev: Any = None          # [1, len] prompt / suffix ids
-    pt_dev: Any = None              # paged: [1, pages] page table
-    # state leaves (paged engine, models with per-slot state): the job's
+    pt_dev: Any = None              # [1, pages] page table
+    # state leaves (models with per-slot state): the job's
     # own batch-1 rows, carried from chunk to chunk — the slot's rows in
     # the decode tree are not touched until the prompt is done
     state: Any = None
@@ -242,8 +253,7 @@ class EngineStats:
     requests_finished: int
     tokens_generated: int
     requests_cancelled: int = 0
-    # KV paging fields (PagedInferenceEngine only; None on the dense
-    # engine and omitted from doc() so the wire schema stays stable)
+    # KV paging fields (doc() leaves out what a producer does not fill)
     kv_page_size: Optional[int] = None
     kv_blocks_total: Optional[int] = None
     kv_blocks_free: Optional[int] = None
@@ -257,7 +267,7 @@ class EngineStats:
     kv_export_blocks: Optional[int] = None
     kv_imports: Optional[int] = None
     kv_import_blocks: Optional[int] = None
-    # tiered KV cache fields (paged engines with a host/storage tier —
+    # tiered KV cache fields (engines with a host/storage tier —
     # serving/kv_tier.py): occupancy of the host rung plus the demotion/
     # promotion ladder counters; None (and off the wire) without a tier
     kv_host_tier_blocks: Optional[int] = None
@@ -266,7 +276,7 @@ class EngineStats:
     kv_tier_promotions: Optional[int] = None
     kv_tier_dropped: Optional[int] = None
     kv_storage_tier_blocks: Optional[int] = None
-    # workflow-aware scheduling (paged engines): conversation chains
+    # workflow-aware scheduling: conversation chains
     # currently parked across fused op-chain tool gaps, and the blocks
     # they pin resident
     kv_parked_chains: Optional[int] = None
@@ -278,13 +288,12 @@ class EngineStats:
     spec_acceptance_rate: Optional[float] = None
     spec_verify_steps: Optional[int] = None
     spec_tokens_per_step: Optional[float] = None
-    # drafts truncated by _grow_for_spec's NoFreeBlocks backstop (paged
-    # engines; a silent perf cliff until it was counted — a pool sized
-    # too tight quietly degrades speculation to 1-token steps)
+    # drafts truncated by _grow_for_spec's NoFreeBlocks backstop (a
+    # silent perf cliff until it was counted — a pool sized too tight
+    # quietly degrades speculation to 1-token steps)
     spec_draft_truncated: Optional[int] = None
-    # native paged-attention fields (PagedInferenceEngine only): which
-    # kernel the decode/verify/prefill programs read KV through
-    # (pallas/lax/legacy) and the active KV quantization mode
+    # which kernel the decode step reads KV through (pallas/lax) and the
+    # active KV quantization mode
     kernel_path: Optional[str] = None
     kv_quant: Optional[str] = None
 
@@ -293,12 +302,22 @@ class EngineStats:
                 if v is not None}
 
 
-class InferenceEngine:
-    """Serve ``generate``-style requests from a shared slot batch.
+class PagedInferenceEngine:
+    """Serve ``generate``-style requests from a shared slot batch over a
+    paged KV pool with radix prefix reuse (the module docstring has the
+    design).
 
     Drive it either with the background loop (``start()``/``close()``, the
     serving-front mode) or synchronously with ``step()`` from one thread
     (the deterministic test mode) — not both at once.
+
+    ``kv_blocks`` (or ``kv_pool_bytes``) sizes the pool: the default gives
+    every slot ``max_seq_len`` tokens of blocks, less overcommits HBM
+    (short requests stop paying for the longest possible one), more grows
+    the prefix cache's working set. Greedy output under the ``lax`` read is
+    bit-identical to the solo ``generate()`` oracle, and so are sampled
+    draws under one seed; the Pallas kernel is within a written tolerance
+    of it.
     """
 
     def __init__(
@@ -307,6 +326,18 @@ class InferenceEngine:
         params: Any,
         *,
         slots: int = 4,
+        page_size: int = 16,
+        kv_blocks: Optional[int] = None,
+        kv_pool_bytes: Optional[int] = None,
+        kv_quant: Optional[str] = None,
+        # tombstone: the benchmark's configuration files pass
+        # ``"native_attention": true``; the benchmark issue that takes it
+        # out there (ROADMAP B2) removes the keyword here
+        native_attention: bool = True,
+        kernel: str = "auto",
+        kv_host_tier_bytes: Optional[int] = None,
+        kv_storage_tier=None,
+        kv_tier=None,
         max_queue: int = 64,
         temperature: float = 0.0,
         top_k: Optional[int] = None,
@@ -321,6 +352,12 @@ class InferenceEngine:
         tenants=None,
         clock=None,
     ):
+        from lzy_tpu.ops.interpret import resolve as pallas_interpreted
+        from lzy_tpu.ops.paged_attention import (
+            DISPATCHES, QUANT_BLOCKS_RESIDENT, default_kernel,
+            lower_pallas_for_tpu)
+        from lzy_tpu.serving.kv_cache import RadixCache, blocks_for_bytes
+
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
         if spec_tokens < 0:
@@ -333,6 +370,38 @@ class InferenceEngine:
             raise ValueError(
                 f"spec_tokens ({spec_tokens}) must leave room in "
                 f"max_seq_len ({base.max_seq_len})")
+        if page_size < 1 or base.max_seq_len % page_size:
+            raise ValueError(
+                f"page_size ({page_size}) must divide max_seq_len "
+                f"({base.max_seq_len})")
+        if kv_quant not in (None, "int8"):
+            raise ValueError(
+                f"unknown kv_quant {kv_quant!r}; known: int8")
+        if kernel not in ("auto", "lax", "pallas"):
+            raise ValueError(
+                f"unknown kernel {kernel!r}; known: auto, lax, pallas")
+        if not native_attention:
+            raise ValueError(
+                "native_attention=False: the gather read (the pool copied "
+                "back into a dense [B, L, KV, D] layout every step) is "
+                "gone; kernel='lax' reads through the page table and gives "
+                "the same bits")
+        if kernel == "pallas" and kv_quant is not None:
+            raise ValueError(
+                "kernel='pallas' reads float pools only; an int8 pool "
+                "is served by kernel='lax' (or 'auto')")
+        self._page = page_size
+        self._pages_per_seq = base.max_seq_len // page_size
+        self._kv_quant = kv_quant
+        # kernel selection (docs/serving.md): "auto" is, on a TPU, the
+        # kernel that compiles there (the Pallas decode kernel) and the
+        # portable lax read anywhere else; "pallas" is taken at the
+        # caller's word and checked below. The kernel takes
+        # the programs whose shape it is written for
+        # (ops.paged_attention.kernel_path) and leaves the rest to lax:
+        # kernel_path is the decode step's.
+        self._paged_kernel = default_kernel() if kernel == "auto" \
+            else kernel
         self.cfg = base
         self.params = params
         self.slots = slots
@@ -378,9 +447,8 @@ class InferenceEngine:
         # between rounds and the host uploads nothing; only admission
         # (``_finish_prefill``) forces a re-upload. Idle rows drift in
         # the device copies (stale token/position garbage) — harmless by
-        # construction: rows are independent, idle writes land on masked
-        # positions (dense) or the scratch block (paged), and idle
-        # outputs are never read.
+        # construction: rows are independent, idle writes land on the
+        # scratch block, and idle outputs are never read.
         self._cur_dev: Any = None        # [slots] int32 last tokens
         self._pos_dev: Any = None        # [slots] int32 cache positions
         self._mask_dev: Any = None       # [slots] bool greedy mask
@@ -396,6 +464,104 @@ class InferenceEngine:
         # measurable host overhead in the decode hot loop)
         self._round_tokens: dict = {}
 
+        self.kernel_path = self._path_of(1)
+        self._dispatches = DISPATCHES
+        # the resident gauge is process-global and this process may run
+        # several quantized pools (disagg: prefill + decode); each engine
+        # contributes its own delta so the exported value is the SUM, and
+        # close() withdraws the contribution (no stale reading after a
+        # drain)
+        self._quant_resident = QUANT_BLOCKS_RESIDENT
+        self._quant_resident_seen = 0
+        self._quant_resident_lock = threading.Lock()
+        if kv_pool_bytes is not None:
+            if kv_blocks is not None:
+                raise ValueError(
+                    "pass kv_blocks or kv_pool_bytes, not both")
+            # size the pool by its HBM payload budget: int8 blocks are
+            # half the bytes of bf16 blocks, so the same budget holds
+            # ~2x the blocks — the whole point of kv_quant
+            kv_blocks = blocks_for_bytes(
+                kv_pool_bytes, page_size=page_size,
+                n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
+                n_layers=base.kv_layers, dtype=base.dtype,
+                kv_quant=kv_quant)
+        if kv_blocks is None:
+            # dense-equivalent HBM by default (+1 scratch); pass less to
+            # overcommit, more to grow the prefix cache's working set
+            kv_blocks = slots * self._pages_per_seq + 1
+        if kv_blocks < 2:
+            raise ValueError(f"kv_blocks must be >= 2, got {kv_blocks}")
+        self._kv_blocks = kv_blocks
+        if self.kernel_path == "pallas" and not pallas_interpreted(None):
+            # no silent drop to the interpreter or to lax: what the TPU
+            # lowering refuses, it refuses here, before a pool exists
+            lower_pallas_for_tpu(
+                batch=slots, n_heads=base.n_heads,
+                n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
+                n_blocks=kv_blocks, page_size=page_size,
+                pages_per_seq=self._pages_per_seq, dtype=base.dtype)
+            base.check_kernels(slots=slots)
+        self.kv = RadixCache(kv_blocks, page_size)
+        # tiered KV cache (serving/kv_tier.py): radix eviction DEMOTES
+        # block payloads to pinned host RAM (and onward to storage)
+        # instead of dropping them; admission PROMOTES them back. The
+        # tier is advisory end to end — every failure path degrades to
+        # classic eviction / local re-prefill.
+        if kv_tier is not None:
+            self.kv_tier = kv_tier
+        elif kv_host_tier_bytes is not None or kv_storage_tier is not None:
+            from lzy_tpu.serving.kv_tier import HostKVTier
+
+            self.kv_tier = HostKVTier(kv_host_tier_bytes or 0, page_size,
+                                      storage=kv_storage_tier)
+        else:
+            self.kv_tier = None
+        if self.kv_tier is not None:
+            self.kv.on_evict = self._demote_block
+            self.kv.on_evict_batch = self._demote_blocks
+            self.kv.on_insert = self.kv_tier.discard
+        # device→host gather accounting for the demotion path: one
+        # BATCHED gather per cache leaf per eviction round (not one per
+        # evicted block) — the count-of-transfers contract the batching
+        # test pins
+        self.kv_tier_gather_ops = 0
+        self.kv_tier_gather_rounds = 0
+        # cross-replica / disagg import queue: transferred KVBlockExports
+        # fold into the pool+tree between engine steps, strictly before
+        # admissions (a queued import is resident by the time the request
+        # that wants it prefills); export requests are the outbound twin,
+        # serviced on THIS thread so the device→host gather never races a
+        # donating prefill
+        self._pending_imports: List[Any] = []
+        self._export_requests: List[tuple] = []
+        # parked conversation chains (workflow-aware scheduling): key ->
+        # _ParkedChain with its radix blocks pinned so a fused op
+        # chain's tool gap cannot evict the conversation KV. Mutated
+        # only on the scheduling thread (cross-thread callers queue
+        # through _park_requests, the request_kv_export pattern);
+        # bounded by the TTL sweep in step(), shed under pool pressure
+        # strictly before any resident request is preempted, and
+        # released wholesale at close().
+        self._parked: Dict[str, _ParkedChain] = {}
+        self._park_requests: List[tuple] = []
+        self._kv_io_lock = threading.Lock()
+        self.kv_imports = 0
+        self.kv_import_blocks = 0
+        # page tables: [slots, pages_per_seq] block ids (0 = scratch pad);
+        # _slot_blocks mirrors the allocated prefix of each row in python
+        self._tables = np.zeros((slots, self._pages_per_seq), np.int32)
+        # device mirror of _tables, uploaded once and reused until a
+        # table write dirties it (upload-once discipline — see
+        # _page_table_dev); every _tables mutation site sets it to None
+        self._pt_dev = None
+        self._slot_blocks: List[List[int]] = [[] for _ in range(slots)]
+        # per-row cached-token counts live in _pos
+        self._admit_seq = np.zeros((slots,), np.int64)  # admission order
+        self._admissions = 0
+        self._packed_out = None      # tokens + model counts of this round
+        self._stat_counters: tuple = ()
+        self._dispatch_paths: dict = {}   # positions a row -> path labels
         self._build_decode_path(base)
 
         # chunked-prefill interleaving: at most ``prefill_budget`` prompt
@@ -429,7 +595,7 @@ class InferenceEngine:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_steps = 0
-        self.spec_draft_truncated = 0   # paged: drafts cut by NoFreeBlocks
+        self.spec_draft_truncated = 0   # drafts cut by NoFreeBlocks
         self.decode_steps = 0     # decode rounds (normal + verify)
         self.decode_rows = 0      # cumulative active rows over rounds
         self.decode_tokens = 0    # tokens emitted by decode rounds
@@ -446,64 +612,8 @@ class InferenceEngine:
         _SLOTS.set(float(slots))
         _BUSY.set(0.0)
 
-    def _build_decode_path(self, base: Any) -> None:
-        """Construct models, caches and jitted steps (the paged engine
-        overrides this with its pooled-cache counterparts)."""
-        slots = self.slots
-        # decode model: [slots] per-row cache positions; prefill model:
-        # batch-1, scalar index (what batched_prefill writes)
-        self._model, self._prefill_model = base.dense_models()
-        self._adopt_cache(init_cache(lambda: self._model.init(
-            jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32))))
-        self._prefill_step = make_prefill_step(self._prefill_model)
-        # abstract cache shapes ONCE: tracing the full model init on every
-        # admission would sit directly on the TTFT path
-        self._prefill_cache_shapes = jax.eval_shape(
-            lambda: self._prefill_model.init(
-                jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32))
-        )["cache"]
-
-        # the jitted steps take the PAYLOAD leaves plus an explicit
-        # [slots] position vector and assemble the per-layer index leaves
-        # inside the trace (see _adopt_cache): only payload is donated,
-        # only payload (plus ONE advanced position vector) comes back, so
-        # the aliasing class that used to force per-round index rebuilds
-        # cannot exist — there is nothing to alias
-        def decode_step(payload, params, cur, pos, greedy_mask, rng):
-            cache = self._assemble_cache(payload, pos)
-            logits, updated = self._model.apply(
-                {"params": params, "cache": cache}, cur[:, None],
-                mutable=["cache"])
-            nxt, rng = self._pick_next(logits[:, -1], greedy_mask, rng)
-            payload, new_pos = self._split_cache(updated["cache"])
-            return payload, new_pos, nxt, rng
-
-        self._decode_step = jax.jit(decode_step, donate_argnums=(0,))
-
-        def verify_step(payload, params, cur, prop, prop_len, pos,
-                        greedy_mask, rng):
-            # speculative verify: the forward scores [B, gamma+1] = the
-            # last emitted token plus each row's (padded) proposal. ONE
-            # chunked decode forward writes all positions into the cache
-            # and returns logits for all of them; argmax over every
-            # position is the acceptance reference, while sampled rows
-            # draw their single token from position 0 — the same logits
-            # (and the same one rng split) a 1-token step would have
-            # used. Acceptance itself is computed HERE, on device
-            # (_accept): the round's only host transfer is the packed
-            # [B, gamma+2] emit matrix it returns.
-            cache = self._assemble_cache(payload, pos)
-            toks = jnp.concatenate([cur[:, None], prop], axis=1)
-            logits, updated = self._model.apply(
-                {"params": params, "cache": cache}, toks, mutable=["cache"])
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt, rng = self._pick_next(logits[:, 0], greedy_mask, rng)
-            payload, _ = self._split_cache(updated["cache"])
-            packed, new_cur, new_pos = self._accept(prop, prop_len, greedy,
-                                                    nxt, pos)
-            return payload, packed, new_cur, new_pos, rng
-
-        self._verify_step = jax.jit(verify_step, donate_argnums=(0,))
+        if self._has_state:
+            self._refuse_for_state()
 
     # -- cache payload/treedef split ---------------------------------------
 
@@ -682,9 +792,29 @@ class InferenceEngine:
             # DRAINING engine still finishes its in-flight rows but must
             # not take on new ones — the graceful-shutdown contract.
             raise AdmissionError("inference engine is shut down")
+        from lzy_tpu.serving.kv_cache import blocks_for
+
         prompt = list(prompt)
         if not prompt:
             raise ValueError("prompt must be non-empty")
+        # reject prompts the pool — or the tenant's quota — can NEVER
+        # cover: past submit they would park in the queue forever
+        # (admission waits for blocks that cannot exist) and waste a
+        # tenant's WFQ share on an unservable head
+        if blocks_for(len(prompt), self._page) > self._kv_blocks - 1:
+            raise PromptTooLong(
+                f"prompt ({len(prompt)} tokens) needs "
+                f"{blocks_for(len(prompt), self._page)} KV blocks but the "
+                f"pool only has {self._kv_blocks - 1}; raise kv_blocks or "
+                f"shorten the prompt")
+        quota = self._tenant_quota(tenant or "default")
+        if quota is not None \
+                and blocks_for(len(prompt), self._page) > quota:
+            raise PromptTooLong(
+                f"prompt ({len(prompt)} tokens) needs "
+                f"{blocks_for(len(prompt), self._page)} KV blocks but "
+                f"tenant {tenant!r} is capped at {quota}; shorten the "
+                f"prompt or raise the tenant's kv_block_quota")
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got "
                              f"{max_new_tokens}")
@@ -775,11 +905,6 @@ class InferenceEngine:
                 trace.note(kind="prefill_only" if worked else "idle")
         return worked
 
-    def _service_io(self) -> bool:
-        """Round work ahead of the reap and the admissions; the paged
-        engine services cross-replica KV I/O and parked chains here."""
-        return False
-
     def _reap_cancelled(self) -> None:
         """Free slots whose waiter abandoned the request (client
         timeout), whose client deadline passed, or whose reply channel
@@ -794,7 +919,7 @@ class InferenceEngine:
         for job in list(self._prefill_jobs):
             if job.req.reapable:
                 # a mid-prefill abandon releases everything staged (the
-                # paged engine returns the job's blocks to the pool)
+                # job's blocks go back to the pool)
                 self._abort_prefill_job(job)
                 self._finish_cancelled(job.req)
         for slot, req in enumerate(self._active):
@@ -837,21 +962,6 @@ class InferenceEngine:
                     "requests_cancelled": 0, "requests_preempted": 0,
                     "requests_error": 0}
             d[key] += n
-
-    def _can_admit(self, req: Request) -> bool:
-        """Resource gate checked BEFORE popping a candidate; the dense
-        engine only needs the free slot the caller already found. The
-        paged engine overrides this with its KV block budget."""
-        return True
-
-    def _admit_verdict(self, req: Request) -> str:
-        """``"admit"`` (pop and stage), ``"wait"`` (global capacity —
-        the whole queue waits so big prompts are never starved by
-        smaller late arrivals), or ``"skip"`` (a *tenant-scoped* limit:
-        this tenant's head steps aside without blocking other tenants'
-        admissible heads — one tenant's quota must never become another
-        tenant's latency)."""
-        return "admit" if self._can_admit(req) else "wait"
 
     def _free_slot(self) -> Optional[int]:
         """A slot neither active nor reserved by a pending prefill job."""
@@ -957,18 +1067,6 @@ class InferenceEngine:
 
     # -- chunked prefill (the _PrefillJob state machine) ---------------------
 
-    def _stage_prefill(self, slot: int, req: Request) -> _PrefillJob:
-        """Allocate everything a prefill needs (dense: a private batch-1
-        cache) WITHOUT running device work — the budgeted advance does
-        that. Failures here are request-scoped (nothing shared was
-        touched)."""
-        cache = jax.tree_util.tree_map(
-            lambda s: jnp.zeros(s.shape, s.dtype),
-            self._prefill_cache_shapes)
-        plan = prefill_plan(len(req.prompt), self.prefill_chunk,
-                            self.cfg.max_seq_len)
-        return _PrefillJob(req=req, slot=slot, plan=plan, cache=cache)
-
     def _advance_prefill(self) -> bool:
         """Advance ONE pending prefill job by at most ``prefill_budget``
         prompt tokens (all of them when the budget is None), rotating
@@ -989,8 +1087,7 @@ class InferenceEngine:
             finished = self._advance_prefill_round(job)
         except PoolCorruption:
             raise            # engine-fatal: the shared pool was donated
-        except Exception as e:  # noqa: BLE001 — request-scoped (dense:
-            # the half-built cache was private to this request)
+        except Exception as e:  # noqa: BLE001 — request-scoped
             _LOG.warning("prefill failed for %s: %s", req.id, e)
             _REQUESTS.inc(status="error")
             TENANT_REQUESTS.inc(tenant=req.tenant, status="error")
@@ -1017,9 +1114,14 @@ class InferenceEngine:
 
     def _abort_prefill_job(self, job: _PrefillJob) -> None:
         """Release a job's staged resources without finishing its
-        request (the caller decides the terminal status); the paged
-        engine returns the staged blocks to the pool."""
+        request (the caller decides the terminal status)."""
         self._drop_prefill_job(job)
+        # drop the staged refs: matched prefix blocks fall back to
+        # cached, freshly-owned ones return to the free list (their
+        # half-written K/V is dead weight a future holder overwrites
+        # during its own prefill, same as any freed slot's blocks)
+        self.kv.release(job.table)
+        job.table = []
 
     def _run_prefill_chunks(self, job: _PrefillJob, cache, arr, run_chunk):
         """Shared budget loop: run chunks of ``job.plan`` through
@@ -1041,41 +1143,6 @@ class InferenceEngine:
                     and job.next_chunk < len(job.plan):
                 return cache, False
         return cache, True
-
-    def _advance_prefill_round(self, job: _PrefillJob) -> bool:
-        """One budgeted round of a DENSE prefill; True when the job
-        finished (slot activated). The chunk plan — and with it every
-        device call — is identical to the one-shot path; only the wall-
-        clock interleaving with decode steps differs, so greedy output
-        is bit-identical chunked or not."""
-        req = job.req
-        if job.tokens_dev is None:
-            job.tokens_dev = jnp.asarray([req.prompt], jnp.int32)
-        cache, finished = self._run_prefill_chunks(
-            job, job.cache, job.tokens_dev,
-            lambda c, tokens, take: self._prefill_step(
-                c, self.params, tokens, jnp.asarray(take - 1, jnp.int32)))
-        if not finished:
-            job.cache = cache
-            return False
-        job.cache = None
-        _, last_take, last_width = job.plan[-1]
-        if last_take != last_width:
-            # final chunk was padded: rewind the index to the true length
-            cache = _set_cache_index(cache, len(req.prompt))
-        first, self._rng = self._pick_first(job.last, req)
-        slot = job.slot
-
-        # splice the prefilled batch-1 cache into the slot's rows; the
-        # scalar index leaves land in the [slots] index at this row
-        def ins(big, small):
-            if small.ndim == 0:
-                return big.at[slot].set(small.astype(big.dtype))
-            return big.at[slot].set(small[0])
-
-        self._cache = jax.tree_util.tree_map(ins, self._cache, cache)
-        self._finish_prefill(slot, req, self._prefill_fence(first))
-        return True
 
     def _prefill_fence(self, first) -> int:
         """The prefill's one blocking transfer: the first token, and with
@@ -1121,16 +1188,6 @@ class InferenceEngine:
         self.host_fetches += 1
         _ROUND_FENCES.inc()
         return np.asarray(arr)
-
-    def _round_out(self):
-        """What the round's fence fetches: the next tokens (a model whose
-        layers sow counts returns them packed behind the tokens)."""
-        return self._cur_dev
-
-    def _note_model_stats(self, fetched: np.ndarray) -> np.ndarray:
-        """Split what the fence fetched into the ``[slots]`` tokens and
-        the model's counts, and add the counts to their counters."""
-        return fetched
 
     def _device_inputs(self):
         """The per-round jit inputs, device-resident across rounds.
@@ -1258,7 +1315,14 @@ class InferenceEngine:
         a usable draft, or any ACTIVE row sits too close to the cache
         edge (the fixed-width ``[B, gamma+1]`` write would clamp/wrap
         past ``max_seq_len`` and corrupt real positions — those rows are
-        about to finish anyway, so the whole batch takes plain steps)."""
+        about to finish anyway, so the whole batch takes plain steps).
+
+        The speculated positions must be block-backed: a proposal may
+        only run as far as this row's allocated pages reach (writes past
+        them land on the scratch block and could never be accepted).
+        Growth here is best-effort — NoFreeBlocks truncates the draft
+        instead of preempting anyone; speculation is an optimization and
+        must never cost a live request its blocks."""
         if self._proposer is None:
             return None
         width = self.spec_tokens + 1
@@ -1277,6 +1341,19 @@ class InferenceEngine:
             p = p[:min(self.spec_tokens, remaining - 1)]
             if p:
                 plan[slot] = [int(t) for t in p]
+        for slot in list(plan):
+            want = len(plan[slot])
+            covered = self._grow_for_spec(slot, want)
+            if covered < want:
+                # the NoFreeBlocks backstop fired — count it: a pool
+                # sized too tight silently degrades speculation toward
+                # 1-token steps, and until this counter existed the only
+                # symptom was a mysteriously low tokens-per-step
+                self.spec_draft_truncated += 1
+                _SPEC_TRUNCATED.inc()
+            plan[slot] = plan[slot][:covered]
+            if not plan[slot]:
+                del plan[slot]
         return plan or None
 
     def _propose_for(self, slot: int, req: Request) -> List[int]:
@@ -1363,8 +1440,8 @@ class InferenceEngine:
             _SPEC_ACCEPTED.inc(acc_total)
 
         # advance positions BEFORE emitting: _free (via _emit on
-        # EOS/limit) resets freed rows on top of this, and the paged
-        # engine's rollback hook releases blocks past the new lengths
+        # EOS/limit) resets freed rows on top of this, and the rollback
+        # releases blocks past the new lengths
         for slot in emit:
             self._pos[slot] += len(emit[slot])
         self._post_verify_rollback()
@@ -1400,10 +1477,6 @@ class InferenceEngine:
         _ROUND_PHASE.observe(fence_dt, phase="fence")
         _ROUND_PHASE.observe(emit_dt, phase="emit")
 
-    def _post_verify_rollback(self) -> None:
-        """Hook after the index rewind; the paged engine releases growth
-        blocks that became wholly rejected."""
-
     def _note_decode_round(self, emitted: int, rows: int, dt: float) -> None:
         self._flush_token_accounting()
         self.decode_steps += 1
@@ -1417,24 +1490,6 @@ class InferenceEngine:
             # per ROW-step: 1.0 = every row advanced one token (no win);
             # the ceiling is spec_tokens + 1
             _SPEC_TPS.set(self.decode_tokens / self.decode_rows)
-
-    # decode-loop hooks (ONE loop body serves both engines — the paged
-    # subclass plugs in block growth, the page-table jit argument, and
-    # per-row length tracking without copying the metrics/emit choreography)
-
-    def _pre_decode(self) -> bool:
-        """Pre-step resource work; False aborts the round (nothing left)."""
-        return True
-
-    def _run_decode_step(self):
-        cur, pos, mask = self._device_inputs()
-        return self._decode_step(self._payload, self.params, cur, pos,
-                                 mask, self._rng)
-
-    def _run_verify_step(self, prop, prop_len):
-        cur, pos, mask = self._device_inputs()
-        return self._verify_step(self._payload, self.params, cur, prop,
-                                 prop_len, pos, mask, self._rng)
 
     def _post_decode_step(self) -> None:
         """Bookkeeping between the device step and token emission: the
@@ -1482,15 +1537,20 @@ class InferenceEngine:
     def _free(self, slot: int) -> None:
         """Host-mirror reset only: the freed row's DEVICE state (token,
         position, greedy-mask bit) is left stale on purpose — idle rows
-        are garbage-tolerant (writes land on masked positions / the
-        scratch block, outputs are never read), and the re-admission
-        that makes the slot matter again rebuilds all three mirrors
-        (``_finish_prefill``). The next insertion overwrites the cache
-        rows wholesale."""
+        are garbage-tolerant (writes land on the scratch block, outputs
+        are never read), and the re-admission that makes the slot matter
+        again rebuilds all three mirrors (``_finish_prefill``). The
+        slot's blocks go back to the pool (or stay cached in the tree)."""
         self._active[slot] = None
         self._cur[slot] = 0
         self._pos[slot] = 0
         self._spec_index[slot] = None
+        blocks = self._slot_blocks[slot]
+        self._slot_blocks[slot] = []
+        self._tables[slot, :] = 0
+        self._pt_dev = None
+        self._admit_seq[slot] = 0
+        self.kv.release(blocks)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -1517,12 +1577,6 @@ class InferenceEngine:
             self._warm_compile(self._verify_step, payload,
                                (vec, prop, vec, vec), mask, rng)
 
-    def _warm_compile(self, step, payload, mids, mask, rng):
-        """``mids`` are the step-specific args between ``params`` and the
-        greedy mask: ``(cur, pos)`` for decode, ``(cur, prop, prop_len,
-        pos)`` for verify (the paged engine inserts the page table)."""
-        step.lower(payload, self.params, *mids, mask, rng).compile()
-
     @property
     def closed(self) -> bool:
         """True once the engine refuses admissions — clean shutdown OR a
@@ -1530,7 +1584,7 @@ class InferenceEngine:
         replica whose engine died under it."""
         return self._closed
 
-    def start(self) -> "InferenceEngine":
+    def start(self) -> "PagedInferenceEngine":
         """Run the engine loop in a daemon thread (the serving-front mode)."""
         if self._thread is not None:
             return self
@@ -1617,8 +1671,8 @@ class InferenceEngine:
         if self._thread is not None:
             self._thread.join(timeout)
             self._thread = None
-        # staged prefills release their resources (paged: blocks back to
-        # the pool); their requests are failed by the untracked sweep
+        # staged prefills release their resources (blocks back to the
+        # pool); their requests are failed by the untracked sweep
         for job in list(self._prefill_jobs):
             self._abort_prefill_job(job)
         for req in self.queue.drain():
@@ -1633,6 +1687,25 @@ class InferenceEngine:
             _REQUESTS.inc(status="shed")
             req.finish(error="engine shutting down")
         _BUSY.set(0.0)
+        if self._kv_quant is not None:
+            self._note_quant_resident(0)
+        if self.kv_tier is not None:
+            self.kv_tier.close()
+        # wake any export waiter parked on a request the loop will
+        # never service again (it reads None and re-prefills locally)
+        with self._kv_io_lock:
+            requests, self._export_requests = self._export_requests, []
+            parks, self._park_requests = self._park_requests, []
+        for _, holder, done in requests:
+            holder["export"] = None
+            done.set()
+        for _kind, _key, _tokens, _ttl, holder, done in parks:
+            holder["ok"] = False
+            done.set()
+        # the loop thread was joined above: releasing the parked pins
+        # here is single-threaded by construction
+        for key in list(self._parked):
+            self._release_parked(key, "shutdown")
 
     def _fail_untracked(self) -> List[Request]:
         """Outstanding requests still unfinished after the queue and the
@@ -1645,6 +1718,11 @@ class InferenceEngine:
         return leftovers
 
     def stats(self) -> EngineStats:
+        ks = self.kv.stats()
+        if self._kv_quant is not None:
+            # blocks currently holding int8 data: everything usable that
+            # is not on the free list (slot-resident + radix-cached)
+            self._note_quant_resident(ks.blocks_total - ks.blocks_free)
         s = EngineStats(
             slots=self.slots,
             busy=sum(r is not None for r in self._active),
@@ -1652,6 +1730,20 @@ class InferenceEngine:
             requests_finished=self._finished,
             tokens_generated=self._tokens_out,
             requests_cancelled=self._cancelled,
+            kv_page_size=self._page,
+            kv_blocks_total=ks.blocks_total,
+            kv_blocks_free=ks.blocks_free,
+            kv_blocks_cached=ks.blocks_cached,
+            kv_evictions=ks.evictions,
+            prefix_hit_rate=round(ks.hit_rate, 4),
+            prefill_tokens_saved=ks.prefill_tokens_saved,
+            kernel_path=self.kernel_path,
+            kv_quant=self._kv_quant,
+            kv_imports=self.kv_imports,
+            kv_import_blocks=self.kv_import_blocks,
+            kv_parked_chains=len(self._parked),
+            kv_parked_blocks=sum(len(c.blocks)
+                                 for c in self._parked.values()),
         )
         if self.spec_tokens > 0:
             rate = (self.spec_accepted / self.spec_proposed
@@ -1668,13 +1760,27 @@ class InferenceEngine:
                 spec_tokens_per_step=round(tps, 4),
                 spec_draft_truncated=self.spec_draft_truncated,
             )
+        if self.kv_tier is not None:
+            ts = self.kv_tier.stats()
+            s = dataclasses.replace(
+                s,
+                kv_host_tier_blocks=ts["host_blocks"],
+                kv_host_tier_bytes=ts["host_bytes"],
+                kv_tier_demotions=(ts["demotions"]
+                                   + ts["demotions_to_storage"]),
+                kv_tier_promotions=(ts["promotions"]
+                                    + ts["promotions_from_storage"]),
+                kv_tier_dropped=ts["dropped"],
+                kv_storage_tier_blocks=ts.get("storage_blocks"),
+            )
         return s
+
 
     def stats_by_tenant(self) -> dict:
         """Per-tenant terminal counters plus live queue depth — the
         scoped half of the stats surface (a tenant sees its own row, the
         operator sees them all; the gateway fleet aggregates these
-        across replicas). The paged engine adds resident KV blocks."""
+        across replicas), with the KV blocks each tenant holds."""
         with self._tenant_counts_lock:
             out = {t: dict(d) for t, d in self._tenant_counts.items()}
         for tenant in self.queue.tenants():
@@ -1685,199 +1791,19 @@ class InferenceEngine:
             row["queue_depth"] = self.queue.depth_of(tenant)
         for row in out.values():
             row.setdefault("queue_depth", 0)
+        tenants = set(out)
+        tenants.update(r.tenant for r in self._active if r is not None)
+        tenants.update(j.req.tenant for j in self._prefill_jobs)
+        for tenant in tenants:
+            held = self._tenant_block_usage(tenant)
+            row = out.setdefault(tenant, {
+                "requests_finished": 0, "tokens_generated": 0,
+                "requests_cancelled": 0, "requests_preempted": 0,
+                "requests_error": 0, "queue_depth": 0})
+            row["kv_blocks"] = held
+            TENANT_KV_BLOCKS.set(float(held), tenant=tenant)
         return out
 
-
-class PagedInferenceEngine(InferenceEngine):
-    """Continuous batching over a paged KV cache with radix prefix reuse.
-
-    The dense engine gives every slot a private ``[max_seq_len, ...]`` KV
-    row and prefills every prompt from token 0. This engine replaces both
-    with the serving-fabric standard (``lzy_tpu/serving/kv_cache.py``):
-
-    - K/V live in ONE pool of ``page_size``-token blocks shared by all
-      slots; each request holds a page table and commits HBM page by page
-      as it actually grows, so short requests stop paying for the longest
-      possible one and ``kv_blocks`` can be sized well below
-      ``slots * max_seq_len / page_size`` (overcommit).
-    - Prompts are matched against a ref-counted radix tree of previously
-      cached blocks: requests sharing a prompt prefix (system prompts,
-      few-shot headers) skip prefill for every matched block and only the
-      unmatched suffix runs through the model. Full prompt blocks are
-      inserted back after prefill for the next arrival.
-    - Admission is budgeted against free + evictable blocks (the slot
-      count alone no longer gates), eviction under pressure removes only
-      unreferenced cached blocks (LRU), and if overcommit squeezes decode
-      growth dry the YOUNGEST active request is preempted (clean
-      ``preempted`` error) — an in-flight request is never corrupted.
-
-    Outputs are bit-identical to the dense engine (and to the solo
-    ``generate()`` oracle) for greedy and sampled decode: the paged
-    attention path gathers blocks back into exactly the dense layout
-    before the shared score/mask/softmax code runs.
-    """
-
-    def __init__(
-        self,
-        cfg: Any,
-        params: Any,
-        *,
-        slots: int = 4,
-        page_size: int = 16,
-        kv_blocks: Optional[int] = None,
-        kv_pool_bytes: Optional[int] = None,
-        kv_quant: Optional[str] = None,
-        native_attention: bool = False,
-        kernel: str = "auto",
-        kv_host_tier_bytes: Optional[int] = None,
-        kv_storage_tier=None,
-        kv_tier=None,
-        **kwargs,
-    ):
-        from lzy_tpu.ops.interpret import resolve as pallas_interpreted
-        from lzy_tpu.ops.paged_attention import (
-            DISPATCHES, QUANT_BLOCKS_RESIDENT, default_kernel,
-            lower_pallas_for_tpu)
-        from lzy_tpu.serving.kv_cache import RadixCache, blocks_for_bytes
-
-        base = cfg.serving_config()
-        if page_size < 1 or base.max_seq_len % page_size:
-            raise ValueError(
-                f"page_size ({page_size}) must divide max_seq_len "
-                f"({base.max_seq_len})")
-        if kv_quant not in (None, "int8"):
-            raise ValueError(
-                f"unknown kv_quant {kv_quant!r}; known: int8")
-        if kernel not in ("auto", "lax", "pallas"):
-            raise ValueError(
-                f"unknown kernel {kernel!r}; known: auto, lax, pallas")
-        self._page = page_size
-        self._pages_per_seq = base.max_seq_len // page_size
-        self._kv_quant = kv_quant
-        # kernel selection (docs/serving.md): "auto" is the kernel that
-        # compiles for a TPU (the Pallas decode kernel), "pallas" is
-        # taken at the caller's word and checked below, and "legacy" —
-        # the original gather-back-to-dense read — serves when
-        # native_attention is off. The kernel takes the programs whose
-        # shape it is written for (ops.paged_attention.kernel_path) and
-        # leaves the rest to lax: kernel_path is the decode step's.
-        self._native = bool(native_attention)
-        if not self._native:
-            if kernel != "auto":
-                # an explicit kernel choice that would be silently
-                # ignored is a misconfiguration, not a preference
-                raise ValueError(
-                    f"kernel={kernel!r} requires native_attention=True "
-                    f"(without it the legacy gather path serves)")
-            self._paged_kernel = "lax"
-        else:
-            if kernel == "pallas" and kv_quant is not None:
-                raise ValueError(
-                    "kernel='pallas' reads float pools only; an int8 pool "
-                    "is served by kernel='lax' (or 'auto')")
-            self._paged_kernel = default_kernel() if kernel == "auto" \
-                else kernel
-        self.kernel_path = self._path_of(1)
-        self._dispatches = DISPATCHES
-        # the resident gauge is process-global and this process may run
-        # several quantized pools (disagg: prefill + decode); each engine
-        # contributes its own delta so the exported value is the SUM, and
-        # close() withdraws the contribution (no stale reading after a
-        # drain)
-        self._quant_resident = QUANT_BLOCKS_RESIDENT
-        self._quant_resident_seen = 0
-        self._quant_resident_lock = threading.Lock()
-        if kv_pool_bytes is not None:
-            if kv_blocks is not None:
-                raise ValueError(
-                    "pass kv_blocks or kv_pool_bytes, not both")
-            # size the pool by its HBM payload budget: int8 blocks are
-            # half the bytes of bf16 blocks, so the same budget holds
-            # ~2x the blocks — the whole point of kv_quant
-            kv_blocks = blocks_for_bytes(
-                kv_pool_bytes, page_size=page_size,
-                n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
-                n_layers=base.kv_layers, dtype=base.dtype,
-                kv_quant=kv_quant)
-        if kv_blocks is None:
-            # dense-equivalent HBM by default (+1 scratch); pass less to
-            # overcommit, more to grow the prefix cache's working set
-            kv_blocks = slots * self._pages_per_seq + 1
-        if kv_blocks < 2:
-            raise ValueError(f"kv_blocks must be >= 2, got {kv_blocks}")
-        self._kv_blocks = kv_blocks
-        if self.kernel_path == "pallas" and not pallas_interpreted(None):
-            # no silent drop to the interpreter or to lax: what the TPU
-            # lowering refuses, it refuses here, before a pool exists
-            lower_pallas_for_tpu(
-                batch=slots, n_heads=base.n_heads,
-                n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
-                n_blocks=kv_blocks, page_size=page_size,
-                pages_per_seq=self._pages_per_seq, dtype=base.dtype)
-            base.check_kernels(slots=slots)
-        self.kv = RadixCache(kv_blocks, page_size)
-        # tiered KV cache (serving/kv_tier.py): radix eviction DEMOTES
-        # block payloads to pinned host RAM (and onward to storage)
-        # instead of dropping them; admission PROMOTES them back. The
-        # tier is advisory end to end — every failure path degrades to
-        # classic eviction / local re-prefill.
-        if kv_tier is not None:
-            self.kv_tier = kv_tier
-        elif kv_host_tier_bytes is not None or kv_storage_tier is not None:
-            from lzy_tpu.serving.kv_tier import HostKVTier
-
-            self.kv_tier = HostKVTier(kv_host_tier_bytes or 0, page_size,
-                                      storage=kv_storage_tier)
-        else:
-            self.kv_tier = None
-        if self.kv_tier is not None:
-            self.kv.on_evict = self._demote_block
-            self.kv.on_evict_batch = self._demote_blocks
-            self.kv.on_insert = self.kv_tier.discard
-        # device→host gather accounting for the demotion path: one
-        # BATCHED gather per cache leaf per eviction round (not one per
-        # evicted block) — the count-of-transfers contract the batching
-        # test pins
-        self.kv_tier_gather_ops = 0
-        self.kv_tier_gather_rounds = 0
-        # cross-replica / disagg import queue: transferred KVBlockExports
-        # fold into the pool+tree between engine steps, strictly before
-        # admissions (a queued import is resident by the time the request
-        # that wants it prefills); export requests are the outbound twin,
-        # serviced on THIS thread so the device→host gather never races a
-        # donating prefill
-        self._pending_imports: List[Any] = []
-        self._export_requests: List[tuple] = []
-        # parked conversation chains (workflow-aware scheduling): key ->
-        # _ParkedChain with its radix blocks pinned so a fused op
-        # chain's tool gap cannot evict the conversation KV. Mutated
-        # only on the scheduling thread (cross-thread callers queue
-        # through _park_requests, the request_kv_export pattern);
-        # bounded by the TTL sweep in step(), shed under pool pressure
-        # strictly before any resident request is preempted, and
-        # released wholesale at close().
-        self._parked: Dict[str, _ParkedChain] = {}
-        self._park_requests: List[tuple] = []
-        self._kv_io_lock = threading.Lock()
-        self.kv_imports = 0
-        self.kv_import_blocks = 0
-        # page tables: [slots, pages_per_seq] block ids (0 = scratch pad);
-        # _slot_blocks mirrors the allocated prefix of each row in python
-        self._tables = np.zeros((slots, self._pages_per_seq), np.int32)
-        # device mirror of _tables, uploaded once and reused until a
-        # table write dirties it (upload-once discipline — see
-        # _page_table_dev); every _tables mutation site sets it to None
-        self._pt_dev = None
-        self._slot_blocks: List[List[int]] = [[] for _ in range(slots)]
-        # per-row cached-token counts live in the base engine's _pos
-        self._admit_seq = np.zeros((slots,), np.int64)  # admission order
-        self._admissions = 0
-        self._packed_out = None      # tokens + model counts of this round
-        self._stat_counters: tuple = ()
-        self._dispatch_paths: dict = {}   # positions a row -> path labels
-        super().__init__(cfg, params, slots=slots, **kwargs)
-        if self._has_state:
-            self._refuse_for_state()
 
     def _refuse_for_state(self) -> None:
         """A model with per-slot state leaves (``models/serving.py``):
@@ -1915,8 +1841,6 @@ class PagedInferenceEngine(InferenceEngine):
         the label of its ``lzy_kernel_dispatch_total`` count."""
         from lzy_tpu.ops.paged_attention import kernel_path
 
-        if not self._native:
-            return "legacy"
         return kernel_path(self._paged_kernel, t=t,
                            quantized=self._kv_quant is not None)
 
@@ -1927,8 +1851,7 @@ class PagedInferenceEngine(InferenceEngine):
         # has them, the job's own batch-1 state rows)
         self._model = self._prefill_model = base.paged_model(
             page_size=self._page, kv_pages=self._kv_blocks,
-            native=self._native, kernel=self._paged_kernel,
-            kv_quant=self._kv_quant)
+            kernel=self._paged_kernel, kv_quant=self._kv_quant)
         dummy_pt = jnp.zeros((slots, pages), jnp.int32)
         self._adopt_cache(init_cache(lambda: self._model.init(
             jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
@@ -1993,11 +1916,9 @@ class PagedInferenceEngine(InferenceEngine):
 
         def verify_step(payload, params, cur, prop, prop_len, pos,
                         page_table, greedy_mask, rng):
-            # paged twin of the dense verify: the [B, gamma+1] chunk
-            # scatters through the page table (positions past a row's
-            # allocated blocks land on the scratch page — garbage nobody
-            # can accept) and the gather-back keeps the score/mask path
-            # literally the dense one, so acceptance is bit-identical
+            # the [B, gamma+1] chunk scatters through the page table
+            # (positions past a row's allocated blocks land on the
+            # scratch page — garbage nobody can accept)
             cache = self._assemble_cache(payload, pos)
             toks = jnp.concatenate([cur[:, None], prop], axis=1)
             logits, updated = self._model.apply(
@@ -2063,31 +1984,6 @@ class PagedInferenceEngine(InferenceEngine):
 
     # -- admission / prefill -------------------------------------------------
 
-    def submit(self, prompt: Sequence[int], **kwargs) -> Request:
-        from lzy_tpu.serving.kv_cache import blocks_for
-
-        prompt = list(prompt)
-        # reject prompts the pool — or the tenant's quota — can NEVER
-        # cover: past submit they would park in the queue forever
-        # (admission waits for blocks that cannot exist) and waste a
-        # tenant's WFQ share on an unservable head
-        if prompt and blocks_for(len(prompt), self._page) > self._kv_blocks - 1:
-            raise PromptTooLong(
-                f"prompt ({len(prompt)} tokens) needs "
-                f"{blocks_for(len(prompt), self._page)} KV blocks but the "
-                f"pool only has {self._kv_blocks - 1}; raise kv_blocks or "
-                f"shorten the prompt")
-        tenant = kwargs.get("tenant") or "default"
-        quota = self._tenant_quota(tenant)
-        if prompt and quota is not None \
-                and blocks_for(len(prompt), self._page) > quota:
-            raise PromptTooLong(
-                f"prompt ({len(prompt)} tokens) needs "
-                f"{blocks_for(len(prompt), self._page)} KV blocks but "
-                f"tenant {tenant!r} is capped at {quota}; shorten the "
-                f"prompt or raise the tenant's kv_block_quota")
-        return super().submit(prompt, **kwargs)
-
     def _tenant_quota(self, tenant: str) -> Optional[int]:
         if self.tenants is None:
             return None
@@ -2127,11 +2023,14 @@ class PagedInferenceEngine(InferenceEngine):
         return self.kv.available() >= need
 
     def _admit_verdict(self, req: Request) -> str:
-        """Tenant KV quota first (a tenant AT its quota is skipped, not
-        head-of-line-blocked — its blocks free as its own requests
-        finish, and other tenants must not wait on that), then the
-        global pool budget (a genuine capacity wait: everyone holds so
-        big prompts are not starved by smaller late arrivals)."""
+        """``"admit"`` (pop and stage), ``"wait"`` (global capacity —
+        the whole queue waits so big prompts are never starved by
+        smaller late arrivals), or ``"skip"`` (a *tenant-scoped* limit:
+        this tenant's head steps aside without blocking other tenants'
+        admissible heads — one tenant's quota must never become another
+        tenant's latency). Tenant KV quota first (a tenant AT its quota
+        is skipped: its blocks free as its own requests finish), then
+        the global pool budget (a genuine capacity wait)."""
         from lzy_tpu.serving.kv_cache import blocks_for
 
         quota = self._tenant_quota(req.tenant)
@@ -2194,7 +2093,8 @@ class PagedInferenceEngine(InferenceEngine):
                            table=blocks + owned, state=state)
 
     def _advance_prefill_round(self, job: _PrefillJob) -> bool:
-        """One budgeted round of a PAGED prefill. The pool k/v leaves are
+        """One budgeted round of a prefill; True when the job finished
+        (slot activated). The pool k/v leaves are
         re-skinned for the batch-1 prefill, advanced by up to the budget,
         and merged back into the decode tree before returning — decode
         steps between rounds run against a fully consistent tree (the
@@ -2257,22 +2157,13 @@ class PagedInferenceEngine(InferenceEngine):
         self._finish_prefill(slot, req, self._prefill_fence(first))
         return True
 
-    def _abort_prefill_job(self, job: _PrefillJob) -> None:
-        super()._abort_prefill_job(job)
-        # drop the staged refs: matched prefix blocks fall back to
-        # cached, freshly-owned ones return to the free list (their
-        # half-written K/V is dead weight a future holder overwrites
-        # during its own prefill, same as any freed slot's blocks)
-        self.kv.release(job.table)
-        job.table = []
-
     # -- tiered KV cache (serving/kv_tier.py) --------------------------------
 
     def _service_io(self) -> bool:
-        """Paged scheduling round: service cross-replica KV I/O (queued
-        imports + export requests) strictly before the base round's
-        admissions — an import queued before a submit is always resident
-        by the time that request prefills."""
+        """Round work ahead of the reap and the admissions: cross-replica
+        KV I/O (queued imports + export requests) strictly before the
+        round's admissions — an import queued before a submit is always
+        resident by the time that request prefills."""
         serviced = self._service_kv_io()
         self._sweep_parked()
         return serviced
@@ -2862,10 +2753,14 @@ class PagedInferenceEngine(InferenceEngine):
         return out
 
     def _round_out(self):
+        """What the round's fence fetches: the next tokens (a model whose
+        layers sow counts returns them packed behind the tokens)."""
         return self._cur_dev if self._packed_out is None \
             else self._packed_out
 
     def _note_model_stats(self, fetched: np.ndarray) -> np.ndarray:
+        """Split what the fence fetched into the ``[slots]`` tokens and
+        the model's counts, and add the counts to their counters."""
         if self._packed_out is None:
             return fetched
         self._packed_out = None
@@ -2888,6 +2783,9 @@ class PagedInferenceEngine(InferenceEngine):
                                  mask, self._rng)
 
     def _warm_compile(self, step, payload, mids, mask, rng):
+        """``mids`` are the step-specific args between ``params`` and the
+        page table: ``(cur, pos)`` for decode, ``(cur, prop, prop_len,
+        pos)`` for verify."""
         pt = jax.ShapeDtypeStruct((self.slots, self._pages_per_seq),
                                   jnp.int32)
         step.lower(payload, self.params, *mids, pt, mask, rng).compile()
@@ -2901,31 +2799,6 @@ class PagedInferenceEngine(InferenceEngine):
                 jax.ShapeDtypeStruct((), jnp.int32)).compile()
 
     # -- speculative decode over the block pool -------------------------------
-
-    def _spec_plan(self) -> Optional[dict]:
-        """Base plan, then make the speculated positions block-backed: a
-        proposal may only run as far as this row's allocated pages reach
-        (writes past them land on the scratch block and could never be
-        accepted). Growth here is best-effort — NoFreeBlocks truncates
-        the draft instead of preempting anyone; speculation is an
-        optimization and must never cost a live request its blocks."""
-        plan = super()._spec_plan()
-        if not plan:
-            return plan
-        for slot in list(plan):
-            want = len(plan[slot])
-            covered = self._grow_for_spec(slot, want)
-            if covered < want:
-                # the NoFreeBlocks backstop fired — count it: a pool
-                # sized too tight silently degrades speculation toward
-                # 1-token steps, and until this counter existed the only
-                # symptom was a mysteriously low tokens-per-step
-                self.spec_draft_truncated += 1
-                _SPEC_TRUNCATED.inc()
-            plan[slot] = plan[slot][:covered]
-            if not plan[slot]:
-                del plan[slot]
-        return plan or None
 
     def _grow_for_spec(self, slot: int, want: int) -> int:
         """Allocate blocks so positions ``pos .. pos+want`` are real
@@ -2979,54 +2852,6 @@ class PagedInferenceEngine(InferenceEngine):
                 self._pt_dev = None
                 self.kv.release(tail)
 
-    def _free(self, slot: int) -> None:
-        super()._free(slot)
-        blocks = self._slot_blocks[slot]
-        self._slot_blocks[slot] = []
-        self._tables[slot, :] = 0
-        self._pt_dev = None
-        self._admit_seq[slot] = 0
-        self.kv.release(blocks)
-
-    def stats(self) -> EngineStats:
-        s = super().stats()
-        ks = self.kv.stats()
-        if self._kv_quant is not None:
-            # blocks currently holding int8 data: everything usable that
-            # is not on the free list (slot-resident + radix-cached)
-            self._note_quant_resident(ks.blocks_total - ks.blocks_free)
-        s = dataclasses.replace(
-            s,
-            kv_page_size=self._page,
-            kv_blocks_total=ks.blocks_total,
-            kv_blocks_free=ks.blocks_free,
-            kv_blocks_cached=ks.blocks_cached,
-            kv_evictions=ks.evictions,
-            prefix_hit_rate=round(ks.hit_rate, 4),
-            prefill_tokens_saved=ks.prefill_tokens_saved,
-            kernel_path=self.kernel_path,
-            kv_quant=self._kv_quant,
-            kv_imports=self.kv_imports,
-            kv_import_blocks=self.kv_import_blocks,
-            kv_parked_chains=len(self._parked),
-            kv_parked_blocks=sum(len(c.blocks)
-                                 for c in self._parked.values()),
-        )
-        if self.kv_tier is not None:
-            ts = self.kv_tier.stats()
-            s = dataclasses.replace(
-                s,
-                kv_host_tier_blocks=ts["host_blocks"],
-                kv_host_tier_bytes=ts["host_bytes"],
-                kv_tier_demotions=(ts["demotions"]
-                                   + ts["demotions_to_storage"]),
-                kv_tier_promotions=(ts["promotions"]
-                                    + ts["promotions_from_storage"]),
-                kv_tier_dropped=ts["dropped"],
-                kv_storage_tier_blocks=ts.get("storage_blocks"),
-            )
-        return s
-
     def _note_quant_resident(self, resident: int) -> None:
         with self._quant_resident_lock:
             if self._closed:
@@ -3038,40 +2863,3 @@ class PagedInferenceEngine(InferenceEngine):
             self._quant_resident_seen = resident
         if delta:
             self._quant_resident.add(float(delta))
-
-    def close(self, timeout: float = 10.0) -> None:
-        super().close(timeout)
-        if self._kv_quant is not None:
-            self._note_quant_resident(0)
-        if self.kv_tier is not None:
-            self.kv_tier.close()
-        # wake any export waiter parked on a request the loop will
-        # never service again (it reads None and re-prefills locally)
-        with self._kv_io_lock:
-            requests, self._export_requests = self._export_requests, []
-            parks, self._park_requests = self._park_requests, []
-        for _, holder, done in requests:
-            holder["export"] = None
-            done.set()
-        for _kind, _key, _tokens, _ttl, holder, done in parks:
-            holder["ok"] = False
-            done.set()
-        # the loop thread is joined by super().close(): releasing the
-        # parked pins here is single-threaded by construction
-        for key in list(self._parked):
-            self._release_parked(key, "shutdown")
-
-    def stats_by_tenant(self) -> dict:
-        out = super().stats_by_tenant()
-        tenants = set(out)
-        tenants.update(r.tenant for r in self._active if r is not None)
-        tenants.update(j.req.tenant for j in self._prefill_jobs)
-        for tenant in tenants:
-            held = self._tenant_block_usage(tenant)
-            row = out.setdefault(tenant, {
-                "requests_finished": 0, "tokens_generated": 0,
-                "requests_cancelled": 0, "requests_preempted": 0,
-                "requests_error": 0, "queue_depth": 0})
-            row["kv_blocks"] = held
-            TENANT_KV_BLOCKS.set(float(held), tenant=tenant)
-        return out

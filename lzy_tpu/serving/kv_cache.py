@@ -27,7 +27,7 @@ wall time, so eviction order is deterministic under test.
 
 Prefix hit rate, blocks in use/free, evictions, and prefill tokens saved
 are exported via ``lzy_tpu.utils.metrics.REGISTRY`` and surfaced through
-``InferStats`` (see ``serving/engine.py``) and ``bench.py``.
+``InferStats`` (see ``serving/engine.py``).
 """
 
 from __future__ import annotations

@@ -100,8 +100,7 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
             raise ValueError(
                 "kernel='pallas' cannot serve sharded: the fused kernel is "
                 "a custom call GSPMD cannot partition; use kernel='lax'")
-        if kwargs.get("native_attention") \
-                and kwargs.get("kernel", "auto") == "auto":
+        if kwargs.get("kernel", "auto") == "auto":
             # for the same reason a gang's "auto" is the lax read
             kwargs["kernel"] = "lax"
         self._mesh = mesh
@@ -137,7 +136,7 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
     # -- construction --------------------------------------------------------
 
     def _build_decode_path(self, base: LlamaConfig) -> None:
-        """The paged build with three changes: rule overrides thread into
+        """The base build with three changes: rule overrides thread into
         the model, params and pool leaves are device_put onto the mesh
         (committed shardings make jit infer in_shardings), and every
         ``apply`` passes ``mesh`` so the activation anchors engage."""
@@ -153,8 +152,8 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
         slots, pages = self.slots, self._pages_per_seq
         self._model = self._prefill_model = base.paged_model(
             page_size=self._page, kv_pages=self._kv_blocks,
-            native=self._native, kernel=self._paged_kernel,
-            kv_quant=self._kv_quant, rules=SERVE_RULES)
+            kernel=self._paged_kernel, kv_quant=self._kv_quant,
+            rules=SERVE_RULES)
         dummy_pt = jnp.zeros((slots, pages), jnp.int32)
         # init meshless (anchors no-op without a mesh), THEN place: the
         # pool shards on kv_heads, index leaves and params replicate
@@ -248,7 +247,7 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
         return self._pt_dev
 
     def _pool_to_prefill(self, start: int, job=None):
-        """Same re-skin as the paged base, with the batch-1 index leaves
+        """Same re-skin as the base, with the batch-1 index leaves
         committed replicated so the donated prefill cache tree is
         uniformly mesh-placed. A FRESH buffer per index leaf — the whole
         tree is donated, and two leaves aliasing one buffer is a

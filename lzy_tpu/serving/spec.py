@@ -34,7 +34,7 @@ as before, from the same rng draw order.
 Proposed/accepted tokens, verify rounds, the cumulative acceptance rate
 and the mean tokens-per-decode-step are exported via
 ``lzy_tpu.utils.metrics.REGISTRY`` (``lzy_spec_*``) and surfaced through
-``InferStats``/``InferFleetStats`` and ``bench.py``.
+``InferStats``/``InferFleetStats``.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Where this process's JAX keeps compiled programs, and what it runs on.
 
 One helper for every entry point that compiles (``service/serve.py``,
-``chip_smoke.py``, ``bench.py``, the test tier): the persistent compilation
+``chip_smoke.py``, the test tier): the persistent compilation
 cache is placed from outside through ``JAX_COMPILATION_CACHE_DIR``, which
 JAX reads by itself, and otherwise sits at one fixed path inside the
 checkout. The path is part of a cache entry's key, so a directory built from

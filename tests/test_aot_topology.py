@@ -192,8 +192,7 @@ def test_llama_does_not_drop_to_the_reference_path_in_silence():
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("t", [1, 5], ids=["decode", "verify"])
 def test_paged_attention_auto_compiles(t, quantized, one_chip):
-    from lzy_tpu.ops.paged_attention import (
-        KVQuant, default_kernel, paged_attention)
+    from lzy_tpu.ops.paged_attention import KVQuant, paged_attention
 
     batch, page = 8, 16
     pages = _8B.max_seq_len // page
@@ -210,7 +209,7 @@ def test_paged_attention_auto_compiles(t, quantized, one_chip):
 
     def read(q, k_pool, v_pool, table, pos, side):
         return paged_attention(q, k_pool, v_pool, table, pos,
-                               kernel=default_kernel(), dtype=jnp.bfloat16,
+                               kernel="pallas", dtype=jnp.bfloat16,
                                quant=side, interpret=False)
 
     jax.jit(read).lower(
@@ -224,15 +223,18 @@ def test_pallas_paged_kernel_lowers_and_auto_is_it(shape, monkeypatch):
     """ROADMAP S2's kernel: the pool stays in HBM and pages are fetched by
     DMA, so the lowering has no pool block to refuse. It lowers at the 8B
     shapes with pages of 16 and 64 and at the benchmark's (32 slots, the 7
-    GiB pool's 7168 pages, tables of 256 pages), ``"auto"`` is it, and an
-    engine built with ``"auto"`` outside the interpreter reports it: the
-    lowering ran when the engine was built."""
+    GiB pool's 7168 pages, tables of 256 pages), ``"auto"`` is it on a
+    TPU (and lax on this CPU), and an engine built for it outside the
+    interpreter reports it: the lowering ran when the engine was built."""
     from lzy_tpu.ops import interpret
     from lzy_tpu.ops.paged_attention import (
         default_kernel, lower_pallas_for_tpu)
     from lzy_tpu.serving import PagedInferenceEngine
 
+    assert default_kernel() == "lax"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert default_kernel() == "pallas"
+    monkeypatch.undo()
     batch, n_blocks, page, pages = {
         "8b_page16": (8, 513, 16, _8B.max_seq_len // 16),
         "8b_page64": (8, 513, 64, _8B.max_seq_len // 64),
@@ -246,7 +248,7 @@ def test_pallas_paged_kernel_lowers_and_auto_is_it(shape, monkeypatch):
     params = unbox(llama.init_params(cfg, jax.random.PRNGKey(0))[0])
     monkeypatch.setattr(interpret, "_process_wide", False)
     engine = PagedInferenceEngine(cfg, params, slots=2, page_size=page,
-                                  native_attention=True, kernel="auto")
+                                  kernel="pallas")
     try:
         assert engine.kernel_path == "pallas"
         assert engine.stats().kernel_path == "pallas"
@@ -268,7 +270,7 @@ def test_paged_decode_step_compiles_at_full_width(one_chip, monkeypatch):
         lambda k: unbox(llama.init_params(cfg, k)[0]), jax.random.PRNGKey(0))
     slots = 2
     engine = PagedInferenceEngine(cfg, params, slots=slots, page_size=16,
-                                  native_attention=True, kernel="auto")
+                                  kernel="pallas")
     try:
         def on_chip(x):
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)

@@ -37,7 +37,7 @@ from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
 from lzy_tpu.rpc.core import Unavailable
 from lzy_tpu.serving import (
-    AdmissionError, DecodeEngine, InferenceEngine, PagedInferenceEngine,
+    AdmissionError, DecodeEngine, PagedInferenceEngine,
     PrefillEngine, QuotaExceeded, RadixCache, SloLimiter, TenantPolicy,
     TenantTable)
 from lzy_tpu.serving.scheduler import RequestQueue
@@ -324,7 +324,7 @@ class TestLoadShedding:
     def test_gateway_shed_counts_and_hints(self, tiny_model):
         cfg, params = tiny_model
         fleet = ReplicaFleet(
-            lambda: InferenceEngine(cfg, params, slots=1, max_queue=1),
+            lambda: PagedInferenceEngine(cfg, params, slots=1, max_queue=1),
             start_engines=False)
         gw = GatewayService(fleet, router=PrefixAffinityRouter(PAGE),
                             model_name="tiny")
@@ -348,7 +348,7 @@ class TestLoadShedding:
 class TestGracefulDrain:
     def test_engine_drain_finishes_inflight_then_refuses(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=2).start()
+        eng = PagedInferenceEngine(cfg, params, slots=2).start()
         req = eng.submit([5, 9, 3], max_new_tokens=6)
         assert eng.drain(timeout_s=60.0)
         assert req.done and req.error is None
@@ -457,7 +457,7 @@ class TestInvariants:
     def test_fleet_lease_audit_catches_double_lease(self, tiny_model):
         cfg, params = tiny_model
         fleet = ReplicaFleet(
-            lambda: InferenceEngine(cfg, params, slots=1),
+            lambda: PagedInferenceEngine(cfg, params, slots=1),
             start_engines=False)
         a = fleet.add_replica()
         b = fleet.add_replica()
@@ -479,7 +479,7 @@ class TestDeadlineAcrossFailover:
         ``deadline_s``."""
         cfg, params = tiny_model
         fleet = ReplicaFleet(
-            lambda: InferenceEngine(cfg, params, slots=2))
+            lambda: PagedInferenceEngine(cfg, params, slots=2))
         gw = GatewayService(fleet, router=PrefixAffinityRouter(PAGE),
                             model_name="tiny")
         seen = []
@@ -616,7 +616,7 @@ class TestAutoscalerStability:
         engine, never gets dumped."""
         cfg, params = tiny_model
         fleet = ReplicaFleet(
-            lambda: InferenceEngine(cfg, params, slots=2))
+            lambda: PagedInferenceEngine(cfg, params, slots=2))
         gw = GatewayService(fleet, router=PrefixAffinityRouter(PAGE),
                             model_name="tiny")
         try:

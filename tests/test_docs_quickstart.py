@@ -50,7 +50,7 @@ def test_quickstart_blocks_execute_in_order(tmp_path):
     assert ran >= 5, f"only {ran} quickstart blocks were runnable"
     # the serving block must EXECUTE (not get skipped as an illustration):
     # it is the doc surface of the inference engine (docs/serving.md)
-    assert "InferenceEngine" in ns, "quickstart serving block did not run"
+    assert "PagedInferenceEngine" in ns, "quickstart serving block did not run"
     assert ns["req"].done
     cluster = ns.get("cluster")
     if cluster is not None:

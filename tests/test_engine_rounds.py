@@ -3,22 +3,25 @@
 The restructured round scheduler promises exactly ONE device→host
 transfer per decode round: every per-row read — next-token ids, EOS
 decisions, spec acceptance lengths — rides a single fused program whose
-one output crosses the fence via ``InferenceEngine._fetch``. These tests
-pin that contract two ways:
+one output crosses the fence via ``PagedInferenceEngine._fetch``. These
+tests pin that contract two ways:
 
 - ``host_fetches`` (the engine's own fence counter) must advance by
-  exactly 1 per steady-state decode round, dense / paged / spec-verify;
+  exactly 1 per steady-state decode round, on both read paths (``lax``,
+  and the Pallas kernel under the interpreter) and in a spec-verify round;
 - a counting transfer shim swapped in for the engine module's ``np``
   must see every device→host conversion go through ``_fetch`` — a
   regression that fetches device data outside the fence (per-row
   ``np.asarray``, the pre-restructure shape) trips the shim even though
   it never touches ``host_fetches``.
 
-Bit-identity rides along: the same restructured loop must still equal
-the ``generate()`` oracle under forced full-acceptance and
-full-rejection proposers (the dense twins of the paged cases in
+The oracle rides along: the same loop must still equal ``generate()``
+under forced full-acceptance and full-rejection proposers when the
+verify window is read by the kernel (the ``lax`` twins are in
 test_spec_decode.py).
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +31,7 @@ import pytest
 from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
-from lzy_tpu.serving import InferenceEngine, PagedInferenceEngine
+from lzy_tpu.serving import PagedInferenceEngine
 from lzy_tpu.serving import engine as engine_mod
 
 VOCAB = 64
@@ -118,20 +121,18 @@ class _CountingNp:
         return self._counting(self._real.array, a, *args, **kw)
 
 
-def _build(cfg, params, *, paged, spec=0, proposer=None):
-    kw = dict(slots=2, spec_tokens=spec)
+def _build(cfg, params, *, kernel="lax", spec=0, proposer=None):
+    kw = dict(slots=2, spec_tokens=spec, page_size=16, kernel=kernel)
     if proposer is not None:
         kw["proposer"] = proposer
-    if paged:
-        return PagedInferenceEngine(cfg, params, page_size=16, **kw)
-    return InferenceEngine(cfg, params, **kw)
+    return PagedInferenceEngine(cfg, params, **kw)
 
 
 class TestOneFencePerRound:
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_plain_decode_one_fetch_per_round(self, tiny_model, paged):
+    @pytest.mark.parametrize("kernel", ["lax", "pallas"])
+    def test_plain_decode_one_fetch_per_round(self, tiny_model, kernel):
         cfg, params = tiny_model
-        eng = _build(cfg, params, paged=paged)
+        eng = _build(cfg, params, kernel=kernel)
         reqs = [eng.submit(p, max_new_tokens=40) for p in PROMPTS]
         _reach_steady_decode(eng, reqs)
         for _ in range(8):
@@ -147,7 +148,7 @@ class TestOneFencePerRound:
         prompt = PROMPTS[1]
         exp = _oracle(cfg, params, prompt, n)
         cls = _OracleProposer if accept else _AdversarialProposer
-        eng = _build(cfg, params, paged=True, spec=gamma,
+        eng = _build(cfg, params, spec=gamma,
                      proposer=cls([prompt + exp], gamma))
         req = eng.submit(prompt, max_new_tokens=n)
         _reach_steady_decode(eng, [req])
@@ -165,11 +166,11 @@ class TestOneFencePerRound:
             assert eng.decode_steps < n - 1
         eng.close()
 
-    @pytest.mark.parametrize("paged", [False, True])
+    @pytest.mark.parametrize("kernel", ["lax", "pallas"])
     def test_shim_sees_no_fetch_outside_the_fence(
-            self, tiny_model, paged, monkeypatch):
+            self, tiny_model, kernel, monkeypatch):
         cfg, params = tiny_model
-        eng = _build(cfg, params, paged=paged)
+        eng = _build(cfg, params, kernel=kernel)
         reqs = [eng.submit(p, max_new_tokens=40) for p in PROMPTS]
         _reach_steady_decode(eng, reqs)
         shim = _CountingNp(np)
@@ -185,13 +186,22 @@ class TestOneFencePerRound:
         eng.close()
 
 
-class TestDenseBitIdentityUnderForcedProposers:
-    def test_full_acceptance_matches_oracle(self, tiny_model):
-        cfg, params = tiny_model
+@pytest.fixture(scope="module")
+def tiny_f32(tiny_model):
+    # float32 compute: the kernel's online softmax reorders the sums, and
+    # in float32 that stays far below any gap between two logits, so its
+    # greedy tokens are the oracle's
+    cfg, params = tiny_model
+    return dataclasses.replace(cfg, dtype=jnp.float32), params
+
+
+class TestKernelVerifyWindowUnderForcedProposers:
+    def test_full_acceptance_matches_oracle(self, tiny_f32):
+        cfg, params = tiny_f32
         n, gamma = 16, 4
         prompt = PROMPTS[0]
         exp = _oracle(cfg, params, prompt, n)
-        eng = _build(cfg, params, paged=False, spec=gamma,
+        eng = _build(cfg, params, kernel="pallas", spec=gamma,
                      proposer=_OracleProposer([prompt + exp], gamma))
         req = eng.submit(prompt, max_new_tokens=n)
         _drain(eng, [req])
@@ -201,12 +211,12 @@ class TestDenseBitIdentityUnderForcedProposers:
         assert eng.decode_steps < n - 1
         eng.close()
 
-    def test_full_rejection_matches_oracle(self, tiny_model):
-        cfg, params = tiny_model
+    def test_full_rejection_matches_oracle(self, tiny_f32):
+        cfg, params = tiny_f32
         n, gamma = 12, 3
         prompt = PROMPTS[1]
         exp = _oracle(cfg, params, prompt, n)
-        eng = _build(cfg, params, paged=False, spec=gamma,
+        eng = _build(cfg, params, kernel="pallas", spec=gamma,
                      proposer=_AdversarialProposer([prompt + exp], gamma))
         req = eng.submit(prompt, max_new_tokens=n)
         _drain(eng, [req])

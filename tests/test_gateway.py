@@ -24,7 +24,7 @@ from lzy_tpu.gateway import (
 from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
-from lzy_tpu.serving import InferenceEngine, PagedInferenceEngine
+from lzy_tpu.serving import PagedInferenceEngine
 
 PAGE = 8
 
@@ -42,14 +42,12 @@ def _oracle_tokens(cfg, params, prompt_ids, n, **kw):
     return np.asarray(out)[0, len(prompt_ids):].tolist()
 
 
-def _make_gateway(cfg, params, *, replicas=3, slots=2, paged=False,
-                  router=None, autoscaler=None, start_engines=True,
-                  allocator=None, **engine_kw):
+def _make_gateway(cfg, params, *, replicas=3, slots=2, router=None,
+                  autoscaler=None, start_engines=True, allocator=None,
+                  **engine_kw):
     def factory():
-        if paged:
-            return PagedInferenceEngine(cfg, params, slots=slots,
-                                        page_size=PAGE, **engine_kw)
-        return InferenceEngine(cfg, params, slots=slots, **engine_kw)
+        return PagedInferenceEngine(cfg, params, slots=slots,
+                                    page_size=PAGE, **engine_kw)
 
     fleet = ReplicaFleet(factory, allocator=allocator,
                          start_engines=start_engines)
@@ -226,7 +224,7 @@ class TestGatewayParity:
         rng stream, and the first request consumes the same draws."""
         cfg, params = tiny_model
         kw = dict(temperature=0.8, top_k=20, seed=7)
-        solo = InferenceEngine(cfg, params, slots=2, **kw)
+        solo = PagedInferenceEngine(cfg, params, slots=2, **kw)
         ref = solo.submit([5, 9, 3], max_new_tokens=6)
         while not ref.done:
             solo.step()
@@ -273,8 +271,7 @@ class TestPrefixAffinityHitRate:
     shape and workload."""
 
     def _drive(self, cfg, params, router):
-        gw, fleet = _make_gateway(cfg, params, replicas=3, paged=True,
-                                  router=router)
+        gw, fleet = _make_gateway(cfg, params, replicas=3, router=router)
         try:
             # four families over three replicas: round-robin cannot stay
             # aligned (family i lands on a different replica every round),
@@ -523,7 +520,7 @@ class TestGatewayRpc:
         from lzy_tpu.service.inference import InferenceService
 
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=1).start()
+        engine = PagedInferenceEngine(cfg, params, slots=1).start()
         cluster = InProcessCluster(
             db_path=str(tmp_path / "meta.db"),
             storage_uri=f"file://{tmp_path}/storage",

@@ -26,7 +26,7 @@ from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
 from lzy_tpu.serving import (
-    BlockPool, InferenceEngine, NoFreeBlocks, PagedInferenceEngine,
+    BlockPool, NoFreeBlocks, PagedInferenceEngine,
     RadixCache)
 
 PAGE = 8
@@ -216,23 +216,26 @@ class TestPagedEngineParity:
         _drive(eng, c)
         assert c.result(0) == _oracle_tokens(cfg, params, c.prompt, 5)
 
-    def test_sampled_decode_matches_dense_engine(self, tiny_model):
-        """Same seed, same arrival schedule, temperature > 0: the paged
-        engine must reproduce the dense engine's sampled stream exactly
-        (both consume the engine-wide rng in the same order)."""
+    def test_sampled_decode_does_not_depend_on_paging(self, tiny_model):
+        """Same seed, same arrival schedule, temperature > 0: how the
+        pool is cut into pages (one page a row against pages of 8) must
+        not move a single draw — both consume the engine-wide rng in the
+        same order over the same logits."""
         cfg, params = tiny_model
         kw = dict(slots=2, temperature=0.8, top_k=20, seed=7)
-        dense = InferenceEngine(cfg, params, **kw)
+        whole = PagedInferenceEngine(cfg, params,
+                                     page_size=cfg.max_seq_len, **kw)
         paged = PagedInferenceEngine(cfg, params, page_size=PAGE, **kw)
-        d1 = dense.submit([5, 9, 3, 7], max_new_tokens=6)
+        w1 = whole.submit([5, 9, 3, 7], max_new_tokens=6)
         p1 = paged.submit([5, 9, 3, 7], max_new_tokens=6)
-        dense.step(), paged.step()
-        d2 = dense.submit([8, 1], max_new_tokens=5)
+        whole.step(), paged.step()
+        w2 = whole.submit([8, 1], max_new_tokens=5)
         p2 = paged.submit([8, 1], max_new_tokens=5)
-        _drive(dense, d1, d2)
+        _drive(whole, w1, w2)
         _drive(paged, p1, p2)
-        assert p1.result(0) == d1.result(0)
-        assert p2.result(0) == d2.result(0)
+        assert p1.result(0) == w1.result(0)
+        assert p2.result(0) == w2.result(0)
+        assert p1.result(0) != _oracle_tokens(cfg, params, p1.prompt, 6)
 
     def test_full_block_prompt_and_one_token_request(self, tiny_model):
         """Edge shapes: a prompt that is exactly N full blocks (the match
@@ -380,7 +383,7 @@ class TestDeadlines:
 
     def test_queued_request_expires_at_pop(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1)
+        eng = PagedInferenceEngine(cfg, params, slots=1)
         hog = eng.submit([5, 9, 3], max_new_tokens=100)
         doomed = eng.submit([1, 2], max_new_tokens=5, deadline_s=0.05)
         eng.step()
@@ -412,6 +415,6 @@ class TestDeadlines:
 
     def test_rejects_nonpositive_deadline(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1)
+        eng = PagedInferenceEngine(cfg, params, slots=1)
         with pytest.raises(ValueError, match="deadline"):
             eng.submit([1, 2], max_new_tokens=2, deadline_s=0.0)

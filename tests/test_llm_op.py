@@ -29,7 +29,7 @@ from lzy_tpu.gateway import (
     GatewayService, PrefixAffinityRouter, ReplicaFleet, RoundRobinRouter)
 from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate as oracle_generate
-from lzy_tpu.serving import InferenceEngine, PagedInferenceEngine
+from lzy_tpu.serving import PagedInferenceEngine
 from lzy_tpu.storage import DefaultStorageRegistry, StorageConfig
 from lzy_tpu.storage.registry import client_for
 
@@ -56,13 +56,11 @@ def _oracle_tokens(cfg, params, prompt_ids, n, **kw):
     return np.asarray(out)[0, len(prompt_ids):].tolist()
 
 
-def _make_gateway(cfg, params, *, replicas=2, slots=2, paged=True,
-                  router=None, **engine_kw):
+def _make_gateway(cfg, params, *, replicas=2, slots=2, router=None,
+                  **engine_kw):
     def factory():
-        if paged:
-            return PagedInferenceEngine(cfg, params, slots=slots,
-                                        page_size=PAGE, **engine_kw)
-        return InferenceEngine(cfg, params, slots=slots, **engine_kw)
+        return PagedInferenceEngine(cfg, params, slots=slots,
+                                    page_size=PAGE, **engine_kw)
 
     fleet = ReplicaFleet(factory)
     gw = GatewayService(fleet,
@@ -195,7 +193,7 @@ class TestTokenStreamChannel:
 class TestDirectGenerate:
     def test_direct_call_hits_engine_and_streams(self, tiny_model):
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=2).start()
+        engine = PagedInferenceEngine(cfg, params, slots=2).start()
         try:
             llm.configure(llm.EngineBackend(engine, model_name="tiny"))
             ch = TokenStreamChannel()
@@ -211,7 +209,7 @@ class TestDirectGenerate:
 
     def test_batch_fans_out_one_node(self, tiny_model):
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=2).start()
+        engine = PagedInferenceEngine(cfg, params, slots=2).start()
         try:
             llm.configure(llm.EngineBackend(engine, model_name="tiny"))
             prompts = [[5, 9, 3], [7, 2, 8, 1]]
@@ -702,7 +700,7 @@ class TestClusterEndToEnd:
         cluster = InProcessCluster(
             storage_uri="mem://llm-cluster",
             inference_factory=lambda c: build_gateway_service(
-                "tiny", replicas=2, slots=2, paged=True, page_size=PAGE,
+                "tiny", replicas=2, slots=2, page_size=PAGE,
                 allocator=c.allocator, autoscale=False))
         gw = cluster.inference_service
         try:

@@ -358,9 +358,9 @@ def test_the_shares_add_up(tiny):
 def _engine(tiny, **kw):
     cfg, params = tiny
     kw.setdefault("slots", 3)
+    kw.setdefault("kernel", "pallas")
     return PagedInferenceEngine(
-        cfg, params, page_size=16, native_attention=True, kernel="auto",
-        prefill_chunk=16, **kw)
+        cfg, params, page_size=16, prefill_chunk=16, **kw)
 
 
 def _drain(engine, limit=600):
@@ -501,7 +501,7 @@ def test_cache_leaves_are_declared_by_kind(served):
 
 @pytest.mark.parametrize("mechanism", [
     "speculation", "host tier", "storage tier", "parking", "import",
-    "export", "sharded engine", "dense engine", "int8 pool"])
+    "export", "sharded engine", "gather read", "int8 pool"])
 def test_each_refusal_names_its_mechanism(tiny, mechanism):
     cfg, params = tiny
     if mechanism == "speculation":
@@ -519,14 +519,12 @@ def test_each_refusal_names_its_mechanism(tiny, mechanism):
 
         with pytest.raises(NoPartitionRules, match="sharded engine"):
             ShardedPagedInferenceEngine(cfg, params, tp=2, slots=2)
-    elif mechanism == "dense engine":
-        from lzy_tpu.serving import InferenceEngine
-
-        with pytest.raises(ValueError, match="PagedInferenceEngine"):
-            InferenceEngine(cfg, params, slots=2)
+    elif mechanism == "gather read":
+        with pytest.raises(ValueError, match="gather read"):
+            _engine(tiny, native_attention=False)
     elif mechanism == "int8 pool":
         with pytest.raises(ValueError, match="kv_quant"):
-            _engine(tiny, kv_quant="int8")
+            _engine(tiny, kv_quant="int8", kernel="lax")
     else:
         engine = _engine(tiny, slots=1)
         try:

@@ -1,27 +1,26 @@
-"""Native paged-attention kernels + int8 KV quantization (PR 9).
+"""The paged-attention read paths + int8 KV quantization (PR 9).
 
 Three layers of coverage, matching the module's correctness contract
-(``lzy_tpu/ops/paged_attention.py``, docs/serving.md "Native paged
-attention & KV quantization"):
+(``lzy_tpu/ops/paged_attention.py``, docs/serving.md "Paged attention & KV
+quantization"):
 
-- **Op-level sweeps**: the lax gather-attention reproduces the legacy
-  read bit for bit (same ops in the same order), and stays the portable
-  oracle. The Pallas decode kernel (TPU interpreter on the CPU: DMAs,
+- **Op-level sweeps**: the lax gather-attention reproduces the dense
+  cache's read bit for bit (same ops in the same order), and stays the
+  portable oracle. The Pallas decode kernel (TPU interpreter on the CPU: DMAs,
   semaphores, scratch memory that starts as NaN) reorders the sums
   (online softmax), so it is judged against a float32 reference within
   the module's written tolerance, across page sizes, ragged per-row
   lengths, scratch-block idle rows, decode and verify windows and
   dtypes, and at the lengths that break such kernels.
 - **Model/engine-level oracle tests**: a ``PagedInferenceEngine`` with
-  ``native_attention=True, kernel="lax"`` must be bit-identical to the
-  solo ``generate()`` oracle — greedy and sampled, speculation on and
-  off. Through the kernel (``"auto"``), logits lie within the tolerance
-  and greedy tokens are the oracle's except at a logit tie.
+  ``kernel="lax"`` must be bit-identical to the solo ``generate()``
+  oracle — greedy and sampled, speculation on and off. Through the
+  kernel (``"pallas"``, interpreted here), logits lie within the
+  tolerance and greedy tokens are the oracle's except at a logit tie.
 - **int8 bounded divergence**: quantized output is intentionally NOT
   bit-identical; what IS asserted: the per-element dequantization error
   bound (one optimal-scale quantization step), kernel-independence of
-  quantized output (legacy == lax on the same int8 pool; the kernel
-  leaves int8 pools to lax),
+  quantized output (the kernel leaves int8 pools to lax),
   greedy-match rate against the fp oracle over long continuations, pool
   integrity (no leaked/corrupted blocks under quantization), and the 2x
   block-count win at a fixed pool byte budget.
@@ -337,11 +336,11 @@ class TestDecodeKernel:
 
 
 class TestModelPathBitExactness:
-    """The three read paths of ``Attention._decode_step`` — legacy
-    gather, native lax, native pallas — through the REAL model forward:
-    prefill chunks, 1-token decode, and a gamma+1 verify window over
-    interleaved per-row positions. lax is the legacy read bit for bit;
-    the kernel (all three windows are decode-sized here) within the
+    """The read paths of ``Attention._decode_step`` through the REAL
+    model forward: prefill chunks, 1-token decode, and a gamma+1 verify
+    window. The reference is the dense scalar-index cache that
+    ``models/generate.py``, the oracle, runs: lax is that read bit for
+    bit; the kernel (all three windows are decode-sized here) within the
     written tolerance."""
 
     def _run_path(self, tiny_model, **over):
@@ -370,37 +369,49 @@ class TestModelPathBitExactness:
             outs.append(logits)
         return outs
 
-    def test_native_lax_bit_identical_to_legacy(self, tiny_model):
-        legacy = self._run_path(tiny_model)
-        native = self._run_path(tiny_model, paged_attention_native=True,
-                                paged_kernel="lax")
-        for a, b in zip(legacy, native):
+    def _run_dense(self, tiny_model):
+        """The same three chunks over the dense ``[B, L, KV, D]`` cache
+        under one scalar index (every row of ``_run_path`` sits at the
+        same positions)."""
+        cfg0, params = tiny_model
+        model = Llama(decode_config(cfg0))
+        cache = init_cache(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((3, 1), jnp.int32)))
+        toks = jnp.asarray(np.random.default_rng(0).integers(
+            1, cfg0.vocab_size, (3, 6)), jnp.int32)
+        outs = []
+        for chunk in (toks, toks[:, :1], toks):
+            logits, upd = model.apply(
+                {"params": params, "cache": cache}, chunk,
+                mutable=["cache"])
+            cache = upd["cache"]
+            outs.append(logits)
+        return outs
+
+    def test_lax_bit_identical_to_the_dense_cache_read(self, tiny_model):
+        dense = self._run_dense(tiny_model)
+        lax = self._run_path(tiny_model, paged_kernel="lax")
+        for a, b in zip(dense, lax):
             assert bool(jnp.array_equal(a, b))
 
-    def test_native_pallas_logits_within_tolerance_of_legacy(
+    def test_pallas_logits_within_tolerance_of_the_dense_cache_read(
             self, tiny_model):
-        legacy = self._run_path(tiny_model)
-        native = self._run_path(tiny_model, paged_attention_native=True,
-                                paged_kernel="pallas")
-        for a, b in zip(legacy, native):
+        dense = self._run_dense(tiny_model)
+        kernel = self._run_path(tiny_model, paged_kernel="pallas")
+        for a, b in zip(dense, kernel):
             _assert_within_tolerance(b, np.asarray(a, np.float32),
                                      tiny_model[0].dtype, "logits")
 
     def test_quantized_output_is_kernel_independent(self, tiny_model):
         """int8 output diverges boundedly from fp but must NOT depend on
-        which path read the pool — legacy gather+dequant and lax
-        dequantize with the same (FMA-invariant) formula, and a model
-        asked for the kernel reads an int8 pool through lax."""
-        ql = self._run_path(tiny_model, kv_quant="int8")
+        which kernel the model was asked for: a model asked for the
+        kernel reads an int8 pool through lax."""
         qn = self._run_path(tiny_model, kv_quant="int8",
-                            paged_attention_native=True,
                             paged_kernel="lax")
         qp = self._run_path(tiny_model, kv_quant="int8",
-                            paged_attention_native=True,
                             paged_kernel="pallas")
-        for a, b, c in zip(ql, qn, qp):
+        for a, b in zip(qn, qp):
             assert bool(jnp.array_equal(a, b))
-            assert bool(jnp.array_equal(a, c))
 
     def test_quant_diverges_boundedly_from_fp(self, tiny_model):
         fp = self._run_path(tiny_model)
@@ -433,7 +444,7 @@ class TestNativeEngineOracle:
         want = [_oracle_tokens(cfg, params, p, self.N)
                 for p in self.PROMPTS]
         eng = PagedInferenceEngine(cfg, params, slots=4, page_size=8,
-                                   native_attention=True, kernel="lax")
+                                   kernel="lax")
         try:
             reqs = [eng.submit(p, max_new_tokens=self.N)
                     for p in self.PROMPTS]
@@ -448,8 +459,7 @@ class TestNativeEngineOracle:
         want = [_oracle_tokens(cfg, params, p, self.N)
                 for p in self.PROMPTS]
         eng = PagedInferenceEngine(cfg, params, slots=4, page_size=8,
-                                   native_attention=True, kernel="lax",
-                                   spec_tokens=4)
+                                   kernel="lax", spec_tokens=4)
         try:
             reqs = [eng.submit(p, max_new_tokens=self.N)
                     for p in self.PROMPTS]
@@ -465,8 +475,7 @@ class TestNativeEngineOracle:
         cfg, params = tiny_model
         want = [_oracle_tokens(cfg, params, p, 12) for p in self.PROMPTS]
         eng = PagedInferenceEngine(cfg, params, slots=4, page_size=8,
-                                   native_attention=True, kernel="pallas",
-                                   spec_tokens=3)
+                                   kernel="pallas", spec_tokens=3)
         try:
             before = _metric_value(DISPATCHES, path="pallas")
             reqs = [eng.submit(p, max_new_tokens=12)
@@ -479,22 +488,21 @@ class TestNativeEngineOracle:
         finally:
             eng.close()
 
-    def test_auto_is_the_kernel_and_counts_dispatches_by_path(
-            self, tiny_model):
-        """``"auto"`` serves decode through the Pallas kernel and prefill
-        chunks through lax, and ``lzy_kernel_dispatch_total`` says so: a
-        silent fall-back of decode to lax would show under ``lax``.
-        Greedy tokens are ``kernel="lax"``'s except at a logit tie."""
+    def test_the_kernel_counts_dispatches_by_path(self, tiny_model):
+        """``"pallas"`` (what ``"auto"`` is on a TPU) serves decode
+        through the Pallas kernel and prefill chunks through lax, and
+        ``lzy_kernel_dispatch_total`` says so: a silent fall-back of
+        decode to lax would show under ``lax``. Greedy tokens are
+        ``kernel="lax"``'s except at a logit tie."""
         cfg, params = tiny_model
         prompts = [list(range(1, 21)), [31, 9] * 9]
 
         def run(kernel):
             eng = PagedInferenceEngine(cfg, params, slots=2, page_size=8,
-                                       native_attention=True,
                                        kernel=kernel)
             try:
                 seen = {path: _metric_value(DISPATCHES, path=path)
-                        for path in ("pallas", "lax", "legacy")}
+                        for path in ("pallas", "lax")}
                 reqs = [eng.submit(p, max_new_tokens=self.N)
                         for p in prompts]
                 _drive(eng, *reqs)
@@ -505,35 +513,39 @@ class TestNativeEngineOracle:
             finally:
                 eng.close()
 
-        path, counts, auto = run("auto")
-        assert path == default_kernel() == "pallas"
+        path, counts, kernel = run("pallas")
+        assert path == "pallas"
         # two prompts of 20 and 18 tokens: one 32-wide chunk each
-        assert counts["lax"] == 2 and counts["legacy"] == 0
+        assert counts["lax"] == 2
         assert counts["pallas"] >= self.N - 1
         path, counts, lax = run("lax")
         assert path == "lax" and counts["pallas"] == 0
-        for p, a, b in zip(prompts, auto, lax):
+        for p, a, b in zip(prompts, kernel, lax):
             _assert_same_or_tie(cfg, params, p, a, b)
 
-    def test_native_sampled_matches_legacy_engine(self, tiny_model):
-        """Sampled rows share the engine-wide rng stream; the native lax
-        path must not perturb a single draw."""
+    def test_sampled_draws_do_not_depend_on_the_kernel(self, tiny_model):
+        """Sampled rows share the engine-wide rng stream; which kernel
+        reads the pool must not perturb a single draw (float32 compute:
+        the kernel's reordered sums stay far below what moves a draw)."""
         cfg, params = tiny_model
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
 
-        def sample_with(native):
+        def sample_with(kernel):
             eng = PagedInferenceEngine(
                 cfg, params, slots=3, page_size=8, temperature=0.8,
-                seed=11, native_attention=native,
-                kernel="lax" if native else "auto")
+                seed=11, kernel=kernel)
             try:
-                reqs = [eng.submit(p, max_new_tokens=10)
+                reqs = [eng.submit(p, max_new_tokens=6)
                         for p in self.PROMPTS]
                 _drive(eng, *reqs)
                 return [r.tokens for r in reqs]
             finally:
                 eng.close()
 
-        assert sample_with(True) == sample_with(False)
+        sampled = sample_with("lax")
+        assert sampled == sample_with("pallas")
+        assert sampled != [_oracle_tokens(cfg, params, p, 6)
+                           for p in self.PROMPTS]
 
     def test_dispatch_counter_counts_each_prefill_chunk(self, tiny_model):
         """One inc per PROGRAM, on every path: a multi-chunk prefill
@@ -542,8 +554,7 @@ class TestNativeEngineOracle:
 
         cfg, params = tiny_model
         eng = PagedInferenceEngine(cfg, params, slots=1, page_size=8,
-                                   prefill_chunk=4,
-                                   native_attention=True)
+                                   prefill_chunk=4)
         try:
             before = _metric_value(DISPATCHES)
             r = eng.submit(list(range(1, 21)), max_new_tokens=3)
@@ -554,20 +565,50 @@ class TestNativeEngineOracle:
         finally:
             eng.close()
 
-    def test_auto_kernel_resolves_by_platform(self, tiny_model):
+    def test_auto_kernel_resolves_by_platform(self, tiny_model, monkeypatch):
+        """``"auto"`` is the code's choice from the platform it observes:
+        lax on this CPU, the kernel on a TPU; a pool the kernel does not
+        read (int8) is lax there too. No keyword is the same as
+        ``"auto"``."""
         cfg, params = tiny_model
-        eng = PagedInferenceEngine(cfg, params, slots=1, page_size=8,
-                                   native_attention=True, kernel="auto")
-        try:
-            assert eng.kernel_path == default_kernel()
-        finally:
-            eng.close()
-        eng = PagedInferenceEngine(cfg, params, slots=1, page_size=8)
-        try:
-            assert eng.kernel_path == "legacy"
-            assert eng.stats().kernel_path == "legacy"
-        finally:
-            eng.close()
+        assert jax.default_backend() == "cpu" and default_kernel() == "lax"
+        for kw in ({}, {"kernel": "auto"}):
+            eng = PagedInferenceEngine(cfg, params, slots=1, page_size=8,
+                                       **kw)
+            try:
+                assert eng.kernel_path == "lax"
+                assert eng.stats().kernel_path == "lax"
+            finally:
+                eng.close()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert default_kernel() == "pallas"
+        for kw, path in (({}, "pallas"), ({"kv_quant": "int8"}, "lax")):
+            eng = PagedInferenceEngine(cfg, params, slots=1, page_size=8,
+                                       **kw)
+            try:
+                assert eng.kernel_path == path
+            finally:
+                eng.close()
+
+    @pytest.mark.parametrize("family", ["llama", "nemotron_h"])
+    def test_the_gather_read_is_refused_by_name(self, tiny_model, family):
+        """The two tombstones (``benchmark/`` still passes the keywords,
+        as ``True``): ``False`` is refused, naming what went and what
+        reads in its place."""
+        cfg, params = tiny_model
+        if family == "nemotron_h":
+            from lzy_tpu.models import nemotron_h
+
+            cfg = nemotron_h.NemotronHConfig.tiny()
+            params = None           # refused before a weight is touched
+        with pytest.raises(ValueError, match="gather read.*kernel='lax'"):
+            PagedInferenceEngine(cfg, params, native_attention=False)
+        with pytest.raises(ValueError, match="gather read.*kernel='lax'"):
+            cfg.paged_model(page_size=8, kv_pages=4, kernel="lax",
+                            kv_quant=None, native=False)
+        model = cfg.paged_model(page_size=8, kv_pages=4, kernel="lax",
+                                kv_quant=None, native=True)
+        assert model.cfg.decode_paged
 
     def test_bad_engine_kwargs_rejected(self, tiny_model):
         cfg, params = tiny_model
@@ -578,28 +619,26 @@ class TestNativeEngineOracle:
         with pytest.raises(ValueError, match="not both"):
             PagedInferenceEngine(cfg, params, kv_blocks=8,
                                  kv_pool_bytes=1 << 20)
-        # an explicit kernel the legacy path would silently ignore is a
-        # misconfiguration, not a preference
-        with pytest.raises(ValueError, match="native_attention"):
-            PagedInferenceEngine(cfg, params, kernel="pallas")
-        # so is the kernel over a pool it does not read
+        # the kernel over a pool it does not read is a misconfiguration,
+        # not a preference
         with pytest.raises(ValueError, match="int8"):
-            PagedInferenceEngine(cfg, params, native_attention=True,
-                                 kernel="pallas", kv_quant="int8")
+            PagedInferenceEngine(cfg, params, kernel="pallas",
+                                 kv_quant="int8")
 
     def test_serve_flags_validated(self):
         from lzy_tpu.service.serve import main
 
-        for flags in (["--serve-kernel", "pallas"],
-                      ["--serve-kv-quant", "int8"],
-                      ["--serve-kv-pool-mb", "64"],
-                      ["--serve-paged", "--serve-kernel", "pallas"],
-                      ["--serve-paged", "--serve-kv-blocks", "8",
-                       "--serve-kv-pool-mb", "64",
-                       "--serve-native-attention"]):
-            with pytest.raises(SystemExit):
+        for flags in (["--serve-kernel", "cuda"],
+                      ["--serve-kv-blocks", "8", "--serve-kv-pool-mb", "64"],
+                      ["--serve-mesh", "2", "--serve-kernel", "pallas"],
+                      # the flags that chose the engine and the read are
+                      # gone: unknown, not ignored
+                      ["--serve-paged"],
+                      ["--serve-native-attention"]):
+            with pytest.raises(SystemExit) as exit_:
                 main(["--storage-uri", "file:///tmp/x",
                       "--serve-model", "tiny"] + flags)
+            assert exit_.value.code == 2
 
 
 # -- int8 engine: bounded divergence + pool integrity -------------------------
@@ -619,8 +658,7 @@ class TestQuantEngine:
         n = 48
         want = [_oracle_tokens(cfg, params, p, n) for p in prompts]
         eng = PagedInferenceEngine(cfg, params, slots=4, page_size=8,
-                                   kv_quant="int8",
-                                   native_attention=True)
+                                   kv_quant="int8")
         try:
             reqs = [eng.submit(p, max_new_tokens=n) for p in prompts]
             _drive(eng, *reqs, rounds=600)
@@ -645,8 +683,7 @@ class TestQuantEngine:
         forked the bookkeeping."""
         cfg, params = tiny_model
         eng = PagedInferenceEngine(cfg, params, slots=2, page_size=8,
-                                   kv_blocks=9, kv_quant="int8",
-                                   native_attention=True)
+                                   kv_blocks=9, kv_quant="int8")
         try:
             prompts = [[i, i + 1, i + 2] * 3 for i in range(1, 11, 2)]
             reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
@@ -672,8 +709,7 @@ class TestQuantEngine:
         outs = []
         for _ in range(2):
             eng = PagedInferenceEngine(cfg, params, slots=2, page_size=8,
-                                       kv_quant="int8",
-                                       native_attention=True)
+                                       kv_quant="int8")
             try:
                 r1 = eng.submit(prompt, max_new_tokens=10)
                 _drive(eng, r1)
@@ -698,8 +734,7 @@ class TestQuantEngine:
         for quant in (None, "int8"):
             eng = PagedInferenceEngine(cfg, params, slots=2, page_size=8,
                                        kv_pool_bytes=budget,
-                                       kv_quant=quant,
-                                       native_attention=True)
+                                       kv_quant=quant)
             try:
                 sizes[quant] = eng.stats().kv_blocks_total
             finally:
@@ -782,16 +817,26 @@ class TestQuantMismatchFailsClosed:
         finally:
             de.close()
 
-    def test_builders_reject_native_knobs_without_paged(self):
+    def test_builders_serve_the_paged_engine_with_no_knob(self):
+        """What ``serve.py --serve-model X`` builds with no further flag:
+        the paged engine, its read chosen by the code; the keywords that
+        used to choose are unknown to every builder."""
         from lzy_tpu.service.inference import (
-            build_gateway_service, build_inference_service)
+            build_disagg_gateway_service, build_gateway_service,
+            build_inference_service)
 
-        for kw in ({"kv_quant": "int8"}, {"native_attention": True},
-                   {"kernel": "lax"}):
-            with pytest.raises(ValueError, match="paged"):
-                build_inference_service("tiny", **kw)
-            with pytest.raises(ValueError, match="paged"):
-                build_gateway_service("tiny", **kw)
+        svc = build_inference_service("tiny", start=False)
+        try:
+            assert type(svc.engine) is PagedInferenceEngine
+            assert svc.engine.kernel_path == default_kernel()
+            assert svc.engine.stats().kv_blocks_total > 0
+        finally:
+            svc.engine.close()
+        for build in (build_inference_service, build_gateway_service,
+                      build_disagg_gateway_service):
+            for kw in ({"paged": True}, {"native_attention": True}):
+                with pytest.raises(TypeError, match="unexpected keyword"):
+                    build("tiny", **kw)
 
     def test_resident_gauge_sums_engines_and_clears_on_close(
             self, tiny_model):
@@ -834,7 +879,7 @@ class TestQuantDisaggTransfer:
 
         cfg, params = tiny_model
         prompt = list(range(16)) + [40]      # 2 full blocks at page 8
-        kw = dict(page_size=8, kv_quant="int8", native_attention=True)
+        kw = dict(page_size=8, kv_quant="int8")
         pf = PrefillEngine(cfg, params, slots=1, **kw)
         try:
             req = pf.submit(prompt)
@@ -894,8 +939,7 @@ class TestSpecDraftTruncation:
         # verify round's _grow_for_spec comes up short
         eng = PagedInferenceEngine(
             cfg, params, slots=2, page_size=page, kv_blocks=7,
-            spec_tokens=6, proposer=_WindowProposer(6),
-            native_attention=True)
+            spec_tokens=6, proposer=_WindowProposer(6))
         try:
             before = _metric_value(DRAFT_TRUNCATED)
             reqs = [eng.submit([1 + i, 2, 3, 4, 5, 6, 7], max_new_tokens=12)

@@ -33,7 +33,7 @@ from lzy_tpu.gateway.journal import ORPHANED
 from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
-from lzy_tpu.serving import InferenceEngine, PagedInferenceEngine
+from lzy_tpu.serving import PagedInferenceEngine
 
 PAGE = 8
 
@@ -51,8 +51,8 @@ def _oracle_tokens(cfg, params, prompt_ids, n, **kw):
     return np.asarray(out)[0, len(prompt_ids):].tolist()
 
 
-def _make_ctx(cfg, params, *, replicas=2, slots=2, paged=False,
-              sharded=False, allocator=None, store=None, **engine_kw):
+def _make_ctx(cfg, params, *, replicas=2, slots=2, sharded=False,
+              allocator=None, store=None, **engine_kw):
     """A journal-backed gateway fleet plus everything a successor needs
     (the factory, the shared store, the fence auditor)."""
     store = store if store is not None else OperationStore(":memory:")
@@ -65,10 +65,8 @@ def _make_ctx(cfg, params, *, replicas=2, slots=2, paged=False,
             return ShardedPagedInferenceEngine(cfg, params, slots=slots,
                                                page_size=PAGE, tp=2,
                                                **engine_kw)
-        if paged:
-            return PagedInferenceEngine(cfg, params, slots=slots,
-                                        page_size=PAGE, **engine_kw)
-        return InferenceEngine(cfg, params, slots=slots, **engine_kw)
+        return PagedInferenceEngine(cfg, params, slots=slots,
+                                    page_size=PAGE, **engine_kw)
 
     fleet = ReplicaFleet(factory, allocator=allocator)
     auditor = FenceAuditor()
@@ -617,7 +615,7 @@ class TestKvIndexRecovery:
         from lzy_tpu.gateway.kv_index import GlobalKVIndex
 
         cfg, params = tiny_model
-        ctx = _make_ctx(cfg, params, replicas=2, paged=True,
+        ctx = _make_ctx(cfg, params, replicas=2,
                         kv_host_tier_bytes=1 << 20)
         gw = ctx["gw"]
         gw.kv_index = GlobalKVIndex(PAGE)
@@ -647,7 +645,7 @@ class TestKvIndexRecovery:
         from lzy_tpu.gateway.kv_index import GlobalKVIndex
 
         cfg, params = tiny_model
-        ctx = _make_ctx(cfg, params, replicas=2, paged=True,
+        ctx = _make_ctx(cfg, params, replicas=2,
                         kv_host_tier_bytes=1 << 20)
         gw = ctx["gw"]
         gw.kv_index = GlobalKVIndex(PAGE)

@@ -4,7 +4,7 @@ The batched prefill is an optimization with an in-tree oracle — the
 original one-device-call-per-token loop is kept as ``prefill="sequential"``
 — so parity is asserted token-for-token, greedy AND sampled (the batched
 path must advance the rng stream in lockstep with the oracle's per-token
-sample-and-discard). The engine tests drive ``InferenceEngine.step()``
+sample-and-discard). The engine tests drive ``PagedInferenceEngine.step()``
 synchronously so admission order is deterministic: requests join a LIVE
 decode batch mid-flight, leave on completion, and each one's tokens must
 match a solo ``generate()`` run bit-for-bit (any cross-request leakage
@@ -22,7 +22,7 @@ import pytest
 from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate, prefill_plan
 from lzy_tpu.models.llama import LlamaConfig
-from lzy_tpu.serving import AdmissionError, InferenceEngine
+from lzy_tpu.serving import AdmissionError, PagedInferenceEngine
 
 
 @pytest.fixture(scope="module")
@@ -140,7 +140,7 @@ def _oracle_tokens(cfg, params, prompt_ids, n):
 class TestInferenceEngine:
     def test_staggered_requests_share_the_decode_batch(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=2)
+        eng = PagedInferenceEngine(cfg, params, slots=2)
         a = eng.submit([5, 9, 3], max_new_tokens=12)
         eng.step()            # admits A (prefill emits token 1) + 1 decode
         eng.step()
@@ -160,7 +160,7 @@ class TestInferenceEngine:
 
     def test_freed_slot_is_reused_without_leakage(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1)
+        eng = PagedInferenceEngine(cfg, params, slots=1)
         a = eng.submit([5, 9, 3], max_new_tokens=3)
         for _ in range(10):
             if a.done:
@@ -180,7 +180,7 @@ class TestInferenceEngine:
         cfg, params = tiny_model
         prompt = [5, 9, 3]
         first = _oracle_tokens(cfg, params, prompt, 1)[0]
-        eng = InferenceEngine(cfg, params, slots=2, eos_token=first)
+        eng = PagedInferenceEngine(cfg, params, slots=2, eos_token=first)
         r = eng.submit(prompt, max_new_tokens=16)
         eng.step()
         assert r.done and r.result(0) == [first]
@@ -191,7 +191,7 @@ class TestInferenceEngine:
         decode steps: a cancelled slot-resident request is reaped at the
         next scheduling round, a cancelled queued one is dropped at pop."""
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1)
+        eng = PagedInferenceEngine(cfg, params, slots=1)
         a = eng.submit([5, 9, 3], max_new_tokens=50)
         queued = eng.submit([1, 2], max_new_tokens=50)
         eng.step()
@@ -212,14 +212,14 @@ class TestInferenceEngine:
 
     def test_admission_backpressure(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1, max_queue=1)
+        eng = PagedInferenceEngine(cfg, params, slots=1, max_queue=1)
         eng.submit([1, 2], max_new_tokens=2)
         with pytest.raises(AdmissionError):
             eng.submit([3, 4], max_new_tokens=2)
 
     def test_invalid_requests_rejected(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1)
+        eng = PagedInferenceEngine(cfg, params, slots=1)
         with pytest.raises(ValueError, match="non-empty|empty"):
             eng.submit([], max_new_tokens=2)
         with pytest.raises(ValueError, match="exceeds"):
@@ -227,7 +227,7 @@ class TestInferenceEngine:
 
     def test_background_loop_and_stats(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=2).start()
+        eng = PagedInferenceEngine(cfg, params, slots=2).start()
         try:
             reqs = [eng.submit([3 + i, 5, 7], max_new_tokens=4)
                     for i in range(3)]
@@ -245,7 +245,7 @@ class TestInferenceEngine:
         arrive in the gap: it must get retryable backpressure immediately,
         not sit in a queue no loop will ever drain until the RPC timeout."""
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1).start()
+        eng = PagedInferenceEngine(cfg, params, slots=1).start()
         eng.close()
         with pytest.raises(AdmissionError, match="shut down"):
             eng.submit([1, 2], max_new_tokens=2)
@@ -256,7 +256,7 @@ class TestInferenceEngine:
         fail every outstanding request and refuse new admissions — not die
         silently while waiters burn their full timeouts."""
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1)
+        eng = PagedInferenceEngine(cfg, params, slots=1)
         req = eng.submit([5, 9, 3], max_new_tokens=8)
         monkeypatch.setattr(
             eng, "step",
@@ -271,7 +271,7 @@ class TestInferenceEngine:
         from lzy_tpu.utils.metrics import REGISTRY
 
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=2)
+        eng = PagedInferenceEngine(cfg, params, slots=2)
         r = eng.submit([5, 9], max_new_tokens=3)
         while not r.done:
             eng.step()
@@ -292,7 +292,7 @@ class TestInferenceRpc:
         from lzy_tpu.service.inference import InferenceService
 
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=2).start()
+        engine = PagedInferenceEngine(cfg, params, slots=2).start()
         cluster = InProcessCluster(
             db_path=str(tmp_path / "meta.db"),
             storage_uri=f"file://{tmp_path}/storage",
@@ -314,3 +314,71 @@ class TestInferenceRpc:
                 client.close()
         finally:
             cluster.shutdown()
+
+
+# -- the model seam (models/serving.py) ---------------------------------------
+
+
+def _documented_names():
+    """Every name ``models/serving.py`` documents as a bullet of its
+    protocol (``- ``name(...)``: ...``) or in its opening sentence on the
+    configuration object's plain fields."""
+    import re
+
+    from lzy_tpu.models import serving
+
+    doc = serving.__doc__.split("**The module class**")[0]
+    fields = re.search(r"gives (.*?) \(the paged pool", doc, re.S).group(1)
+    names = re.findall(r"``(\w+)``", fields)
+    names += re.findall(r"^- ``(\w+)", doc, re.M)
+    return names
+
+
+class TestModelSeam:
+    @pytest.mark.parametrize("family", ["llama", "nemotron_h"])
+    def test_every_documented_name_is_answered(self, family):
+        """The protocol asks nothing its implementers refuse: every name
+        the module documents for the configuration object is an attribute
+        of both families' (a method, a property or a field), and each
+        builds its paged module and declares its cache kinds."""
+        from lzy_tpu.models import nemotron_h, serving
+
+        cfg = (LlamaConfig.tiny() if family == "llama"
+               else nemotron_h.NemotronHConfig.tiny())
+        names = _documented_names()
+        assert {"serving_config", "paged_model", "kv_layers", "kernel_paths",
+                "check_kernels", "max_seq_len", "head_dim"} <= set(names)
+        assert "dense_models" not in names
+        for name in names:
+            assert hasattr(cfg, name), f"{type(cfg).__name__}.{name}"
+        module = cfg.serving_config().paged_model(
+            page_size=16, kv_pages=4, kernel="lax", kv_quant=None)
+        assert isinstance(type(module).CACHE_KINDS, dict)
+        assert isinstance(tuple(type(module).STATS), tuple)
+        assert set(type(module).CACHE_KINDS.values()) <= {
+            serving.INDEX, serving.PAGED, serving.STATE}
+
+    def test_the_engine_names_no_model(self):
+        """``serving/engine.py`` holds one engine class and imports no
+        model family: what it needs it asks of the configuration."""
+        import ast
+        import inspect
+
+        from lzy_tpu.serving import engine
+
+        tree = ast.parse(inspect.getsource(engine))
+        imported = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom):
+                imported.add(node.module)
+                imported.update(f"{node.module}.{a.name}"
+                                for a in node.names)
+            elif isinstance(node, ast.Import):
+                imported.update(a.name for a in node.names)
+        for family in ("llama", "nemotron_h", "moe", "t5", "bert"):
+            assert f"lzy_tpu.models.{family}" not in imported
+        engines = [n.name for n in tree.body
+                   if isinstance(n, ast.ClassDef)
+                   and n.name.endswith("InferenceEngine")]
+        assert engines == ["PagedInferenceEngine"]
+        assert engine.PagedInferenceEngine.__mro__[1] is object

@@ -28,6 +28,7 @@ through every contract the serving stack pins —
 """
 
 import dataclasses
+import importlib
 import threading
 import time
 
@@ -46,6 +47,9 @@ from lzy_tpu.serving import engine as engine_mod
 from lzy_tpu.serving.disagg.kv_export import export_kv, import_kv
 from lzy_tpu.serving.sharded import ShardedPagedInferenceEngine
 from lzy_tpu.serving.sharded import metrics as _m
+
+# the module: ``lzy_tpu.ops`` exports the function under the same name
+paged_attention = importlib.import_module("lzy_tpu.ops.paged_attention")
 
 VOCAB = 64
 PAGE = 16
@@ -189,17 +193,42 @@ class TestConstruction:
             ShardedPagedInferenceEngine(cfg, params, tp=2,
                                         kernel="pallas")
 
-    def test_auto_kernel_is_lax_in_a_gang(self, tiny_model):
+    def test_auto_kernel_is_lax_in_a_gang(self, tiny_model, monkeypatch):
         """The Pallas decode kernel is a custom call GSPMD cannot
         partition: a gang's ``"auto"`` is the lax read, where a solo
-        engine's is the kernel."""
+        engine's is the kernel on a TPU."""
         cfg, params = tiny_model
+        monkeypatch.setattr(paged_attention, "default_kernel",
+                            lambda: "pallas")
         eng = ShardedPagedInferenceEngine(
-            cfg, params, tp=2, slots=2, page_size=PAGE,
-            native_attention=True, kernel="auto")
+            cfg, params, tp=2, slots=2, page_size=PAGE, kernel="auto")
         try:
             assert eng.kernel_path == "lax"
             assert eng.stats().kernel_path == "lax"
+        finally:
+            eng.close()
+
+    def test_default_arguments_count_dispatches_under_lax(
+            self, tiny_model, monkeypatch):
+        """No keyword at all, on a platform whose ``"auto"`` is the
+        kernel: every program a gang dispatches (prefill chunk, decode
+        steps) is counted under ``lax``, none under ``pallas``."""
+        DISPATCHES = paged_attention.DISPATCHES
+
+        def count(path):
+            return sum(v for key, v in DISPATCHES._values.items()
+                       if ("path", path) in key)
+
+        cfg, params = tiny_model
+        monkeypatch.setattr(paged_attention, "default_kernel",
+                            lambda: "pallas")
+        eng = ShardedPagedInferenceEngine(cfg, params, slots=2)
+        try:
+            before = {p: count(p) for p in ("lax", "pallas")}
+            assert _run(eng, PROMPTS[0], 6) == _oracle(
+                cfg, params, PROMPTS[0], 6)
+            assert count("lax") - before["lax"] >= 1 + 5
+            assert count("pallas") == before["pallas"]
         finally:
             eng.close()
 

@@ -13,6 +13,8 @@ the rollback path), so the accept and reject machinery are each pinned
 down exactly, not sampled by luck of the n-gram matcher.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,7 +24,7 @@ from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
 from lzy_tpu.serving import (
-    InferenceEngine, NgramProposer, PagedInferenceEngine)
+    NgramProposer, PagedInferenceEngine)
 
 VOCAB = 64
 
@@ -107,18 +109,28 @@ class TestNgramProposer:
             NgramProposer(max_ngram=2, min_ngram=3)
 
 
+def _for_kernel(tiny_model, kernel):
+    """The model a read path is held to the oracle on: ``lax`` makes the
+    oracle's sums in the oracle's order, in any dtype; the kernel's online
+    softmax reorders them, which in float32 compute stays far below any
+    gap between two logits."""
+    cfg, params = tiny_model
+    if kernel == "pallas":
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+    return cfg, params
+
+
 class TestGreedyBitIdentical:
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_spec_on_matches_oracle_and_spec_off(self, tiny_model, paged):
-        cfg, params = tiny_model
-        n = 20
+    @pytest.mark.parametrize("kernel", ["lax", "pallas"])
+    def test_spec_on_matches_oracle_and_spec_off(self, tiny_model, kernel):
+        cfg, params = _for_kernel(tiny_model, kernel)
+        n = 20 if kernel == "lax" else 8       # interpreted: keep it short
         expected = [_oracle(cfg, params, p, n) for p in PROMPTS]
 
         def build(spec):
-            if paged:
-                return PagedInferenceEngine(
-                    cfg, params, slots=2, page_size=16, spec_tokens=spec)
-            return InferenceEngine(cfg, params, slots=2, spec_tokens=spec)
+            return PagedInferenceEngine(
+                cfg, params, slots=2, page_size=16, spec_tokens=spec,
+                kernel=kernel)
 
         for spec in (0, 4):
             eng = build(spec)
@@ -219,7 +231,7 @@ class TestMixedBatch:
         exp_greedy = _oracle(cfg, params, greedy_prompt, n)
         outs = {}
         for spec in (0, 4):
-            eng = InferenceEngine(
+            eng = PagedInferenceEngine(
                 cfg, params, slots=2, temperature=0.8, top_k=20, seed=7,
                 spec_tokens=spec)
             r_sampled = eng.submit(sampled_prompt, max_new_tokens=n)
@@ -261,7 +273,7 @@ class TestEosAndLimits:
         gamma = 4
         prompt = PROMPTS[1]
         exp = _oracle(cfg, params, prompt, 16)
-        eng = InferenceEngine(
+        eng = PagedInferenceEngine(
             cfg, params, slots=1, spec_tokens=gamma,
             proposer=_OracleProposer([prompt + exp], gamma))
         req = eng.submit(prompt, max_new_tokens=7)
@@ -295,22 +307,19 @@ class TestStatsAndWarmup:
 
     def test_spec_off_omits_spec_fields(self, tiny_model):
         cfg, params = tiny_model
-        eng = InferenceEngine(cfg, params, slots=1)
+        eng = PagedInferenceEngine(cfg, params, slots=1)
         doc = eng.stats().doc()
         assert "spec_tokens" not in doc
         assert "spec_acceptance_rate" not in doc
         eng.close()
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_warmup_does_not_perturb_decode(self, tiny_model, paged):
-        cfg, params = tiny_model
+    @pytest.mark.parametrize("kernel", ["lax", "pallas"])
+    def test_warmup_does_not_perturb_decode(self, tiny_model, kernel):
+        cfg, params = _for_kernel(tiny_model, kernel)
         n = 10
         exp = _oracle(cfg, params, PROMPTS[1], n)
-        if paged:
-            eng = PagedInferenceEngine(
-                cfg, params, slots=2, page_size=16, spec_tokens=3)
-        else:
-            eng = InferenceEngine(cfg, params, slots=2, spec_tokens=3)
+        eng = PagedInferenceEngine(
+            cfg, params, slots=2, page_size=16, spec_tokens=3, kernel=kernel)
         eng.warmup()
         req = eng.submit(PROMPTS[1], max_new_tokens=n)
         _drain(eng, [req])
@@ -320,10 +329,10 @@ class TestStatsAndWarmup:
     def test_spec_tokens_validation(self, tiny_model):
         cfg, params = tiny_model
         with pytest.raises(ValueError, match="spec_tokens"):
-            InferenceEngine(cfg, params, spec_tokens=-1)
+            PagedInferenceEngine(cfg, params, spec_tokens=-1)
         with pytest.raises(ValueError, match="spec_tokens"):
-            InferenceEngine(cfg, params,
-                            spec_tokens=cfg.max_seq_len)
+            PagedInferenceEngine(cfg, params,
+                                 spec_tokens=cfg.max_seq_len)
 
 
 class TestServiceSurface:
@@ -335,8 +344,7 @@ class TestServiceSurface:
         from lzy_tpu.service.inference import build_inference_service
 
         svc = build_inference_service(
-            "tiny", slots=2, paged=True, page_size=16,
-            spec_tokens=3, warm_start=True)
+            "tiny", slots=2, page_size=16, spec_tokens=3, warm_start=True)
         try:
             assert svc.engine.spec_tokens == 3
             scfg = svc.engine.cfg

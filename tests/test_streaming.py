@@ -34,7 +34,7 @@ from lzy_tpu.gateway import (
 from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
-from lzy_tpu.serving import InferenceEngine, PagedInferenceEngine
+from lzy_tpu.serving import PagedInferenceEngine
 from lzy_tpu.serving.streams import (
     CANCELS, ConsumerGone, RESUMES, SHED_SLOW, StreamSessionManager)
 from lzy_tpu.service.inference import InferenceService
@@ -61,12 +61,9 @@ def _oracle_tokens(cfg, params, prompt_ids, n):
     return np.asarray(out)[0, len(prompt_ids):].tolist()
 
 
-def _service(cfg, params, *, paged=False, slots=2, **engine_kw):
-    if paged:
-        engine = PagedInferenceEngine(cfg, params, slots=slots,
-                                      page_size=PAGE, **engine_kw)
-    else:
-        engine = InferenceEngine(cfg, params, slots=slots, **engine_kw)
+def _service(cfg, params, *, slots=2, **engine_kw):
+    engine = PagedInferenceEngine(cfg, params, slots=slots,
+                                  page_size=PAGE, **engine_kw)
     engine.start()
     return InferenceService(engine, model_name="tiny"), engine
 
@@ -262,7 +259,7 @@ class TestClientDisconnect:
         liveness check — previously only deadline reaping covered it,
         so a dead client's request would eventually burn a slot."""
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=1)   # synchronous
+        engine = PagedInferenceEngine(cfg, params, slots=1)   # synchronous
         occupant = engine.submit([5, 9], max_new_tokens=60, greedy=True)
         ghost = engine.submit([6, 1], max_new_tokens=60, greedy=True,
                               tenant="ghost", liveness=lambda: False)
@@ -368,7 +365,7 @@ class TestClientDisconnect:
         the streaming layer must not kill a healthy request (the
         deadline still bounds it)."""
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=1)
+        engine = PagedInferenceEngine(cfg, params, slots=1)
 
         def boom():
             raise RuntimeError("probe bug")
@@ -492,11 +489,13 @@ class TestCancelPhases:
         assert after["decode"] == before["decode"] + 1
         engine.close()
 
-    def test_cancel_dense_engine_all_phases_clean(self, tiny_model):
-        """The dense plane has no pool to audit but the same phase
-        accounting; queued + decode cancels both land."""
+    def test_cancel_queued_and_decoding_in_one_round(self, tiny_model):
+        """A queued and a decoding request cancelled before the same
+        round: both land in it, each under its own phase, and the pool
+        comes out whole."""
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=1)
+        engine = PagedInferenceEngine(cfg, params, slots=1,
+                                      page_size=PAGE)
         before = self._deltas()
         occupant = engine.submit([5, 9], max_new_tokens=60, greedy=True,
                                  liveness=lambda: True)
@@ -509,6 +508,7 @@ class TestCancelPhases:
         engine.step()
         assert queued.status == "cancelled"
         assert occupant.status == "cancelled"
+        audit_engine(engine)
         after = self._deltas()
         assert after["queued"] == before["queued"] + 1
         assert after["decode"] == before["decode"] + 1
@@ -633,7 +633,7 @@ class TestStreamChaos:
         from lzy_tpu.service import InProcessCluster
 
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=2).start()
+        engine = PagedInferenceEngine(cfg, params, slots=2).start()
         tmp = tempfile.mkdtemp()
         cluster = InProcessCluster(
             db_path=f"{tmp}/meta.db", storage_uri=f"file://{tmp}/s",
@@ -663,7 +663,7 @@ class TestStreamChaos:
         the session flips dead and the engine evicts the request —
         slot free, pool clean — within one decode round."""
         cfg, params = tiny_model
-        svc, engine = _service(cfg, params, paged=True)
+        svc, engine = _service(cfg, params)
         plan = CHAOS.arm(FaultPlan(
             7, rate=1.0, modes=(ERROR,), points=("stream.consumer",),
             max_faults=1))
@@ -840,7 +840,7 @@ class TestRpcStreamDelivery:
         from lzy_tpu.service import InProcessCluster
 
         cfg, params = tiny_model
-        engine = InferenceEngine(cfg, params, slots=2).start()
+        engine = PagedInferenceEngine(cfg, params, slots=2).start()
         cluster = InProcessCluster(
             db_path=str(tmp_path / "meta.db"),
             storage_uri=f"file://{tmp_path}/storage",

@@ -34,11 +34,11 @@ class TestProfiled:
         from jax.profiler import ProfileData
 
         from lzy_tpu.models import llama, unbox
-        from lzy_tpu.serving import InferenceEngine
+        from lzy_tpu.serving import PagedInferenceEngine
 
         cfg = llama.LlamaConfig.tiny(vocab_size=64)
         params = unbox(llama.init_params(cfg, jax.random.PRNGKey(0))[0])
-        eng = InferenceEngine(cfg, params, slots=2)
+        eng = PagedInferenceEngine(cfg, params, slots=2)
         warm = eng.submit([1, 2], max_new_tokens=2)
         while not warm.done:
             eng.step()

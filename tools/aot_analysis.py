@@ -21,7 +21,7 @@ Outputs ``tpu_evidence/AOT_ANALYSIS.json`` + ``.md``. Run:
 
 The equivalence argument: XLA-TPU compilation is deterministic given
 (HLO, topology, compiler version); the scheduled module this tool
-analyses is byte-identical to what the driver's bench would execute on
+analyses is byte-identical to what a run of that step would execute on
 hardware, so FLOPs/bytes/collectives/memory are *facts* about the real
 program, and only the wall-clock (hence achieved MFU) still needs the
 chip. Reference perf target: BASELINE.md north star ≥ 0.40 MFU.
@@ -326,20 +326,27 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
 
 
 def targets() -> dict:
-    """The flagship configs, matched to bench.py pick_config()."""
+    """The configs ``tpu_evidence/AOT_ANALYSIS.*`` records: a ~350M-param
+    Llama sized for one v5e chip, and its v5e-16 variants."""
     import dataclasses
 
-    from bench import pick_config
+    from lzy_tpu.models.llama import LlamaConfig
 
-    # pick_config now returns the PROMOTED fused-b16 headline (fused CE +
-    # nothing-saveable remat, batch 16 — the config whose row says fits:
-    # yes); the pre-promotion dense no-remat config survives here as the
-    # secondary probe and the kept-as-evidence non-fitting northstar row
-    cfg, batch, seq, _, _ = pick_config()
+    # the fused-b16 headline (fused CE + nothing-saveable remat, batch 16
+    # — the config whose row says fits: yes); the dense no-remat config
+    # survives as the secondary probe and the kept-as-evidence non-fitting
+    # northstar row
+    cfg = LlamaConfig(
+        vocab_size=32_768, d_model=1024, n_layers=20, n_heads=8,
+        n_kv_heads=8, d_ff=4096, max_seq_len=2048,
+        remat=True, remat_policy="nothing", fused_ce=True,
+        tie_embeddings=True, use_flash_kernel=True,
+    )
+    batch, seq = 16, 2048
     dense = dataclasses.replace(cfg, fused_ce=False, remat=False)
     dense_batch = 8
     return {
-        # exactly the driver-bench headline: one v5e chip, 350M llama,
+        # the one-chip headline: one v5e chip, 350M llama,
         # fused-b16 (8.55 GB / bound 0.79 — fits)
         "bench_1chip": dict(
             cfg=cfg, topo="v5e-1", global_batch=batch, seq_len=seq,
@@ -353,11 +360,11 @@ def targets() -> dict:
         # same per-chip load as the old dense headline. The plain config is
         # kept although it does NOT fit (17.05 GB, the f32 logits +
         # remat=False activations) — that OOM row is itself evidence the
-        # driver bench needs the fused variant on this topology
+        # step needs the fused variant on this topology
         "northstar_v5e16_fsdp": dict(
             cfg=dense, topo="v5e-16", global_batch=dense_batch * 16,
             seq_len=seq, mesh_axes={"fsdp": -1}),
-        # the config the driver bench should actually run on a v5e-16:
+        # the config to actually run on a v5e-16:
         # logits-free chunked CE + dots-remat restores the memory headroom
         # (fused alone missed the 15.75 GB budget by 221 MB), which also
         # stops the scheduler's all-gather refetching (param re-gathers
