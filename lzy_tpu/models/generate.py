@@ -73,13 +73,37 @@ def init_cache(init_fn):
 PREFILL_BUCKETS = (8, 16, 32, 64, 128, 256)
 
 
+def prefill_width(budget: Optional[int] = None,
+                  widest: Optional[int] = None) -> int:
+    """The width of a prefill program: the widest bucket that is over
+    neither ``budget`` (the prompt tokens a scheduling round may spend;
+    None: no bound) nor ``widest`` (what the model says its kernels take:
+    ``models/serving.py``). A program costs one read of the weights whatever
+    its width up to the chip's balance of arithmetic to bytes (a couple of
+    hundred tokens in bfloat16), so a round's budget is spent as one wide
+    program, not as several narrow ones. A bound under the smallest bucket
+    gives the smallest bucket."""
+    bound = min(b for b in (budget, widest, PREFILL_BUCKETS[-1])
+                if b is not None)
+    return max((w for w in PREFILL_BUCKETS if w <= bound),
+               default=PREFILL_BUCKETS[0])
+
+
 def prefill_plan(t0: int, chunk: int, max_seq_len: int):
     """Chunk schedule for a ``t0``-token prompt: list of
     ``(start, take, width)`` where ``take`` real tokens starting at
     ``start`` run as one forward pass padded to ``width`` (the smallest
     bucket that fits, capped so the padded write never spills past
     ``max_seq_len`` — ``dynamic_update_slice`` would clamp the start and
-    overwrite real cache rows). At most ``ceil(t0/chunk)`` passes."""
+    overwrite real cache rows). At most ``ceil(t0/chunk)`` passes.
+
+    Only the last chunk is padded, and by less than half its width (the
+    buckets double), whatever ``chunk`` is. The tail is NOT cut into
+    descending buckets (144 -> 128 + 16): every further program reads the
+    weights again, and timed on the chip that costs as much as the pad or
+    more at every tail (Mistral-7B at 16 layers on a v5e: a tail padded to
+    256 takes 26.5 ms, as 128 + 16 26.8 ms; padded to 128 14.1 ms, as
+    64 + 8 25.4 ms: PERF.md section 6, PR 32), with a dispatch more."""
     chunk = max(1, chunk)
     widths = sorted({w for w in PREFILL_BUCKETS if w <= chunk} | {chunk})
     plan = []

@@ -136,6 +136,15 @@ class LlamaConfig:
             kv_pages=kv_pages, paged_kernel=kernel, kv_quant=kv_quant),
             **module_kw)
 
+    @property
+    def widest_prefill(self) -> int:
+        """The widest prefill program this family's kernels take: dense
+        matmuls and the lax read, which take any width, so the widest
+        bucket."""
+        from lzy_tpu.models.generate import PREFILL_BUCKETS
+
+        return PREFILL_BUCKETS[-1]
+
     def kernel_paths(self, t: int) -> tuple:
         """``lzy_kernel_dispatch_total{path}`` labels beside the attention
         read's own: this family has no other kernel."""

@@ -159,6 +159,16 @@ class NemotronHConfig:
             self, decode_paged=True, kv_page_size=page_size,
             kv_pages=kv_pages, paged_kernel=kernel))
 
+    @property
+    def widest_prefill(self) -> int:
+        """The widest prefill program this model's kernels take: 256.
+        ``ops/grouped_experts.py``'s arithmetic grows with rows x touched
+        experts while the read of the experts it amortises does not, and
+        at the Nemotron-3-Super widths the read still hides most of it at
+        256 rows: a program of 64 / 128 / 256 positions takes 12.7 / 14.1 /
+        16.8 ms on a v5e chip (PERF.md section 6, PR 32)."""
+        return 256
+
     def kernel_paths(self, t: int) -> Tuple[str, ...]:
         """``lzy_kernel_dispatch_total{path}`` labels of a program over
         ``t`` positions a row, beside the attention read's own."""

@@ -14,6 +14,11 @@ the attention kernel's shapes), and:
   pool through the page table (``ops/paged_attention.py``);
 - ``kv_layers``: layers that keep keys and values in the paged pool, what a
   byte budget for the pool is divided by;
+- ``widest_prefill``: the widest prefill program (query positions of one
+  batch-1 chunk) the model's kernels take; the engine's chunk width is the
+  widest bucket under it and under the round's ``prefill_budget``. A model
+  of dense matmuls answers the widest bucket; one whose kernel's
+  arithmetic grows faster than its rows answers where that stops paying;
 - ``kernel_paths(t)``: ``lzy_kernel_dispatch_total{path}`` labels of a
   program over ``t`` positions a row, beside the attention read's own;
 - ``check_kernels(slots=)``: lower the model's own kernels for a TPU at the

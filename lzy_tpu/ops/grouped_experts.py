@@ -8,9 +8,11 @@ The experts are ungated two-matrix MLPs with a squared ReLU,
 ``m`` did not choose ``e`` (or is a pad row, or an idle slot).
 
 :func:`grouped_experts` is written for the serving batch, where the rows are
-few (a decode round's slots, a prefill chunk's positions: 8 to 64) and the
+few (a decode round's slots, a prefill chunk's positions: 8 to 256) and the
 experts many: the cost is the read of each touched expert's weights from HBM
-(11 MB at the Nemotron-3-Super widths), not arithmetic. So the kernel
+(11 MB at the Nemotron-3-Super widths), not arithmetic; at 256 rows the
+arithmetic is as long as the read and the pipeline still overlaps the two
+(``NemotronHConfig.widest_prefill`` has the timings). So the kernel
 (``grouped_experts`` in a device trace) walks the **touched** experts, reads
 each one's two matrices once, in tiles of the intermediate width, multiplies
 all the rows by it (one pass of the matrix unit whatever the row count) and

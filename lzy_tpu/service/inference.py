@@ -276,7 +276,7 @@ def build_gateway_service(
     eos_token: Optional[int] = None,
     checkpoint: Optional[str] = None,
     seed: int = 0,
-    prefill_chunk: int = 64,
+    prefill_chunk: Optional[int] = None,
     page_size: int = 16,
     kv_blocks: Optional[int] = None,
     kv_pool_bytes: Optional[int] = None,
@@ -318,7 +318,9 @@ def build_gateway_service(
     draft-free speculative decoding on every replica (``--serve-spec``);
     ``warm_start`` AOT-compiles each replica's decode/verify programs at
     boot instead of on the first request. ``prefill_budget`` bounds
-    prefill tokens per engine step (chunked-prefill interleaving);
+    prefill tokens per engine step (chunked-prefill interleaving), spent as
+    one program as wide as the widest bucket under it (``prefill_chunk``
+    None: the engine derives the width; a number is used as given);
     ``tenants`` (a ``serving.tenancy.TenantTable``) turns on the
     multi-tenant SLO layer: token-bucket rate limits at the gateway,
     WFQ + per-tenant queue caps + KV quotas in every replica.
@@ -428,7 +430,7 @@ def build_disagg_gateway_service(
     eos_token: Optional[int] = None,
     checkpoint: Optional[str] = None,
     seed: int = 0,
-    prefill_chunk: int = 64,
+    prefill_chunk: Optional[int] = None,
     page_size: int = 16,
     kv_blocks: Optional[int] = None,
     kv_pool_bytes: Optional[int] = None,
@@ -573,7 +575,7 @@ def build_inference_service(
     eos_token: Optional[int] = None,
     checkpoint: Optional[str] = None,
     seed: int = 0,
-    prefill_chunk: int = 64,
+    prefill_chunk: Optional[int] = None,
     page_size: int = 16,
     kv_blocks: Optional[int] = None,
     kv_pool_bytes: Optional[int] = None,
@@ -616,7 +618,8 @@ def build_inference_service(
 
     ``prefill_budget`` bounds prompt tokens prefilled per engine round
     (chunked-prefill interleaving — long prompts cannot starve resident
-    rows); ``tenants`` (a ``serving.tenancy.TenantTable``) turns on the
+    rows), spent as one program as wide as the widest bucket under it;
+    ``tenants`` (a ``serving.tenancy.TenantTable``) turns on the
     multi-tenant SLO layer: rate limits at this front, WFQ + queue caps
     + KV quotas in the engine (docs/serving.md "Multi-tenant SLO
     serving").

@@ -148,7 +148,8 @@ def main(argv=None) -> int:
                              "interleaving: long prompts advance in "
                              "bounded chunks BETWEEN decode steps so "
                              "they cannot starve resident requests' "
-                             "token streams; 0 runs each prompt's "
+                             "token streams; the width of a prefill "
+                             "program follows it; 0 runs each prompt's "
                              "prefill in one round)")
     parser.add_argument("--serve-slo", action="store_true",
                         help="multi-tenant SLO enforcement: per-tenant "

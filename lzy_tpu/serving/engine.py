@@ -26,8 +26,12 @@ in ``models/serving.py``): this file names no model.
   through the model as batch-1 bucketed chunks against the same pool, at
   most ``prefill_budget`` tokens a scheduling round, interleaved with the
   resident rows' decode steps; the finished job's slot starts generating
-  on the very next step. A model with per-slot ``state`` leaves carries
-  the job's own batch-1 rows between chunks.
+  on the very next step. A chunk is as wide as the round's budget allows
+  and the model says its kernels take (``models/generate.py``
+  ``prefill_width``): a program reads the weights once whatever its width,
+  so a budget of 256 is one program of 256 positions, not four of 64. A
+  model with per-slot ``state`` leaves carries the job's own batch-1 rows
+  between chunks.
 - **One jitted step a round, one fence**: the decode hot loop is ONE jitted
   step over the ``[slots]`` rows, whose positions live in one ``[slots]``
   vector; a finished row leaves its slot immediately (its blocks go back to
@@ -80,7 +84,8 @@ import numpy as np
 
 from lzy_tpu.chaos.faults import CHAOS, CRASH, DELAY, ERROR, SLOW
 from lzy_tpu.models import serving
-from lzy_tpu.models.generate import init_cache, prefill_plan, sample_token
+from lzy_tpu.models.generate import (
+    init_cache, prefill_plan, prefill_width, sample_token)
 from lzy_tpu.serving.scheduler import (
     AdmissionError, PromptTooLong, Request, RequestQueue)
 from lzy_tpu.serving.tenancy import (
@@ -140,6 +145,19 @@ _FP_PREFILL = CHAOS.register(
 _PREFILL_ROUNDS = REGISTRY.counter(
     "lzy_inference_prefill_rounds_total",
     "bounded prefill rounds run between decode steps (chunked prefill)")
+
+# what prefill programs carry: a program costs a read of the weights
+# whatever its width, so tokens / programs says how well a round's budget
+# is spent, and positions - tokens what the last chunk's pad costs
+_PREFILL_TOKENS = REGISTRY.counter(
+    "lzy_engine_prefill_tokens_total",
+    "prompt tokens forwarded by prefill programs (real tokens, no pads)")
+_PREFILL_PROGRAMS = REGISTRY.counter(
+    "lzy_engine_prefill_programs_total",
+    "prefill programs dispatched (one a chunk of a plan)")
+_PREFILL_POSITIONS = REGISTRY.counter(
+    "lzy_engine_prefill_positions_total",
+    "positions prefill programs ran over: their widths, pads included")
 
 # decode-round scheduling (docs/serving.md "Decode-round scheduling"):
 # each round dispatches ONE fused device program and takes ONE
@@ -210,8 +228,9 @@ class _PrefillJob:
     prompt tokens per scheduling round, interleaved with decode steps,
     so a 32k-token prompt can never freeze resident rows' token streams.
     The chunk *plan* is fixed at staging (identical to the one-shot
-    path), so pausing between chunks changes scheduling, never numerics
-    — greedy output stays bit-identical to an uncontended run."""
+    path at the same chunk width, which follows the budget), so pausing
+    between chunks changes scheduling, never numerics — greedy output
+    stays bit-identical to an uncontended run."""
 
     req: Request
     slot: int                       # reserved; activates on completion
@@ -343,7 +362,7 @@ class PagedInferenceEngine:
         top_k: Optional[int] = None,
         top_p: Optional[float] = None,
         eos_token: Optional[int] = None,
-        prefill_chunk: int = 64,
+        prefill_chunk: Optional[int] = None,
         seed: int = 0,
         spec_tokens: int = 0,
         spec_ngram: int = 3,
@@ -413,7 +432,11 @@ class PagedInferenceEngine:
 
         self._clock = clock if clock is not None else SYSTEM_CLOCK
         self.eos_token = eos_token
-        self.prefill_chunk = prefill_chunk
+        # the width of a prefill program follows the round's budget and
+        # what the model says its kernels take (models/generate.py
+        # prefill_width); a caller's ``prefill_chunk`` is used as given
+        self.prefill_chunk = prefill_chunk if prefill_chunk is not None \
+            else prefill_width(prefill_budget, base.widest_prefill)
         self._temperature = temperature
         self._top_k, self._top_p = top_k, top_p
         self._rng = jax.random.PRNGKey(seed)
@@ -566,8 +589,10 @@ class PagedInferenceEngine:
 
         # chunked-prefill interleaving: at most ``prefill_budget`` prompt
         # tokens advance per scheduling round (None = whole prompt in one
-        # round, the pre-tenancy behavior); jobs rotate round-robin so a
-        # short prompt staged behind a long one completes in O(1) rounds
+        # round, the pre-tenancy behavior), as one program where a bucket
+        # is as wide as the budget (``prefill_chunk`` above); jobs rotate
+        # round-robin so a short prompt staged behind a long one completes
+        # in O(1) rounds
         self.prefill_budget = (None if prefill_budget is None
                                else int(prefill_budget))
         self._prefill_jobs: List[_PrefillJob] = []
@@ -1099,7 +1124,9 @@ class PagedInferenceEngine:
         _PREFILL_ROUNDS.inc()
         if trace.ON:
             trace.note(request=req.id, chunks=job.next_chunk - chunks0,
-                       tokens=job.done - tokens0, finished=finished)
+                       tokens=job.done - tokens0, finished=finished,
+                       width=sum(w for _, _, w
+                                 in job.plan[chunks0:job.next_chunk]))
         if finished:
             self._drop_prefill_job(job)
         else:
@@ -2041,7 +2068,6 @@ class PagedInferenceEngine:
         return "admit" if self._can_admit(req) else "wait"
 
     def _stage_prefill(self, slot: int, req: Request) -> _PrefillJob:
-        from lzy_tpu.models.generate import prefill_plan
         from lzy_tpu.serving.kv_cache import blocks_for
 
         prompt = req.prompt
@@ -2125,6 +2151,9 @@ class PagedInferenceEngine:
                 # run several) — the dispatch counter must agree with
                 # the decode/verify paths' one-inc-per-program rule
                 self._count_dispatch(tokens.shape[1])
+                _PREFILL_PROGRAMS.inc()
+                _PREFILL_TOKENS.inc(take)
+                _PREFILL_POSITIONS.inc(tokens.shape[1])
                 return self._prefill_step(
                     c, self.params, tokens, pt,
                     jnp.asarray(take - 1, jnp.int32))

@@ -295,10 +295,11 @@ def test_paged_decode_step_compiles_at_full_width(one_chip, monkeypatch):
 
 # -- the Nemotron-H serving kernels at published widths ----------------------
 
-@pytest.mark.parametrize("rows", [64, 8], ids=["decode", "chunk8"])
+@pytest.mark.parametrize("rows", [64, 8, 256],
+                         ids=["decode", "chunk8", "chunk256"])
 def test_grouped_experts_compiles_at_published_widths(rows, one_chip):
     """128 held experts of 1024 x 2688 in bfloat16: a decode round's 64
-    rows and the narrowest prefill chunk."""
+    rows, the narrowest prefill chunk and the widest."""
     from lzy_tpu.ops import grouped_experts as gexp
 
     def sds(shape, dtype):
