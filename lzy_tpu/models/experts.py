@@ -1,0 +1,89 @@
+"""What the served models with sparse experts share: the counters their
+expert layers feed, and the router that tells a layer which of the experts
+it holds each row reaches (``models/nemotron_h.py``,
+``models/solar_open2.py``).
+
+**The router** is a sigmoid over all the routed experts, float32 at the
+highest precision (a near-tie among its scores decides which expert a row
+reaches): the ``top_k`` largest of ``score + bias`` are chosen (the
+correction bias of auxiliary-loss-free load balancing steers the choice
+only), and the chosen scores are renormalised and scaled. **The layer is
+told which experts it holds** (``held = (lo, hi)``): the router keeps its
+published width and its experts per token, and a chosen expert held
+elsewhere gets no weight here. Pad positions and idle slots (``real``
+False) are left out of the weights and of the counts.
+
+**The counts** (:data:`STATS`): one vector a layer, sown into the ``stats``
+collection in this order, summed over layers by the engine and carried out
+of a decode round with its tokens (``models/serving.py``).
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from lzy_tpu.utils.metrics import REGISTRY
+
+MOE_ASSIGNMENTS = REGISTRY.counter(
+    "lzy_moe_assignments_total",
+    "(row, chosen expert) pairs of decode rounds, real rows only, a layer")
+MOE_HELD_ASSIGNMENTS = REGISTRY.counter(
+    "lzy_moe_held_assignments_total",
+    "of lzy_moe_assignments_total, those that fell on an expert held here")
+MOE_EXPERTS_TOUCHED = REGISTRY.counter(
+    "lzy_moe_experts_touched_total",
+    "held experts that a decode round's rows reached, a layer a round")
+MOE_EXPERTS_HELD = REGISTRY.counter(
+    "lzy_moe_experts_held_total",
+    "held experts, a layer a round (the denominator of the touched share)")
+
+#: what an expert layer sows into the ``stats`` collection, in this order
+STATS = (MOE_ASSIGNMENTS, MOE_HELD_ASSIGNMENTS, MOE_EXPERTS_TOUCHED,
+         MOE_EXPERTS_HELD)
+
+
+def row_mask(valid_len, b: int, t: int):
+    """``[B, T]`` bool: which positions are real."""
+    if valid_len is None:
+        return jnp.ones((b, t), bool)
+    return jnp.arange(t)[None, :] < valid_len[:, None]
+
+
+def held_weights(layer: nn.Module, um, real, *, n_routed: int, top_k: int,
+                 held: Tuple[int, int], scaling: float):
+    """``[M, held]`` float32: each row's weight for each expert held here
+    (0 where it did not choose it, or is not real). Called from the expert
+    layer's compact method: the parameters ``router`` ``[D, n_routed]`` and
+    ``router_bias`` are the layer's own, the row's choices are sown as
+    ``intermediates/chosen`` and the layer's counts as ``stats/moe``."""
+    f32 = jnp.float32
+    lo, hi = held
+    n_held = hi - lo
+    wr = layer.param("router", nn.initializers.normal(0.02),
+                     (um.shape[-1], n_routed), f32)
+    bias = layer.param("router_bias", nn.initializers.normal(0.02),
+                       (n_routed,), f32)
+    scores = jax.nn.sigmoid(jnp.dot(
+        um.astype(f32), wr, precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + bias, top_k)              # [M, k]
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) \
+        * scaling
+    # for whoever asks (``mutable=["intermediates"]``): a row's choices
+    layer.sow("intermediates", "chosen", chosen)
+    on_held = (chosen >= lo) & (chosen < hi) & real[:, None]
+    # [M, k, held]: which held expert each of a row's choices is
+    onehot = on_held[:, :, None] & (
+        chosen[:, :, None] - lo == jnp.arange(n_held)[None, None, :])
+    weights = jnp.sum(jnp.where(onehot, picked[:, :, None], 0.0), axis=1)
+    reached = jnp.any(onehot, axis=(0, 1))
+    layer.sow("stats", "moe", jnp.stack([
+        jnp.sum(real) * top_k, jnp.sum(on_held), jnp.sum(reached),
+        jnp.asarray(n_held)]).astype(jnp.int32),
+        reduce_fn=lambda a, c: a + c,
+        init_fn=lambda: jnp.zeros((4,), jnp.int32))
+    return weights

@@ -44,27 +44,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from lzy_tpu.models.experts import STATS, held_weights, row_mask
 from lzy_tpu.models.llama import RMSNorm
+from lzy_tpu.models.paged_blocks import (
+    PagedAttention, dense, inv_softplus, normal)
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import mamba2
-from lzy_tpu.utils.metrics import REGISTRY
-
-MOE_ASSIGNMENTS = REGISTRY.counter(
-    "lzy_moe_assignments_total",
-    "(row, chosen expert) pairs of decode rounds, real rows only, a layer")
-MOE_HELD_ASSIGNMENTS = REGISTRY.counter(
-    "lzy_moe_held_assignments_total",
-    "of lzy_moe_assignments_total, those that fell on an expert held here")
-MOE_EXPERTS_TOUCHED = REGISTRY.counter(
-    "lzy_moe_experts_touched_total",
-    "held experts that a decode round's rows reached, a layer a round")
-MOE_EXPERTS_HELD = REGISTRY.counter(
-    "lzy_moe_experts_held_total",
-    "held experts, a layer a round (the denominator of the touched share)")
-
-#: what an expert layer sows into the ``stats`` collection, in this order
-STATS = (MOE_ASSIGNMENTS, MOE_HELD_ASSIGNMENTS, MOE_EXPERTS_TOUCHED,
-         MOE_EXPERTS_HELD)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +62,8 @@ class NemotronHConfig:
     n_heads: int = 32
     n_kv_heads: int = 2
     head_dim: int = 128
+    #: no output gate on the heads (``models/paged_blocks.py`` reads it)
+    attn_gate: bool = False
     # Mamba-2
     mamba_heads: int = 128
     mamba_head_dim: int = 64
@@ -206,43 +193,6 @@ class NemotronHConfig:
             max_seq_len=128, dtype=jnp.float32, param_dtype=jnp.float32)
 
 
-def _normal(std: float = 0.02):
-    """``normal(std)`` drawn in float32 and then cast: drawn in bfloat16
-    directly, a normal variate takes a few hundred distinct values."""
-    def init(key, shape, dtype=jnp.float32):
-        return (jax.random.normal(key, shape, jnp.float32) * std).astype(
-            dtype)
-
-    return init
-
-
-class Linear(nn.Module):
-    """``x @ kernel`` with no bias; ``out_dtype`` is what leaves the
-    accumulator (float32 where the result steers an exponential)."""
-    features: int
-    dtype: Any
-    param_dtype: Any
-    out_dtype: Any = None
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param("kernel", _normal(),
-                            (x.shape[-1], self.features), self.param_dtype)
-        return jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype),
-                       preferred_element_type=self.out_dtype or self.dtype)
-
-
-def _dense(features, name, cfg, out_dtype=None):
-    return Linear(features, cfg.dtype, cfg.param_dtype, out_dtype, name=name)
-
-
-def _row_mask(valid_len, b: int, t: int):
-    """``[B, T]`` bool: which positions are real."""
-    if valid_len is None:
-        return jnp.ones((b, t), bool)
-    return jnp.arange(t)[None, :] < valid_len[:, None]
-
-
 class Mamba2Mixer(nn.Module):
     cfg: NemotronHConfig
 
@@ -257,7 +207,7 @@ class Mamba2Mixer(nn.Module):
 
         # [z, xBC, dt] in one projection; float32 out of the accumulator:
         # dt steers an exponential
-        zxbcdt = _dense(di + cd + h, "in_proj", cfg, f32)(u)
+        zxbcdt = dense(di + cd + h, "in_proj", cfg, f32)(u)
         z = zxbcdt[..., :di].astype(f32)
         # the convolution's inputs are kept a row (the conv state), in the
         # activations' dtype: round them before use, in prefill and decode
@@ -271,7 +221,7 @@ class Mamba2Mixer(nn.Module):
         # dt = softplus(dt_raw + dt_bias) starts log-uniform in
         # [time_step_min, time_step_max] = [0.001, 0.1]
         dt_bias = self.param(
-            "dt_bias", lambda key, shape: _inv_softplus(jnp.exp(
+            "dt_bias", lambda key, shape: inv_softplus(jnp.exp(
                 jax.random.uniform(key, shape, f32, jnp.log(1e-3),
                                    jnp.log(1e-1)))), (h,))
         a_log = self.param(
@@ -290,7 +240,7 @@ class Mamba2Mixer(nn.Module):
             prev = jnp.zeros((b, k - 1, cd), cfg.dtype)
             state = jnp.zeros((b, h, p, n), f32)
 
-        real = _row_mask(valid_len, b, t)                        # [B, T]
+        real = row_mask(valid_len, b, t)                         # [B, T]
         seq = jnp.concatenate([prev, xbc], axis=1)               # [B, T+k-1]
         conv = conv_b + sum(conv_w[i] * seq[:, i:i + t].astype(f32)
                             for i in range(k))
@@ -326,57 +276,7 @@ class Mamba2Mixer(nn.Module):
         yg = yg * jax.lax.rsqrt(
             jnp.mean(jnp.square(yg), axis=-1, keepdims=True) + cfg.norm_eps)
         y = (yg.reshape(b, t, di) * gate_w).astype(cfg.dtype)
-        return _dense(cfg.d_model, "out_proj", cfg)(y)
-
-
-def _inv_softplus(x):
-    return x + jnp.log(-jnp.expm1(-x))
-
-
-class PagedAttention(nn.Module):
-    """Grouped-query attention, no rotary embedding, over the shared paged
-    pool (or, uncached, causal over the chunk)."""
-    cfg: NemotronHConfig
-
-    @nn.compact
-    def __call__(self, u, page_table=None):
-        from lzy_tpu.ops.paged_attention import (
-            paged_attention, paged_scatter_index)
-
-        cfg = self.cfg
-        b, t, _ = u.shape
-        h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = _dense(h * d, "q_proj", cfg)(u).reshape(b, t, h, d)
-        k = _dense(kv * d, "k_proj", cfg)(u).reshape(b, t, kv, d)
-        v = _dense(kv * d, "v_proj", cfg)(u).reshape(b, t, kv, d)
-        if not cfg.decode_paged:
-            qg = q.reshape(b, t, kv, h // kv, d)
-            s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
-                           preferred_element_type=jnp.float32) * d ** -0.5
-            keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-            pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
-            out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype), v)
-            return _dense(cfg.d_model, "o_proj", cfg)(
-                out.reshape(b, t, h * d))
-        shape = (cfg.kv_pages, cfg.kv_page_size, kv, d)
-        pool_k = self.variable("cache", "k", jnp.zeros, shape, cfg.dtype)
-        pool_v = self.variable("cache", "v", jnp.zeros, shape, cfg.dtype)
-        index = self.variable("cache", "index",
-                              lambda: jnp.zeros((b,), jnp.int32))
-        pos = index.value[:, None] + jnp.arange(t, dtype=jnp.int32)
-        if not self.is_initializing():
-            if page_table is None:
-                raise ValueError("a paged forward needs page_table")
-            rows, offs = paged_scatter_index(page_table, pos,
-                                             cfg.kv_page_size)
-            pool_k.value = pool_k.value.at[rows, offs].set(
-                k.astype(cfg.dtype).reshape(b * t, kv, d))
-            pool_v.value = pool_v.value.at[rows, offs].set(
-                v.astype(cfg.dtype).reshape(b * t, kv, d))
-            index.value = index.value + t
-        out = paged_attention(q, pool_k.value, pool_v.value, page_table,
-                              pos, kernel=cfg.paged_kernel, dtype=cfg.dtype)
-        return _dense(cfg.d_model, "o_proj", cfg)(out.reshape(b, t, h * d))
+        return dense(cfg.d_model, "out_proj", cfg)(y)
 
 
 class LatentExperts(nn.Module):
@@ -390,42 +290,17 @@ class LatentExperts(nn.Module):
         b, t, dm = u.shape
         m = b * t
         f32 = jnp.float32
-        lo, hi = cfg.experts_held
         um = u.reshape(m, dm)
-        real = _row_mask(valid_len, b, t).reshape(m)
+        real = row_mask(valid_len, b, t).reshape(m)
 
-        # the router, float32 at the highest precision: a near-tie among
-        # its scores decides which expert a row reaches
-        wr = self.param("router", nn.initializers.normal(0.02),
-                        (dm, cfg.n_routed_experts), f32)
-        bias = self.param("router_bias", nn.initializers.normal(0.02),
-                          (cfg.n_routed_experts,), f32)
-        scores = jax.nn.sigmoid(jnp.dot(
-            um.astype(f32), wr, precision=jax.lax.Precision.HIGHEST))
-        _, chosen = jax.lax.top_k(scores + bias, cfg.top_k)      # [M, k]
-        picked = jnp.take_along_axis(scores, chosen, axis=-1)
-        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) \
-            * cfg.routed_scaling
-        # for whoever asks (``mutable=["intermediates"]``): a row's choices
-        self.sow("intermediates", "chosen", chosen)
-        held = (chosen >= lo) & (chosen < hi) & real[:, None]
-        # [M, k, held]: which held expert each of a row's choices is
-        onehot = held[:, :, None] & (
-            chosen[:, :, None] - lo == jnp.arange(cfg.n_held)[None, None, :])
-        # [M, held]: a row's weight for each expert held here
-        weights = jnp.sum(jnp.where(onehot, picked[:, :, None], 0.0), axis=1)
-        reached = jnp.any(onehot, axis=(0, 1))
-        self.sow("stats", "moe", jnp.stack([
-            jnp.sum(real) * cfg.top_k, jnp.sum(held), jnp.sum(reached),
-            jnp.asarray(cfg.n_held)]).astype(jnp.int32),
-            reduce_fn=lambda a, c: a + c,
-            init_fn=lambda: jnp.zeros((4,), jnp.int32))
-
-        v = _dense(cfg.latent, "latent_down", cfg)(um)
+        weights = held_weights(
+            self, um, real, n_routed=cfg.n_routed_experts, top_k=cfg.top_k,
+            held=cfg.experts_held, scaling=cfg.routed_scaling)
+        v = dense(cfg.latent, "latent_down", cfg)(um)
         w1 = self.param("experts_w1", nn.initializers.normal(0.02),
                         (cfg.n_held, cfg.latent, cfg.expert_width),
                         cfg.param_dtype)
-        w2 = self.param("experts_w2", _normal(),
+        w2 = self.param("experts_w2", normal(),
                         (cfg.n_held, cfg.expert_width, cfg.latent),
                         cfg.param_dtype)
         if self.is_initializing():
@@ -433,10 +308,10 @@ class LatentExperts(nn.Module):
         else:
             routed = gexp.grouped_experts(v, w1.astype(cfg.dtype),
                                           w2.astype(cfg.dtype), weights)
-        out = _dense(dm, "latent_up", cfg)(routed.astype(cfg.dtype))
-        hid = _dense(cfg.shared_width, "shared_w1", cfg)(um)
+        out = dense(dm, "latent_up", cfg)(routed.astype(cfg.dtype))
+        hid = dense(cfg.shared_width, "shared_w1", cfg)(um)
         hid = jnp.square(jax.nn.relu(hid.astype(f32))).astype(cfg.dtype)
-        out = out + _dense(dm, "shared_w2", cfg)(hid)
+        out = out + dense(dm, "shared_w2", cfg)(hid)
         return out.reshape(b, t, dm)
 
 

@@ -1,8 +1,9 @@
 """What a serving engine asks of a model family, so that
 ``serving/engine.py`` names no model (ROADMAP D1). One protocol, answered by
 the configuration object and by the module class it builds;
-``models/llama.py``'s ``LlamaConfig`` / ``Llama`` and
-``models/nemotron_h.py``'s ``NemotronHConfig`` / ``NemotronH`` both do.
+``models/llama.py``'s ``LlamaConfig`` / ``Llama``,
+``models/nemotron_h.py``'s ``NemotronHConfig`` / ``NemotronH`` and
+``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2`` all do.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads``, ``n_kv_heads`` and ``head_dim`` (the paged pool's and

@@ -2,10 +2,14 @@
 every held expert it chose, whatever the others chose (ROADMAP S4; the
 one-hot dispatch of ``models/moe.py`` drops rows over a capacity).
 
-The experts are ungated two-matrix MLPs with a squared ReLU,
-``E_e(v) = relu(v W1_e)^2 W2_e``, and the result is
-``sum_e w[m, e] * E_e(v[m])`` over the held experts, ``w`` being 0 where row
-``m`` did not choose ``e`` (or is a pad row, or an idle slot).
+An expert takes one of two forms, and one kernel computes both: ungated
+two-matrix MLPs with a squared ReLU, ``E_e(v) = relu(v W1_e)^2 W2_e``
+(Nemotron-3-Super's, in a latent space of 1024), or, where the caller gives
+a third matrix ``gate``, gated three-matrix MLPs,
+``E_e(v) = (silu(v Wg_e) * (v W1_e)) W2_e`` (Solar-Open2's, at the hidden
+width of 4096). The result is ``sum_e w[m, e] * E_e(v[m])`` over the held
+experts, ``w`` being 0 where row ``m`` did not choose ``e`` (or is a pad
+row, or an idle slot).
 
 :func:`grouped_experts` is written for the serving batch, where the rows are
 few (a decode round's slots, a prefill chunk's positions: 8 to 256) and the
@@ -43,17 +47,27 @@ from lzy_tpu.ops import interpret as _interpret
 PATH = "experts_pallas"
 
 
-def _tile(width: int) -> int:
+#: bytes of one weight tile: two or three of them, double-buffered, and the
+#: rows' input and float32 result stay inside the kernel's VMEM
+_TILE_BYTES = 2 << 20
+#: above this the kernel asks for its VMEM by name (the compiler's default
+#: scoped limit is 16 MiB of a v5e core's 128)
+_DEFAULT_VMEM = 14 << 20
+
+
+def _tile(width: int, latent: int, itemsize: int) -> int:
     """The widest tile of the intermediate width that is a multiple of 128,
-    divides it and keeps a pair of double-buffered weight tiles inside the
-    default VMEM budget (a tile of 1024 x 896 bfloat16 is 1.8 MB)."""
-    for lanes in range(min(width, 1024) // 128 * 128, 0, -128):
+    divides it and is at most ``_TILE_BYTES`` (1024 x 896 bfloat16, 1.8 MB;
+    at an input of 4096, 4096 x 256)."""
+    most = max(128, _TILE_BYTES // (latent * itemsize))
+    for lanes in range(min(width, most, 1024) // 128 * 128, 0, -128):
         if width % lanes == 0:
             return lanes
     return width
 
 
-def _kernel(ids_ref, n_ref, x_ref, w1_ref, w2_ref, wc_ref, o_ref):
+def _kernel(ids_ref, n_ref, x_ref, *refs, gated: bool):
+    *w_refs, w2_ref, wc_ref, o_ref = refs
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((i == 0) & (j == 0))
@@ -62,19 +76,30 @@ def _kernel(ids_ref, n_ref, x_ref, w1_ref, w2_ref, wc_ref, o_ref):
 
     @pl.when(i < n_ref[0])
     def _():
-        h = jnp.dot(x_ref[...], w1_ref[0],
+        h = jnp.dot(x_ref[...], w_refs[-1][0],
                     preferred_element_type=jnp.float32)      # [M, tile]
-        h = jnp.square(jnp.maximum(h, 0.0)) * wc_ref[0]      # [M, 1] weights
+        if gated:
+            h = h * jax.nn.silu(jnp.dot(
+                x_ref[...], w_refs[0][0],
+                preferred_element_type=jnp.float32))
+        else:
+            h = jnp.square(jnp.maximum(h, 0.0))
+        h = h * wc_ref[0]                                    # [M, 1] weights
         o_ref[...] += jnp.dot(h.astype(w2_ref.dtype), w2_ref[0],
                               preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _pallas_grouped(x, w1, w2, weights, *, interpret: bool):
+def _pallas_grouped(x, w1, w2, weights, gate=None, *, interpret: bool):
     m, latent = x.shape
     e, _, width = w1.shape
-    tile = _tile(width)
+    size = jnp.dtype(w1.dtype).itemsize
+    tile = _tile(width, latent, size)
     tiles = width // tile
+    ups = (w1,) if gate is None else (gate, w1)
+    # what the pipeline keeps in VMEM: every block twice
+    vmem = 2 * ((len(ups) + 1) * latent * tile * size
+                + m * latent * (size + 4) + m * 128 * 4)
     touched = jnp.any(weights != 0.0, axis=0)                # [E]
     n = jnp.sum(touched).astype(jnp.int32)
     # the touched experts first, in their own order
@@ -88,15 +113,16 @@ def _pallas_grouped(x, w1, w2, weights, *, interpret: bool):
         # real step held: nothing more is fetched
         return jnp.where(i < n[0], j, tiles - 1)
 
+    up_spec = pl.BlockSpec((1, latent, tile), lambda i, j, ids, n:
+                           (expert(i, ids, n), 0, tile_of(i, j, n)))
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, gated=gate is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(e, tiles),
             in_specs=[
                 pl.BlockSpec((m, latent), lambda i, j, ids, n: (0, 0)),
-                pl.BlockSpec((1, latent, tile), lambda i, j, ids, n:
-                             (expert(i, ids, n), 0, tile_of(i, j, n))),
+                *[up_spec] * len(ups),
                 pl.BlockSpec((1, tile, latent), lambda i, j, ids, n:
                              (expert(i, ids, n), tile_of(i, j, n), 0)),
                 pl.BlockSpec((1, m, 1), lambda i, j, ids, n:
@@ -106,48 +132,59 @@ def _pallas_grouped(x, w1, w2, weights, *, interpret: bool):
         ),
         out_shape=jax.ShapeDtypeStruct((m, latent), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=None if vmem <= _DEFAULT_VMEM
+            else vmem + (8 << 20)),
         interpret=interpret,
         name="grouped_experts",
-    )(ids, n.reshape(1), x, w1, w2,
+    )(ids, n.reshape(1), x, *ups, w2,
       weights.astype(jnp.float32).T[:, :, None])
 
 
 def grouped_experts(x: jax.Array, w1: jax.Array, w2: jax.Array,
-                    weights: jax.Array, *,
+                    weights: jax.Array, *, gate: Optional[jax.Array] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """``x`` [M, L] (the experts' input, in the weights' dtype), ``w1``
     [E, L, F], ``w2`` [E, F, L], ``weights`` [M, E] float32 (0 where the row
-    does not reach the expert). Returns ``[M, L]`` float32."""
-    return _pallas_grouped(x.astype(w1.dtype), w1, w2, weights,
+    does not reach the expert); ``gate`` [E, L, F] makes the experts gated
+    (``silu(x gate) * (x w1)`` where there is none ``relu(x w1)^2``).
+    Returns ``[M, L]`` float32."""
+    return _pallas_grouped(x.astype(w1.dtype), w1, w2, weights, gate,
                            interpret=_interpret.resolve(interpret))
 
 
-def lax_grouped_experts(x, w1, w2, weights):
+def lax_grouped_experts(x, w1, w2, weights, gate=None):
     """The same sum over every held expert, one after another."""
     x = x.astype(w1.dtype)
+    gated = gate is not None
 
     def one(acc, ew):
-        a, b, col = ew
+        a, b, col, *g = ew
         h = jnp.dot(x, a, preferred_element_type=jnp.float32)
-        h = jnp.square(jnp.maximum(h, 0.0)) * col[:, None]
+        if gated:
+            h = h * jax.nn.silu(jnp.dot(
+                x, g[0], preferred_element_type=jnp.float32))
+        else:
+            h = jnp.square(jnp.maximum(h, 0.0))
+        h = h * col[:, None]
         return acc + jnp.dot(h.astype(b.dtype), b,
                              preferred_element_type=jnp.float32), None
 
     out, _ = jax.lax.scan(
         one, jnp.zeros(x.shape, jnp.float32),
-        (w1, w2, weights.astype(jnp.float32).T))
+        (w1, w2, weights.astype(jnp.float32).T) + ((gate,) if gated else ()))
     return out
 
 
 def lower_for_tpu(*, rows: int, experts: int, latent: int, width: int,
-                  dtype) -> None:
+                  dtype, gated: bool = False) -> None:
     """Lower the kernel for a TPU at these shapes with no device, and let
     the lowering's error out."""
     sds = jax.ShapeDtypeStruct
+    up = sds((experts, latent, width), dtype)
     jax.jit(functools.partial(_pallas_grouped.__wrapped__, interpret=False)
             ).trace(
-        sds((rows, latent), dtype), sds((experts, latent, width), dtype),
+        sds((rows, latent), dtype), up,
         sds((experts, width, latent), dtype),
-        sds((rows, experts), jnp.float32),
+        sds((rows, experts), jnp.float32), up if gated else None,
     ).lower(lowering_platforms=("tpu",))

@@ -329,3 +329,44 @@ def test_ssm_state_update_compiles_at_published_widths(one_chip):
         sds((128,)), sds((64, 8, 128)), sds((64, 8, 128))).compile()
     state_bytes = 64 * 128 * 64 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < state_bytes // 8
+
+
+# -- the Solar-Open2 serving kernels at published widths ---------------------
+
+@pytest.mark.parametrize("rows", [32, 16, 256],
+                         ids=["decode", "chunk16", "chunk256"])
+def test_gated_experts_compile_at_published_widths(rows, one_chip):
+    """40 held experts of three 4096 x 1280 matrices in bfloat16: tiles of
+    4096 x 256 and, at 256 rows, a VMEM limit asked for by name (the rows'
+    input and float32 result are 6 MB beside 12 MB of weight tiles)."""
+    from lzy_tpu.ops import grouped_experts as gexp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((40, 4096, 1280), jnp.bfloat16)
+    compiled = jax.jit(lambda x, g, a, b, w: gexp.grouped_experts(
+        x, a, b, w, gate=g, interpret=False)).lower(
+        sds((rows, 4096), jnp.bfloat16), up, up,
+        sds((40, 1280, 4096), jnp.bfloat16),
+        sds((rows, 40), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kda_state_update_compiles_at_published_widths(one_chip):
+    """32 slots x 64 heads x 128 x 128 float32 of state, updated in place:
+    the donated state is the output's buffer (no second 134 MB copy)."""
+    from lzy_tpu.ops import kda
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    vec = sds((32, 64, 128))
+    compiled = jax.jit(
+        lambda s, q, k, v, a, b, live: kda.kda_state_update(
+            s, q, k, v, a, b, live, interpret=False),
+        donate_argnums=(0,)).lower(
+        sds((32, 64, 128, 128)), vec, vec, vec, vec, sds((32, 64)),
+        sds((32,), jnp.bool_)).compile()
+    state_bytes = 32 * 64 * 128 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes // 8
