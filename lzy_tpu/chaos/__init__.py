@@ -28,7 +28,7 @@ from lzy_tpu.chaos.faults import (
     CHAOS, CRASH, DELAY, ERROR, FaultPlan, FaultPoint, InjectedFault, SLOW)
 from lzy_tpu.chaos.invariants import (
     FenceAuditor, InvariantViolation, audit_engine, audit_fleet_leases,
-    audit_kv_tier, audit_pool, audit_radix, audit_recovery)
+    audit_kv_counts, audit_kv_tier, audit_pool, audit_radix, audit_recovery)
 
 __all__ = [
     "CHAOS",
@@ -43,6 +43,7 @@ __all__ = [
     "SLOW",
     "audit_engine",
     "audit_fleet_leases",
+    "audit_kv_counts",
     "audit_kv_tier",
     "audit_pool",
     "audit_radix",

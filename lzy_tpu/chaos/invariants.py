@@ -17,6 +17,11 @@ The invariants:
 - **radix-tree consistency** (:func:`audit_radix`): parent/child links
   mirror each other, chunk keys are page-size, the block->node map is
   exactly the set of tree nodes, tree blocks are never on the free list.
+- **kept KV counts** (:func:`audit_kv_counts`): the numbers the radix
+  cache keeps as it goes (cached blocks, evictable blocks, each node's
+  ``busy``, the eviction order's candidates) equal a from-scratch
+  reckoning over the whole tree — the walks that WERE the cache's
+  ``available()``, ``cached_count()`` and victim pick until PR 34.
 - **engine/slot consistency** (:func:`audit_engine`): an active slot's
   page table mirrors its block list, its position fits its allocated
   pages, and every held block is actually referenced.
@@ -123,6 +128,98 @@ def audit_radix(kv) -> None:
                 f"block {b}: map points at a detached node")
 
 
+# -- what the radix cache keeps, reckoned from scratch ------------------------
+#
+# These walks defined RadixCache.available(), cached_count() and the
+# victim pick until the cache began to keep the numbers (PR 34). They stay
+# as the definition: the audit below and tests/test_kv_cache.py hold the
+# kept numbers to them. Each costs a pass over the whole tree.
+
+def reckon_cached(kv) -> int:
+    """Tree blocks with refcount 0."""
+    return sum(1 for b in kv._node_of if kv.pool.refcount(b) == 0)
+
+
+def reckon_evictable(kv) -> int:
+    """Tree blocks in a fully unreferenced subtree: what ``available()``
+    adds to the free list."""
+
+    def count(node):
+        n_evictable, all_free = 0, True
+        for child in node.children.values():
+            c_n, c_free = count(child)
+            n_evictable += c_n
+            all_free = all_free and c_free
+        if node is kv._root:
+            return n_evictable, all_free
+        if all_free and kv.pool.refcount(node.block) == 0:
+            return n_evictable + 1, True
+        return n_evictable, False
+
+    return count(kv._root)[0]
+
+
+def reckon_victims(kv, n: int) -> list:
+    """The first ``n`` nodes an eviction round would take, in order: each
+    time the unreferenced leaf with the lowest ``last_access`` (the first
+    in tree order on a tie), a node counting as a leaf once its children
+    are taken. Nothing is detached."""
+    gone: set = set()
+    out: list = []
+
+    def leaves(node, found):
+        for child in node.children.values():
+            if any(id(c) not in gone for c in child.children.values()):
+                leaves(child, found)
+            elif id(child) not in gone \
+                    and kv.pool.refcount(child.block) == 0:
+                found.append(child)
+        return found
+
+    for _ in range(n):
+        found = leaves(kv._root, [])
+        if not found:
+            break
+        victim = min(found, key=lambda node: node.last_access)
+        gone.add(id(victim))
+        out.append(victim)
+    return out
+
+
+def audit_kv_counts(kv) -> None:
+    """The radix cache's kept numbers against the reckoning above."""
+    cached = reckon_cached(kv)
+    if kv._cached != cached:
+        raise InvariantViolation(
+            f"kept cached count {kv._cached} != {cached} unreferenced "
+            f"tree blocks")
+    evictable = reckon_evictable(kv)
+    if kv._evictable != evictable:
+        raise InvariantViolation(
+            f"kept evictable count {kv._evictable} != {evictable} tree "
+            f"blocks in unreferenced subtrees")
+    # the heap's pick is the LRU unreferenced leaf as long as each such
+    # leaf has an entry stamped at or below its last_access (an entry
+    # stamped lower is pushed back under the true value when it surfaces)
+    lowest: Dict[int, int] = {}
+    for stamp, _, node in kv._lru:
+        lowest[id(node)] = min(stamp, lowest.get(id(node), stamp))
+    for b, node in kv._node_of.items():
+        busy = (kv.pool.refcount(b) > 0) + sum(
+            1 for c in node.children.values() if c.busy)
+        if node.busy != busy:
+            raise InvariantViolation(
+                f"block {b}: kept busy count {node.busy} != {busy} "
+                f"(own reference + busy children)")
+        if busy or node.children:
+            continue
+        if lowest.get(id(node), node.last_access + 1) > node.last_access:
+            raise InvariantViolation(
+                f"block {b} is an unreferenced leaf but the eviction "
+                f"order has no entry at or below its last_access "
+                f"{node.last_access}")
+
+
 def audit_engine(engine) -> None:
     """Slot/table/pool consistency of one inference engine. Paged
     engines get the full block audit; dense engines the position
@@ -140,6 +237,7 @@ def audit_engine(engine) -> None:
         return
     audit_pool(kv)
     audit_radix(kv)
+    audit_kv_counts(kv)
     audit_kv_tier(kv, getattr(engine, "kv_tier", None))
     page = engine._page
     held: Dict[int, int] = {}

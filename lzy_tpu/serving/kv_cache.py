@@ -25,6 +25,13 @@ fix, in two pieces:
 LRU order uses a logical clock (a counter bumped per tree operation), not
 wall time, so eviction order is deterministic under test.
 
+What a call costs follows the blocks and the chain that call touches, never
+the size of the tree: the cache keeps its cached count, its evictable count
+and its eviction order as it goes (see :class:`RadixCache`). The
+from-scratch walks that define those numbers live in
+``lzy_tpu/chaos/invariants.py`` (``audit_kv_counts``), where the audit and
+the tests hold the kept numbers to them.
+
 Prefix hit rate, blocks in use/free, evictions, and prefill tokens saved
 are exported via ``lzy_tpu.utils.metrics.REGISTRY`` and surfaced through
 ``InferStats`` (see ``serving/engine.py``).
@@ -33,6 +40,7 @@ are exported via ``lzy_tpu.utils.metrics.REGISTRY`` and surfaced through
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from lzy_tpu.utils import trace
@@ -56,6 +64,13 @@ _LOOKUP_TOKENS = REGISTRY.counter(
 _HIT_RATE = REGISTRY.gauge(
     "lzy_kv_prefix_hit_rate",
     "cumulative hit tokens / lookup tokens")
+_TREE_VISITS = REGISTRY.counter(
+    "lzy_kv_tree_visits_total",
+    "radix-tree nodes the KV manager's calls touched (descents, parent-chain "
+    "updates, eviction picks)")
+_CALLS = REGISTRY.counter(
+    "lzy_kv_calls_total",
+    "KV manager calls: allocate, release, match, lookup, insert, available")
 
 
 class NoFreeBlocks(RuntimeError):
@@ -141,7 +156,7 @@ class _Node:
     """One radix-tree node: a full block whose edge key is its token chunk."""
 
     __slots__ = ("chunk", "block", "children", "parent", "last_access",
-                 "origin")
+                 "origin", "busy")
 
     def __init__(self, chunk: Optional[Tuple[int, ...]], block: Optional[int],
                  parent: Optional["_Node"]):
@@ -154,6 +169,10 @@ class _Node:
         # block's KV came from; None = computed locally. Read by
         # chain_origin so replies can say who REALLY produced the KV.
         self.origin: Optional[str] = None
+        # 1 if this node's block is referenced, plus the children whose
+        # own ``busy`` is not 0: 0 says the whole subtree is unreferenced,
+        # which is when the block counts in ``available()``
+        self.busy = 0
 
 
 class RadixCache:
@@ -170,6 +189,24 @@ class RadixCache:
     - :meth:`release` on EOS/cancel/preempt — drops the request's refs;
       unreferenced blocks *in* the tree stay cached (evictable),
       unreferenced blocks *outside* it return to the free list.
+
+    Three things are kept as the calls go, so that no call walks the tree:
+    ``_cached`` (tree blocks with refcount 0), ``_evictable`` (tree blocks
+    in a fully unreferenced subtree: nodes whose ``busy`` is 0) and
+    ``_lru``, a heap of the unreferenced leaves by ``last_access``. They
+    change where a tree block's refcount crosses 0 <-> 1 (``_mark_busy`` /
+    ``_mark_idle`` carry the crossing up the parent chain, and stop at the
+    first ancestor it does not flip), where a node is created and where a
+    victim leaves. Heap entries are never removed in place: one whose node
+    has left the tree, gained a child or a reference is dropped when it
+    reaches the top, one whose ``last_access`` has moved is pushed back
+    under the new value. Two candidates cannot carry the same
+    ``last_access`` (a tick of the clock stamps one root-to-node path, and
+    two leaves never share one); were they to, the one that entered the
+    heap first leaves first.
+
+    ``stats()`` and the gauges read those integers only, so another thread
+    may call ``stats()`` while the engine's thread changes the tree.
     """
 
     def __init__(self, n_blocks: int, page_size: int):
@@ -208,6 +245,15 @@ class RadixCache:
         self.on_evict = None
         self.on_evict_batch = None
         self.on_insert = None
+        self._cached = 0
+        self._evictable = 0
+        self._lru: List[Tuple[int, int, _Node]] = []
+        self._lru_seq = 0
+        # what the calls cost, cumulative: tree nodes touched, and calls.
+        # Plain integers; _update_gauges carries them to the registry
+        self.tree_visits = 0
+        self.calls = 0
+        self._flushed = (0, 0)
         self._update_gauges()
 
     # -- tree ----------------------------------------------------------------
@@ -242,14 +288,13 @@ class RadixCache:
         :meth:`release`). Pass ``prompt[:-1]`` to guarantee at least one
         suffix token remains for prefill (logits need a real forward
         position)."""
+        self.calls += 1
         self._clock += 1
         chain = self._walk(tokens)
-        blocks: List[int] = []
+        self.tree_visits += len(chain)
         for child in chain:
             child.last_access = self._clock
-            blocks.append(child.block)
-        for b in blocks:
-            self.pool.incref(b)
+        blocks = self._pin(chain)
         self.hit_tokens += len(blocks) * self.page_size
         self.lookup_tokens += len(tokens)
         _HIT_TOKENS.inc(len(blocks) * self.page_size)
@@ -265,10 +310,62 @@ class RadixCache:
         tree to ship them to a decode replica): an export must not
         distort the admission hit-rate stats or the eviction order the
         serving traffic established."""
-        blocks = [child.block for child in self._walk(tokens)]
-        for b in blocks:
-            self.pool.incref(b)
+        self.calls += 1
+        chain = self._walk(tokens)
+        self.tree_visits += len(chain)
+        blocks = self._pin(chain)
         return blocks, len(blocks) * self.page_size
+
+    def _pin(self, chain: List[_Node]) -> List[int]:
+        """One reference on each node's block, for the caller."""
+        blocks: List[int] = []
+        for node in chain:
+            blocks.append(node.block)
+            if self.pool.incref(node.block) == 1:
+                self._cached -= 1
+                self._mark_busy(node)
+        return blocks
+
+    def _mark_busy(self, node: _Node) -> None:
+        """``node``'s block took its first reference: its subtree, and
+        every ancestor's whose subtree was unreferenced until now, stops
+        being evictable."""
+        while node.parent is not None:
+            self.tree_visits += 1
+            node.busy += 1
+            if node.busy != 1:
+                return
+            self._evictable -= 1
+            node = node.parent
+
+    def _mark_idle(self, node: _Node) -> None:
+        """``node``'s block lost its last reference: the reverse of
+        :meth:`_mark_busy`."""
+        if not node.children:
+            self._push_candidate(node)
+        while node.parent is not None:
+            self.tree_visits += 1
+            node.busy -= 1
+            if node.busy:
+                return
+            self._evictable += 1
+            node = node.parent
+
+    def _push_candidate(self, node: _Node) -> None:
+        """``node`` became an unreferenced leaf: it joins the eviction
+        order. The heap is rebuilt from the tree once stale entries
+        outnumber the nodes two to one, which keeps it no larger than the
+        pool at a cost that the pushes since the last rebuild have paid."""
+        if len(self._lru) > 2 * len(self._node_of) + 64:
+            self.tree_visits += len(self._node_of)
+            self._lru = [
+                (n.last_access, i, n)
+                for i, n in enumerate(self._node_of.values())
+                if not n.children and self.pool.refcount(n.block) == 0]
+            heapq.heapify(self._lru)
+            self._lru_seq = len(self._lru)
+        self._lru_seq += 1
+        heapq.heappush(self._lru, (node.last_access, self._lru_seq, node))
 
     def match_len(self, tokens: Sequence[int]) -> int:
         """Read-only probe of :meth:`match` — no refs taken, no metrics,
@@ -286,6 +383,7 @@ class RadixCache:
         ``origin`` tags NEWLY created nodes with the remote producer of
         their KV (a disagg prefill replica id); existing nodes keep their
         provenance (whoever computed the resident bytes)."""
+        self.calls += 1
         if not self.reuse:
             return 0
         self._clock += 1
@@ -301,11 +399,19 @@ class RadixCache:
                 node.children[chunk] = child
                 self._node_of[block] = child
                 created += 1
+                # the inserting request holds the block, as a rule
+                self._evictable += 1
+                if self.pool.refcount(block) > 0:
+                    self._mark_busy(child)
+                else:
+                    self._cached += 1
+                    self._push_candidate(child)
                 if self.on_insert is not None:
                     try:
                         self.on_insert(tuple(chain))
                     except Exception:  # noqa: BLE001 — advisory hook
                         pass
+            self.tree_visits += 1
             child.last_access = self._clock
             node = child
         if created:
@@ -337,9 +443,11 @@ class RadixCache:
         instead of per block; per-block ``on_evict`` is the fallback),
         and only then do the blocks return to the free list — the hook
         must see the victims' K/V before anything can overwrite it."""
-        if n > self.available():
+        self.calls += 1
+        have = self.pool.free_count() + self._evictable
+        if n > have:
             raise NoFreeBlocks(
-                f"need {n} blocks, only {self.available()} available "
+                f"need {n} blocks, only {have} available "
                 f"(free + evictable)")
         victims: List[_Node] = []
         while self.pool.free_count() + len(victims) < n:
@@ -384,36 +492,54 @@ class RadixCache:
         returning its block to the free list (the caller batches the
         demotion hook first).  ``chain_tokens`` stays valid on the
         detached node — parents are intact, only the child link is cut."""
-        leaves = self._evictable_leaves()
-        if not leaves:
+        victim = self._next_victim()
+        if victim is None:
             return None
-        victim = min(leaves, key=lambda node: node.last_access)
-        del victim.parent.children[victim.chunk]
+        heapq.heappop(self._lru)
+        parent = victim.parent
+        del parent.children[victim.chunk]
         del self._node_of[victim.block]
         self.structure_version += 1
+        self._cached -= 1
+        self._evictable -= 1
+        if (parent is not self._root and not parent.children
+                and self.pool.refcount(parent.block) == 0):
+            self.tree_visits += 1
+            self._push_candidate(parent)
         return victim
+
+    def _next_victim(self) -> Optional["_Node"]:
+        """The unreferenced leaf with the lowest ``last_access``, left in
+        the tree and at the top of the heap; stale entries above it go."""
+        lru = self._lru
+        while lru:
+            self.tree_visits += 1
+            stamp, _, node = lru[0]
+            if (self._node_of.get(node.block) is not node or node.children
+                    or self.pool.refcount(node.block) != 0):
+                heapq.heappop(lru)
+            elif stamp != node.last_access:
+                self._lru_seq += 1
+                heapq.heapreplace(
+                    lru, (node.last_access, self._lru_seq, node))
+            else:
+                return node
+        return None
 
     def release(self, blocks: Sequence[int]) -> None:
         """Drop one reference per block. Unreferenced blocks in the tree
         stay cached (evictable); unreferenced blocks outside it return to
         the free list immediately."""
+        self.calls += 1
         for b in blocks:
-            if self.pool.decref(b) == 0 and b not in self._node_of:
-                self.pool.release_to_free(b)
+            if self.pool.decref(b) == 0:
+                node = self._node_of.get(b)
+                if node is None:
+                    self.pool.release_to_free(b)
+                else:
+                    self._cached += 1
+                    self._mark_idle(node)
         self._update_gauges()
-
-    def _evictable_leaves(self) -> List[_Node]:
-        out: List[_Node] = []
-
-        def walk(node: _Node) -> None:
-            for child in node.children.values():
-                if child.children:
-                    walk(child)
-                elif self.pool.refcount(child.block) == 0:
-                    out.append(child)
-
-        walk(self._root)
-        return out
 
     def chain_tokens(self, node: "_Node") -> List[int]:
         """The full root→``node`` token chain (the tier identity of the
@@ -430,39 +556,36 @@ class RadixCache:
     def available(self) -> int:
         """Blocks an :meth:`allocate` could obtain right now: the free
         list plus every tree block in a fully-unreferenced subtree (those
-        evict leaf-by-leaf until the whole subtree is gone)."""
-
-        def count(node: _Node) -> Tuple[int, bool]:
-            n_evictable, all_free = 0, True
-            for child in node.children.values():
-                c_n, c_free = count(child)
-                n_evictable += c_n
-                all_free = all_free and c_free
-            if node is self._root:
-                return n_evictable, all_free
-            if all_free and self.pool.refcount(node.block) == 0:
-                return n_evictable + 1, True
-            return n_evictable, False
-
-        return self.pool.free_count() + count(self._root)[0]
+        evict leaf-by-leaf until the whole subtree is gone). A referenced
+        node can sit under an unreferenced ancestor (``insert`` keeps an
+        existing node's block), so the count is of subtrees, not of
+        blocks."""
+        self.calls += 1
+        return self.pool.free_count() + self._evictable
 
     def cached_count(self) -> int:
         """Tree blocks currently unreferenced (reusable, evictable)."""
-        return sum(1 for b in self._node_of if self.pool.refcount(b) == 0)
+        return self._cached
 
     # -- observability -------------------------------------------------------
 
     def _update_gauges(self) -> None:
         _BLOCKS.set(float(self.pool.n_blocks))
         _FREE.set(float(self.pool.free_count()))
-        _CACHED.set(float(self.cached_count()))
-        _HIT_RATE.set(self.stats().hit_rate)
+        _CACHED.set(float(self._cached))
+        _HIT_RATE.set(self.hit_tokens / self.lookup_tokens
+                      if self.lookup_tokens else 0.0)
+        visits, calls = self._flushed
+        self._flushed = (self.tree_visits, self.calls)
+        if self.tree_visits != visits:     # the decode round's allocate(1)
+            _TREE_VISITS.inc(self.tree_visits - visits)    # touches no node
+        _CALLS.inc(self.calls - calls)
 
     def stats(self) -> KVCacheStats:
         return KVCacheStats(
             blocks_total=self.pool.n_blocks - 1,    # scratch excluded
             blocks_free=self.pool.free_count(),
-            blocks_cached=self.cached_count(),
+            blocks_cached=self._cached,
             evictions=self.evictions,
             prefix_hit_tokens=self.hit_tokens,
             prefix_lookup_tokens=self.lookup_tokens,

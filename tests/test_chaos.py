@@ -26,7 +26,7 @@ import pytest
 
 from lzy_tpu.chaos import (
     CHAOS, FaultPlan, FenceAuditor, InvariantViolation, audit_engine,
-    audit_fleet_leases, audit_pool, audit_radix)
+    audit_fleet_leases, audit_kv_counts, audit_pool, audit_radix)
 from lzy_tpu.chaos.faults import CRASH, DELAY, ERROR, FaultPoint, SLOW
 from lzy_tpu.gateway import (
     Autoscaler, DisaggGatewayService, GatewayService, HealthPolicy,
@@ -435,6 +435,24 @@ class TestInvariants:
         node.parent = rc._root           # detach from its true parent
         with pytest.raises(InvariantViolation, match="parent link"):
             audit_radix(rc)
+
+    @pytest.mark.parametrize("drift, named", [
+        (lambda rc, node: setattr(rc, "_cached", rc._cached + 1),
+         "kept cached count 3 != 2"),
+        (lambda rc, node: setattr(rc, "_evictable", rc._evictable - 1),
+         "kept evictable count 1 != 2"),
+        (lambda rc, node: setattr(node, "busy", 1), "kept busy count"),
+        (lambda rc, node: rc._lru.clear(), "eviction order has no entry"),
+    ], ids=["cached", "evictable", "busy", "lru"])
+    def test_auditor_catches_a_drifted_kept_count(self, drift, named):
+        rc = RadixCache(8, PAGE)
+        blocks = rc.allocate(2)
+        rc.insert(list(range(2 * PAGE)), blocks)
+        rc.release(blocks)
+        audit_kv_counts(rc)
+        drift(rc, rc._node_of[blocks[1]])
+        with pytest.raises(InvariantViolation, match=named):
+            audit_kv_counts(rc)
 
     def test_fence_auditor_rejects_a_shrunk_fence(self):
         session = FenceAuditor().session([1, 2, 3])

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lzy_tpu.chaos import invariants
 from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
@@ -171,6 +172,268 @@ class TestKvMetricsExported:
                      "lzy_kv_prefix_hit_tokens_total",
                      "lzy_kv_prefix_hit_rate"):
             assert name in text
+
+
+    def test_cost_counters_reach_the_registry(self):
+        from benchmark.harness.serve import exposition_samples
+        from lzy_tpu.utils.metrics import REGISTRY
+
+        def read():
+            c = exposition_samples(REGISTRY.exposition())
+            return (c.get("lzy_kv_tree_visits_total", 0.0),
+                    c.get("lzy_kv_calls_total", 0.0))
+
+        kv = RadixCache(8, PAGE)
+        visits0, calls0 = read()
+        blocks = kv.allocate(2)
+        kv.insert(list(range(16)), blocks)
+        kv.available()                  # flushed by the next call's gauges
+        kv.release(blocks)
+        visits, calls = read()
+        assert calls - calls0 == kv.calls == 4
+        assert visits - visits0 == kv.tree_visits > 0
+
+
+def _check_against_reckoning(kv):
+    """The kept numbers, and the next victim, against the from-scratch
+    walks that defined them until PR 34."""
+    free, cached = kv.pool.free_count(), invariants.reckon_cached(kv)
+    assert kv.cached_count() == cached
+    assert kv.available() == free + invariants.reckon_evictable(kv)
+    st = kv.stats()
+    assert (st.blocks_total, st.blocks_free, st.blocks_cached,
+            st.evictions, st.prefix_hit_tokens, st.prefix_lookup_tokens) \
+        == (kv.pool.n_blocks - 1, free, cached, kv.evictions,
+            kv.hit_tokens, kv.lookup_tokens)
+    want = invariants.reckon_victims(kv, 1)
+    assert kv._next_victim() is (want[0] if want else None)
+    invariants.audit_pool(kv)
+    invariants.audit_radix(kv)
+    invariants.audit_kv_counts(kv)
+
+
+class _Walker:
+    """A seeded random walk over a small pool: requests that share
+    prefixes and branch (a three-token vocabulary), that insert under
+    nodes they did not match (a duplicate insert: referenced nodes under
+    unreferenced ancestors), that grow, finish and are exported, with the
+    free list empty most of the time. Every call is held to the
+    reckoning, and every eviction round to the victims it predicts."""
+
+    def __init__(self, kv, page, seed, hook):
+        self.kv, self.page = kv, page
+        self.rng = np.random.default_rng(seed)
+        self.live = []                  # (tokens, blocks) of open requests
+        self.prompts = []
+        self.batches, self.inserted = [], []
+        if hook == "batch":
+            kv.on_evict_batch = lambda b: self.batches.append(list(b))
+        else:
+            kv.on_evict = lambda *v: self.batches.append([v])
+        kv.on_insert = self.inserted.append
+        self.evicted = 0
+
+    def _prompt(self):
+        n = int(self.rng.integers(1, 6 * self.page))
+        fresh = self.rng.integers(0, 3, size=n).tolist()
+        if self.prompts and self.rng.random() < 0.7:
+            base = self.prompts[int(self.rng.integers(len(self.prompts)))]
+            cut = int(self.rng.integers(0, len(base) + 1))
+            fresh = (base[:cut] + fresh)[:8 * self.page]
+        self.prompts = (self.prompts + [fresh])[-24:]
+        return fresh
+
+    def _allocate(self, n):
+        kv = self.kv
+        free = kv.pool.free_count()
+        if n > free + invariants.reckon_evictable(kv):
+            with pytest.raises(NoFreeBlocks):
+                kv.allocate(n)
+            assert kv.pool.free_count() == free    # nothing was taken
+            return None
+        want = [(kv.chain_tokens(v), v.block, v.origin)
+                for v in invariants.reckon_victims(kv, max(0, n - free))]
+        self.batches.clear()
+        out = kv.allocate(n)
+        got = [v for batch in self.batches for v in batch]
+        assert got == want
+        if want and kv.on_evict_batch is not None:
+            assert len(self.batches) == 1          # one round, one call
+        self.evicted += len(want)
+        assert kv.evictions == self.evicted
+        assert len(out) == n == len(set(out))
+        return out
+
+    def _insert(self, tokens, blocks):
+        kv, page = self.kv, self.page
+        known = kv.match_len(tokens) // page
+        upto = min(len(tokens) // page, len(blocks)) if kv.reuse else 0
+        self.inserted.clear()
+        created = kv.insert(tokens, blocks)
+        assert created == max(0, upto - known)
+        assert self.inserted == [tuple(tokens[:k * page])
+                                 for k in range(known + 1, upto + 1)]
+
+    def step(self):
+        kv, rng = self.kv, self.rng
+        op = rng.choice(["admit", "admit", "blind", "grow", "finish",
+                         "finish", "export", "probe"])
+        if op in ("admit", "blind"):
+            tokens = self._prompt()
+            held = []
+            if op == "admit":       # "blind" skips the match: its insert
+                held, n = kv.match(tokens[:-1])    # meets nodes it holds
+                assert n == len(held) * self.page  # no reference on
+                _check_against_reckoning(kv)
+            need = -(-len(tokens) // self.page) - len(held)
+            fresh = self._allocate(need)
+            if fresh is None:
+                kv.release(held)
+            else:
+                _check_against_reckoning(kv)
+                self._insert(tokens, held + fresh)
+                self.live.append((tokens, held + fresh))
+        elif op == "grow" and self.live:
+            fresh = self._allocate(int(rng.integers(1, 3)))
+            if fresh is not None:
+                self.live[int(rng.integers(len(self.live)))][1].extend(fresh)
+        elif op == "finish" and self.live:
+            _, blocks = self.live.pop(int(rng.integers(len(self.live))))
+            kv.release(blocks)
+        elif op == "export" and self.prompts:
+            tokens = self.prompts[int(rng.integers(len(self.prompts)))]
+            stamp = kv._clock
+            held, _ = kv.lookup(tokens)
+            assert kv._clock == stamp              # no LRU bump
+            _check_against_reckoning(kv)
+            kv.release(held)
+        elif self.prompts:
+            kv.match_len(self.prompts[-1])
+            kv.chain_origin(self.prompts[-1])
+        _check_against_reckoning(kv)
+
+
+class TestKeptCounts:
+    """PR 34: the cache keeps what it used to recompute by walking the
+    tree. The walks live on in ``chaos/invariants.py`` as the oracle."""
+
+    @pytest.mark.parametrize("hook", ["batch", "single"])
+    @pytest.mark.parametrize("page", [1, 4, 8])
+    @pytest.mark.parametrize("reuse", [True, False])
+    def test_random_walk_holds_to_the_reckoning(self, reuse, page, hook):
+        kv = RadixCache(40, page)
+        kv.reuse = reuse
+        walker = _Walker(kv, page, seed=1000 * page + reuse, hook=hook)
+        for _ in range(2500):
+            walker.step()
+        if reuse:
+            assert walker.evicted > 100, "the walk never ran the pool dry"
+        for _, blocks in walker.live:
+            kv.release(blocks)
+        _check_against_reckoning(kv)
+        assert kv.available() == kv.pool.n_blocks - 1
+
+    @staticmethod
+    def _beside(n_other):
+        """A cache that holds ``n_other`` blocks of other prompts (chains
+        of 10, released), and one 4-block chain inserted and held."""
+        kv = RadixCache(n_other + 64, PAGE)
+        for i in range(n_other // 10):
+            blocks = kv.allocate(10)
+            kv.insert([1000 + i] + list(range(10 * PAGE - 1)), blocks)
+            kv.release(blocks)
+        assert kv.cached_count() == n_other
+        chain = list(range(7, 7 + 4 * PAGE))
+        blocks = kv.allocate(4)
+        return kv, chain, blocks
+
+    def test_allocate_from_the_free_list_visits_no_node(self):
+        kv, _, _ = self._beside(5000)
+        before = kv.tree_visits
+        kv.allocate(1)
+        kv.available()
+        assert kv.tree_visits == before
+
+    @pytest.mark.parametrize("op", ["insert", "release", "match", "lookup"])
+    def test_a_chain_costs_the_same_beside_any_tree(self, op):
+        def visits(n_other):
+            kv, chain, blocks = self._beside(n_other)
+            if op != "insert":
+                kv.insert(chain, blocks)
+            if op in ("match", "lookup"):
+                kv.release(blocks)
+            before = kv.tree_visits
+            if op == "insert":
+                assert kv.insert(chain, blocks) == 4
+            elif op == "release":
+                kv.release(blocks)
+            else:
+                assert getattr(kv, op)(chain)[1] == 4 * PAGE
+            return kv.tree_visits - before
+
+        assert 4 <= visits(100) == visits(5000) <= 12
+
+    def test_eviction_cost_does_not_follow_the_tree(self):
+        def visits(n_other):
+            kv, _, _ = self._beside(n_other)
+            kv.allocate(kv.pool.free_count())      # the free list is empty
+            before = kv.tree_visits
+            kv.allocate(8)                         # eight victims
+            return kv.tree_visits - before
+
+        assert visits(100) == visits(5000) <= 24
+
+    def test_the_eviction_heap_stays_bounded(self):
+        """A hot leaf that is matched and released for ever leaves one
+        stale entry a round: the heap is rebuilt from the tree before it
+        outgrows the pool, and still gives the victims up in order."""
+        kv, chain, blocks = self._beside(20)
+        kv.insert(chain, blocks)
+        kv.release(blocks)
+        for _ in range(500):
+            held, _ = kv.match(chain)
+            kv.release(held)
+            assert len(kv._lru) <= 2 * len(kv._node_of) + 65
+        _check_against_reckoning(kv)
+        kv.allocate(kv.pool.free_count())
+        order = [v.block for v in invariants.reckon_victims(kv, 24)]
+        seen = []
+        kv.on_evict = lambda tokens, block, origin: seen.append(block)
+        for _ in range(24):
+            kv.allocate(1)
+        assert seen == order and order[-4:] == blocks[::-1]
+
+    def test_stats_may_be_read_from_another_thread(self):
+        import sys
+        import threading
+
+        kv = RadixCache(64, PAGE)
+        errors, halt = [], threading.Event()
+
+        def reader():
+            try:
+                while not halt.is_set():
+                    st = kv.stats()
+                    assert 0 <= st.blocks_cached <= st.blocks_total
+            except BaseException as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        thread = threading.Thread(target=reader, daemon=True)
+        thread.start()
+        try:
+            for i in range(3000):                  # inserts, then evicts
+                blocks = kv.allocate(3)
+                kv.insert([i] + list(range(3 * PAGE - 1)), blocks)
+                kv.release(blocks)
+        finally:
+            halt.set()
+            thread.join(timeout=30)
+            sys.setswitchinterval(interval)
+        assert not thread.is_alive()
+        assert not errors, errors
+        assert kv.evictions > 2000
 
 
 class TestPagedEngineParity:
