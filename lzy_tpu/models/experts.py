@@ -1,7 +1,8 @@
 """What the served models with sparse experts share: the counters their
-expert layers feed, and the router that tells a layer which of the experts
+expert layers feed, the router that tells a layer which of the experts
 it holds each row reaches (``models/nemotron_h.py``,
-``models/solar_open2.py``).
+``models/solar_open2.py``, ``models/deepseek_v3.py``), and the gated expert
+layer two of them are built from (:class:`GatedExperts`).
 
 **The router** is a sigmoid over all the routed experts, float32 at the
 highest precision (a near-tie among its scores decides which expert a row
@@ -20,12 +21,14 @@ of a decode round with its tokens (``models/serving.py``).
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from lzy_tpu.models.paged_blocks import dense, normal
+from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.utils.metrics import REGISTRY
 
 MOE_ASSIGNMENTS = REGISTRY.counter(
@@ -54,12 +57,14 @@ def row_mask(valid_len, b: int, t: int):
 
 
 def held_weights(layer: nn.Module, um, real, *, n_routed: int, top_k: int,
-                 held: Tuple[int, int], scaling: float):
+                 held: Tuple[int, int], scaling: float, other_stats: int = 0):
     """``[M, held]`` float32: each row's weight for each expert held here
     (0 where it did not choose it, or is not real). Called from the expert
     layer's compact method: the parameters ``router`` ``[D, n_routed]`` and
     ``router_bias`` are the layer's own, the row's choices are sown as
-    ``intermediates/chosen`` and the layer's counts as ``stats/moe``."""
+    ``intermediates/chosen`` and the layer's counts as ``stats/moe``
+    (followed by ``other_stats`` zeros: the places of the counts the model's
+    other layers sow, every ``stats`` leaf being one vector of ``STATS``)."""
     f32 = jnp.float32
     lo, hi = held
     n_held = hi - lo
@@ -83,7 +88,49 @@ def held_weights(layer: nn.Module, um, real, *, n_routed: int, top_k: int,
     reached = jnp.any(onehot, axis=(0, 1))
     layer.sow("stats", "moe", jnp.stack([
         jnp.sum(real) * top_k, jnp.sum(on_held), jnp.sum(reached),
-        jnp.asarray(n_held)]).astype(jnp.int32),
+        jnp.asarray(n_held)] + [jnp.asarray(0)] * other_stats
+        ).astype(jnp.int32),
         reduce_fn=lambda a, c: a + c,
-        init_fn=lambda: jnp.zeros((4,), jnp.int32))
+        init_fn=lambda: jnp.zeros((4 + other_stats,), jnp.int32))
     return weights
+
+
+class GatedExperts(nn.Module):
+    """Sigmoid router over all the routed experts, the held experts'
+    product at hidden width (gated three-matrix experts), a shared expert of
+    the same form: Solar-Open2's expert layer and the DeepSeek-V3 family's.
+    ``cfg`` gives ``n_routed_experts``, ``experts_held``, ``n_held``,
+    ``top_k``, ``routed_scaling``, ``expert_width``, ``shared_width``,
+    ``dtype`` and ``param_dtype``; ``other_stats`` as :func:`held_weights`."""
+    cfg: Any
+    other_stats: int = 0
+
+    @nn.compact
+    def __call__(self, u, valid_len=None):
+        cfg = self.cfg
+        b, t, dm = u.shape
+        m = b * t
+        f32 = jnp.float32
+        um = u.reshape(m, dm)
+        real = row_mask(valid_len, b, t).reshape(m)
+        weights = held_weights(
+            self, um, real, n_routed=cfg.n_routed_experts, top_k=cfg.top_k,
+            held=cfg.experts_held, scaling=cfg.routed_scaling,
+            other_stats=self.other_stats)
+        up_shape = (cfg.n_held, dm, cfg.expert_width)
+        wg = self.param("experts_gate", normal(), up_shape, cfg.param_dtype)
+        wu = self.param("experts_up", normal(), up_shape, cfg.param_dtype)
+        wd = self.param("experts_down", normal(),
+                        (cfg.n_held, cfg.expert_width, dm), cfg.param_dtype)
+        if self.is_initializing():
+            routed = jnp.zeros((m, dm), f32)            # no kernel at init
+        else:
+            routed = gexp.grouped_experts(
+                um, wu.astype(cfg.dtype), wd.astype(cfg.dtype), weights,
+                gate=wg.astype(cfg.dtype))
+        hid = jax.nn.silu(dense(cfg.shared_width, "shared_gate", cfg,
+                                 f32)(um)) \
+            * dense(cfg.shared_width, "shared_up", cfg, f32)(um)
+        out = routed + dense(dm, "shared_down", cfg, f32)(
+            hid.astype(cfg.dtype))
+        return out.astype(cfg.dtype).reshape(b, t, dm)

@@ -31,10 +31,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from lzy_tpu.models.common import cross_entropy_loss
+from lzy_tpu.models.serving import HeadPool
 
 
 @dataclasses.dataclass(frozen=True)
-class LlamaConfig:
+class LlamaConfig(HeadPool):
     vocab_size: int = 128_256
     d_model: int = 4096
     n_layers: int = 32
@@ -150,8 +151,15 @@ class LlamaConfig:
         read's own: this family has no other kernel."""
         return ()
 
-    def check_kernels(self, *, slots: int) -> None:
-        """Nothing to lower beside the attention read."""
+    def check_kernels(self, *, slots: int, kv_blocks: Optional[int] = None,
+                      page_size: Optional[int] = None,
+                      pages_per_seq: Optional[int] = None,
+                      kv_quant: Optional[str] = None) -> None:
+        """The attention read over a pool of these shapes (where the caller
+        names one); nothing else to lower."""
+        self.lower_read(
+            slots=slots, kv_blocks=kv_blocks, page_size=page_size,
+            pages_per_seq=pages_per_seq, kv_quant=kv_quant)
 
     @staticmethod
     def llama3_8b() -> "LlamaConfig":

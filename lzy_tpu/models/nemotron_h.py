@@ -48,12 +48,13 @@ from lzy_tpu.models.experts import STATS, held_weights, row_mask
 from lzy_tpu.models.llama import RMSNorm
 from lzy_tpu.models.paged_blocks import (
     PagedAttention, dense, inv_softplus, normal)
+from lzy_tpu.models.serving import HeadPool
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import mamba2
 
 
 @dataclasses.dataclass(frozen=True)
-class NemotronHConfig:
+class NemotronHConfig(HeadPool):
     vocab_size: int = 131072
     d_model: int = 4096
     #: one character a block: M (Mamba-2), E (experts), * (attention)
@@ -166,10 +167,16 @@ class NemotronHConfig:
             paths.append(gexp.PATH)
         return tuple(paths)
 
-    def check_kernels(self, *, slots: int) -> None:
+    def check_kernels(self, *, slots: int, kv_blocks: Optional[int] = None,
+                      page_size: Optional[int] = None,
+                      pages_per_seq: Optional[int] = None,
+                      kv_quant: Optional[str] = None) -> None:
         """Lower this model's own kernels for a TPU at the decode step's
         shapes (no device, no compile): refused here, not at the first
-        request."""
+        request. With a pool named, the attention read over it too."""
+        self.lower_read(
+            slots=slots, kv_blocks=kv_blocks, page_size=page_size,
+            pages_per_seq=pages_per_seq, kv_quant=kv_quant)
         if "M" in self.pattern:
             mamba2.lower_update_for_tpu(
                 batch=slots, heads=self.mamba_heads,

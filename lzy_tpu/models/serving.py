@@ -2,43 +2,66 @@
 ``serving/engine.py`` names no model (ROADMAP D1). One protocol, answered by
 the configuration object and by the module class it builds;
 ``models/llama.py``'s ``LlamaConfig`` / ``Llama``,
-``models/nemotron_h.py``'s ``NemotronHConfig`` / ``NemotronH`` and
-``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2`` all do.
+``models/nemotron_h.py``'s ``NemotronHConfig`` / ``NemotronH``,
+``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2`` and
+``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3`` all do.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
-``dtype``, ``n_heads``, ``n_kv_heads`` and ``head_dim`` (the paged pool's and
-the attention kernel's shapes), and:
+``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
+``n_kv_heads`` and ``head_dim`` (the paged pool's and the attention kernel's
+shapes there: :class:`HeadPool` at the foot of this file reads them, the
+engine reads neither), and:
 
 - ``serving_config()``: itself with every training-only feature cleared;
 - ``paged_model(page_size=, kv_pages=, kernel=, kv_quant=)``: the module
   the engine runs, for decode rounds and batch-1 prefill alike, reading its
-  pool through the page table (``ops/paged_attention.py``);
-- ``kv_layers``: layers that keep keys and values in the paged pool, what a
-  byte budget for the pool is divided by;
+  pool through the page table (``ops/paged_attention.py``, ``ops/mla.py``);
+  a ``kv_quant`` its pool cannot take is refused here, by name;
+- ``kv_layers``: layers that keep a leaf in the paged pool;
+- ``kv_token_bytes(kv_quant)``: the bytes one cached token costs one such
+  layer. The engine divides a byte budget for the pool by
+  ``page_size x kv_layers x kv_token_bytes`` and asks nothing about what a
+  page holds: keys and values a head answer ``2 x KV x D`` elements (what
+  ``kv_cache.kv_block_bytes`` counts a position), a latent cache with no
+  head axis answers its one vector (``models/deepseek_v3.py``: 576 values
+  in 640 lanes, 1280 bytes);
+- ``read_path(kernel, t=, kv_quant=)``: the
+  ``lzy_kernel_dispatch_total{path}`` label of the read of the pool by a
+  program over ``t`` positions a row (``pallas`` / ``lax`` for keys and
+  values a head; a latent pool's two reads have labels of their own);
+  ``read_path(kernel, t=1)`` is the engine's ``kernel_path``;
 - ``widest_prefill``: the widest prefill program (query positions of one
   batch-1 chunk) the model's kernels take; the engine's chunk width is the
   widest bucket under it and under the round's ``prefill_budget``. A model
   of dense matmuls answers the widest bucket; one whose kernel's
   arithmetic grows faster than its rows answers where that stops paying;
 - ``kernel_paths(t)``: ``lzy_kernel_dispatch_total{path}`` labels of a
-  program over ``t`` positions a row, beside the attention read's own;
-- ``check_kernels(slots=)``: lower the model's own kernels for a TPU at the
-  decode step's shapes, so that what the lowering refuses is refused at
-  construction.
+  program over ``t`` positions a row, beside the read's own;
+- ``check_kernels(slots=, kv_blocks=, page_size=, pages_per_seq=,
+  kv_quant=)``: lower the model's kernels for a TPU at the decode step's
+  shapes, its read of a pool of these shapes among them (its own lowering:
+  the engine names no kernel), so that what the lowering refuses is refused
+  at construction; with no pool named, the kernels beside the read only.
 
 **The module class** declares ``CACHE_KINDS`` (cache leaf name -> kind; a
 leaf it does not name is ``paged``) and ``STATS``: the counters its
 ``stats`` collection feeds, in the order of the vector its layers sow
 (summed over layers by the engine and carried out of a decode round with
-its tokens); empty for a model that sows none.
+its tokens; every ``stats`` leaf is one such vector, a layer filling its own
+places); empty for a model that sows none. A model with state leaves or with
+counts is told which positions are real (``valid_len``).
 
 **Cache leaves have kinds**:
 
 - ``index``: tokens resident a row. The engine keeps one position vector and
   places it at every index leaf.
-- ``paged``: a pool of pages shared by all slots, addressed through a page
-  table. A batch-1 prefill writes the same pool; a prefix can be shared,
-  exported, demoted, and a speculated position rewound by moving an index.
+- ``paged``: a pool of pages shared by all slots, ``[pages, page, ...]``,
+  addressed through a page table; what follows the page axis is the
+  model's (``[KV, D]`` keys or values, a latent vector). A batch-1 prefill
+  writes the same pool; a prefix can be shared, exported, demoted, and a
+  speculated position rewound by moving an index: every mechanism moves
+  pages by block id and reads no shape past the page axis, so a latent
+  leaf is served by all of them (``docs/serving.md`` has the table).
 - ``state``: ``[slots, ...]``, one row a slot (a recurrence's state, a
   convolution's window). A prefill job carries its own batch-1 row between
   chunks and the engine splices it into the slot's row when the prompt is
@@ -50,7 +73,7 @@ its tokens); empty for a model that sows none.
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 INDEX, PAGED, STATE = "index", "paged", "state"
 
@@ -58,3 +81,44 @@ INDEX, PAGED, STATE = "index", "paged", "state"
 def leaf_kind(model: Any, path) -> str:
     """The kind of ``model``'s cache leaf at ``path``, by its own name."""
     return type(model).CACHE_KINDS.get(getattr(path[-1], "key", None), PAGED)
+
+
+class HeadPool:
+    """The answers of a configuration whose pages hold keys and values a
+    head (``models/llama.py``, ``models/nemotron_h.py``,
+    ``models/solar_open2.py``: pools ``[pages, page, KV, D]``, twice, read by
+    ``ops/paged_attention.py``), from its ``n_heads``, ``n_kv_heads``,
+    ``head_dim`` and ``dtype``."""
+
+    def kv_token_bytes(self, kv_quant: Optional[str] = None) -> int:
+        """Bytes one cached token costs one pool layer: keys and values a
+        head, what ``kv_cache.kv_block_bytes`` counts a position a layer."""
+        from lzy_tpu.serving.kv_cache import kv_block_bytes
+
+        return kv_block_bytes(page_size=1, n_kv_heads=self.n_kv_heads,
+                              head_dim=self.head_dim, dtype=self.dtype,
+                              kv_quant=kv_quant)
+
+    def read_path(self, kernel: str, *, t: int,
+                  kv_quant: Optional[str] = None) -> str:
+        """``lzy_kernel_dispatch_total{path}`` label of the attention read
+        of a program over ``t`` positions a row."""
+        from lzy_tpu.ops.paged_attention import kernel_path
+
+        return kernel_path(kernel, t=t, quantized=kv_quant is not None)
+
+    def lower_read(self, *, slots: int, kv_blocks: Optional[int],
+                   page_size: Optional[int], pages_per_seq: Optional[int],
+                   kv_quant: Optional[str]) -> None:
+        """Lower the decode read of a key/value pool of these shapes for a
+        TPU, where the decode step takes the kernel; nothing where the
+        caller names no pool."""
+        from lzy_tpu.ops.paged_attention import lower_pallas_for_tpu
+
+        if kv_blocks is None or self.read_path(
+                "pallas", t=1, kv_quant=kv_quant) != "pallas":
+            return
+        lower_pallas_for_tpu(
+            batch=slots, n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+            head_dim=self.head_dim, n_blocks=kv_blocks, page_size=page_size,
+            pages_per_seq=pages_per_seq, dtype=self.dtype)

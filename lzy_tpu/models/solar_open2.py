@@ -49,16 +49,17 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from lzy_tpu.models.experts import STATS, held_weights, row_mask
+from lzy_tpu.models.experts import STATS, GatedExperts, row_mask
 from lzy_tpu.models.llama import RMSNorm
 from lzy_tpu.models.paged_blocks import (
-    PagedAttention, dense, inv_softplus, normal)
+    PagedAttention, dense, inv_softplus)
+from lzy_tpu.models.serving import HeadPool
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import kda
 
 
 @dataclasses.dataclass(frozen=True)
-class SolarOpen2Config:
+class SolarOpen2Config(HeadPool):
     vocab_size: int = 196608
     d_model: int = 4096
     n_layers: int = 48
@@ -159,10 +160,16 @@ class SolarOpen2Config:
             kda.UPDATE_PATH if t == 1 else kda.SCAN_PATH,)
         return mixer + (gexp.PATH,)
 
-    def check_kernels(self, *, slots: int) -> None:
+    def check_kernels(self, *, slots: int, kv_blocks: Optional[int] = None,
+                      page_size: Optional[int] = None,
+                      pages_per_seq: Optional[int] = None,
+                      kv_quant: Optional[str] = None) -> None:
         """Lower this model's own kernels for a TPU at the decode step's
         shapes (no device, no compile): refused here, not at the first
-        request."""
+        request. With a pool named, the attention read over it too."""
+        self.lower_read(
+            slots=slots, kv_blocks=kv_blocks, page_size=page_size,
+            pages_per_seq=pages_per_seq, kv_quant=kv_quant)
         if self.kda_layers:
             kda.lower_for_tpu(batch=slots, heads=self.kda_heads,
                               key_dim=self.kda_head_dim,
@@ -270,41 +277,6 @@ class KdaMixer(nn.Module):
             jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
         o = (o * norm_w).reshape(b, t, hd) * jax.nn.sigmoid(gate)
         return dense(cfg.d_model, "o_proj", cfg)(o.astype(cfg.dtype))
-
-
-class GatedExperts(nn.Module):
-    """Sigmoid router over all the routed experts, the held experts'
-    product at hidden width, a shared expert of the same form."""
-    cfg: SolarOpen2Config
-
-    @nn.compact
-    def __call__(self, u, valid_len=None):
-        cfg = self.cfg
-        b, t, dm = u.shape
-        m = b * t
-        f32 = jnp.float32
-        um = u.reshape(m, dm)
-        real = row_mask(valid_len, b, t).reshape(m)
-        weights = held_weights(
-            self, um, real, n_routed=cfg.n_routed_experts, top_k=cfg.top_k,
-            held=cfg.experts_held, scaling=cfg.routed_scaling)
-        up_shape = (cfg.n_held, dm, cfg.expert_width)
-        wg = self.param("experts_gate", normal(), up_shape, cfg.param_dtype)
-        wu = self.param("experts_up", normal(), up_shape, cfg.param_dtype)
-        wd = self.param("experts_down", normal(),
-                        (cfg.n_held, cfg.expert_width, dm), cfg.param_dtype)
-        if self.is_initializing():
-            routed = jnp.zeros((m, dm), f32)            # no kernel at init
-        else:
-            routed = gexp.grouped_experts(
-                um, wu.astype(cfg.dtype), wd.astype(cfg.dtype), weights,
-                gate=wg.astype(cfg.dtype))
-        hid = jax.nn.silu(dense(cfg.shared_width, "shared_gate", cfg,
-                                 f32)(um)) \
-            * dense(cfg.shared_width, "shared_up", cfg, f32)(um)
-        out = routed + dense(dm, "shared_down", cfg, f32)(
-            hid.astype(cfg.dtype))
-        return out.astype(cfg.dtype).reshape(b, t, dm)
 
 
 class SolarOpen2(nn.Module):

@@ -1,0 +1,287 @@
+"""Reads of a paged *latent* cache: multi-head latent attention (DeepSeek-V2 /
+V3, ``models/deepseek_v3.py``) in its absorbed form.
+
+A latent-attention layer caches, a token, one vector with no head axis:
+``[c ; k_rope]``, the normalised compressed key-value ``c`` (``value_dim``
+values, 512) and the one rotary key all heads share (64). The pool is
+``[n_blocks, page, width]`` (``width`` 576), addressed through the same page
+table as a key/value pool (``ops/paged_attention.py``). In the absorbed form
+the key's up-projection is folded into the query
+(``q~_h = W_kvb,K,h^T q_nope_h``) and the value's is applied after the sum, so
+the read is attention with **one** "key" of ``width`` shared by every query
+head, whose first ``value_dim`` values are also the "value":
+
+    s_h(l) = scale * <[q~_h ; q_rope_h], pool(l)>     l <= the query's position
+    u_h    = sum_l softmax(s_h)(l) * pool(l)[:value_dim]
+
+Equal in exact arithmetic to the published form that expands ``c`` into
+per-head keys and values; a page is read once and serves scores and values of
+every head.
+
+- :func:`mla_attention` with ``kernel="pallas"``: one kernel body under two
+  names a device trace shows, ``mla_paged_decode`` (one grid cell a batch
+  row: plain decode and the speculative verify window) and
+  ``mla_paged_prefill`` (a batch-1 chunk cut into tiles of
+  ``_PREFILL_TILE`` query positions, one grid cell a tile). The pool stays in
+  HBM (``memory_space=ANY``); the page table and each row's first position
+  arrive by scalar prefetch; a cell copies the pages its last query can
+  see, and no more, into VMEM by DMA, a block of pages in flight while the
+  block before it is scored, and folds them into an online softmax (float32
+  scores, running max and sum). An idle slot (position 0, zeroed table)
+  reads one page. What it costs follows the live context, not
+  ``max_seq_len``: both are the full softmax over the whole prefix.
+- ``kernel="lax"``: the same sum in plain ``jax.numpy`` over the gathered
+  table (every page of the table, live or not): the portable path and the
+  kernel's oracle.
+
+Query positions of a row are consecutive (``start + t``), as every program of
+the engine makes them (decode ``T = 1``, verify ``T = gamma + 1``, a prefill
+chunk), so the causal mask needs each row's first position only.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from lzy_tpu.ops import interpret as _interpret
+
+_NEG_INF = -1e30
+
+#: ``lzy_kernel_dispatch_total{path}`` labels of the reads
+DECODE_PATH = "mla_decode_pallas"
+PREFILL_PATH = "mla_prefill_pallas"
+LAX_PATH = "mla_lax"
+
+#: the widest query window one grid cell takes whole (decode, verify); a
+#: wider window is a prefill chunk and is cut into tiles
+MAX_DECODE_TOKENS = 8
+#: query positions a grid cell of the prefill read (x heads rows of the q
+#: tile) and pooled positions scored a block (two such buffers are in VMEM).
+#: On a v5e chip, 16 heads, 640 lanes, a chunk of 256 against a live prefix of
+#: 2048 / 4096 / 7680 (ms a layer, 27 reads a program; PERF.md section 6,
+#: PR 36): tile 32 block 128 0.31 / 0.58 / 1.05 (the float32 accumulator is
+#: rescaled once a block: at 128 positions a block that is as long as the
+#: block's matrix products); tile 32 block 512 0.18 / 0.31 / 0.55; tile 64
+#: block 512 0.17 / 0.29 / 0.50; the lax form 0.68 whatever is live. Decode,
+#: 32 rows at 7936: block 128 229 GB/s, block 512 420, block 1024 494 with
+#: 8 rows at 1024 the slower for it. Tile 128 and block 1024 at tile 64 pass
+#: the kernel's VMEM.
+_PREFILL_TILE = 64
+_BLOCK_POSITIONS = 512
+
+
+def read_path(kernel: str, *, t: int) -> str:
+    """The label of a program with ``t`` query positions a row."""
+    if kernel != "pallas":
+        return LAX_PATH
+    return DECODE_PATH if t <= MAX_DECODE_TOKENS else PREFILL_PATH
+
+
+def _absorbed(q, lat, pos, *, value_dim: int, scale: float):
+    """``q`` [B, T, H, W] at positions ``pos`` [B, T] against ``lat``
+    [B, L, W], whose row ``l`` is position ``l``: scores and softmax in
+    float32, probabilities cast to ``lat``'s dtype before the value
+    contraction (as the kernel does). Returns [B, T, H, value_dim]."""
+    s = jnp.einsum("bthw,blw->bhtl", q.astype(lat.dtype), lat,
+                   preferred_element_type=jnp.float32) * scale
+    visible = (jnp.arange(lat.shape[1])[None, None, None, :]
+               <= pos[:, None, :, None])
+    p = jax.nn.softmax(jnp.where(visible, s, _NEG_INF), axis=-1)
+    return jnp.einsum("bhtl,blv->bthv", p.astype(lat.dtype),
+                      lat[..., :value_dim])
+
+
+def lax_mla_attention(q, pool, page_table, start, *, value_dim: int,
+                      scale: float):
+    """The absorbed sum over the whole table: ``pool`` [n_blocks, page, W]
+    gathered through ``page_table`` [B, P], every page of it, live or not;
+    ``start`` [B]."""
+    b, t, _, w = q.shape
+    pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+    return _absorbed(q, pool[page_table].reshape(b, -1, w), pos,
+                     value_dim=value_dim, scale=scale)
+
+
+def causal_mla_attention(q, lat, *, value_dim: int, scale: float):
+    """The absorbed sum with no cache: ``q`` [B, T, H, W] against the chunk's
+    own ``lat`` [B, T, W], causal."""
+    b, t = q.shape[:2]
+    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
+    return _absorbed(q, lat, pos, value_dim=value_dim, scale=scale)
+
+
+def _kernel(start_ref, pt_ref, q_ref, pool_hbm, o_ref, buf, sems, m_ref,
+            l_ref, acc_ref, *, tq, heads, page, pages_per_seq, block_pages,
+            value_dim, scale):
+    """One grid cell: ``tq`` consecutive query positions of batch row ``b``
+    (``tq * heads`` rows of the q tile, position-major) against the pages
+    the last of them can see. Numerics: scores, the running max and sum and
+    the accumulator in float32, scaled after the dot; probabilities cast to
+    the pool's dtype before the value contraction; the sum of the float32
+    probabilities divides the accumulator once at the end."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    rows = tq * heads
+    cols = block_pages * page
+    first = start_ref[b] + i * tq
+    n_pages = lax.div(jnp.maximum(first + tq - 1 + page, 0), page)
+    n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
+    # per q row: the last pooled position it sees
+    row_pos = first + lax.div(
+        lax.broadcasted_iota(jnp.int32, (rows, 1), 0), heads)
+    col = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+
+    @pl.when((b == 0) & (i == 0))
+    def _():
+        # a partial block leaves rows of the buffer unwritten; their
+        # probabilities are 0, and 0 x whatever VMEM held must be 0
+        buf[...] = jnp.zeros_like(buf)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def for_pages(j, slot, op):
+        for k in range(block_pages):
+            @pl.when(j * block_pages + k < n_pages)
+            def _():
+                pid = pt_ref[b * pages_per_seq + j * block_pages + k]
+                op(pltpu.make_async_copy(
+                    pool_hbm.at[pid], buf.at[slot, pl.ds(k * page, page)],
+                    sems.at[slot]))
+
+    for_pages(0, 0, lambda c: c.start())
+
+    def body(j, _):
+        slot = lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_blocks)
+        def _():
+            for_pages(j + 1, 1 - slot, lambda c: c.start())
+
+        for_pages(j, slot, lambda c: c.wait())
+        lat = buf[slot]                                       # [cols, W]
+        s = lax.dot_general(
+            q_ref[...], lat, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale       # [rows, cols]
+        visible = col <= row_pos - j * cols
+        s = jnp.where(visible, s, _NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
+            p.astype(lat.dtype), lat[:, :value_dim],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return 0
+
+    lax.fori_loop(0, n_blocks, body, 0)
+    l = l_ref[...]
+    # a row that sees nothing (position -1) reads nothing and returns 0
+    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "scale",
+                                             "interpret"))
+def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
+                          scale: float, interpret: bool):
+    """jitted so that the layers, which all make this call at one shape,
+    trace and lower the kernel once a program."""
+    b, t, h, w = q.shape
+    n, page, _ = pool.shape
+    pages = page_table.shape[1]
+    decode = t <= MAX_DECODE_TOKENS
+    tq = t if decode else min(t, _PREFILL_TILE)
+    if t % tq:
+        raise ValueError(
+            f"a prefill chunk of {t} positions is not whole tiles of {tq}")
+    rows = tq * h
+    block_pages = max(1, min(pages, _BLOCK_POSITIONS // page))
+    kernel = functools.partial(
+        _kernel, tq=tq, heads=h, page=page, pages_per_seq=pages,
+        block_pages=block_pages, value_dim=value_dim, scale=scale)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, t // tq),
+            in_specs=[
+                pl.BlockSpec((None, rows, w), lambda bi, i, *_: (bi, i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, rows, value_dim),
+                                   lambda bi, i, *_: (bi, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages * page, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, value_dim), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, t * h, value_dim), pool.dtype),
+        # the page buffer is zeroed by the first cell and kept by the rest
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret.tpu_params(interpret),
+        name="mla_paged_decode" if decode else "mla_paged_prefill",
+    )(start.astype(jnp.int32).reshape(-1),
+      page_table.astype(jnp.int32).reshape(-1),
+      q.astype(pool.dtype).reshape(b, t * h, w), pool)
+    return out.reshape(b, t, h, value_dim)
+
+
+def mla_attention(q: jax.Array, pool: jax.Array, page_table: jax.Array,
+                  start: jax.Array, *, value_dim: int, scale: float,
+                  kernel: str = "lax",
+                  interpret: Optional[bool] = None) -> jax.Array:
+    """The absorbed latent read through the page table.
+
+    - ``q``: ``[B, T, H, W]`` absorbed queries ``[q~ ; q_rope]`` (rotary
+      applied), ``W = value_dim + rope width``;
+    - ``pool``: ``[n_blocks, page, W]`` cached ``[c ; k_rope]`` (id 0 = the
+      reserved scratch block);
+    - ``page_table``: ``[B, P]`` int32 block ids in position order;
+    - ``start``: ``[B]`` int32, the position of each row's first query; query
+      ``t`` sits at ``start + t`` and sees pooled positions up to itself;
+    - ``kernel``: ``"lax"`` or ``"pallas"`` (``interpret=None`` takes the
+      process's ``ops.interpret`` setting).
+
+    Returns ``[B, T, H, value_dim]`` in the pool's dtype: each head's
+    weighted sum of ``c``, for the value up-projection to finish."""
+    if kernel not in ("lax", "pallas"):
+        raise ValueError(
+            f"unknown latent-read kernel {kernel!r}; known: lax, pallas")
+    if kernel == "pallas":
+        return _pallas_mla_attention(
+            q, pool, page_table, start, value_dim=value_dim,
+            scale=float(scale), interpret=_interpret.resolve(interpret))
+    return lax_mla_attention(q, pool, page_table, start,
+                             value_dim=value_dim, scale=scale)
+
+
+def lower_for_tpu(*, batch: int, t: int, heads: int, width: int,
+                  value_dim: int, n_blocks: int, page_size: int,
+                  pages_per_seq: int, dtype) -> None:
+    """Lower the kernel for a TPU at these shapes, with no device and no
+    compile, and let the lowering's error out."""
+    sds = jax.ShapeDtypeStruct
+
+    def read(q, pool, page_table, start):
+        return _pallas_mla_attention(
+            q, pool, page_table, start, value_dim=value_dim,
+            scale=1.0, interpret=False)
+
+    jax.jit(read).trace(
+        sds((batch, t, heads, width), dtype),
+        sds((n_blocks, page_size, width), dtype),
+        sds((batch, pages_per_seq), jnp.int32), sds((batch,), jnp.int32),
+    ).lower(lowering_platforms=("tpu",))

@@ -315,6 +315,9 @@ class EngineStats:
     # active KV quantization mode
     kernel_path: Optional[str] = None
     kv_quant: Optional[str] = None
+    # bytes one cached token costs the pool over all its layers (keys and
+    # values a head, or one vector with no head axis: the model's answer)
+    kv_token_bytes: Optional[int] = None
 
     def doc(self) -> dict:
         return {k: v for k, v in dataclasses.asdict(self).items()
@@ -373,9 +376,8 @@ class PagedInferenceEngine:
     ):
         from lzy_tpu.ops.interpret import resolve as pallas_interpreted
         from lzy_tpu.ops.paged_attention import (
-            DISPATCHES, QUANT_BLOCKS_RESIDENT, default_kernel,
-            lower_pallas_for_tpu)
-        from lzy_tpu.serving.kv_cache import RadixCache, blocks_for_bytes
+            DISPATCHES, QUANT_BLOCKS_RESIDENT, default_kernel)
+        from lzy_tpu.serving.kv_cache import RadixCache
 
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
@@ -488,6 +490,9 @@ class PagedInferenceEngine:
         self._round_tokens: dict = {}
 
         self.kernel_path = self._path_of(1)
+        # what one cached token costs the pool, every layer: the model's
+        # answer (models/serving.py), whatever its pages hold
+        self._kv_token_bytes = base.kv_layers * base.kv_token_bytes(kv_quant)
         self._dispatches = DISPATCHES
         # the resident gauge is process-global and this process may run
         # several quantized pools (disagg: prefill + decode); each engine
@@ -501,14 +506,13 @@ class PagedInferenceEngine:
             if kv_blocks is not None:
                 raise ValueError(
                     "pass kv_blocks or kv_pool_bytes, not both")
-            # size the pool by its HBM payload budget: int8 blocks are
-            # half the bytes of bf16 blocks, so the same budget holds
-            # ~2x the blocks — the whole point of kv_quant
-            kv_blocks = blocks_for_bytes(
-                kv_pool_bytes, page_size=page_size,
-                n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
-                n_layers=base.kv_layers, dtype=base.dtype,
-                kv_quant=kv_quant)
+            # size the pool by its HBM payload budget over what the model
+            # says a cached token costs a layer (keys and values a head, or
+            # one vector with no head axis): int8 blocks are half the bytes
+            # of bf16 blocks, so the same budget holds ~2x the blocks — the
+            # whole point of kv_quant
+            kv_blocks = max(2, kv_pool_bytes // (
+                page_size * self._kv_token_bytes))
         if kv_blocks is None:
             # dense-equivalent HBM by default (+1 scratch); pass less to
             # overcommit, more to grow the prefix cache's working set
@@ -516,15 +520,13 @@ class PagedInferenceEngine:
         if kv_blocks < 2:
             raise ValueError(f"kv_blocks must be >= 2, got {kv_blocks}")
         self._kv_blocks = kv_blocks
-        if self.kernel_path == "pallas" and not pallas_interpreted(None):
+        if self._paged_kernel == "pallas" and not pallas_interpreted(None):
             # no silent drop to the interpreter or to lax: what the TPU
-            # lowering refuses, it refuses here, before a pool exists
-            lower_pallas_for_tpu(
-                batch=slots, n_heads=base.n_heads,
-                n_kv_heads=base.n_kv_heads, head_dim=base.head_dim,
-                n_blocks=kv_blocks, page_size=page_size,
-                pages_per_seq=self._pages_per_seq, dtype=base.dtype)
-            base.check_kernels(slots=slots)
+            # lowering refuses of the model's kernels, its read of this
+            # pool among them, it refuses here, before a pool exists
+            base.check_kernels(
+                slots=slots, kv_blocks=kv_blocks, page_size=page_size,
+                pages_per_seq=self._pages_per_seq, kv_quant=kv_quant)
         self.kv = RadixCache(kv_blocks, page_size)
         # tiered KV cache (serving/kv_tier.py): radix eviction DEMOTES
         # block payloads to pinned host RAM (and onward to storage)
@@ -1766,6 +1768,7 @@ class PagedInferenceEngine:
             prefill_tokens_saved=ks.prefill_tokens_saved,
             kernel_path=self.kernel_path,
             kv_quant=self._kv_quant,
+            kv_token_bytes=self._kv_token_bytes,
             kv_imports=self.kv_imports,
             kv_import_blocks=self.kv_import_blocks,
             kv_parked_chains=len(self._parked),
@@ -1866,10 +1869,8 @@ class PagedInferenceEngine:
     def _path_of(self, t: int) -> str:
         """The read path of a program with ``t`` query positions a row:
         the label of its ``lzy_kernel_dispatch_total`` count."""
-        from lzy_tpu.ops.paged_attention import kernel_path
-
-        return kernel_path(self._paged_kernel, t=t,
-                           quantized=self._kv_quant is not None)
+        return self.cfg.read_path(self._paged_kernel, t=t,
+                                  kv_quant=self._kv_quant)
 
     def _build_decode_path(self, base: Any) -> None:
         slots, pages = self.slots, self._pages_per_seq
@@ -1887,20 +1888,22 @@ class PagedInferenceEngine:
 
     def _build_steps(self) -> None:
         """The jitted prefill, decode and verify programs over
-        ``self._model``. A model with state leaves is also told which
-        positions are real (``valid_len``): a padded prefill chunk ends at
-        its last real token, an idle slot (zeroed page table: block 0 is
-        the scratch block no row owns) has none."""
+        ``self._model``. A model with state leaves, or one that counts
+        (``STATS``), is also told which positions are real (``valid_len``):
+        a padded prefill chunk ends at its last real token, an idle slot
+        (zeroed page table: block 0 is the scratch block no row owns) has
+        none."""
         import functools
 
         self._stat_counters = tuple(type(self._model).STATS)
         has_state, has_stats = self._has_state, bool(self._stat_counters)
+        tells_real = has_state or has_stats
         mutable = ["cache", "stats"] if has_stats else ["cache"]
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def prefill_step(cache, params, tokens, page_table, last_idx):
             real = {"valid_len": jnp.reshape(last_idx + 1, (1,))} \
-                if has_state else {}
+                if tells_real else {}
             logits, updated = self._prefill_model.apply(
                 {"params": params, "cache": cache}, tokens,
                 page_table=page_table, mutable=["cache"], **real)
@@ -1914,7 +1917,7 @@ class PagedInferenceEngine:
                         greedy_mask, rng):
             cache = self._assemble_cache(payload, pos)
             real = {"valid_len": (page_table[:, 0] != 0).astype(jnp.int32)} \
-                if has_state else {}
+                if tells_real else {}
             logits, updated = self._model.apply(
                 {"params": params, "cache": cache}, cur[:, None],
                 page_table=page_table, mutable=mutable, **real)

@@ -370,3 +370,47 @@ def test_kda_state_update_compiles_at_published_widths(one_chip):
         sds((32,), jnp.bool_)).compile()
     state_bytes = 32 * 64 * 128 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < state_bytes // 8
+
+
+@pytest.mark.parametrize("batch,t", [(32, 1), (32, 5), (1, 16), (1, 256)],
+                         ids=["decode", "verify", "chunk16", "chunk256"])
+def test_latent_reads_compile_at_published_widths(batch, t, one_chip):
+    """The absorbed read of a paged latent cache under both its names: 16
+    heads over a vector of 576 values in 640 lanes, a pool of 7,000 pages of
+    16, a table of 512 pages a slot (64 KiB of scalar prefetch at 32 slots,
+    the widest the repo compiles). No copy of the pool is made."""
+    from lzy_tpu.ops import mla
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda q, pool, table, start: mla.mla_attention(
+        q, pool, table, start, value_dim=512, scale=192 ** -0.5,
+        kernel="pallas", interpret=False)).lower(
+        sds((batch, t, 16, 640), jnp.bfloat16),
+        sds((7000, 16, 640), jnp.bfloat16),
+        sds((batch, 512), jnp.int32), sds((batch,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("mla_paged_decode" if t <= 8 else "mla_paged_prefill") in text
+    pool_bytes = 7000 * 16 * 640 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+@pytest.mark.parametrize("rows", [32, 256], ids=["decode", "chunk256"])
+def test_gated_experts_compile_at_a_width_of_eleven_lane_tiles(rows,
+                                                               one_chip):
+    """16 held experts of three 2048 x 1408 matrices: 1408 = 11 x 128 has no
+    divisor in lanes but 128 and itself, so the tiles are 2048 x 128."""
+    from lzy_tpu.ops import grouped_experts as gexp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((16, 2048, 1408), jnp.bfloat16)
+    compiled = jax.jit(lambda x, g, a, b, w: gexp.grouped_experts(
+        x, a, b, w, gate=g, interpret=False)).lower(
+        sds((rows, 2048), jnp.bfloat16), up, up,
+        sds((16, 1408, 2048), jnp.bfloat16),
+        sds((rows, 16), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

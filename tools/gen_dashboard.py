@@ -48,6 +48,9 @@ def registry_metrics():
     # assignments, experts touched / held a decode round (lzy_moe_*);
     # state rows zeroed for a new request is the engine's (lzy_state_*)
     import lzy_tpu.models.nemotron_h  # noqa: F401
+    # a latent (MLA) cache: cached positions the decode rounds' rows read
+    # through the latent pool, and those rows, a layer (lzy_mla_*)
+    import lzy_tpu.models.deepseek_v3  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
