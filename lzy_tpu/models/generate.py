@@ -22,13 +22,23 @@ def sample_token(logits: jax.Array, temperature: float, rng: jax.Array,
                  *, top_k: Optional[int] = None,
                  top_p: Optional[float] = None):
     """Shared sampling for every model family's decode loop; logits [B, V] →
-    ([B] int32, rng). ``temperature<=0`` is greedy; ``top_k`` keeps the k
+    ([B] int32, rng): one split of ``rng`` (greedy too, so that the draw
+    order never depends on the mode), then :func:`draw_token` with the
+    half that is spent."""
+    rng, sub = jax.random.split(rng)
+    return draw_token(logits, temperature, sub, top_k=top_k, top_p=top_p), rng
+
+
+def draw_token(logits: jax.Array, temperature: float, key: jax.Array,
+               *, top_k: Optional[int] = None,
+               top_p: Optional[float] = None) -> jax.Array:
+    """One draw from ``key`` (spent here, not split); logits [B, V] → [B]
+    int32. ``temperature<=0`` is greedy; ``top_k`` keeps the k
     highest logits (``<=0`` disables the filter, the common sentinel
     convention); ``top_p`` keeps the smallest nucleus whose probability
     mass reaches p (both filters compose: k first, then p)."""
-    rng, sub = jax.random.split(rng)
     if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), rng
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     logits = logits / temperature
     if top_k is not None and 0 < top_k < logits.shape[-1]:
         kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
@@ -43,8 +53,7 @@ def sample_token(logits: jax.Array, temperature: float, rng: jax.Array,
         idx = jnp.argmax(crossed, axis=-1)
         cutoff = jnp.take_along_axis(sorted_logits, idx[..., None], axis=-1)
         logits = jnp.where(logits < cutoff, -jnp.inf, logits)
-    nxt = jax.random.categorical(sub, logits, axis=-1)
-    return nxt.astype(jnp.int32), rng
+    return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
 def decode_config(cfg: LlamaConfig, **overrides) -> LlamaConfig:

@@ -31,7 +31,10 @@ in ``models/serving.py``): this file names no model.
   ``prefill_width``): a program reads the weights once whatever its width,
   so a budget of 256 is one program of 256 positions, not four of 64. A
   model with per-slot ``state`` leaves carries the job's own batch-1 rows
-  between chunks.
+  between chunks. A round's prefill is ONE device call, as its decode is:
+  the jitted ``prefill_step`` builds its index leaves, cuts its chunk out
+  of the job's prompt buffer (uploaded once, in one shape for every
+  prompt) and picks the first token inside the program.
 - **One jitted step a round, one fence**: the decode hot loop is ONE jitted
   step over the ``[slots]`` rows, whose positions live in one ``[slots]``
   vector; a finished row leaves its slot immediately (its blocks go back to
@@ -85,7 +88,7 @@ import numpy as np
 from lzy_tpu.chaos.faults import CHAOS, CRASH, DELAY, ERROR, SLOW
 from lzy_tpu.models import serving
 from lzy_tpu.models.generate import (
-    init_cache, prefill_plan, prefill_width, sample_token)
+    draw_token, init_cache, prefill_plan, prefill_width, sample_token)
 from lzy_tpu.serving.scheduler import (
     AdmissionError, PromptTooLong, Request, RequestQueue)
 from lzy_tpu.serving.tenancy import (
@@ -158,6 +161,14 @@ _PREFILL_PROGRAMS = REGISTRY.counter(
 _PREFILL_POSITIONS = REGISTRY.counter(
     "lzy_engine_prefill_positions_total",
     "positions prefill programs ran over: their widths, pads included")
+# counted where the engine makes them, in the prefill phase: a round is one
+# (the program; a job's buffer rides in its first dispatch), a prompt's
+# last adds the rng's split and a state model's splice of its rows
+PREFILL_CALLS = REGISTRY.counter(
+    "lzy_engine_prefill_device_calls_total",
+    "device calls of the prefill phase: programs, the rng's split a "
+    "finished prompt, state rows made or spliced, an upload made apart "
+    "from a program (a gang's)")
 
 # decode-round scheduling (docs/serving.md "Decode-round scheduling"):
 # each round dispatches ONE fused device program and takes ONE
@@ -221,6 +232,15 @@ class StateLeavesUnsupported(ValueError):
     names the mechanism."""
 
 
+# what a prefill program is told about its chunk, one row of four int32 a
+# chunk of the job's plan, written into the job's buffer when it is staged:
+# where the chunk starts (the position of its first token in the prompt,
+# which is also the cache index it writes from), how many of its positions
+# are real, the row's sampling mode, and whether the job's state rows start
+# from zero
+_CTL_START, _CTL_TAKE, _CTL_GREEDY, _CTL_FRESH, _CTL_LEN = range(5)
+
+
 @dataclasses.dataclass
 class _PrefillJob:
     """One admitted request's in-progress prefill. With a
@@ -237,19 +257,23 @@ class _PrefillJob:
     plan: list                      # [(start, take, width)] over suffix
     next_chunk: int = 0
     done: int = 0                   # suffix tokens already prefilled
-    last: Any = None                # logits at the last real position
     matched: int = 0                # radix-matched prompt prefix
     table: list = dataclasses.field(default_factory=list)  # pool blocks
-    # device arrays invariant for the job's lifetime, uploaded once on
-    # the first round (a 32k prompt at budget 256 runs ~128 rounds —
-    # re-uploading the prompt and page table every round would repeat
-    # the host-to-device transfer on the decode-interleaved path the
-    # budget exists to keep short)
-    tokens_dev: Any = None          # [1, len] prompt / suffix ids
-    pt_dev: Any = None              # [1, pages] page table
+    # everything the job's programs are told, in ONE int32 array of one
+    # shape whatever the prompt's length (a 32k prompt at budget 256 runs
+    # ~128 rounds, and no program or transfer may follow the length):
+    # ``[1, n]`` = the cursor (which chunk runs next), a ``_CTL_*`` row a
+    # chunk of the plan, the page table, and the whole prompt from
+    # position 0 followed by the pad id as far as ``max_seq_len`` plus the
+    # widest chunk (``_job_layout``). A host array until the job's first
+    # program, in whose dispatch it rides; every program hands it back on
+    # the device with the cursor moved on, so the rounds after the first
+    # upload nothing
+    inputs: Any = None
     # state leaves (models with per-slot state): the job's
-    # own batch-1 rows, carried from chunk to chunk — the slot's rows in
-    # the decode tree are not touched until the prompt is done
+    # own batch-1 rows, carried from program to program — the slot's rows
+    # in the decode tree are not touched until the prompt is done. None
+    # until the job's first program, which starts them from zero
     state: Any = None
 
 
@@ -665,7 +689,15 @@ class PagedInferenceEngine:
         payload_kinds = [k for k in self._leaf_kinds if k != serving.INDEX]
         self._state_at = [i for i, k in enumerate(payload_kinds)
                           if k == serving.STATE]
+        self._pool_at = [i for i, k in enumerate(payload_kinds)
+                         if k != serving.STATE]
         self._has_state = bool(self._state_at)
+        # a model with state leaves, or one that counts (``STATS``), is
+        # told which positions of a program are real (``valid_len``)
+        self._tells_real = self._has_state or bool(type(self._model).STATS)
+        # the batch-1 state rows finished (or abandoned) prefill jobs no
+        # longer need: the next job's first program starts from them
+        self._spare_state: List[list] = []
 
     def _assemble_cache(self, payload, index_leaf):
         """Full cache tree from payload leaves + ONE index value placed
@@ -690,11 +722,12 @@ class PagedInferenceEngine:
     @property
     def _cache(self):
         """The full cache tree, index leaves materialized from the host
-        positions — the compatibility surface for everything OFF the
-        decode hot path (prefill splices, KV export/import, tier
-        demotion/promotion). Each index leaf is a fresh device buffer
-        (``jnp.array`` copies), so a consumer that donates the result
-        can never hand one buffer in twice."""
+        positions — the compatibility surface for everything OFF the hot
+        path (KV export/import, tier demotion/promotion). Neither jitted
+        step uses it: decode and prefill both take the payload leaves and
+        build their index leaves inside the program. Each index leaf is
+        a fresh device buffer (``jnp.array`` copies), so a consumer that
+        donates the result can never hand one buffer in twice."""
         vals = np.asarray(self._pos, np.int32)
         leaves, it = [], iter(self._payload)
         for idx in self._leaf_is_index:
@@ -760,14 +793,20 @@ class PagedInferenceEngine:
             greedy_mask, jnp.argmax(logits, axis=-1).astype(jnp.int32), nxt)
         return nxt, rng
 
-    def _pick_first(self, logits, req: Request):
-        """First-token pick after prefill; same one-split rng discipline
-        as :meth:`_pick_next`, host-side per request."""
-        tok, rng = sample_token(logits, self._temperature, self._rng,
-                                top_k=self._top_k, top_p=self._top_p)
-        if self._row_greedy(req) and self._temperature > 0.0:
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return tok, rng
+    def _pick_first(self, logits, row_greedy, key):
+        """First-token pick after prefill, inside ``prefill_step``, over
+        the one row of a prefill program. ``key`` is the spent half of the
+        rng's one split a finished prompt (the same discipline as
+        :meth:`_pick_next`; the split itself is ``_split_rng``, outside
+        the program); ``row_greedy`` is the request's own sampling mode
+        (:meth:`_row_greedy`), a traced flag."""
+        tok = draw_token(logits, self._temperature, key,
+                         top_k=self._top_k, top_p=self._top_p)
+        if self._temperature > 0.0:
+            tok = jnp.where(
+                row_greedy, jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                tok)
+        return tok
 
     def _row_greedy(self, req: Request) -> bool:
         """Effective sampling mode for a request: its own override, else
@@ -1151,36 +1190,95 @@ class PagedInferenceEngine:
         # during its own prefill, same as any freed slot's blocks)
         self.kv.release(job.table)
         job.table = []
+        self._leave_state_rows(job)
 
-    def _run_prefill_chunks(self, job: _PrefillJob, cache, arr, run_chunk):
-        """Shared budget loop: run chunks of ``job.plan`` through
-        ``run_chunk(cache, tokens, take)`` until the plan ends or the
-        budget is spent. Returns ``(cache, finished)``; ``job.last``
-        holds the final chunk's last-position logits once finished."""
+    def _run_prefill_chunks(self, job: _PrefillJob) -> tuple:
+        """The budget loop: one program a chunk of ``job.plan`` until the
+        plan ends or the budget is spent (a budget of one bucket's width
+        is one program a round). Returns ``(finished, first)``; ``first``
+        is the device's pick of the first token once finished."""
         budget = self.prefill_budget
         spent = 0
+        first = None
         while job.next_chunk < len(job.plan):
-            start, take, width = job.plan[job.next_chunk]
-            tokens = arr[:, start:start + take]
-            if width != take:
-                tokens = jnp.pad(tokens, ((0, 0), (0, width - take)))
-            cache, job.last = run_chunk(cache, tokens, take)
+            _, take, width = job.plan[job.next_chunk]
+            first = self._run_prefill_program(job, take, width)
             job.next_chunk += 1
             job.done += take
             spent += take
             if budget is not None and spent >= budget \
                     and job.next_chunk < len(job.plan):
-                return cache, False
-        return cache, True
+                return False, None
+        return True, first
+
+    def _run_prefill_program(self, job: _PrefillJob, take: int, width: int):
+        """ONE device call: ``prefill_step`` over the next ``width``
+        positions of the job's prompt, ``take`` of them real. Where the
+        chunk starts and the rest of what the program is told stand in the
+        job's buffer, which rides in the dispatch of the job's first
+        program and stays on the device, so nothing is uploaded, sliced,
+        padded or picked by a call of its own. Everything the program is
+        handed but the parameters and the key is donated and comes back:
+        the pool leaves, the job's buffer, a state model's job rows (a
+        job's first program zeroes what it is given).
+
+        The chunk that finishes a prompt is preceded by the rng's one
+        split a finished prompt (``_split_rng``, compiled once), whose
+        spent half the program draws the first token from; the other
+        chunks are handed the rng as it is and their pick is dropped. The
+        split is not inside the program because a Threefry split is a
+        third of what a width costs to lower, at every width, whatever
+        the compile cache holds (PERF.md section 6, PR 38)."""
+        # one program dispatch per CHUNK (a budgeted round may run
+        # several) — the dispatch counter must agree with the
+        # decode/verify paths' one-inc-per-program rule
+        self._count_dispatch(width)
+        _PREFILL_PROGRAMS.inc()
+        _PREFILL_TOKENS.inc(take)
+        _PREFILL_POSITIONS.inc(width)
+        PREFILL_CALLS.inc()
+        if self._has_state and job.state is None:
+            job.state = self._spare_state.pop() if self._spare_state \
+                else self._new_state_rows()
+        key = self._rng
+        if job.next_chunk == len(job.plan) - 1:
+            PREFILL_CALLS.inc()
+            self._rng, key = self._split_rng(self._rng)
+        payload = self._payload
+        pool, state, job.inputs, first = self._prefill_step(
+            [payload[i] for i in self._pool_at], job.state or [],
+            job.inputs, self.params, key, width=width)
+        for i, leaf in zip(self._pool_at, pool):
+            payload[i] = leaf
+        if self._has_state:
+            job.state = state
+        return first
+
+    def _leave_state_rows(self, job: _PrefillJob) -> None:
+        """A finished or abandoned job's state rows stay for the next job
+        to start from; no more than two sets are kept (a set is as large
+        as a slot's state), so a burst of prompts does not hold the
+        memory of its widest moment."""
+        if job.state is not None and len(self._spare_state) < 2:
+            self._spare_state.append(job.state)
+        job.state = None
+
+    def _new_state_rows(self) -> list:
+        """Batch-1 rows of every state leaf, for a prefill job when no
+        finished job has left its own behind."""
+        PREFILL_CALLS.inc(len(self._state_at))
+        return [jnp.zeros((1,) + self._payload[i].shape[1:],
+                          self._payload[i].dtype) for i in self._state_at]
 
     def _prefill_fence(self, first) -> int:
-        """The prefill's one blocking transfer: the first token, and with
-        it the wait for every chunk still queued on the device. Timed
-        apart from the ``prefill`` phase: here the loop waits for the
-        device, not the device for the loop."""
+        """The prefill's one blocking transfer: the first token as the
+        finishing program picked it, and with it the wait for every chunk
+        still queued on the device. Timed apart from the ``prefill``
+        phase: here the loop waits for the device, not the device for the
+        loop."""
         t0 = self._clock.now()
         with trace.span(trace.ENGINE_PREFILL_FENCE):
-            token = int(first[0])
+            token = int(np.asarray(first)[0])
         self._prefill_wait = self._clock.now() - t0
         return token
 
@@ -1592,7 +1690,14 @@ class PagedInferenceEngine:
         whose KV pool is sized to fill HBM cannot OOM the boot. The
         in-process HLO-keyed compilation cache (and the persistent one
         serve.py enables) then makes the first real call's "compile" a
-        lookup."""
+        lookup.
+
+        No prefill width is compiled here, by decision: a width's trace
+        and lowering are Python over the unrolled layers and cost seconds
+        whatever the compile cache holds, and a deployment's prompts may
+        never reach some of the six. ``prefill_step`` compiles a width
+        when the first request reaches it; a server that wants that paid
+        before it opens sends one request a width (docs/serving.md)."""
         payload = [jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
                    for leaf in self._payload]
         vec = jax.ShapeDtypeStruct((self.slots,), jnp.int32)
@@ -1897,21 +2002,19 @@ class PagedInferenceEngine:
 
         self._stat_counters = tuple(type(self._model).STATS)
         has_state, has_stats = self._has_state, bool(self._stat_counters)
-        tells_real = has_state or has_stats
+        tells_real = self._tells_real
         mutable = ["cache", "stats"] if has_stats else ["cache"]
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def prefill_step(cache, params, tokens, page_table, last_idx):
-            real = {"valid_len": jnp.reshape(last_idx + 1, (1,))} \
-                if tells_real else {}
-            logits, updated = self._prefill_model.apply(
-                {"params": params, "cache": cache}, tokens,
-                page_table=page_table, mutable=["cache"], **real)
-            last = jax.lax.dynamic_index_in_dim(
-                logits, last_idx, axis=1, keepdims=False)
-            return updated["cache"], last
+        def prefill_step(pool, state, job, params, key, width):
+            return self._prefill_program(pool, state, job, params, key,
+                                         width)
 
-        self._prefill_step = prefill_step
+        self._prefill_step = jax.jit(
+            prefill_step, static_argnames=("width",),
+            donate_argnums=(0, 1, 2))
+        # the rng's one split a finished prompt: (what the stream goes on
+        # from, what the first token's draw spends), as one program
+        self._split_rng = jax.jit(lambda rng: tuple(jax.random.split(rng)))
 
         def decode_step(payload, params, cur, pos, page_table,
                         greedy_mask, rng):
@@ -1965,52 +2068,96 @@ class PagedInferenceEngine:
 
     # -- cache-tree plumbing -------------------------------------------------
 
-    def _pool_to_prefill(self, start: int, job: Optional[_PrefillJob] = None):
-        """The decode cache tree re-skinned for a batch-1 prefill: pool
-        k/v leaves move over unchanged (they are ABOUT to be donated —
-        ``self._cache`` must not be touched until ``_merge_prefill``
-        replaces them), index leaves become ``[1]`` at ``start``, and a
-        state leaf becomes the JOB's own batch-1 row: a state row belongs
-        to one slot, and the decode rounds interleaved with this prefill
-        see that slot as idle."""
-        state = iter(job.state) if self._has_state else None
-        leaves, payload = [], iter(self._payload)
+    @property
+    def _job_layout(self) -> tuple:
+        """Where a prefill job's buffer (``_PrefillJob.inputs``) keeps what:
+        the offsets of its plan rows, its page table and its prompt, and
+        its length. A plan has at most ``max_seq_len / prefill_chunk``
+        chunks; the prompt's part runs a chunk past ``max_seq_len`` so
+        that a chunk cut anywhere inside the prompt never runs off the
+        end."""
+        chunk = max(1, self.prefill_chunk)
+        table = 1 + _CTL_LEN * -(-self.cfg.max_seq_len // chunk)
+        prompt = table + self._pages_per_seq
+        return 1, table, prompt, prompt + self.cfg.max_seq_len + chunk
+
+    def _prefill_program(self, pool, state, job, params, key, width,
+                         **apply_kw):
+        """The body of ``prefill_step`` (traced): one batch-1 chunk of
+        ``width`` positions against the shared pool, everything a round
+        needs computed here from the job's buffer, as ``decode_step``
+        computes its own from ``pos``.
+
+        - The chunk's ``_CTL_*`` row is the one the buffer's cursor
+          points at; the cursor moves on in the buffer handed back.
+        - The cache tree: the pool's paged leaves as they are, ONE index
+          value ``[start]`` placed at every index leaf, a state leaf the
+          JOB's own batch-1 row (a state row belongs to one slot, and the
+          decode rounds interleaved with this prefill see that slot as
+          idle), zeroed on the job's first program.
+        - The chunk: ``width`` ids of the job's prompt from ``start``,
+          positions at or past ``take`` set to the pad id 0 (the buffer
+          holds 0 there already; the program does not lean on it), so a
+          padded tail sees exactly what a padded upload held.
+        - The first token: picked from the logits of the last real
+          position with the engine's sampling parameters, the row's
+          greedy override and ``key``, which on the chunk that finishes a
+          prompt is the spent half of the rng's split; on the others the
+          pick is computed and dropped, the price of one program a width.
+
+        Returns the pool leaves, the job's state rows, its buffer (on the
+        device from here on) and the ``[1]`` token."""
+        plan_at, table_at, prompt_at, _ = self._job_layout
+        ctl = jax.lax.dynamic_slice_in_dim(
+            job[0], plan_at + _CTL_LEN * job[0, 0], _CTL_LEN)
+        page_table = job[:, table_at:prompt_at]
+        prompt = job[:, prompt_at:]
+        start, take = ctl[_CTL_START], ctl[_CTL_TAKE]
+        index = jnp.reshape(start, (1,))
+        leaves, paged, rows = [], iter(pool), iter(state)
         for kind in self._leaf_kinds:
             if kind == serving.INDEX:
-                leaves.append(jnp.full((1,), start, jnp.int32))
-                continue
-            leaf = next(payload)
-            leaves.append(next(state) if kind == serving.STATE else leaf)
-        return jax.tree_util.tree_unflatten(self._cache_treedef, leaves)
+                leaves.append(index)
+            elif kind == serving.STATE:
+                row = next(rows)
+                leaves.append(jnp.where(ctl[_CTL_FRESH] != 0,
+                                        jnp.zeros_like(row), row))
+            else:
+                leaves.append(next(paged))
+        cache = jax.tree_util.tree_unflatten(self._cache_treedef, leaves)
+        chunk = jax.lax.dynamic_slice_in_dim(prompt, start, width, axis=1)
+        tokens = jnp.where(jnp.arange(width, dtype=jnp.int32) < take,
+                           chunk, 0)
+        real = {"valid_len": jnp.reshape(take, (1,))} \
+            if self._tells_real else {}
+        logits, updated = self._prefill_model.apply(
+            {"params": params, "cache": cache}, tokens,
+            page_table=page_table, mutable=["cache"], **real, **apply_kw)
+        last = jax.lax.dynamic_index_in_dim(
+            logits, take - 1, axis=1, keepdims=False)
+        first = self._pick_first(last, ctl[_CTL_GREEDY] != 0, key)
+        out = jax.tree_util.tree_leaves(updated["cache"])
+        return ([leaf for leaf, kind in zip(out, self._leaf_kinds)
+                 if kind == serving.PAGED],
+                [leaf for leaf, kind in zip(out, self._leaf_kinds)
+                 if kind == serving.STATE],
+                job.at[0, 0].add(1), first)
 
-    def _merge_prefill(self, pre_cache, job: _PrefillJob,
-                       finished: bool) -> None:
-        """Fold a prefill round back into the decode tree: pool k/v
-        leaves are taken from the prefill output (the decode tree's were
-        donated). Index state needs no splice — the host ``_pos`` mirror
-        (set by ``_finish_prefill``; 0 while the job is mid-flight) is the
-        single source of truth for positions. State leaves stay with the
-        job until its prompt is done; then its batch-1 rows are spliced
-        into the slot's rows of the decode tree."""
-        if not self._has_state:
-            self._cache = pre_cache
-            return
-        leaves = jax.tree_util.tree_leaves(pre_cache)
-        job.state = [leaf for leaf, kind in zip(leaves, self._leaf_kinds)
-                     if kind == serving.STATE]
-        payload = [leaf for leaf, kind in zip(leaves, self._leaf_kinds)
-                   if kind != serving.INDEX]
-        for i in self._state_at:
-            payload[i] = self._payload[i]   # the decode tree keeps its rows
-        if finished:
-            with trace.span(trace.ENGINE_PREFILL_STATE):
-                rows = self._splice_state(
-                    [payload[i] for i in self._state_at], job.state,
-                    jnp.asarray(job.slot, jnp.int32))
-            for i, row in zip(self._state_at, rows):
-                payload[i] = row
-            job.state = None
-        self._payload = payload
+    def _splice_job_state(self, job: _PrefillJob) -> None:
+        """A finished prompt's state: the job's batch-1 rows are spliced
+        into the slot's rows of the decode tree (one device call), and
+        left for the next job to start from. Index state needs no splice
+        — the host ``_pos`` mirror (set by ``_finish_prefill``; 0 while
+        the job is mid-flight) is the single source of truth for
+        positions."""
+        with trace.span(trace.ENGINE_PREFILL_STATE):
+            PREFILL_CALLS.inc()
+            rows = self._splice_state(
+                [self._payload[i] for i in self._state_at], job.state,
+                np.int32(job.slot))
+        for i, row in zip(self._state_at, rows):
+            self._payload[i] = row
+        self._leave_state_rows(job)
 
     # -- admission / prefill -------------------------------------------------
 
@@ -2090,8 +2237,7 @@ class PagedInferenceEngine:
         # disagg gateway reports it as `prefilled_by` (used, not staged)
         req.kv_prefilled_by = (
             self.kv.chain_origin(prompt[:matched]) if matched else None)
-        suffix = prompt[matched:]
-        plan = prefill_plan(len(suffix), self.prefill_chunk,
+        plan = prefill_plan(t0 - matched, self.prefill_chunk,
                             self.cfg.max_seq_len - matched)
         # blocks for the REAL prompt positions only: a padded final
         # chunk's pad positions (>= t0) fall past the table's allocated
@@ -2111,63 +2257,64 @@ class PagedInferenceEngine:
         # job completes — decode rounds interleaved with this prefill
         # must see the reserved slot as idle (its garbage writes land on
         # block 0), never on the job's half-written real blocks
-        state = None
         if self._has_state:
-            # a reused slot starts from zero state: the job's own rows
-            state = [jnp.zeros((1,) + self._payload[i].shape[1:],
-                               self._payload[i].dtype)
-                     for i in self._state_at]
+            # a reused slot starts from zero state: the job's first
+            # program zeroes the rows it is handed
             _STATE_RESETS.inc()
         return _PrefillJob(req=req, slot=slot, plan=plan, matched=matched,
-                           table=blocks + owned, state=state)
+                           table=blocks + owned)
+
+    def _upload(self, array):
+        """A job's buffer as its first program takes it: the host array
+        as it is, so that it rides in that dispatch (a gang places it on
+        its mesh by a call of its own)."""
+        return array
+
+    def _stage_prefill_inputs(self, job: _PrefillJob) -> None:
+        """Write the job's buffer (``_job_layout``), once: the cursor at
+        0, a ``_CTL_*`` row a chunk of the plan, the page table, the
+        prompt."""
+        prompt = job.req.prompt
+        plan_at, table_at, prompt_at, length = self._job_layout
+        buf = np.zeros((1, length), np.int32)
+        greedy = self._row_greedy(job.req)
+        rows = [(job.matched + start, take, greedy,
+                 n == 0 and self._has_state)       # in the order of _CTL_*
+                for n, (start, take, _) in enumerate(job.plan)]
+        buf[0, plan_at:plan_at + _CTL_LEN * len(rows)] = \
+            np.asarray(rows, np.int32).ravel()
+        buf[0, table_at:table_at + len(job.table)] = job.table
+        buf[0, prompt_at:prompt_at + len(prompt)] = prompt
+        job.inputs = self._upload(buf)
 
     def _advance_prefill_round(self, job: _PrefillJob) -> bool:
         """One budgeted round of a prefill; True when the job finished
-        (slot activated). The pool k/v leaves are
-        re-skinned for the batch-1 prefill, advanced by up to the budget,
-        and merged back into the decode tree before returning — decode
-        steps between rounds run against a fully consistent tree (the
-        job's slot reads as idle: index 0, scratch page table). Resuming
-        at ``matched + done`` reproduces the one-shot index exactly
-        (interior chunks are unpadded), so chunking never changes the
-        device math — only its interleaving."""
+        (slot activated). A round is ONE device call a chunk (the job's
+        buffer rides in the dispatch of its first and stays on the device):
+        the jitted ``prefill_step`` takes the pool's leaves, advances
+        them by the chunk and hands them back, so decode steps between
+        rounds run against a fully consistent tree (the job's slot reads
+        as idle: index 0, scratch page table). Resuming at ``matched +
+        done`` reproduces the one-shot index exactly (interior chunks are
+        unpadded), so chunking never changes the device math — only its
+        interleaving. The round that finishes a prompt reads the first
+        token the program picked (the fence) and, on a model with state
+        leaves, splices the job's rows into the slot's."""
         req = job.req
         t0 = len(req.prompt)
-        if job.pt_dev is None:
-            pt = np.zeros((1, self._pages_per_seq), np.int32)
-            pt[0, :len(job.table)] = job.table
-            job.pt_dev = jnp.asarray(pt)
-        pt = job.pt_dev
         # everything device-side below donates the SHARED pool: a failure
         # here poisons every request, not just this one
         try:
             # chaos boundary: an injected error here is exactly a device
             # call dying mid-prefill — engine-fatal by construction
             CHAOS.hit("engine.prefill")
-            cache = self._pool_to_prefill(job.matched + job.done, job)
-            if job.tokens_dev is None:
-                job.tokens_dev = jnp.asarray(
-                    [req.prompt[job.matched:]], jnp.int32)
-
-            def run_chunk(c, tokens, take):
-                # one program dispatch per CHUNK (a budgeted round may
-                # run several) — the dispatch counter must agree with
-                # the decode/verify paths' one-inc-per-program rule
-                self._count_dispatch(tokens.shape[1])
-                _PREFILL_PROGRAMS.inc()
-                _PREFILL_TOKENS.inc(take)
-                _PREFILL_POSITIONS.inc(tokens.shape[1])
-                return self._prefill_step(
-                    c, self.params, tokens, pt,
-                    jnp.asarray(take - 1, jnp.int32))
-
-            cache, finished = self._run_prefill_chunks(
-                job, cache, job.tokens_dev, run_chunk)
+            if job.inputs is None:
+                self._stage_prefill_inputs(job)
+            finished, first = self._run_prefill_chunks(job)
             if not finished:
-                self._merge_prefill(cache, job, False)
                 return False
-            first, self._rng = self._pick_first(job.last, req)
-            self._merge_prefill(cache, job, True)
+            if self._has_state:
+                self._splice_job_state(job)
         except Exception as e:  # noqa: BLE001 — see PoolCorruption
             raise PoolCorruption(
                 f"paged prefill died mid-flight for {req.id}: "

@@ -35,7 +35,6 @@ chunked prefill, speculation) is inherited verbatim. The contract:
 
 from __future__ import annotations
 
-import functools
 import threading
 from typing import Any, List, Optional, Tuple
 
@@ -46,7 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from lzy_tpu.models.generate import init_cache
 from lzy_tpu.models.llama import LlamaConfig
-from lzy_tpu.serving.engine import PagedInferenceEngine
+from lzy_tpu.serving.engine import PREFILL_CALLS, PagedInferenceEngine
 from lzy_tpu.serving.sharded import metrics as _m
 from lzy_tpu.serving.sharded.partition import (
     SERVE_RULES, pool_leaf_sharding, serve_mesh_for, shard_params)
@@ -169,16 +168,20 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
         self.params = shard_params(self.params, mesh)
         self._payload_shardings = [leaf.sharding for leaf in self._payload]
 
-        @functools.partial(jax.jit, **donate)
-        def prefill_step(cache, params, tokens, page_table, last_idx):
-            logits, updated = self._prefill_model.apply(
-                {"params": params, "cache": cache}, tokens, mesh=mesh,
-                page_table=page_table, mutable=["cache"])
-            last = jax.lax.dynamic_index_in_dim(
-                logits, last_idx, axis=1, keepdims=False)
-            return updated["cache"], last
+        def prefill_step(pool, state, job, params, key, width):
+            # the base program with the mesh on every apply; the index
+            # leaves are built inside it
+            return self._prefill_program(
+                pool, state, job, params, key, width, mesh=mesh)
 
-        self._prefill_step = prefill_step
+        self._prefill_step = jax.jit(
+            prefill_step, static_argnames=("width",),
+            **({"donate_argnums": (0, 1, 2)} if donate else {}))
+        # both halves leave replicated, as the decode program was warmed
+        # to take the rng
+        self._split_rng = jax.jit(
+            lambda rng: tuple(jax.random.split(rng)),
+            out_shardings=(self._repl, self._repl))
 
         def decode_step(payload, params, cur, pos, page_table,
                         greedy_mask, rng):
@@ -246,17 +249,13 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
                 np.array(self._tables), self._repl)
         return self._pt_dev
 
-    def _pool_to_prefill(self, start: int, job=None):
-        """Same re-skin as the base, with the batch-1 index leaves
-        committed replicated so the donated prefill cache tree is
-        uniformly mesh-placed. A FRESH buffer per index leaf — the whole
-        tree is donated, and two leaves aliasing one buffer is a
-        double-donation error at dispatch."""
-        host_idx = np.full((1,), start, np.int32)
-        return jax.tree_util.tree_map_with_path(
-            lambda path, leaf: jax.device_put(host_idx, self._repl)
-            if self._is_index(path) else leaf,
-            self._cache)
+    def _upload(self, array):
+        """A prefill job's buffer, committed replicated like every other
+        round input: a host array would reach the job's first program
+        unplaced, and that is another program than the one its later
+        rounds compile."""
+        PREFILL_CALLS.inc()
+        return jax.device_put(array, self._repl)
 
     # -- gang liveness -------------------------------------------------------
 

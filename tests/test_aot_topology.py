@@ -293,6 +293,38 @@ def test_paged_decode_step_compiles_at_full_width(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_paged_prefill_step_compiles_at_full_width(one_chip, monkeypatch):
+    """The round's one prefill program of the same engine at its widest
+    chunk: the index leaves, the chunk cut out of the job's buffer and the
+    first token's pick are inside it, so this is every device operation of
+    a prefill round, compiled for the chip from shapes alone."""
+    from lzy_tpu.ops import interpret
+    from lzy_tpu.serving import PagedInferenceEngine
+
+    monkeypatch.setattr(interpret, "_process_wide", False)
+
+    cfg = dataclasses.replace(_8B, n_layers=2, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda k: unbox(llama.init_params(cfg, k)[0]), jax.random.PRNGKey(0))
+    engine = PagedInferenceEngine(cfg, params, slots=2, page_size=16,
+                                  kernel="pallas", prefill_budget=256,
+                                  temperature=0.8, top_k=40, top_p=0.9)
+    try:
+        def on_chip(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        assert engine.prefill_chunk == 256
+        compiled = engine._prefill_step.lower(
+            [on_chip(leaf) for leaf in engine._payload], [],
+            jax.ShapeDtypeStruct((1, engine._job_layout[-1]), jnp.int32,
+                                 sharding=one_chip),
+            jax.tree_util.tree_map(on_chip, params),
+            on_chip(engine._rng), width=256).compile()
+    finally:
+        engine.close()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # -- the Nemotron-H serving kernels at published widths ----------------------
 
 @pytest.mark.parametrize("rows", [64, 8, 256],
