@@ -190,6 +190,14 @@ _ROUND_PHASE = REGISTRY.histogram(
     "engine-loop phase wall time (phase=kv_io|reap|admit|prefill|"
     "prefill_fence|plan|dispatch|overlap|fence|emit|park)",
     buckets=(0.0001, 0.0005, 0.001, 0.005, 0.02, 0.05, 0.25, 1.0))
+# a stall has a name without a trace: a phase of the loop (``park`` is a
+# wait, not a phase of work) that outlasted this edge of the histogram
+# above is counted by its label and logged with the round it fell in
+_SLOW_PHASE_S = 0.25
+_SLOW_PHASE = REGISTRY.counter(
+    "lzy_engine_slow_phase_total",
+    "engine-loop phases other than park that took longer than 0.25 s, "
+    "by phase")
 _ROUND_FENCES = REGISTRY.counter(
     "lzy_engine_round_fences_total",
     "device-to-host fences taken by decode rounds (contract: exactly "
@@ -956,13 +964,13 @@ class PagedInferenceEngine:
             t4 = now()
             stepped = self._decode()
             # observed after the round's fence, like the decode half's
-            _ROUND_PHASE.observe(t1 - t0, phase="kv_io")
-            _ROUND_PHASE.observe(t2 - t1, phase="reap")
-            _ROUND_PHASE.observe(t3 - t2, phase="admit")
+            self._observe_phase("kv_io", t1 - t0)
+            self._observe_phase("reap", t2 - t1)
+            self._observe_phase("admit", t3 - t2)
             wait = self._prefill_wait
-            _ROUND_PHASE.observe(t4 - t3 - wait, phase="prefill")
+            self._observe_phase("prefill", t4 - t3 - wait)
             if wait:        # only a round that finished a prompt has one
-                _ROUND_PHASE.observe(wait, phase="prefill_fence")
+                self._observe_phase("prefill_fence", wait)
             worked = serviced or admitted or progressed or stepped
             if rnd and stepped:
                 trace.note(kind=self._round_kind, rows=self._round_rows,
@@ -1331,6 +1339,13 @@ class PagedInferenceEngine:
             self._mask_dev = jnp.array(self._greedy_mask())
         return self._cur_dev, self._pos_dev, self._mask_dev
 
+    def _stale_inputs(self) -> int:
+        """How many of the round's inputs (``cur``, ``pos``, the greedy
+        mask, the page table) the dispatch is about to rebuild from the
+        host: 0 in a round that follows no admission."""
+        return sum(x is None for x in (self._cur_dev, self._pos_dev,
+                                       self._mask_dev, self._pt_dev))
+
     def _overlap_window(self) -> None:
         """Host work run BETWEEN the round's dispatch and its fence —
         while the device computes, for free on the wall clock: the next
@@ -1405,6 +1420,8 @@ class PagedInferenceEngine:
             return self._decode_verify(plan, t_plan)
         t0 = self._clock.now()
         with trace.span(trace.ENGINE_DECODE_DISPATCH):
+            if trace.ON:
+                trace.note(uploads=self._stale_inputs())
             (self._payload, self._pos_dev, self._cur_dev,
              self._rng) = self._run_decode_step()
         t1 = self._clock.now()
@@ -1415,6 +1432,8 @@ class PagedInferenceEngine:
             # the round's ONE fence: the tokens, and behind them whatever
             # counts the model's layers carried out of the step
             nxt = self._fetch(self._round_out())
+            if trace.ON:
+                trace.note(bytes=nxt.nbytes)
         t3 = self._clock.now()
         dt = t3 - t0
         with trace.span(trace.ENGINE_DECODE_EMIT):
@@ -1520,6 +1539,8 @@ class PagedInferenceEngine:
         t0 = self._clock.now()
         gamma = self.spec_tokens
         with trace.span(trace.ENGINE_DECODE_DISPATCH):
+            if trace.ON:
+                trace.note(uploads=self._stale_inputs())
             prop = np.zeros((self.slots, gamma), np.int32)
             plen = np.zeros((self.slots,), np.int32)
             for slot, p in plan.items():
@@ -1534,6 +1555,8 @@ class PagedInferenceEngine:
         t2 = self._clock.now()
         with trace.span(trace.ENGINE_DECODE_FENCE):
             packed = self._fetch(packed)   # the round's ONE fence
+            if trace.ON:
+                trace.note(bytes=packed.nbytes)
         t3 = self._clock.now()
         dt = t3 - t0
         with trace.span(trace.ENGINE_DECODE_EMIT):
@@ -1598,11 +1621,24 @@ class PagedInferenceEngine:
         dispatch and transfer)."""
         _ROUNDS.inc(kind=kind)
         self._round_kind = kind
-        _ROUND_PHASE.observe(plan_dt, phase="plan")
-        _ROUND_PHASE.observe(dispatch_dt, phase="dispatch")
-        _ROUND_PHASE.observe(overlap_dt, phase="overlap")
-        _ROUND_PHASE.observe(fence_dt, phase="fence")
-        _ROUND_PHASE.observe(emit_dt, phase="emit")
+        self._observe_phase("plan", plan_dt)
+        self._observe_phase("dispatch", dispatch_dt)
+        self._observe_phase("overlap", overlap_dt)
+        self._observe_phase("fence", fence_dt)
+        self._observe_phase("emit", emit_dt)
+
+    def _observe_phase(self, phase: str, dt: float) -> None:
+        """One phase of this round into the histogram; a slow one is
+        also counted and logged, so a stalled loop names the phase it
+        stalled in where no trace was being taken."""
+        _ROUND_PHASE.observe(dt, phase=phase)
+        if dt > _SLOW_PHASE_S:
+            _SLOW_PHASE.inc(phase=phase)
+            decoded = self._round_kind is not None
+            _LOG.warning(
+                "engine loop: phase %s took %.3f s (round kind=%s rows=%d)",
+                phase, dt, self._round_kind if decoded else "no_decode",
+                self._round_rows if decoded else 0)
 
     def _note_decode_round(self, emitted: int, rows: int, dt: float) -> None:
         self._flush_token_accounting()

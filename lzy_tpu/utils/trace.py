@@ -35,7 +35,10 @@ and would cover every gap). When the recorder starts, and at the first
 ``engine.round`` of every second, an instant annotation
 ``lzy.clock.<time.monotonic_ns()>`` is emitted: its start on the
 profiler's clock less the number in its name is what to add to a record's
-stamp (``spans.jsonl``) to place it in the trace.
+stamp (``spans.jsonl``) to place it in the trace. Each anchor is also an
+event ``lzy.clock`` among the records (``monotonic_ns``: the same number),
+so a reader can tell a trace that holds no anchor from anchors it did not
+find.
 
 **The profiler.**
 
@@ -107,7 +110,8 @@ LLM_BATCH = "llm.batch"
 LLM_ROW = "llm.row"
 LLM_ROW_POOL_WAIT = "llm.row.pool_wait"
 LLM_DISPATCH = "llm.dispatch"
-CLOCK_ANCHOR = "lzy.clock."
+CLOCK = "lzy.clock"                         # event: one for each anchor
+CLOCK_ANCHOR = CLOCK + "."
 #: what ``profiled()`` leaves beside the trace: the recorder's records
 SPANS_FILE = "spans.jsonl"
 
@@ -299,13 +303,16 @@ def context() -> Optional[Tuple[int, Any]]:
 
 def anchor() -> None:
     """Ties the two clocks: an instant annotation whose name holds
-    ``time.monotonic_ns()`` as read where the profiler stamps it."""
+    ``time.monotonic_ns()`` as read where the profiler stamps it, and an
+    event ``lzy.clock`` with the same number (``monotonic_ns``), so the
+    records list the anchors a reader has to find in the host plane."""
     global _next_anchor
     ns = time.monotonic_ns()
     _next_anchor = ns / 1e9 + 1.0
     a = _annotation(CLOCK_ANCHOR + str(ns))
     if a is not None:
         a.__exit__(None, None, None)
+    emit(CLOCK, ns / 1e9, ns / 1e9, monotonic_ns=ns)
 
 
 def name_thread(name: str) -> None:

@@ -69,7 +69,8 @@ class TestRecorder:
         assert trace.emit("x", 0.0, 1.0) is None
         assert trace.context() is None
         with trace.recording() as rec:
-            assert rec.drain() == []      # nothing leaked in from before
+            # its own anchor, and nothing leaked in from before
+            assert [r.name for r in rec.drain()] == [trace.CLOCK]
 
     def test_nesting_sets_parent_request_and_attrs(self):
         with trace.recording() as rec:
@@ -100,10 +101,10 @@ class TestRecorder:
         with trace.recording(maxlen=4) as rec:
             for i in range(10):
                 trace.event(trace.KV_EVICT, i=i)
-            assert rec.dropped == 6
+            assert rec.dropped == 7       # six events and the anchor
             kept = rec.drain()
             assert [r.attrs["i"] for r in kept] == [6, 7, 8, 9]
-            assert rec.drain() == [] and rec.dropped == 6
+            assert rec.drain() == [] and rec.dropped == 7
 
     def test_nested_holders_share_one_recorder(self):
         with trace.recording() as outer:
@@ -111,7 +112,9 @@ class TestRecorder:
                 assert inner is outer
             assert trace.ON is True
             trace.event(trace.KV_EVICT)
-            assert len(outer.drain()) == 1
+            # every holder that comes in anchors the clocks anew
+            assert [r.name for r in outer.drain()] == [
+                trace.CLOCK, trace.CLOCK, trace.KV_EVICT]
         assert trace.ON is False
 
     def test_threads_share_the_recorder_without_losing_a_record(self):
@@ -139,7 +142,7 @@ class TestRecorder:
                 for t in threads:
                     t.join(timeout=60)
                 assert not any(t.is_alive() for t in threads)
-                recs = rec.drain()
+                recs = [r for r in rec.drain() if r.name != trace.CLOCK]
                 assert rec.dropped == 0
         finally:
             sys.setswitchinterval(interval)
@@ -168,7 +171,7 @@ class TestRecorder:
         head, *lines = [json.loads(line) for line in
                         open(tmp_path / "t" / trace.SPANS_FILE)]
         assert head == {"clock": "time.monotonic", "records": 3,
-                        "anchor": "lzy.clock.", "dropped": 1}
+                        "anchor": "lzy.clock.", "dropped": 2}
         assert [r["name"] for r in lines] == [
             trace.GATEWAY_ATTEMPT, trace.GATEWAY_ATTEMPT,
             trace.GATEWAY_GENERATE]
@@ -460,3 +463,150 @@ class TestEngineLoop:
         for e in at_admit:
             assert admits[e.parent].attrs["evicted"] == e.attrs["blocks"]
         eng.close()
+
+    def test_every_anchor_is_a_record_and_a_parked_loop_keeps_anchoring(
+            self, tiny_model):
+        """The anchors a reader has to find in a profile's host plane are
+        listed among the records. A parked loop wakes every half second
+        into an idle round, so two seconds of park still anchor the
+        clocks, one anchor a second."""
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=1, page_size=PAGE)
+        with trace.recording() as rec:
+            eng.start()
+            time.sleep(2.2)
+            eng.close()
+            recs = _by_name(rec.drain())
+        anchors = recs[trace.CLOCK]
+        assert len(anchors) >= 2 and recs[trace.ENGINE_PARK]
+        for a in anchors:
+            assert a.start == a.end
+            assert a.attrs == {"monotonic_ns": round(a.start * 1e9)}
+        first, *later = anchors
+        assert first.thread == threading.current_thread().name
+        rounds = {r.id for r in recs[trace.ENGINE_ROUND]}
+        assert all(a.parent in rounds for a in later)
+        gaps = [b.start - a.start for a, b in zip(later, later[1:])]
+        assert all(gap >= 1.0 for gap in gaps)
+
+    def test_a_round_says_what_it_moved(self, tiny_model):
+        """``uploads`` on the dispatch span counts the inputs the round
+        rebuilt from the host (the round after an admission; none in the
+        rounds that follow), ``bytes`` on the fence span is the size of
+        the one array the round fetched."""
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
+        first = eng.submit([5, 9, 3], max_new_tokens=4)
+        with trace.recording() as rec:
+            while not first.done:
+                eng.step()
+            one = _by_name(rec.drain())
+            second = eng.submit([7, 1], max_new_tokens=3)
+            while not second.done:
+                eng.step()
+            two = _by_name(rec.drain())
+        for recs in (one, two):
+            uploads = [r.attrs["uploads"]
+                       for r in recs[trace.ENGINE_DECODE_DISPATCH]]
+            assert uploads[0] >= 3          # cur, pos and the mask at least
+            assert len(uploads) > 1 and set(uploads[1:]) == {0}
+            assert {r.attrs["bytes"] for r in recs[
+                trace.ENGINE_DECODE_FENCE]} == {eng.slots * 4}
+        eng.close()
+
+    def test_off_a_round_makes_no_span_and_no_note(self, tiny_model,
+                                                   monkeypatch):
+        """The recorder off, which is how every end-to-end number is
+        taken: a round builds no ``_Span`` and reaches no ``note``, the
+        new call sites included."""
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
+
+        def refuse(*args, **kwargs):
+            raise AssertionError("reached with the recorder off")
+
+        assert trace.ON is False
+        monkeypatch.setattr(trace, "_Span", refuse)
+        monkeypatch.setattr(trace, "note", refuse)
+        monkeypatch.setattr(trace, "anchor", refuse)
+        req = eng.submit([5, 9, 3, 7], max_new_tokens=12)
+        while not req.done:
+            eng.step()
+        assert eng.step() is False and len(req.tokens) == 12
+        eng.close()
+
+
+class _SkewedClock:
+    """The system's clock plus what a test has added to it, so a test can
+    make one phase of the loop as long as it likes."""
+
+    def __init__(self, park_s=0.0):
+        from lzy_tpu.utils.clock import SYSTEM_CLOCK
+
+        self._real, self.skew, self.park_s = SYSTEM_CLOCK, 0.0, park_s
+
+    def now(self):
+        return self._real.now() + self.skew
+
+    def wait(self, event, timeout=None):
+        self.skew += self.park_s          # the loop's park: a long one
+        return self._real.wait(event, 0.01)
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+
+def _slow_phases():
+    return {phase: float(v) for phase, v in re.findall(
+        r'lzy_engine_slow_phase_total\{phase="(\w+)"\} (\S+)',
+        REGISTRY.exposition())}
+
+
+class TestSlowPhase:
+    def test_a_slow_phase_is_counted_and_logged_once(self, tiny_model,
+                                                     caplog):
+        cfg, params = tiny_model
+        clock = _SkewedClock()
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE,
+                                   clock=clock)
+        req = eng.submit([5, 9, 3, 7], max_new_tokens=6)
+        while len(req.tokens) < 2:
+            eng.step()
+        before = _slow_phases()
+        reap = eng._reap_cancelled
+
+        def slow_reap():
+            reap()
+            clock.skew += 0.3
+
+        eng._reap_cancelled = slow_reap
+        caplog.clear()          # a compile in the rounds above may be slow
+        with caplog.at_level("WARNING"):
+            eng.step()
+            eng._reap_cancelled = reap
+            while not req.done:
+                eng.step()
+        after = _slow_phases()
+        grown = {p: after[p] - before.get(p, 0.0) for p in after
+                 if after[p] != before.get(p, 0.0)}
+        assert grown == {"reap": 1.0}
+        lines = [r.getMessage() for r in caplog.records
+                 if "engine loop: phase" in r.getMessage()]
+        assert len(lines) == 1
+        assert re.fullmatch(r"engine loop: phase reap took 0\.3\d\d s "
+                            r"\(round kind=decode rows=1\)", lines[0])
+        eng.close()
+
+    def test_a_long_park_is_not_a_slow_phase(self, tiny_model):
+        cfg, params = tiny_model
+        clock = _SkewedClock(park_s=1.0)
+        eng = PagedInferenceEngine(cfg, params, slots=1, page_size=PAGE,
+                                   clock=clock)
+        before, parked = _slow_phases(), _phase_sums().get("park", 0.0)
+        eng.start()
+        deadline = time.monotonic() + 10
+        while _phase_sums().get("park", 0.0) < parked + 1.0:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        eng.close()
+        assert _slow_phases() == before

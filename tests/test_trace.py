@@ -85,6 +85,41 @@ class TestProfiled:
         for annotated, r in zip(starts, rounds):
             assert abs(annotated - (r["start"] * 1e9 + offset)) < 1e6
 
+    def test_every_anchor_gives_one_offset_and_has_its_record(
+            self, tmp_path):
+        """Two clocks of one host: every ``lzy.clock.<ns>`` annotation in
+        the capture's host plane puts ``time.monotonic()`` at the same
+        place on the profiler's clock, to within a millisecond, and each
+        is listed in ``spans.jsonl`` as a ``lzy.clock`` record."""
+        import glob
+        import time
+
+        from jax.profiler import ProfileData
+
+        from lzy_tpu.models import llama, unbox
+        from lzy_tpu.serving import PagedInferenceEngine
+
+        cfg = llama.LlamaConfig.tiny(vocab_size=64)
+        params = unbox(llama.init_params(cfg, jax.random.PRNGKey(0))[0])
+        eng = PagedInferenceEngine(cfg, params, slots=1)
+        with profiled(str(tmp_path / "trace")) as logdir:
+            eng.start()                  # parked: an idle round a wake-up
+            time.sleep(2.2)
+            eng.close()
+        records = [json.loads(line) for line in
+                   open(f"{logdir}/{trace.SPANS_FILE}")][1:]
+        listed = {r["attrs"]["monotonic_ns"] for r in records
+                  if r["name"] == trace.CLOCK}
+        path, = glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb")
+        found = {int(e.name[len(trace.CLOCK_ANCHOR):]): float(e.start_ns)
+                 for plane in ProfileData.from_file(path).planes
+                 if plane.name == "/host:CPU" for line in plane.lines
+                 for e in line.events
+                 if e.name.startswith(trace.CLOCK_ANCHOR)}
+        assert len(found) >= 3 and set(found) == listed
+        offsets = [start - ns for ns, start in found.items()]
+        assert max(offsets) - min(offsets) < 1e6
+
     def test_upload_to_storage(self, tmp_path):
         from lzy_tpu.storage.mem import MemStorageClient
 
