@@ -40,6 +40,14 @@ in ``models/serving.py``): this file names no model.
   vector; a finished row leaves its slot immediately (its blocks go back to
   the pool or stay cached in the tree), and with every slot idle the loop
   parks on the queue's event instead of spinning the device.
+- **One round in flight**: a step hands back its tokens and positions as
+  device arrays, so round n+1 is dispatched from round n's outputs before
+  round n's tokens are fetched. ``step()`` fetches the round it dispatched
+  at once; the loop thread of ``start()`` fetches it one turn late, behind
+  the next dispatch (``_InFlight``), so the fence's tail, ``emit`` and the
+  next turn's front half run under a device program. A turn that needs the
+  tokens first (a finished prompt, a proposer, a squeeze, the last row)
+  drains the round in flight and goes on as ``step()`` does.
 - **Deadlines, tenants, KV I/O**: per-request deadlines and dead clients
   evict mid-decode with a ``cancelled`` status; WFQ, queue caps and KV
   quotas come from a ``TenantTable``; cross-replica KV import / export,
@@ -205,6 +213,21 @@ _ROUND_FENCES = REGISTRY.counter(
 _ROUNDS = REGISTRY.counter(
     "lzy_engine_rounds_total",
     "decode scheduling rounds by kind (kind=decode|verify)")
+# the loop thread keeps one decode round in flight (``_InFlight``): how
+# often it does (over ``lzy_engine_round_fences_total``), why it did not,
+# and what a finish learnt one round late costs
+_ROUNDS_OVERLAPPED = REGISTRY.counter(
+    "lzy_engine_rounds_overlapped_total",
+    "decode rounds dispatched while the round before them was unfetched")
+_ROUND_DRAINS = REGISTRY.counter(
+    "lzy_engine_round_drains_total",
+    "decode rounds the loop fetched with no later round dispatched over "
+    "them, by what needed their tokens first "
+    "(reason=admission|spec|squeeze|last_row|io|stop)")
+_OVERRUN_ROWS = REGISTRY.counter(
+    "lzy_engine_overrun_rows_total",
+    "rows a decode round carried whose token was dropped at its fetch: the "
+    "request had finished, been reaped or been preempted after the dispatch")
 _OVERLAP_COMMITS = REGISTRY.counter(
     "lzy_engine_admission_plan_total",
     "admission plans computed in the overlap window, by outcome "
@@ -294,6 +317,22 @@ class _ParkedChain:
     blocks: List[int]
     tokens: int                 # whole-block prefix length pinned
     expires_at: float           # engine-clock deadline (TTL sweep)
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """A decode round the device was handed and whose tokens the host has
+    not fetched. ``step()`` fetches it before it returns; the loop thread
+    of ``start()`` keeps one across turns and fetches it behind the next
+    round's dispatch. Tokens are applied by REQUEST, not by slot: a row
+    that finished, was reaped or was preempted between the dispatch and
+    the fetch (its slot empty, or another request's by then) drops its
+    token."""
+    seq: int                    # the round's number (``round`` on its spans)
+    out: Any                    # what the fence fetches: tokens (+ counts)
+    rows: List[tuple]           # [(slot, request)] it was dispatched with
+    rng: Any                    # the engine's key before the round
+    t0: float                   # dispatch start (engine clock)
 
 
 @dataclasses.dataclass
@@ -493,16 +532,22 @@ class PagedInferenceEngine:
         self._spec_index: List[Optional[Any]] = [None] * slots
 
         self._active: List[Optional[Request]] = [None] * slots
-        self._cur = np.zeros((slots,), np.int32)   # last token per slot
+        # last token per slot, as of the last round FETCHED: it lags the
+        # device by the round in flight
+        self._cur = np.zeros((slots,), np.int32)
         # host mirror of each slot's cache index (tokens resident in the
-        # row's KV cache); what speculation rolls back to after rejection
+        # row's KV cache); what speculation rolls back to after rejection.
+        # It moves at a round's DISPATCH, so the next round's block growth
+        # and page table need no token of the round in flight
         self._pos = np.zeros((slots,), np.int64)
         # device-resident mirrors of the per-round jit inputs, uploaded
         # once and reused until a host-side mutation invalidates them
         # (None = stale). ``_cur_dev``/``_pos_dev`` are normally the
         # PREVIOUS step's own outputs — the device keeps its own state
         # between rounds and the host uploads nothing; only admission
-        # (``_finish_prefill``) forces a re-upload. Idle rows drift in
+        # (``_finish_prefill``) forces a re-upload, from host mirrors that
+        # a round in flight has not reached yet: it is drained first. Idle
+        # rows drift in
         # the device copies (stale token/position garbage) — harmless by
         # construction: rows are independent, idle writes land on the
         # scratch block, and idle outputs are never read.
@@ -512,6 +557,16 @@ class PagedInferenceEngine:
         # device->host fences taken by decode rounds — public so the
         # transfer-count regression test can pin the one-fence contract
         self.host_fetches = 0
+        # the round in flight (``_InFlight``), its number, the thread that
+        # may leave one across turns (``start()``'s loop; ``step()`` called
+        # from any other fetches what it dispatched before it returns),
+        # when the last fence ended, and the seconds a drain has taken
+        # inside the loop phase now being timed
+        self._inflight: Optional[_InFlight] = None
+        self._round_seq = 0
+        self._loop_ident: Optional[int] = None
+        self._fenced_at = 0.0
+        self._drain_wait = 0.0
         # admission plan computed in the overlap window (while the device
         # runs): (queue.version, free slot, candidate-or-None); committed
         # by the next round's _admit iff the queue did not move
@@ -616,7 +671,6 @@ class PagedInferenceEngine:
         # per-row cached-token counts live in _pos
         self._admit_seq = np.zeros((slots,), np.int64)  # admission order
         self._admissions = 0
-        self._packed_out = None      # tokens + model counts of this round
         self._stat_counters: tuple = ()
         self._dispatch_paths: dict = {}   # positions a row -> path labels
         self._build_decode_path(base)
@@ -935,15 +989,25 @@ class PagedInferenceEngine:
         prompt's prefill is spread over many rounds, each of which also
         runs a decode step for the resident rows — bounded inter-token
         latency for them, bounded time-to-first-chunk for newly staged
-        short prompts (jobs rotate round-robin)."""
+        short prompts (jobs rotate round-robin).
+
+        When it returns, every token of the decode round it dispatched
+        has been emitted: ``plan -> dispatch -> overlap -> fence -> emit``,
+        one fence a round. Called by the loop thread of ``start()`` the
+        fence lags the dispatch by one turn (``plan(n+1) -> dispatch(n+1)
+        -> overlap -> fence(n) -> emit(n)``): still one fence a round,
+        taken while the device runs the next one."""
         now = self._clock.now
+        lag = threading.get_ident() == self._loop_ident
         self._round_kind = None
+        self._round_rows = self._round_emitted = 0
         self._prefill_wait = 0.0
         with trace.span(trace.ENGINE_ROUND) as rnd:
             t0 = now()
             with trace.span(trace.ENGINE_KV_IO):
                 serviced = self._service_io()
             t1 = now()
+            kv_io_dt = self._less_drains(t1 - t0)
             if CHAOS.armed is not None and (
                     self.queue.depth() or self._prefill_jobs
                     or any(r is not None for r in self._active)):
@@ -962,15 +1026,18 @@ class PagedInferenceEngine:
             with trace.span(trace.ENGINE_PREFILL):
                 progressed = self._advance_prefill()
             t4 = now()
-            stepped = self._decode()
+            # a finished prompt drains the round in flight before it waits
+            # for its first token: that fence and emit are observed as
+            # such, not as prefill
+            prefill_dt = self._less_drains(t4 - t3) - self._prefill_wait
+            stepped = self._decode(lag)
             # observed after the round's fence, like the decode half's
-            self._observe_phase("kv_io", t1 - t0)
+            self._observe_phase("kv_io", kv_io_dt)
             self._observe_phase("reap", t2 - t1)
             self._observe_phase("admit", t3 - t2)
-            wait = self._prefill_wait
-            self._observe_phase("prefill", t4 - t3 - wait)
-            if wait:        # only a round that finished a prompt has one
-                self._observe_phase("prefill_fence", wait)
+            self._observe_phase("prefill", prefill_dt)
+            if self._prefill_wait:  # only a round that finished a prompt
+                self._observe_phase("prefill_fence", self._prefill_wait)
             worked = serviced or admitted or progressed or stepped
             if rnd and stepped:
                 trace.note(kind=self._round_kind, rows=self._round_rows,
@@ -978,6 +1045,14 @@ class PagedInferenceEngine:
             elif rnd:
                 trace.note(kind="prefill_only" if worked else "idle")
         return worked
+
+    def _less_drains(self, dt: float) -> float:
+        """A loop phase's seconds less what a drain of the round in flight
+        took inside it (its fence and emit are observed under their own
+        labels)."""
+        dt -= self._drain_wait
+        self._drain_wait = 0.0
+        return dt
 
     def _reap_cancelled(self) -> None:
         """Free slots whose waiter abandoned the request (client
@@ -1283,7 +1358,11 @@ class PagedInferenceEngine:
         finishing program picked it, and with it the wait for every chunk
         still queued on the device. Timed apart from the ``prefill``
         phase: here the loop waits for the device, not the device for the
-        loop."""
+        loop. A decode round in flight was queued in front of the prompt's
+        programs and the slot is about to be activated from the host
+        mirrors, so it is drained first: its tokens go out now, not
+        behind the prompt's programs."""
+        self._drain("admission")
         t0 = self._clock.now()
         with trace.span(trace.ENGINE_PREFILL_FENCE):
             token = int(np.asarray(first)[0])
@@ -1310,7 +1389,9 @@ class PagedInferenceEngine:
         # admission changed the live row set: the device-resident round
         # inputs must be rebuilt from the host mirrors (the ONLY event
         # that forces a re-upload — frees leave harmless idle-row
-        # garbage in place instead)
+        # garbage in place instead). The mirror of tokens holds every
+        # round dispatched so far: ``_prefill_fence`` drained the one in
+        # flight
         self._cur_dev = None
         self._pos_dev = None
         self._mask_dev = None
@@ -1319,7 +1400,9 @@ class PagedInferenceEngine:
     def _fetch(self, arr) -> np.ndarray:
         """THE round fence: the one device→host transfer a decode round
         is allowed. Counted (``host_fetches``) so the transfer-count
-        regression test can pin the contract at exactly one per round."""
+        regression test can pin the contract at exactly one per round:
+        taken at once by ``step()``, one turn after the dispatch by the
+        loop thread (``_fence_emit``), never twice and never skipped."""
         self.host_fetches += 1
         _ROUND_FENCES.inc()
         return np.asarray(arr)
@@ -1327,8 +1410,11 @@ class PagedInferenceEngine:
     def _device_inputs(self):
         """The per-round jit inputs, device-resident across rounds.
         ``_cur_dev``/``_pos_dev`` are normally the previous step's own
-        outputs (nothing uploaded); after an admission they are rebuilt
-        from the host mirrors. ``jnp.array`` (an explicit copy), never
+        outputs (nothing uploaded, and no token of that step needed on the
+        host: it may still be in flight); after an admission they are
+        rebuilt from the host mirrors, which is why ``_decode`` drains a
+        round in flight before a round whose ``_cur_dev`` is stale.
+        ``jnp.array`` (an explicit copy), never
         ``jnp.asarray``: asarray zero-copies the live numpy buffer, and
         ``_emit``'s later host writes would mutate the device view."""
         if self._cur_dev is None:
@@ -1408,50 +1494,157 @@ class PagedInferenceEngine:
             self._tenant_count(tenant, "tokens_generated", n)
         _TOKENS.inc(total)
 
-    def _decode(self) -> bool:
+    def _decode(self, lag: bool = False) -> bool:
+        """The decode half of a turn. ``lag`` (the loop thread of
+        ``start()``): the round dispatched here stays in flight and the
+        one fetched is the round before it, so the fence's tail, the emit
+        and the next turn's front half run under a device program. The
+        round in flight is drained first where this turn needs its
+        tokens: the device inputs are to be rebuilt from the host mirrors
+        (a finished prompt), a proposer reads the newest token, or every
+        live row ends with the token in flight."""
+        drained = False
+        if self._inflight is not None:
+            why = self._needs_tokens()
+            if why is not None:
+                drained = self._drain(why)
+                self._drain_wait = 0.0      # it fell in no timed phase
         if not any(r is not None for r in self._active):
-            return False
+            return drained
         t_plan = self._clock.now()
         with trace.span(trace.ENGINE_DECODE_PLAN):
             if not self._pre_decode():
-                return False
+                return drained
             plan = self._spec_plan()
+        # with a proposer every round is fetched in its own turn (the
+        # proposals read its tokens), which the loop thread counts
+        spec = "spec" if lag and self._proposer is not None else None
+        lag = lag and spec is None
         if plan is not None:
-            return self._decode_verify(plan, t_plan)
+            return self._decode_verify(plan, t_plan, spec)
         t0 = self._clock.now()
+        plan_dt = self._less_drains(t0 - t_plan)
+        before = self._inflight
         with trace.span(trace.ENGINE_DECODE_DISPATCH):
+            self._round_seq += 1
             if trace.ON:
-                trace.note(uploads=self._stale_inputs())
-            (self._payload, self._pos_dev, self._cur_dev,
-             self._rng) = self._run_decode_step()
+                trace.note(uploads=self._stale_inputs(),
+                           round=self._round_seq,
+                           overlapped=before is not None)
+            rec = self._dispatch_decode(t0)
         t1 = self._clock.now()
         with trace.span(trace.ENGINE_DECODE_OVERLAP):
             self._overlap_window()
         t2 = self._clock.now()
+        if before is not None:
+            _ROUNDS_OVERLAPPED.inc()
+            self._fence_emit(before)
+        if not lag:
+            self._fence_emit(rec, spec)
+        # observed after the turn's fence: these take locks
+        self._observe_phase("plan", plan_dt)
+        self._observe_phase("dispatch", t1 - t0)
+        self._observe_phase("overlap", t2 - t1)
+        return True
+
+    def _dispatch_decode(self, t0: float) -> _InFlight:
+        """Hand the device one decode round over the active rows and move
+        the host's positions with it (the 1-token step puts one more
+        token into every active row's cache): everything the next round's
+        plan needs is known once this returns, the tokens are not."""
+        rng = self._rng
+        # a model whose layers sow counts returns them packed behind the
+        # tokens, as the one array the fence fetches
+        (self._payload, self._pos_dev, self._cur_dev, self._rng,
+         *packed) = self._run_decode_step()
+        rows = [(slot, req) for slot, req in enumerate(self._active)
+                if req is not None]
+        for slot, _ in rows:
+            self._pos[slot] += 1
+        out = packed[0] if packed else self._cur_dev
+        # asked for now, in front of whatever is queued next: the fence
+        # then waits for this round, not for a copy behind a later program
+        out.copy_to_host_async()
+        rec = self._inflight = _InFlight(
+            seq=self._round_seq, out=out, rows=rows, rng=rng, t0=t0)
+        self._round_kind, self._round_rows = "decode", len(rows)
+        return rec
+
+    def _needs_tokens(self) -> Optional[str]:
+        """Why this turn cannot dispatch over the round in flight, or
+        None: what ``lzy_engine_round_drains_total`` is labelled with."""
+        riding = self._riding()
+        if all(req is None or slot in riding
+               for slot, req in enumerate(self._active)):
+            # a length finish is known before the fetch: no round is
+            # dispatched past the end of the last live row
+            return "last_row"
+        if self._cur_dev is None:
+            return "admission"
+        return None
+
+    def _riding(self) -> set:
+        """Slots whose request ends by length with the token in flight:
+        still active until that token is fetched, so they ride the next
+        round (its token for them is dropped) but grow no block for it."""
+        rec = self._inflight
+        if rec is None:
+            return set()
+        return {slot for slot, req in rec.rows
+                if self._active[slot] is req
+                and len(req.tokens) + 1 >= req.max_new_tokens}
+
+    def _drain(self, reason: str) -> bool:
+        """Fetch and emit the round in flight with no later round queued
+        behind it; False when there is none. What it takes is observed as
+        ``fence`` and ``emit`` and kept out of the phase it fell in."""
+        rec = self._inflight
+        if rec is None:
+            return False
+        t0 = self._clock.now()
+        self._fence_emit(rec, reason)
+        self._drain_wait += self._clock.now() - t0
+        return True
+
+    def _fence_emit(self, rec: _InFlight,
+                    drained: Optional[str] = None) -> None:
+        """A round's ONE fence and its emit: the tokens, and behind them
+        whatever counts the model's layers carried out of the step. A
+        token goes to the request the row was dispatched for if that
+        request still holds the slot; otherwise the row over-ran (an EOS
+        or a reap learnt one round late) and the token is dropped. The
+        emit span's ``rows`` and ``model_stats`` are this round's, the
+        rows being what it was dispatched with."""
+        if drained is not None:
+            _ROUND_DRAINS.inc(reason=drained)
+        t2 = self._clock.now()
         with trace.span(trace.ENGINE_DECODE_FENCE):
-            # the round's ONE fence: the tokens, and behind them whatever
-            # counts the model's layers carried out of the step
-            nxt = self._fetch(self._round_out())
+            nxt = self._fetch(rec.out)
             if trace.ON:
-                trace.note(bytes=nxt.nbytes)
+                trace.note(bytes=nxt.nbytes, round=rec.seq)
+        if self._inflight is rec:
+            self._inflight = None
         t3 = self._clock.now()
-        dt = t3 - t0
+        # the round's own seconds: from its dispatch, or from the fence
+        # before it where it was queued behind that round
+        dt = t3 - max(rec.t0, self._fenced_at)
+        self._fenced_at = t3
         with trace.span(trace.ENGINE_DECODE_EMIT):
             _STEP.observe(dt)
-            nxt = self._note_model_stats(nxt)
-            self._post_decode_step()
-            emitted = rows = 0
-            for slot, req in enumerate(self._active):
-                if req is None:
-                    continue
-                self._emit(slot, req, int(nxt[slot]), active=True)
-                emitted += 1
-                rows += 1
-            self._note_decode_round(emitted, rows, dt)
+            nxt = self._note_model_stats(nxt, rec)
+            emitted = 0
+            for slot, req in rec.rows:
+                if self._active[slot] is req:
+                    self._emit(slot, req, int(nxt[slot]), active=True)
+                    emitted += 1
+            if emitted < len(rec.rows):
+                _OVERRUN_ROWS.inc(len(rec.rows) - emitted)
+            self._note_decode_round(emitted, len(rec.rows), dt)
             _BUSY.set(float(sum(r is not None for r in self._active)))
-            self._note_round_phases("decode", t0 - t_plan, t1 - t0, t2 - t1,
-                                    t3 - t2, self._clock.now() - t3)
-        return True
+        _ROUNDS.inc(kind="decode")
+        self._round_kind = "decode"
+        self._observe_phase("fence", t3 - t2)
+        self._observe_phase("emit", self._clock.now() - t3)
 
     # -- speculative decode (serving/spec.py) ------------------------------
 
@@ -1523,7 +1716,8 @@ class PagedInferenceEngine:
             idx.extend(hist[len(idx):])
         return idx.propose()
 
-    def _decode_verify(self, plan: dict, t_plan: float) -> bool:
+    def _decode_verify(self, plan: dict, t_plan: float,
+                       drained: Optional[str] = None) -> bool:
         """One speculative round: a single fused verify program scores
         ``[slots, gamma+1]`` positions (last emitted token + each row's
         padded proposal), computes acceptance ON DEVICE (:meth:`_accept`)
@@ -1535,12 +1729,18 @@ class PagedInferenceEngine:
         over the rejected tail (``new_pos = pos + count``) — K/V written
         at rejected positions stays in place as garbage beyond the
         rewound index, invisible to every mask and overwritten before it
-        could surface."""
+        could surface. Never left in flight: the next round's proposals
+        read this round's tokens (``drained`` labels the loop thread's
+        count of that)."""
         t0 = self._clock.now()
         gamma = self.spec_tokens
+        self._round_seq += 1
+        if drained is not None:
+            _ROUND_DRAINS.inc(reason=drained)
         with trace.span(trace.ENGINE_DECODE_DISPATCH):
             if trace.ON:
-                trace.note(uploads=self._stale_inputs())
+                trace.note(uploads=self._stale_inputs(),
+                           round=self._round_seq, overlapped=False)
             prop = np.zeros((self.slots, gamma), np.int32)
             plen = np.zeros((self.slots,), np.int32)
             for slot, p in plan.items():
@@ -1556,8 +1756,8 @@ class PagedInferenceEngine:
         with trace.span(trace.ENGINE_DECODE_FENCE):
             packed = self._fetch(packed)   # the round's ONE fence
             if trace.ON:
-                trace.note(bytes=packed.nbytes)
-        t3 = self._clock.now()
+                trace.note(bytes=packed.nbytes, round=self._round_seq)
+        t3 = self._fenced_at = self._clock.now()
         dt = t3 - t0
         with trace.span(trace.ENGINE_DECODE_EMIT):
             _STEP.observe(dt)
@@ -1654,13 +1854,6 @@ class PagedInferenceEngine:
             # the ceiling is spec_tokens + 1
             _SPEC_TPS.set(self.decode_tokens / self.decode_rows)
 
-    def _post_decode_step(self) -> None:
-        """Bookkeeping between the device step and token emission: the
-        1-token step put one more token into every active row's cache."""
-        for slot, req in enumerate(self._active):
-            if req is not None:
-                self._pos[slot] += 1
-
     def _emit(self, slot: int, req: Request, token: int, *,
               active: bool) -> None:
         """Record one generated token; finish + free the slot on EOS or
@@ -1714,6 +1907,15 @@ class PagedInferenceEngine:
         self._pt_dev = None
         self._admit_seq[slot] = 0
         self.kv.release(blocks)
+        rec = self._inflight
+        if rec is not None and not any(
+                self._active[at] is req for at, req in rec.rows):
+            # no row of the round in flight survives: that round would not
+            # have run had the finish been known in time, so the rng goes
+            # on from the key it had before it (the blocks released above
+            # stay untouched by it all the same: whoever takes them is
+            # queued on the device behind that round)
+            self._rng = rec.rng
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -1764,6 +1966,8 @@ class PagedInferenceEngine:
             # so that a profile's host plane shows the loop on a line of
             # its own, where its ``engine.*`` annotations name idle gaps
             trace.name_thread(f"lzy-engine-{next(_loop_ids)}")
+            # this thread's step() leaves a decode round in flight
+            self._loop_ident = threading.get_ident()
             try:
                 while not self._stop.is_set():
                     if not self.step():
@@ -1777,6 +1981,9 @@ class PagedInferenceEngine:
                             self.queue.work_available.clear()
                         _ROUND_PHASE.observe(self._clock.now() - t0,
                                              phase="park")
+                # stopped with a round in flight: its tokens are delivered
+                # before close() sheds the rows
+                self._drain("stop")
             except BaseException:  # noqa: BLE001 — engine-fatal
                 # a step()-level failure (device OOM, a poisoned compile) is
                 # engine-fatal, not request-scoped: without this the daemon
@@ -1787,6 +1994,10 @@ class PagedInferenceEngine:
                 _LOG.exception("inference engine loop died; failing all "
                                "outstanding requests")
                 self._closed = True
+                # a round in flight is dropped, not fetched: the device may
+                # be what died, and a waiter must not wait on it. Its rows
+                # fail below with the tokens they had
+                self._inflight = None
                 for req in self.queue.drain():
                     _REQUESTS.inc(status="error")
                     req.finish(error="engine loop died")
@@ -1803,6 +2014,8 @@ class PagedInferenceEngine:
                     _REQUESTS.inc(status="error")
                     req.finish(error="engine loop died")
                 _BUSY.set(0.0)
+            finally:
+                self._loop_ident = None
 
         self._thread = threading.Thread(
             target=loop, name="inference-engine", daemon=True)
@@ -2745,6 +2958,8 @@ class PagedInferenceEngine:
                 return did
             requests, self._export_requests = self._export_requests, []
             parks, self._park_requests = self._park_requests, []
+        # a chain parked or exported may be a live row's: its tokens first
+        self._drain("io")
         for kind, key, tokens, ttl_s, holder, done in parks:
             try:
                 holder["ok"] = (self._park_now(key, tokens, ttl_s)
@@ -2889,11 +3104,17 @@ class PagedInferenceEngine:
         a block some other in-flight request references."""
         from lzy_tpu.serving.kv_cache import NoFreeBlocks
 
+        # positions moved at the last dispatch, so this needs no token of
+        # a round in flight; a row that ends with that token grows nothing
+        # (its over-run write lands on the scratch block past its blocks)
+        riding = self._riding()
         for slot, req in enumerate(self._active):
-            if req is None:
+            if req is None or slot in riding:
                 continue
             pidx = int(self._pos[slot]) // self._page
-            while pidx >= len(self._slot_blocks[slot]):
+            # ``is req``: a drain below may finish the row itself
+            while self._active[slot] is req \
+                    and pidx >= len(self._slot_blocks[slot]):
                 try:
                     block = self.kv.allocate(1)[0]
                 except NoFreeBlocks:
@@ -2904,6 +3125,10 @@ class PagedInferenceEngine:
                         key = min(self._parked,
                                   key=lambda k: self._parked[k].expires_at)
                         self._release_parked(key, "pressure")
+                        continue
+                    if self._drain("squeeze"):
+                        # the victim is owed the token in flight, and a
+                        # row it finishes may free what is needed
                         continue
                     victim = self._preempt_youngest()
                     if victim == slot:
@@ -2961,31 +3186,23 @@ class PagedInferenceEngine:
     def _run_decode_step(self):
         cur, pos, mask = self._device_inputs()
         self._count_dispatch(1)
-        out = self._decode_step(self._payload, self.params, cur, pos,
-                                self._page_table_dev(), mask, self._rng)
-        if self._stat_counters:
-            *out, self._packed_out = out
-        return out
+        return self._decode_step(self._payload, self.params, cur, pos,
+                                 self._page_table_dev(), mask, self._rng)
 
-    def _round_out(self):
-        """What the round's fence fetches: the next tokens (a model whose
-        layers sow counts returns them packed behind the tokens)."""
-        return self._cur_dev if self._packed_out is None \
-            else self._packed_out
-
-    def _note_model_stats(self, fetched: np.ndarray) -> np.ndarray:
+    def _note_model_stats(self, fetched: np.ndarray,
+                          rec: _InFlight) -> np.ndarray:
         """Split what the fence fetched into the ``[slots]`` tokens and
         the model's counts, and add the counts to their counters."""
-        if self._packed_out is None:
+        if not self._stat_counters:
             return fetched
-        self._packed_out = None
         counts = [int(n) for n in fetched[self.slots:]]
         for counter, n in zip(self._stat_counters, counts):
             counter.inc(n)
         if trace.ON:
-            # on the round's emit span: what this very round's rows did,
-            # each count under its counter's name
-            trace.note(rows=sum(r is not None for r in self._active),
+            # on the emit span of the round that was FETCHED: the rows it
+            # was dispatched with (an over-run row's work was done, and is
+            # in the counts) and each count under its counter's name
+            trace.note(rows=len(rec.rows),
                        model_stats={c.name: n for c, n in zip(
                            self._stat_counters, counts)})
         return fetched[:self.slots]

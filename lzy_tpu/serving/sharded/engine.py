@@ -18,7 +18,11 @@ chunked prefill, speculation) is inherited verbatim. The contract:
 * **One fence per round.** The emit matrix (next-token ids / packed spec
   acceptances) is replicated by the ``act_vocab`` anchor before it leaves
   the jit, so the inherited ``_fetch`` is still exactly one device→host
-  sync per steady-state decode round (``host_fetches`` contract).
+  sync per steady-state decode round (``host_fetches`` contract): taken
+  at once by ``step()``, one turn late by the loop thread, which keeps
+  one round in flight as the base engine does (round n+1's inputs are
+  round n's replicated outputs; the rebuild from the host mirrors after
+  an admission follows a drain).
 * **Sharded pool, shared table.** KV pool payload leaves shard on the
   kv_heads axis; the logical block table (``_tables``/``RadixCache``) is
   host-side and shared — one admission/eviction decision drives N
