@@ -456,6 +456,13 @@ class ReplicaFleet:
             # live occupancy (dies with the replica, not banked)
             if s.kv_host_tier_blocks is not None:
                 agg["kv_host_tier_blocks"] += s.kv_host_tier_blocks
+            # a model with two kinds of page (models/serving.py, kind
+            # ``window``): the second kind's pages, live sums; a fleet of
+            # models with one kind has none of these keys
+            for key in ("kv_window_blocks_live", "kv_window_blocks_free",
+                        "kv_window_pages_released"):
+                if getattr(s, key, None) is not None:
+                    agg[key] = agg.get(key, 0) + getattr(s, key)
         return agg
 
     def aggregate_tenants(self) -> Dict[str, Dict[str, int]]:

@@ -57,7 +57,8 @@ def row_mask(valid_len, b: int, t: int):
 
 
 def held_weights(layer: nn.Module, um, real, *, n_routed: int, top_k: int,
-                 held: Tuple[int, int], scaling: float, other_stats: int = 0):
+                 held: Tuple[int, int], scaling: float, other_stats: int = 0,
+                 choice_bias: bool = True):
     """``[M, held]`` float32: each row's weight for each expert held here
     (0 where it did not choose it, or is not real). Called from the expert
     layer's compact method: the parameters ``router`` ``[D, n_routed]`` and
@@ -70,11 +71,14 @@ def held_weights(layer: nn.Module, um, real, *, n_routed: int, top_k: int,
     n_held = hi - lo
     wr = layer.param("router", nn.initializers.normal(0.02),
                      (um.shape[-1], n_routed), f32)
-    bias = layer.param("router_bias", nn.initializers.normal(0.02),
-                       (n_routed,), f32)
     scores = jax.nn.sigmoid(jnp.dot(
         um.astype(f32), wr, precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(scores + bias, top_k)              # [M, k]
+    if choice_bias:
+        bias = layer.param("router_bias", nn.initializers.normal(0.02),
+                           (n_routed,), f32)
+        _, chosen = jax.lax.top_k(scores + bias, top_k)          # [M, k]
+    else:
+        _, chosen = jax.lax.top_k(scores, top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) \
         * scaling
@@ -101,7 +105,11 @@ class GatedExperts(nn.Module):
     the same form: Solar-Open2's expert layer and the DeepSeek-V3 family's.
     ``cfg`` gives ``n_routed_experts``, ``experts_held``, ``n_held``,
     ``top_k``, ``routed_scaling``, ``expert_width``, ``shared_width``,
-    ``dtype`` and ``param_dtype``; ``other_stats`` as :func:`held_weights`."""
+    ``dtype`` and ``param_dtype``; ``other_stats`` as :func:`held_weights`.
+    Two answers only some configurations give: ``router_bias`` False (no
+    correction bias on the choice) and ``shared_scale`` (the shared part is
+    several experts side by side, ``shared_width`` their widths together,
+    and their outputs are averaged, not summed: 1 / how many; Command A+)."""
     cfg: Any
     other_stats: int = 0
 
@@ -116,7 +124,8 @@ class GatedExperts(nn.Module):
         weights = held_weights(
             self, um, real, n_routed=cfg.n_routed_experts, top_k=cfg.top_k,
             held=cfg.experts_held, scaling=cfg.routed_scaling,
-            other_stats=self.other_stats)
+            other_stats=self.other_stats,
+            choice_bias=getattr(cfg, "router_bias", True))
         up_shape = (cfg.n_held, dm, cfg.expert_width)
         wg = self.param("experts_gate", normal(), up_shape, cfg.param_dtype)
         wu = self.param("experts_up", normal(), up_shape, cfg.param_dtype)
@@ -131,6 +140,7 @@ class GatedExperts(nn.Module):
         hid = jax.nn.silu(dense(cfg.shared_width, "shared_gate", cfg,
                                  f32)(um)) \
             * dense(cfg.shared_width, "shared_up", cfg, f32)(um)
-        out = routed + dense(dm, "shared_down", cfg, f32)(
-            hid.astype(cfg.dtype))
+        shared = dense(dm, "shared_down", cfg, f32)(hid.astype(cfg.dtype))
+        scale = getattr(cfg, "shared_scale", 1.0)
+        out = routed + (shared if scale == 1.0 else shared * scale)
         return out.astype(cfg.dtype).reshape(b, t, dm)

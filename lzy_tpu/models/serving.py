@@ -3,8 +3,9 @@
 the configuration object and by the module class it builds;
 ``models/llama.py``'s ``LlamaConfig`` / ``Llama``,
 ``models/nemotron_h.py``'s ``NemotronHConfig`` / ``NemotronH``,
-``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2`` and
-``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3`` all do.
+``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2``,
+``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3`` and
+``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe`` all do.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -43,6 +44,17 @@ engine reads neither), and:
   the engine names no kernel), so that what the lowering refuses is refused
   at construction; with no pool named, the kernels beside the read only.
 
+A configuration whose module has ``window`` leaves (below) gives three
+answers more, and the engine asks them of no other: ``kv_window``, the
+positions such a leaf keeps readable behind a row's newest (a query at
+``p`` reads ``p - kv_window < j <= p`` there and nothing older, ever);
+``window_layers``, the layers that keep one (``kv_layers`` counts the layers
+of ``paged`` leaves only, and ``kv_token_bytes`` is a layer's cost of either
+kind); and ``paged_model`` / ``check_kernels`` take ``window_pages=`` /
+``window_blocks=``, the second pool's size. Its module's ``__call__`` takes
+``window_table=`` beside ``page_table=``: the same shape, addressing the
+``window`` leaves.
+
 **The module class** declares ``CACHE_KINDS`` (cache leaf name -> kind; a
 leaf it does not name is ``paged``) and ``STATS``: the counters its
 ``stats`` collection feeds, in the order of the vector its layers sow
@@ -62,6 +74,19 @@ counts is told which positions are real (``valid_len``).
   speculated position rewound by moving an index: every mechanism moves
   pages by block id and reads no shape past the page axis, so a latent
   leaf is served by all of them (``docs/serving.md`` has the table).
+- ``window``: a pool of pages like ``paged``, with a second lifetime: a
+  token's entry stops being read ``kv_window`` positions behind the row's
+  newest, so the engine returns a page that lies wholly behind the window
+  of everything it has dispatched (a round in flight, the slot's prefill
+  job) to an allocator of its own (``serving/kv_cache.py``
+  ``WindowPages``), where another row can take it, and the row's window
+  table reads scratch there. A row holds at most
+  ``ceil((kv_window + chunk) / page) + 1`` such pages however long its
+  context. Block counts, page tables and free lists are apart from the
+  ``paged`` leaves'; a request is admitted when both can hold it. What was
+  read through a window cannot be shared by a prefix's tokens (the pages
+  behind it are gone), so the radix cache is off and the mechanisms that
+  move pages by tokens refuse such a model by name (``docs/serving.md``).
 - ``state``: ``[slots, ...]``, one row a slot (a recurrence's state, a
   convolution's window). A prefill job carries its own batch-1 row between
   chunks and the engine splices it into the slot's row when the prompt is
@@ -75,7 +100,9 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
-INDEX, PAGED, STATE = "index", "paged", "state"
+INDEX, PAGED, STATE, WINDOW = "index", "paged", "state", "window"
+#: the kinds whose leaves are pools of pages shared by all slots
+POOLS = (PAGED, WINDOW)
 
 
 def leaf_kind(model: Any, path) -> str:

@@ -191,7 +191,8 @@ def paged_scatter_index(page_table: jax.Array, positions: jax.Array,
 
 
 def _lax_paged_attention(q, k_pool, v_pool, page_table, positions, *,
-                         dtype, quant: Optional[KVQuant]):
+                         dtype, quant: Optional[KVQuant],
+                         window: Optional[int] = None):
     """Gather-attention in EXACTLY the op sequence of the dense cache's
     read. This is the bit-exactness anchor: ``models/llama.py``'s dense
     decode branch (what ``models/generate.py``, the oracle, runs) makes
@@ -220,6 +221,10 @@ def _lax_paged_attention(q, k_pool, v_pool, page_table, positions, *,
     ) * (d ** -0.5)                                   # [B, KV, G, T, L]
     visible = (jnp.arange(L)[None, None, None, None, :]
                <= positions[:, None, None, :, None])
+    if window is not None:
+        # a windowed layer: query i sees keys j with i - window < j <= i
+        visible &= (jnp.arange(L)[None, None, None, None, :]
+                    > positions[:, None, None, :, None] - window)
     s = jnp.where(visible, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(dtype)
     return jnp.einsum("bkgtl,blkd->btkgd", p, vals)
@@ -406,6 +411,247 @@ def lower_pallas_for_tpu(*, batch: int, n_heads: int, n_kv_heads: int,
     jax.jit(read).trace(
         sds((batch, t, n_heads, head_dim), dtype), pool, pool,
         sds((batch, pages_per_seq), jnp.int32), sds((batch, t), jnp.int32),
+    ).lower(lowering_platforms=("tpu",))
+
+
+# -- pallas kernel for wide groups, decode and chunk -----------------------------
+
+#: ``lzy_kernel_dispatch_total{path}`` labels of the reads of a pool laid out
+#: ``[n_blocks, page, KV x D]`` (:func:`paged_group_attention`)
+GROUP_DECODE_PATH = "group_decode_pallas"
+GROUP_CHUNK_PATH = "group_prefill_pallas"
+
+#: query positions a grid cell of the chunk read takes (x the group's heads:
+#: the rows of a q tile a key-value head) and pooled positions scored a block
+_CHUNK_TILE = 32
+_GROUP_BLOCK = 512
+
+
+def group_path(kernel: str, *, t: int) -> str:
+    """The label of a program over ``t`` query positions a row that reads
+    through :func:`paged_group_attention`."""
+    if kernel != "pallas":
+        return kernel
+    return GROUP_DECODE_PATH if t <= MAX_Q_TOKENS else GROUP_CHUNK_PATH
+
+
+def _group_kernel(start_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                  v_buf, sems, m_ref, l_ref, acc_ref, *, tq, group, kv_heads,
+                  d, page, pages_per_seq, block_pages, window, scale):
+    """One grid cell: ``tq`` consecutive query positions of batch row ``b``
+    against the pages its queries can see, from the first page that can hold
+    a visible key (``window``: a query at ``p`` sees ``p - window < j <= p``;
+    page 0 where there is none) to the page of the cell's last query, a
+    block of pages in flight while the block before it is scored, folded
+    into a running max / sum / weighted value a key-value head. Scores are
+    ``[tq x group, block]`` a head and never the whole context.
+
+    A page arrives as ``[page, KV x D]``, the pool's own layout: head
+    ``g``'s keys are the lanes ``g x D .. (g + 1) x D`` of every row, so a
+    head's ``group`` query heads are scored against their own keys only,
+    with no relayout of the page and no masked arithmetic (the decode kernel
+    above scores every head against every row: at a group of 16 that is
+    eight times the exponentials). The q tile is ``[KV, tq x group, D]``,
+    rows ordered (position, head in the group).
+
+    Numerics as the decode kernel's: float32 scores, max, sum and
+    accumulator, scaled after the dot, probabilities cast to the pool's
+    dtype before the value contraction."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    rows = tq * group
+    cols = block_pages * page
+    first = start_ref[b] + i * tq
+    n_pages = lax.div(jnp.maximum(first + tq - 1 + page, 0), page)
+    lo = 0 if window is None else lax.div(
+        jnp.maximum(first - window + 1, 0), page)
+    n_blocks = lax.div(n_pages - lo + block_pages - 1, block_pages)
+    row_pos = first + lax.div(
+        lax.broadcasted_iota(jnp.int32, (rows, 1), 0), group)
+    col = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+
+    @pl.when((b == 0) & (i == 0))
+    def _():
+        # a partial block leaves rows of the buffer unwritten; their
+        # probabilities are 0, and 0 x whatever VMEM held must be 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def for_pages(j, slot, op):
+        for k in range(block_pages):
+            at = lo + j * block_pages + k
+
+            @pl.when(at < n_pages)
+            def _():
+                pid = pt_ref[b * pages_per_seq + at]
+                dst = pl.ds(k * page, page)
+                op(pltpu.make_async_copy(
+                    k_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]))
+                op(pltpu.make_async_copy(
+                    v_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
+
+    for_pages(0, 0, lambda c: c.start())
+
+    def body(j, _):
+        slot = lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_blocks)
+        def _():
+            for_pages(j + 1, 1 - slot, lambda c: c.start())
+
+        for_pages(j, slot, lambda c: c.wait())
+        seen = row_pos - (lo + j * block_pages) * page
+        visible = col <= seen
+        if window is not None:
+            visible &= col > seen - window
+        for g in range(kv_heads):
+            lanes = slice(g * d, (g + 1) * d)
+            s = lax.dot_general(
+                q_ref[g], k_buf[slot, :, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [rows, cols]
+            s = jnp.where(visible, s, _NEG_INF)
+            m = m_ref[g]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+            l_ref[g] = alpha * l_ref[g] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[g] = alpha * acc_ref[g] + lax.dot_general(
+                p.astype(v_buf.dtype), v_buf[slot, :, lanes],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_ref[g] = m_new
+        return 0
+
+    lax.fori_loop(0, n_blocks, body, 0)
+    l = l_ref[...]
+    # a row that sees nothing (position -1) reads nothing and returns 0
+    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def _pallas_group_attention(q, k_pool, v_pool, page_table, start, *,
+                            window: Optional[int], interpret: bool):
+    """jitted so that the layers of one kind, which all make this call at one
+    shape, trace and lower the kernel once a program."""
+    b, t, h, d = q.shape
+    n, page, width = k_pool.shape
+    kv_heads = width // d
+    pages = page_table.shape[1]
+    group = h // kv_heads
+    decode = t <= MAX_Q_TOKENS
+    tq = t if decode else min(t, _CHUNK_TILE)
+    if t % tq:
+        raise ValueError(
+            f"a prefill chunk of {t} positions is not whole tiles of {tq}")
+    rows = tq * group
+    block_pages = max(1, min(pages, _GROUP_BLOCK // page))
+    cols = block_pages * page
+    size = jnp.dtype(k_pool.dtype).itemsize
+    # what the kernel keeps in VMEM: the q and output tiles twice, the two
+    # page buffers twice, the accumulator, the max and the sum (a row of
+    # lanes each), a block's scores and probabilities
+    vmem = (4 * kv_heads * rows * d * size + 4 * cols * width * size
+            + kv_heads * rows * (d + 2 * 128) * 4 + 4 * rows * cols * 4)
+    kernel = functools.partial(
+        _group_kernel, tq=tq, group=group, kv_heads=kv_heads, d=d, page=page,
+        pages_per_seq=pages, block_pages=block_pages, window=window,
+        scale=d ** -0.5)
+    tile = pl.BlockSpec((None, kv_heads, rows, d),
+                        lambda bi, i, *_: (bi, 0, i, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    # [B, T, KV, G, D] -> [B, KV, T x G, D]: a head's rows together
+    qt = q.astype(k_pool.dtype).reshape(b, t, kv_heads, group, d).transpose(
+        0, 2, 1, 3, 4).reshape(b, kv_heads, t * group, d)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, t // tq),
+            in_specs=[tile, pool, pool],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((2, cols, width), k_pool.dtype),
+                pltpu.VMEM((2, cols, width), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+                pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+                pltpu.VMEM((kv_heads, rows, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, kv_heads, t * group, d),
+                                       k_pool.dtype),
+        # the V buffer is zeroed by the first cell and kept by the rest
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20, vmem + (8 << 20))),
+        interpret=_interpret.tpu_params(interpret),
+        name="paged_group_decode" if decode else "paged_group_prefill",
+    )(start.astype(jnp.int32).reshape(-1),
+      page_table.astype(jnp.int32).reshape(-1), qt, k_pool, v_pool)
+    return out.reshape(b, kv_heads, t, group, d).transpose(0, 2, 1, 3, 4)
+
+
+def paged_group_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                          page_table: jax.Array, start: jax.Array, *,
+                          window: Optional[int] = None, kernel: str = "lax",
+                          interpret: Optional[bool] = None) -> jax.Array:
+    """Attention through the page table for a model with many query heads a
+    key-value head (a group of 16: ``models/cohere2_moe.py``), whose pools
+    are ``[n_blocks, page, KV x D]``: a token's keys (or values) of all
+    heads side by side in one row, so that a head's are a slice of lanes.
+    (Why another layout than ``[n_blocks, page, KV, D]``: the compiler
+    copies the whole pool to turn one into the other, 287 MB a leaf a call
+    at the Command A+ widths; one layout has to serve decode and chunk.)
+
+    ``q`` ``[B, T, H, D]`` post-RoPE queries at the consecutive positions
+    ``start[b] + t`` (``start`` ``[B]`` int32); ``page_table`` ``[B, P]``;
+    ``window`` (static): a query at ``p`` sees keys ``p - window < j <= p``
+    (None: every key up to itself), and pages wholly behind the window are
+    never named: the engine has returned them and the table reads scratch
+    there. ``"pallas"``: one kernel body under two names of a device trace,
+    ``paged_group_decode`` (``T <= MAX_Q_TOKENS``: a grid cell a row) and
+    ``paged_group_prefill`` (a chunk cut into tiles of ``_CHUNK_TILE``
+    positions), which walks the pages from the first visible one to the
+    cell's own and keeps a running softmax: what it costs follows the
+    visible context, never the table's width, and no ``[heads, chunk,
+    context]`` scores exist. ``"lax"``: the gathered table scored whole, its
+    oracle. Both are judged against float32 attention within
+    :data:`TOLERANCE`. Returns ``[B, T, KV, G, D]``."""
+    if kernel not in ("lax", "pallas"):
+        raise ValueError(
+            f"unknown paged-attention kernel {kernel!r}; known: lax, pallas")
+    if kernel == "pallas":
+        return _pallas_group_attention(
+            q, k_pool, v_pool, page_table, start, window=window,
+            interpret=_interpret.resolve(interpret))
+    n, page, width = k_pool.shape
+    d = q.shape[-1]
+    positions = start[:, None] + jnp.arange(q.shape[1], dtype=jnp.int32)
+    return _lax_paged_attention(
+        q, k_pool.reshape(n, page, width // d, d),
+        v_pool.reshape(n, page, width // d, d), page_table, positions,
+        dtype=k_pool.dtype, quant=None, window=window)
+
+
+def lower_group_for_tpu(*, batch: int, t: int, n_heads: int, n_kv_heads: int,
+                        head_dim: int, n_blocks: int, page_size: int,
+                        pages_per_seq: int, dtype: Any,
+                        window: Optional[int]) -> None:
+    """Lower :func:`paged_group_attention`'s kernel for a TPU at these
+    shapes, with no device and no compile, and let the lowering's error
+    out."""
+    sds = jax.ShapeDtypeStruct
+    pool = sds((n_blocks, page_size, n_kv_heads * head_dim), dtype)
+
+    def read(q, k_pool, v_pool, page_table, start):
+        return _pallas_group_attention(
+            q, k_pool, v_pool, page_table, start, window=window,
+            interpret=False)
+
+    jax.jit(read).trace(
+        sds((batch, t, n_heads, head_dim), dtype), pool, pool,
+        sds((batch, pages_per_seq), jnp.int32), sds((batch,), jnp.int32),
     ).lower(lowering_platforms=("tpu",))
 
 

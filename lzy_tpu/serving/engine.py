@@ -263,6 +263,14 @@ class StateLeavesUnsupported(ValueError):
     names the mechanism."""
 
 
+class WindowLeavesUnsupported(ValueError):
+    """A mechanism that moves, shares or rewinds pages by a prefix's tokens
+    was asked of a model some of whose paged leaves lose their tokens
+    behind a window (``models/serving.py``, kind ``window``): the pages
+    behind it have gone back to their pool. The message names the
+    mechanism."""
+
+
 # what a prefill program is told about its chunk, one row of four int32 a
 # chunk of the job's plan, written into the job's buffer when it is staged:
 # where the chunk starts (the position of its first token in the prompt,
@@ -306,6 +314,10 @@ class _PrefillJob:
     # in the decode tree are not touched until the prompt is done. None
     # until the job's first program, which starts them from zero
     state: Any = None
+    # window leaves (models/serving.py): the job's own row of window pages
+    # (``kv_cache.WindowRow``), grown and shed chunk by chunk; the slot's
+    # once the prompt is done
+    window: Any = None
 
 
 @dataclasses.dataclass
@@ -389,6 +401,13 @@ class EngineStats:
     # bytes one cached token costs the pool over all its layers (keys and
     # values a head, or one vector with no head axis: the model's answer)
     kv_token_bytes: Optional[int] = None
+    # a model with ``window`` leaves (models/serving.py) has two kinds of
+    # page: every ``kv_blocks_*`` above counts the ``paged`` kind (pages
+    # that keep every token), these the ``window`` kind; None without one
+    kv_window_blocks_total: Optional[int] = None
+    kv_window_blocks_free: Optional[int] = None
+    kv_window_blocks_live: Optional[int] = None
+    kv_window_pages_released: Optional[int] = None
 
     def doc(self) -> dict:
         return {k: v for k, v in dataclasses.asdict(self).items()
@@ -422,6 +441,7 @@ class PagedInferenceEngine:
         page_size: int = 16,
         kv_blocks: Optional[int] = None,
         kv_pool_bytes: Optional[int] = None,
+        kv_window_blocks: Optional[int] = None,
         kv_quant: Optional[str] = None,
         # tombstone: the benchmark's configuration files pass
         # ``"native_attention": true``; the benchmark issue that takes it
@@ -448,7 +468,8 @@ class PagedInferenceEngine:
         from lzy_tpu.ops.interpret import resolve as pallas_interpreted
         from lzy_tpu.ops.paged_attention import (
             DISPATCHES, QUANT_BLOCKS_RESIDENT, default_kernel)
-        from lzy_tpu.serving.kv_cache import RadixCache
+        from lzy_tpu.serving.kv_cache import (
+            RadixCache, WindowPages, window_bound)
 
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
@@ -589,6 +610,23 @@ class PagedInferenceEngine:
         self._quant_resident = QUANT_BLOCKS_RESIDENT
         self._quant_resident_seen = 0
         self._quant_resident_lock = threading.Lock()
+        # a model with ``window`` leaves has a second kind of page, with a
+        # pool, tables and a free list of its own (models/serving.py)
+        window = getattr(base, "kv_window", None)
+        if window is None and kv_window_blocks is not None:
+            raise ValueError(
+                f"kv_window_blocks: {type(base).__name__} has no window "
+                f"leaves")
+        if window is not None:
+            # every slot's most, and the scratch block
+            most = slots * window_bound(window, self.prefill_chunk,
+                                        page_size, self._pages_per_seq) + 1
+            if kv_pool_bytes is not None and kv_window_blocks is None:
+                kv_window_blocks, kv_pool_bytes = self._divide_pool(
+                    kv_pool_bytes, base, most,
+                    slots * self._pages_per_seq + 1, page_size)
+            elif kv_window_blocks is None:
+                kv_window_blocks = most
         if kv_pool_bytes is not None:
             if kv_blocks is not None:
                 raise ValueError(
@@ -613,8 +651,15 @@ class PagedInferenceEngine:
             # pool among them, it refuses here, before a pool exists
             base.check_kernels(
                 slots=slots, kv_blocks=kv_blocks, page_size=page_size,
-                pages_per_seq=self._pages_per_seq, kv_quant=kv_quant)
+                pages_per_seq=self._pages_per_seq, kv_quant=kv_quant,
+                **({} if window is None
+                   else {"window_blocks": kv_window_blocks}))
         self.kv = RadixCache(kv_blocks, page_size)
+        # the window kind's allocator and each slot's row of it; None for
+        # a model with one kind of page
+        self._win = None if window is None else WindowPages(
+            kv_window_blocks, page_size, window, self._pages_per_seq,
+            self.prefill_chunk)
         # tiered KV cache (serving/kv_tier.py): radix eviction DEMOTES
         # block payloads to pinned host RAM (and onward to storage)
         # instead of dropping them; admission PROMOTES them back. The
@@ -668,6 +713,11 @@ class PagedInferenceEngine:
         # _page_table_dev); every _tables mutation site sets it to None
         self._pt_dev = None
         self._slot_blocks: List[List[int]] = [[] for _ in range(slots)]
+        if self._win is not None:
+            # the window kind's twin of ``_tables`` / ``_slot_blocks``; a
+            # write to either table dirties the one device mirror
+            self._win_tables = np.zeros_like(self._tables)
+            self._win_rows = [self._win.row() for _ in range(slots)]
         # per-row cached-token counts live in _pos
         self._admit_seq = np.zeros((slots,), np.int64)  # admission order
         self._admissions = 0
@@ -727,6 +777,27 @@ class PagedInferenceEngine:
 
         if self._has_state:
             self._refuse_for_state()
+        if self._win is not None:
+            self._refuse_for_window()
+
+    @staticmethod
+    def _divide_pool(pool_bytes: int, base: Any, most_window: int,
+                     most_paged: int, page_size: int) -> tuple:
+        """``kv_pool_bytes`` between the two kinds of page of a model with
+        ``window`` leaves: ``(window blocks, bytes for the paged kind)``.
+        Each kind gets what ``slots`` rows at ``max_seq_len`` come to at
+        their most (``most_window``, ``most_paged`` blocks: a row never
+        holds more, and with the prefix cache off nothing else would), if
+        the budget covers both; where it does not, each gets its share of
+        the budget in proportion to that."""
+        token = base.kv_token_bytes(None)
+        per_window = page_size * base.window_layers * token
+        want_window = most_window * per_window
+        want_paged = most_paged * page_size * base.kv_layers * token
+        if want_window + want_paged <= pool_bytes:
+            return most_window, want_paged
+        for_window = pool_bytes * want_window // (want_window + want_paged)
+        return max(2, for_window // per_window), pool_bytes - for_window
 
     # -- cache payload/treedef split ---------------------------------------
 
@@ -1248,6 +1319,7 @@ class PagedInferenceEngine:
         _PREFILL_ROUNDS.inc()
         if trace.ON:
             trace.note(request=req.id, chunks=job.next_chunk - chunks0,
+                       start=job.matched + tokens0,
                        tokens=job.done - tokens0, finished=finished,
                        width=sum(w for _, _, w
                                  in job.plan[chunks0:job.next_chunk]))
@@ -1273,6 +1345,8 @@ class PagedInferenceEngine:
         # during its own prefill, same as any freed slot's blocks)
         self.kv.release(job.table)
         job.table = []
+        if job.window is not None:
+            self._win.release(job.window)
         self._leave_state_rows(job)
 
     def _run_prefill_chunks(self, job: _PrefillJob) -> tuple:
@@ -1328,9 +1402,19 @@ class PagedInferenceEngine:
             PREFILL_CALLS.inc()
             self._rng, key = self._split_rng(self._rng)
         payload = self._payload
+        tables = ()
+        if job.window is not None:
+            # the chunk's own pages are taken and the pages wholly behind
+            # its first query's window go back, before the dispatch; the
+            # table rides in it (a copy: the row's array changes under the
+            # next chunk)
+            start = job.matched + job.plan[job.next_chunk][0]
+            self._win.cover(job.window, start - self._win.window,
+                            start + take)
+            tables = (job.window.table[None].copy(),)
         pool, state, job.inputs, first = self._prefill_step(
             [payload[i] for i in self._pool_at], job.state or [],
-            job.inputs, self.params, key, width=width)
+            job.inputs, self.params, key, *tables, width=width)
         for i, leaf in zip(self._pool_at, pool):
             payload[i] = leaf
         if self._has_state:
@@ -1907,6 +1991,9 @@ class PagedInferenceEngine:
         self._pt_dev = None
         self._admit_seq[slot] = 0
         self.kv.release(blocks)
+        if self._win is not None:
+            self._win.release(self._win_rows[slot])
+            self._win_tables[slot, :] = 0
         rec = self._inflight
         if rec is not None and not any(
                 self._active[at] is req for at, req in rec.rows):
@@ -2129,6 +2216,14 @@ class PagedInferenceEngine:
             kv_parked_blocks=sum(len(c.blocks)
                                  for c in self._parked.values()),
         )
+        if self._win is not None:
+            s = dataclasses.replace(
+                s,
+                kv_window_blocks_total=self._win.pool.n_blocks - 1,
+                kv_window_blocks_free=self._win.pool.free_count(),
+                kv_window_blocks_live=self._win.live(),
+                kv_window_pages_released=self._win.released,
+            )
         if self.spec_tokens > 0:
             rate = (self.spec_accepted / self.spec_proposed
                     if self.spec_proposed else 0.0)
@@ -2217,6 +2312,35 @@ class PagedInferenceEngine:
             raise StateLeavesUnsupported(
                 f"{mechanism}: it moves or pins pages by a prefix's tokens, "
                 f"and this model's per-slot state is not in any page")
+        if self._win is not None:
+            raise WindowLeavesUnsupported(
+                f"{mechanism}: it moves or pins pages by a prefix's tokens, "
+                f"and this model's window leaves have returned the pages "
+                f"behind the window")
+
+    def _refuse_for_window(self) -> None:
+        """A model with ``window`` leaves (``models/serving.py``): what
+        shares a prefix is turned off, what moves or rewinds cache by index
+        and pages is refused, by name. Each could be made to work over both
+        kinds of page (a hit would have to bring the window's worth of
+        window pages with it); none has been."""
+        if self.spec_tokens > 0:
+            raise WindowLeavesUnsupported(
+                f"speculative decoding (spec_tokens={self.spec_tokens}): a "
+                f"rejected draft is rewound by moving an index, and a page "
+                f"that went back behind the drafted positions' window "
+                f"cannot be called back")
+        if self.kv_tier is not None:
+            raise WindowLeavesUnsupported(
+                "the tiered KV cache (kv_host_tier_bytes / kv_storage_tier "
+                "/ kv_tier): a demoted prefix is pages of one kind")
+        # a matched prefix would skip prefill for tokens whose window
+        # pages nobody kept
+        self.kv.reuse = False
+        _LOG.info(
+            "%s has window leaves: the radix prefix cache is off (every "
+            "match is 0 tokens, finished prompts are not inserted)",
+            type(self._model).__name__)
 
     # -- construction --------------------------------------------------------
 
@@ -2231,13 +2355,17 @@ class PagedInferenceEngine:
         # one module for decode rounds and batch-1 prefill: prefill reuses
         # the SAME pool arrays with a batch-1 index (and, where the model
         # has them, the job's own batch-1 state rows)
+        second = {} if self._win is None \
+            else {"window_pages": self._win.pool.n_blocks}
         self._model = self._prefill_model = base.paged_model(
             page_size=self._page, kv_pages=self._kv_blocks,
-            kernel=self._paged_kernel, kv_quant=self._kv_quant)
+            kernel=self._paged_kernel, kv_quant=self._kv_quant, **second)
         dummy_pt = jnp.zeros((slots, pages), jnp.int32)
+        tables = {"page_table": dummy_pt} if self._win is None \
+            else {"page_table": dummy_pt, "window_table": dummy_pt}
         self._adopt_cache(init_cache(lambda: self._model.init(
             jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
-            page_table=dummy_pt)))
+            **tables)))
         self._build_steps()
 
     def _build_steps(self) -> None:
@@ -2254,9 +2382,14 @@ class PagedInferenceEngine:
         tells_real = self._tells_real
         mutable = ["cache", "stats"] if has_stats else ["cache"]
 
-        def prefill_step(pool, state, job, params, key, width):
+        def prefill_step(pool, state, job, params, key, window_table=None,
+                         *, width):
+            # ``window_table``: a model with window leaves only (the job's
+            # row of the second kind of page, as this chunk needs it)
+            second = {} if window_table is None \
+                else {"window_table": window_table}
             return self._prefill_program(pool, state, job, params, key,
-                                         width)
+                                         width, **second)
 
         self._prefill_step = jax.jit(
             prefill_step, static_argnames=("width",),
@@ -2268,11 +2401,17 @@ class PagedInferenceEngine:
         def decode_step(payload, params, cur, pos, page_table,
                         greedy_mask, rng):
             cache = self._assemble_cache(payload, pos)
+            # a model with window leaves is handed both kinds' tables
+            tables = {"page_table": page_table}
+            if isinstance(page_table, tuple):
+                page_table, window_table = page_table
+                tables = {"page_table": page_table,
+                          "window_table": window_table}
             real = {"valid_len": (page_table[:, 0] != 0).astype(jnp.int32)} \
                 if tells_real else {}
             logits, updated = self._model.apply(
                 {"params": params, "cache": cache}, cur[:, None],
-                page_table=page_table, mutable=mutable, **real)
+                mutable=mutable, **tables, **real)
             nxt, rng = self._pick_next(logits[:, -1], greedy_mask, rng)
             payload, new_pos = self._split_cache(updated["cache"])
             if not has_stats:
@@ -2387,7 +2526,7 @@ class PagedInferenceEngine:
         first = self._pick_first(last, ctl[_CTL_GREEDY] != 0, key)
         out = jax.tree_util.tree_leaves(updated["cache"])
         return ([leaf for leaf, kind in zip(out, self._leaf_kinds)
-                 if kind == serving.PAGED],
+                 if kind in serving.POOLS],
                 [leaf for leaf, kind in zip(out, self._leaf_kinds)
                  if kind == serving.STATE],
                 job.at[0, 0].add(1), first)
@@ -2446,6 +2585,9 @@ class PagedInferenceEngine:
             # parked tool-gap chains yield to live admissions: shed them
             # (soonest expiry first) before making anyone wait
             self._shed_parked_for_pressure(need)
+        if self._win is not None and self._win.available() \
+                < self._win.need(len(req.prompt)):
+            return False       # both kinds of page must hold the prompt
         return self.kv.available() >= need
 
     def _admit_verdict(self, req: Request) -> str:
@@ -2510,8 +2652,18 @@ class PagedInferenceEngine:
             # a reused slot starts from zero state: the job's first
             # program zeroes the rows it is handed
             _STATE_RESETS.inc()
+        row = None
+        if self._win is not None:
+            # the job's row of window pages: what it will hold at its most
+            # is set aside now, taken and shed chunk by chunk
+            row = self._win.row()
+            try:
+                self._win.reserve(row, t0)
+            except Exception:
+                self.kv.release(blocks + owned)
+                raise
         return _PrefillJob(req=req, slot=slot, plan=plan, matched=matched,
-                           table=blocks + owned)
+                           table=blocks + owned, window=row)
 
     def _upload(self, array):
         """A job's buffer as its first program takes it: the host array
@@ -2580,6 +2732,13 @@ class PagedInferenceEngine:
         self._tables[slot, len(table):] = 0
         self._pt_dev = None
         self._slot_blocks[slot] = list(table)
+        if job.window is not None:
+            # the slot's row from here on; what was set aside for the
+            # prompt and not taken is anybody's again
+            self._win.unreserve(job.window)
+            self._win_rows[slot] = job.window
+            self._win_tables[slot] = job.window.table
+            job.window = None
         self._admissions += 1
         self._admit_seq[slot] = self._admissions
         self._finish_prefill(slot, req, self._prefill_fence(first))
@@ -3137,6 +3296,21 @@ class PagedInferenceEngine:
                 self._slot_blocks[slot].append(block)
                 self._tables[slot, len(self._slot_blocks[slot]) - 1] = block
                 self._pt_dev = None
+            # the second kind of page: this round writes position ``pos``,
+            # and the oldest position anything dispatched still reads is
+            # the round in flight's, ``pos - 1 - window + 1``
+            while self._win is not None and self._active[slot] is req:
+                pos, row = int(self._pos[slot]), self._win_rows[slot]
+                try:
+                    if self._win.cover(row, pos - self._win.window, pos + 1):
+                        self._win_tables[slot] = row.table
+                        self._pt_dev = None
+                    break
+                except NoFreeBlocks:
+                    if self._drain("squeeze"):
+                        continue
+                    if self._preempt_youngest() == slot:
+                        break
 
     def _preempt_youngest(self) -> int:
         """Fail the most recently admitted active request (its waiter gets
@@ -3170,7 +3344,8 @@ class PagedInferenceEngine:
         ``_tables`` buffer and later host writes would mutate the
         device view mid-flight."""
         if self._pt_dev is None:
-            self._pt_dev = jnp.array(self._tables)
+            self._pt_dev = jnp.array(self._tables) if self._win is None \
+                else (jnp.array(self._tables), jnp.array(self._win_tables))
         return self._pt_dev
 
     def _count_dispatch(self, t: int) -> None:
@@ -3220,6 +3395,8 @@ class PagedInferenceEngine:
         pos)`` for verify."""
         pt = jax.ShapeDtypeStruct((self.slots, self._pages_per_seq),
                                   jnp.int32)
+        if self._win is not None:
+            pt = (pt, pt)
         step.lower(payload, self.params, *mids, pt, mask, rng).compile()
         if self._has_state and step is self._decode_step:
             # the splice of a finished prefill's state rows, one program

@@ -43,6 +43,8 @@ import dataclasses
 import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
@@ -608,8 +610,6 @@ def kv_block_bytes(*, page_size: int, n_kv_heads: int, head_dim: int,
     bytes. Quantization sidecars (per-position scale/zero-point,
     :func:`kv_quant_sidecar_bytes`) are metadata accounted OUTSIDE the
     payload budget, like the page tables themselves."""
-    import numpy as np
-
     elem = 1 if kv_quant == "int8" else np.dtype(dtype).itemsize
     return 2 * n_layers * page_size * n_kv_heads * head_dim * elem
 
@@ -639,3 +639,151 @@ def blocks_for_bytes(pool_bytes: int, *, page_size: int, n_kv_heads: int,
                          head_dim=head_dim, n_layers=n_layers,
                          dtype=dtype, kv_quant=kv_quant)
     return max(2, pool_bytes // per)
+
+
+# -- pages with a second lifetime: behind a window they go back ----------------------
+
+_WINDOW_BLOCKS = REGISTRY.gauge(
+    "lzy_kv_window_blocks",
+    "window-page pool capacity (scratch block included): pages of the "
+    "layers that read only their last positions")
+_WINDOW_FREE = REGISTRY.gauge(
+    "lzy_kv_window_blocks_free", "window pages on the free list")
+_WINDOW_LIVE = REGISTRY.gauge(
+    "lzy_kv_window_blocks_live", "window pages held by rows and prefill jobs")
+_WINDOW_RELEASED = REGISTRY.counter(
+    "lzy_kv_window_pages_released_total",
+    "window pages returned to their pool because they fell wholly behind "
+    "the window of a live row or of a prefill job")
+
+
+class WindowRow:
+    """One row's pages of the window kind: ``table`` is its page table in
+    position order (0, the scratch block, wherever it holds nothing) and the
+    pages ``lo .. hi`` of it are held; ``reserve`` is what the pool keeps
+    aside for it beyond those (a prefill job's, so that a job admitted
+    beside others never finds the pool empty half way through its prompt)."""
+
+    __slots__ = ("table", "lo", "hi", "reserve", "budget")
+
+    def __init__(self, pages_per_seq: int):
+        self.table = np.zeros((pages_per_seq,), np.int32)
+        self.lo = self.hi = self.reserve = self.budget = 0
+
+    @property
+    def held(self) -> int:
+        return self.hi - self.lo
+
+
+def window_bound(window: int, chunk: int, page_size: int,
+                 pages_per_seq: int) -> int:
+    """The most window pages a row holds at once: the window, the prefill
+    chunk being written, and a page for where the window's edge falls
+    inside one; never more than the table."""
+    return min(pages_per_seq, blocks_for(window + chunk, page_size) + 1)
+
+
+class WindowPages:
+    """The allocator of the paged leaves that lose their tokens behind a
+    window (``models/serving.py``, kind ``window``): a query at position
+    ``p`` reads keys ``p - window < j <= p`` there, so a page that lies
+    wholly behind the window of everything dispatched is returned to the
+    free list, where another row can take it, and the row's table reads
+    scratch in its place. No tree and no sharing: a page has one holder.
+
+    A row never holds more than :attr:`bound` pages
+    (:func:`window_bound`), however long its context.
+
+    Programs run on the device in the order they were dispatched, so a page
+    released while a round is in flight is safe to hand out at once: whoever
+    takes it writes it in a later program."""
+
+    def __init__(self, n_blocks: int, page_size: int, window: int,
+                 pages_per_seq: int, chunk: int):
+        self.pool = BlockPool(n_blocks, page_size)
+        self.page_size, self.window = page_size, window
+        self.pages_per_seq = pages_per_seq
+        self.bound = window_bound(window, chunk, page_size, pages_per_seq)
+        self.reserved = 0          # set aside for prefill jobs under way
+        self.released = 0          # pages returned behind a window, ever
+        self._update_gauges()
+
+    def row(self) -> WindowRow:
+        return WindowRow(self.pages_per_seq)
+
+    def need(self, n_tokens: int) -> int:
+        """The most pages a prompt of ``n_tokens`` holds at once."""
+        return min(blocks_for(n_tokens, self.page_size), self.bound)
+
+    def available(self) -> int:
+        """Free pages no prefill job under way has been promised."""
+        return self.pool.free_count() - self.reserved
+
+    def live(self) -> int:
+        return self.pool.n_blocks - 1 - self.pool.free_count()
+
+    def reserve(self, row: WindowRow, n_tokens: int) -> None:
+        """Set aside what a prompt of ``n_tokens`` will hold at its most,
+        or raise :class:`NoFreeBlocks`."""
+        need = self.need(n_tokens)
+        if self.available() < need:
+            raise NoFreeBlocks(
+                f"window pages: {need} needed, {self.available()} free")
+        row.budget = row.reserve = need
+        self.reserved += need
+
+    def unreserve(self, row: WindowRow) -> None:
+        """The prompt is done (or dropped): what was set aside and not
+        taken is anybody's again."""
+        self.reserved -= row.reserve
+        row.reserve = row.budget = 0
+
+    def cover(self, row: WindowRow, first: int, end: int) -> bool:
+        """Make ``row`` hold the pages of positions ``first .. end - 1``
+        (``first``: the oldest position anything dispatched from now on
+        reads; below 0 counts as 0): pages wholly behind ``first`` go back
+        to the free list, pages up to ``end`` are taken, from the row's
+        reserve first. True if the table changed. Raises
+        :class:`NoFreeBlocks` with nothing taken."""
+        page = self.page_size
+        lo = max(row.lo, min(max(first, 0) // page, row.hi))
+        hi = max(row.hi, blocks_for(end, page))
+        gone, take = lo - row.lo, hi - row.hi
+        # a job under way keeps set aside what it may still come to hold
+        reserve = max(0, row.budget - (hi - lo)) if row.budget else 0
+        if self.available() + gone - take + row.reserve - reserve < 0:
+            raise NoFreeBlocks(
+                f"window pages: {take} needed, {self.available()} free "
+                f"beside what prefill jobs were promised")
+        self._give_back(row, lo)
+        if gone:
+            self.released += gone
+            _WINDOW_RELEASED.inc(gone)
+        for at in range(row.hi, hi):
+            row.table[at] = self.pool.alloc()
+        self.reserved += reserve - row.reserve
+        row.reserve, row.lo, row.hi = reserve, lo, hi
+        if gone or take:
+            self._update_gauges()
+        return bool(gone or take)
+
+    def _give_back(self, row: WindowRow, upto: int) -> None:
+        """The row's pages ``row.lo .. upto`` go back to the free list and
+        its table reads scratch there."""
+        for at in range(row.lo, upto):
+            block = int(row.table[at])
+            row.table[at] = 0
+            self.pool.decref(block)
+            self.pool.release_to_free(block)
+
+    def release(self, row: WindowRow) -> None:
+        """The row ended: every page it holds goes back, its reserve too."""
+        self._give_back(row, row.hi)
+        row.lo = row.hi = 0
+        self.unreserve(row)
+        self._update_gauges()
+
+    def _update_gauges(self) -> None:
+        _WINDOW_BLOCKS.set(float(self.pool.n_blocks))
+        _WINDOW_FREE.set(float(self.pool.free_count()))
+        _WINDOW_LIVE.set(float(self.live()))

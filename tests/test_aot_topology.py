@@ -446,3 +446,49 @@ def test_gated_experts_compile_at_a_width_of_eleven_lane_tiles(rows,
         sds((16, 1408, 2048), jnp.bfloat16),
         sds((rows, 16), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+@pytest.mark.parametrize("batch,t", [(32, 1), (1, 16), (1, 256)],
+                         ids=["decode", "chunk16", "chunk256"])
+def test_group_reads_compile_at_published_widths(batch, t, window, one_chip):
+    """The reads of pools ``[pages, page, KV x D]`` under both their names:
+    128 query heads over 8 of 128 (a group of 16), pages of 32, a table of
+    512 pages a slot (16,384 positions), with a window of 4096 and without.
+    No copy of the pool is made (the ``[pages, page, KV, D]`` layout costs
+    one a call: 287 MB a leaf here)."""
+    import importlib
+
+    pa = importlib.import_module("lzy_tpu.ops.paged_attention")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((4385, 32, 1024), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v, table, start: pa.paged_group_attention(
+        q, k, v, table, start, window=window, kernel="pallas",
+        interpret=False)).lower(
+        sds((batch, t, 128, 128), jnp.bfloat16), pool, pool,
+        sds((batch, 512), jnp.int32), sds((batch,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("paged_group_decode" if t <= 8 else "paged_group_prefill") in text
+    pool_bytes = 4385 * 32 * 1024 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+@pytest.mark.parametrize("rows", [32, 256], ids=["decode", "chunk256"])
+def test_gated_experts_compile_at_a_square_of_4096(rows, one_chip):
+    """16 held experts of three 4096 x 4096 matrices (Command A+): tiles of
+    4096 x 256 by bytes, a VMEM limit by name at 256 rows."""
+    from lzy_tpu.ops import grouped_experts as gexp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((16, 4096, 4096), jnp.bfloat16)
+    compiled = jax.jit(lambda x, g, a, b, w: gexp.grouped_experts(
+        x, a, b, w, gate=g, interpret=False)).lower(
+        sds((rows, 4096), jnp.bfloat16), up, up, up,
+        sds((rows, 16), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
