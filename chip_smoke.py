@@ -404,7 +404,8 @@ def phase_kernels(args) -> dict:
                                    jnp.bfloat16)
         table = jnp.asarray(rng.permutation(np.arange(1, n_blocks))
                             .reshape(batch, pages).astype(np.int32))
-        for t in (1, 5):
+        # decode, the verify window, a prefill chunk (the chunk kernel)
+        for t in (1, 5, 64):
             starts = rng.integers(0, base.max_seq_len - t, size=(batch,))
             pos = jnp.asarray(starts[:, None] + np.arange(t)[None, :],
                               jnp.int32)

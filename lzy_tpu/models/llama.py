@@ -80,8 +80,9 @@ class LlamaConfig(HeadPool):
     # how attention reads the pool through the page table
     # (ops/paged_attention.py): "lax" (portable gather-attention, the same
     # sums in the same order as the dense cache's read below) or "pallas"
-    # (the decode kernel: live pages by DMA, online softmax; decode-sized
-    # windows over float pools, lax for the rest)
+    # (the kernels: live pages by DMA, online softmax; the decode kernel
+    # for decode-sized windows and the chunk kernel for prefill chunks
+    # over float pools, lax for an int8 pool)
     paged_kernel: str = "lax"
     # int8 per-block KV quantization (paged cache only): pooled K/V are
     # stored int8 with per-position/per-head scale+zero-point sidecars
@@ -140,8 +141,8 @@ class LlamaConfig(HeadPool):
     @property
     def widest_prefill(self) -> int:
         """The widest prefill program this family's kernels take: dense
-        matmuls and the lax read, which take any width, so the widest
-        bucket."""
+        matmuls and a chunk read that tiles the width, which take any, so
+        the widest bucket."""
         from lzy_tpu.models.generate import PREFILL_BUCKETS
 
         return PREFILL_BUCKETS[-1]
@@ -456,10 +457,10 @@ class Attention(nn.Module):
             # (ops/paged_attention): decode, prefill chunks and the
             # [B, gamma+1] speculative verify all make this one call.
             # "lax" makes the dense read's sums below in the same order
-            # (bit-identical to it); "pallas" is the decode kernel
-            # (within a written tolerance of float32 attention) for
-            # decode and verify windows over float pools, and lax for
-            # prefill chunks and int8 pools.
+            # (bit-identical to it); "pallas" is the decode kernel for
+            # decode and verify windows and the chunk kernel for prefill
+            # chunks over float pools (within a written tolerance of
+            # float32 attention), and lax for int8 pools.
             out = paged_attention(
                 q, cache_k.value, cache_v.value, page_table, pos,
                 kernel=cfg.paged_kernel, dtype=cfg.dtype, quant=kvq)

@@ -137,15 +137,21 @@ class HeadPool:
     def lower_read(self, *, slots: int, kv_blocks: Optional[int],
                    page_size: Optional[int], pages_per_seq: Optional[int],
                    kv_quant: Optional[str]) -> None:
-        """Lower the decode read of a key/value pool of these shapes for a
-        TPU, where the decode step takes the kernel; nothing where the
-        caller names no pool."""
-        from lzy_tpu.ops.paged_attention import lower_pallas_for_tpu
+        """Lower the reads of a key/value pool of these shapes for a TPU,
+        where the programs take the kernels: the decode read over every
+        slot, and the chunk read of one row at the widest prefill width
+        (no other width: a width is compiled by the first request that
+        reaches it); nothing where the caller names no pool."""
+        from lzy_tpu.ops.paged_attention import (
+            CHUNK_PATH, lower_pallas_for_tpu)
 
-        if kv_blocks is None or self.read_path(
-                "pallas", t=1, kv_quant=kv_quant) != "pallas":
+        if kv_blocks is None:
             return
-        lower_pallas_for_tpu(
-            batch=slots, n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
-            head_dim=self.head_dim, n_blocks=kv_blocks, page_size=page_size,
-            pages_per_seq=pages_per_seq, dtype=self.dtype)
+        for batch, t, path in ((slots, 1, "pallas"),
+                               (1, self.widest_prefill, CHUNK_PATH)):
+            if self.read_path("pallas", t=t, kv_quant=kv_quant) == path:
+                lower_pallas_for_tpu(
+                    batch=batch, t=t, n_heads=self.n_heads,
+                    n_kv_heads=self.n_kv_heads, head_dim=self.head_dim,
+                    n_blocks=kv_blocks, page_size=page_size,
+                    pages_per_seq=pages_per_seq, dtype=self.dtype)

@@ -5,45 +5,56 @@ scattering through the page table; this module is how attention reads them
 back, the one read path of the serving engine:
 
 - :func:`paged_attention` — attention computed *through* the page table.
-  Two kernels behind one signature:
+  Three reads behind one signature:
 
   * ``kernel="lax"`` — a pure ``jax.lax`` gather-attention whose op
     sequence reproduces the dense cache's read in ``models/llama.py``
     EXACTLY (same einsums, same mask, same softmax, same dtypes), so its
     output is bit-identical to the ``generate()`` oracle's. It is kept as
-    the portable path, the sharded gang's read and the tests' bit-exact
-    reference. Its cost is the table's width: it gathers
-    ``pages_per_seq`` pages for every row, live or not.
-  * ``kernel="pallas"`` — the decode kernel (ROADMAP S2), the one
+    the portable path, the sharded gang's read, the read of an int8 pool
+    and the tests' bit-exact reference. Its cost is the table's width: it
+    gathers ``pages_per_seq`` pages for every row, live or not, and scores
+    every query against all of them.
+  * ``kernel="pallas"`` — the Pallas kernels (ROADMAP S2), what
     ``"auto"`` resolves to on a TPU (:func:`default_kernel`). The pools
-    stay in
-    HBM in their own ``[n_blocks, page, KV, D]`` layout
+    stay in HBM in their own ``[n_blocks, page, KV, D]`` layout
     (``memory_space=ANY``; a page of all KV heads is one contiguous
     ``[page * KV, D]`` tile-aligned slab, a free reshape); the page
-    table and the positions arrive by scalar prefetch; one grid cell
-    per batch row copies that row's ``ceil(len / page)`` pages, and no
-    more, into VMEM by DMA, a block of pages in flight while the block
-    before it is scored, and folds them into an online softmax. An idle
-    slot (position 0, zeroed table) reads one page. The trip count is
-    dynamic: one compiled program serves every context length. What it
-    costs follows the live context, not ``max_seq_len``.
+    table and the positions arrive by scalar prefetch; a grid cell
+    copies the pages its queries can see, and no more, into VMEM by DMA,
+    a block of pages in flight while the block before it is scored, and
+    folds them into an online softmax. The trip count is dynamic: one
+    compiled program serves every context length. What a read costs
+    follows the live context, not ``max_seq_len``. Which kernel a program
+    gets follows its shape (:func:`kernel_path`):
 
-    It takes the programs whose shape it is written for
-    (:func:`kernel_path`): ``T <= MAX_Q_TOKENS`` query positions a row
-    over a float pool, which is plain decode and the speculative verify
-    window (``T = gamma + 1``, the same q tile ``T`` times taller).
-    Wider windows (prefill chunks) and int8 pools are read by lax under
-    the same ``kernel="pallas"``; the engine labels
-    ``lzy_kernel_dispatch_total{path}`` with the path each program took.
-    The sharded engine (GSPMD cannot partition the custom call) resolves
-    ``"auto"`` to ``"lax"`` itself.
+    - the **decode kernel** (``paged_decode_attention``): ``T <=
+      MAX_Q_TOKENS`` query positions a row, which is plain decode and the
+      speculative verify window (``T = gamma + 1``, the same q tile ``T``
+      times taller); one grid cell a batch row; every query head scored
+      against every row of a page behind a block-diagonal mask, ``KV``
+      times the arithmetic on a read that the HBM bounds. An idle slot
+      (position 0, zeroed table) reads one page.
+    - the **chunk kernel** (``paged_chunk_attention``): a wider window
+      at the consecutive positions ``start + t``, which is a prefill chunk
+      (or the verify window of ``gamma >= 8``, a grid cell a slot); a grid
+      cell a tile of query positions, pages 0 to the tile's own; a block
+      of pages is de-interleaved by key-value head once (a float32
+      staging copy read with a stride of ``KV`` rows) and each head's
+      query rows are scored against their own keys only.
 
-    Online softmax reorders the sums, so the kernel is not bit-identical
-    to lax: both are judged against float32 attention within
-    :data:`TOLERANCE` (ROADMAP D4). Off the TPU it runs under the Pallas
+    An int8 pool is read by lax under the same ``kernel="pallas"``; the
+    engine labels ``lzy_kernel_dispatch_total{path}`` with the path each
+    program took (``pallas``, ``chunk_pallas``, ``lax``). The sharded
+    engine (GSPMD cannot partition the custom call) resolves ``"auto"``
+    to ``"lax"`` itself.
+
+    Online softmax reorders the sums, so the kernels are not bit-identical
+    to lax: all are judged against float32 attention within
+    :data:`TOLERANCE` (ROADMAP D4). Off the TPU they run under the Pallas
     TPU interpreter (``ops/interpret.py``), which models the DMAs and
-    semaphores; an engine built for it outside the interpreter lowers it
-    for a TPU at construction (:func:`lower_pallas_for_tpu`) and fails
+    semaphores; an engine built for them outside the interpreter lowers
+    them for a TPU at construction (:func:`lower_pallas_for_tpu`) and fails
     there, in the lowering's own words, if the shapes cannot be served.
 
 - :func:`quantize_kv` / :func:`dequantize_kv` — per-position, per-head
@@ -69,6 +80,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -88,7 +100,7 @@ TOLERANCE = {"bfloat16": 2e-2, "float32": 1e-5}
 
 DISPATCHES = REGISTRY.counter(
     "lzy_kernel_dispatch_total",
-    "paged-attention dispatches by kernel path (pallas/lax)")
+    "paged-attention dispatches by kernel path (pallas/chunk_pallas/lax)")
 QUANT_BLOCKS_RESIDENT = REGISTRY.gauge(
     "lzy_kernel_kv_quant_blocks_resident",
     "int8-quantized KV blocks currently holding live data (summed over "
@@ -114,8 +126,8 @@ def note_dequant_error(err: float, alpha: float = 0.2) -> float:
 
 def default_kernel() -> str:
     """The kernel ``"auto"`` resolves to, by the platform JAX runs on. On
-    a TPU: the Pallas decode kernel, which compiles there at serving
-    shapes and reads no more than the live context. Anywhere else: lax,
+    a TPU: the Pallas kernels, which compile there at serving shapes and
+    read no more than the live context. Anywhere else: lax,
     the portable read. Never the interpreter (``ops/interpret.py``): a
     test that wants the kernel off the TPU asks for ``"pallas"`` by name."""
     return "pallas" if jax.default_backend() == "tpu" else "lax"
@@ -232,10 +244,14 @@ def _lax_paged_attention(q, k_pool, v_pool, page_table, positions, *,
 
 # -- pallas decode kernel ----------------------------------------------------------
 
-#: the widest query window the kernel takes: plain decode (T == 1) and
-#: the speculative verify window (T == gamma + 1) share one q tile of
-#: ``T * H`` rows. Prefill chunks are wider and stay on the lax path.
+#: the widest query window the decode kernel takes: plain decode (T == 1)
+#: and the speculative verify window (T == gamma + 1) share one q tile of
+#: ``T * H`` rows. Prefill chunks are wider: the chunk kernel's.
 MAX_Q_TOKENS = 8
+
+#: ``lzy_kernel_dispatch_total{path}`` label of a prefill chunk's read
+#: through the chunk kernel
+CHUNK_PATH = "chunk_pallas"
 
 #: pool rows, ``(position, kv head)`` pairs, scored per compute block:
 #: 8 pages of 16 positions x 8 kv heads. One block is two ``[1024, D]``
@@ -245,13 +261,16 @@ _BLOCK_ROWS = 1024
 
 def kernel_path(kernel: str, *, t: int, quantized: bool) -> str:
     """The path a ``paged_attention(kernel=kernel)`` call takes at this
-    shape: asking for ``"pallas"`` gets the kernel for a decode-sized
-    query window over a float pool, and the lax path for everything else
-    (prefill chunks, int8 pools). The engine labels
+    shape: asking for ``"pallas"`` over a float pool gets the decode kernel
+    for a decode-sized query window and the chunk kernel
+    (:data:`CHUNK_PATH`) for a wider one, a prefill chunk; an int8 pool is
+    read by lax at any width. The engine labels
     ``lzy_kernel_dispatch_total`` with the same answer."""
-    if kernel == "pallas" and (t > MAX_Q_TOKENS or quantized):
+    if kernel != "pallas":
+        return kernel
+    if quantized:
         return "lax"
-    return kernel
+    return "pallas" if t <= MAX_Q_TOKENS else CHUNK_PATH
 
 
 def _decode_kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -392,20 +411,227 @@ def _pallas_paged_attention(q, k_pool, v_pool, page_table, positions, *,
     return out.reshape(b, t, kv_heads, h // kv_heads, d)
 
 
+# -- pallas chunk kernel -----------------------------------------------------------
+
+#: rows of a chunk read's q tile a key-value head (query positions x the
+#: group's heads: what one de-interleaved block of keys is scored against),
+#: and pool rows, ``(position, kv head)`` pairs, fetched and scored a block:
+#: 256 positions at 8 key-value heads, 1,024 at 2. Chosen on a v5e chip
+#: (PERF.md section 6, PR 42; ms a layer for a 256-wide chunk at a start of
+#: 384 / 3,840, q rows x positions a block): 32 / 8 heads 256 x 128 0.181 /
+#: 0.610, 512 x 128 0.160 / 0.509, 1024 x 256 0.153 / 0.366, 1024 x 512
+#: 0.168 / 0.359 (the lax read 0.68); 32 / 2 heads 1024 x 256 0.185 / 0.587,
+#: 1024 x 512 0.159 / 0.378, 1024 x 1024 0.139 / 0.276: a block's fixed
+#: cost (the waits, the row reductions across lanes, the accumulator's
+#: rescale) favours large steps, and VMEM ends them (1024 positions of 8
+#: heads do not fit).
+_CHUNK_ROWS = 1024
+_CHUNK_BLOCK_ROWS = 2048
+
+
+def _chunk_kernel(start_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                  v_buf, k_f32, v_f32, sems, m_ref, l_ref, acc_ref, *,
+                  tq, group, kv_heads, page, pages_per_seq, block_pages,
+                  scale):
+    """One grid cell: ``tq`` consecutive query positions of batch row ``b``
+    against the pages they can see, page 0 to the page of the cell's last
+    query (clamped to the table's width: a pad's position may lie past it),
+    a block of pages in flight while the block before it is scored, folded
+    into a running max / sum / weighted value a key-value head. Scores are
+    ``[tq x group, block]`` a head and never the whole context.
+
+    A page arrives as ``[page * KV, D]``, the pool's own layout, rows
+    ordered ``(position, kv head)``, as in the decode kernel. A block is
+    copied once into a float32 staging buffer (a 16-bit pool packs two rows,
+    two heads, into one word, and a strided read is of whole words), from
+    which head ``g``'s rows, every ``KV``-th, are a strided read. A head's
+    ``tq x group`` query rows (the q tile is ``[KV, tq x group, D]``, rows
+    ordered (position, head in the group)) are scored against their own
+    keys only: none of the decode kernel's ``KV``-fold masked arithmetic, at
+    two passes over the block, which ``tq x group`` rows amortise.
+
+    The loops over a block's pages and over the heads are ``fori_loop``s,
+    not Python's: every prefill width traces and lowers this body anew when
+    its first request arrives, and a body unrolled in Python (a thousand
+    equations: a ``cond`` a page a call site) cost 2 s a width of set-up on
+    the serving host (PERF.md section 6, PR 42). The heads' loop is unrolled
+    when it is lowered (``unroll=True``: one head's matrix work overlaps the
+    next one's exponentials, and ``g`` is static to the strided read); the
+    pages' has a dynamic trip count, the pages the tile can see.
+
+    Numerics as the decode kernel's: float32 scores, max, sum and
+    accumulator, scaled after the dot, probabilities cast to the pool's
+    dtype before the value contraction, one division at the end."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    rows = tq * group
+    cols = block_pages * page
+    slab = page * kv_heads
+    first = start_ref[b] + i * tq
+    n_pages = jnp.minimum(lax.div(first + tq - 1 + page, page), pages_per_seq)
+    n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
+    row_pos = first + lax.div(
+        lax.broadcasted_iota(jnp.int32, (rows, 1), 0), group)
+    col = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+
+    @pl.when((b == 0) & (i == 0))
+    def _():
+        # a partial block leaves rows of the buffer unwritten; their
+        # probabilities are 0, and 0 x whatever VMEM held must be 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def for_pages(j, slot, op):
+        # the block's pages the tile can see: none past the last block
+        def one(k, _):
+            pid = pt_ref[b * pages_per_seq + j * block_pages + k]
+            dst = pl.ds(pl.multiple_of(k * slab, slab), slab)
+            op(pltpu.make_async_copy(
+                k_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]))
+            op(pltpu.make_async_copy(
+                v_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
+            return 0
+
+        lax.fori_loop(
+            0, jnp.clip(n_pages - j * block_pages, 0, block_pages), one, 0)
+
+    for_pages(0, 0, lambda c: c.start())
+
+    def body(j, _):
+        slot = lax.rem(j, 2)
+        for_pages(j + 1, 1 - slot, lambda c: c.start())
+        for_pages(j, slot, lambda c: c.wait())
+        k_f32[...] = k_buf[slot].astype(jnp.float32)
+        v_f32[...] = v_buf[slot].astype(jnp.float32)
+        visible = col <= row_pos - j * cols
+
+        def head(g, _):
+            own = pl.ds(g, cols, stride=kv_heads)
+            keys = k_f32[own, :].astype(k_buf.dtype)
+            vals = v_f32[own, :].astype(v_buf.dtype)
+            s = lax.dot_general(
+                q_ref[g], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [rows, cols]
+            s = jnp.where(visible, s, _NEG_INF)
+            m = m_ref[g]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            # position 0 is in block 0 and every query sees it, so m_new is
+            # a real score from the first block on and a masked column's
+            # probability is exp(-1e30 - m_new) = 0 with no second mask
+            p = jnp.exp(s - m_new)
+            l_ref[g] = alpha * l_ref[g] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[g] = alpha * acc_ref[g] + lax.dot_general(
+                p.astype(v_buf.dtype), vals, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[g] = m_new
+            return 0
+
+        lax.fori_loop(0, kv_heads, head, 0, unroll=True)
+        return 0
+
+    lax.fori_loop(0, n_blocks, body, 0)
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _pallas_chunk_attention(q, k_pool, v_pool, page_table, start, *,
+                            interpret: bool):
+    """jitted so that a model's layers, which all make this call at one
+    shape, trace and lower the kernel once a program."""
+    b, t, h, d = q.shape
+    n, page, kv_heads, _ = k_pool.shape
+    pages = page_table.shape[1]
+    group = h // kv_heads
+    tq = t
+    while tq * group > _CHUNK_ROWS and tq % 2 == 0:
+        tq //= 2
+    rows = tq * group
+    slab = page * kv_heads
+    block_pages = max(1, min(pages, _CHUNK_BLOCK_ROWS // slab))
+    cols = block_pages * page
+    size = jnp.dtype(k_pool.dtype).itemsize
+    # what the kernel keeps in VMEM: the q and output tiles twice, the two
+    # page buffers twice and their float32 copies, the accumulator, the max
+    # and the sum (a row of lanes each), a block's scores and probabilities
+    vmem = (4 * kv_heads * rows * d * size + 4 * cols * kv_heads * d * size
+            + 2 * cols * kv_heads * d * 4
+            + kv_heads * rows * (d + 2 * 128) * 4 + 4 * rows * cols * 4)
+    kernel = functools.partial(
+        _chunk_kernel, tq=tq, group=group, kv_heads=kv_heads, page=page,
+        pages_per_seq=pages, block_pages=block_pages, scale=d ** -0.5)
+    tile = pl.BlockSpec((None, kv_heads, rows, d),
+                        lambda bi, i, *_: (bi, 0, i, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    # [B, T, KV, G, D] -> [B, KV, T x G, D]: a head's rows together
+    qt = q.astype(k_pool.dtype).reshape(b, t, kv_heads, group, d).transpose(
+        0, 2, 1, 3, 4).reshape(b, kv_heads, t * group, d)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, t // tq),
+            in_specs=[tile, pool, pool],
+            out_specs=tile,
+            scratch_shapes=[
+                pltpu.VMEM((2, cols * kv_heads, d), k_pool.dtype),
+                pltpu.VMEM((2, cols * kv_heads, d), v_pool.dtype),
+                pltpu.VMEM((cols * kv_heads, d), jnp.float32),
+                pltpu.VMEM((cols * kv_heads, d), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+                pltpu.VMEM((kv_heads, rows, 1), jnp.float32),
+                pltpu.VMEM((kv_heads, rows, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, kv_heads, t * group, d),
+                                       k_pool.dtype),
+        # the V buffer is zeroed by the first cell and kept by the rest
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(32 << 20, vmem + (8 << 20))),
+        interpret=_interpret.tpu_params(interpret),
+        name="paged_chunk_attention",
+    )(start.astype(jnp.int32).reshape(-1),
+      page_table.astype(jnp.int32).reshape(-1), qt,
+      # a page is contiguous in the pool: [page, KV, D] -> [page*KV, D]
+      k_pool.reshape(n, slab, d), v_pool.reshape(n, slab, d))
+    return out.reshape(b, kv_heads, t, group, d).transpose(0, 2, 1, 3, 4)
+
+
+def _require_consecutive(positions) -> None:
+    """The chunk kernel's contract: a row's positions are ``start + t``
+    (``models/llama.py`` and ``models/paged_blocks.py`` build them so, for
+    a prefill chunk and a verify window alike). Checked where the values
+    are at hand, in an eager call; inside a traced program they are not,
+    and the contract is the caller's word."""
+    if isinstance(positions, jax.core.Tracer):
+        return
+    steps = np.diff(np.asarray(positions), axis=1)
+    if (steps != 1).any():
+        raise ValueError(
+            "the chunk kernel reads a window of consecutive positions "
+            "(start + t) a row; got steps of "
+            f"{sorted(set(steps.ravel().tolist()))}: read such a window "
+            "with kernel='lax'")
+
+
 def lower_pallas_for_tpu(*, batch: int, n_heads: int, n_kv_heads: int,
                          head_dim: int, n_blocks: int, page_size: int,
                          pages_per_seq: int, dtype: Any, t: int = 1) -> None:
-    """Lower the Pallas kernel for a TPU at these shapes, with no device
-    and no compile, and let the lowering's error out. An engine whose
-    decode step takes the kernel calls this when it is built: what the
-    TPU would refuse at the first request is refused at construction, in
-    the lowering's own words."""
+    """Lower the Pallas kernel that reads ``t`` query positions a row (the
+    decode kernel, or the chunk kernel past ``MAX_Q_TOKENS``) for a TPU at
+    these shapes, with no device and no compile, and let the lowering's
+    error out. An engine whose programs take the kernels calls this when
+    it is built: what the TPU would refuse at the first request is refused
+    at construction, in the lowering's own words."""
     sds = jax.ShapeDtypeStruct
     pool = sds((n_blocks, page_size, n_kv_heads, head_dim), dtype)
 
     def read(q, k_pool, v_pool, page_table, positions):
-        return _pallas_paged_attention(
-            q, k_pool, v_pool, page_table, positions,
+        return paged_attention(
+            q, k_pool, v_pool, page_table, positions, kernel="pallas",
             dtype=jnp.dtype(dtype), interpret=False)
 
     jax.jit(read).trace(
@@ -682,9 +908,11 @@ def paged_attention(
       (the causal mask: pooled slot ``l`` is visible iff
       ``l <= position``);
     - ``kernel``: ``"lax"`` (portable, bit-identical to the dense
-      cache's read) or ``"pallas"`` (the decode kernel for the shapes
-      :func:`kernel_path` gives it, lax for the rest; ``interpret=None``
-      takes the process's ``ops.interpret`` setting);
+      cache's read) or ``"pallas"`` (the decode kernel or the chunk kernel
+      by the window's width, lax for an int8 pool: :func:`kernel_path`;
+      the chunk kernel takes ``positions[:, 0]`` and the positions after
+      it as consecutive, and an eager call with others is refused;
+      ``interpret=None`` takes the process's ``ops.interpret`` setting);
     - ``dtype``: compute/output dtype (defaults to the pool dtype; int8
       pools must pass the model's activation dtype).
 
@@ -698,10 +926,16 @@ def paged_attention(
     if kernel not in ("lax", "pallas"):
         raise ValueError(
             f"unknown paged-attention kernel {kernel!r}; known: lax, pallas")
-    if kernel_path(kernel, t=q.shape[1], quantized=quant is not None) \
-            == "pallas":
+    path = kernel_path(kernel, t=q.shape[1], quantized=quant is not None)
+    if path == "pallas":
         return _pallas_paged_attention(
             q, k_pool, v_pool, page_table, positions, dtype=jnp.dtype(dtype),
             interpret=_interpret.resolve(interpret))
+    if path == CHUNK_PATH:
+        # a chunk's positions are consecutive: the row's first names them
+        _require_consecutive(positions)
+        return _pallas_chunk_attention(
+            q, k_pool, v_pool, page_table, positions[:, 0],
+            interpret=_interpret.resolve(interpret)).astype(dtype)
     return _lax_paged_attention(
         q, k_pool, v_pool, page_table, positions, dtype=dtype, quant=quant)

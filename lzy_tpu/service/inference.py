@@ -603,7 +603,7 @@ def build_inference_service(
     tokens a slot — size it below that to overcommit HBM, above to grow
     the prefix cache; docs/serving.md has the tradeoffs). Attention reads
     KV through the page table (``kernel``: auto/pallas/lax — ``auto`` is
-    the Pallas decode kernel on a TPU and the portable lax read anywhere
+    the Pallas kernels on a TPU and the portable lax read anywhere
     else); ``kv_quant="int8"`` halves pooled KV bytes (~2x blocks at
     fixed HBM, boundedly-divergent output) — docs/serving.md "Paged
     attention & KV quantization".

@@ -16,10 +16,11 @@ in ``models/serving.py``): this file names no model.
   (a clean ``preempted`` error), never corrupts one.
 - **One read path decision, made by the code**: attention reads the pool
   through the page table (``ops/paged_attention.py``). ``kernel="auto"``
-  is, on a TPU, the Pallas decode kernel for the programs whose shape it
-  is written for (``kernel_path``: decode and verify windows over a float
-  pool) and the portable ``lax`` read for the rest (prefill chunks, int8
-  pools); ``"lax"`` is also what ``"auto"`` is off the TPU, the sharded
+  is, on a TPU, the Pallas kernel written for a program's shape
+  (``kernel_path``: the decode kernel for decode and verify windows, the
+  chunk kernel for prefill chunks, over a float pool) and the portable
+  ``lax`` read for an int8 pool; ``"lax"`` is also what ``"auto"`` is off
+  the TPU, the sharded
   gang's read, and the tests' bit-exact reference against
   ``models/generate.py``.
 - **Prefill on arrival, in budgeted chunks**: a prompt's suffix runs
@@ -507,12 +508,11 @@ class PagedInferenceEngine:
         self._pages_per_seq = base.max_seq_len // page_size
         self._kv_quant = kv_quant
         # kernel selection (docs/serving.md): "auto" is, on a TPU, the
-        # kernel that compiles there (the Pallas decode kernel) and the
-        # portable lax read anywhere else; "pallas" is taken at the
-        # caller's word and checked below. The kernel takes
-        # the programs whose shape it is written for
-        # (ops.paged_attention.kernel_path) and leaves the rest to lax:
-        # kernel_path is the decode step's.
+        # kernels that compile there (Pallas) and the portable lax read
+        # anywhere else; "pallas" is taken at the caller's word and
+        # checked below. A program gets the kernel written for its
+        # shape (ops.paged_attention.kernel_path: decode, chunk) and an
+        # int8 pool the lax read: kernel_path is the decode step's.
         self._paged_kernel = default_kernel() if kernel == "auto" \
             else kernel
         self.cfg = base

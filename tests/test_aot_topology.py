@@ -11,8 +11,8 @@ The CPU dryrun proves the sharded step executes; these tests prove the
 - the Pallas kernels compile at Llama-3-8B widths with ``interpret=False``:
   flash attention forward and backward up to the longest length its wrapper
   accepts, the paged-attention read ``"auto"`` resolves to (the Pallas decode
-  kernel over float pools, lax over int8), and one paged decode step of a
-  two-layer model at full width.
+  and chunk kernels over float pools, lax over int8), and one paged decode
+  step and one prefill step of a two-layer model at full width.
 
 Interpret mode cannot see a block shape the TPU lowering refuses or a kernel
 that runs out of VMEM; these compiles can, at about two seconds each and no
@@ -323,6 +323,64 @@ def test_paged_prefill_step_compiles_at_full_width(one_chip, monkeypatch):
     finally:
         engine.close()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # the chunk's attention read is the chunk kernel: no scores of the whole
+    # table go through HBM
+    assert "paged_chunk_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch,t", [(1, 16), (1, 256), (16, 9)])
+@pytest.mark.parametrize("config", ["mistral", "nemotron", "solar"])
+def test_chunk_read_compiles_at_the_three_configurations(
+        config, batch, t, one_chip, monkeypatch):
+    """The chunk kernel at the head shapes, pools and tables of the three
+    benchmark configurations that read a pool ``[n_blocks, page, KV, D]``
+    (32 / 8 over 7168 pages and a table of 256; 32 / 2 over 4096 and 256;
+    64 / 8 over 4096 and 288), at the narrowest and widest prefill widths
+    and at the verify window of ``spec_tokens=8`` over 16 slots (a q tile
+    of 9 positions, no whole sublane tile).
+    Mosaic takes the strided read of the float32 staging copy (of the
+    bfloat16 buffer it does not: "Strided load with non 32-bit data"), and
+    no copy of the pool is made. ``HeadPool.lower_read`` lowers it beside
+    the decode kernel when an engine is built."""
+    import importlib
+
+    from lzy_tpu.models.serving import HeadPool
+    from lzy_tpu.ops.paged_attention import paged_attention
+
+    heads, kv, n, pages = {"mistral": (32, 8, 7168, 256),
+                           "nemotron": (32, 2, 4096, 256),
+                           "solar": (64, 8, 4096, 288)}[config]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((n, 16, kv, _D), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v, table, pos: paged_attention(
+        q, k, v, table, pos, kernel="pallas", interpret=False)).lower(
+        sds((batch, t, heads, _D), jnp.bfloat16), pool, pool,
+        sds((batch, pages), jnp.int32), sds((batch, t), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_chunk_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < n * 16 * kv * _D * 2 // 8
+
+    class Shape(HeadPool):
+        n_heads, n_kv_heads, head_dim, dtype = heads, kv, _D, jnp.bfloat16
+        widest_prefill = 256
+
+    lowered = []
+    pa = importlib.import_module("lzy_tpu.ops.paged_attention")
+    real = pa.lower_pallas_for_tpu
+    monkeypatch.setattr(
+        pa, "lower_pallas_for_tpu",
+        lambda **kw: (lowered.append((kw["batch"], kw["t"])), real(**kw)))
+    Shape().lower_read(slots=32, kv_blocks=n, page_size=16,
+                       pages_per_seq=pages, kv_quant=None)
+    assert lowered == [(32, 1), (1, 256)]
+    lowered.clear()
+    Shape().lower_read(slots=32, kv_blocks=n, page_size=16,
+                       pages_per_seq=pages, kv_quant="int8")
+    assert lowered == []
 
 
 # -- the Nemotron-H serving kernels at published widths ----------------------

@@ -37,8 +37,8 @@ from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import decode_config, generate, init_cache
 from lzy_tpu.models.llama import Llama, LlamaConfig
 from lzy_tpu.ops.paged_attention import (
-    DEQUANT_ERROR_EWMA, DISPATCHES, MAX_Q_TOKENS, TOLERANCE, KVQuant,
-    default_kernel, dequantize_kv, kernel_path, note_dequant_error,
+    CHUNK_PATH, DEQUANT_ERROR_EWMA, DISPATCHES, MAX_Q_TOKENS, TOLERANCE,
+    KVQuant, default_kernel, dequantize_kv, kernel_path, note_dequant_error,
     paged_attention, quantize_kv)
 from lzy_tpu.serving import PagedInferenceEngine
 from lzy_tpu.serving.kv_cache import (
@@ -302,18 +302,24 @@ class TestDecodeKernel:
                               kernel="pallas", interpret=True)
         _assert_within_tolerance(got, _reference(q, kp, vp, pt, pos), dtype)
 
-    def test_kernel_takes_decode_shapes_and_leaves_the_rest_to_lax(self):
+    def test_kernel_takes_decode_and_chunk_shapes_and_leaves_int8_to_lax(
+            self):
         assert kernel_path("pallas", t=1, quantized=False) == "pallas"
         assert kernel_path("pallas", t=MAX_Q_TOKENS, quantized=False) \
             == "pallas"
         assert kernel_path("pallas", t=MAX_Q_TOKENS + 1,
-                           quantized=False) == "lax"
-        assert kernel_path("pallas", t=1, quantized=True) == "lax"
-        assert kernel_path("lax", t=1, quantized=False) == "lax"
-        # and the call does as the label says: a prefill-wide window and
-        # an int8 pool come back as the lax read's very bytes
+                           quantized=False) == CHUNK_PATH == "chunk_pallas"
+        assert kernel_path("pallas", t=256, quantized=False) == CHUNK_PATH
+        for t in (1, MAX_Q_TOKENS + 1, 256):
+            assert kernel_path("pallas", t=t, quantized=True) == "lax"
+            assert kernel_path("lax", t=t, quantized=False) == "lax"
+        # and the call does as the label says: an int8 pool comes back as
+        # the lax read's very bytes at either width; a prefill-wide window
+        # over a float pool does not (the chunk kernel reorders the sums)
+        # and lies within the tolerance all the same
         rng = np.random.default_rng(5)
-        for t, quant in ((MAX_Q_TOKENS + 1, False), (1, True)):
+        for t, quant in ((MAX_Q_TOKENS + 1, True), (1, True),
+                         (MAX_Q_TOKENS + 1, False)):
             q, kp, vp, pt, pos, side = _random_case(
                 rng, page=4, pages=8, b=2, t=t, kv=2, g=2, d=16,
                 dtype=jnp.bfloat16, quant=quant)
@@ -321,7 +327,10 @@ class TestDecodeKernel:
                                     dtype=jnp.bfloat16, quant=side,
                                     interpret=True)
                     for k in ("lax", "pallas"))
-            assert bool(jnp.array_equal(a, p))
+            assert bool(jnp.array_equal(a, p)) == quant
+            if not quant:
+                _assert_within_tolerance(
+                    p, _reference(q, kp, vp, pt, pos), jnp.bfloat16)
 
     def test_unknown_kernel_and_missing_dtype_rejected(self):
         rng = np.random.default_rng(3)
@@ -333,6 +342,156 @@ class TestDecodeKernel:
                             dtype=jnp.float32, quant=side)
         with pytest.raises(ValueError, match="dtype"):
             paged_attention(q, kp, vp, pt, pos, quant=side)
+
+
+#: heads / key-value heads of the three configurations that read a pool
+#: ``[n_blocks, page, KV, D]`` (head size 128, pages of 16)
+_CHUNK_SHAPES = {"mistral": (32, 8), "nemotron": (32, 2), "solar": (64, 8)}
+
+
+def _chunk_case(rng, *, h, kv, t, starts, pages, live=None, page=16, d=128,
+                dtype=jnp.bfloat16):
+    """A prefill chunk of ``t`` consecutive positions a row from
+    ``starts``. A row owns the pages under its first ``live`` positions
+    (default: up to its chunk's end, within the table); the table's entries
+    behind them name pages of NaN, as another row's would be to this one: a
+    read that touches one shows in the output."""
+    b = len(starts)
+    n = b * pages + 1
+    q = jnp.asarray(rng.standard_normal((b, t, h, d)), dtype)
+    k_pool = rng.standard_normal((n, page, kv, d)).astype(np.float32)
+    v_pool = rng.standard_normal((n, page, kv, d)).astype(np.float32)
+    pt = rng.permutation(np.arange(1, n)).reshape(b, pages).astype(np.int32)
+    for row, start in enumerate(starts):
+        upto = start + t if live is None else live[row]
+        owned = min(-(-upto // page), pages)
+        k_pool[pt[row, owned:]] = np.nan
+        v_pool[pt[row, owned:]] = np.nan
+        if live is not None:
+            pt[row, owned:] = 0           # not grown yet: scratch
+    pos = jnp.asarray(np.asarray(starts)[:, None] + np.arange(t)[None, :],
+                      jnp.int32)
+    return (q, jnp.asarray(k_pool, dtype), jnp.asarray(v_pool, dtype),
+            jnp.asarray(pt), pos)
+
+
+class TestChunkKernel:
+    """The chunk kernel under the Pallas TPU interpreter against float32
+    attention, at the three configurations' head shapes."""
+
+    @pytest.mark.parametrize("t", [16, 32, 256])
+    @pytest.mark.parametrize("shape", sorted(_CHUNK_SHAPES))
+    def test_two_rows_at_different_starts_read_their_own_pages_only(
+            self, shape, t):
+        """Batch 2, one start inside a page and one past a block of pages;
+        every page behind a row's chunk is NaN (a table wider than the live
+        prefix, filled with another row's pages): the kernel stops at the
+        page of a tile's last query."""
+        h, kv = _CHUNK_SHAPES[shape]
+        rng = np.random.default_rng(t + h + kv)
+        pages = 24 + t // 16
+        q, kp, vp, pt, pos = _chunk_case(
+            rng, h=h, kv=kv, t=t, starts=[5, 8 * 16 + 16 + 3], pages=pages)
+        got = paged_attention(q, kp, vp, pt, pos, kernel="pallas",
+                              interpret=True)
+        assert got.shape == (2, t, kv, h // kv, 128)
+        assert got.dtype == jnp.bfloat16
+        _assert_within_tolerance(got, _reference(q, kp, vp, pt, pos),
+                                 jnp.bfloat16, f"{shape} t={t}")
+
+    @pytest.mark.parametrize("case", [
+        "start_0", "inside_a_page", "ends_on_the_last_page",
+        "pads_past_the_allocated_pages", "pads_past_the_table"])
+    @pytest.mark.parametrize("shape", sorted(_CHUNK_SHAPES))
+    def test_starts_and_pads_that_break_such_kernels(self, shape, case):
+        h, kv = _CHUNK_SHAPES[shape]
+        t, pages, page = 32, 20, 16
+        L = pages * page
+        # start, and how many of the chunk's positions are real tokens
+        start, real = {
+            "start_0": (0, t),
+            "inside_a_page": (page + 5, t),
+            "ends_on_the_last_page": (L - t, t),
+            # the row's pages end with its prompt; the pads' positions read
+            # scratch and what they return is garbage that nothing keeps
+            "pads_past_the_allocated_pages": (9 * page + 2, 9),
+            # a pad's position may lie past max_seq_len: the page index is
+            # clamped to the table's width
+            "pads_past_the_table": (L - t // 2, t // 2),
+        }[case]
+        rng = np.random.default_rng(len(case) + h)
+        q, kp, vp, pt, pos = _chunk_case(
+            rng, h=h, kv=kv, t=t, starts=[start], pages=pages,
+            live=None if real == t else [start + real])
+        got = paged_attention(q, kp, vp, pt, pos, kernel="pallas",
+                              interpret=True)
+        want = _reference(q[:, :real], kp, vp, pt, pos[:, :real])
+        _assert_within_tolerance(got[:, :real], want, jnp.bfloat16,
+                                 f"{shape} {case}")
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_small_tiles_and_blocks_under_jit_at_odd_shapes(
+            self, dtype, monkeypatch):
+        """A group of 3, a head size of 24, a width that is no power of
+        two, q tiles of 2 positions and blocks of 2 pages: every tile walks
+        several blocks, the last of them partial."""
+        import importlib
+
+        pa = importlib.import_module("lzy_tpu.ops.paged_attention")
+        monkeypatch.setattr(pa, "_CHUNK_ROWS", 8)
+        monkeypatch.setattr(pa, "_CHUNK_BLOCK_ROWS", 16)
+        rng = np.random.default_rng(9)
+        dtype = jnp.dtype(dtype)
+        q, kp, vp, pt, pos = _chunk_case(
+            rng, h=6, kv=2, t=12, starts=[0, 7, 30], pages=12, page=4,
+            d=24, dtype=dtype)
+        # the wrapper reads the two sizes when it is traced: nothing else
+        # traces it at this shape, before or after
+        pa._pallas_chunk_attention.clear_cache()
+        try:
+            got = paged_attention(q, kp, vp, pt, pos, kernel="pallas",
+                                  interpret=True)
+        finally:
+            pa._pallas_chunk_attention.clear_cache()
+        _assert_within_tolerance(got, _reference(q, kp, vp, pt, pos), dtype)
+
+    @pytest.mark.parametrize("shape", sorted(_CHUNK_SHAPES))
+    def test_verify_window_wider_than_the_decode_kernel_takes(self, shape):
+        """``spec_tokens >= 8`` makes a verify window ``[slots, 9+]``, past
+        ``MAX_Q_TOKENS``: it is the chunk kernel's, a grid cell a slot, each
+        at its own start (one slot fresh at 0, one inside a page, one past
+        a block of pages), a q tile of 9 positions."""
+        h, kv = _CHUNK_SHAPES[shape]
+        rng = np.random.default_rng(h * kv)
+        q, kp, vp, pt, pos = _chunk_case(
+            rng, h=h, kv=kv, t=MAX_Q_TOKENS + 1,
+            starts=[0, 21, 8 * 16 + 7, 300], pages=24)
+        assert kernel_path("pallas", t=q.shape[1], quantized=False) \
+            == CHUNK_PATH
+        got = paged_attention(q, kp, vp, pt, pos, kernel="pallas",
+                              interpret=True)
+        _assert_within_tolerance(got, _reference(q, kp, vp, pt, pos),
+                                 jnp.bfloat16, shape)
+
+    def test_positions_that_are_not_consecutive_are_refused(self):
+        """The chunk kernel reads ``positions[:, 0]`` and takes the rest as
+        ``start + t``; the lax read and the decode kernel honour any
+        positions. An eager call with others is refused, by name, at every
+        width past the decode kernel's."""
+        rng = np.random.default_rng(2)
+        q, kp, vp, pt, pos = _chunk_case(
+            rng, h=4, kv=2, t=MAX_Q_TOKENS + 1, starts=[3, 9], pages=6,
+            page=4, d=16)
+        gap = pos.at[1, 5:].add(2)
+        for bad in (gap, pos[:, ::-1], jnp.zeros_like(pos)):
+            with pytest.raises(ValueError, match="consecutive"):
+                paged_attention(q, kp, vp, pt, bad, kernel="pallas",
+                                interpret=True)
+            # the reads that honour every position take them
+            paged_attention(q, kp, vp, pt, bad, kernel="lax")
+        paged_attention(q[:, :MAX_Q_TOKENS], kp, vp, pt,
+                        gap[:, :MAX_Q_TOKENS], kernel="pallas",
+                        interpret=True)
 
 
 class TestModelPathBitExactness:
@@ -490,10 +649,10 @@ class TestNativeEngineOracle:
 
     def test_the_kernel_counts_dispatches_by_path(self, tiny_model):
         """``"pallas"`` (what ``"auto"`` is on a TPU) serves decode
-        through the Pallas kernel and prefill chunks through lax, and
-        ``lzy_kernel_dispatch_total`` says so: a silent fall-back of
-        decode to lax would show under ``lax``. Greedy tokens are
-        ``kernel="lax"``'s except at a logit tie."""
+        through the decode kernel and prefill chunks through the chunk
+        kernel, and ``lzy_kernel_dispatch_total`` says so: a silent
+        fall-back of either to lax would show under ``lax``. Greedy tokens
+        are ``kernel="lax"``'s except at a logit tie."""
         cfg, params = tiny_model
         prompts = [list(range(1, 21)), [31, 9] * 9]
 
@@ -502,7 +661,7 @@ class TestNativeEngineOracle:
                                        kernel=kernel)
             try:
                 seen = {path: _metric_value(DISPATCHES, path=path)
-                        for path in ("pallas", "lax")}
+                        for path in ("pallas", CHUNK_PATH, "lax")}
                 reqs = [eng.submit(p, max_new_tokens=self.N)
                         for p in prompts]
                 _drive(eng, *reqs)
@@ -516,10 +675,11 @@ class TestNativeEngineOracle:
         path, counts, kernel = run("pallas")
         assert path == "pallas"
         # two prompts of 20 and 18 tokens: one 32-wide chunk each
-        assert counts["lax"] == 2
+        assert counts[CHUNK_PATH] == 2 and counts["lax"] == 0
         assert counts["pallas"] >= self.N - 1
         path, counts, lax = run("lax")
-        assert path == "lax" and counts["pallas"] == 0
+        assert path == "lax"
+        assert counts["pallas"] == counts[CHUNK_PATH] == 0
         for p, a, b in zip(prompts, kernel, lax):
             _assert_same_or_tie(cfg, params, p, a, b)
 
@@ -549,21 +709,52 @@ class TestNativeEngineOracle:
 
     def test_dispatch_counter_counts_each_prefill_chunk(self, tiny_model):
         """One inc per PROGRAM, on every path: a multi-chunk prefill
-        must move the counter by its chunk count, like decode/verify."""
-        from lzy_tpu.ops.paged_attention import DISPATCHES
-
+        must move the counter by its chunk count, like decode/verify, and
+        under the label of the read each program's width gets."""
         cfg, params = tiny_model
         eng = PagedInferenceEngine(cfg, params, slots=1, page_size=8,
-                                   prefill_chunk=4)
+                                   prefill_chunk=16, kernel="pallas")
         try:
-            before = _metric_value(DISPATCHES)
-            r = eng.submit(list(range(1, 21)), max_new_tokens=3)
+            before = {path: _metric_value(DISPATCHES, path=path)
+                      for path in ("pallas", CHUNK_PATH, "lax")}
+            r = eng.submit(list(range(1, 39)), max_new_tokens=3)
             _drive(eng, r)
-            # 20-token prompt at chunk 4 = 5 prefill programs, plus the
-            # decode steps after it
-            assert _metric_value(DISPATCHES) - before >= 5 + 2
+            moved = {path: _metric_value(DISPATCHES, path=path) - n
+                     for path, n in before.items()}
+            # a 38-token prompt at chunk 16: two 16-wide programs through
+            # the chunk kernel, a tail of 6 padded to 8 (a window the
+            # decode kernel takes), plus the decode steps after it
+            assert moved[CHUNK_PATH] == 2 and moved["lax"] == 0
+            assert moved["pallas"] >= 1 + 2
         finally:
             eng.close()
+
+    def test_chunked_prefill_through_the_chunk_kernel_matches_lax(
+            self, tiny_model, chunk=64, prompt_len=150):
+        """A prompt cut into three programs, the last narrower (64, 64,
+        32), each reading the prefix the ones before it wrote: greedy
+        tokens are the lax engine's, or part from them at a logit tie."""
+        cfg, params = tiny_model
+        rng = np.random.default_rng(prompt_len)
+        prompt = rng.integers(1, cfg.vocab_size, prompt_len).tolist()
+
+        def run(kernel):
+            eng = PagedInferenceEngine(cfg, params, slots=2, page_size=8,
+                                       prefill_chunk=chunk, kernel=kernel)
+            try:
+                before = _metric_value(DISPATCHES, path=CHUNK_PATH)
+                r = eng.submit(prompt, max_new_tokens=12)
+                _drive(eng, r)
+                return r.tokens, \
+                    _metric_value(DISPATCHES, path=CHUNK_PATH) - before
+            finally:
+                eng.close()
+
+        got, programs = run("pallas")
+        assert programs == 3
+        want, programs = run("lax")
+        assert programs == 0
+        _assert_same_or_tie(cfg, params, prompt, got, want)
 
     def test_auto_kernel_resolves_by_platform(self, tiny_model, monkeypatch):
         """``"auto"`` is the code's choice from the platform it observes:
