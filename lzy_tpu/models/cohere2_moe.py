@@ -58,7 +58,8 @@ import jax.numpy as jnp
 from lzy_tpu.models import experts
 from lzy_tpu.models.experts import GatedExperts, row_mask
 from lzy_tpu.models.llama import _rope
-from lzy_tpu.models.paged_blocks import dense, normal
+from lzy_tpu.models.paged_blocks import (
+    ATTN_FULL_KEYS, ATTN_ROWS, dense, normal)
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops.paged_attention import (
     group_path, lower_group_for_tpu, paged_group_attention,
@@ -69,13 +70,6 @@ ATTN_WINDOW_KEYS = REGISTRY.counter(
     "lzy_attn_window_keys_total",
     "cached keys the real rows of decode rounds read in layers with a "
     "window (a row at position p reads min(p + 1, window)), a layer")
-ATTN_FULL_KEYS = REGISTRY.counter(
-    "lzy_attn_full_keys_total",
-    "cached keys the real rows of decode rounds read in layers that see "
-    "everything (a row at position p reads p + 1), a layer")
-ATTN_ROWS = REGISTRY.counter(
-    "lzy_attn_rows_total",
-    "real rows of decode rounds that read a paged attention layer, a layer")
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 

@@ -5,7 +5,12 @@ embedding over the shared paged pool. ``cfg`` is the model's configuration
 object: of it these read ``dtype``, ``param_dtype`` and, for the attention
 block, ``d_model``, ``n_heads``, ``n_kv_heads``, ``head_dim``, ``attn_gate``
 and the paging fields ``paged_model`` sets (``decode_paged``, ``kv_pages``,
-``kv_page_size``, ``paged_kernel``)."""
+``kv_page_size``, ``paged_kernel``). A configuration that says
+``group_read`` (``models/jamba.py``: 20 query heads over one key-value head)
+has the same pools read as ``[pages, page, KV x D]`` by
+``ops/paged_attention.py`` ``paged_group_attention``; one that does not has
+the programs it had. The two counters of a full attention layer's reads live
+here, for every family that sows them."""
 
 from __future__ import annotations
 
@@ -14,6 +19,16 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from lzy_tpu.utils.metrics import REGISTRY
+
+ATTN_FULL_KEYS = REGISTRY.counter(
+    "lzy_attn_full_keys_total",
+    "cached keys the real rows of decode rounds read in layers that see "
+    "everything (a row at position p reads p + 1), a layer")
+ATTN_ROWS = REGISTRY.counter(
+    "lzy_attn_rows_total",
+    "real rows of decode rounds that read a paged attention layer, a layer")
 
 
 def normal(std: float = 0.02):
@@ -54,11 +69,17 @@ class PagedAttention(nn.Module):
     """Grouped-query attention, no rotary embedding, over the shared paged
     pool (or, uncached, causal over the chunk). A configuration whose
     ``attn_gate`` is true has the heads' output multiplied by
-    ``sigmoid(gate_proj(u))`` before ``o_proj``."""
+    ``sigmoid(gate_proj(u))`` before ``o_proj``. ``stats`` ``(at, of)``: the
+    block sows the cached keys its real rows read (a row at position p reads
+    p + 1) and those rows into places ``at`` and ``at + 1`` of a ``stats``
+    vector of ``of`` (``models/serving.py``: the module's ``STATS``); only a
+    block told ``valid_len`` whose configuration says ``group_read``
+    counts."""
     cfg: Any
+    stats: Any = None
 
     @nn.compact
-    def __call__(self, u, page_table=None):
+    def __call__(self, u, page_table=None, valid_len=None):
         from lzy_tpu.ops.paged_attention import (
             paged_attention, paged_scatter_index)
 
@@ -81,7 +102,8 @@ class PagedAttention(nn.Module):
         pool_v = self.variable("cache", "v", jnp.zeros, shape, cfg.dtype)
         index = self.variable("cache", "index",
                               lambda: jnp.zeros((b,), jnp.int32))
-        pos = index.value[:, None] + jnp.arange(t, dtype=jnp.int32)
+        start = index.value
+        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
         if not self.is_initializing():
             if page_table is None:
                 raise ValueError("a paged forward needs page_table")
@@ -92,9 +114,41 @@ class PagedAttention(nn.Module):
             pool_v.value = pool_v.value.at[rows, offs].set(
                 v.astype(cfg.dtype).reshape(b * t, kv, d))
             index.value = index.value + t
-        out = paged_attention(q, pool_k.value, pool_v.value, page_table,
-                              pos, kernel=cfg.paged_kernel, dtype=cfg.dtype)
+        if getattr(cfg, "group_read", False):
+            out = self._group_read(q, pool_k.value, pool_v.value, page_table,
+                                   start, valid_len)
+        else:
+            out = paged_attention(q, pool_k.value, pool_v.value, page_table,
+                                  pos, kernel=cfg.paged_kernel,
+                                  dtype=cfg.dtype)
         return self._project(out, u)
+
+    def _group_read(self, q, pool_k, pool_v, page_table, start, valid_len):
+        """The read of the pools as ``[pages, page, KV x D]`` (a token's keys
+        of all heads side by side: the same bytes) from the first page to
+        the row's own by ``paged_group_attention`` (decode rounds and prefill
+        chunks alike; what it costs follows the context, not the table's
+        width), and the block's counts."""
+        from lzy_tpu.ops.paged_attention import paged_group_attention
+
+        b = q.shape[0]
+        side_by_side = pool_k.shape[:2] + (-1,)
+        real = jnp.ones((b,), bool) if valid_len is None else valid_len > 0
+        # an idle slot (no real position) reads one page, whatever its
+        # stale position says
+        out = paged_group_attention(
+            q, pool_k.reshape(side_by_side), pool_v.reshape(side_by_side),
+            page_table, jnp.where(real, start, 0),
+            kernel=self.cfg.paged_kernel)
+        if self.stats is not None and valid_len is not None:
+            at, of = self.stats
+            seen = jnp.where(real, start + valid_len.astype(jnp.int32), 0)
+            counts = jnp.zeros((of,), jnp.int32).at[at].set(
+                jnp.sum(seen)).at[at + 1].set(jnp.sum(real))
+            self.sow("stats", "attn", counts,
+                     reduce_fn=lambda a, x: a + x,
+                     init_fn=lambda: jnp.zeros((of,), jnp.int32))
+        return out.astype(self.cfg.dtype)
 
     def _project(self, out, u):
         cfg = self.cfg

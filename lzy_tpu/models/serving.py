@@ -4,8 +4,9 @@ the configuration object and by the module class it builds;
 ``models/llama.py``'s ``LlamaConfig`` / ``Llama``,
 ``models/nemotron_h.py``'s ``NemotronHConfig`` / ``NemotronH``,
 ``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2``,
-``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3`` and
-``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe`` all do.
+``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3``,
+``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe`` and
+``models/jamba.py``'s ``JambaConfig`` / ``Jamba`` all do.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -115,7 +116,9 @@ class HeadPool:
     head (``models/llama.py``, ``models/nemotron_h.py``,
     ``models/solar_open2.py``: pools ``[pages, page, KV, D]``, twice, read by
     ``ops/paged_attention.py``), from its ``n_heads``, ``n_kv_heads``,
-    ``head_dim`` and ``dtype``."""
+    ``head_dim`` and ``dtype``. ``models/jamba.py`` takes the bytes a token
+    from here and answers ``read_path`` and its lowering itself: its pools
+    are ``[pages, page, KV x D]``, read by ``paged_group_attention``."""
 
     def kv_token_bytes(self, kv_quant: Optional[str] = None) -> int:
         """Bytes one cached token costs one pool layer: keys and values a

@@ -825,12 +825,26 @@ class PagedInferenceEngine:
         self._pool_at = [i for i, k in enumerate(payload_kinds)
                          if k != serving.STATE]
         self._has_state = bool(self._state_at)
+        self._state_names = [jax.tree_util.keystr(p) for (p, _), k
+                             in zip(flat, self._leaf_kinds)
+                             if k == serving.STATE]
         # a model with state leaves, or one that counts (``STATS``), is
         # told which positions of a program are real (``valid_len``)
         self._tells_real = self._has_state or bool(type(self._model).STATS)
         # the batch-1 state rows finished (or abandoned) prefill jobs no
         # longer need: the next job's first program starts from them
         self._spare_state: List[list] = []
+
+    def state_leaves(self) -> Dict[str, Any]:
+        """The per-slot state leaves of the decode tree as they stand, by
+        their path in the cache (``{}`` for a model that has none):
+        ``[slots, ...]`` device arrays, a row a slot. A freed slot's row
+        stays as its last round left it until the next prompt's state is
+        spliced in, so what a finished request's recurrence ended at can
+        be read afterwards. A step donates these buffers: read them while
+        no round is in flight."""
+        return dict(zip(self._state_names,
+                        (self._payload[i] for i in self._state_at)))
 
     def _assemble_cache(self, payload, index_leaf):
         """Full cache tree from payload leaves + ONE index value placed

@@ -550,3 +550,64 @@ def test_gated_experts_compile_at_a_square_of_4096(rows, one_chip):
         sds((rows, 4096), jnp.bfloat16), up, up, up,
         sds((rows, 16), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the Jamba2-3B serving kernels at published widths -----------------------
+
+@pytest.mark.parametrize("case", ["scan256", "scan16", "update", "read_decode",
+                                  "read_chunk256"])
+def test_jamba_kernels_compile_at_published_widths(case, one_chip):
+    """The Mamba-1 scan of one row's chunk and the state update of 32 slots
+    at 5120 channels x 16 state entries (the state ``[B, 16, 5120]`` float32,
+    donated: updated in place, no second copy), and the attention read at 20
+    query heads over ONE key-value head (a group of 20: no multiple of 8
+    sublanes) over pools ``[16384, 128, 128]`` with a table of 512 pages a
+    slot (65,536 positions)."""
+    import importlib
+
+    from lzy_tpu.ops import mamba1
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    di, n = 5120, 16
+    if case.startswith("scan"):
+        t = int(case[4:])
+        compiled = jax.jit(
+            lambda x, dt, a, b, c, s: mamba1.selective_scan(
+                x, dt, a, b, c, s, interpret=False),
+            donate_argnums=(5,)).lower(
+            sds((1, t, di)), sds((1, t, di)), sds((n, di)), sds((1, t, n)),
+            sds((1, t, n)), sds((1, n, di))).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and "selective_scan" in text
+        # nothing the size of the [T, N, Di] products is ever allocated
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < t * n * di * 4 // 4
+    elif case == "update":
+        compiled = jax.jit(
+            lambda s, x, dt, a, b, c: mamba1.selective_state_update(
+                s, x, dt, a, b, c, interpret=False),
+            donate_argnums=(0,)).lower(
+            sds((32, n, di)), sds((32, di)), sds((32, di)), sds((n, di)),
+            sds((32, n)), sds((32, n))).compile()
+        assert "selective_state_update" in compiled.as_text()
+        state_bytes = 32 * n * di * 4
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < state_bytes // 8
+    else:
+        pa = importlib.import_module("lzy_tpu.ops.paged_attention")
+        batch, t = (32, 1) if case == "read_decode" else (1, 256)
+        pool = sds((16384, 128, 128), jnp.bfloat16)
+        compiled = jax.jit(
+            lambda q, k, v, table, start: pa.paged_group_attention(
+                q, k, v, table, start, kernel="pallas",
+                interpret=False)).lower(
+            sds((batch, t, 20, 128), jnp.bfloat16), pool, pool,
+            sds((batch, 512), jnp.int32), sds((batch,), jnp.int32)).compile()
+        text = compiled.as_text()
+        assert ("paged_group_decode" if t == 1
+                else "paged_group_prefill") in text
+        pool_bytes = 16384 * 128 * 128 * 2
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < pool_bytes // 8

@@ -53,6 +53,8 @@ def registry_metrics():
     import lzy_tpu.models.deepseek_v3  # noqa: F401
     # a model with window layers: keys read by kind of layer, a round
     import lzy_tpu.models.cohere2_moe  # noqa: F401
+    # a model with Mamba-1 layers: live rows whose state a round moved
+    import lzy_tpu.models.jamba  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
