@@ -345,11 +345,11 @@ class LatentAttention(nn.Module):
                 pool.value = pool.value.at[rows, offs].set(
                     lat.reshape(b * t, w))
                 index.value = index.value + t
-            # an idle slot (no real position) reads one page, whatever its
-            # stale position says
+            # an idle slot (no real position) is told so, whatever its stale
+            # position says: the read skips it and gives it 0
             summed = mla.mla_attention(
                 q_full, pool.value, page_table,
-                jnp.where(real[:, 0], start, 0), value_dim=r,
+                jnp.where(real[:, 0], start, -1), value_dim=r,
                 scale=cfg.softmax_scale, kernel=cfg.paged_kernel)
             # the last real query of a row at position p reads p + 1
             last = pos[:, 0] + jnp.sum(real, axis=1)

@@ -19,24 +19,29 @@ per-head keys and values; a page is read once and serves scores and values of
 every head.
 
 - :func:`mla_attention` with ``kernel="pallas"``: one kernel body under two
-  names a device trace shows, ``mla_paged_decode`` (one grid cell a batch
-  row: plain decode and the speculative verify window) and
-  ``mla_paged_prefill`` (a batch-1 chunk cut into tiles of
+  names a device trace shows, ``mla_paged_decode`` (plain decode and the
+  speculative verify window: a grid cell walks as many batch rows as keep
+  its q block within ``_CELL_ROWS`` rows, all 32 slots of 16 heads in one
+  cell) and ``mla_paged_prefill`` (a batch-1 chunk cut into tiles of
   ``_PREFILL_TILE`` query positions, one grid cell a tile). The pool stays in
   HBM (``memory_space=ANY``); the page table and each row's first position
-  arrive by scalar prefetch; a cell copies the pages its last query can
+  arrive by scalar prefetch; a live row copies the pages its last query can
   see, and no more, into VMEM by DMA, a block of pages in flight while the
   block before it is scored, and folds them into an online softmax (float32
-  scores, running max and sum). An idle slot (position 0, zeroed table)
-  reads one page. What it costs follows the live context, not
-  ``max_seq_len``: both are the full softmax over the whole prefix.
+  scores, running max and sum). What it costs follows the live rows and
+  their live context, not the slots and not ``max_seq_len``: both are the
+  full softmax over the whole prefix.
 - ``kernel="lax"``: the same sum in plain ``jax.numpy`` over the gathered
   table (every page of the table, live or not): the portable path and the
   kernel's oracle.
 
 Query positions of a row are consecutive (``start + t``), as every program of
 the engine makes them (decode ``T = 1``, verify ``T = gamma + 1``, a prefill
-chunk), so the causal mask needs each row's first position only.
+chunk), so the causal mask needs each row's first position only. **A row
+whose ``start`` is below 0 is idle** (a slot with no request in it; position
+0 is a live row with one visible key): under both kernels its result is 0,
+and the Pallas read spends a scalar compare on it, no page, no block of
+scores, no wait.
 """
 
 from __future__ import annotations
@@ -75,6 +80,29 @@ MAX_DECODE_TOKENS = 8
 #: the kernel's VMEM.
 _PREFILL_TILE = 64
 _BLOCK_POSITIONS = 512
+#: q rows (batch rows x window x heads) of the batch rows one grid cell of
+#: the decode read walks: 32 slots of 16 heads in one cell (655 KB of q and
+#: 524 KB of result in VMEM), 4 slots a cell for a verify window of 5.
+#: Decode on a v5e chip, 32 slots of 16 heads, page 16, us a call by the
+#: rows that are live, the rest idle (PERF.md section 6, PR 45; device time,
+#: median of 81 calls; "before": one grid cell a slot, an idle slot reading
+#: one page and scoring one block):
+#:
+#:     live rows at 4,096     0      1      2      4      8      32
+#:     before               40.4   49.8   59.1   78.0  116.6  342.9
+#:     now                   2.6   13.2   23.6   45.2   87.7  342.8
+#:
+#: 32 rows at 7,936: 666.5 -> 665.7 (488 GB/s); 8 rows at 1,024: 55.5 ->
+#: 27.0; a verify window of 5 at 4,096, 11 slots of 32 live: 202.1 -> 169.3;
+#: a prefill chunk of 256 at 3,840: 255.3 -> 255.0. A live row's result is
+#: the same to the bit in every case. Two forms that were measured and did
+#: not stay: row ids sorted live-first by scalar prefetch over a grid of 32
+#: cells, the idle ones standing still (14.6 / 25.6 at 1 / 2 live rows, and
+#: 1.6 us a call of sort and select outside the kernel); a live row's last
+#: block starting the next live row's first (25.0 at 2 live rows, 353.8 at
+#: 32: the loop body that can start another row's pages is 0.07 us a block
+#: slower, which is more than the wait it saves).
+_CELL_ROWS = 512
 
 
 def read_path(kernel: str, *, t: int) -> str:
@@ -102,11 +130,12 @@ def lax_mla_attention(q, pool, page_table, start, *, value_dim: int,
                       scale: float):
     """The absorbed sum over the whole table: ``pool`` [n_blocks, page, W]
     gathered through ``page_table`` [B, P], every page of it, live or not;
-    ``start`` [B]."""
+    ``start`` [B], below 0 for an idle row, whose result is 0."""
     b, t, _, w = q.shape
     pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-    return _absorbed(q, pool[page_table].reshape(b, -1, w), pos,
-                     value_dim=value_dim, scale=scale)
+    out = _absorbed(q, pool[page_table].reshape(b, -1, w), pos,
+                    value_dim=value_dim, scale=scale)
+    return jnp.where((start >= 0)[:, None, None, None], out, 0)
 
 
 def causal_mla_attention(q, lat, *, value_dim: int, scale: float):
@@ -118,76 +147,102 @@ def causal_mla_attention(q, lat, *, value_dim: int, scale: float):
 
 
 def _kernel(start_ref, pt_ref, q_ref, pool_hbm, o_ref, buf, sems, m_ref,
-            l_ref, acc_ref, *, tq, heads, page, pages_per_seq, block_pages,
-            value_dim, scale):
-    """One grid cell: ``tq`` consecutive query positions of batch row ``b``
-    (``tq * heads`` rows of the q tile, position-major) against the pages
-    the last of them can see. Numerics: scores, the running max and sum and
-    the accumulator in float32, scaled after the dot; probabilities cast to
-    the pool's dtype before the value contraction; the sum of the float32
-    probabilities divides the accumulator once at the end."""
-    b, i = pl.program_id(0), pl.program_id(1)
+            l_ref, acc_ref, *, group, tq, heads, page, pages_per_seq,
+            block_pages, value_dim, scale):
+    """One grid cell: ``group`` batch rows at tile ``i`` of their query
+    window, walked in order. A live row is ``tq`` consecutive query
+    positions (``tq * heads`` rows of q, position-major) against the pages
+    the last of them can see; an idle row (``start`` below 0) is given 0
+    and costs a scalar compare: no page, no block of scores, no wait.
+    Numerics: scores, the running max and sum and the accumulator in
+    float32, scaled after the dot; probabilities cast to the pool's dtype
+    before the value contraction; the sum of the float32 probabilities
+    divides the accumulator once at the end."""
+    g, i = pl.program_id(0), pl.program_id(1)
     rows = tq * heads
     cols = block_pages * page
-    first = start_ref[b] + i * tq
-    n_pages = lax.div(jnp.maximum(first + tq - 1 + page, 0), page)
-    n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
-    # per q row: the last pooled position it sees
-    row_pos = first + lax.div(
+    # per q row: its position past the window's first
+    row_off = i * tq + lax.div(
         lax.broadcasted_iota(jnp.int32, (rows, 1), 0), heads)
     col = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
 
-    @pl.when((b == 0) & (i == 0))
+    @pl.when((g == 0) & (i == 0))
     def _():
         # a partial block leaves rows of the buffer unwritten; their
         # probabilities are 0, and 0 x whatever VMEM held must be 0
         buf[...] = jnp.zeros_like(buf)
 
-    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    def row(rl, _):
+        r = g * group + rl
+        first = start_ref[r]
 
-    def for_pages(j, slot, op):
-        for k in range(block_pages):
-            @pl.when(j * block_pages + k < n_pages)
-            def _():
-                pid = pt_ref[b * pages_per_seq + j * block_pages + k]
-                op(pltpu.make_async_copy(
-                    pool_hbm.at[pid], buf.at[slot, pl.ds(k * page, page)],
-                    sems.at[slot]))
-
-    for_pages(0, 0, lambda c: c.start())
-
-    def body(j, _):
-        slot = lax.rem(j, 2)
-
-        @pl.when(j + 1 < n_blocks)
+        @pl.when(first < 0)
         def _():
-            for_pages(j + 1, 1 - slot, lambda c: c.start())
+            o_ref[rl] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
-        for_pages(j, slot, lambda c: c.wait())
-        lat = buf[slot]                                       # [cols, W]
-        s = lax.dot_general(
-            q_ref[...], lat, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [rows, cols]
-        visible = col <= row_pos - j * cols
-        s = jnp.where(visible, s, _NEG_INF)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
-            p.astype(lat.dtype), lat[:, :value_dim],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        @pl.when(first >= 0)
+        def _():
+            # per q row: the last pooled position it sees
+            row_pos = first + row_off
+            n_pages = lax.div(first + (i + 1) * tq - 1 + page, page)
+            n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+            def for_pages(j, slot, op):
+                for k in range(block_pages):
+                    @pl.when(j * block_pages + k < n_pages)
+                    def _():
+                        pid = pt_ref[r * pages_per_seq + j * block_pages + k]
+                        op(pltpu.make_async_copy(
+                            pool_hbm.at[pid],
+                            buf.at[slot, pl.ds(k * page, page)],
+                            sems.at[slot]))
+
+            for_pages(0, 0, lambda c: c.start())
+
+            def body(j, _):
+                slot = lax.rem(j, 2)
+
+                @pl.when(j + 1 < n_blocks)
+                def _():
+                    for_pages(j + 1, 1 - slot, lambda c: c.start())
+
+                for_pages(j, slot, lambda c: c.wait())
+                lat = buf[slot]                               # [cols, W]
+                s = lax.dot_general(
+                    q_ref[rl], lat, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                visible = col <= row_pos - j * cols           # [rows, cols]
+                s = jnp.where(visible, s, _NEG_INF)
+                m = m_ref[...]
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+                l_ref[...] = alpha * l_ref[...] + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                acc_ref[...] = alpha * acc_ref[...] + lax.dot_general(
+                    p.astype(lat.dtype), lat[:, :value_dim],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[...] = m_new
+                return 0
+
+            lax.fori_loop(0, n_blocks, body, 0)
+            # a live row sees its own position at least: the sum is not 0
+            o_ref[rl] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
         return 0
 
-    lax.fori_loop(0, n_blocks, body, 0)
-    l = l_ref[...]
-    # a row that sees nothing (position -1) reads nothing and returns 0
-    o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
-        o_ref.dtype)
+    lax.fori_loop(0, group, row, 0)
+
+
+def _group(b: int, rows: int) -> int:
+    """Batch rows a grid cell walks: the most that divide the batch and
+    keep the cell's q block within ``_CELL_ROWS`` rows."""
+    return max(g for g in range(1, b + 1)
+               if b % g == 0 and (g == 1 or g * rows <= _CELL_ROWS))
 
 
 @functools.partial(jax.jit, static_argnames=("value_dim", "scale",
@@ -205,21 +260,22 @@ def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
         raise ValueError(
             f"a prefill chunk of {t} positions is not whole tiles of {tq}")
     rows = tq * h
+    group = _group(b, rows)
     block_pages = max(1, min(pages, _BLOCK_POSITIONS // page))
     kernel = functools.partial(
-        _kernel, tq=tq, heads=h, page=page, pages_per_seq=pages,
+        _kernel, group=group, tq=tq, heads=h, page=page, pages_per_seq=pages,
         block_pages=block_pages, value_dim=value_dim, scale=scale)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, t // tq),
+            grid=(b // group, t // tq),
             in_specs=[
-                pl.BlockSpec((None, rows, w), lambda bi, i, *_: (bi, i, 0)),
+                pl.BlockSpec((group, rows, w), lambda g, i, *_: (g, i, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((None, rows, value_dim),
-                                   lambda bi, i, *_: (bi, i, 0)),
+            out_specs=pl.BlockSpec((group, rows, value_dim),
+                                   lambda g, i, *_: (g, i, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, block_pages * page, w), pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
@@ -252,6 +308,8 @@ def mla_attention(q: jax.Array, pool: jax.Array, page_table: jax.Array,
     - ``page_table``: ``[B, P]`` int32 block ids in position order;
     - ``start``: ``[B]`` int32, the position of each row's first query; query
       ``t`` sits at ``start + t`` and sees pooled positions up to itself;
+      below 0 for an idle row, whose result is 0 and whose pages (the
+      kernel's) are not read;
     - ``kernel``: ``"lax"`` or ``"pallas"`` (``interpret=None`` takes the
       process's ``ops.interpret`` setting).
 

@@ -30,7 +30,7 @@ def _float32(q, pool, table, start, v, scale):
 
 #: (batch, query positions, first positions): decode over a page boundary
 #: (15 -> 16), a block boundary (the kernel scores 512 positions a block: 32
-#: pages of 16) and an idle-like row at 0; the verify window across the block
+#: pages of 16) and a row at 0; the verify window across the block
 #: boundary (510, 511, 512); prefill chunks cut into tiles (128 = 2 x 64, the
 #: second across the block boundary) and one narrower than a tile (16).
 #: Pages of 16 here: 36 of them reach past the block's 512 positions
@@ -78,23 +78,78 @@ def test_softmax_is_float32_where_bfloat16_scores_would_tie():
 
 
 def test_an_idle_slot_and_pages_past_the_context_change_nothing():
-    """A row reads the pages its position reaches and no more: garbage (NaN)
-    in every other block of the pool, the scratch block 0 among them, does
-    not reach a live row's result; an idle row (position 0, zeroed table)
-    reads block 0 alone and returns finite garbage nobody reads."""
+    """A row reads the pages its position reaches and no more, and an idle
+    row (``start`` below 0) reads nothing at all: with NaN in every block of
+    the pool but the live row's pages, the scratch block 0 too, the live
+    row's result is the clean pool's bit for bit and the idle row's is 0."""
     h, w, v, page, pages = 2, 128, 64, 8, 6
     q, pool, table = _case(2, 1, h, w, 14, page, pages, jnp.float32)
     table = table.at[1].set(0)                       # row 1 idle
-    start = jnp.asarray([11, 0], jnp.int32)          # row 0: two pages
+    start = jnp.asarray([11, -1], jnp.int32)         # row 0: two pages
     live = np.asarray(table[0, :2])
-    dirty = jnp.full_like(pool, jnp.nan).at[live].set(pool[live]) \
-        .at[0].set(pool[0])
+    dirty = jnp.full_like(pool, jnp.nan).at[live].set(pool[live])
     want = mla.mla_attention(q, pool, table, start, value_dim=v,
                              scale=0.1, kernel="pallas")
     got = mla.mla_attention(q, dirty, table, start, value_dim=v, scale=0.1,
                             kernel="pallas")
     assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
-    assert np.isfinite(np.asarray(got)).all()
+    assert np.isfinite(np.asarray(want[0])).all()
+    assert not np.asarray(got[1]).any()
+
+
+#: which of four slots hold a row
+_LIVE = {
+    "none": [False, False, False, False],
+    "first": [True, False, False, False],
+    "last": [False, False, False, True],
+    "alternating": [False, True, False, True],
+    "all": [True, True, True, True],
+}
+
+
+@pytest.mark.parametrize("t", [1, 4])
+@pytest.mark.parametrize("pattern", list(_LIVE))
+def test_live_rows_are_read_and_idle_rows_are_zero(pattern, t):
+    """Decode and the verify window over every pattern of live slots: a live
+    row is the lax oracle's and float32's at the file's tolerances, whatever
+    its neighbours are (bit for bit what it is when all four are live); an
+    idle row is 0 under both kernels. The first positions cross a page (15)
+    and the block boundary (509-512 with four queries)."""
+    h, w, v, page, pages, dtype = 2, 128, 64, 16, 36, "bfloat16"
+    q, pool, whole = _case(4, t, h, w, 1 + 4 * pages, page, pages,
+                           jnp.dtype(dtype), seed=5)
+    live = np.asarray(_LIVE[pattern])
+    full = jnp.asarray([15, 509, 3, 530], jnp.int32)
+    start = jnp.where(live, full, -1)
+    table = jnp.where(live[:, None], whole, 0)
+    kw = dict(value_dim=v, scale=w ** -0.5)
+    got = np.asarray(mla.mla_attention(
+        q, pool, table, start, kernel="pallas", **kw), np.float32)
+    lax = np.asarray(mla.mla_attention(
+        q, pool, table, start, kernel="lax", **kw), np.float32)
+    assert not got[~live].any() and not lax[~live].any()
+    if live.any():
+        exact = _float32(q, pool, table, start, v, w ** -0.5)[live]
+        scale = max(1.0, float(np.abs(exact).max()))
+        for out in (lax, got):
+            assert np.abs(out[live] - exact).max() / scale < TOLERANCE[dtype]
+        every = np.asarray(mla.mla_attention(
+            q, pool, whole, full, kernel="pallas", **kw), np.float32)
+        assert np.array_equal(got[live], every[live])
+
+
+def test_a_live_row_at_position_0_is_still_read():
+    """Position 0 is a row with one visible key, not an idle slot: its
+    result is that key's own values, beside an idle row's 0."""
+    h, w, v, page, pages = 2, 128, 64, 8, 3
+    q, pool, table = _case(2, 1, h, w, 7, page, pages, jnp.float32, seed=2)
+    start = jnp.asarray([0, -1], jnp.int32)
+    own = np.asarray(pool[table[0, 0], 0, :v])
+    for kernel in ("lax", "pallas"):
+        got = np.asarray(mla.mla_attention(
+            q, pool, table, start, value_dim=v, scale=0.1, kernel=kernel))
+        assert np.abs(got[0, 0] - own[None, :]).max() < 1e-6, kernel
+        assert not got[1].any(), kernel
 
 
 def test_the_uncached_form_is_the_paged_form():
