@@ -75,6 +75,9 @@ counts is told which positions are real (``valid_len``).
   speculated position rewound by moving an index: every mechanism moves
   pages by block id and reads no shape past the page axis, so a latent
   leaf is served by all of them (``docs/serving.md`` has the table).
+  Block 0 is the scratch block no row owns, so a row whose table starts
+  with the scratch block has no real position: the decode read
+  (``ops/paged_attention.py``) gives it 0 and reads nothing for it.
 - ``window``: a pool of pages like ``paged``, with a second lifetime: a
   token's entry stops being read ``kv_window`` positions behind the row's
   newest, so the engine returns a page that lies wholly behind the window

@@ -56,6 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.ops.paged_attention import cell_group
 
 _NEG_INF = -1e30
 
@@ -238,13 +239,6 @@ def _kernel(start_ref, pt_ref, q_ref, pool_hbm, o_ref, buf, sems, m_ref,
     lax.fori_loop(0, group, row, 0)
 
 
-def _group(b: int, rows: int) -> int:
-    """Batch rows a grid cell walks: the most that divide the batch and
-    keep the cell's q block within ``_CELL_ROWS`` rows."""
-    return max(g for g in range(1, b + 1)
-               if b % g == 0 and (g == 1 or g * rows <= _CELL_ROWS))
-
-
 @functools.partial(jax.jit, static_argnames=("value_dim", "scale",
                                              "interpret"))
 def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
@@ -260,7 +254,7 @@ def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
         raise ValueError(
             f"a prefill chunk of {t} positions is not whole tiles of {tq}")
     rows = tq * h
-    group = _group(b, rows)
+    group = cell_group(b, rows, _CELL_ROWS)
     block_pages = max(1, min(pages, _BLOCK_POSITIONS // page))
     kernel = functools.partial(
         _kernel, group=group, tq=tq, heads=h, page=page, pages_per_seq=pages,

@@ -31,10 +31,15 @@ back, the one read path of the serving engine:
     - the **decode kernel** (``paged_decode_attention``): ``T <=
       MAX_Q_TOKENS`` query positions a row, which is plain decode and the
       speculative verify window (``T = gamma + 1``, the same q tile ``T``
-      times taller); one grid cell a batch row; every query head scored
+      times taller); one grid cell walks the batch rows (a decode batch
+      whole, a wide window's in groups); every query head scored
       against every row of a page behind a block-diagonal mask, ``KV``
-      times the arithmetic on a read that the HBM bounds. An idle slot
-      (position 0, zeroed table) reads one page.
+      times the arithmetic on a read that the HBM bounds. **A row whose
+      table starts with the scratch block is an idle slot** (block 0 is
+      no row's; the engine zeroes a freed slot's table and leaves its
+      position stale): its result is exactly 0 and it costs a scalar
+      compare, no page, no block of scores and no wait. What a call costs
+      follows the rows that are live, not the slots.
     - the **chunk kernel** (``paged_chunk_attention``): a wider window
       at the consecutive positions ``start + t``, which is a prefill chunk
       (or the verify window of ``gamma >= 8``, a grid cell a slot); a grid
@@ -258,6 +263,26 @@ CHUNK_PATH = "chunk_pallas"
 #: buffers (K and V), double-buffered.
 _BLOCK_ROWS = 1024
 
+#: q rows (batch rows x window x heads) of the batch rows one grid cell of
+#: the decode kernel walks: every decode batch the engine runs is one cell
+#: (32 slots of 32 or 64 heads, 64 slots of 32: 262 or 524 KB of q and as
+#: much of result in VMEM); a verify window of 5 over 32 slots of 32 heads
+#: is 4 cells of 8 slots. Decode on a v5e chip, 32 slots of 32 / 8 heads of
+#: 128, bfloat16, page 16, us a call by the rows that are live at 1,024 of
+#: context, the rest idle (PERF.md section 6, PR 46; device time, median of
+#: 48 calls; "before": one grid cell a slot, an idle slot reading one page
+#: and scoring one block):
+#:
+#:     live rows at 1,024     0      1      2      4      8      32
+#:     before               31.7   38.2   44.6   57.3   82.6  236.0
+#:     now                   2.0    9.3   16.6   31.2   60.4  235.5
+#:
+#: 30 rows at 370: 101.1 -> 99.0; the verify window of 5, 11 slots of 32
+#: live: 143.0 -> 114.3; a prefill tail (batch 1, 8 positions): 13.74 ->
+#: 13.78; 64 slots of 32 / 2 heads, 21 live: 117.8 -> 68.9. A live row's
+#: result is the same to the bit in every case.
+_CELL_ROWS = 2048
+
 
 def kernel_path(kernel: str, *, t: int, quantized: bool) -> str:
     """The path a ``paged_attention(kernel=kernel)`` call takes at this
@@ -274,14 +299,17 @@ def kernel_path(kernel: str, *, t: int, quantized: bool) -> str:
 
 
 def _decode_kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   k_buf, v_buf, sems, *, t, heads, kv_heads, page,
+                   k_buf, v_buf, sems, *, group, t, heads, kv_heads, page,
                    pages_per_seq, block_pages, scale):
-    """One batch row per grid cell. The row's ``[T*H, D]`` query tile is
-    scored against the row's live pages only: ``ceil(len / page)`` pages
-    are copied from the HBM pool by DMA, ``block_pages`` at a time into
-    one of two VMEM buffers (the next block's copies are in flight while
-    this one is scored), and folded into a running max / sum / weighted
-    value (online softmax).
+    """One grid cell walks ``group`` batch rows in order (all of a decode
+    batch: one cell a call). A row none of whose positions reaches 0 is an
+    idle slot: it is given 0 and costs a scalar compare, no page, no block
+    of scores, no wait. A live row's ``[T*H, D]`` query tile is scored
+    against the row's live pages only: ``ceil(len / page)`` pages are copied
+    from the HBM pool by DMA, ``block_pages`` at a time into one of two VMEM
+    buffers (the next block's copies are in flight while this one is
+    scored), and folded into a running max / sum / weighted value (online
+    softmax).
 
     A page arrives as ``[page * KV, D]``: the pool's own layout, rows
     ordered ``(position, kv head)``. Every query head is scored against
@@ -294,77 +322,107 @@ def _decode_kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref,
     the dot, probabilities cast to the pool's dtype before the value
     contraction (as the lax path does), the sum of the float32
     probabilities divides the float32 accumulator once at the end."""
-    b = pl.program_id(0)
-    m_rows, d = q_ref.shape
+    cell = pl.program_id(0)
+    _, m_rows, d = q_ref.shape
     rows = page * kv_heads
     cols = block_pages * rows
     g = heads // kv_heads
 
-    # per query row (t-major over heads): the last position it sees
+    # what no row changes, built once a call: each query row's place in
+    # the window (t-major over heads) and the block-diagonal mask
     row_t = lax.div(lax.broadcasted_iota(jnp.int32, (m_rows, 1), 0), heads)
-    last = pos_ref[b * t]
-    pos_rows = jnp.full((m_rows, 1), last, jnp.int32)
-    for ti in range(1, t):
-        p_ti = pos_ref[b * t + ti]
-        pos_rows = jnp.where(row_t == ti, p_ti, pos_rows)
-        last = jnp.maximum(last, p_ti)
-    n_pages = lax.div(jnp.maximum(last + page, 0), page)
-    n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
-
     col = lax.broadcasted_iota(jnp.int32, (m_rows, cols), 1)
     row = lax.broadcasted_iota(jnp.int32, (m_rows, cols), 0)
     own_head = lax.rem(col, kv_heads) == lax.div(lax.rem(row, heads), g)
     col_pos = lax.div(col, kv_heads)
 
-    @pl.when(b == 0)
+    @pl.when(cell == 0)
     def _():
         # a partial block leaves rows of the buffer unwritten; their
         # probabilities are 0, and 0 x whatever VMEM held must be 0
         v_buf[...] = jnp.zeros_like(v_buf)
 
-    def for_pages(j, slot, op):
-        for i in range(block_pages):
-            @pl.when(j * block_pages + i < n_pages)
-            def _():
-                pid = pt_ref[b * pages_per_seq + j * block_pages + i]
-                dst = pl.ds(i * rows, rows)
-                op(pltpu.make_async_copy(
-                    k_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]))
-                op(pltpu.make_async_copy(
-                    v_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
+    def one_row(rl, _):
+        b = cell * group + rl
+        # the last position any of the row's queries sees
+        last = pos_ref[b * t]
+        for ti in range(1, t):
+            last = jnp.maximum(last, pos_ref[b * t + ti])
 
-    for_pages(0, 0, lambda c: c.start())
-
-    def body(j, carry):
-        m, l, acc = carry
-        slot = lax.rem(j, 2)
-
-        @pl.when(j + 1 < n_blocks)
+        @pl.when(last < 0)
         def _():
-            for_pages(j + 1, 1 - slot, lambda c: c.start())
+            o_ref[rl] = jnp.zeros((m_rows, d), o_ref.dtype)
 
-        for_pages(j, slot, lambda c: c.wait())
-        s = lax.dot_general(
-            q_ref[...], k_buf[slot], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [T*H, cols]
-        visible = own_head & (col_pos <= pos_rows - j * (block_pages * page))
-        s = jnp.where(visible, s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
-        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc = alpha * acc + lax.dot_general(
-            p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        @pl.when(last >= 0)
+        def _():
+            # per query row: the last position it sees
+            pos_rows = jnp.full((m_rows, 1), pos_ref[b * t], jnp.int32)
+            for ti in range(1, t):
+                pos_rows = jnp.where(row_t == ti, pos_ref[b * t + ti],
+                                     pos_rows)
+            n_pages = lax.div(last + page, page)
+            n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
 
-    _, l, acc = lax.fori_loop(
-        0, n_blocks, body,
-        (jnp.full((m_rows, 1), _NEG_INF, jnp.float32),
-         jnp.zeros((m_rows, 1), jnp.float32),
-         jnp.zeros((m_rows, d), jnp.float32)))
-    # a row that sees nothing (position -1) reads nothing and returns 0
-    o_ref[...] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+            def for_pages(j, slot, op):
+                for i in range(block_pages):
+                    @pl.when(j * block_pages + i < n_pages)
+                    def _():
+                        pid = pt_ref[b * pages_per_seq + j * block_pages + i]
+                        dst = pl.ds(i * rows, rows)
+                        op(pltpu.make_async_copy(
+                            k_hbm.at[pid], k_buf.at[slot, dst],
+                            sems.at[0, slot]))
+                        op(pltpu.make_async_copy(
+                            v_hbm.at[pid], v_buf.at[slot, dst],
+                            sems.at[1, slot]))
+
+            for_pages(0, 0, lambda c: c.start())
+
+            def body(j, carry):
+                m, l, acc = carry
+                slot = lax.rem(j, 2)
+
+                @pl.when(j + 1 < n_blocks)
+                def _():
+                    for_pages(j + 1, 1 - slot, lambda c: c.start())
+
+                for_pages(j, slot, lambda c: c.wait())
+                s = lax.dot_general(
+                    q_ref[rl], k_buf[slot], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # [T*H, cols]
+                visible = own_head & (
+                    col_pos <= pos_rows - j * (block_pages * page))
+                s = jnp.where(visible, s, _NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.where(visible, jnp.exp(s - m_new), 0.0)
+                l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+                acc = alpha * acc + lax.dot_general(
+                    p.astype(v_buf.dtype), v_buf[slot],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return m_new, l, acc
+
+            _, l, acc = lax.fori_loop(
+                0, n_blocks, body,
+                (jnp.full((m_rows, 1), _NEG_INF, jnp.float32),
+                 jnp.zeros((m_rows, 1), jnp.float32),
+                 jnp.zeros((m_rows, d), jnp.float32)))
+            # a query of a live row that sees nothing (position -1) is 0
+            o_ref[rl] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(
+                o_ref.dtype)
+
+        return 0
+
+    lax.fori_loop(0, group, one_row, 0)
+
+
+def cell_group(b: int, m_rows: int, limit: int) -> int:
+    """Batch rows one grid cell of a decode read walks (this kernel's and
+    ``ops/mla.py``'s): the most that divide the batch and keep the cell's q
+    block, ``m_rows`` rows a batch row, within ``limit`` rows."""
+    return max(g for g in range(1, b + 1)
+               if b % g == 0 and (g == 1 or g * m_rows <= limit))
 
 
 @functools.partial(jax.jit,
@@ -380,16 +438,22 @@ def _pallas_paged_attention(q, k_pool, v_pool, page_table, positions, *,
     pages = page_table.shape[1]
     rows = page * kv_heads
     block_pages = max(1, min(pages, block_rows // rows))
+    group = cell_group(b, t * h, _CELL_ROWS)
     kernel = functools.partial(
-        _decode_kernel, t=t, heads=h, kv_heads=kv_heads, page=page,
-        pages_per_seq=pages, block_pages=block_pages, scale=d ** -0.5)
-    tile = pl.BlockSpec((None, t * h, d), lambda bi, *_: (bi, 0, 0))
+        _decode_kernel, group=group, t=t, heads=h, kv_heads=kv_heads,
+        page=page, pages_per_seq=pages, block_pages=block_pages,
+        scale=d ** -0.5)
+    # a row whose table starts with the scratch block, which no row owns,
+    # is an idle slot: it has no real position, whatever its index says
+    positions = jnp.where(page_table[:, :1] != 0,
+                          positions.astype(jnp.int32), -1)
+    tile = pl.BlockSpec((group, t * h, d), lambda c, *_: (c, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b,),
+            grid=(b // group,),
             in_specs=[tile, pool, pool],
             out_specs=tile,
             scratch_shapes=[
@@ -403,7 +467,7 @@ def _pallas_paged_attention(q, k_pool, v_pool, page_table, positions, *,
             dimension_semantics=("arbitrary",)),
         interpret=_interpret.tpu_params(interpret),
         name="paged_decode_attention",
-    )(positions.astype(jnp.int32).reshape(-1),
+    )(positions.reshape(-1),
       page_table.astype(jnp.int32).reshape(-1),
       q.astype(k_pool.dtype).reshape(b, t * h, d),
       # a page is contiguous in the pool: [page, KV, D] -> [page*KV, D]
@@ -903,7 +967,10 @@ def paged_attention(
     - ``k_pool``/``v_pool``: ``[n_blocks, page_size, KV, D]`` pooled
       cache (float, or int8 with ``quant`` sidecars);
     - ``page_table``: ``[B, P]`` int32 block ids in position order
-      (id 0 = the reserved scratch block);
+      (id 0 = the reserved scratch block; a row whose first entry is 0 is
+      an idle slot to the decode kernel, which gives it 0 and reads no
+      page for it: the lax read and the chunk kernel score what its
+      positions say, and nobody reads an idle slot's result);
     - ``positions``: ``[B, T]`` int32 absolute positions of the queries
       (the causal mask: pooled slot ``l`` is visible iff
       ``l <= position``);

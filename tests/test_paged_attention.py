@@ -74,6 +74,14 @@ def _metric_value(metric, **labels) -> float:
                if want <= set(key))
 
 
+def _module():
+    """``lzy_tpu.ops.paged_attention``, the module (``lzy_tpu.ops`` exports
+    the function under the same name)."""
+    import importlib
+
+    return importlib.import_module("lzy_tpu.ops.paged_attention")
+
+
 def _reference(q, k_pool, v_pool, pt, pos):
     """Float32 attention over each row's gathered pages, one (row,
     position, head) at a time: nothing shared with the code under test.
@@ -198,7 +206,7 @@ def _edge_case(name, *, page=8, pages=6, block_pages=4):
     n puts the query at position n - 1 and owns ceil(n / page) pages."""
     L = pages * page
     return {
-        "idle_slot": [1, 1, 1],                  # position 0, scratch table
+        "idle_slot": [1, 2 * page + 3, 1],       # position 0, zeroed table
         "length_0": [0, 5, 0],                   # a query that sees nothing
         "length_1": [1, 2, 1],
         "page_boundary": [page, 2 * page, 3 * page],
@@ -238,10 +246,7 @@ class TestDecodeKernel:
         """kv=2 and page=8 make a page 16 pool rows; a compute block of 64
         rows is 4 pages, so 6-page tables run one full and one partial
         block, double-buffered."""
-        import importlib
-
-        kernel = importlib.import_module(
-            "lzy_tpu.ops.paged_attention")._pallas_paged_attention
+        kernel = _module()._pallas_paged_attention
         page, pages, kv, g, d = 8, 6, 2, 2, 16
         lens = np.asarray(_edge_case(case, page=page, pages=pages))
         rng = np.random.default_rng(len(case))
@@ -253,16 +258,118 @@ class TestDecodeKernel:
         vp = jnp.asarray(rng.standard_normal((n, page, kv, d)), dtype)
         pt = np.zeros((b, pages), np.int32)
         ids = rng.permutation(np.arange(1, n))
+        # an idle slot keeps the zeroed table and a stale position 0; a live
+        # row of one position owns a page like any other
+        idle = (lens == 1) if case == "idle_slot" else (lens == 0)
         for row, owned in enumerate(-(-lens // page)):
-            if lens[row] > 1:                    # idle rows keep scratch
+            if not idle[row]:
                 pt[row, :owned], ids = ids[:owned], ids[owned:]
         pos = jnp.asarray(lens[:, None] - 1, jnp.int32)
         got = kernel(q, kp, vp, jnp.asarray(pt), pos, dtype=dtype,
                      interpret=True, block_rows=64)
-        _assert_within_tolerance(got, _reference(q, kp, vp, pt, pos),
-                                 dtype, case)
-        if case == "length_0":
-            assert not np.asarray(got, np.float32)[[0, 2]].any()
+        want = _reference(q, kp, vp, pt, pos)
+        want[idle] = 0.0
+        _assert_within_tolerance(got, want, dtype, case)
+        assert not np.asarray(got, np.float32)[idle].any()
+        assert np.asarray(got, np.float32)[~idle].any(axis=(1, 2, 3, 4)).all()
+
+    @staticmethod
+    def _live_case(live, *, t, page=8, pages=6, kv=2, g=2, d=16,
+                   dtype=jnp.bfloat16):
+        """Rows by ``live``: a live row owns the pages under its positions
+        (the tail of its table is scratch, as a row not yet grown); an idle
+        one keeps the zeroed table and a stale position, as the engine
+        leaves a slot. The pools are float32 arrays of numpy, for a test
+        to poison."""
+        b = len(live)
+        rng = np.random.default_rng(1000 * t + int(live.sum()) + b)
+        n = b * pages + 1
+        q = jnp.asarray(rng.standard_normal((b, t, kv * g, d)), dtype)
+        k_pool = rng.standard_normal((n, page, kv, d)).astype(np.float32)
+        v_pool = rng.standard_normal((n, page, kv, d)).astype(np.float32)
+        starts = rng.integers(0, pages * page - t + 1, size=b)
+        starts[~live] = rng.integers(0, 3, size=int((~live).sum()))
+        ids = rng.permutation(np.arange(1, n))
+        pt = np.zeros((b, pages), np.int32)
+        for row in np.flatnonzero(live):
+            owned = -(-(starts[row] + t) // page)
+            pt[row, :owned], ids = ids[:owned], ids[owned:]
+        pos = starts[:, None] + np.arange(t)[None, :]
+        return (q, k_pool, v_pool, jnp.asarray(pt),
+                jnp.asarray(pos, jnp.int32))
+
+    @pytest.mark.parametrize("cell_rows", [2048, 16],
+                             ids=["one_cell", "cells"])
+    @pytest.mark.parametrize("t", [1, 5], ids=["decode", "verify"])
+    @pytest.mark.parametrize("pattern", [
+        "none_live", "first_only", "last_only", "alternating", "all_live"])
+    def test_idle_rows_are_zero_and_live_rows_the_reference(
+            self, pattern, t, cell_rows, monkeypatch):
+        """A row whose table starts with the scratch block is an idle slot:
+        exactly 0, whatever its stale position; the others are the float32
+        reference's, in one grid cell and in several (six rows under a q
+        block of 16 rows a cell: two cells of three rows for decode, six of
+        one for the window)."""
+        b = 6
+        live = {"none_live": np.zeros(b, bool),
+                "first_only": np.arange(b) == 0,
+                "last_only": np.arange(b) == b - 1,
+                "alternating": np.arange(b) % 2 == 0,
+                "all_live": np.ones(b, bool)}[pattern]
+        q, kp, vp, pt, pos = self._live_case(live, t=t)
+        dtype = jnp.bfloat16
+        kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
+        want = _reference(q, kp, vp, pt, pos)
+        want[~live] = 0.0
+        monkeypatch.setattr(_module(), "_CELL_ROWS", cell_rows)
+        # the jitted wrapper's cache does not know the module's constant
+        got = _module()._pallas_paged_attention.__wrapped__(
+            q, kp, vp, pt, pos, dtype=dtype, interpret=True, block_rows=64)
+        _assert_within_tolerance(got, want, dtype, pattern)
+        got = np.asarray(got, np.float32)
+        assert not got[~live].any()
+        assert got[live].any(axis=(1, 2, 3, 4)).all()
+
+    @pytest.mark.parametrize("t", [1, 5], ids=["decode", "verify"])
+    def test_a_live_row_reads_its_own_blocks_and_no_other(self, t):
+        """Every block a live row does not own, the scratch block too, is
+        NaN: the live rows' results are the clean pool's bit for bit, the
+        idle rows' exactly 0."""
+        live = np.asarray([True, False, True, False, False, True])
+        q, kp, vp, pt, pos = self._live_case(live, t=t)
+        dtype = jnp.bfloat16
+        owned = np.unique(np.asarray(pt)[np.asarray(pt) != 0])
+        k_bad = np.full_like(kp, np.nan)
+        v_bad = np.full_like(vp, np.nan)
+        k_bad[owned], v_bad[owned] = kp[owned], vp[owned]
+        clean, poisoned = (
+            np.asarray(paged_attention(
+                q, jnp.asarray(k, dtype), jnp.asarray(v, dtype), pt, pos,
+                kernel="pallas", interpret=True).astype(jnp.float32))
+            for k, v in ((kp, vp), (k_bad, v_bad)))
+        assert np.isfinite(poisoned).all()
+        assert np.array_equal(clean[live], poisoned[live])
+        assert not poisoned[~live].any() and clean[live].any()
+
+    def test_a_live_row_at_position_0_on_a_page_of_its_own_is_read(self):
+        """Position 0 is a live row's first, not an idle slot's mark: what
+        says idle is the table."""
+        page, pages, kv, g, d = 8, 3, 2, 2, 16
+        rng = np.random.default_rng(46)
+        dtype = jnp.bfloat16
+        q = jnp.asarray(rng.standard_normal((2, 1, kv * g, d)), dtype)
+        kp = jnp.asarray(rng.standard_normal((5, page, kv, d)), dtype)
+        vp = jnp.asarray(rng.standard_normal((5, page, kv, d)), dtype)
+        pt = jnp.asarray([[3, 0, 0], [0, 0, 0]], jnp.int32)
+        pos = jnp.zeros((2, 1), jnp.int32)
+        got = np.asarray(paged_attention(
+            q, kp, vp, pt, pos, kernel="pallas",
+            interpret=True).astype(jnp.float32))
+        # one visible key: the result is that position's values, a head
+        want = np.asarray(vp.astype(jnp.float32))[3, 0]      # [kv, d]
+        assert np.array_equal(got[0, 0], np.broadcast_to(
+            want[:, None, :], (kv, g, d)))
+        assert not got[1].any()
 
     def test_within_tolerance_under_jit_and_odd_head_dim(self):
         # the engine runs the op inside jitted programs (d=24: a head dim
@@ -295,6 +402,7 @@ class TestDecodeKernel:
         q = jnp.asarray(rng.standard_normal((3, 1, kv * g, d)), dtype)
         lens = np.asarray([1, 3 * page - 2, pages * page])
         pt = np.zeros((3, pages), np.int32)
+        pt[0, 0] = 5
         pt[1, :3] = [n - 1, 7, n - 2]
         pt[2] = n - 3 - np.arange(pages)
         pos = jnp.asarray(lens[:, None] - 1, jnp.int32)
@@ -435,9 +543,7 @@ class TestChunkKernel:
         """A group of 3, a head size of 24, a width that is no power of
         two, q tiles of 2 positions and blocks of 2 pages: every tile walks
         several blocks, the last of them partial."""
-        import importlib
-
-        pa = importlib.import_module("lzy_tpu.ops.paged_attention")
+        pa = _module()
         monkeypatch.setattr(pa, "_CHUNK_ROWS", 8)
         monkeypatch.setattr(pa, "_CHUNK_BLOCK_ROWS", 16)
         rng = np.random.default_rng(9)
