@@ -47,8 +47,7 @@ from lzy_tpu.serving.spec import NgramProposer
 from lzy_tpu.serving.streams import StreamSession, StreamSessionManager
 from lzy_tpu.serving.tenancy import (
     SloLimiter, TenantPolicy, TenantTable, TokenBucket)
-from lzy_tpu.serving.disagg import (
-    DecodeEngine, PrefillEngine, export_kv, import_kv)
+from lzy_tpu.serving.disagg import DecodeEngine, PrefillEngine
 
 __all__ = [
     "AdmissionError",
@@ -73,6 +72,4 @@ __all__ = [
     "TenantPolicy",
     "TenantTable",
     "TokenBucket",
-    "export_kv",
-    "import_kv",
 ]

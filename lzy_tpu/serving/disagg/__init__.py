@@ -11,11 +11,11 @@ two pools connected by the channels data plane:
   chunked + radix-cached prefill, and finishes each request with a
   host-side :class:`~lzy_tpu.channels.kv_transfer.KVBlockExport` of the
   prompt's whole-block KV prefix attached (``request.kv_export``).
-- :func:`export_kv` / :func:`import_kv` — the pool-level halves: export
-  pins tree blocks for the gather (refcounts make a concurrent eviction
-  impossible), import allocates fresh blocks (evicting LRU unreferenced
-  ones under pressure — never a resident request's) and registers the
-  prefix in the destination radix tree.
+- ``engine.kv_io.export_kv`` / ``import_kv`` (``serving/kv_io.py``) — the
+  pool-level halves: export pins tree blocks for the gather (refcounts make
+  a concurrent eviction impossible), import allocates fresh blocks
+  (evicting LRU unreferenced ones under pressure — never a resident
+  request's) and registers the prefix in the destination radix tree.
 - :class:`DecodeEngine` — a paged engine with an import queue drained
   at the top of every scheduling round, strictly before admissions.
 
@@ -27,12 +27,9 @@ transfer costs FLOPs, never correctness.
 """
 
 from lzy_tpu.serving.disagg.decode import DecodeEngine
-from lzy_tpu.serving.disagg.kv_export import export_kv, import_kv
 from lzy_tpu.serving.disagg.prefill import PrefillEngine
 
 __all__ = [
     "DecodeEngine",
     "PrefillEngine",
-    "export_kv",
-    "import_kv",
 ]

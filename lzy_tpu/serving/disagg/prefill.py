@@ -20,7 +20,6 @@ disaggregated output bit-identical, greedy and sampled.
 from __future__ import annotations
 
 
-from lzy_tpu.serving.disagg.kv_export import export_kv
 from lzy_tpu.serving.engine import _REQUESTS, PagedInferenceEngine
 from lzy_tpu.serving.scheduler import Request
 from lzy_tpu.utils.log import get_logger
@@ -78,7 +77,7 @@ class PrefillEngine(PagedInferenceEngine):
         req.first_token_at = now            # "time to KV ready" here
         _PREFILL_SECONDS.observe(now - req.submitted_at)
         try:
-            req.kv_export = export_kv(self, req.prompt)
+            req.kv_export = self.kv_io.export_kv(req.prompt)
         except Exception as e:  # noqa: BLE001 — export is advisory
             _LOG.warning("kv export failed for %s: %s", req.id, e)
             req.kv_export = None

@@ -52,8 +52,9 @@ in ``models/serving.py``): this file names no model.
 - **Deadlines, tenants, KV I/O**: per-request deadlines and dead clients
   evict mid-decode with a ``cancelled`` status; WFQ, queue caps and KV
   quotas come from a ``TenantTable``; cross-replica KV import / export,
-  the host and storage tiers and parked conversation chains are serviced
-  between rounds on the scheduling thread.
+  the host and storage tiers and parked conversation chains are one
+  object the engine owns (``serving/kv_io.py``), serviced between rounds
+  on the scheduling thread.
 
 Sampling is engine-wide (greedy by default). Under ``temperature>0`` the
 rng stream is shared by the whole batch, so a request's sampled tokens
@@ -98,6 +99,9 @@ from lzy_tpu.chaos.faults import CHAOS, CRASH, DELAY, ERROR, SLOW
 from lzy_tpu.models import serving
 from lzy_tpu.models.generate import (
     draw_token, init_cache, prefill_plan, prefill_width, sample_token)
+from lzy_tpu.serving.kv_io import (  # noqa: F401 — the two errors are
+    # what the engine's refusals raise, and are imported from here
+    KvIO, StateLeavesUnsupported, WindowLeavesUnsupported, leaf_refusal)
 from lzy_tpu.serving.scheduler import (
     AdmissionError, PromptTooLong, Request, RequestQueue)
 from lzy_tpu.serving.tenancy import (
@@ -234,21 +238,6 @@ _OVERLAP_COMMITS = REGISTRY.counter(
     "admission plans computed in the overlap window, by outcome "
     "(outcome=committed|stale|empty)")
 
-# workflow-aware scheduling (lzy_tpu/llm/sched.py): a fused
-# ``generate -> tool-op -> generate`` chain parks its conversation's
-# radix chain — blocks pinned resident — across the tool gap so step 2
-# is a suffix prefill on the same replica. Park/release events are
-# engine-owned; the scheduler-side lzy_wfsched_* counters live in
-# lzy_tpu/llm/metrics.py.
-_PARKED = REGISTRY.counter(
-    "lzy_wfsched_parked_total",
-    "conversation KV chains parked (pinned resident) across tool gaps")
-_PARKED_RELEASED = REGISTRY.counter(
-    "lzy_wfsched_parked_released_total",
-    "parked chain releases by reason "
-    "(reason=repark|ttl|pressure|explicit|shutdown)")
-
-
 # per-slot state (models/serving.py, cache-leaf kind ``state``): a prefill
 # job of a model with state leaves starts from a zeroed batch-1 row and the
 # engine splices it into the slot's row when the prompt is done
@@ -256,20 +245,6 @@ _STATE_RESETS = REGISTRY.counter(
     "lzy_state_slots_reset_total",
     "per-slot state rows started from zero for a newly admitted request "
     "(models with state cache leaves)")
-
-
-class StateLeavesUnsupported(ValueError):
-    """A mechanism that shares, moves or rewinds cache by index and pages
-    was asked of a model whose cache has per-slot state leaves. The message
-    names the mechanism."""
-
-
-class WindowLeavesUnsupported(ValueError):
-    """A mechanism that moves, shares or rewinds pages by a prefix's tokens
-    was asked of a model some of whose paged leaves lose their tokens
-    behind a window (``models/serving.py``, kind ``window``): the pages
-    behind it have gone back to their pool. The message names the
-    mechanism."""
 
 
 # what a prefill program is told about its chunk, one row of four int32 a
@@ -319,17 +294,6 @@ class _PrefillJob:
     # (``kv_cache.WindowRow``), grown and shed chunk by chunk; the slot's
     # once the prompt is done
     window: Any = None
-
-
-@dataclasses.dataclass
-class _ParkedChain:
-    """One parked conversation prefix (workflow-aware scheduling): its
-    radix blocks carry one pinned reference each (``RadixCache.lookup``)
-    until release, so the tool gap of a fused op chain cannot evict the
-    conversation's KV out from under step 2."""
-    blocks: List[int]
-    tokens: int                 # whole-block prefix length pinned
-    expires_at: float           # engine-clock deadline (TTL sweep)
 
 
 @dataclasses.dataclass
@@ -413,6 +377,10 @@ class EngineStats:
     def doc(self) -> dict:
         return {k: v for k, v in dataclasses.asdict(self).items()
                 if v is not None}
+
+
+def _kv_io_stat(name: str) -> property:
+    return property(lambda self: self.kv_io.stats().get(name, 0))
 
 
 class PagedInferenceEngine:
@@ -660,51 +628,6 @@ class PagedInferenceEngine:
         self._win = None if window is None else WindowPages(
             kv_window_blocks, page_size, window, self._pages_per_seq,
             self.prefill_chunk)
-        # tiered KV cache (serving/kv_tier.py): radix eviction DEMOTES
-        # block payloads to pinned host RAM (and onward to storage)
-        # instead of dropping them; admission PROMOTES them back. The
-        # tier is advisory end to end — every failure path degrades to
-        # classic eviction / local re-prefill.
-        if kv_tier is not None:
-            self.kv_tier = kv_tier
-        elif kv_host_tier_bytes is not None or kv_storage_tier is not None:
-            from lzy_tpu.serving.kv_tier import HostKVTier
-
-            self.kv_tier = HostKVTier(kv_host_tier_bytes or 0, page_size,
-                                      storage=kv_storage_tier)
-        else:
-            self.kv_tier = None
-        if self.kv_tier is not None:
-            self.kv.on_evict = self._demote_block
-            self.kv.on_evict_batch = self._demote_blocks
-            self.kv.on_insert = self.kv_tier.discard
-        # device→host gather accounting for the demotion path: one
-        # BATCHED gather per cache leaf per eviction round (not one per
-        # evicted block) — the count-of-transfers contract the batching
-        # test pins
-        self.kv_tier_gather_ops = 0
-        self.kv_tier_gather_rounds = 0
-        # cross-replica / disagg import queue: transferred KVBlockExports
-        # fold into the pool+tree between engine steps, strictly before
-        # admissions (a queued import is resident by the time the request
-        # that wants it prefills); export requests are the outbound twin,
-        # serviced on THIS thread so the device→host gather never races a
-        # donating prefill
-        self._pending_imports: List[Any] = []
-        self._export_requests: List[tuple] = []
-        # parked conversation chains (workflow-aware scheduling): key ->
-        # _ParkedChain with its radix blocks pinned so a fused op
-        # chain's tool gap cannot evict the conversation KV. Mutated
-        # only on the scheduling thread (cross-thread callers queue
-        # through _park_requests, the request_kv_export pattern);
-        # bounded by the TTL sweep in step(), shed under pool pressure
-        # strictly before any resident request is preempted, and
-        # released wholesale at close().
-        self._parked: Dict[str, _ParkedChain] = {}
-        self._park_requests: List[tuple] = []
-        self._kv_io_lock = threading.Lock()
-        self.kv_imports = 0
-        self.kv_import_blocks = 0
         # page tables: [slots, pages_per_seq] block ids (0 = scratch pad);
         # _slot_blocks mirrors the allocated prefix of each row in python
         self._tables = np.zeros((slots, self._pages_per_seq), np.int32)
@@ -724,6 +647,27 @@ class PagedInferenceEngine:
         self._stat_counters: tuple = ()
         self._dispatch_paths: dict = {}   # positions a row -> path labels
         self._build_decode_path(base)
+        refusal = leaf_refusal(self._leaf_kinds)
+        if refusal is not None:
+            self._refuse_for_leaves(
+                *refusal, kv_tier is not None or kv_storage_tier is not None
+                or kv_host_tier_bytes is not None)
+        # the tiers, parked chains, KV import and export
+        # (serving/kv_io.py), serviced between rounds on the scheduling
+        # thread: the object reaches the scheduler through these alone
+        self.kv_io = KvIO(
+            self.kv, page_size, self._clock,
+            leaf_keys=self._leaf_keys, refusal=refusal,
+            payload=lambda: self._payload,
+            adopt=lambda leaves: setattr(self, "_payload", leaves),
+            drain=self._drain,
+            wake=lambda: self.queue.work_available.set(),
+            threaded=lambda: self._thread is not None,
+            closed=lambda: self._closed,
+            note_import=self._note_kv_import,
+            tier=kv_tier, host_tier_bytes=kv_host_tier_bytes,
+            storage_tier=kv_storage_tier,
+            mesh_shape=getattr(self, "kv_mesh_shape", None))
 
         # chunked-prefill interleaving: at most ``prefill_budget`` prompt
         # tokens advance per scheduling round (None = whole prompt in one
@@ -775,10 +719,6 @@ class PagedInferenceEngine:
         _SLOTS.set(float(slots))
         _BUSY.set(0.0)
 
-        if self._has_state:
-            self._refuse_for_state()
-        if self._win is not None:
-            self._refuse_for_window()
 
     @staticmethod
     def _divide_pool(pool_bytes: int, base: Any, most_window: int,
@@ -815,6 +755,10 @@ class PagedInferenceEngine:
         self._leaf_kinds = [serving.leaf_kind(self._model, p)
                             for p, _ in flat]
         self._leaf_is_index = [k == serving.INDEX for k in self._leaf_kinds]
+        # what a block's payload is keyed by when it leaves the pool
+        # (serving/kv_io.py): each payload leaf's path
+        self._leaf_keys = [jax.tree_util.keystr(p) for (p, _), idx
+                           in zip(flat, self._leaf_is_index) if not idx]
         self._payload = [leaf for (p, leaf), idx
                          in zip(flat, self._leaf_is_index) if not idx]
         # which payload leaves are per-slot state ([slots, ...], one row a
@@ -825,9 +769,7 @@ class PagedInferenceEngine:
         self._pool_at = [i for i, k in enumerate(payload_kinds)
                          if k != serving.STATE]
         self._has_state = bool(self._state_at)
-        self._state_names = [jax.tree_util.keystr(p) for (p, _), k
-                             in zip(flat, self._leaf_kinds)
-                             if k == serving.STATE]
+        self._state_names = [self._leaf_keys[i] for i in self._state_at]
         # a model with state leaves, or one that counts (``STATS``), is
         # told which positions of a program are real (``valid_len``)
         self._tells_real = self._has_state or bool(type(self._model).STATS)
@@ -865,30 +807,6 @@ class PagedInferenceEngine:
         new_pos = next(leaf for leaf, idx
                        in zip(leaves, self._leaf_is_index) if idx)
         return payload, new_pos
-
-    @property
-    def _cache(self):
-        """The full cache tree, index leaves materialized from the host
-        positions — the compatibility surface for everything OFF the hot
-        path (KV export/import, tier demotion/promotion). Neither jitted
-        step uses it: decode and prefill both take the payload leaves and
-        build their index leaves inside the program. Each index leaf is
-        a fresh device buffer (``jnp.array`` copies), so a consumer that
-        donates the result can never hand one buffer in twice."""
-        vals = np.asarray(self._pos, np.int32)
-        leaves, it = [], iter(self._payload)
-        for idx in self._leaf_is_index:
-            leaves.append(jnp.array(vals) if idx else next(it))
-        return jax.tree_util.tree_unflatten(self._cache_treedef, leaves)
-
-    @_cache.setter
-    def _cache(self, tree) -> None:
-        """Adopt a consumer's updated tree: payload leaves are kept,
-        index leaves are DISCARDED — ``_pos`` (host) is the single
-        source of truth for positions, so a setter cannot desync them."""
-        leaves = jax.tree_util.tree_leaves(tree)
-        self._payload = [leaf for leaf, idx
-                         in zip(leaves, self._leaf_is_index) if not idx]
 
     def _accept(self, prop, prop_len, greedy, nxt, pos):
         """On-device speculative acceptance (traced inside verify_step).
@@ -968,10 +886,6 @@ class PagedInferenceEngine:
         return np.asarray(
             [self._row_greedy(r) if r is not None else True
              for r in self._active], bool)
-
-    @staticmethod
-    def _is_index(path) -> bool:
-        return any(getattr(p, "key", None) == "index" for p in path)
 
     # -- request surface ---------------------------------------------------
 
@@ -1090,7 +1004,7 @@ class PagedInferenceEngine:
         with trace.span(trace.ENGINE_ROUND) as rnd:
             t0 = now()
             with trace.span(trace.ENGINE_KV_IO):
-                serviced = self._service_io()
+                serviced = self.kv_io.service()
             t1 = now()
             kv_io_dt = self._less_drains(t1 - t0)
             if CHAOS.armed is not None and (
@@ -2173,23 +2087,9 @@ class PagedInferenceEngine:
         _BUSY.set(0.0)
         if self._kv_quant is not None:
             self._note_quant_resident(0)
-        if self.kv_tier is not None:
-            self.kv_tier.close()
-        # wake any export waiter parked on a request the loop will
-        # never service again (it reads None and re-prefills locally)
-        with self._kv_io_lock:
-            requests, self._export_requests = self._export_requests, []
-            parks, self._park_requests = self._park_requests, []
-        for _, holder, done in requests:
-            holder["export"] = None
-            done.set()
-        for _kind, _key, _tokens, _ttl, holder, done in parks:
-            holder["ok"] = False
-            done.set()
-        # the loop thread was joined above: releasing the parked pins
-        # here is single-threaded by construction
-        for key in list(self._parked):
-            self._release_parked(key, "shutdown")
+        # the loop thread was joined above: the tier is closed, the waiters
+        # woken and the parked pins released single-threaded by construction
+        self.kv_io.close()
 
     def _fail_untracked(self) -> List[Request]:
         """Outstanding requests still unfinished after the queue and the
@@ -2224,11 +2124,7 @@ class PagedInferenceEngine:
             kernel_path=self.kernel_path,
             kv_quant=self._kv_quant,
             kv_token_bytes=self._kv_token_bytes,
-            kv_imports=self.kv_imports,
-            kv_import_blocks=self.kv_import_blocks,
-            kv_parked_chains=len(self._parked),
-            kv_parked_blocks=sum(len(c.blocks)
-                                 for c in self._parked.values()),
+            **self.kv_io.stats(),
         )
         if self._win is not None:
             s = dataclasses.replace(
@@ -2252,19 +2148,6 @@ class PagedInferenceEngine:
                 spec_verify_steps=self.spec_steps,
                 spec_tokens_per_step=round(tps, 4),
                 spec_draft_truncated=self.spec_draft_truncated,
-            )
-        if self.kv_tier is not None:
-            ts = self.kv_tier.stats()
-            s = dataclasses.replace(
-                s,
-                kv_host_tier_blocks=ts["host_blocks"],
-                kv_host_tier_bytes=ts["host_bytes"],
-                kv_tier_demotions=(ts["demotions"]
-                                   + ts["demotions_to_storage"]),
-                kv_tier_promotions=(ts["promotions"]
-                                    + ts["promotions_from_storage"]),
-                kv_tier_dropped=ts["dropped"],
-                kv_storage_tier_blocks=ts.get("storage_blocks"),
             )
         return s
 
@@ -2298,63 +2181,24 @@ class PagedInferenceEngine:
         return out
 
 
-    def _refuse_for_state(self) -> None:
-        """A model with per-slot state leaves (``models/serving.py``):
+    def _refuse_for_leaves(self, error, why: dict, tier_asked: bool) -> None:
+        """A pool whose leaves are not all ``paged`` (``models/serving.py``;
+        ``serving/kv_io.py`` has the error and the reasons by leaf kind):
         what shares a prefix is turned off, what moves or rewinds cache by
         index and pages is refused, by name."""
         if self.spec_tokens > 0:
-            raise StateLeavesUnsupported(
-                f"speculative decoding (spec_tokens={self.spec_tokens}): a "
-                f"rejected draft is rewound by moving an index, and a "
-                f"per-slot state that has consumed it cannot be rewound")
-        if self.kv_tier is not None:
-            raise StateLeavesUnsupported(
-                "the tiered KV cache (kv_host_tier_bytes / kv_storage_tier "
-                "/ kv_tier): a demoted prefix is pages without the state "
-                "that belongs after them")
-        # a matched prefix would skip prefill for tokens whose recurrent
-        # state nobody kept: every match is 0 tokens and finished prompts
-        # are not inserted
+            raise error(f"speculative decoding (spec_tokens="
+                        f"{self.spec_tokens}): {why['spec']}")
+        if tier_asked:
+            raise error(f"the tiered KV cache (kv_host_tier_bytes / "
+                        f"kv_storage_tier / kv_tier): {why['tier']}")
+        # a matched prefix would skip prefill for tokens whose state rows
+        # or window pages nobody kept
         self.kv.reuse = False
         _LOG.info(
-            "%s keeps per-slot state: the radix prefix cache is off "
-            "(every match is 0 tokens, finished prompts are not inserted)",
-            type(self._model).__name__)
-
-    def _refuse_call_for_state(self, mechanism: str) -> None:
-        if self._has_state:
-            raise StateLeavesUnsupported(
-                f"{mechanism}: it moves or pins pages by a prefix's tokens, "
-                f"and this model's per-slot state is not in any page")
-        if self._win is not None:
-            raise WindowLeavesUnsupported(
-                f"{mechanism}: it moves or pins pages by a prefix's tokens, "
-                f"and this model's window leaves have returned the pages "
-                f"behind the window")
-
-    def _refuse_for_window(self) -> None:
-        """A model with ``window`` leaves (``models/serving.py``): what
-        shares a prefix is turned off, what moves or rewinds cache by index
-        and pages is refused, by name. Each could be made to work over both
-        kinds of page (a hit would have to bring the window's worth of
-        window pages with it); none has been."""
-        if self.spec_tokens > 0:
-            raise WindowLeavesUnsupported(
-                f"speculative decoding (spec_tokens={self.spec_tokens}): a "
-                f"rejected draft is rewound by moving an index, and a page "
-                f"that went back behind the drafted positions' window "
-                f"cannot be called back")
-        if self.kv_tier is not None:
-            raise WindowLeavesUnsupported(
-                "the tiered KV cache (kv_host_tier_bytes / kv_storage_tier "
-                "/ kv_tier): a demoted prefix is pages of one kind")
-        # a matched prefix would skip prefill for tokens whose window
-        # pages nobody kept
-        self.kv.reuse = False
-        _LOG.info(
-            "%s has window leaves: the radix prefix cache is off (every "
-            "match is 0 tokens, finished prompts are not inserted)",
-            type(self._model).__name__)
+            "%s %s: the radix prefix cache is off (every match is 0 "
+            "tokens, finished prompts are not inserted)",
+            type(self._model).__name__, why["reuse"])
 
     # -- construction --------------------------------------------------------
 
@@ -2593,12 +2437,11 @@ class PagedInferenceEngine:
         # land mid-step (after the top-of-loop drain but before _admit
         # pops it), and its staged import must be resident before the
         # prefill's prefix match runs. No-op when the queue is empty.
-        self._apply_imports()
+        self.kv_io.apply_imports()
         need = blocks_for(len(req.prompt), self._page)
-        if self.kv.available() < need and self._parked:
-            # parked tool-gap chains yield to live admissions: shed them
-            # (soonest expiry first) before making anyone wait
-            self._shed_parked_for_pressure(need)
+        # parked tool-gap chains yield to live admissions: shed them
+        # (soonest expiry first) before making anyone wait
+        self.kv_io.shed_parked(need)
         if self._win is not None and self._win.available() \
                 < self._win.need(len(req.prompt)):
             return False       # both kinds of page must hold the prompt
@@ -2632,7 +2475,7 @@ class PagedInferenceEngine:
         # the match below hits them like any locally-cached prefix — and
         # counts them in prefill_tokens_saved, which is the honest
         # accounting (the prefill really is skipped)
-        self._promote_for(prompt[:-1])
+        self.kv_io.promote(prompt[:-1])
         # longest cached whole-block prefix; capped at prompt[:-1] so at
         # least one real token remains to forward (logits for the first
         # generated token must come from an actual prefill position)
@@ -2758,515 +2601,43 @@ class PagedInferenceEngine:
         self._finish_prefill(slot, req, self._prefill_fence(first))
         return True
 
-    # -- tiered KV cache (serving/kv_tier.py) --------------------------------
-
-    def _service_io(self) -> bool:
-        """Round work ahead of the reap and the admissions: cross-replica
-        KV I/O (queued imports + export requests) strictly before the
-        round's admissions — an import queued before a submit is always
-        resident by the time that request prefills."""
-        serviced = self._service_kv_io()
-        self._sweep_parked()
-        return serviced
-
-    def _demote_block(self, chain, block: int, origin) -> None:
-        """``RadixCache.on_evict`` hook (single-victim form): one block
-        through the batched path below."""
-        self._demote_blocks([(chain, block, origin)])
-
-    def _demote_blocks(self, victims) -> None:
-        """``RadixCache.on_evict_batch`` hook: demote one eviction
-        round's victims — ``[(chain_tokens, block, origin), ...]`` — with
-        the per-block device→host copies COALESCED into a single gather
-        per cache leaf (int8 sidecar leaves included — they are ordinary
-        cache leaves).  A pressured admission that evicts a dozen blocks
-        used to pay a dozen tiny transfers per leaf; now it pays one
-        ``leaf[ids]`` gather per leaf for the whole round.  Every
-        failure — including the ``kvtier.demote`` chaos fault inside
-        ``put`` — degrades to the classic drop the eviction was going to
-        do anyway, counted per victim."""
-        tier = self.kv_tier
-        if tier is None:
-            return
-        victims = [(chain, block, origin) for chain, block, origin
-                   in victims if chain]
-        if not victims:
-            return
-        try:
-            ids = jnp.asarray([block for _, block, _ in victims],
-                              jnp.int32)
-            gathered = {}
-            for key, leaf in zip(self._kv_leaf_keys(),
-                                 jax.tree_util.tree_leaves(self._cache)):
-                if key is None:        # index leaf: not payload
-                    continue
-                # ONE [n_victims, page, ...] gather + host transfer per
-                # leaf, split per block below (np views, no extra copy)
-                gathered[key] = np.asarray(leaf[ids])
-                self.kv_tier_gather_ops += 1
-            self.kv_tier_gather_rounds += 1
-            from lzy_tpu.serving.kv_tier import GATHER_BATCHES
-
-            GATHER_BATCHES.inc()
-        except Exception as e:  # noqa: BLE001 — demotion is advisory
-            for chain, _, _ in victims:
-                tier.note_dropped()
-            _LOG.debug("kvtier: batched demotion of %d chain(s) dropped "
-                       "(%s: %s)", len(victims), type(e).__name__, e)
-            return
-        for i, (chain, block, origin) in enumerate(victims):
-            try:
-                # per-victim COPY, not a view: a view would pin the whole
-                # [n_victims, ...] gather base in host RAM for as long as
-                # ANY sibling entry survives in the tier, while the
-                # tier's byte accounting only books the slice — the
-                # budget would stop bounding real memory. The copy is a
-                # host memcpy; the device->host transfer above is still
-                # one gather per leaf (the batching win).
-                leaves = {key: arr[i].copy()
-                          for key, arr in gathered.items()}
-                tier.put(tuple(int(t) for t in chain), leaves,
-                         origin=origin)
-            except Exception as e:  # noqa: BLE001 — demotion is advisory
-                tier.note_dropped()
-                _LOG.debug("kvtier: demotion of a %d-token chain dropped "
-                           "(%s: %s)", len(chain), type(e).__name__, e)
-
-    def _kv_leaf_keys(self):
-        """Cache-leaf keystrs in ``tree_leaves`` order, index leaves as
-        None — computed ONCE per engine (the cache's structure never
-        changes after build). Demotion runs inside the admission path's
-        eviction loop, and a full ``tree_flatten_with_path`` + per-leaf
-        ``keystr`` per evicted block would tax every pressured
-        admission with repeated pytree walks."""
-        keys = getattr(self, "_kv_leaf_keys_cache", None)
-        if keys is None:
-            flat, _ = jax.tree_util.tree_flatten_with_path(self._cache)
-            keys = [None if self._is_index(path)
-                    else jax.tree_util.keystr(path)
-                    for path, _ in flat]
-            self._kv_leaf_keys_cache = keys
-        return keys
-
-    def kv_tier_match_len(self, tokens: Sequence[int]) -> int:
-        """Tokens coverable by the radix tree PLUS contiguously
-        promotable tier chains — the probe the gateway uses to value a
-        tier hit like a radix hit before staging a sibling import.
-        Read-only: no refs, no promotion, no LRU bumps."""
-        page = self._page
-        n_full = len(tokens) // page
-        prefix = [int(t) for t in tokens[:n_full * page]]
-        depth = self.kv.match_len(prefix) // page
-        if self.kv_tier is not None:
-            while depth < n_full and self.kv_tier.has(
-                    tuple(prefix[:(depth + 1) * page])) is not None:
-                depth += 1
-        return depth * page
-
-    def _promote_for(self, tokens: Sequence[int]) -> int:
-        """Extend the radix match for ``tokens`` from the host/storage
-        tiers: pop contiguous tier chains past the resident prefix,
-        re-allocate pool blocks for them (evict-then-import — resident
-        refcounted blocks are untouchable by construction), scatter the
-        payloads in, and re-insert the chains with their origin
-        provenance. Returns blocks promoted; 0 on any failure — the
-        request simply re-prefills the tail locally (``kvtier.import``
-        chaos proves that path bit-identical)."""
-        tier = self.kv_tier
-        if tier is None:
-            return 0
-        from lzy_tpu.serving.kv_cache import NoFreeBlocks
-
-        page = self._page
-        n_full = len(tokens) // page
-        if n_full == 0:
-            return 0
-        prefix = [int(t) for t in tokens[:n_full * page]]
-        matched = self.kv.match_len(prefix) // page
-        if matched >= n_full:
-            return 0
-        entries: List[Any] = []
-        pin_blocks: List[int] = []
-        blocks: List[int] = []
-        try:
-            CHAOS.hit("kvtier.import")
-            depth = matched
-            while depth < n_full:
-                entry = tier.take(tuple(prefix[:(depth + 1) * page]))
-                if entry is None:
-                    break
-                entries.append(entry)
-                depth += 1
-            if not entries:
-                return 0
-            # pin the already-resident prefix: the allocate below may
-            # evict unreferenced leaves, and evicting an ancestor of the
-            # chain being promoted would corrupt the insert
-            if matched:
-                pin_blocks, _ = self.kv.lookup(prefix[:matched * page])
-            blocks = self.kv.allocate(len(entries))
-            ids = jnp.asarray(blocks, jnp.int32)
-            flat, _ = jax.tree_util.tree_flatten_with_path(self._cache)
-            expected = {jax.tree_util.keystr(p) for p, _ in flat
-                        if not self._is_index(p)}
-            for entry in entries:
-                if set(entry.leaves) != expected:
-                    # same fail-closed contract as import_kv: scattering
-                    # a quantized payload into an fp pool (or vice
-                    # versa) would serve garbage with no error anywhere
-                    raise ValueError(
-                        "tier entry leaves do not match the pool's "
-                        "cache leaves (mismatched kv_quant between the "
-                        "demoting and promoting pools?)")
-
-            def put(path, leaf):
-                if self._is_index(path):
-                    return leaf
-                key = jax.tree_util.keystr(path)
-                data = np.stack([e.leaves[key] for e in entries])
-                if data.shape[1:] != leaf.shape[1:] \
-                        or data.dtype != leaf.dtype:
-                    raise ValueError(
-                        f"tier leaf {data.shape}/{data.dtype} does not "
-                        f"fit pool leaf {leaf.shape}/{leaf.dtype}")
-                return leaf.at[ids].set(jnp.asarray(data))
-
-            self._cache = jax.tree_util.tree_map_with_path(put, self._cache)
-            # per-chain inserts so each node keeps ITS producer's
-            # provenance (a host-promoted chain may ride on a block a
-            # sibling replica originally prefilled)
-            for i, entry in enumerate(entries):
-                self.kv.insert(prefix[:(matched + i + 1) * page],
-                               pin_blocks + blocks[:i + 1],
-                               origin=entry.origin)
-            self.kv.release(blocks)
-            if pin_blocks:
-                self.kv.release(pin_blocks)
-            for entry in entries:
-                # counted at SUCCESS, not at take: a failed promotion
-                # must not make the tier look effective
-                tier.note_promoted(getattr(entry, "tier", None) or "host")
-            return len(entries)
-        except Exception as e:  # noqa: BLE001 — promotion is advisory
-            # roll back: popped host entries are re-filed (their payload
-            # never logically left the tier), refs dropped, and the
-            # caller re-prefills — a failed promotion costs FLOPs, never
-            # correctness and never a failed request
-            for entry in entries:
-                if getattr(entry, "tier", None) == "host":
-                    tier.restore(entry)
-            if blocks:
-                self.kv.release(blocks)
-            if pin_blocks:
-                self.kv.release(pin_blocks)
-            _LOG.info("kvtier: promotion failed (%s: %s); falling back "
-                      "to local prefill", type(e).__name__, e)
-            return 0
-
-    # -- parked conversation chains (workflow-aware scheduling) ---------------
+    # -- KV I/O (serving/kv_io.py): the replica's surface ----------------------
+    # what the gateway, the fleet and the tests call on a replica's engine;
+    # each is the object's method or counter of (nearly) the same name
 
     def park_chain(self, key: str, tokens: Sequence[int],
                    ttl_s: float = 30.0, timeout_s: float = 5.0) -> bool:
-        """Pin the longest cached whole-block prefix of ``tokens`` under
-        ``key`` for up to ``ttl_s`` so it survives the tool gap of a
-        fused ``generate -> tool-op -> generate`` chain. Re-parking a
-        key refreshes both the pin (covering newly cached blocks, e.g.
-        after a speculative prefill) and the TTL. The pin itself runs on
-        the engine's scheduling thread — same cross-thread contract as
-        :meth:`request_kv_export` — and the whole surface is advisory:
-        False (nothing cached, timeout, shutdown) degrades the caller
-        to the ordinary routed path."""
-        self._refuse_call_for_state("parking a conversation's chain")
-        if self._closed:
-            return False
-        if self._thread is None:
-            # synchronous/test mode: by the engine's single-driver
-            # contract the caller IS the scheduling thread
-            try:
-                return self._park_now(str(key), list(tokens), float(ttl_s))
-            except Exception:  # noqa: BLE001 — parking is advisory
-                return False
-        holder: dict = {}
-        done = threading.Event()
-        with self._kv_io_lock:
-            self._park_requests.append(
-                ("park", str(key), list(tokens), float(ttl_s), holder,
-                 done))
-        self.queue.work_available.set()
-        if not done.wait(timeout_s):
-            return False
-        return bool(holder.get("ok"))
+        return self.kv_io.park_chain(key, tokens, ttl_s, timeout_s)
 
     def unpark_chain(self, key: str, timeout_s: float = 5.0) -> bool:
-        """Release a parked chain's pins (the blocks fall back to
-        ordinary LRU-evictable cache entries). False if nothing was
-        parked under ``key`` — releasing twice is harmless."""
-        if self._closed:
-            return False
-        if self._thread is None:
-            return self._release_parked(str(key), "explicit")
-        holder: dict = {}
-        done = threading.Event()
-        with self._kv_io_lock:
-            self._park_requests.append(
-                ("unpark", str(key), None, 0.0, holder, done))
-        self.queue.work_available.set()
-        if not done.wait(timeout_s):
-            return False
-        return bool(holder.get("ok"))
+        return self.kv_io.unpark_chain(key, timeout_s)
 
-    def _park_now(self, key: str, tokens: List[int], ttl_s: float) -> bool:
-        old = self._parked.pop(key, None)
-        if old is not None:
-            self.kv.release(old.blocks)
-            _PARKED_RELEASED.inc(reason="repark")
-        # lookup, not match: a park must not distort the hit-rate stats
-        # or the LRU order the serving traffic established
-        blocks, matched = self.kv.lookup(tokens)
-        if not blocks:
-            return False
-        self._parked[key] = _ParkedChain(
-            blocks=blocks, tokens=matched,
-            expires_at=self._clock.now() + ttl_s)
-        _PARKED.inc()
-        return True
-
-    def _release_parked(self, key: str, reason: str) -> bool:
-        chain = self._parked.pop(key, None)
-        if chain is None:
-            return False
-        self.kv.release(chain.blocks)
-        _PARKED_RELEASED.inc(reason=reason)
-        return True
-
-    def _sweep_parked(self) -> None:
-        if not self._parked:
-            return
-        now = self._clock.now()
-        expired = [k for k, c in self._parked.items()
-                   if now >= c.expires_at]
-        for key in expired:
-            self._release_parked(key, "ttl")
-
-    def _shed_parked_for_pressure(self, need_blocks: int) -> None:
-        """Release parked chains — soonest expiry first — until
-        ``need_blocks`` are coverable. Parked chains are strictly
-        cheaper to lose than any resident request: a released pin costs
-        a future re-prefill, a preemption throws away decode work."""
-        while self._parked and self.kv.available() < need_blocks:
-            key = min(self._parked,
-                      key=lambda k: self._parked[k].expires_at)
-            self._release_parked(key, "pressure")
-
-    # -- cross-replica KV import/export --------------------------------------
+    def request_kv_export(self, tokens: Sequence[int],
+                          timeout_s: float = 5.0):
+        return self.kv_io.request_kv_export(tokens, timeout_s)
 
     def queue_kv_import(self, export) -> None:
-        """Enqueue a transferred prefix (``KVBlockExport``); applied
-        between engine steps, strictly before admissions. Queue BEFORE
-        submitting the request that wants it."""
-        self._refuse_call_for_state("KV import")
-        with self._kv_io_lock:
-            self._pending_imports.append(export)
-        self.queue.work_available.set()     # wake a parked loop
-
-    def _apply_imports(self) -> bool:
-        with self._kv_io_lock:
-            if not self._pending_imports:
-                return False
-            pending, self._pending_imports = self._pending_imports, []
-        from lzy_tpu.serving.disagg.kv_export import import_kv
-
-        applied = False
-        for export in pending:
-            n = import_kv(self, export)
-            if n:
-                applied = True
-                self.kv_imports += 1
-                self.kv_import_blocks += n
-                self._note_kv_import("applied", n)
-            else:
-                self._note_kv_import("skipped", 0)
-        return applied
+        self.kv_io.queue_kv_import(export)
 
     def _note_kv_import(self, outcome: str, blocks: int) -> None:
         """Metrics hook — the disagg ``DecodeEngine`` counts its
         ``lzy_disagg_kv_imports_total`` family here."""
 
-    def request_kv_export(self, tokens: Sequence[int],
-                          timeout_s: float = 5.0):
-        """Snapshot this engine's cached KV covering ``tokens``' prefix
-        — radix-resident blocks plus host-tier continuation chains — as
-        one ``KVBlockExport``, WITHOUT the caller touching the live
-        cache: the gather runs on the engine's own scheduling thread
-        between steps (a concurrent prefill would donate those
-        buffers). Returns None on timeout, shutdown, or nothing cached
-        — the caller (the gateway's cross-replica import) degrades to
-        a local re-prefill."""
-        self._refuse_call_for_state("KV export")
-        if self._closed:
-            return None
-        if self._thread is None:
-            # synchronous/test mode: by the engine's single-driver
-            # contract the caller IS the scheduling thread
-            try:
-                return self._export_now(tokens)
-            except Exception:  # noqa: BLE001 — export is advisory
-                return None
-        holder: dict = {}
-        done = threading.Event()
-        with self._kv_io_lock:
-            self._export_requests.append((list(tokens), holder, done))
-        self.queue.work_available.set()
-        if not done.wait(timeout_s):
-            return None
-        return holder.get("export")
-
-    def _service_kv_io(self) -> bool:
-        """Between-steps servicing of the import queue and pending
-        export requests (both on the scheduling thread — the only
-        thread that may read or scatter the pooled cache leaves)."""
-        did = self._apply_imports()
-        with self._kv_io_lock:
-            if not self._export_requests and not self._park_requests:
-                return did
-            requests, self._export_requests = self._export_requests, []
-            parks, self._park_requests = self._park_requests, []
-        # a chain parked or exported may be a live row's: its tokens first
-        self._drain("io")
-        for kind, key, tokens, ttl_s, holder, done in parks:
-            try:
-                holder["ok"] = (self._park_now(key, tokens, ttl_s)
-                                if kind == "park"
-                                else self._release_parked(key, "explicit"))
-            except Exception as e:  # noqa: BLE001 — parking is advisory
-                _LOG.warning("park request failed (%s: %s)",
-                             type(e).__name__, e)
-                holder["ok"] = False
-            finally:
-                done.set()
-            did = True
-        for tokens, holder, done in requests:
-            try:
-                holder["export"] = self._export_now(tokens)
-            except Exception as e:  # noqa: BLE001 — export is advisory
-                _LOG.warning("kv export request failed (%s: %s)",
-                             type(e).__name__, e)
-                holder["export"] = None
-            finally:
-                done.set()
-            did = True
-        return did
-
-    def _export_now(self, tokens: Sequence[int]):
-        """Compose the export: the pinned radix gather (``export_kv``)
-        for the HBM-resident prefix, extended block-by-block from the
-        host tier (``peek`` — the source keeps its copy; the importer
-        allocates its own fresh blocks)."""
-        from lzy_tpu.channels.kv_transfer import KVBlockExport
-        from lzy_tpu.serving.disagg.kv_export import export_kv
-
-        page = self._page
-        n_full = len(tokens) // page
-        if n_full == 0:
-            return None
-        prefix = [int(t) for t in tokens[:n_full * page]]
-        export = export_kv(self, prefix)
-        depth = len(export.tokens) // page if export is not None else 0
-        tier = self.kv_tier
-        if tier is None or depth >= n_full:
-            return export
-        extra: List[Any] = []
-        while depth + len(extra) < n_full:
-            entry = tier.peek(
-                tuple(prefix[:(depth + len(extra) + 1) * page]))
-            if entry is None:
-                break
-            extra.append(entry)
-        if not extra:
-            return export
-        if export is None:
-            keys = set(extra[0].leaves)
-            if any(set(e.leaves) != keys for e in extra):
-                return None
-            leaves = {k: np.stack([e.leaves[k] for e in extra])
-                      for k in extra[0].leaves}
-            return KVBlockExport(tokens=prefix[:len(extra) * page],
-                                 page_size=page, leaves=leaves)
-        keys = set(export.leaves)
-        if any(set(e.leaves) != keys for e in extra):
-            return export           # mismatched leaf sets: HBM part only
-        leaves = {}
-        for k, arr in export.leaves.items():
-            leaves[k] = np.concatenate(
-                [np.asarray(arr)] + [e.leaves[k][None] for e in extra])
-        return KVBlockExport(
-            tokens=prefix[:(depth + len(extra)) * page],
-            page_size=page, leaves=leaves)
+    def kv_tier_match_len(self, tokens: Sequence[int]) -> int:
+        return self.kv_io.tier_match_len(tokens)
 
     def kv_chains(self, limit: int = 4096) -> dict:
-        """Chains this replica could serve an import from, by tier —
-        the advertisement the gateway's global prefix index refreshes
-        each tick. Best-effort and lock-free over the tree (the index
-        is an expectation; a torn walk costs at worst one pointless
-        import attempt that degrades to re-prefill). Cached by the
-        tree/tier structure versions: an unchanged cache returns the
-        SAME object, which the gateway uses to skip re-hashing the
-        whole advertisement every tick."""
-        version = (self.kv.structure_version,
-                   self.kv_tier.version if self.kv_tier is not None
-                   else 0)
-        cached = getattr(self, "_kv_chains_cache", None)
-        if cached is not None and cached[0] == version:
-            return cached[1]
-        out = {"hbm": [], "host": []}
-        try:
-            # LEAF chains only: the index registers every chunk depth of
-            # a chain, so interior-node chains would be pure redundancy —
-            # wasted hashing per tick, and worse, shallow chains crowding
-            # the advertisement limit out of the deep ones that make
-            # imports worth staging
-            def walk(node, prefix):
-                for child in list(node.children.values()):
-                    if len(out["hbm"]) >= limit:
-                        return
-                    chain = prefix + list(child.chunk)
-                    if not child.children:
-                        out["hbm"].append(chain)
-                    walk(child, chain)
+        return self.kv_io.kv_chains(limit)
 
-            walk(self.kv._root, [])
-        except Exception:  # noqa: BLE001 — advertisement is advisory
-            pass
-        if self.kv_tier is not None:
-            try:
-                out["host"] = [list(c)
-                               for c in self.kv_tier.chains()[:limit]]
-            except Exception:  # noqa: BLE001 — advertisement is advisory
-                pass
-        self._kv_chains_cache = (version, out)
-        return out
-
-    @property
-    def kv_tier_demotions(self) -> int:
-        """Demotions down the ladder (hbm→host + host→storage); 0
-        without a tier. Read by the fleet aggregate."""
-        if self.kv_tier is None:
-            return 0
-        s = self.kv_tier.stats()
-        return s["demotions"] + s["demotions_to_storage"]
-
-    @property
-    def kv_tier_promotions(self) -> int:
-        if self.kv_tier is None:
-            return 0
-        s = self.kv_tier.stats()
-        return s["promotions"] + s["promotions_from_storage"]
-
-    @property
-    def kv_tier_dropped(self) -> int:
-        if self.kv_tier is None:
-            return 0
-        return self.kv_tier.stats()["dropped"]
+    kv_tier = property(lambda self: self.kv_io.tier)
+    kv_tier_gather_ops = property(lambda self: self.kv_io.gather_ops)
+    kv_tier_gather_rounds = property(lambda self: self.kv_io.gather_rounds)
+    kv_imports = property(lambda self: self.kv_io.imports)
+    kv_import_blocks = property(lambda self: self.kv_io.import_blocks)
+    # read by the fleet aggregate; 0 without a tier
+    kv_tier_demotions = _kv_io_stat("kv_tier_demotions")
+    kv_tier_promotions = _kv_io_stat("kv_tier_promotions")
+    kv_tier_dropped = _kv_io_stat("kv_tier_dropped")
 
     # -- decode --------------------------------------------------------------
 
@@ -3291,13 +2662,9 @@ class PagedInferenceEngine:
                 try:
                     block = self.kv.allocate(1)[0]
                 except NoFreeBlocks:
-                    if self._parked:
+                    if self.kv_io.shed_parked(1):
                         # parked chains are sacrificed before ANY
-                        # resident request: one release, then retry
-                        # (their blocks fall back to evictable cache)
-                        key = min(self._parked,
-                                  key=lambda k: self._parked[k].expires_at)
-                        self._release_parked(key, "pressure")
+                        # resident request: then retry
                         continue
                     if self._drain("squeeze"):
                         # the victim is owed the token in flight, and a

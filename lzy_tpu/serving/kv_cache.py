@@ -230,22 +230,19 @@ class RadixCache:
         # tokens whose state nobody kept, so every lookup finds nothing
         # (and still counts as a lookup) and nothing is inserted
         self.reuse = True
-        # tier hooks (serving/kv_tier.py): ``on_evict(chain_tokens,
-        # block, origin)`` fires BEFORE an evicted leaf's block returns
-        # to the free list — the engine's demotion hook gathers the
-        # block's K/V rows to host memory there; ``on_evict_batch``
-        # (preferred when set) receives every victim of ONE eviction
-        # round — ``[(chain_tokens, block, origin), ...]`` — in a single
-        # call, so the engine can coalesce the per-block device→host
-        # copies into one gather per cache leaf; ``on_insert(chain)``
+        # tier hooks (serving/kv_tier.py): ``on_evict(victims)`` receives
+        # every victim of ONE eviction round — ``[(chain_tokens, block,
+        # origin), ...]`` — in a single call BEFORE the evicted leaves'
+        # blocks return to the free list, so the engine's demotion hook
+        # can gather their K/V rows to host memory there, one gather per
+        # cache leaf for the round; ``on_insert(chain)``
         # fires for each NEWLY created tree node with its full root→node
         # token chain — the engine drops any demoted-tier copy of that
         # chain (the HBM copy is authoritative, and a chain must live in
-        # exactly one tier for the conservation audit to hold). All are
+        # exactly one tier for the conservation audit to hold). Both are
         # guarded: a hook failure degrades to classic eviction / a
         # harmless stale tier entry, never a broken tree.
         self.on_evict = None
-        self.on_evict_batch = None
         self.on_insert = None
         self._cached = 0
         self._evictable = 0
@@ -441,10 +438,10 @@ class RadixCache:
 
         Evictions for one allocate call form ONE round: every victim is
         detached first, the demotion hook runs once over the whole batch
-        (``on_evict_batch`` — one device→host gather per cache leaf
-        instead of per block; per-block ``on_evict`` is the fallback),
-        and only then do the blocks return to the free list — the hook
-        must see the victims' K/V before anything can overwrite it."""
+        (``on_evict`` — one device→host gather per cache leaf instead of
+        per block), and only then do the blocks return to the free list —
+        the hook must see the victims' K/V before anything can overwrite
+        it."""
         self.calls += 1
         have = self.pool.free_count() + self._evictable
         if n > have:
@@ -472,22 +469,13 @@ class RadixCache:
     def _offer_demotions(self, victims: List["_Node"]) -> None:
         """Offer one eviction round's victims for demotion (guarded —
         a hook failure degrades to the classic drop)."""
-        if self.on_evict_batch is not None:
-            try:
-                self.on_evict_batch(
-                    [(self.chain_tokens(v), v.block, v.origin)
-                     for v in victims])
-            except Exception:  # noqa: BLE001 — demotion is advisory
-                pass
-            return
         if self.on_evict is None:
             return
-        for victim in victims:
-            try:
-                self.on_evict(self.chain_tokens(victim), victim.block,
-                              victim.origin)
-            except Exception:  # noqa: BLE001 — demotion is advisory
-                pass
+        try:
+            self.on_evict([(self.chain_tokens(v), v.block, v.origin)
+                           for v in victims])
+        except Exception:  # noqa: BLE001 — demotion is advisory
+            pass
 
     def _detach_victim(self) -> Optional["_Node"]:
         """Detach the LRU unreferenced leaf from the tree WITHOUT

@@ -27,8 +27,7 @@ from lzy_tpu.models import llama, unbox
 from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
 from lzy_tpu.serving import (
-    DecodeEngine, NoFreeBlocks, PagedInferenceEngine, PrefillEngine,
-    export_kv, import_kv)
+    DecodeEngine, NoFreeBlocks, PagedInferenceEngine, PrefillEngine)
 
 PAGE = 8
 
@@ -185,7 +184,7 @@ class TestExportImportUnits:
                 pf.kv.allocate(pf.kv.available() + 1)
             seen["match_during"] = pf.kv.match_len(prompt[:16])
 
-        export = export_kv(pf, prompt, on_pinned=while_pinned)
+        export = pf.kv_io.export_kv(prompt, on_pinned=while_pinned)
         assert export is not None and export.n_blocks == 2
         assert seen["pinned"] == 2
         assert seen["match_during"] == 16
@@ -212,7 +211,7 @@ class TestExportImportUnits:
         warm = de.submit(list(range(32, 48)) + [41], max_new_tokens=2)
         _drive(de, warm)
         assert de.kv.match_len(list(range(32, 48))) == 16
-        assert import_kv(de, export) == 2             # 1 free + 1 evicted
+        assert de.kv_io.import_kv(export) == 2        # 1 free + 1 evicted
         assert de.kv.evictions >= 1, "import did not need eviction"
         assert de.kv.match_len(export.tokens) == 16
         # now pin the whole pool with a live request and import on top
@@ -227,7 +226,7 @@ class TestExportImportUnits:
         # free+evictable cannot cover 2 blocks with the resident pinned:
         # the import is refused outright, never forced
         assert de.kv.available() < 2
-        assert import_kv(de, big) == 0
+        assert de.kv_io.import_kv(big) == 0
         _drive(de, resident)
         assert resident.result(0) == _oracle_tokens(
             cfg, params, resident.prompt, 5), "resident request corrupted"
@@ -243,8 +242,8 @@ class TestExportImportUnits:
         cfg, params = tiny_model
         de = DecodeEngine(cfg, params, slots=1, page_size=PAGE)
         # 1) page-size mismatch → skipped outright
-        assert import_kv(
-            de, dataclasses.replace(export16, page_size=PAGE * 2)) == 0
+        assert de.kv_io.import_kv(
+            dataclasses.replace(export16, page_size=PAGE * 2)) == 0
         # 2) queued import lands before the admission round
         prompt = export16.tokens + [40, 41]
         de.queue_kv_import(export16)
@@ -256,7 +255,7 @@ class TestExportImportUnits:
         assert s.prefill_tokens_saved == 16
         # 3) the prefix is now cached: importing it again is a no-op
         free_before = de.kv.pool.free_count()
-        assert import_kv(de, export16) == 0
+        assert de.kv_io.import_kv(export16) == 0
         assert de.kv.pool.free_count() == free_before
 
 

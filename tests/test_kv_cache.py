@@ -220,16 +220,13 @@ class _Walker:
     free list empty most of the time. Every call is held to the
     reckoning, and every eviction round to the victims it predicts."""
 
-    def __init__(self, kv, page, seed, hook):
+    def __init__(self, kv, page, seed):
         self.kv, self.page = kv, page
         self.rng = np.random.default_rng(seed)
         self.live = []                  # (tokens, blocks) of open requests
         self.prompts = []
         self.batches, self.inserted = [], []
-        if hook == "batch":
-            kv.on_evict_batch = lambda b: self.batches.append(list(b))
-        else:
-            kv.on_evict = lambda *v: self.batches.append([v])
+        kv.on_evict = lambda b: self.batches.append(list(b))
         kv.on_insert = self.inserted.append
         self.evicted = 0
 
@@ -257,7 +254,7 @@ class _Walker:
         out = kv.allocate(n)
         got = [v for batch in self.batches for v in batch]
         assert got == want
-        if want and kv.on_evict_batch is not None:
+        if want:
             assert len(self.batches) == 1          # one round, one call
         self.evicted += len(want)
         assert kv.evictions == self.evicted
@@ -317,13 +314,12 @@ class TestKeptCounts:
     """PR 34: the cache keeps what it used to recompute by walking the
     tree. The walks live on in ``chaos/invariants.py`` as the oracle."""
 
-    @pytest.mark.parametrize("hook", ["batch", "single"])
     @pytest.mark.parametrize("page", [1, 4, 8])
     @pytest.mark.parametrize("reuse", [True, False])
-    def test_random_walk_holds_to_the_reckoning(self, reuse, page, hook):
+    def test_random_walk_holds_to_the_reckoning(self, reuse, page):
         kv = RadixCache(40, page)
         kv.reuse = reuse
-        walker = _Walker(kv, page, seed=1000 * page + reuse, hook=hook)
+        walker = _Walker(kv, page, seed=1000 * page + reuse)
         for _ in range(2500):
             walker.step()
         if reuse:
@@ -398,7 +394,8 @@ class TestKeptCounts:
         kv.allocate(kv.pool.free_count())
         order = [v.block for v in invariants.reckon_victims(kv, 24)]
         seen = []
-        kv.on_evict = lambda tokens, block, origin: seen.append(block)
+        kv.on_evict = lambda victims: seen.extend(
+            block for _, block, _ in victims)
         for _ in range(24):
             kv.allocate(1)
         assert seen == order and order[-4:] == blocks[::-1]

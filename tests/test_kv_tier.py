@@ -513,8 +513,7 @@ class TestKvTierChaos:
 class TestBatchedDemotionGathers:
     """Satellite (ROADMAP item 2 remainder): one eviction round's
     per-block device→host copies coalesce into a single gather per
-    cache leaf (``RadixCache.on_evict_batch`` →
-    ``PagedInferenceEngine._demote_blocks``)."""
+    cache leaf (``RadixCache.on_evict`` → ``KvIO.demote``)."""
 
     def test_one_gather_per_leaf_per_eviction_round(self, tiny_model):
         cfg, params = tiny_model
@@ -537,8 +536,7 @@ class TestBatchedDemotionGathers:
             rounds = eng.kv_tier_gather_rounds - rounds_before
             ops = eng.kv_tier_gather_ops - ops_before
             demoted = eng.kv_tier.stats()["demotions"] - demoted_before
-            n_leaves = sum(1 for k in eng._kv_leaf_keys()
-                           if k is not None)
+            n_leaves = len(eng.kv_io.leaf_keys)
             # the count-of-transfers contract: >= 2 blocks demoted in
             # ONE round, paying exactly one gather PER LEAF — not one
             # per (leaf x block) as the per-block path did

@@ -658,7 +658,6 @@ class TestKvProvenance:
         replica; a request matching them records it (the disagg reply's
         `prefilled_by` used-semantics), while locally-prefilled chains
         stay origin-free."""
-        from lzy_tpu.serving.disagg.kv_export import export_kv, import_kv
 
         cfg, params = tiny_model
         src = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
@@ -667,9 +666,9 @@ class TestKvProvenance:
         req = src.submit(prompt, max_new_tokens=2)
         while not req.done:
             src.step()
-        export = export_kv(src, prompt[:16])
+        export = src.kv_io.export_kv(prompt[:16])
         export.prefilled_by = "prefill-7"
-        assert import_kv(dst, export) == 2
+        assert dst.kv_io.import_kv(export) == 2
         assert dst.kv.chain_origin(prompt[:16]) == "prefill-7"
         # a request through the engine records the used origin
         req2 = dst.submit(prompt, max_new_tokens=2)

@@ -1068,7 +1068,6 @@ class TestQuantMismatchFailsClosed:
         pool that reads them as KV values — the decode replica's output
         must stay the fp oracle's."""
         from lzy_tpu.serving import DecodeEngine, PrefillEngine
-        from lzy_tpu.serving.disagg.kv_export import import_kv
 
         cfg, params = tiny_model
         prompt = list(range(16)) + [40]
@@ -1083,7 +1082,7 @@ class TestQuantMismatchFailsClosed:
         de = DecodeEngine(cfg, params, slots=1, page_size=8)
         try:
             free_before = de.kv.pool.free_count()
-            assert import_kv(de, export) == 0
+            assert de.kv_io.import_kv(export) == 0
             assert de.kv.match_len(prompt) == 0, \
                 "a refused import must not register the prefix"
             assert de.kv.pool.free_count() == free_before
@@ -1095,7 +1094,6 @@ class TestQuantMismatchFailsClosed:
 
     def test_fp_export_into_quant_pool_is_refused(self, tiny_model):
         from lzy_tpu.serving import DecodeEngine, PrefillEngine
-        from lzy_tpu.serving.disagg.kv_export import import_kv
 
         cfg, params = tiny_model
         prompt = list(range(16)) + [40]
@@ -1109,7 +1107,7 @@ class TestQuantMismatchFailsClosed:
         de = DecodeEngine(cfg, params, slots=1, page_size=8,
                           kv_quant="int8")
         try:
-            assert import_kv(de, export) == 0
+            assert de.kv_io.import_kv(export) == 0
             assert de.kv.match_len(prompt) == 0
         finally:
             de.close()
@@ -1172,7 +1170,6 @@ class TestQuantDisaggTransfer:
         the monolithic quantized engine's (quantization is deterministic,
         so identical fp inputs produce identical int8 bytes)."""
         from lzy_tpu.serving import DecodeEngine, PrefillEngine
-        from lzy_tpu.serving.disagg.kv_export import import_kv
 
         cfg, params = tiny_model
         prompt = list(range(16)) + [40]      # 2 full blocks at page 8
@@ -1190,7 +1187,7 @@ class TestQuantDisaggTransfer:
             "quant sidecars must ride the transfer payload"
         de = DecodeEngine(cfg, params, slots=1, **kw)
         try:
-            assert import_kv(de, export) == 2
+            assert de.kv_io.import_kv(export) == 2
             r = de.submit(prompt, max_new_tokens=8)
             _drive(de, r)
             assert r.error is None, r.error

@@ -44,7 +44,6 @@ from lzy_tpu.models.generate import generate
 from lzy_tpu.models.llama import LlamaConfig
 from lzy_tpu.serving import PagedInferenceEngine
 from lzy_tpu.serving import engine as engine_mod
-from lzy_tpu.serving.disagg.kv_export import export_kv, import_kv
 from lzy_tpu.serving.sharded import ShardedPagedInferenceEngine
 from lzy_tpu.serving.sharded import metrics as _m
 
@@ -353,7 +352,7 @@ class TestShardedKVTransfer:
         cfg, params = tiny_model
         prompt = [3, 1, 4, 1, 5, 9, 2, 6] * 4          # 2 full pages
         out = _run(gang, prompt, 8)
-        export = export_kv(gang, prompt)
+        export = gang.kv_io.export_kv(prompt)
         assert export is not None
         assert tuple(export.mesh_shape) == (1, 2)
         assert export.n_blocks == 2
@@ -361,13 +360,13 @@ class TestShardedKVTransfer:
         # geometry-exact import into a fresh 1×2 gang
         sibling = _gang(cfg, params)
         try:
-            assert import_kv(sibling, export) == 2
+            assert sibling.kv_io.import_kv(export) == 2
             assert _run(sibling, prompt, 8) == out
         finally:
             sibling.close()
 
         # fail closed into the single-device pool (mesh (1,2) ≠ none)
-        assert import_kv(baseline, export) == 0
+        assert baseline.kv_io.import_kv(export) == 0
         # ...which costs nothing but a local re-prefill
         assert _run(baseline, prompt, 8) == out
 
@@ -378,11 +377,11 @@ class TestShardedKVTransfer:
         cfg, params = tiny_model
         prompt = [7, 7, 2, 9, 1, 8, 3, 5] * 4
         out = _run(baseline, prompt, 8)
-        export = export_kv(baseline, prompt)
+        export = baseline.kv_io.export_kv(prompt)
         assert export is not None and export.mesh_shape is None
         eng = _gang(cfg, params)
         try:
-            assert import_kv(eng, export) == 2
+            assert eng.kv_io.import_kv(export) == 2
             assert _run(eng, prompt, 8) == out
         finally:
             eng.close()

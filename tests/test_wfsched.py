@@ -91,9 +91,9 @@ def _prefill_saved(fleet) -> int:
 
 
 def _parked_released(reason: str) -> float:
-    from lzy_tpu.serving.engine import _PARKED_RELEASED
+    from lzy_tpu.serving.kv_io import PARKED_RELEASED
 
-    return sum(v for k, v in _PARKED_RELEASED._values.items()
+    return sum(v for k, v in PARKED_RELEASED._values.items()
                if reason in str(k))
 
 

@@ -411,12 +411,17 @@ def test_a_park_request_drains_the_round_in_flight(models):
             break
     assert engine._inflight is not None
     before = _drains()
-    holder, done = {}, threading.Event()
-    # as ``park_chain`` queues it from another thread for a running loop
-    engine._park_requests.append(
-        ("park", "conversation", prompt, 30.0, holder, done))
+    # ``park_chain`` from another thread queues it, as for a running loop
+    engine._thread, parked = threading.current_thread(), []
+    asker = threading.Thread(target=lambda: parked.append(
+        engine.park_chain("conversation", prompt)))
+    asker.start()
+    while not engine.kv_io._calls:
+        time.sleep(0.001)
     engine.step()
-    assert done.is_set() and holder["ok"] is True
+    asker.join(5.0)
+    engine._thread = None
+    assert parked == [True]
     assert _drains()["io"] - before["io"] == 1
     _run(engine, [req])
     assert len(req.tokens) == 30 and req.error is None

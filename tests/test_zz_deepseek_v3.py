@@ -588,19 +588,18 @@ def test_parking_pins_a_conversations_latent_pages(tiny):
 
 
 def test_export_and_import_move_the_latent_leaf_by_block_id(tiny):
-    from lzy_tpu.serving.disagg.kv_export import export_kv, import_kv
 
     cfg, _ = tiny
     prompt = _tokens(72, 37, cfg.vocab_size)
     source = _engine(tiny, slots=1)
     a = source.submit(prompt, max_new_tokens=5, greedy=True)
     _drain(source)
-    export = export_kv(source, prompt)
+    export = source.kv_io.export_kv(prompt)
     assert export is not None and len(export.tokens) == 32
     assert all(leaf.shape == (2, 16, 128)
                for leaf in export.leaves.values()) and len(export.leaves) == 3
     target = _engine(tiny, slots=1)
-    assert import_kv(target, export) == 2
+    assert target.kv_io.import_kv(export) == 2
     b = target.submit(prompt, max_new_tokens=5, greedy=True)
     _drain(target)
     assert target.stats().prefill_tokens_saved == 32

@@ -264,7 +264,8 @@ def _host_side_prefill(engine, prompt, blocks):
               if kind == serving.STATE else leaf
               for kind, leaf in zip(
                   engine._leaf_kinds,
-                  jax.tree_util.tree_leaves(engine._cache))]
+                  jax.tree_util.tree_leaves(engine._assemble_cache(
+                      engine._payload, jnp.zeros((1,), jnp.int32))))]
     arr = jnp.asarray([prompt], jnp.int32)
     for start, take, width in prefill_plan(
             len(prompt), engine.prefill_chunk, cfg.max_seq_len):
