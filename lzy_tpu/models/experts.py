@@ -1,18 +1,26 @@
 """What the served models with sparse experts share: the counters their
-expert layers feed, the router that tells a layer which of the experts
-it holds each row reaches (``models/nemotron_h.py``,
-``models/solar_open2.py``, ``models/deepseek_v3.py``), and the gated expert
-layer two of them are built from (:class:`GatedExperts`).
+expert layers feed, the part of the router that tells a layer which of the
+experts it holds each row reaches (``models/nemotron_h.py``,
+``models/solar_open2.py``, ``models/deepseek_v3.py``,
+``models/cohere2_moe.py``, ``models/zaya.py``), and the gated expert layer
+three of them are built from (:class:`GatedExperts`).
 
-**The router** is a sigmoid over all the routed experts, float32 at the
-highest precision (a near-tie among its scores decides which expert a row
-reaches): the ``top_k`` largest of ``score + bias`` are chosen (the
-correction bias of auxiliary-loss-free load balancing steers the choice
-only), and the chosen scores are renormalised and scaled. **The layer is
-told which experts it holds** (``held = (lo, hi)``): the router keeps its
-published width and its experts per token, and a chosen expert held
-elsewhere gets no weight here. Pad positions and idle slots (``real``
-False) are left out of the weights and of the counts.
+**The scoring is the family's.** Four of the five score with a sigmoid over
+all the routed experts (:func:`sigmoid_scores`: ``sigmoid(u W)``, float32 at
+the highest precision: a near-tie among its scores decides which expert a row
+reaches; a correction bias of auxiliary-loss-free load balancing for the
+choice); ZAYA's scores are a softmax over an MLP that adds the previous
+layer's router state (``models/zaya.py``).
+
+**The choice, the weights and the counts are shared**
+(:func:`held_weights`): the ``top_k`` largest of ``score + bias`` are chosen
+(the bias steers the choice only), the chosen scores are the weights,
+renormalised and scaled where the family says so (with one expert a token the
+weight is the score itself). **The layer is told which experts it holds**
+(``held = (lo, hi)``): the router keeps its published width and its experts
+per token, and a chosen expert held elsewhere gets no weight here. Pad
+positions and idle slots (``real`` False) are left out of the weights and of
+the counts.
 
 **The counts** (:data:`STATS`): one vector a layer, sown into the ``stats``
 collection in this order, summed over layers by the engine and carried out
@@ -56,32 +64,44 @@ def row_mask(valid_len, b: int, t: int):
     return jnp.arange(t)[None, :] < valid_len[:, None]
 
 
-def held_weights(layer: nn.Module, um, real, *, n_routed: int, top_k: int,
-                 held: Tuple[int, int], scaling: float, other_stats: int = 0,
-                 choice_bias: bool = True):
-    """``[M, held]`` float32: each row's weight for each expert held here
-    (0 where it did not choose it, or is not real). Called from the expert
-    layer's compact method: the parameters ``router`` ``[D, n_routed]`` and
-    ``router_bias`` are the layer's own, the row's choices are sown as
-    ``intermediates/chosen`` and the layer's counts as ``stats/moe``
-    (followed by ``other_stats`` zeros: the places of the counts the model's
-    other layers sow, every ``stats`` leaf being one vector of ``STATS``)."""
+def sigmoid_scores(layer: nn.Module, um, n_routed: int, *,
+                   choice_bias: bool = True):
+    """The scoring of the four families whose router is ``sigmoid(u W)``:
+    ``([M, n_routed] float32 scores, the choice's correction bias or None)``.
+    Called from the expert layer's compact method: the parameters ``router``
+    ``[D, n_routed]`` and ``router_bias`` are the layer's own."""
     f32 = jnp.float32
-    lo, hi = held
-    n_held = hi - lo
     wr = layer.param("router", nn.initializers.normal(0.02),
                      (um.shape[-1], n_routed), f32)
     scores = jax.nn.sigmoid(jnp.dot(
         um.astype(f32), wr, precision=jax.lax.Precision.HIGHEST))
-    if choice_bias:
-        bias = layer.param("router_bias", nn.initializers.normal(0.02),
-                           (n_routed,), f32)
-        _, chosen = jax.lax.top_k(scores + bias, top_k)          # [M, k]
-    else:
-        _, chosen = jax.lax.top_k(scores, top_k)
+    bias = layer.param("router_bias", nn.initializers.normal(0.02),
+                       (n_routed,), f32) if choice_bias else None
+    return scores, bias
+
+
+def held_weights(layer: nn.Module, scores, real, *, top_k: int,
+                 held: Tuple[int, int], bias=None, renormalise: bool = True,
+                 scaling: float = 1.0, other_stats: int = 0):
+    """``[M, held]`` float32: each row's weight for each expert held here
+    (0 where it did not choose it, or is not real), from the family's own
+    ``scores`` ``[M, n_routed]`` float32. The ``top_k`` largest of
+    ``scores + bias`` are chosen (``bias`` steers the choice only; None for
+    none); a row's weight for a chosen expert is its score, renormalised
+    over the row's choices where ``renormalise`` says so, times ``scaling``.
+    Called from the expert layer's compact method: the row's choices are sown
+    as ``intermediates/chosen`` and the layer's counts as ``stats/moe``
+    (followed by ``other_stats`` zeros: the places of the counts the model's
+    other layers sow, every ``stats`` leaf being one vector of ``STATS``)."""
+    lo, hi = held
+    n_held = hi - lo
+    _, chosen = jax.lax.top_k(scores if bias is None else scores + bias,
+                              top_k)                             # [M, k]
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) \
-        * scaling
+    if renormalise:
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    if scaling != 1.0:
+        picked = picked * scaling
     # for whoever asks (``mutable=["intermediates"]``): a row's choices
     layer.sow("intermediates", "chosen", chosen)
     on_held = (chosen >= lo) & (chosen < hi) & real[:, None]
@@ -121,11 +141,13 @@ class GatedExperts(nn.Module):
         f32 = jnp.float32
         um = u.reshape(m, dm)
         real = row_mask(valid_len, b, t).reshape(m)
-        weights = held_weights(
-            self, um, real, n_routed=cfg.n_routed_experts, top_k=cfg.top_k,
-            held=cfg.experts_held, scaling=cfg.routed_scaling,
-            other_stats=self.other_stats,
+        scores, bias = sigmoid_scores(
+            self, um, cfg.n_routed_experts,
             choice_bias=getattr(cfg, "router_bias", True))
+        weights = held_weights(
+            self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
+            bias=bias, scaling=cfg.routed_scaling,
+            other_stats=self.other_stats)
         up_shape = (cfg.n_held, dm, cfg.expert_width)
         wg = self.param("experts_gate", normal(), up_shape, cfg.param_dtype)
         wu = self.param("experts_up", normal(), up_shape, cfg.param_dtype)

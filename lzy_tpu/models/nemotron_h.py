@@ -44,7 +44,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from lzy_tpu.models.experts import STATS, held_weights, row_mask
+from lzy_tpu.models.experts import (
+    STATS, held_weights, row_mask, sigmoid_scores)
 from lzy_tpu.models.llama import RMSNorm
 from lzy_tpu.models.paged_blocks import (
     PagedAttention, dense, inv_softplus, normal)
@@ -300,9 +301,10 @@ class LatentExperts(nn.Module):
         um = u.reshape(m, dm)
         real = row_mask(valid_len, b, t).reshape(m)
 
+        scores, bias = sigmoid_scores(self, um, cfg.n_routed_experts)
         weights = held_weights(
-            self, um, real, n_routed=cfg.n_routed_experts, top_k=cfg.top_k,
-            held=cfg.experts_held, scaling=cfg.routed_scaling)
+            self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
+            bias=bias, scaling=cfg.routed_scaling)
         v = dense(cfg.latent, "latent_down", cfg)(um)
         w1 = self.param("experts_w1", nn.initializers.normal(0.02),
                         (cfg.n_held, cfg.latent, cfg.expert_width),

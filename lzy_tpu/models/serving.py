@@ -5,8 +5,9 @@ the configuration object and by the module class it builds;
 ``models/nemotron_h.py``'s ``NemotronHConfig`` / ``NemotronH``,
 ``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2``,
 ``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3``,
-``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe`` and
-``models/jamba.py``'s ``JambaConfig`` / ``Jamba`` all do.
+``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe``,
+``models/jamba.py``'s ``JambaConfig`` / ``Jamba`` and ``models/zaya.py``'s
+``ZayaConfig`` / ``Zaya`` all do: seven families.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -92,7 +93,8 @@ counts is told which positions are real (``valid_len``).
   behind it are gone), so the radix cache is off and the mechanisms that
   move pages by tokens refuse such a model by name (``docs/serving.md``).
 - ``state``: ``[slots, ...]``, one row a slot (a recurrence's state, a
-  convolution's window). A prefill job carries its own batch-1 row between
+  convolution's window; ``models/zaya.py``'s is the last two positions'
+  latent projections and nothing else: a window, not a recurrence). A prefill job carries its own batch-1 row between
   chunks and the engine splices it into the slot's row when the prompt is
   done; a padded chunk and an idle slot must not advance it (the engine
   passes ``valid_len``); nothing can share, export or rewind it, so a model
@@ -117,8 +119,8 @@ def leaf_kind(model: Any, path) -> str:
 class HeadPool:
     """The answers of a configuration whose pages hold keys and values a
     head (``models/llama.py``, ``models/nemotron_h.py``,
-    ``models/solar_open2.py``: pools ``[pages, page, KV, D]``, twice, read by
-    ``ops/paged_attention.py``), from its ``n_heads``, ``n_kv_heads``,
+    ``models/solar_open2.py``, ``models/zaya.py``: pools ``[pages, page, KV,
+    D]``, twice, read by ``ops/paged_attention.py``), from its ``n_heads``, ``n_kv_heads``,
     ``head_dim`` and ``dtype``. ``models/jamba.py`` takes the bytes a token
     from here and answers ``read_path`` and its lowering itself: its pools
     are ``[pages, page, KV x D]``, read by ``paged_group_attention``."""

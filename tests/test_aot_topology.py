@@ -611,3 +611,71 @@ def test_jamba_kernels_compile_at_published_widths(case, one_chip):
         pool_bytes = 16384 * 128 * 128 * 2
         assert compiled.memory_analysis().temp_size_in_bytes \
             < pool_bytes // 8
+
+
+@pytest.mark.parametrize("case", ["update64", "update1", "decode", "prefill"])
+def test_zaya_kernels_compile_at_published_widths(case, one_chip,
+                                                  monkeypatch):
+    """ZAYA1-8B: the window update at 64 slots and at one row (a chunk of
+    one), and the paged programs of a two-layer model at full width: the
+    decode round of 64 slots (``cca_mix_update``, the decode read at 8 / 2
+    heads of 128 over pages of 64, the expert product at 16 experts of 2048)
+    and the prefill chunk of 256 (the chunk read, the expert product at 256
+    rows). Mosaic refused the update once for a head's bias read as a lane
+    slice of one row spread over the rows, which the lowering alone had let
+    through."""
+    from lzy_tpu.models import zaya
+    from lzy_tpu.ops import cca
+    from lzy_tpu.ops import interpret
+
+    # the kernels as the chip compiles them, whatever conftest.py asked for
+    monkeypatch.setattr(interpret, "_process_wide", False)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if case.startswith("update"):
+        b, (h, g, d) = int(case[6:]), (8, 2, 128)
+        c, lk, width = (h + g) * d, g * d, cca.window_width(h, g, d)
+        compiled = jax.jit(
+            lambda win, new, v1, live, *w: cca.cca_mix_update(
+                win, new, v1, live, cca.Mixer(*w), heads=h, groups=g,
+                dtype=jnp.bfloat16, interpret=False),
+            donate_argnums=(0,)).lower(
+            sds((b, 2 * width)), sds((b, width)), sds((b, lk // 2)),
+            sds((b,), jnp.bool_), sds((2, c)), sds((c,)),
+            sds((h + g, 2 * d, d), jnp.bfloat16), sds((c,)), sds((g,))
+        ).compile()
+        assert "cca_mix_update" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+        return
+    cfg = zaya.ZayaConfig(n_layers=2, max_seq_len=4096)
+    page, batch, t = 64, *((64, 1) if case == "decode" else (1, 256))
+    model = cfg.paged_model(page_size=page, kv_pages=2049, kernel="pallas",
+                            kv_quant=None)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: sds(s.shape, s.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: zaya.init_params(cfg, jax.random.PRNGKey(0))))
+    pages = cfg.max_seq_len // page
+    cache = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((batch, 1), jnp.int32),
+        page_table=jnp.zeros((batch, pages), jnp.int32)))["cache"])
+
+    def step(params, cache, toks, table, valid):
+        logits, upd = model.apply(
+            {"params": params, "cache": cache}, toks, page_table=table,
+            valid_len=valid, mutable=["cache", "stats"])
+        return jnp.argmax(logits[:, -1], -1), upd["cache"], upd["stats"]
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((batch, t), jnp.int32),
+        sds((batch, pages), jnp.int32), sds((batch,), jnp.int32)
+    ).compile().as_text()
+    for kernel in (("cca_mix_update", "paged_decode_attention")
+                   if case == "decode" else ("paged_chunk_attention",)) \
+            + ("grouped_experts",):
+        assert kernel in text

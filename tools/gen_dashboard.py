@@ -55,6 +55,8 @@ def registry_metrics():
     import lzy_tpu.models.cohere2_moe  # noqa: F401
     # a model with Mamba-1 layers: live rows whose state a round moved
     import lzy_tpu.models.jamba  # noqa: F401
+    # a model with a carried window: live rows whose window a round moved
+    import lzy_tpu.models.zaya  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
