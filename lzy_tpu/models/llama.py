@@ -48,11 +48,20 @@ class LlamaConfig(HeadPool):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    # which activations remat KEEPS: "nothing" (max memory savings),
-    # "dots" (save matmul outputs — the standard TPU transformer policy:
-    # recompute cheap elementwise/norms, keep the MXU work), "all" is
-    # spelled remat=False
-    remat_policy: str = "nothing"
+    # what a rematerialised decoder layer KEEPS for its backward
+    # (docs/architecture.md, "Training a LlamaConfig"):
+    # "dots" (the default): the results of its matmuls and, under
+    #   use_flash_kernel, the attention kernel's output and log-sum-exp, so
+    #   the backward runs no projection and no attention forward again;
+    #   norms, RoPE, silu(gate) * up, casts and residual adds are recomputed
+    #   from what is kept. In bf16 that is at most 2 x (q + k + v + o +
+    #   gate + up + down widths) + 2 x d_model (+ 4 a head for the
+    #   log-sum-exp) bytes a token a layer: 94,336 at Mistral-7B's widths.
+    # "nothing": the layer's input alone (2 x d_model bytes a token a
+    #   layer); the backward runs the whole layer's forward again, a third
+    #   more matmul work. For a model that cannot hold the results.
+    # Keeping everything is spelled remat=False.
+    remat_policy: str = "dots"
     tie_embeddings: bool = False         # Llama-3 uses an untied lm_head
     use_ring_attention: bool = False     # SP via ppermute ring over 'sp'
     use_ulysses_attention: bool = False  # SP via all-to-all head resharding
@@ -201,10 +210,18 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def _remat_policy(name: str):
-    """Checkpoint policy by name (LlamaConfig.remat_policy)."""
+    """Checkpoint policy by name (LlamaConfig.remat_policy). ``"dots"``
+    keeps the matmuls' results and the flash kernel's two (a custom call's
+    results are no dot's: they are kept by the names the kernel gives
+    them), so that nothing the matrix unit did is done again."""
+    from lzy_tpu.ops.flash_attention import SAVED_NAMES
+
+    cp = jax.checkpoint_policies
     policies = {
-        "nothing": jax.checkpoint_policies.nothing_saveable,
-        "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        "nothing": cp.nothing_saveable,
+        "dots": cp.save_from_both_policies(
+            cp.dots_with_no_batch_dims_saveable,
+            cp.save_only_these_names(*SAVED_NAMES)),
     }
     try:
         return policies[name]

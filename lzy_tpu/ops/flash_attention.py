@@ -47,6 +47,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -54,6 +55,12 @@ from lzy_tpu.ops import interpret as _interpret
 
 _NEG_INF = -1e30
 _LANE = 128
+
+#: the names the forward kernel's two results carry for ``jax.checkpoint``:
+#: a policy that saves them (``save_only_these_names(*SAVED_NAMES)``) spares
+#: a rematerialised caller the forward kernel in its backward. They are a
+#: custom call's results, so no policy that looks for matmuls finds them.
+SAVED_NAMES = ("flash_attention_out", "flash_attention_lse")
 
 #: most scoped VMEM a kernel here asks for: a v5e core has 128 MiB, and the
 #: rest is left to the program around the kernel
@@ -494,6 +501,14 @@ def _flash_fwd(q, k, v, bias, seg, scale, causal, block_q, block_kv,
     o, lse = _fwd(q, k, v, bias, seg, scale=scale, causal=causal,
                   block_q=block_q, block_kv=block_kv, interpret=interpret,
                   n_heads=n_heads)
+    # the kernel writes the log-sum-exp over all 128 lanes (the module's
+    # tiling note) and ``_fwd`` cuts one out. Left alone, XLA sinks that cut
+    # to the backward and keeps the float32 [bh, t, 128] block, twice the
+    # output's bytes, for as long as the residual lives; tied to the output,
+    # the cut is made before anything reads the output
+    o, lse = lax.optimization_barrier((o, lse))
+    o = checkpoint_name(o, SAVED_NAMES[0])
+    lse = checkpoint_name(lse, SAVED_NAMES[1])
     return o, (q, k, v, bias, seg, o, lse)
 
 

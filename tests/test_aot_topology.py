@@ -66,7 +66,7 @@ def _small_cfg():
     )
 
 
-def _compile(cfg, devices, mesh_axes, batch_shape):
+def _compile(cfg, devices, mesh_axes, batch_shape, packed=False):
     import optax
 
     from lzy_tpu.parallel import MeshSpec, TrainState, make_train_step
@@ -81,8 +81,9 @@ def _compile(cfg, devices, mesh_axes, batch_shape):
         llama.make_loss_fn(cfg, mesh), tx, mesh=mesh,
         param_logical_axes=param_logical_axes(boxed),
         batch_logical_axes=("batch", "seq"))
-    batch = {"tokens": jax.ShapeDtypeStruct(
-        batch_shape, jnp.int32, sharding=batch_sharding)}
+    row = jax.ShapeDtypeStruct(batch_shape, jnp.int32,
+                               sharding=batch_sharding)
+    batch = {"tokens": row, "segments": row} if packed else {"tokens": row}
 
     from tools.aot_analysis import StderrCapture, collective_census
 
@@ -132,6 +133,82 @@ def test_fsdp_module_collectives_are_the_expected_ones(topo):
         f"all-gather traffic {ag_bytes/1e6:.1f} MB exceeds 6x param bytes "
         f"{6*param_bytes/1e6:.1f} MB — unexpected gathers beyond fsdp's "
         f"param fwd+bwd budget")
+
+
+# -- what a rematerialised layer's backward runs again -------------------------
+
+@pytest.mark.parametrize("scope,kind", [
+    ("transpose(jvp(Llama))/jvp(Llama)/checkpoint/rematted_computation/"
+     "layer_0/mlp/gate_proj/dot_general", "dot_general"),
+    ("transpose(jvp(Llama))/jvp(Llama)/checkpoint/rematted_computation/"
+     "layer_3/attn/shard_map/pallas_call", "pallas_call"),
+    ("transpose(jvp(Llama))/jvp(Llama)/checkpoint/rematted_computation/"
+     "layer_3/mlp_norm/rsqrt", "other"),
+    # the backward's own matmul, and the forward's: not run a second time
+    ("transpose(jvp(Llama))/jvp(Llama)/checkpoint/layer_0/mlp/gate_proj/"
+     "dot_general", None),
+    ("jvp(Llama)/layer_0/attn/shard_map/pallas_call", None),
+], ids=["matmul", "kernel", "other", "backward_own", "forward"])
+def test_recompute_census_reads_the_scope_from_op_name(scope, kind):
+    from tools.aot_analysis import recompute_census
+
+    hlo = (f'  %fusion.1 = bf16[2,4096]{{1,0}} fusion(%p), kind=kOutput, '
+           f'metadata={{op_name="jit(step)/{scope}" stack_frame_id=3}}\n'
+           f'  %add.2 = f32[] add(%a, %b)\n')
+    want = {"dot_general": 0, "pallas_call": 0, "other": 0}
+    if kind:
+        want[kind] = 1
+    assert recompute_census(hlo) == want
+
+
+@pytest.fixture(scope="module")
+def remat_steps(topo):
+    """The training cell's kind of step (flash kernels, fused cross-entropy,
+    packed rows, fsdp=4) at two layers and small widths, compiled once under
+    each spelling of ``remat_policy``: ``(recompute census, temp bytes,
+    partitioner's log)`` by policy."""
+    from lzy_tpu.ops import interpret
+    from tools.aot_analysis import recompute_census
+
+    base = llama.LlamaConfig(
+        vocab_size=4096, d_model=512, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=1024, max_seq_len=2048, use_flash_kernel=True, fused_ce=True)
+    assert base.remat and base.remat_policy == "dots"   # the defaults
+    out = {}
+    # the kernels are compiled, not interpreted, as on the chip
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(interpret, "_process_wide", False)
+        for policy in ("dots", "nothing"):
+            cfg = dataclasses.replace(base, remat_policy=policy)
+            compiled, _, stderr = _compile(
+                cfg, list(topo.devices), {"fsdp": 4}, (8, 2048), packed=True)
+            hlo = compiled.as_text()
+            assert "tpu_custom_call" in hlo
+            out[policy] = (recompute_census(hlo),
+                           compiled.memory_analysis().temp_size_in_bytes,
+                           stderr)
+    return out
+
+
+def test_default_policy_recomputes_no_matmul_and_no_kernel(remat_steps):
+    census, _, _ = remat_steps["dots"]
+    assert census["dot_general"] == 0, census
+    assert census["pallas_call"] == 0, census
+    # norms, RoPE, silu(gate) * up are still run again: reads of what is kept
+    assert census["other"] > 0, census
+
+
+def test_nothing_policy_recomputes_both_in_less_memory(remat_steps):
+    census, temp, _ = remat_steps["nothing"]
+    assert census["dot_general"] > 0, census
+    assert census["pallas_call"] > 0, census
+    assert temp < remat_steps["dots"][1]
+
+
+@pytest.mark.parametrize("policy", ["dots", "nothing"])
+def test_remat_steps_partition_without_a_resharding_cliff(policy,
+                                                          remat_steps):
+    assert "Involuntary full rematerialization" not in remat_steps[policy][2]
 
 
 # -- the kernels of the main path at Llama-3-8B widths ------------------------

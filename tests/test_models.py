@@ -569,3 +569,95 @@ class TestPackedDocuments:
             {"tokens": tokens, "segments": segments}, mesh, steps=4,
         )
         assert losses[-1] < losses[0]
+
+
+class TestRematPolicy:
+    """What a rematerialised layer keeps changes the backward's work, never
+    its numbers: the kept values are the ones it would have recomputed."""
+
+    @staticmethod
+    def _loss_and_grads(cfg):
+        params = unbox(llama.init_params(cfg, jax.random.PRNGKey(0))[0])
+        tokens = jnp.asarray(
+            np.random.default_rng(4).integers(0, 128, (2, 128)))
+        segments = jnp.asarray(
+            np.repeat(np.arange(2), 64)[None, :].repeat(2, 0))
+        return jax.jit(jax.value_and_grad(llama.make_loss_fn(cfg)))(
+            params, {"tokens": tokens, "segments": segments})
+
+    @pytest.mark.parametrize("flash", [True, False],
+                             ids=["flash_kernel", "chunked"])
+    @pytest.mark.parametrize("fused_ce", [True, False],
+                             ids=["fused_ce", "logits"])
+    def test_loss_and_every_gradient_equal_under_each_policy(self, flash,
+                                                             fused_ce):
+        base = dataclasses.replace(
+            LlamaConfig.tiny(vocab_size=128), dtype=jnp.float32,
+            param_dtype=jnp.float32, use_flash_kernel=flash,
+            fused_ce=fused_ce, remat=True)
+        assert base.remat_policy == "dots"     # the default keeps results
+        want_loss, want = self._loss_and_grads(base)
+        for other in (dataclasses.replace(base, remat_policy="nothing"),
+                      dataclasses.replace(base, remat=False)):
+            loss, grads = self._loss_and_grads(other)
+            assert float(loss) == float(want_loss)
+            for (path, a), b in zip(
+                    jax.tree_util.tree_leaves_with_path(want),
+                    jax.tree_util.tree_leaves(grads)):
+                np.testing.assert_array_equal(
+                    np.asarray(a), np.asarray(b),
+                    err_msg=jax.tree_util.keystr(path))
+
+    def test_kept_results_survive_the_sharded_attention_wrapper(self):
+        # under a mesh the kernel runs inside shard_map: the names must
+        # still reach the policy, and the numbers must still be the same
+        mesh = fsdp_mesh()
+        base = dataclasses.replace(
+            LlamaConfig.tiny(vocab_size=128), dtype=jnp.float32,
+            param_dtype=jnp.float32, use_flash_kernel=True, fused_ce=True,
+            remat=True)
+        params = unbox(llama.init_params(base, jax.random.PRNGKey(0))[0])
+        tokens = jnp.asarray(
+            np.random.default_rng(5).integers(0, 128, (8, 128)))
+        batch = {"tokens": tokens}
+
+        def run(cfg):
+            return jax.jit(jax.value_and_grad(
+                llama.make_loss_fn(cfg, mesh)))(params, batch)
+
+        want_loss, want = run(base)
+        loss, grads = run(dataclasses.replace(base, remat_policy="nothing"))
+        assert abs(float(loss) - float(want_loss)) < 1e-6
+        for a, b in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(grads)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-6, rtol=1e-5)
+
+    @pytest.mark.parametrize("policy,kept", [("dots", True),
+                                             ("nothing", False)])
+    def test_policy_keeps_matmuls_and_the_kernels_results(self, policy,
+                                                          kept, capsys):
+        """Read off the residuals a checkpointed function's backward takes
+        (``print_saved_residuals`` is the public reader)."""
+        from jax.ad_checkpoint import checkpoint_name, print_saved_residuals
+        from lzy_tpu.ops.flash_attention import SAVED_NAMES
+
+        def layer(x, w):
+            y = x @ w
+            o = checkpoint_name(jnp.sin(y), SAVED_NAMES[0])
+            return jnp.sum(jnp.cos(o))
+
+        f = jax.checkpoint(layer, policy=llama._remat_policy(policy))
+        print_saved_residuals(f, jnp.ones((4, 8)), jnp.ones((8, 8)))
+        # beyond the arguments: the matmul's result and the named value
+        extra = [line for line in capsys.readouterr().out.splitlines()
+                 if line.strip() and "argument" not in line]
+        assert len(extra) == (2 if kept else 0), extra
+
+    def test_unknown_policy_is_refused(self):
+        with pytest.raises(ValueError, match="unknown remat_policy 'all'"):
+            llama._remat_policy("all")
+        cfg = dataclasses.replace(LlamaConfig.tiny(vocab_size=128),
+                                  remat=True, remat_policy="all")
+        with pytest.raises(ValueError, match="known: .*dots.*nothing"):
+            llama.init_params(cfg, jax.random.PRNGKey(0))

@@ -9,6 +9,8 @@ built:
 - per-device FLOPs and HBM bytes from XLA's cost analysis,
 - the collective census of the SPMD module (op counts + bytes moved),
 - compiled memory footprint (does the config fit in 16 GB HBM?),
+- what a rematerialised layer's backward runs a second time
+  (:func:`recompute_census`: matmuls, kernels, the rest),
 - the roofline-implied MFU bound for the flagship config, and
 - the partitioner's stderr (asserting no "Involuntary full
   rematerialization" resharding cliffs — the CPU-dryrun warning assert
@@ -16,8 +18,11 @@ built:
 
 Outputs ``tpu_evidence/AOT_ANALYSIS.json`` + ``.md``. Run:
 
-    python tools/aot_analysis.py            # all targets
+    python tools/aot_analysis.py            # all targets but the by-hand
     python tools/aot_analysis.py bench_1chip  # one target
+    python tools/aot_analysis.py mistral-7b-v0.3-train-l8-fsdp4
+                                            # the benchmark's training cell
+                                            # under each remat spelling
 
 The equivalence argument: XLA-TPU compilation is deterministic given
 (HLO, topology, compiler version); the scheduled module this tool
@@ -137,6 +142,32 @@ def collective_census(hlo_text: str) -> dict:
     return out
 
 
+# an instruction the backward runs a second time carries the scope
+# ``jax.checkpoint`` gives its recomputation in ``op_name`` metadata:
+#   ... metadata={op_name="jit(step)/.../rematted_computation/.../dot_general"
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_REMAT_SCOPE = "rematted_computation"
+
+
+def recompute_census(hlo_text: str) -> dict:
+    """Instructions of the module whose ``op_name`` lies under
+    ``rematted_computation`` (what a rematerialised layer's backward runs
+    again), by the kind of the traced operation they came from: matmuls
+    (``dot_general``), kernels (``pallas_call``), and the rest. A count of
+    lines, fusions' inner instructions included: 0 is the statement, the
+    size of a non-zero count says little."""
+    census = {"dot_general": 0, "pallas_call": 0, "other": 0}
+    for line in hlo_text.splitlines():
+        m = _OP_NAME_RE.search(line)
+        if m is None or _REMAT_SCOPE not in m.group(1):
+            continue
+        inner = m.group(1).split(_REMAT_SCOPE, 1)[1]
+        kind = next((k for k in ("pallas_call", "dot_general")
+                     if k in inner), "other")
+        census[kind] += 1
+    return census
+
+
 class StderrCapture:
     """Tee fd 2 so C++ partitioner warnings are assertable (python warning
     hooks never see absl logging) — same mechanism as __graft_entry__."""
@@ -197,8 +228,10 @@ def _topology(name: str):
 
 
 def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
-            seq_len: int, mesh_axes: dict) -> dict:
-    """AOT-compile the full train step for one config and extract evidence."""
+            seq_len: int, mesh_axes: dict, packed: bool = False) -> dict:
+    """AOT-compile the full train step for one config and extract evidence.
+    ``packed``: the batch carries ``segments`` beside ``tokens`` (packed
+    documents: the flash kernels' segmented variants, per-document RoPE)."""
     import optax
 
     from lzy_tpu.models import count_params, llama, unbox
@@ -222,8 +255,9 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
     step, _, batch_sharding = make_train_step(
         llama.make_loss_fn(cfg, mesh), tx, mesh=mesh,
         param_logical_axes=axes, batch_logical_axes=("batch", "seq"))
-    batch = {"tokens": jax.ShapeDtypeStruct(
-        (global_batch, seq_len), jnp.int32, sharding=batch_sharding)}
+    row = jax.ShapeDtypeStruct(
+        (global_batch, seq_len), jnp.int32, sharding=batch_sharding)
+    batch = {"tokens": row, "segments": row} if packed else {"tokens": row}
 
     print(f"[{tag}] lowering + compiling ({n_chips} chips, "
           f"{n_params/1e6:.0f}M params, batch {global_batch}x{seq_len})...",
@@ -240,6 +274,7 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
     ma = compiled.memory_analysis()
     hlo = compiled.as_text()
     census = collective_census(hlo)
+    recompute = recompute_census(hlo)
 
     # --- roofline ---------------------------------------------------------
     flops_dev = float(ca.get("flops", 0.0))        # per-device (SPMD module)
@@ -302,6 +337,7 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
             "fits_16gb_hbm": bool(hbm_need < V5E["hbm_capacity"]),
         },
         "collectives": census,
+        "recompute": recompute,
         "roofline": {
             "t_mxu_ms": round(1e3 * t_mxu, 3),
             "t_hbm_ms": round(1e3 * t_hbm, 3),
@@ -321,13 +357,50 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
           f"{rec['roofline']['mfu_upper_bound']}, bound by "
           f"{rec['roofline']['bound']}, collectives="
           f"{ {k: v['count'] for k, v in census.items() if not k.startswith('_')} }, "
-          f"remat_warnings={remat_warnings}", flush=True)
+          f"remat_warnings={remat_warnings}; a chip: argument "
+          f"{ma.argument_size_in_bytes / 1e9:.2f} GB + temp "
+          f"{ma.temp_size_in_bytes / 1e9:.2f} GB + code "
+          f"{ma.generated_code_size_in_bytes / 1e9:.2f} GB, "
+          f"{flops_dev / 1e12:.1f} TFLOP, recomputed: {recompute}",
+          flush=True)
     return rec
+
+
+#: the benchmark's training cell (``BENCHMARK.json`` ``train-fsdp4``)
+_CELL = "mistral-7b-v0.3-train-l8-fsdp4"
+
+
+def _cell_targets() -> dict:
+    """The step ``benchmark/harness/train.py`` builds for ``train-fsdp4``,
+    from the benchmark's own files: its configuration through the model
+    file's ``program_config``, its mesh, its packed batch. Three rows: what
+    the cell runs (``LlamaConfig``'s default policy), and the two other
+    ways to spell rematerialisation, so that the step's memory and its
+    recomputation can be read side by side. By hand only (one to one
+    and a half minutes a compile): ``python tools/aot_analysis.py mistral-7b-v0.3-train-l8-fsdp4``
+    runs the three."""
+    from benchmark.models import mistral
+
+    def doc(*path):
+        with open(os.path.join(REPO, "benchmark", *path)) as f:
+            return json.load(f)
+
+    config, traffic = doc("configs", _CELL + ".json"), \
+        doc("traffic", "train-fsdp4.json")
+    rows = {"": {}, ":nothing": {"remat_policy": "nothing"},
+            ":noremat": {"remat": False}}
+    return {
+        _CELL + suffix: dict(
+            cfg=mistral.program_config(config, **over), topo="v5e-4",
+            global_batch=traffic["batch"], seq_len=traffic["seq"],
+            mesh_axes=config["mesh"], packed=True, by_hand=True)
+        for suffix, over in rows.items()}
 
 
 def targets() -> dict:
     """The configs ``tpu_evidence/AOT_ANALYSIS.*`` records: a ~350M-param
-    Llama sized for one v5e chip, and its v5e-16 variants."""
+    Llama sized for one v5e chip, and its v5e-16 variants; and the
+    benchmark's training cell (:func:`_cell_targets`), by hand."""
     import dataclasses
 
     from lzy_tpu.models.llama import LlamaConfig
@@ -393,6 +466,7 @@ def targets() -> dict:
         "v5e16_dp4_fsdp4": dict(
             cfg=dense, topo="v5e-16", global_batch=dense_batch * 16,
             seq_len=seq, mesh_axes={"dp": 4, "fsdp": -1}),
+        **_cell_targets(),
     }
 
 
@@ -409,13 +483,17 @@ def main(argv: list) -> int:
         pass
     results, errors = [], []
     for tag, spec in targets().items():
-        if only and tag not in only:
+        # a name given on the command line also picks its ":" rows; a
+        # by-hand target runs only when named
+        named = only and (tag in only or tag.split(":")[0] in only)
+        if not named and (only or spec.get("by_hand")):
             continue
         try:
             results.append(analyze(
                 tag, spec["cfg"], spec["topo"],
                 global_batch=spec["global_batch"], seq_len=spec["seq_len"],
-                mesh_axes=spec["mesh_axes"]))
+                mesh_axes=spec["mesh_axes"],
+                packed=spec.get("packed", False)))
         except Exception as e:  # noqa: BLE001 — record, keep going
             import traceback
 
@@ -511,6 +589,18 @@ def _write_md(doc: dict, path: str) -> None:
         lines.append(
             f"  - {r['tag']}: {r['partitioner']['involuntary_remat_warnings']}"
             f" warnings, compiled in {r['compile_seconds']}s")
+    lines += [
+        "- Bytes a chip (argument + temp + code) and the instructions under "
+        "`rematted_computation` (what the backward runs a second time: "
+        "matmuls / kernels / the rest):",
+    ]
+    for r in doc["results"]:
+        m, again = r["memory"], r.get("recompute")
+        lines.append(
+            f"  - {r['tag']}: {m['argument_bytes'] / 1e9:.2f} + "
+            f"{m['temp_bytes'] / 1e9:.2f} + {m['code_bytes'] / 1e9:.2f} GB"
+            + (f"; {again['dot_general']} / {again['pallas_call']} / "
+               f"{again['other']}" if again else ""))
     if doc["errors"]:
         lines += ["", "## Errors", ""]
         for e in doc["errors"]:
