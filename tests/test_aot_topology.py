@@ -166,9 +166,11 @@ def remat_steps(topo):
     """The training cell's kind of step (flash kernels, fused cross-entropy,
     packed rows, fsdp=4) at two layers and small widths, compiled once under
     each spelling of ``remat_policy``: ``(recompute census, temp bytes,
-    partitioner's log)`` by policy."""
+    partitioner's log, scheduled collectives)`` by policy. Since PR 50 these
+    compiles carry ``parallel.train.TPU_SHARDED_STEP_OPTIONS``: the mesh's
+    devices are TPUs and fsdp=4 shards the parameters."""
     from lzy_tpu.ops import interpret
-    from tools.aot_analysis import recompute_census
+    from tools.aot_analysis import collectives_scheduled, recompute_census
 
     base = llama.LlamaConfig(
         vocab_size=4096, d_model=512, n_layers=2, n_heads=4, n_kv_heads=2,
@@ -186,12 +188,12 @@ def remat_steps(topo):
             assert "tpu_custom_call" in hlo
             out[policy] = (recompute_census(hlo),
                            compiled.memory_analysis().temp_size_in_bytes,
-                           stderr)
+                           stderr, collectives_scheduled(hlo))
     return out
 
 
 def test_default_policy_recomputes_no_matmul_and_no_kernel(remat_steps):
-    census, _, _ = remat_steps["dots"]
+    census = remat_steps["dots"][0]
     assert census["dot_general"] == 0, census
     assert census["pallas_call"] == 0, census
     # norms, RoPE, silu(gate) * up are still run again: reads of what is kept
@@ -199,7 +201,7 @@ def test_default_policy_recomputes_no_matmul_and_no_kernel(remat_steps):
 
 
 def test_nothing_policy_recomputes_both_in_less_memory(remat_steps):
-    census, temp, _ = remat_steps["nothing"]
+    census, temp = remat_steps["nothing"][:2]
     assert census["dot_general"] > 0, census
     assert census["pallas_call"] > 0, census
     assert temp < remat_steps["dots"][1]
@@ -209,6 +211,108 @@ def test_nothing_policy_recomputes_both_in_less_memory(remat_steps):
 def test_remat_steps_partition_without_a_resharding_cliff(policy,
                                                           remat_steps):
     assert "Involuntary full rematerialization" not in remat_steps[policy][2]
+
+
+# -- the sharded step's compiler options, and what they schedule --------------
+
+_START = ('  %async-collective-start.3 = (bf16[256,8]{1,0}, bf16[1024,8]{1,0}) '
+          'fusion(%p.1), kind=kCustom, calls=%fused_computation.7\n')
+_CANNED = (
+    'HloModule jit_step, is_scheduled=true\n\n'
+    '%fused_computation.7 (param_0.1: bf16[256,8]) -> (bf16[256,8], '
+    'bf16[1024,8]) {\n'
+    '  %param_0.1 = bf16[256,8]{1,0} parameter(0)\n'
+    '  %all-gather.5 = bf16[1024,8]{1,0} all-gather(%param_0.1), '
+    'channel_id=71, replica_groups=[1,4]<=[4], dimensions={0}\n'
+    '  ROOT %custom-call.1 = (bf16[256,8]{1,0}, bf16[1024,8]{1,0}) '
+    'custom-call(%all-gather.5), custom_call_target="AsyncCollectiveStart"\n'
+    '}\n\n'
+    '%async_collective_fusion.9 (param_0.2: bf16[256,8]) -> bf16[1024,8] {\n'
+    '  %param_0.2 = bf16[256,8]{1,0} parameter(0)\n'
+    '  ROOT %all-gather.6 = bf16[1024,8]{1,0} all-gather(%param_0.2), '
+    'channel_id=71, replica_groups=[1,4]<=[4], dimensions={0}\n'
+    '}\n\n'
+    '%all-reduce-scatter.2 (input.2: bf16[1024,8]) -> bf16[256,8] {\n'
+    '  %input.2 = bf16[1024,8]{1,0} parameter(0)\n'
+    '  %all-reduce.4 = bf16[1024,8]{1,0} all-reduce(%input.2), '
+    'channel_id=80, replica_groups={{0,1,2,3}}, to_apply=%add.1\n'
+    '  ROOT %dynamic-slice.1 = bf16[256,8]{1,0} dynamic-slice(%all-reduce.4, '
+    '%c.0, %c.1), dynamic_slice_sizes={256,8}\n'
+    '}\n\n'
+    'ENTRY %main.1_spmd (p.1: bf16[256,8]) -> bf16[256,8] {\n'
+    '  %p.1 = bf16[256,8]{1,0} parameter(0)\n'
+    '{start}'
+    '  %fusion.9 = bf16[1024,8]{1,0} fusion(%p.1), kind=kOutput, '
+    'calls=%async_collective_fusion.9\n'
+    '  %async-collective-done.3 = bf16[1024,8]{1,0} fusion(%p.1), '
+    'kind=kCustom, calls=%fused_computation.7\n'
+    '  %all-reduce.9 = f32[]{:T(128)} all-reduce(%s.1), channel_id=90, '
+    'replica_groups={{0,1,2,3}}, to_apply=%add.1\n'
+    '  ROOT %fusion.2 = bf16[256,8]{1,0} fusion(%fusion.9), kind=kCustom, '
+    'calls=%all-reduce-scatter.2\n'
+    '}\n')
+
+
+def test_collectives_scheduled_reads_start_pairs_and_fused_reduce_scatters():
+    from tools.aot_analysis import collectives_scheduled
+
+    # the gather's channel is in its start, its done and the compute fusion
+    # that carries its steps: one asynchronous all-gather
+    assert collectives_scheduled(_CANNED.replace("{start}", _START)) == {
+        "all-gather": {"async": 1, "sync": 0},
+        "all-reduce": {"async": 0, "sync": 1},
+        "reduce-scatter": {"async": 0, "sync": 1}}
+    # without a start the same channel is a synchronous one
+    assert collectives_scheduled(_CANNED.replace("{start}", ""))[
+        "all-gather"] == {"async": 0, "sync": 1}
+
+
+def test_collective_census_counts_a_channel_once():
+    from tools.aot_analysis import collective_census
+
+    census = collective_census(_CANNED.replace("{start}", _START))
+    # channel 71 is written in two fusion bodies
+    assert census["all-gather"] == {"count": 1, "bytes": 1024 * 8 * 2}
+    assert census["all-reduce"]["count"] == 2          # channels 80 and 90
+
+
+def test_tpu_mesh_gets_the_options_and_one_chip_none(topo):
+    from lzy_tpu.parallel import MeshSpec, train
+    from lzy_tpu.parallel.sharding import tree_shardings
+
+    def options(devices):
+        mesh = MeshSpec(fsdp=-1).build(devices)
+        layout = tree_shardings(mesh, {"w": ("embed", None)}, None)
+        return train.step_compiler_options(mesh, layout)
+
+    assert options(list(topo.devices)) == train.TPU_SHARDED_STEP_OPTIONS
+    assert options(list(topo.devices)[:1]) is None
+
+
+def test_the_options_reach_the_compile(topo, monkeypatch):
+    """An option the compiler does not know fails the compile of a sharded
+    step on a TPU mesh, and not the one-chip step, which gets none."""
+    from lzy_tpu.parallel import train
+
+    monkeypatch.setattr(train, "TPU_SHARDED_STEP_OPTIONS",
+                        {"xla_no_such_option_of_any_compiler": True})
+    with pytest.raises(Exception, match="No such compile option"):
+        _compile(_small_cfg(), list(topo.devices), {"fsdp": -1}, (8, 256))
+    _compile(_small_cfg(), list(topo.devices)[:1], {"fsdp": -1}, (4, 256))
+
+
+@pytest.mark.parametrize("policy", ["dots", "nothing"])
+def test_sharded_step_schedules_its_gathers_asynchronously(policy,
+                                                           remat_steps):
+    """The parameter all-gathers ride beside the matmuls; the gradients'
+    reduce-scatters are fusions on the core's own timeline, as the options
+    leave them (PERF.md section 6, PR 50: the compiler's asynchronous form
+    cost the matmuls that carry it what it hid)."""
+    scheduled = remat_steps[policy][3]
+    gathers = scheduled["all-gather"]
+    assert gathers["async"] > 4 * gathers["sync"], scheduled
+    assert scheduled["reduce-scatter"]["async"] == 0, scheduled
+    assert "all-to-all" not in scheduled
 
 
 # -- the kernels of the main path at Llama-3-8B widths ------------------------

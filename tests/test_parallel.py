@@ -131,6 +131,65 @@ class TestTrainStep:
         np.testing.assert_allclose(w1_a, w1_b, atol=1e-8)
 
 
+class TestStepCompilerOptions:
+    """The sharded step's compiler options follow the platform and the mesh
+    it is handed (``parallel.train.step_compiler_options``)."""
+
+    @staticmethod
+    def _mesh(platform, **axes):
+        import types
+
+        n = int(np.prod(list(axes.values())))
+        devices = np.array(
+            [types.SimpleNamespace(platform=platform) for _ in range(n)],
+            dtype=object).reshape(tuple(axes.values()))
+        return types.SimpleNamespace(devices=devices, shape=dict(axes))
+
+    @pytest.mark.parametrize("platform,axes,spec,expected", [
+        ("tpu", {"dp": 1, "fsdp": 4}, P(None, "fsdp"), True),
+        # an axis of a tuple entry shards the parameter too
+        ("tpu", {"dp": 1, "fsdp": 4}, P(("dp", "fsdp"), None), True),
+        ("tpu", {"dp": 1, "fsdp": 1}, P(None, "fsdp"), False),
+        # four chips that only split the batch: no gradient reduce-scatter
+        ("tpu", {"dp": 4, "fsdp": 1}, P(None, "fsdp"), False),
+        ("tpu", {"dp": 1, "fsdp": 4}, P(), False),
+        ("cpu", {"dp": 1, "fsdp": 4}, P(None, "fsdp"), False),
+    ], ids=["tpu_fsdp4", "tpu_fsdp4_tuple", "tpu_one_chip", "tpu_dp4",
+            "tpu_replicated", "cpu_fsdp4"])
+    def test_options_follow_platform_and_sharded_axes(
+            self, platform, axes, spec, expected):
+        from lzy_tpu.parallel import train
+
+        layout = {"w": NamedSharding(mesh_for(8, dp=2, fsdp=4), spec),
+                  "b": NamedSharding(mesh_for(8, dp=2, fsdp=4), P())}
+        got = train.step_compiler_options(self._mesh(platform, **axes),
+                                          layout)
+        if expected:
+            assert got == train.TPU_SHARDED_STEP_OPTIONS
+            assert got is not train.TPU_SHARDED_STEP_OPTIONS   # a copy
+        else:
+            assert got is None
+
+    def test_cpu_step_is_jitted_with_no_option(self, monkeypatch):
+        """On the CPU an option of the TPU compiler is a compile error: the
+        step is compiled as it always was, and its first loss is the one the
+        arithmetic gives."""
+        seen = []
+        real_jit = jax.jit
+
+        def spy(fun, **kwargs):
+            seen.append(kwargs.get("compiler_options", "absent"))
+            return real_jit(fun, **kwargs)
+
+        monkeypatch.setattr(jax, "jit", spy)
+        step, state, batch, _ = TestTrainStep()._setup()
+        _, metrics = step(state, batch)
+        assert seen == [None]
+        logit = 32 * 0.01 * np.tanh(16 * 0.01)
+        np.testing.assert_allclose(float(metrics["loss"]), logit ** 2,
+                                   rtol=1e-5)
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_reference_attention(self, causal):

@@ -7,7 +7,9 @@ compiler the real chip uses — and records what the scheduler actually
 built:
 
 - per-device FLOPs and HBM bytes from XLA's cost analysis,
-- the collective census of the SPMD module (op counts + bytes moved),
+- the collective census of the SPMD module (op counts + bytes moved, a
+  collective once by its channel), and which collectives of the scheduled
+  ``ENTRY`` are asynchronous (:func:`collectives_scheduled`),
 - compiled memory footprint (does the config fit in 16 GB HBM?),
 - what a rematerialised layer's backward runs a second time
   (:func:`recompute_census`: matmuls, kernels, the rest),
@@ -102,29 +104,44 @@ def _shape_bytes(dtype: str, dims: str) -> int:
     return n * _DTYPE_BYTES.get(dtype, 4)
 
 
+_CHANNEL_RE = re.compile(r"channel_id=(\d+)")
+
+
 def collective_census(hlo_text: str) -> dict:
-    """Count SPMD collectives and the bytes each moves (output shape)."""
+    """Count SPMD collectives and the bytes each moves (output shape). A
+    collective is counted once by its ``channel_id``: the TPU compiler
+    writes an asynchronous one into the body of its start, of its done and
+    of every compute fusion that carries its steps, under one channel."""
     census = {op: {"count": 0, "bytes": 0} for op in _COLLECTIVES}
     largest = []
-    for m in _DEF_RE.finditer(hlo_text):
-        dtype, dims, op = m.groups()
+    seen = set()
+    for line in hlo_text.splitlines():
+        m = _DEF_RE.search(line)
+        if m is not None:
+            dtype, dims, op = m.groups()
+            desc = op
+        else:
+            m = _ASYNC_RE.search(line)
+            if m is None:
+                continue
+            tuple_body, op = m.groups()
+            shapes = _SHAPE_RE.findall(tuple_body)
+            if not shapes:
+                continue
+            # the tuple mixes (operand, result, sync-flag scalars...); the
+            # moved payload is the largest element (= result: >= operand
+            # for all-gather, == operand for a permute)
+            dtype, dims = max(shapes, key=lambda s: _shape_bytes(*s))
+            desc = op + "-async"
+        channel = _CHANNEL_RE.search(line, m.end())
+        if channel is not None:
+            if channel.group(1) in seen:
+                continue
+            seen.add(channel.group(1))
         nbytes = _shape_bytes(dtype, dims)
         census[op]["count"] += 1
         census[op]["bytes"] += nbytes
-        largest.append((nbytes, f"{op} {dtype}[{dims}]"))
-    for m in _ASYNC_RE.finditer(hlo_text):
-        tuple_body, op = m.groups()
-        shapes = _SHAPE_RE.findall(tuple_body)
-        if not shapes:
-            continue
-        # the tuple mixes (operand, result, sync-flag scalars...); the
-        # moved payload is the largest element (= result: >= operand for
-        # all-gather, == operand for a permute)
-        dtype, dims = max(shapes, key=lambda s: _shape_bytes(*s))
-        nbytes = _shape_bytes(dtype, dims)
-        census[op]["count"] += 1
-        census[op]["bytes"] += nbytes
-        largest.append((nbytes, f"{op}-async {dtype}[{dims}]"))
+        largest.append((nbytes, f"{desc} {dtype}[{dims}]"))
     out = {op: v for op, v in census.items() if v["count"]}
     if largest:
         largest.sort(reverse=True)
@@ -139,6 +156,93 @@ def collective_census(hlo_text: str) -> dict:
             {"shape": desc, "count": n, "bytes": total}
             for desc, (n, total) in top
         ]
+    return out
+
+
+# a computation's header, ``%name (params) -> result {`` at column 0, and an
+# instruction of its body, ``  [ROOT] %name = <shape> <opcode>(``
+_COMPUTATION_RE = re.compile(r"^(ENTRY\s+)?%([\w.\-]+)\s+\(.*\{\s*$")
+_INSTRUCTION_RE = re.compile(
+    r"^\s+(?:ROOT\s+)?%([\w.\-]+)\s+=\s+.*?\s([a-z][a-z\-]*)\(")
+_CALLS_RE = re.compile(r"calls=%([\w.\-]+)")
+
+
+def collectives_scheduled(hlo_text: str) -> dict:
+    """The collectives of the scheduled ``ENTRY`` by kind, asynchronous and
+    synchronous: ``{kind: {"async": n, "sync": n}}``, each counted once by
+    its ``channel_id`` (loops' bodies are not read: the head's
+    reduce-scatter lives in the cross-entropy loop).
+
+    How the TPU compiler writes them. A synchronous one is the collective
+    itself, or a ``fusion`` whose called computation holds it: a gradient's
+    reduce-scatter is ``fusion(...), calls=%all-reduce-scatter.N``, an
+    ``all-reduce`` with the ``dynamic-slice`` fused on. An asynchronous one
+    is a pair of fusions named ``%async-collective-start.N`` / ``-done.N``
+    (or a plain ``<kind>-start`` / ``-done``) with the same channel in their
+    bodies; its steps may ride in compute fusions between the two
+    (``calls=%async_collective_fusion.N``), whose bodies hold the channel
+    again. So: a channel is asynchronous if one of the ``ENTRY``
+    instructions that reach it is a start."""
+    bodies, entry, current = {}, None, None
+    for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            m = _COMPUTATION_RE.match(line)
+            current = m.group(2) if m else None
+            if m:
+                bodies[current] = []
+                if m.group(1):
+                    entry = current
+        elif current is not None:
+            bodies[current].append(line)
+
+    def collective_of(line):
+        m = _INSTRUCTION_RE.match(line)
+        if m is None:
+            return None
+        name, opcode = m.groups()
+        started = opcode.endswith("-start")
+        kind = opcode[:-len("-start")] if started else opcode
+        if kind not in _COLLECTIVES:
+            return None
+        channel = _CHANNEL_RE.search(line)
+        # one without a channel is its own
+        return (channel.group(1) if channel else name), kind, started
+
+    reached: dict = {}
+
+    def under(computation):
+        if computation not in reached:
+            reached[computation] = found = []
+            for line in bodies.get(computation, ()):
+                hit = collective_of(line)
+                if hit is not None:
+                    found.append(hit)
+                for callee in _CALLS_RE.findall(line):
+                    found.extend(under(callee))
+        return reached[computation]
+
+    channels: dict = {}            # channel -> [kind, asynchronous]
+    for line in bodies.get(entry, ()):
+        m = _INSTRUCTION_RE.match(line)
+        if m is None:
+            continue
+        name = m.group(1)
+        hit = collective_of(line)
+        found = [hit] if hit is not None else []
+        callees = _CALLS_RE.findall(line)
+        for callee in callees:
+            found.extend(under(callee))
+        for channel, kind, started in found:
+            if kind == "all-reduce" and any(
+                    c.startswith("all-reduce-scatter") for c in callees):
+                kind = "reduce-scatter"
+            row = channels.setdefault(channel, [kind, False])
+            if started or name.startswith("async-collective-start"):
+                row[1] = True
+    out: dict = {}
+    for kind, asynchronous in channels.values():
+        row = out.setdefault(kind, {"async": 0, "sync": 0})
+        row["async" if asynchronous else "sync"] += 1
     return out
 
 
@@ -274,6 +378,7 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
     ma = compiled.memory_analysis()
     hlo = compiled.as_text()
     census = collective_census(hlo)
+    scheduled = collectives_scheduled(hlo)
     recompute = recompute_census(hlo)
 
     # --- roofline ---------------------------------------------------------
@@ -337,6 +442,7 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
             "fits_16gb_hbm": bool(hbm_need < V5E["hbm_capacity"]),
         },
         "collectives": census,
+        "collectives_scheduled": scheduled,
         "recompute": recompute,
         "roofline": {
             "t_mxu_ms": round(1e3 * t_mxu, 3),
@@ -361,7 +467,9 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
           f"{ma.argument_size_in_bytes / 1e9:.2f} GB + temp "
           f"{ma.temp_size_in_bytes / 1e9:.2f} GB + code "
           f"{ma.generated_code_size_in_bytes / 1e9:.2f} GB, "
-          f"{flops_dev / 1e12:.1f} TFLOP, recomputed: {recompute}",
+          f"{flops_dev / 1e12:.1f} TFLOP, recomputed: {recompute}; "
+          f"scheduled (async / sync): "
+          f"{ {k: (v['async'], v['sync']) for k, v in scheduled.items()} }",
           flush=True)
     return rec
 
@@ -592,15 +700,20 @@ def _write_md(doc: dict, path: str) -> None:
     lines += [
         "- Bytes a chip (argument + temp + code) and the instructions under "
         "`rematted_computation` (what the backward runs a second time: "
-        "matmuls / kernels / the rest):",
+        "matmuls / kernels / the rest); the scheduled `ENTRY`'s collectives "
+        "by kind, asynchronous / synchronous:",
     ]
     for r in doc["results"]:
         m, again = r["memory"], r.get("recompute")
+        scheduled = r.get("collectives_scheduled")
         lines.append(
             f"  - {r['tag']}: {m['argument_bytes'] / 1e9:.2f} + "
             f"{m['temp_bytes'] / 1e9:.2f} + {m['code_bytes'] / 1e9:.2f} GB"
             + (f"; {again['dot_general']} / {again['pallas_call']} / "
-               f"{again['other']}" if again else ""))
+               f"{again['other']}" if again else "")
+            + ("; " + ", ".join(
+                f"{kind} {n['async']} / {n['sync']}"
+                for kind, n in scheduled.items()) if scheduled else ""))
     if doc["errors"]:
         lines += ["", "## Errors", ""]
         for e in doc["errors"]:
