@@ -6,8 +6,9 @@ the configuration object and by the module class it builds;
 ``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2``,
 ``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3``,
 ``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe``,
-``models/jamba.py``'s ``JambaConfig`` / ``Jamba`` and ``models/zaya.py``'s
-``ZayaConfig`` / ``Zaya`` all do: seven families.
+``models/jamba.py``'s ``JambaConfig`` / ``Jamba``, ``models/zaya.py``'s
+``ZayaConfig`` / ``Zaya`` and ``models/minicpm_sala.py``'s
+``MiniCPMSalaConfig`` / ``MiniCPMSala`` all do: eight families.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -63,7 +64,11 @@ leaf it does not name is ``paged``) and ``STATS``: the counters its
 (summed over layers by the engine and carried out of a decode round with
 its tokens; every ``stats`` leaf is one such vector, a layer filling its own
 places); empty for a model that sows none. A model with state leaves or with
-counts is told which positions are real (``valid_len``).
+counts is told which positions are real (``valid_len``). A class that
+declares ``TOLD_PROMPT_LEN`` is also handed ``prompt_len`` ``[1]`` in every
+prefill program, the length of the prompt the request was admitted with
+(``models/minicpm_sala.py`` fixes a request's mode by it and keeps the
+answer in a ``state`` leaf, which is all a decode round is told).
 
 **Cache leaves have kinds**:
 
@@ -71,7 +76,9 @@ counts is told which positions are real (``valid_len``).
   places it at every index leaf.
 - ``paged``: a pool of pages shared by all slots, ``[pages, page, ...]``,
   addressed through a page table; what follows the page axis is the
-  model's (``[KV, D]`` keys or values, a latent vector). A batch-1 prefill
+  model's (``[KV, D]`` keys or values, a latent vector, ``[KV, page, D]``
+  with the page axis second and ``[page / 16, KV, D]`` compressed keys
+  beside them under the same table: ``models/minicpm_sala.py``). A batch-1 prefill
   writes the same pool; a prefix can be shared, exported, demoted, and a
   speculated position rewound by moving an index: every mechanism moves
   pages by block id and reads no shape past the page axis, so a latent

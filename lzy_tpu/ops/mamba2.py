@@ -46,6 +46,10 @@ from lzy_tpu.ops import interpret as _interpret
 #: ``lzy_kernel_dispatch_total{path}`` labels of the two programs
 SCAN_PATH = "ssm_scan_lax"
 UPDATE_PATH = "ssm_update_pallas"
+#: the update kernel's name in a device trace where every head brings its own
+#: ``B`` and ``C`` (a grid cell's layout differs from the grouped program's,
+#: ``ssm_state_update``, and a metric anchors on one of the two)
+PER_HEAD_UPDATE = "lightning_state_update"
 
 _HI = lax.Precision.HIGHEST
 
@@ -119,10 +123,15 @@ def _pallas_update(state, x, da, dtb, c, live, *, interpret: bool):
     live rows first (their ids arrive by scalar prefetch) and then stands
     still on the last one's last block, so an idle slot's state is neither
     read nor written: it stays where it is, bit for bit (the state is
-    updated in place)."""
+    updated in place). ``c`` ``[B, G, 1, N]``: a ``C`` a group, or a head
+    (``G == H``: a recurrence whose every head has its own ``B`` and ``C``,
+    linear attention with a decay: a device trace calls that program
+    ``PER_HEAD_UPDATE``, the grouped one ``ssm_state_update``)."""
     bsz, h, p, n = state.shape
-    g = c.shape[1]
-    hb = h // g        # a grid cell: one group's heads, one ``B``, one ``C``
+    # a grid cell: one group's heads, one ``B``, one ``C``; or eight heads
+    # with a ``C`` each
+    g, hc = (c.shape[1], 1) if c.shape[1] != h else (h // 8, 8)
+    hb = h // g
     # with no live row at all the grid would write back a block it never
     # filled: walk row 0 then, whose dt of 0 leaves its state as it is
     live = live.at[0].set(live[0] | ~jnp.any(live))
@@ -146,7 +155,7 @@ def _pallas_update(state, x, da, dtb, c, live, *, interpret: bool):
             num_scalar_prefetch=2,
             grid=(bsz, g),
             in_specs=[spec((1, hb, p, n)), y_spec, spec((1, hb, 1, n)),
-                      spec((1, hb, 1, n)), spec((1, 1, 1, n))],
+                      spec((1, hb, 1, n)), spec((1, hc, 1, n))],
             out_specs=[spec((1, hb, p, n)), y_spec]),
         out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
                    jax.ShapeDtypeStruct((bsz, h, p), jnp.float32)],
@@ -155,7 +164,7 @@ def _pallas_update(state, x, da, dtb, c, live, *, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="ssm_state_update",
+        name=PER_HEAD_UPDATE if hc > 1 else "ssm_state_update",
     )(rows, count, state, x, da, dtb, c)
     # an idle row's y was never written: whatever the buffer held
     return new, jnp.where(live[:, None, None], y, 0.0)
@@ -166,14 +175,18 @@ def ssm_state_update(state: jax.Array, x: jax.Array, dt: jax.Array,
                      interpret: Optional[bool] = None):
     """One decode position: ``state`` [B, H, P, N] float32 (donated and
     updated in place), ``x`` [B, H, P], ``dt`` [B, H], ``a`` [H], ``b`` /
-    ``c`` [B, G, N]. Returns ``(y [B, H, P] float32, new state)``. The
-    per-head scalars arrive spread over the state's lanes (``exp(dt A)`` and
-    ``dt B`` as ``[B, H, 1, N]``: 1/64 of the state's bytes), so the kernel
-    is one multiply-add over each state tile and one reduction."""
+    ``c`` [B, G, N] (``G == H``: a ``B`` and a ``C`` a head). Returns ``(y
+    [B, H, P] float32, new state)``. The per-head scalars arrive spread over
+    the state's lanes (``exp(dt A)`` and ``dt B`` as ``[B, H, 1, N]``: 1/64
+    of the state's bytes), so the kernel is one multiply-add over each state
+    tile and one reduction."""
     bsz, h, p, n = state.shape
     g = b.shape[1]
-    if h % g or (h // g) % 8:
-        raise ValueError(f"heads a group ({h}/{g}) must be a multiple of 8")
+    per_head = g == h and h % 8 == 0
+    if h % g or ((h // g) % 8 and not per_head):
+        raise ValueError(
+            f"heads a group ({h}/{g}) must be a multiple of 8, or every "
+            f"head its own group and the heads a multiple of 8")
     dt = dt.astype(jnp.float32)
     da = jnp.exp(dt * a.astype(jnp.float32))           # [B, H]
     da = jnp.broadcast_to(da[:, :, None, None], (bsz, h, 1, n))

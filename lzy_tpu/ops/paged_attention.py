@@ -425,14 +425,17 @@ def cell_group(b: int, m_rows: int, limit: int) -> int:
                if b % g == 0 and (g == 1 or g * m_rows <= limit))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("dtype", "interpret", "block_rows"))
+@functools.partial(jax.jit, static_argnames=(
+    "dtype", "interpret", "block_rows", "name"))
 def _pallas_paged_attention(q, k_pool, v_pool, page_table, positions, *,
                             dtype, interpret: bool,
-                            block_rows: int = _BLOCK_ROWS):
+                            block_rows: int = _BLOCK_ROWS,
+                            name: str = "paged_decode_attention"):
     """jitted so that a model's layers, which all make this call at one
     shape, trace and lower the kernel once a program, not once a layer
-    (a second of Python each: set-up time on every start of a replica)."""
+    (a second of Python each: set-up time on every start of a replica).
+    ``name``: what a device trace calls the kernel (a read of chosen pages,
+    ``ops/sparse_attention.py``, runs this body under a name of its own)."""
     b, t, h, d = q.shape
     n, page, kv_heads, _ = k_pool.shape
     pages = page_table.shape[1]
@@ -466,7 +469,7 @@ def _pallas_paged_attention(q, k_pool, v_pool, page_table, positions, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret.tpu_params(interpret),
-        name="paged_decode_attention",
+        name=name,
     )(positions.reshape(-1),
       page_table.astype(jnp.int32).reshape(-1),
       q.astype(k_pool.dtype).reshape(b, t * h, d),

@@ -247,13 +247,15 @@ _STATE_RESETS = REGISTRY.counter(
     "(models with state cache leaves)")
 
 
-# what a prefill program is told about its chunk, one row of four int32 a
+# what a prefill program is told about its chunk, one row of five int32 a
 # chunk of the job's plan, written into the job's buffer when it is staged:
 # where the chunk starts (the position of its first token in the prompt,
 # which is also the cache index it writes from), how many of its positions
-# are real, the row's sampling mode, and whether the job's state rows start
-# from zero
-_CTL_START, _CTL_TAKE, _CTL_GREEDY, _CTL_FRESH, _CTL_LEN = range(5)
+# are real, the row's sampling mode, whether the job's state rows start
+# from zero, and the length of the prompt the request was admitted with
+# (handed to a model that asks for it: ``TOLD_PROMPT_LEN``)
+_CTL_START, _CTL_TAKE, _CTL_GREEDY, _CTL_FRESH, _CTL_PROMPT, _CTL_LEN = \
+    range(6)
 
 
 @dataclasses.dataclass
@@ -773,6 +775,10 @@ class PagedInferenceEngine:
         # a model with state leaves, or one that counts (``STATS``), is
         # told which positions of a program are real (``valid_len``)
         self._tells_real = self._has_state or bool(type(self._model).STATS)
+        # a model whose read of a request follows the length it was admitted
+        # with is told it in every prefill program (``prompt_len``)
+        self._tells_prompt_len = getattr(
+            type(self._model), "TOLD_PROMPT_LEN", False)
         # the batch-1 state rows finished (or abandoned) prefill jobs no
         # longer need: the next job's first program starts from them
         self._spare_state: List[list] = []
@@ -2376,6 +2382,8 @@ class PagedInferenceEngine:
                            chunk, 0)
         real = {"valid_len": jnp.reshape(take, (1,))} \
             if self._tells_real else {}
+        if self._tells_prompt_len:
+            real["prompt_len"] = jnp.reshape(ctl[_CTL_PROMPT], (1,))
         logits, updated = self._prefill_model.apply(
             {"params": params, "cache": cache}, tokens,
             page_table=page_table, mutable=["cache"], **real, **apply_kw)
@@ -2537,7 +2545,8 @@ class PagedInferenceEngine:
         buf = np.zeros((1, length), np.int32)
         greedy = self._row_greedy(job.req)
         rows = [(job.matched + start, take, greedy,
-                 n == 0 and self._has_state)       # in the order of _CTL_*
+                 n == 0 and self._has_state,
+                 len(prompt))                      # in the order of _CTL_*
                 for n, (start, take, _) in enumerate(job.plan)]
         buf[0, plan_at:plan_at + _CTL_LEN * len(rows)] = \
             np.asarray(rows, np.int32).ravel()

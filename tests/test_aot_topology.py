@@ -22,6 +22,7 @@ while a module is imported (``/opt/skills/guides/on-chip-measurement``).
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -860,3 +861,76 @@ def test_zaya_kernels_compile_at_published_widths(case, one_chip,
                    if case == "decode" else ("paged_chunk_attention",)) \
             + ("grouped_experts",):
         assert kernel in text
+
+
+# -- MiniCPM-SALA's serving kernels at the cell's shapes ----------------------
+
+@pytest.mark.parametrize("case", ["decode", "prefill"])
+def test_minicpm_sala_kernels_compile_at_the_cells_shapes(case, one_chip,
+                                                          monkeypatch,
+                                                          capsys):
+    """MiniCPM-SALA at its published widths, a sparse layer and a lightning
+    layer, as ``sparse-steady`` runs them: 16 slots, pages of 64, a page
+    table 528 wide (33,792 positions), a pool of 3,855 pages. The decode
+    round (``sparse_select_decode``, the read of the chosen pages under the
+    name ``sparse_decode_attention``, ``lightning_state_update`` at a state
+    of 32 x 128 x 128 a slot) and the prefill chunk of 256
+    (``sparse_select_prefill``, ``sparse_prefill_attention``, the shared
+    scan). The compiled step's memory is printed."""
+    from lzy_tpu.models import minicpm_sala as sala
+    from lzy_tpu.ops import interpret
+
+    # the kernels as the chip compiles them, whatever conftest.py asked for
+    monkeypatch.setattr(interpret, "_process_wide", False)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = sala.MiniCPMSalaConfig(
+        mixer_types=(sala.SPARSE, sala.LIGHTNING), max_seq_len=33792)
+    page, batch, t = 64, *((16, 1) if case == "decode" else (1, 256))
+    model = cfg.paged_model(page_size=page, kv_pages=3855, kernel="pallas",
+                            kv_quant=None)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: sds(s.shape, s.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: sala.init_params(cfg, jax.random.PRNGKey(0))))
+    pages = cfg.max_seq_len // page
+    assert pages == 528
+    cache = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((batch, 1), jnp.int32),
+        page_table=jnp.zeros((batch, pages), jnp.int32)))["cache"])
+
+    def step(params, cache, toks, table, valid, told):
+        logits, upd = model.apply(
+            {"params": params, "cache": cache}, toks, page_table=table,
+            valid_len=valid, mutable=["cache", "stats"],
+            **({} if t == 1 else {"prompt_len": told}))
+        return jnp.argmax(logits[:, -1], -1), upd["cache"], \
+            upd.get("stats", {})
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((batch, t), jnp.int32),
+        sds((batch, pages), jnp.int32), sds((batch,), jnp.int32),
+        sds((batch,), jnp.int32)).compile()
+    text = compiled.as_text()
+    for kernel in (("sparse_select_decode", "sparse_decode_attention",
+                    "lightning_state_update") if case == "decode"
+                   else ("sparse_select_prefill",
+                         "sparse_prefill_attention")):
+        assert kernel in text
+    assert "paged_decode_attention" not in text
+    memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nminicpm_sala {case} step at the cell's shapes: arguments "
+              f"{memory.argument_size_in_bytes:,} bytes, temporaries "
+              f"{memory.temp_size_in_bytes:,} bytes")
+    # the pool's leaves are donated and updated in place: no second copy,
+    # and no scatter that makes the compiler copy a leaf into a layout of
+    # its own and back (126 MB a leaf a layer a program: PERF.md section 6)
+    pool_bytes = 3855 * 2 * 64 * 128 * 2
+    assert memory.temp_size_in_bytes < 4 * pool_bytes
+    assert not re.search(r"= bf16\[3855,2,64,128\]\S* copy\(", text)

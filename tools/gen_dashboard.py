@@ -57,6 +57,9 @@ def registry_metrics():
     import lzy_tpu.models.jamba  # noqa: F401
     # a model with a carried window: live rows whose window a round moved
     import lzy_tpu.models.zaya  # noqa: F401
+    # a model that chooses its key blocks: blocks visible and read, rows
+    # that chose and rows served densely, rows whose lightning state moved
+    import lzy_tpu.models.minicpm_sala  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
