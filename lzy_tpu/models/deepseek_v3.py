@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from lzy_tpu.models import experts
 from lzy_tpu.models.experts import GatedExperts, row_mask
 from lzy_tpu.models.llama import RMSNorm, _rope
-from lzy_tpu.models.paged_blocks import dense, normal
+from lzy_tpu.models.paged_blocks import dense, into_heads, normal
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import mla
 from lzy_tpu.ops.paged_attention import paged_scatter_index
@@ -300,7 +300,8 @@ class LatentAttention(nn.Module):
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
         w = cfg.latent_width
-        q = dense(h * (dn + dr), "q_proj", cfg)(u).reshape(b, t, h, dn + dr)
+        q = into_heads(dense(h * (dn + dr), "q_proj", cfg)(u),
+                       b, t, h, dn + dr)
         kva = dense(r + dr, "kv_a_proj", cfg)(u)
         c = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="kv_a_norm")(
             kva[..., :r])

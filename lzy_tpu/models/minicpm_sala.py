@@ -60,7 +60,7 @@ import jax.numpy as jnp
 
 from lzy_tpu.models.experts import row_mask
 from lzy_tpu.models.llama import RMSNorm, _rope
-from lzy_tpu.models.paged_blocks import dense, normal
+from lzy_tpu.models.paged_blocks import dense, into_heads, normal
 from lzy_tpu.ops import mamba2
 from lzy_tpu.ops import sparse_attention as sparse
 from lzy_tpu.ops.sparse_attention import SparseSpec
@@ -358,9 +358,11 @@ class SparseAttention(nn.Module):
         b, t, _ = u.shape
         h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         # float32 out of the accumulator: the norms a head read it
-        q = dense(h * d, "q_proj", cfg, jnp.float32)(u).reshape(b, t, h, d)
-        k = dense(kv * d, "k_proj", cfg, jnp.float32)(u).reshape(b, t, kv, d)
-        v = dense(kv * d, "v_proj", cfg)(u).reshape(b, t, kv, d)
+        q = into_heads(dense(h * d, "q_proj", cfg, jnp.float32)(u),
+                       b, t, h, d)
+        k = into_heads(dense(kv * d, "k_proj", cfg, jnp.float32)(u),
+                       b, t, kv, d)
+        v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
         # the selector reads the query as the norm leaves it: rounded to the
         # products' type it flips near-ties between block scores
         q32 = _head_norm(cfg, "q_norm")(q)
@@ -448,9 +450,9 @@ class LightningAttention(nn.Module):
         b, t, _ = u.shape
         h, d = cfg.lightning_heads, cfg.lightning_head_dim
         f32 = jnp.float32
-        q = dense(h * d, "q_proj", cfg, f32)(u).reshape(b, t, h, d)
-        k = dense(h * d, "k_proj", cfg, f32)(u).reshape(b, t, h, d)
-        v = dense(h * d, "v_proj", cfg)(u).reshape(b, t, h, d)
+        q = into_heads(dense(h * d, "q_proj", cfg, f32)(u), b, t, h, d)
+        k = into_heads(dense(h * d, "k_proj", cfg, f32)(u), b, t, h, d)
+        v = into_heads(dense(h * d, "v_proj", cfg)(u), b, t, h, d)
         q = _head_norm(cfg, "q_norm")(q)
         k = _head_norm(cfg, "k_norm")(k)
         cached = cfg.decode_paged

@@ -61,6 +61,29 @@ def dense(features, name, cfg, out_dtype=None):
     return Linear(features, cfg.dtype, cfg.param_dtype, out_dtype, name=name)
 
 
+def into_heads(y, *shape):
+    """A projection's result ``y`` ``[.., out]`` split into heads:
+    ``y.reshape(shape)`` behind an ``optimization_barrier``. The head norms,
+    the rotary embedding and the attention kernels want the result a head a
+    row, and without the barrier the compiler gets it there by carrying that
+    layout back through the matmul onto the *weight*: it transposes the
+    whole ``[in, out]`` matrix in every program (``copy`` of
+    ``bf16[4096,4096]``: 32 MB, 41 us on a v5e chip, 46 of them a
+    MiniCPM-SALA decode round, 1.87 ms of 16.72; ``bf16[8192,4096]`` 154 us
+    twice a Solar-Open2 round; ``bf16[2048,3072]`` 10.7 us in each of
+    Moonlight's 27 layers: ledger, PR 51) to spare a relayout of ``[16,
+    4096]`` activations. Behind the barrier the matmul reads the weight as it
+    is stored and the relayout falls on ``y``. The copy was also the weight's
+    one read from HBM (its result lay near the core), so what went with it is
+    the transposition, not the read: a 4096 x 4096 projection of a decode
+    round 49-53 us -> 28-29, a round 16.72 -> 15.80 ms (PERF.md section 6,
+    PR 52).
+    ``models/cohere2_moe.py`` ``HeadMajorLinear`` reached the same program by
+    storing its weight ``[out, in]``; this leaves the parameter tree as it
+    is. The barrier is the identity, with a transpose rule: gradients pass."""
+    return jax.lax.optimization_barrier(y).reshape(shape)
+
+
 def inv_softplus(x):
     return x + jnp.log(-jnp.expm1(-x))
 
@@ -86,9 +109,9 @@ class PagedAttention(nn.Module):
         cfg = self.cfg
         b, t, _ = u.shape
         h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = dense(h * d, "q_proj", cfg)(u).reshape(b, t, h, d)
-        k = dense(kv * d, "k_proj", cfg)(u).reshape(b, t, kv, d)
-        v = dense(kv * d, "v_proj", cfg)(u).reshape(b, t, kv, d)
+        q = into_heads(dense(h * d, "q_proj", cfg)(u), b, t, h, d)
+        k = into_heads(dense(kv * d, "k_proj", cfg)(u), b, t, kv, d)
+        v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
         if not cfg.decode_paged:
             qg = q.reshape(b, t, kv, h // kv, d)
             s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,

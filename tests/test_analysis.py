@@ -39,10 +39,14 @@ def corpus_result():
 def live_result():
     import time as _time
 
-    t0 = _time.perf_counter()
+    # the lint's own CPU seconds (it runs in this thread alone: 2.9 s on an
+    # idle machine), not the wall clock: under six workers and another
+    # file's multi-threaded compiles the same work waited for a core and
+    # read 10.3 and 11.2 s (ROADMAP D17, PR 52)
+    t0 = _time.process_time()
     index = load_tree(LIVE_ROOT)
     result = run_passes(index)
-    elapsed = _time.perf_counter() - t0
+    elapsed = _time.process_time() - t0
     return index, result, elapsed
 
 
@@ -186,7 +190,7 @@ class TestRatchet:
         index, _result, elapsed = live_result
         assert len(index.modules) > 100, "live tree went missing?"
         assert elapsed < 10.0, (
-            f"full-tree lzy-lint took {elapsed:.1f}s — over the 10s "
+            f"full-tree lzy-lint took {elapsed:.1f}s of CPU — over the 10s "
             f"tier-1 budget; profile the passes before this becomes "
             f"the test everyone skips")
 

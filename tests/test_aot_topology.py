@@ -22,6 +22,7 @@ while a module is imported (``/opt/skills/guides/on-chip-measurement``).
 """
 
 import dataclasses
+import importlib
 import re
 
 import jax
@@ -934,3 +935,120 @@ def test_minicpm_sala_kernels_compile_at_the_cells_shapes(case, one_chip,
     pool_bytes = 3855 * 2 * 64 * 128 * 2
     assert memory.temp_size_in_bytes < 4 * pool_bytes
     assert not re.search(r"= bf16\[3855,2,64,128\]\S* copy\(", text)
+    # nor a projection's weight transposed for its heads (four copies of
+    # bf16[4096,4096] a layer pair before ``paged_blocks.into_heads``)
+    assert _parameter_copies(text, params) == []
+
+
+# -- no serving program copies a weight matrix --------------------------------
+
+def _parameter_copies(text, params, least=1_000_000):
+    """The ``copy(`` instructions of a compiled module whose result has the
+    type and the shape of a parameter leaf of ``least`` elements or more, or
+    of a matrix among them transposed (an ``[in, out]`` kernel laid out for
+    the heads shows as ``[out, in]``): a weight the program lays out anew
+    every time it runs. Read from the shapes alone: a chunk's activations of
+    a weight's very shape would be counted too (none of the cells' are)."""
+    names = {"bfloat16": "bf16", "float32": "f32", "float16": "f16"}
+    leaves = set()
+    for leaf in jax.tree_util.tree_leaves(params):
+        if leaf.size >= least:
+            dtype = names.get(jnp.dtype(leaf.dtype).name, leaf.dtype.name)
+            leaves.add((dtype, tuple(leaf.shape)))
+            if leaf.ndim == 2:
+                leaves.add((dtype, tuple(leaf.shape[::-1])))
+    return [f"{dtype}[{dims}]" for dtype, dims in re.findall(
+        r"= (\w+)\[([\d,]+)\]\S* copy\(", text)
+        if (dtype, tuple(int(d) for d in dims.split(","))) in leaves]
+
+
+def test_parameter_copies_reads_a_transposed_kernel():
+    params = {"q_proj": {"kernel": jax.ShapeDtypeStruct(
+        (4096, 8192), jnp.bfloat16)}, "norm": jax.ShapeDtypeStruct(
+        (4096,), jnp.float32), "kv_b_proj": jax.ShapeDtypeStruct(
+        (512, 16, 256), jnp.bfloat16)}
+    text = """
+  %copy.1 = bf16[8192,4096]{1,0:T(8,128)(2,1)} copy(%bitcast.3)
+  %copy.2 = bf16[32,3,24576]{2,1,0} copy(%window)
+  %copy.3 = f32[8192,4096]{1,0} copy(%other)
+  %copy.4 = f32[4096]{0} copy(%scale)
+  %copy.5 = bf16[4096,8192]{0,1} copy(%kernel)
+  ROOT %copy.6 = bf16[256,16,512]{2,0,1} copy(%param_0.1794)
+"""
+    assert _parameter_copies(text, params) == ["bf16[8192,4096]",
+                                               "bf16[4096,8192]"]
+
+
+#: the four configurations that share ``PagedAttention`` or
+#: ``LatentAttention``, as their cells run them: the program's model module,
+#: its own configuration class and what the cell sets of it, the engine's
+#: slots, the page size and the pool's pages (``benchmark/configs/*.json``;
+#: nothing imported from there)
+_SERVING_CELLS = {
+    "solar-open2-serve-l8-ep8": (
+        "solar_open2", "SolarOpen2Config", dict(
+            vocab_size=24576, n_layers=8, attn_layers=(0, 4),
+            experts_held=(0, 40), max_seq_len=4608), 32, 16, 4096),
+    "moonlight-16b-a3b-serve-ep4": (
+        "deepseek_v3", "DeepseekV3Config", dict(
+            vocab_size=40960, experts_held=(0, 16), max_seq_len=8192),
+        32, 16, 7767),
+    "nemotron-3-super-serve-l11-ep4": (
+        "nemotron_h", "NemotronHConfig", dict(
+            vocab_size=32768, experts_held=(0, 128), max_seq_len=4096),
+        64, 16, 16384),
+    "jamba2-3b-serve": (
+        "jamba", "JambaConfig", dict(max_seq_len=65536), 32, 128, 16384),
+}
+
+
+@pytest.mark.parametrize("case", ["decode", "prefill"])
+@pytest.mark.parametrize("name", list(_SERVING_CELLS))
+def test_serving_steps_copy_no_parameter(name, case, one_chip, monkeypatch):
+    """The whole decode round and the whole prefill chunk of 256 of each
+    configuration, every layer, at its cell's slots, page size and pool: the
+    compiled program holds no ``copy`` of a parameter of a million elements
+    or more. Before ``paged_blocks.into_heads`` the compiler transposed
+    ``q_proj`` (and ``k_proj``, ``v_proj``) for the heads in every program:
+    2 x bf16[8192,4096] and 4 x bf16[1024,4096] a Solar-Open2 round, 27 x
+    bf16[2048,3072] a Moonlight round, bf16[4096,4096] and 2 x
+    bf16[256,4096] a Nemotron-3-Super round, 2 x bf16[2560,2560] a Jamba2
+    round (PERF.md section 6, PR 52)."""
+    from lzy_tpu.ops import interpret
+
+    # the kernels as the chip compiles them, whatever conftest.py asked for
+    monkeypatch.setattr(interpret, "_process_wide", False)
+    module, config, sets, slots, page, kv_pages = _SERVING_CELLS[name]
+    module = importlib.import_module(f"lzy_tpu.models.{module}")
+    cfg = getattr(module, config)(**sets)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: sds(s.shape, s.dtype), tree)
+
+    batch, t = (slots, 1) if case == "decode" else (1, 256)
+    model = cfg.paged_model(page_size=page, kv_pages=kv_pages,
+                            kernel="pallas", kv_quant=None)
+    params = on_chip(jax.eval_shape(
+        lambda: module.init_params(cfg, jax.random.PRNGKey(0))))
+    pages = cfg.max_seq_len // page
+    cache = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((batch, 1), jnp.int32),
+        page_table=jnp.zeros((batch, pages), jnp.int32)))["cache"])
+
+    def step(params, cache, toks, table, valid):
+        logits, upd = model.apply(
+            {"params": params, "cache": cache}, toks, page_table=table,
+            valid_len=valid, mutable=["cache", "stats"])
+        return jnp.argmax(logits[:, -1], -1), upd["cache"], \
+            upd.get("stats", {})
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((batch, t), jnp.int32),
+        sds((batch, pages), jnp.int32), sds((batch,), jnp.int32)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _parameter_copies(text, params) == []

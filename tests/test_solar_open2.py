@@ -71,6 +71,41 @@ def test_forward_is_the_reference(tiny):
     assert 0 < int(total[2]) <= cfg.n_held * cfg.n_layers
 
 
+@pytest.mark.parametrize("under", ["jit", "grad"])
+def test_into_heads_is_a_reshape_and_gradients_pass(tiny, under):
+    """``paged_blocks.into_heads`` keeps the compiler from laying a
+    projection's weight out for its heads; to the arithmetic it is a reshape,
+    bit for bit, and ``grad`` of an uncached forward through
+    ``PagedAttention`` (layer 0's here) still reaches the projections."""
+    from lzy_tpu.models.paged_blocks import PagedAttention, into_heads
+
+    y = jax.random.normal(jax.random.PRNGKey(3), (2, 5, 24), jnp.float32)
+    if under == "jit":
+        split = jax.jit(lambda y: into_heads(y, 2, 5, 4, 6))
+        for y in (y, y.astype(jnp.bfloat16)):
+            got = split(y)
+            assert got.shape == (2, 5, 4, 6) and got.dtype == y.dtype
+            assert np.array_equal(
+                np.asarray(got.astype(jnp.float32)),
+                np.asarray(y.astype(jnp.float32)).reshape(2, 5, 4, 6))
+        return
+    w = jax.random.normal(jax.random.PRNGKey(4), (2, 5, 4, 6), jnp.float32)
+    got = jax.grad(lambda y: jnp.sum(into_heads(y, 2, 5, 4, 6) * w))(y)
+    assert np.array_equal(np.asarray(got), np.asarray(w).reshape(2, 5, 24))
+    cfg, params = tiny
+    assert 0 in cfg.attn_layers
+    u = jax.random.normal(jax.random.PRNGKey(5), (1, 12, cfg.d_model),
+                          jnp.float32)
+
+    def loss(layer):
+        return jnp.sum(PagedAttention(cfg).apply({"params": layer}, u) ** 2)
+
+    grads = jax.jit(jax.grad(loss))(params["layer_0"])
+    for name in ("q_proj", "k_proj", "v_proj"):
+        g = np.asarray(grads[name]["kernel"])
+        assert np.isfinite(g).all() and np.abs(g).max() > 0
+
+
 def test_the_references_delta_rule_is_the_written_recurrence():
     """``S_t = (I - beta k k^T) Diag(alpha) S_{t-1} + beta k v^T`` with the
     matrices written out, in numpy float64."""
