@@ -115,32 +115,18 @@ def changed_keys(cfg) -> dict:
 # -- small helpers (children only; they import jax) ---------------------------
 
 
-class CompileMeter:
-    """Counts what JAX compiled in this process: every compile request, the
-    seconds it took (a persistent-cache read included) and how many were
-    cache reads. The first thing the compile cache has to pay back."""
+def compile_doc() -> dict:
+    """What JAX compiled in this process, as the program's own build meter
+    counted it (``lzy_tpu/utils/jaxenv.py``, on since
+    ``enable_compile_cache``): every compile request, the seconds it took (a
+    persistent-cache read included) and how many were cache reads. The
+    first thing the compile cache has to pay back."""
+    from lzy_tpu.utils.jaxenv import build_totals
 
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, seconds, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += seconds
-            self.compiles += 1
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def doc(self) -> dict:
-        return {"compile_seconds": round(self.seconds, 2),
-                "compiles": self.compiles, "cache_hits": self.cache_hits}
+    totals = build_totals()
+    return {"compile_seconds": round(totals["seconds"], 2),
+            "compiles": totals["requests"],
+            "cache_hits": totals["cache_hits"]}
 
 
 def _rel_err(got, ref) -> float:
@@ -980,12 +966,11 @@ def run_phase(args) -> int:
     on anything but a TPU."""
     t0 = time.monotonic()
     line = {"phase": args.phase}
-    meter = None
-    if args.phase != "control-plane":
+    on_chip = args.phase != "control-plane"
+    if on_chip:
         from lzy_tpu.utils.jaxenv import device_summary, enable_compile_cache
 
         cache_dir = enable_compile_cache()
-        meter = CompileMeter()
         device = device_summary()
         if args.require_tpu and device["platform"] != "tpu":
             print(f"chip_smoke: JAX found platform={device['platform']!r} "
@@ -999,8 +984,8 @@ def run_phase(args) -> int:
         line["compile_cache"] = cache_dir or \
             os.environ["JAX_COMPILATION_CACHE_DIR"]
     result = PHASES[args.phase](args)
-    if meter is not None:
-        line.update(meter.doc())
+    if on_chip:
+        line.update(compile_doc())
     line.update(result)
     line["seconds"] = round(time.monotonic() - t0, 2)
     print(json.dumps(line), flush=True)

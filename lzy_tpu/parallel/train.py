@@ -30,6 +30,7 @@ from lzy_tpu.parallel.sharding import (
     named_sharding,
     tree_shardings,
 )
+from lzy_tpu.utils import trace
 
 
 @jax.tree_util.register_dataclass
@@ -206,6 +207,10 @@ def make_train_step(
         def __call__(self, state: TrainState, batch: Any):
             if self._compiled is None:
                 self._compiled = jit_step(state)
+                # the first call traces, lowers and compiles the step (or
+                # reads it from the cache): a build of site ``train.step``
+                with trace.building(trace.SITE_TRAIN_STEP):
+                    return self._compiled(state, batch)
             return self._compiled(state, batch)
 
         def lower(self, state: TrainState, batch: Any):

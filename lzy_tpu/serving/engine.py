@@ -86,7 +86,9 @@ a finished prefill does; the scheduler is theirs unchanged.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import itertools
 import threading
 from typing import Any, Dict, List, Optional, Sequence
@@ -111,7 +113,7 @@ from lzy_tpu.serving.spec import (
     DRAFT_TRUNCATED as _SPEC_TRUNCATED, NgramProposer,
     PROPOSED as _SPEC_PROPOSED, TOKENS_PER_STEP as _SPEC_TPS,
     VERIFY_STEPS as _SPEC_STEPS)
-from lzy_tpu.utils import trace
+from lzy_tpu.utils import jaxenv, trace
 from lzy_tpu.utils.log import get_logger
 from lzy_tpu.utils.metrics import REGISTRY
 
@@ -237,6 +239,55 @@ _OVERLAP_COMMITS = REGISTRY.counter(
     "lzy_engine_admission_plan_total",
     "admission plans computed in the overlap window, by outcome "
     "(outcome=committed|stale|empty)")
+
+# set-up, and a program built after it. The constructor (``engine.init``:
+# the kernels' check, the pool, the jitted steps) and ``warmup()``
+# (``engine.warmup``) are timed whole, and the seconds JAX spent building
+# programs inside each (utils/jaxenv.py's meter) beside them: the
+# difference is what set-up costs beyond its builds. A program built later
+# is built by the round that first needs it, on the scheduling thread: one
+# that kept rows in decode waiting is counted, with the seconds they waited.
+_SETUP_SECONDS = REGISTRY.counter(
+    "lzy_engine_setup_seconds_total",
+    "wall seconds of engine set-up (phase=init|warmup: the constructor, "
+    "warmup())")
+_SETUP_BUILD_SECONDS = REGISTRY.counter(
+    "lzy_engine_setup_build_seconds_total",
+    "seconds of lzy_engine_setup_seconds_total that were program builds "
+    "(trace + lower + compile), by the same phase")
+_SERVING_BUILDS = REGISTRY.counter(
+    "lzy_engine_serving_builds_total",
+    "programs built on the scheduling thread after set-up while rows were "
+    "in decode, by site: every such row waited for the whole build")
+_BUILD_STALLED = REGISTRY.counter(
+    "lzy_engine_build_stalled_row_seconds_total",
+    "build seconds of lzy_engine_serving_builds_total times the rows in "
+    "decode that waited")
+
+
+def _setup_phase(name: str, phase: str, site: Optional[str] = None):
+    """Decorates the constructor and ``warmup()``: the span ``name`` when
+    the recorder is on, the always-on seconds of the phase and of the
+    builds inside it, and (``site``) a build context around the whole of
+    it for the builds no narrower context claims."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def timed(self, *args, **kwargs):
+            t0, built0 = trace.now(), jaxenv.thread_build_seconds()
+            builds = trace.NOOP if site is None \
+                else trace.building(site, rows=0, warm=True)
+            self._setting_up = True
+            try:
+                with trace.span(name), builds:
+                    return fn(self, *args, **kwargs)
+            finally:
+                self._setting_up = False
+                _SETUP_SECONDS.inc(trace.now() - t0, phase=phase)
+                _SETUP_BUILD_SECONDS.inc(
+                    jaxenv.thread_build_seconds() - built0, phase=phase)
+        return timed
+    return decorate
+
 
 # per-slot state (models/serving.py, cache-leaf kind ``state``): a prefill
 # job of a model with state leaves starts from a zeroed batch-1 row and the
@@ -403,6 +454,7 @@ class PagedInferenceEngine:
     of it.
     """
 
+    @_setup_phase(trace.ENGINE_INIT, "init", site=trace.SITE_AUX)
     def __init__(
         self,
         cfg: Any,
@@ -646,6 +698,11 @@ class PagedInferenceEngine:
         # per-row cached-token counts live in _pos
         self._admit_seq = np.zeros((slots,), np.int64)  # admission order
         self._admissions = 0
+        # the programs this engine has called once (``_first``): a prefill
+        # width by its number, the others by name. And what was built in
+        # the round now running, for the slow-phase line
+        self._built: set = set()
+        self._round_builds: list = []
         self._stat_counters: tuple = ()
         self._dispatch_paths: dict = {}   # positions a row -> path labels
         self._build_decode_path(base)
@@ -1007,6 +1064,8 @@ class PagedInferenceEngine:
         self._round_kind = None
         self._round_rows = self._round_emitted = 0
         self._prefill_wait = 0.0
+        if self._round_builds:
+            self._round_builds = []
         with trace.span(trace.ENGINE_ROUND) as rnd:
             t0 = now()
             with trace.span(trace.ENGINE_KV_IO):
@@ -1334,7 +1393,8 @@ class PagedInferenceEngine:
         key = self._rng
         if job.next_chunk == len(job.plan) - 1:
             PREFILL_CALLS.inc()
-            self._rng, key = self._split_rng(self._rng)
+            with self._first("split_rng", trace.SITE_AUX, phase="prefill"):
+                self._rng, key = self._split_rng(self._rng)
         payload = self._payload
         tables = ()
         if job.window is not None:
@@ -1346,9 +1406,13 @@ class PagedInferenceEngine:
             self._win.cover(job.window, start - self._win.window,
                             start + take)
             tables = (job.window.table[None].copy(),)
-        pool, state, job.inputs, first = self._prefill_step(
-            [payload[i] for i in self._pool_at], job.state or [],
-            job.inputs, self.params, key, *tables, width=width)
+        # the first program of a width is traced, lowered and compiled (or
+        # read from the cache) here, while every resident row waits
+        with self._first(width, trace.SITE_PREFILL, phase="prefill",
+                         width=width):
+            pool, state, job.inputs, first = self._prefill_step(
+                [payload[i] for i in self._pool_at], job.state or [],
+                job.inputs, self.params, key, *tables, width=width)
         for i, leaf in zip(self._pool_at, pool):
             payload[i] = leaf
         if self._has_state:
@@ -1368,8 +1432,10 @@ class PagedInferenceEngine:
         """Batch-1 rows of every state leaf, for a prefill job when no
         finished job has left its own behind."""
         PREFILL_CALLS.inc(len(self._state_at))
-        return [jnp.zeros((1,) + self._payload[i].shape[1:],
-                          self._payload[i].dtype) for i in self._state_at]
+        with self._first("state_rows", trace.SITE_AUX, phase="prefill"):
+            return [jnp.zeros((1,) + self._payload[i].shape[1:],
+                              self._payload[i].dtype)
+                    for i in self._state_at]
 
     def _prefill_fence(self, first) -> int:
         """The prefill's one blocking transfer: the first token as the
@@ -1853,10 +1919,14 @@ class PagedInferenceEngine:
         if dt > _SLOW_PHASE_S:
             _SLOW_PHASE.inc(phase=phase)
             decoded = self._round_kind is not None
+            # a slow phase that held a build says so: it was no stall
+            built = "".join(
+                f"; built {b.describe()}" for b in self._round_builds
+                if b.attrs.get("phase") == phase)
             _LOG.warning(
-                "engine loop: phase %s took %.3f s (round kind=%s rows=%d)",
+                "engine loop: phase %s took %.3f s (round kind=%s rows=%d)%s",
                 phase, dt, self._round_kind if decoded else "no_decode",
-                self._round_rows if decoded else 0)
+                self._round_rows if decoded else 0, built)
 
     def _note_decode_round(self, emitted: int, rows: int, dt: float) -> None:
         self._flush_token_accounting()
@@ -1940,6 +2010,7 @@ class PagedInferenceEngine:
 
     # -- lifecycle ---------------------------------------------------------
 
+    @_setup_phase(trace.ENGINE_WARMUP, "warmup")
     def warmup(self) -> None:
         """AOT-compile the decode (and, with speculation on, verify)
         programs before the first request: jit compiles lazily, so
@@ -1962,13 +2033,54 @@ class PagedInferenceEngine:
         vec = jax.ShapeDtypeStruct((self.slots,), jnp.int32)
         mask = jax.ShapeDtypeStruct((self.slots,), jnp.bool_)
         rng = jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype)
-        self._warm_compile(self._decode_step, payload, (vec, vec),
-                           mask, rng)
+        with self._build(trace.SITE_DECODE):
+            self._warm_compile(self._decode_step, payload, (vec, vec),
+                               mask, rng)
+        if self._has_state:
+            # the splice of a finished prefill's state rows, one program
+            # for every slot
+            with self._build(trace.SITE_SPLICE):
+                rows = [payload[i] for i in self._state_at]
+                self._splice_state.lower(
+                    rows, [jax.ShapeDtypeStruct((1,) + r.shape[1:], r.dtype)
+                           for r in rows],
+                    jax.ShapeDtypeStruct((), jnp.int32)).compile()
         if self.spec_tokens > 0:
             prop = jax.ShapeDtypeStruct((self.slots, self.spec_tokens),
                                         jnp.int32)
-            self._warm_compile(self._verify_step, payload,
-                               (vec, prop, vec, vec), mask, rng)
+            with self._build(trace.SITE_VERIFY):
+                self._warm_compile(self._verify_step, payload,
+                                   (vec, prop, vec, vec), mask, rng)
+
+    # -- a program's build has a site and a name ---------------------------
+
+    def _first(self, key, site: str, **attrs):
+        """A build context around the first call of one of the engine's
+        programs (``key``: a prefill width, or a name), where jit traces,
+        lowers and compiles it or reads it from the cache; nothing at all
+        (one set lookup) from then on."""
+        if key in self._built:
+            return trace.NOOP
+        return self._build(site, key, **attrs)
+
+    @contextlib.contextmanager
+    def _build(self, site: str, key=None, **attrs):
+        """``trace.building(site)`` with what the engine knows: ``warm``
+        (inside the constructor or ``warmup()``) and ``rows``, the rows
+        in decode that wait for it (0 in set-up). A build after set-up is
+        kept for the round's slow-phase line, and counted where rows
+        waited."""
+        warm = self._setting_up
+        rows = 0 if warm else sum(r is not None for r in self._active)
+        with trace.building(site, rows=rows, warm=warm, **attrs) as b:
+            yield b
+        if key is not None:
+            self._built.add(key)
+        if b.built and not warm:
+            self._round_builds.append(b)
+            if rows:
+                _SERVING_BUILDS.inc(site=site)
+                _BUILD_STALLED.inc(b.seconds * rows)
 
     @property
     def closed(self) -> bool:
@@ -2239,8 +2351,6 @@ class PagedInferenceEngine:
         a padded prefill chunk ends at its last real token, an idle slot
         (zeroed page table: block 0 is the scratch block no row owns) has
         none."""
-        import functools
-
         self._stat_counters = tuple(type(self._model).STATS)
         has_state, has_stats = self._has_state, bool(self._stat_counters)
         tells_real = self._tells_real
@@ -2404,7 +2514,8 @@ class PagedInferenceEngine:
         — the host ``_pos`` mirror (set by ``_finish_prefill``; 0 while
         the job is mid-flight) is the single source of truth for
         positions."""
-        with trace.span(trace.ENGINE_PREFILL_STATE):
+        with trace.span(trace.ENGINE_PREFILL_STATE), \
+                self._first("splice", trace.SITE_SPLICE, phase="prefill"):
             PREFILL_CALLS.inc()
             rows = self._splice_state(
                 [self._payload[i] for i in self._state_at], job.state,
@@ -2748,11 +2859,20 @@ class PagedInferenceEngine:
         for path in paths:
             self._dispatches.inc(path=path)
 
+    def _round_inputs(self):
+        """``(cur, pos, mask)`` and the page table, on the device; their
+        first uploads' small programs are ``engine.aux`` builds."""
+        with self._first("inputs", trace.SITE_AUX, phase="dispatch"):
+            return self._device_inputs(), self._page_table_dev()
+
     def _run_decode_step(self):
-        cur, pos, mask = self._device_inputs()
+        (cur, pos, mask), tables = self._round_inputs()
         self._count_dispatch(1)
-        return self._decode_step(self._payload, self.params, cur, pos,
-                                 self._page_table_dev(), mask, self._rng)
+        # builds here only where warmup() was not called (after it jit
+        # finds what it traced, lowered and compiled there)
+        with self._first("decode", trace.SITE_DECODE, phase="dispatch"):
+            return self._decode_step(self._payload, self.params, cur, pos,
+                                     tables, mask, self._rng)
 
     def _note_model_stats(self, fetched: np.ndarray,
                           rec: _InFlight) -> np.ndarray:
@@ -2773,11 +2893,11 @@ class PagedInferenceEngine:
         return fetched[:self.slots]
 
     def _run_verify_step(self, prop, prop_len):
-        cur, pos, mask = self._device_inputs()
+        (cur, pos, mask), tables = self._round_inputs()
         self._dispatches.inc(path=self._path_of(self.spec_tokens + 1))
-        return self._verify_step(self._payload, self.params, cur, prop,
-                                 prop_len, pos, self._page_table_dev(),
-                                 mask, self._rng)
+        with self._first("verify", trace.SITE_VERIFY, phase="dispatch"):
+            return self._verify_step(self._payload, self.params, cur, prop,
+                                     prop_len, pos, tables, mask, self._rng)
 
     def _warm_compile(self, step, payload, mids, mask, rng):
         """``mids`` are the step-specific args between ``params`` and the
@@ -2788,14 +2908,6 @@ class PagedInferenceEngine:
         if self._win is not None:
             pt = (pt, pt)
         step.lower(payload, self.params, *mids, pt, mask, rng).compile()
-        if self._has_state and step is self._decode_step:
-            # the splice of a finished prefill's state rows, one program
-            # for every slot
-            rows = [payload[i] for i in self._state_at]
-            self._splice_state.lower(
-                rows, [jax.ShapeDtypeStruct((1,) + r.shape[1:], r.dtype)
-                       for r in rows],
-                jax.ShapeDtypeStruct((), jnp.int32)).compile()
 
     # -- speculative decode over the block pool -------------------------------
 

@@ -42,6 +42,12 @@ class Counter:
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + amount
 
+    def values(self) -> Dict[tuple, float]:
+        """Every series as it stands, by its sorted ``(label, value)``
+        pairs."""
+        with self._lock:
+            return dict(self._values)
+
     def collect(self) -> List[str]:
         out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} counter"]
         with self._lock:
@@ -93,6 +99,12 @@ class Histogram:
                     counts[i] += 1
             counts[-1] += 1  # +Inf
             self._sums[key] = self._sums.get(key, 0.0) + value
+
+    def sums(self) -> Dict[tuple, float]:
+        """Every series' sum of observations, keyed as
+        :meth:`Counter.values` keys."""
+        with self._lock:
+            return dict(self._sums)
 
     def time(self, **labels: str):
         hist = self

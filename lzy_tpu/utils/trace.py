@@ -26,9 +26,10 @@ other. Spans of one request share ``request``, the id of their root.
   file is uploaded with the trace's other artifacts.
 
 **One clock with the device trace.** The engine loop's spans
-(``LOOP_SPANS``) also open a ``jax.profiler.TraceAnnotation`` of the same
-name, so they land in the trace's ``/host:CPU`` plane on the profiler's
-clock, where a reader names the device's idle gaps by them. Names are
+(``LOOP_SPANS``) and ``program.build`` also open a
+``jax.profiler.TraceAnnotation`` of the same name, so they land in the
+trace's ``/host:CPU`` plane on the profiler's clock, where a reader names
+the device's idle gaps by them. Names are
 constants of ``[a-z0-9_.]``, at most 40 characters: ids and sizes are
 attributes. Request-scoped spans are never annotations (they last seconds
 and would cover every gap). When the recorder starts, and at the first
@@ -39,6 +40,18 @@ stamp (``spans.jsonl``) to place it in the trace. Each anchor is also an
 event ``lzy.clock`` among the records (``monotonic_ns``: the same number),
 so a reader can tell a trace that holds no anchor from anchors it did not
 find.
+
+**A program's build.** :func:`building` (beside :func:`span`) is opened
+where the program knows that JAX is about to trace, lower and compile for
+it: ``with building(ENGINE_PREFILL, width=256) as b``. It names the *site*
+of every build JAX reports on that thread while it is open
+(``utils/jaxenv.py`` listens to ``jax.monitoring``; a build under no
+context is ``other``), which is all it does while the recorder is off: the
+counters ``lzy_program_build_seconds{site,stage}`` and
+``lzy_program_builds_total{site,cache}`` are always on. With the recorder
+on it is also the span ``program.build`` (an annotation too, so a device
+gap during a build has a name), whose attributes are the same seconds the
+counters took; a context in which JAX built nothing leaves no record.
 
 **The profiler.**
 
@@ -91,11 +104,19 @@ ENGINE_DECODE_OVERLAP = "engine.decode.overlap"
 ENGINE_DECODE_FENCE = "engine.decode.fence"
 ENGINE_DECODE_EMIT = "engine.decode.emit"
 ENGINE_PARK = "engine.park"
-#: the engine loop's spans: the only ones that are profiler annotations too
+# a build of a program (trace, lowering, compile or cache read), wherever
+# it happens: in warm-up, or on the loop thread under ``engine.prefill``
+PROGRAM_BUILD = "program.build"
+#: the engine loop's spans: with ``program.build`` the only ones that are
+#: profiler annotations too
 LOOP_SPANS = frozenset({
     ENGINE_ROUND, ENGINE_KV_IO, ENGINE_REAP, ENGINE_ADMIT, ENGINE_PREFILL,
     ENGINE_PREFILL_FENCE, ENGINE_DECODE_PLAN, ENGINE_DECODE_DISPATCH, ENGINE_DECODE_OVERLAP,
     ENGINE_DECODE_FENCE, ENGINE_DECODE_EMIT, ENGINE_PARK})
+_ANNOTATED = LOOP_SPANS | {PROGRAM_BUILD}
+# set-up's two parents: the engine's constructor and ``warmup()``
+ENGINE_INIT = "engine.init"
+ENGINE_WARMUP = "engine.warmup"
 ENGINE_PREEMPT = "engine.preempt"           # event
 KV_EVICT = "kv.evict"                       # event
 ENGINE_REQUEST = "engine.request"
@@ -110,6 +131,21 @@ LLM_BATCH = "llm.batch"
 LLM_ROW = "llm.row"
 LLM_ROW_POOL_WAIT = "llm.row.pool_wait"
 LLM_DISPATCH = "llm.dispatch"
+
+# -- build sites: the ``site`` label of the build counters --------------------
+
+SITE_DECODE = "engine.decode"
+SITE_VERIFY = "engine.verify"
+SITE_SPLICE = "engine.splice"
+SITE_PREFILL = "engine.prefill"
+# the engine's small programs: the constructor's allocations, the rng's
+# split, a state model's row maker, the round inputs' first uploads
+SITE_AUX = "engine.aux"
+SITE_TRAIN_STEP = "train.step"
+# no context open: eager stragglers, and whatever the library's caller
+# compiles for itself
+SITE_OTHER = "other"
+
 CLOCK = "lzy.clock"                         # event: one for each anchor
 CLOCK_ANCHOR = CLOCK + "."
 #: what ``profiled()`` leaves beside the trace: the recorder's records
@@ -199,7 +235,7 @@ class _Span:
         self._outer = getattr(_tls, "open", None)
         if self.parent is None and self._outer is not None:
             self.parent, self.request = self._outer.id, self._outer.request
-        loop = self.name in LOOP_SPANS
+        loop = self.name in _ANNOTATED
         if self.request is None and not loop:
             self.request = self.id            # the root of a request's tree
         _tls.open = self
@@ -223,8 +259,115 @@ class _Span:
                             self.parent, self.request, self.attrs))
         return False
 
+    def drop(self) -> None:
+        """Close without a record (a build context in which nothing was
+        built)."""
+        self._recorder = None
+        self.__exit__()
+
     def __bool__(self):
         return True
+
+
+class Build:
+    """What :func:`building` yields: the site of the thread's builds while
+    it is open, and what JAX built meanwhile, by stage. ``built`` says
+    whether anything was; ``seconds`` is trace + lower + compile."""
+    __slots__ = ("site", "attrs", "trace_s", "lower_s", "compile_s",
+                 "cache_read_s", "compile_requests", "hits", "misses",
+                 "_outer", "_span")
+
+    def __init__(self, site: str, attrs: dict):
+        self.site, self.attrs = site, attrs
+        self.trace_s = self.lower_s = self.compile_s = 0.0
+        self.cache_read_s = 0.0
+        self.compile_requests = self.hits = self.misses = 0
+        self._span = None
+
+    def __enter__(self):
+        from lzy_tpu.utils import jaxenv
+
+        jaxenv.install_build_meter()      # once a process
+        self._outer = getattr(_tls, "build", None)
+        _tls.build = self
+        if ON:
+            self._span = _Span(PROGRAM_BUILD, None, None, self.attrs)
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        _tls.build = self._outer
+        sp = self._span
+        if sp is not None:
+            if self.built:
+                sp.attrs.update(
+                    site=self.site, trace_s=self.trace_s,
+                    lower_s=self.lower_s, compile_s=self.compile_s,
+                    cache_read_s=self.cache_read_s, cache=self.cache,
+                    compile_requests=self.compile_requests)
+                sp.__exit__()
+            else:
+                sp.drop()
+        return False
+
+    def add(self, stage: str, seconds: float) -> None:
+        """The listener's: ``seconds`` of ``trace``, ``lower``, ``compile``
+        or ``cache_read`` reported inside this context."""
+        setattr(self, stage + "_s", getattr(self, stage + "_s") + seconds)
+
+    def add_request(self, cache: str) -> None:
+        """The listener's: one backend compile request, and what the
+        persistent cache did with it (``hit``, ``miss``, ``off``)."""
+        self.compile_requests += 1
+        self.hits += cache == "hit"
+        self.misses += cache == "miss"
+
+    @property
+    def built(self) -> bool:
+        # a compile request, a lowering, or a trace of some length (an
+        # ``eval_shape`` of a model is one): JAX also reports a trace, of
+        # some microseconds, where it finds the jaxpr it traced before
+        return bool(self.compile_requests or self.lower_s
+                    or self.trace_s >= 1e-3)
+
+    @property
+    def seconds(self) -> float:
+        return self.trace_s + self.lower_s + self.compile_s
+
+    @property
+    def cache(self) -> str:
+        """``hit`` where every compile request that asked the persistent
+        cache was answered by it, ``miss`` where one was not, ``off``
+        where none asked."""
+        if self.misses:
+            return "miss"
+        return "hit" if self.hits else "off"
+
+    def describe(self) -> str:
+        """``prefill width=256 (trace 0.47 s, lower 0.29 s, compile 1.2 s,
+        cache hit)``: the words of the engine's slow-phase log line."""
+        what = self.site.rpartition(".")[2]
+        if "width" in self.attrs:
+            what += f" width={self.attrs['width']}"
+        return (f"{what} (trace {self.trace_s:.2f} s, lower "
+                f"{self.lower_s:.2f} s, compile {self.compile_s:.2f} s, "
+                f"cache {self.cache})")
+
+
+def building(site: str, **attrs) -> Build:
+    """``with building(SITE, width=...) as b:`` around code that may make
+    JAX build a program. Always on (one thread-local write): the builds
+    JAX reports on this thread inside the block are counted under
+    ``site``, the innermost context's. With the recorder on the block is
+    the span ``program.build`` with ``attrs`` and the stage seconds, if
+    anything was built."""
+    return Build(site, attrs)
+
+
+def open_build() -> Optional[Build]:
+    """The thread's innermost open build context (for the listener)."""
+    return getattr(_tls, "build", None)
+
 
 
 _annotation_cls = None

@@ -149,3 +149,27 @@ class TestCompileCacheHelper:
     def test_unset_it_is_one_fixed_path_in_the_checkout(self):
         returned, fixed, in_effect = self._run({})
         assert returned == fixed == in_effect == str(REPO / ".jax_cache")
+
+
+def test_the_compile_keys_of_a_phases_line_come_from_the_programs_meter():
+    """``chip_smoke.py`` has no meter of its own (ROADMAP D12): the three
+    keys of its line are the build meter's totals, and they move with a
+    compile."""
+    import jax
+    import jax.numpy as jnp
+
+    from lzy_tpu.utils import jaxenv
+
+    assert not hasattr(chip_smoke, "CompileMeter")
+    jaxenv.install_build_meter()
+    x = jnp.ones((3,))
+    before = chip_smoke.compile_doc()
+    assert set(before) == {"compile_seconds", "compiles", "cache_hits"}
+    jax.jit(lambda v: v * 19 - 5)(x)
+    after = chip_smoke.compile_doc()
+    assert after["compiles"] == before["compiles"] + 1
+    assert after["compile_seconds"] >= before["compile_seconds"]
+    assert after["cache_hits"] - before["cache_hits"] in (0, 1)
+    totals = jaxenv.build_totals()
+    assert (after["compiles"], after["cache_hits"]) == (
+        totals["requests"], totals["cache_hits"])

@@ -610,3 +610,278 @@ class TestSlowPhase:
             time.sleep(0.01)
         eng.close()
         assert _slow_phases() == before
+
+
+# -- a program's build: the meter, the span, the engine's sites ---------------
+
+
+def _samples(name):
+    """``{label string: value}`` of one series of the registry."""
+    return {labels: float(v) for labels, v in re.findall(
+        rf"^{name}\{{([^}}]*)\}} (\S+)$", REGISTRY.exposition(), re.M)}
+
+
+def _grown(name, before):
+    after = _samples(name)
+    return {k: after[k] - before.get(k, 0.0) for k in after
+            if after[k] != before.get(k, 0.0)}
+
+
+def _builds(records, site=None):
+    return [r for r in records if r.name == trace.PROGRAM_BUILD
+            and site in (None, r.attrs["site"])]
+
+
+STAGE_ATTRS = {"trace_s", "lower_s", "compile_s", "cache_read_s"}
+
+
+class TestBuildMeter:
+    def test_the_listeners_turn_jaxs_events_into_stage_seconds(self):
+        """Fed by hand: a jit traced inside a trace is taken out of the
+        outer trace's seconds, the compile request is closed by its
+        duration with what the cache said, and all of it lands on the
+        open context and under its site."""
+        from lzy_tpu.utils import jaxenv
+
+        tr, lo, co = (e for e, _ in sorted(
+            jaxenv._STAGE.items(), key=lambda kv: ("trace", "lower",
+                                                   "compile").index(kv[1])))
+        secs = _samples("lzy_program_build_seconds_sum")
+        reqs = _samples("lzy_program_builds_total")
+        with trace.building(trace.SITE_VERIFY) as b:
+            jaxenv._on_stage_start(tr, 0.0)
+            jaxenv._on_stage_start(tr, 0.0)
+            jaxenv._on_duration(tr, 0.25)              # the inner jit
+            jaxenv._on_duration(tr, 1.0)               # holds the 0.25
+            jaxenv._on_stage_start(lo, 0.0)
+            jaxenv._on_duration(lo, 0.5)
+            jaxenv._on_stage_start(co, 0.0)
+            jaxenv._on_event(jaxenv._CACHE_ASKED)
+            jaxenv._on_event(jaxenv._CACHE_HIT)
+            jaxenv._on_duration(jaxenv._CACHE_READ, 0.125)
+            jaxenv._on_duration(co, 2.0)
+            jaxenv._on_stage_start(co, 0.0)
+            jaxenv._on_duration(co, 4.0)               # the cache not asked
+            jaxenv._on_duration("/jax/some/other_duration", 9.0)
+        assert (b.trace_s, b.lower_s, b.compile_s, b.cache_read_s) == (
+            1.0, 0.5, 6.0, 0.125)
+        assert (b.compile_requests, b.hits, b.misses) == (2, 1, 0)
+        assert b.built and b.seconds == 7.5 and b.cache == "hit"
+        site = f'site="{trace.SITE_VERIFY}"'
+        assert _grown("lzy_program_build_seconds_sum", secs) == {
+            f'{site},stage="trace"': 1.0, f'{site},stage="lower"': 0.5,
+            f'{site},stage="compile"': 6.0,
+            f'{site},stage="cache_read"': 0.125}
+        assert _grown("lzy_program_builds_total", reqs) == {
+            f'cache="hit",{site}': 1.0, f'cache="off",{site}': 1.0}
+        assert b.describe() == ("verify (trace 1.00 s, lower 0.50 s, "
+                                "compile 6.00 s, cache hit)")
+
+    def test_a_compile_outside_any_context_lands_under_other(self):
+        from lzy_tpu.utils import jaxenv
+
+        x = jax.numpy.ones((3,))
+        reqs = _samples("lzy_program_builds_total")
+        totals = jaxenv.build_totals()
+        assert trace.open_build() is None
+        jax.jit(lambda v: v * 7 - 2)(x)
+        grown = _grown("lzy_program_builds_total", reqs)
+        assert sum(grown.values()) == 1
+        assert all('site="other"' in k for k in grown)
+        assert jaxenv.build_totals()["requests"] == totals["requests"] + 1
+
+    def test_off_the_counters_move_and_no_record_is_made(self):
+        x = jax.numpy.ones((3,))
+        reqs = _samples("lzy_program_builds_total")
+        assert trace.ON is False
+        with trace.building(trace.SITE_SPLICE) as b:
+            jax.jit(lambda v: v * 11 - 3)(x)
+        assert b.built and b.compile_requests == 1 and b._span is None
+        assert sum(_grown("lzy_program_builds_total", reqs).values()) == 1
+        with trace.recording() as rec:
+            assert _builds(rec.drain()) == []
+
+    def test_a_context_in_which_nothing_was_built_leaves_no_record(self):
+        f = jax.jit(lambda v: v * 13 - 4)
+        x = jax.numpy.ones((3,))
+        f(x)
+        with trace.recording() as rec:
+            with trace.span(trace.LLM_BATCH) as outer:
+                with trace.building(trace.SITE_DECODE, width=8) as b:
+                    f(x)
+                # the thread's open span is the outer one again
+                trace.note(after=True)
+            records = rec.drain()
+        assert not b.built and _builds(records) == []
+        assert [r.attrs for r in records if r.id == outer.id] == [
+            {"after": True}]
+
+    def test_the_train_steps_first_call_is_one_build_its_second_none(self):
+        import optax
+
+        from lzy_tpu.parallel import TrainState, fsdp_mesh, make_train_step
+
+        tx = optax.sgd(0.1)
+        step, shard_state, _ = make_train_step(
+            lambda p, batch: jax.numpy.mean((batch["x"] @ p["w"]) ** 2), tx,
+            mesh=fsdp_mesh(), param_logical_axes={"w": (None, None)},
+            batch_logical_axes=("batch",))
+        batch = {"x": jax.numpy.ones((8, 4))}
+        state = shard_state(TrainState.create(
+            {"w": jax.numpy.ones((4, 2))}, tx))
+        reqs = _samples("lzy_program_builds_total")
+        with trace.recording() as rec:
+            state, _ = step(state, batch)
+            first = _builds(rec.drain())
+            grown = _grown("lzy_program_builds_total", reqs)
+            state, _ = step(state, batch)
+            assert _builds(rec.drain()) == []
+        assert len(first) == 1
+        assert first[0].attrs["site"] == trace.SITE_TRAIN_STEP
+        # the step's program (and a helper's, where placing the batch
+        # needs one): all of it under the site, none of it later
+        assert first[0].attrs["compile_requests"] >= 1
+        assert all(f'site="{trace.SITE_TRAIN_STEP}"' in k for k in grown)
+        assert sum(grown.values()) == first[0].attrs["compile_requests"]
+        assert _grown("lzy_program_builds_total", reqs) == grown
+
+
+class TestEngineBuilds:
+    def test_set_up_is_two_parents_and_warmup_builds_decode(
+            self, tiny_model):
+        cfg, params = tiny_model
+        setup = _samples("lzy_engine_setup_seconds_total")
+        inside = _samples("lzy_engine_setup_build_seconds_total")
+        with trace.recording() as rec:
+            eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
+            eng.warmup()
+            recs = rec.drain()
+        named = _by_name(recs)
+        init, = named[trace.ENGINE_INIT]
+        warm, = named[trace.ENGINE_WARMUP]
+        decode, = _builds(recs, trace.SITE_DECODE)
+        assert decode.parent == warm.id
+        assert decode.attrs["warm"] is True and decode.attrs["rows"] == 0
+        assert STAGE_ATTRS <= set(decode.attrs)
+        assert decode.attrs["compile_requests"] >= 1
+        assert decode.attrs["cache"] in ("hit", "miss", "off")
+        assert decode.attrs["lower_s"] > 0 and decode.attrs["compile_s"] > 0
+        # the constructor's builds are its own context's, engine.aux
+        # (none where the process has built its small programs before)
+        assert {r.attrs["site"] for r in _builds(recs)
+                if r.parent == init.id} <= {trace.SITE_AUX}
+        # always on: each phase's seconds, and the builds inside them
+        grown = _grown("lzy_engine_setup_seconds_total", setup)
+        built = _grown("lzy_engine_setup_build_seconds_total", inside)
+        assert set(grown) == {'phase="init"', 'phase="warmup"'}
+        assert built.get('phase="warmup"', 0.0) > 0
+        for phase, parent in (("init", init), ("warmup", warm)):
+            took = grown[f'phase="{phase}"']
+            assert took == pytest.approx(parent.end - parent.start, abs=0.05)
+            assert built.get(f'phase="{phase}"', 0.0) <= took
+            # (to a millisecond: a trace JAX answers from its own cache
+            # is counted and makes no span)
+            assert built.get(f'phase="{phase}"', 0.0) == pytest.approx(sum(
+                r.attrs["trace_s"] + r.attrs["lower_s"]
+                + r.attrs["compile_s"] for r in _builds(recs)
+                if r.parent == parent.id), abs=1e-3)
+        eng.close()
+
+    def test_a_width_is_built_once_by_the_first_request_that_reaches_it(
+            self, tiny_model):
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
+        eng.warmup()
+        secs = _samples("lzy_program_build_seconds_sum")
+        reqs = _samples("lzy_program_builds_total")
+        serving = _samples("lzy_engine_serving_builds_total")
+
+        def run(prompt):
+            req = eng.submit(prompt, max_new_tokens=2)
+            while not req.done:
+                eng.step()
+
+        with trace.recording() as rec:
+            run([5, 9, 3])                              # width 8
+            first = rec.drain()
+            run(list(range(1, 41)))                     # width 64
+            second = rec.drain()
+            run([7, 1, 2, 6])                           # width 8 again
+            third = rec.drain()
+        for recs, width in ((first, 8), (second, 64)):
+            build, = _builds(recs, trace.SITE_PREFILL)
+            assert build.attrs["width"] == width
+            assert build.attrs["warm"] is False
+            assert build.attrs["phase"] == "prefill"
+            under, = [r for r in recs if r.id == build.parent]
+            assert under.name == trace.ENGINE_PREFILL
+        assert _builds(third) == []
+        # no row was in decode while a width was built: nothing waited,
+        # and the alert's counter names no prefill build
+        assert all(r.attrs["rows"] == 0 for r in _builds(
+            first + second, trace.SITE_PREFILL))
+        assert f'site="{trace.SITE_PREFILL}"' not in _grown(
+            "lzy_engine_serving_builds_total", serving)
+        # the counters took the numbers the spans carry
+        builds = _builds(first + second)
+        for stage in ("trace", "lower", "compile", "cache_read"):
+            by_site = {}
+            for r in builds:
+                by_site[r.attrs["site"]] = by_site.get(
+                    r.attrs["site"], 0.0) + r.attrs[stage + "_s"]
+            grown = _grown("lzy_program_build_seconds_sum", secs)
+            for site, seconds in by_site.items():
+                assert grown.get(f'site="{site}",stage="{stage}"', 0.0) \
+                    == pytest.approx(seconds)
+        assert sum(v for k, v in _grown(
+            "lzy_program_builds_total", reqs).items()
+            if 'site="other"' not in k) == sum(
+            r.attrs["compile_requests"] for r in builds)
+        eng.close()
+
+    def test_a_build_under_a_resident_row_is_counted_spanned_and_named(
+            self, tiny_model, caplog):
+        cfg, params = tiny_model
+        clock = _SkewedClock()
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE,
+                                   clock=clock)
+        eng.warmup()
+        resident = eng.submit([5, 9, 3], max_new_tokens=200)
+        while len(resident.tokens) < 2:
+            eng.step()
+        serving = _samples("lzy_engine_serving_builds_total")
+        stalled = _stalled_row_seconds()
+        step = eng._prefill_step
+
+        def slow_first_program(*args, **kwargs):
+            clock.skew += 0.3          # the build, as the loop's clock sees
+            return step(*args, **kwargs)
+
+        eng._prefill_step = slow_first_program
+        caplog.clear()
+        with trace.recording() as rec, caplog.at_level("WARNING"):
+            wide = eng.submit(list(range(1, 41)), max_new_tokens=2)
+            while not wide.done:
+                eng.step()
+            recs = rec.drain()
+        build, = _builds(recs, trace.SITE_PREFILL)
+        assert build.attrs["rows"] == 1 and build.attrs["width"] == 64
+        assert build.attrs["warm"] is False
+        assert _grown("lzy_engine_serving_builds_total", serving) == {
+            f'site="{trace.SITE_PREFILL}"': 1.0}
+        seconds = sum(build.attrs[k] for k in
+                      ("trace_s", "lower_s", "compile_s"))
+        assert _stalled_row_seconds() - stalled == pytest.approx(seconds)
+        line, = [r.getMessage() for r in caplog.records
+                 if "engine loop: phase prefill" in r.getMessage()]
+        assert re.search(
+            r"\(round kind=\w+ rows=\d\); built prefill width=64 "
+            r"\(trace \d+\.\d\d s, lower \d+\.\d\d s, compile \d+\.\d\d s, "
+            r"cache (hit|miss|off)\)$", line)
+        eng.close()
+
+
+def _stalled_row_seconds():
+    m = re.search(r"^lzy_engine_build_stalled_row_seconds_total (\S+)$",
+                  REGISTRY.exposition(), re.M)
+    return float(m.group(1)) if m else 0.0

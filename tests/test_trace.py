@@ -120,6 +120,35 @@ class TestProfiled:
         offsets = [start - ns for ns, start in found.items()]
         assert max(offsets) - min(offsets) < 1e6
 
+    def test_a_programs_build_is_an_annotation_on_the_anchors_clock(
+            self, tmp_path):
+        """``program.build`` lies in the capture's host plane where its
+        record says, so a device gap during a build has a name."""
+        import glob
+
+        from jax.profiler import ProfileData
+
+        x = jnp.ones((4, 4))
+        with profiled(str(tmp_path / "trace")) as logdir:
+            with trace.building(trace.SITE_DECODE, width=4):
+                float(jax.jit(lambda v: (v @ v).sum() * 17)(x))
+        records = [json.loads(line) for line in
+                   open(f"{logdir}/{trace.SPANS_FILE}")][1:]
+        build, = [r for r in records if r["name"] == trace.PROGRAM_BUILD]
+        assert build["attrs"]["site"] == trace.SITE_DECODE
+        assert build["attrs"]["compile_requests"] == 1
+        path, = glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb")
+        events = [(float(e.start_ns), float(e.duration_ns), e.name)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name == "/host:CPU" for line in plane.lines
+                  for e in line.events]
+        offset, = {s - int(n[len(trace.CLOCK_ANCHOR):]) for s, _, n in events
+                   if n.startswith(trace.CLOCK_ANCHOR)}
+        (start, duration), = [(s, d) for s, d, n in events
+                              if n == trace.PROGRAM_BUILD]
+        assert abs(start - (build["start"] * 1e9 + offset)) < 1e6
+        assert abs(duration - (build["end"] - build["start"]) * 1e9) < 1e6
+
     def test_upload_to_storage(self, tmp_path):
         from lzy_tpu.storage.mem import MemStorageClient
 
