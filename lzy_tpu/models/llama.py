@@ -1120,17 +1120,54 @@ def _lm_loss(cfg: LlamaConfig, out, tokens, shifted_mask, mesh=None,
         features, head = out
         from lzy_tpu.ops.chunked_ce import chunked_cross_entropy
 
-        # anchor the CE operands: features keep the batch sharded; the
-        # head is gathered whole ONCE (vocab x embed, ~67 MB bf16 at
-        # flagship size) instead of the partitioner keeping its embed dim
-        # fsdp-sharded and batch-all-gathering every chunk of the scan —
-        # the 193 GB/step pathology a deviceless v5e-16 compile showed
+        # anchor the CE operands: features keep the batch sharded
         features = _anchor(features, mesh, "batch", "seq", "act_embed",
                            rules=rules)
-        # (vocab, None): "act_embed" here would map to the same mesh axis
-        # as "vocab" (both tp) and P("tp","tp") is illegal
-        head = _anchor(head, mesh, "vocab", None, rules=rules)
+        shard = _loss_batch_shard(features.shape[0], mesh, rules)
+        if shard is None:
+            # the head is gathered whole ONCE (vocab x embed, ~67 MB bf16
+            # at flagship size) instead of the partitioner keeping its
+            # embed dim fsdp-sharded and batch-all-gathering every block of
+            # the scan — the 193 GB/step pathology a deviceless v5e-16
+            # compile showed. (vocab, None): "act_embed" here would map to
+            # the same mesh axis as "vocab" (both tp) and P("tp","tp") is
+            # illegal. Per batch shard the ``shard_map`` takes the head
+            # whole itself, and an anchor would only pin its gradient
+            # replicated on the way back: a gather of what was scattered
+            head = _anchor(head, mesh, "vocab", None, rules=rules)
+        # the last position predicts nothing: a weight-0 row, not a slice
+        # (a slice copies the features and leaves 4095 rows a sequence,
+        # which no block of 8 divides)
+        labels = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+        weights = jnp.ones(tokens[:, 1:].shape, jnp.float32) \
+            if shifted_mask is None else shifted_mask.astype(jnp.float32)
         return chunked_cross_entropy(
-            features[:, :-1], head, tokens[:, 1:], mask=shifted_mask,
+            features, head, labels, mask=jnp.pad(weights, ((0, 0), (0, 1))),
+            shard=shard,
         )
     return cross_entropy_loss(out[:, :-1], tokens[:, 1:], shifted_mask)
+
+
+def _loss_batch_shard(batch, mesh, rules):
+    """Where the fused loss runs per batch shard (``ops.chunked_ce``
+    ``BatchShard``): the mesh shards the batch over more than one device
+    and the batch divides, the rule ``_batch_sharded_attention`` uses. None
+    elsewhere (one device, a mesh that shards no batch, an odd batch, a
+    manual region, which a nested ``shard_map`` cannot re-bind): the same
+    loss under plain ``jit``, whose partitioner sums the head's gradient
+    inside the loop."""
+    import math
+
+    from lzy_tpu.ops.chunked_ce import BatchShard
+    from lzy_tpu.parallel.sharding import manual_axes
+
+    if mesh is None or mesh.size == 1 or manual_axes():
+        return None
+    batch_axes = _mesh_axes_for(rules, "batch", mesh)
+    shards = math.prod(mesh.shape[a] for a in batch_axes)
+    if shards == 1 or batch % shards:
+        return None
+    # the head's own layout: its parameter's logical axes under the rules
+    return BatchShard(mesh, batch_axes, tuple(
+        tuple(a for a in _mesh_axes_for(rules, name, mesh) if a in batch_axes)
+        for name in ("vocab", "embed")))

@@ -163,15 +163,23 @@ def test_recompute_census_reads_the_scope_from_op_name(scope, kind):
     assert recompute_census(hlo) == want
 
 
+#: tokens a block of the fused loss in ``remat_steps``: four blocks a chip
+_LOSS_ROWS = 1024
+
+
 @pytest.fixture(scope="module")
 def remat_steps(topo):
     """The training cell's kind of step (flash kernels, fused cross-entropy,
     packed rows, fsdp=4) at two layers and small widths, compiled once under
     each spelling of ``remat_policy``: ``(recompute census, temp bytes,
-    partitioner's log, scheduled collectives)`` by policy. Since PR 50 these
-    compiles carry ``parallel.train.TPU_SHARDED_STEP_OPTIONS``: the mesh's
-    devices are TPUs and fsdp=4 shards the parameters."""
-    from lzy_tpu.ops import interpret
+    partitioner's log, scheduled collectives, compiled text)`` by policy.
+    Since PR 50 these compiles carry
+    ``parallel.train.TPU_SHARDED_STEP_OPTIONS``: the mesh's devices are TPUs
+    and fsdp=4 shards the parameters. Since PR 54 the loss is one loop over
+    blocks of tokens: at these widths its rule would take a chip's 4,096
+    tokens in one block and leave no loop to read, so the budget of a
+    block's logits is set here to what makes four."""
+    from lzy_tpu.ops import chunked_ce, interpret
     from tools.aot_analysis import collectives_scheduled, recompute_census
 
     base = llama.LlamaConfig(
@@ -182,6 +190,8 @@ def remat_steps(topo):
     # the kernels are compiled, not interpreted, as on the chip
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(interpret, "_process_wide", False)
+        patch.setattr(chunked_ce, "_LOGITS_BYTES",
+                      _LOSS_ROWS * base.vocab_size * 4)
         for policy in ("dots", "nothing"):
             cfg = dataclasses.replace(base, remat_policy=policy)
             compiled, _, stderr = _compile(
@@ -190,7 +200,7 @@ def remat_steps(topo):
             assert "tpu_custom_call" in hlo
             out[policy] = (recompute_census(hlo),
                            compiled.memory_analysis().temp_size_in_bytes,
-                           stderr, collectives_scheduled(hlo))
+                           stderr, collectives_scheduled(hlo), hlo)
     return out
 
 
@@ -315,6 +325,82 @@ def test_sharded_step_schedules_its_gathers_asynchronously(policy,
     assert gathers["async"] > 4 * gathers["sync"], scheduled
     assert scheduled["reduce-scatter"]["async"] == 0, scheduled
     assert "all-to-all" not in scheduled
+
+
+# -- the fused loss: one local loop, three products, one reduce-scatter -------
+
+_CANNED_LOOPS = (
+    'HloModule jit_step, is_scheduled=true\n\n'
+    '%fused_computation.1 (p.0: bf16[8,4]) -> f32[8,8] {\n'
+    '  %p.0 = bf16[8,4]{1,0} parameter(0)\n'
+    '  ROOT %convolution.1 = f32[8,8]{1,0} convolution(%p.0, %p.0), '
+    'dim_labels=bf_oi->bf\n'
+    '}\n\n'
+    '%body.1 (t.0: (s32[], bf16[8,4])) -> (s32[], bf16[8,4]) {\n'
+    '  %t.0 = (s32[], bf16[8,4]{1,0}) parameter(0)\n'
+    '  %fusion.1 = f32[8,8]{1,0} fusion(%x.1), kind=kOutput, '
+    'calls=%fused_computation.1\n'
+    '{inside}'
+    '  ROOT %tuple.1 = (s32[], bf16[8,4]{1,0}) tuple(%i.1, %x.1)\n'
+    '}\n\n'
+    'ENTRY %main.1_spmd (p.1: bf16[8,4]) -> bf16[8,4] {\n'
+    '  %p.1 = bf16[8,4]{1,0} parameter(0)\n'
+    '  %while.1 = (s32[], bf16[8,4]{1,0}) while(%tuple.9), '
+    'condition=%cond.1, body=%body.1\n'
+    '  ROOT %all-reduce.9 = f32[]{:T(128)} all-reduce(%s.1), channel_id=1, '
+    'replica_groups={{0,1,2,3}}, to_apply=%add.1\n'
+    '}\n')
+_INSIDE = ('  %all-reduce.4 = f32[8,8]{1,0} all-reduce(%fusion.1), '
+           'channel_id=33, replica_groups=[1,4]<=[4], to_apply=%add.1\n')
+
+
+def test_loop_census_reads_a_body_and_what_it_calls():
+    from tools.aot_analysis import loop_census
+
+    assert loop_census(_CANNED_LOOPS.replace("{inside}", "")) == [
+        {"body": "body.1", "matmuls": 1, "collectives": {}}]
+    # the partitioner's sum of a carried gradient sits inside the body;
+    # the one of ENTRY does not count
+    assert loop_census(_CANNED_LOOPS.replace("{inside}", _INSIDE)) == [
+        {"body": "body.1", "matmuls": 1, "collectives": {"all-reduce": 1}}]
+
+
+@pytest.mark.parametrize("policy", ["dots", "nothing"])
+def test_the_loss_is_one_local_loop_of_three_products(policy, remat_steps):
+    """The mechanism's counter, on the program the chip runs: the fused
+    cross-entropy is ONE ``while`` (the two-loop form had a forward loop of
+    one product and a backward loop of three, the logits' a second time,
+    with the partitioner's all-reduce of the head's gradient inside: eight
+    a step at the cell's shapes); its body holds the three products a loss
+    and its gradients need and no collective."""
+    from tools.aot_analysis import loop_census
+
+    loops = [row for row in loop_census(remat_steps[policy][4])
+             if row["matmuls"]]
+    assert len(loops) == 1, loops
+    assert loops[0]["matmuls"] == 3, loops
+    assert loops[0]["collectives"] == {}, loops
+
+
+@pytest.mark.parametrize("policy", ["dots", "nothing"])
+def test_the_heads_gradient_is_reduced_once_outside_the_loop(policy,
+                                                             remat_steps):
+    """One float32 reduce-scatter of the head's gradient onto the head's
+    own sharding (vocab x embed / fsdp), an instruction of ``ENTRY``: what
+    ``psum_scatter`` asks for after the loop. ``collectives_scheduled``
+    counts it among the synchronous reduce-scatters (the cell's step: 57
+    -> 58, where the two-loop form's eight all-reduces inside the backward
+    loop were counted by nobody)."""
+    hlo = remat_steps[policy][4]
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    heads = re.findall(
+        r"= f32\[4096,128\]\S* reduce-scatter\([^\n]*shard_map/reduce_scatter",
+        entry)
+    assert len(heads) == 1, heads
+    # the weights' own are all-reduce-scatter fusions: no other instruction
+    # of the module is a reduce-scatter, in a loop or out of one
+    assert len(re.findall(r"\sreduce-scatter\(", hlo)) == 1
 
 
 # -- the kernels of the main path at Llama-3-8B widths ------------------------

@@ -633,6 +633,39 @@ class TestRematPolicy:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-6, rtol=1e-5)
 
+    @pytest.mark.parametrize("axes,batch,per_shard", [
+        ({"fsdp": -1}, 8, True), ({"fsdp": -1}, 6, False),
+        ({"dp": 2, "fsdp": 2, "tp": 2}, 8, True),
+    ], ids=["batch_divides", "batch_does_not", "dp_fsdp_tp"])
+    def test_fused_loss_on_a_mesh_equals_the_dense_loss_on_one_device(
+            self, axes, batch, per_shard):
+        """The fused loss runs per batch shard where the batch divides the
+        mesh's batch axes, with the head's gradient summed across the
+        devices by one ``psum_scatter`` (and a ``psum`` over a batch axis
+        the head is not laid over: dp), and under plain ``jit`` where it
+        does not: either way the loss and every gradient are the dense
+        loss's."""
+        mesh = mesh_for(**axes)
+        assert (llama._loss_batch_shard(batch, mesh, None) is not None) \
+            == per_shard
+        dense = dataclasses.replace(
+            LlamaConfig.tiny(vocab_size=128), dtype=jnp.float32,
+            param_dtype=jnp.float32)
+        params = unbox(llama.init_params(dense, jax.random.PRNGKey(0))[0])
+        rng = np.random.default_rng(6)
+        data = {"tokens": jnp.asarray(rng.integers(0, 128, (batch, 32))),
+                "mask": jnp.asarray(rng.integers(0, 2, (batch, 32)))}
+        want_loss, want = jax.jit(jax.value_and_grad(
+            llama.make_loss_fn(dense)))(params, data)
+        loss, grads = jax.jit(jax.value_and_grad(llama.make_loss_fn(
+            dataclasses.replace(dense, fused_ce=True), mesh)))(params, data)
+        assert abs(float(loss) - float(want_loss)) < 1e-6
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want),
+                                jax.tree_util.tree_leaves(grads)):
+            np.testing.assert_allclose(
+                np.asarray(b), np.asarray(a), atol=1e-6, rtol=1e-5,
+                err_msg=jax.tree_util.keystr(path))
+
     @pytest.mark.parametrize("policy,kept", [("dots", True),
                                              ("nothing", False)])
     def test_policy_keeps_matmuls_and_the_kernels_results(self, policy,

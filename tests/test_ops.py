@@ -259,17 +259,124 @@ class TestChunkedCrossEntropy:
         assert jnp.allclose(gx_f, gx_d, atol=1e-5)
 
     def test_batched_and_indivisible_chunk(self):
+        """Rows that do not fill the last block (12 tokens in blocks of 5,
+        batched input, a vocabulary no power of two): value and gradients."""
         import numpy as np
 
         from lzy_tpu.ops.chunked_ce import chunked_cross_entropy
 
         rng = np.random.default_rng(2)
         x = jnp.asarray(rng.standard_normal((2, 6, 16)), jnp.float32)
-        head = jnp.asarray(rng.standard_normal((60, 16)), jnp.float32)  # 60 % 16 != 0
+        head = jnp.asarray(rng.standard_normal((60, 16)), jnp.float32)
         labels = jnp.asarray(rng.integers(0, 60, size=(2, 6)), jnp.int32)
-        fused = chunked_cross_entropy(x, head, labels, chunk=16)
-        dense = self._dense(x.reshape(12, 16), head, labels.reshape(12))
+        fused, (gx_f, gh_f) = jax.value_and_grad(
+            lambda a, h: chunked_cross_entropy(a, h, labels, chunk=5),
+            argnums=(0, 1))(x, head)
+        dense, (gx_d, gh_d) = jax.value_and_grad(
+            lambda a, h: self._dense(a.reshape(12, 16), h,
+                                     labels.reshape(12)),
+            argnums=(0, 1))(x, head)
         assert jnp.allclose(fused, dense, atol=1e-5)
+        assert jnp.allclose(gx_f, gx_d, atol=1e-5)
+        assert jnp.allclose(gh_f, gh_d, atol=1e-5)
+
+    @pytest.mark.parametrize("chunk", [1, 4, 5, 100, None],
+                             ids=["one", "divisor", "non_divisor",
+                                  "past_the_tokens", "from_shapes"])
+    def test_any_block_size_gives_the_dense_numbers(self, chunk):
+        from lzy_tpu.ops.chunked_ce import chunked_cross_entropy
+
+        x, head, labels = self._setup()
+        fused, grads = jax.value_and_grad(
+            lambda a, h: chunked_cross_entropy(a, h, labels, chunk=chunk),
+            argnums=(0, 1))(x, head)
+        dense, want = jax.value_and_grad(
+            lambda a, h: self._dense(a, h, labels), argnums=(0, 1))(x, head)
+        assert jnp.allclose(fused, dense, atol=1e-5)
+        for got, ref in zip(grads, want):
+            assert jnp.allclose(got, ref, atol=1e-5)
+
+    @pytest.mark.parametrize("wrt", [0, 1], ids=["features", "head"])
+    def test_a_cotangent_that_is_not_one_scales_both_gradients(self, wrt):
+        # the gradients are made in the forward for a cotangent of 1: the
+        # backward has to multiply them by what arrives
+        from lzy_tpu.ops.chunked_ce import chunked_cross_entropy
+
+        x, head, labels = self._setup()
+        got = jax.grad(lambda a, h: 3.0 * chunked_cross_entropy(
+            a, h, labels, chunk=16), argnums=wrt)(x, head)
+        want = jax.grad(lambda a, h: 3.0 * self._dense(a, h, labels),
+                        argnums=wrt)(x, head)
+        assert jnp.allclose(got, want, atol=1e-5)
+
+    @pytest.mark.parametrize("wrt", [None, 0, 1],
+                             ids=["loss", "features", "head"])
+    def test_bf16_operands_match_the_dense_bf16_path(self, wrt):
+        from lzy_tpu.ops.chunked_ce import chunked_cross_entropy
+
+        x, head, labels = self._setup(n=24, d=32, v=128)
+        x, head = x.astype(jnp.bfloat16), head.astype(jnp.bfloat16)
+        if wrt is None:
+            got = chunked_cross_entropy(x, head, labels, chunk=16)
+            assert got.dtype == jnp.float32
+            assert jnp.allclose(got, self._dense(x, head, labels), atol=1e-5)
+            return
+        got = jax.grad(lambda a, h: chunked_cross_entropy(
+            a, h, labels, chunk=16), argnums=wrt)(x, head)
+        want = jax.grad(lambda a, h: self._dense(a, h, labels),
+                        argnums=wrt)(x, head)
+        assert got.dtype == jnp.bfloat16
+        # one bf16 rounding of dlogits and one of the result, each 2**-8
+        assert jnp.allclose(got.astype(jnp.float32),
+                            want.astype(jnp.float32), rtol=2e-2, atol=2e-4)
+
+    @pytest.mark.parametrize("wrt", [None, 0, 1],
+                             ids=["loss", "features", "head"])
+    def test_a_mask_of_zeros_gives_zero_and_nothing_nan(self, wrt):
+        from lzy_tpu.ops.chunked_ce import chunked_cross_entropy
+
+        x, head, labels = self._setup()
+        mask = jnp.zeros(labels.shape, jnp.float32)
+        if wrt is None:
+            assert float(chunked_cross_entropy(
+                x, head, labels, chunk=5, mask=mask)) == 0.0
+            return
+        got = jax.grad(lambda a, h: chunked_cross_entropy(
+            a, h, labels, chunk=5, mask=mask), argnums=wrt)(x, head)
+        assert not jnp.any(jnp.isnan(got))
+        assert float(jnp.abs(got).max()) == 0.0
+
+    def test_last_position_as_a_zero_weight_row_equals_the_slice(self):
+        """``_lm_loss`` hands the features whole, the labels shifted and the
+        last column's weight 0, where it used to hand ``features[:, :-1]``:
+        the same loss and the same gradient of every parameter."""
+        import dataclasses
+
+        from lzy_tpu.models import llama, unbox
+        from lzy_tpu.ops.chunked_ce import chunked_cross_entropy
+
+        cfg = dataclasses.replace(
+            llama.LlamaConfig.tiny(vocab_size=128), fused_ce=True,
+            dtype=jnp.float32, param_dtype=jnp.float32)
+        params = unbox(llama.init_params(cfg, jax.random.PRNGKey(0))[0])
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 128)
+        mask = jax.random.bernoulli(jax.random.PRNGKey(2), 0.7, (2, 16))
+        model = llama.Llama(cfg)
+
+        def sliced(p):
+            features, head = model.apply({"params": p}, tokens)
+            return chunked_cross_entropy(features[:, :-1], head,
+                                         tokens[:, 1:], mask=mask[:, 1:])
+
+        want_loss, want = jax.value_and_grad(sliced)(params)
+        loss, grads = jax.value_and_grad(llama.make_loss_fn(cfg))(
+            params, {"tokens": tokens, "mask": mask})
+        assert abs(float(loss) - float(want_loss)) < 1e-6
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want),
+                                jax.tree_util.tree_leaves(grads)):
+            np.testing.assert_allclose(
+                np.asarray(b), np.asarray(a), atol=1e-6, rtol=1e-5,
+                err_msg=jax.tree_util.keystr(path))
 
     def test_fused_llama_loss_matches_dense(self):
         import dataclasses

@@ -12,7 +12,9 @@ built:
   ``ENTRY`` are asynchronous (:func:`collectives_scheduled`),
 - compiled memory footprint (does the config fit in 16 GB HBM?),
 - what a rematerialised layer's backward runs a second time
-  (:func:`recompute_census`: matmuls, kernels, the rest),
+  (:func:`recompute_census`: matmuls, kernels, the rest), and what each
+  ``while`` of the step holds (:func:`loop_census`: the fused
+  cross-entropy's three matmuls and no collective),
 - the roofline-implied MFU bound for the flagship config, and
 - the partitioner's stderr (asserting no "Involuntary full
   rematerialization" resharding cliffs — the CPU-dryrun warning assert
@@ -109,9 +111,11 @@ _CHANNEL_RE = re.compile(r"channel_id=(\d+)")
 
 def collective_census(hlo_text: str) -> dict:
     """Count SPMD collectives and the bytes each moves (output shape). A
-    collective is counted once by its ``channel_id``: the TPU compiler
-    writes an asynchronous one into the body of its start, of its done and
-    of every compute fusion that carries its steps, under one channel."""
+    collective is counted once by its ``channel_id``, kind and shape: the
+    TPU compiler writes an asynchronous one into the body of its start, of
+    its done and of every compute fusion that carries its steps, under one
+    channel (and every collective a ``shard_map`` body asks for itself
+    carries channel 1, whatever it is)."""
     census = {op: {"count": 0, "bytes": 0} for op in _COLLECTIVES}
     largest = []
     seen = set()
@@ -135,9 +139,10 @@ def collective_census(hlo_text: str) -> dict:
             desc = op + "-async"
         channel = _CHANNEL_RE.search(line, m.end())
         if channel is not None:
-            if channel.group(1) in seen:
+            key = (channel.group(1), op, dtype, dims)
+            if key in seen:
                 continue
-            seen.add(channel.group(1))
+            seen.add(key)
         nbytes = _shape_bytes(dtype, dims)
         census[op]["count"] += 1
         census[op]["bytes"] += nbytes
@@ -167,22 +172,8 @@ _INSTRUCTION_RE = re.compile(
 _CALLS_RE = re.compile(r"calls=%([\w.\-]+)")
 
 
-def collectives_scheduled(hlo_text: str) -> dict:
-    """The collectives of the scheduled ``ENTRY`` by kind, asynchronous and
-    synchronous: ``{kind: {"async": n, "sync": n}}``, each counted once by
-    its ``channel_id`` (loops' bodies are not read: the head's
-    reduce-scatter lives in the cross-entropy loop).
-
-    How the TPU compiler writes them. A synchronous one is the collective
-    itself, or a ``fusion`` whose called computation holds it: a gradient's
-    reduce-scatter is ``fusion(...), calls=%all-reduce-scatter.N``, an
-    ``all-reduce`` with the ``dynamic-slice`` fused on. An asynchronous one
-    is a pair of fusions named ``%async-collective-start.N`` / ``-done.N``
-    (or a plain ``<kind>-start`` / ``-done``) with the same channel in their
-    bodies; its steps may ride in compute fusions between the two
-    (``calls=%async_collective_fusion.N``), whose bodies hold the channel
-    again. So: a channel is asynchronous if one of the ``ENTRY``
-    instructions that reach it is a start."""
+def _computations(hlo_text: str):
+    """``({computation: its instruction lines}, the ENTRY's name)``."""
     bodies, entry, current = {}, None, None
     for line in hlo_text.splitlines():
         if not line.startswith(" "):
@@ -194,6 +185,27 @@ def collectives_scheduled(hlo_text: str) -> dict:
                     entry = current
         elif current is not None:
             bodies[current].append(line)
+    return bodies, entry
+
+
+def collectives_scheduled(hlo_text: str) -> dict:
+    """The collectives of the scheduled ``ENTRY`` by kind, asynchronous and
+    synchronous: ``{kind: {"async": n, "sync": n}}``, each counted once by
+    its ``channel_id`` and kind (loops' bodies are not read:
+    :func:`loop_census` reads those; since PR 54 the cross-entropy's holds
+    none, and the head's reduce-scatter is an instruction of ``ENTRY``).
+
+    How the TPU compiler writes them. A synchronous one is the collective
+    itself, or a ``fusion`` whose called computation holds it: a gradient's
+    reduce-scatter is ``fusion(...), calls=%all-reduce-scatter.N``, an
+    ``all-reduce`` with the ``dynamic-slice`` fused on. An asynchronous one
+    is a pair of fusions named ``%async-collective-start.N`` / ``-done.N``
+    (or a plain ``<kind>-start`` / ``-done``) with the same channel in their
+    bodies; its steps may ride in compute fusions between the two
+    (``calls=%async_collective_fusion.N``), whose bodies hold the channel
+    again. So: a channel is asynchronous if one of the ``ENTRY``
+    instructions that reach it is a start."""
+    bodies, entry = _computations(hlo_text)
 
     def collective_of(line):
         m = _INSTRUCTION_RE.match(line)
@@ -205,8 +217,9 @@ def collectives_scheduled(hlo_text: str) -> dict:
         if kind not in _COLLECTIVES:
             return None
         channel = _CHANNEL_RE.search(line)
-        # one without a channel is its own
-        return (channel.group(1) if channel else name), kind, started
+        # one without a channel is its own; the collectives a shard_map
+        # body asks for itself all carry channel 1, so the kind is in the key
+        return (channel.group(1) if channel else name, kind), kind, started
 
     reached: dict = {}
 
@@ -244,6 +257,50 @@ def collectives_scheduled(hlo_text: str) -> dict:
         row = out.setdefault(kind, {"async": 0, "sync": 0})
         row["async" if asynchronous else "sync"] += 1
     return out
+
+
+_WHILE_BODY_RE = re.compile(r"\swhile\(.*\bbody=%([\w.\-]+)")
+_MATMULS = ("convolution", "dot")
+
+
+def loop_census(hlo_text: str) -> list:
+    """One row a ``while`` of the module, in the order they are written:
+    ``{"body": name, "matmuls": n, "collectives": {kind: n}}``, counted
+    over the body and every computation the body calls. On the TPU a
+    ``dot_general`` is a ``convolution`` inside a fusion. The train step's
+    only loop with matmuls is the fused cross-entropy's
+    (``ops/chunked_ce.py``): three of the head's size and no collective
+    says the loop is local and nothing is multiplied twice."""
+    bodies, _ = _computations(hlo_text)
+
+    def under(computation, seen):
+        if computation in seen:
+            return
+        seen.add(computation)
+        for line in bodies.get(computation, ()):
+            m = _INSTRUCTION_RE.match(line)
+            if m is not None:
+                yield m.group(2)
+            for callee in _CALLS_RE.findall(line):
+                yield from under(callee, seen)
+
+    rows = []
+    for lines in bodies.values():
+        for line in lines:
+            m = _WHILE_BODY_RE.search(line)
+            if m is None:
+                continue
+            row = {"body": m.group(1), "matmuls": 0, "collectives": {}}
+            for opcode in under(m.group(1), set()):
+                kind = opcode[:-len("-start")] \
+                    if opcode.endswith("-start") else opcode
+                if opcode in _MATMULS:
+                    row["matmuls"] += 1
+                elif kind in _COLLECTIVES:
+                    row["collectives"][kind] = \
+                        row["collectives"].get(kind, 0) + 1
+            rows.append(row)
+    return rows
 
 
 # an instruction the backward runs a second time carries the scope
@@ -380,6 +437,7 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
     census = collective_census(hlo)
     scheduled = collectives_scheduled(hlo)
     recompute = recompute_census(hlo)
+    loops = loop_census(hlo)
 
     # --- roofline ---------------------------------------------------------
     flops_dev = float(ca.get("flops", 0.0))        # per-device (SPMD module)
@@ -444,6 +502,7 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
         "collectives": census,
         "collectives_scheduled": scheduled,
         "recompute": recompute,
+        "loops": loops,
         "roofline": {
             "t_mxu_ms": round(1e3 * t_mxu, 3),
             "t_hbm_ms": round(1e3 * t_hbm, 3),
@@ -468,6 +527,8 @@ def analyze(tag: str, cfg, topo_name: str, *, global_batch: int,
           f"{ma.temp_size_in_bytes / 1e9:.2f} GB + code "
           f"{ma.generated_code_size_in_bytes / 1e9:.2f} GB, "
           f"{flops_dev / 1e12:.1f} TFLOP, recomputed: {recompute}; "
+          f"loops (matmuls, collectives): "
+          f"{[(r['matmuls'], r['collectives']) for r in loops]}; "
           f"scheduled (async / sync): "
           f"{ {k: (v['async'], v['sync']) for k, v in scheduled.items()} }",
           flush=True)
