@@ -7,8 +7,9 @@ the configuration object and by the module class it builds;
 ``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3``,
 ``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe``,
 ``models/jamba.py``'s ``JambaConfig`` / ``Jamba``, ``models/zaya.py``'s
-``ZayaConfig`` / ``Zaya`` and ``models/minicpm_sala.py``'s
-``MiniCPMSalaConfig`` / ``MiniCPMSala`` all do: eight families.
+``ZayaConfig`` / ``Zaya``, ``models/minicpm_sala.py``'s
+``MiniCPMSalaConfig`` / ``MiniCPMSala`` and ``models/brumby.py``'s
+``BrumbyConfig`` / ``Brumby`` all do: nine families.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -21,7 +22,17 @@ engine reads neither), and:
   the engine runs, for decode rounds and batch-1 prefill alike, reading its
   pool through the page table (``ops/paged_attention.py``, ``ops/mla.py``);
   a ``kv_quant`` its pool cannot take is refused here, by name;
-- ``kv_layers``: layers that keep a leaf in the paged pool;
+- ``kv_layers``: layers that keep a leaf in the paged pool. **0 (and no
+  ``kv_window``) is a model with no pool** (``models/brumby.py``: every cache
+  leaf is ``state``): the engine builds no block for it, not even the scratch
+  one (``RadixCache(0, ..)``), keeps and uploads no page table (a decode
+  round is handed the ``[slots]`` vector of live rows in the table's place,
+  as ``valid_len``), admits a request by a free slot and ``max_seq_len``
+  alone, grows nothing in decode, reports ``kv_blocks_*`` 0, and refuses
+  ``kv_blocks`` / ``kv_pool_bytes`` by name; its module is still handed
+  ``page_table=`` (``[1, 0]`` in a prefill program, nothing in a decode
+  round) and does not read it. The engine checks the answer against the
+  module's leaf kinds and refuses a model whose two answers disagree;
 - ``kv_token_bytes(kv_quant)``: the bytes one cached token costs one such
   layer. The engine divides a byte budget for the pool by
   ``page_size x kv_layers x kv_token_bytes`` and asks nothing about what a

@@ -485,6 +485,7 @@ class PagedInferenceEngine:
         spec_ngram: int = 3,
         proposer=None,
         prefill_budget: Optional[int] = None,
+        max_prefill_jobs: Optional[int] = None,
         tenants=None,
         clock=None,
     ):
@@ -501,6 +502,9 @@ class PagedInferenceEngine:
         if prefill_budget is not None and prefill_budget < 1:
             raise ValueError(
                 f"prefill_budget must be >= 1, got {prefill_budget}")
+        if max_prefill_jobs is not None and max_prefill_jobs < 1:
+            raise ValueError(
+                f"max_prefill_jobs must be >= 1, got {max_prefill_jobs}")
         base = cfg.serving_config()
         if spec_tokens + 1 >= base.max_seq_len:
             raise ValueError(
@@ -526,8 +530,22 @@ class PagedInferenceEngine:
             raise ValueError(
                 "kernel='pallas' reads float pools only; an int8 pool "
                 "is served by kernel='lax' (or 'auto')")
+        # a model none of whose cache leaves is a pool of pages
+        # (models/serving.py: ``kv_layers`` 0 and no window) has no block to
+        # size, no page table, and nothing to be admitted by but a free slot
+        self._pooled = bool(base.kv_layers) \
+            or getattr(base, "kv_window", None) is not None
+        if not self._pooled:
+            for name, given in (("kv_blocks", kv_blocks),
+                                ("kv_pool_bytes", kv_pool_bytes)):
+                if given is not None:
+                    raise ValueError(
+                        f"{name}: {type(base).__name__} keeps no page pool "
+                        f"(kv_layers 0: its cache is state a slot), so "
+                        f"there is nothing for it to size")
         self._page = page_size
-        self._pages_per_seq = base.max_seq_len // page_size
+        self._pages_per_seq = base.max_seq_len // page_size \
+            if self._pooled else 0
         self._kv_quant = kv_quant
         # kernel selection (docs/serving.md): "auto" is, on a TPU, the
         # kernels that compile there (Pallas) and the portable lax read
@@ -662,9 +680,11 @@ class PagedInferenceEngine:
                 page_size * self._kv_token_bytes))
         if kv_blocks is None:
             # dense-equivalent HBM by default (+1 scratch); pass less to
-            # overcommit, more to grow the prefix cache's working set
-            kv_blocks = slots * self._pages_per_seq + 1
-        if kv_blocks < 2:
+            # overcommit, more to grow the prefix cache's working set. No
+            # block at all, not even the scratch one, where no leaf is paged
+            kv_blocks = slots * self._pages_per_seq + 1 if self._pooled \
+                else 0
+        if kv_blocks < 2 and self._pooled:
             raise ValueError(f"kv_blocks must be >= 2, got {kv_blocks}")
         self._kv_blocks = kv_blocks
         if self._paged_kernel == "pallas" and not pallas_interpreted(None):
@@ -672,7 +692,8 @@ class PagedInferenceEngine:
             # lowering refuses of the model's kernels, its read of this
             # pool among them, it refuses here, before a pool exists
             base.check_kernels(
-                slots=slots, kv_blocks=kv_blocks, page_size=page_size,
+                slots=slots, kv_blocks=kv_blocks if self._pooled else None,
+                page_size=page_size,
                 pages_per_seq=self._pages_per_seq, kv_quant=kv_quant,
                 **({} if window is None
                    else {"window_blocks": kv_window_blocks}))
@@ -690,6 +711,11 @@ class PagedInferenceEngine:
         # _page_table_dev); every _tables mutation site sets it to None
         self._pt_dev = None
         self._slot_blocks: List[List[int]] = [[] for _ in range(slots)]
+        # with no pool there is no table to say which rows are live (a live
+        # row's first page is not the scratch block): the rows themselves,
+        # set when a prompt's state is spliced in, cleared when the slot is
+        # freed, uploaded where a table would be
+        self._live = None if self._pooled else np.zeros((slots,), np.int32)
         if self._win is not None:
             # the window kind's twin of ``_tables`` / ``_slot_blocks``; a
             # write to either table dirties the one device mirror
@@ -706,6 +732,12 @@ class PagedInferenceEngine:
         self._stat_counters: tuple = ()
         self._dispatch_paths: dict = {}   # positions a row -> path labels
         self._build_decode_path(base)
+        if self._pooled != any(k in serving.POOLS for k in self._leaf_kinds):
+            raise ValueError(
+                f"{type(base).__name__} says kv_layers {base.kv_layers} and "
+                f"its module's cache leaves are of kinds "
+                f"{sorted(set(self._leaf_kinds))}: a pool of pages has a "
+                f"layer that writes it, and the other way round")
         refusal = leaf_refusal(self._leaf_kinds)
         if refusal is not None:
             self._refuse_for_leaves(
@@ -737,6 +769,12 @@ class PagedInferenceEngine:
         self.prefill_budget = (None if prefill_budget is None
                                else int(prefill_budget))
         self._prefill_jobs: List[_PrefillJob] = []
+        # prompts staged at once at most (None: one a free slot). A job of a
+        # model with state leaves holds its own batch-1 row of every one
+        # until it is spliced in, so a burst of prompts holds as many copies
+        # of a slot's state as it has jobs: where a slot's state is large, a
+        # deployment bounds them here and the rest wait in the queue
+        self._max_prefill_jobs = max_prefill_jobs
         self._next_prefill = 0
         self.prefill_rounds = 0         # public: interleave observability
         # what the round's decode half did, for the engine.round span
@@ -982,8 +1020,6 @@ class PagedInferenceEngine:
             # DRAINING engine still finishes its in-flight rows but must
             # not take on new ones — the graceful-shutdown contract.
             raise AdmissionError("inference engine is shut down")
-        from lzy_tpu.serving.kv_cache import blocks_for
-
         prompt = list(prompt)
         if not prompt:
             raise ValueError("prompt must be non-empty")
@@ -991,18 +1027,18 @@ class PagedInferenceEngine:
         # cover: past submit they would park in the queue forever
         # (admission waits for blocks that cannot exist) and waste a
         # tenant's WFQ share on an unservable head
-        if blocks_for(len(prompt), self._page) > self._kv_blocks - 1:
+        need = self._blocks_for(len(prompt))
+        if need > max(self._kv_blocks - 1, 0):
             raise PromptTooLong(
                 f"prompt ({len(prompt)} tokens) needs "
-                f"{blocks_for(len(prompt), self._page)} KV blocks but the "
+                f"{need} KV blocks but the "
                 f"pool only has {self._kv_blocks - 1}; raise kv_blocks or "
                 f"shorten the prompt")
         quota = self._tenant_quota(tenant or "default")
-        if quota is not None \
-                and blocks_for(len(prompt), self._page) > quota:
+        if quota is not None and need > quota:
             raise PromptTooLong(
                 f"prompt ({len(prompt)} tokens) needs "
-                f"{blocks_for(len(prompt), self._page)} KV blocks but "
+                f"{need} KV blocks but "
                 f"tenant {tenant!r} is capped at {quota}; shorten the "
                 f"prompt or raise the tenant's kv_block_quota")
         if max_new_tokens < 1:
@@ -1177,7 +1213,11 @@ class PagedInferenceEngine:
             d[key] += n
 
     def _free_slot(self) -> Optional[int]:
-        """A slot neither active nor reserved by a pending prefill job."""
+        """A slot neither active nor reserved by a pending prefill job;
+        none while ``max_prefill_jobs`` prompts are staged."""
+        if self._max_prefill_jobs is not None \
+                and len(self._prefill_jobs) >= self._max_prefill_jobs:
+            return None
         reserved = {job.slot for job in self._prefill_jobs}
         for slot, req in enumerate(self._active):
             if req is None and slot not in reserved:
@@ -1992,6 +2032,8 @@ class PagedInferenceEngine:
         blocks = self._slot_blocks[slot]
         self._slot_blocks[slot] = []
         self._tables[slot, :] = 0
+        if self._live is not None:
+            self._live[slot] = 0
         self._pt_dev = None
         self._admit_seq[slot] = 0
         self.kv.release(blocks)
@@ -2353,7 +2395,7 @@ class PagedInferenceEngine:
         none."""
         self._stat_counters = tuple(type(self._model).STATS)
         has_state, has_stats = self._has_state, bool(self._stat_counters)
-        tells_real = self._tells_real
+        tells_real, pooled = self._tells_real, self._pooled
         mutable = ["cache", "stats"] if has_stats else ["cache"]
 
         def prefill_step(pool, state, job, params, key, window_table=None,
@@ -2381,8 +2423,14 @@ class PagedInferenceEngine:
                 page_table, window_table = page_table
                 tables = {"page_table": page_table,
                           "window_table": window_table}
-            real = {"valid_len": (page_table[:, 0] != 0).astype(jnp.int32)} \
-                if tells_real else {}
+            if not pooled:
+                # no pool, no table: what arrives in its place is the rows
+                # that are live (``_live``), 1 or 0 a slot
+                tables, real = {}, {"valid_len": page_table}
+            else:
+                real = {"valid_len":
+                        (page_table[:, 0] != 0).astype(jnp.int32)} \
+                    if tells_real else {}
             logits, updated = self._model.apply(
                 {"params": params, "cache": cache}, cur[:, None],
                 mutable=mutable, **tables, **real)
@@ -2526,6 +2574,13 @@ class PagedInferenceEngine:
 
     # -- admission / prefill -------------------------------------------------
 
+    def _blocks_for(self, n_tokens: int) -> int:
+        """Blocks of the paged pool that ``n_tokens`` cache positions
+        take: none where the model keeps no pool."""
+        from lzy_tpu.serving.kv_cache import blocks_for
+
+        return blocks_for(n_tokens, self._page) if self._pooled else 0
+
     def _tenant_quota(self, tenant: str) -> Optional[int]:
         if self.tenants is None:
             return None
@@ -2550,14 +2605,12 @@ class PagedInferenceEngine:
         conservatively — it may or may not already be pinned by another
         request). Decode growth beyond the prompt is overcommitted and
         backstopped by eviction + youngest-preemption."""
-        from lzy_tpu.serving.kv_cache import blocks_for
-
         # drain queued KV imports at the admission gate: a submit can
         # land mid-step (after the top-of-loop drain but before _admit
         # pops it), and its staged import must be resident before the
         # prefill's prefix match runs. No-op when the queue is empty.
         self.kv_io.apply_imports()
-        need = blocks_for(len(req.prompt), self._page)
+        need = self._blocks_for(len(req.prompt))
         # parked tool-gap chains yield to live admissions: shed them
         # (soonest expiry first) before making anyone wait
         self.kv_io.shed_parked(need)
@@ -2575,18 +2628,14 @@ class PagedInferenceEngine:
         tenant's latency). Tenant KV quota first (a tenant AT its quota
         is skipped: its blocks free as its own requests finish), then
         the global pool budget (a genuine capacity wait)."""
-        from lzy_tpu.serving.kv_cache import blocks_for
-
         quota = self._tenant_quota(req.tenant)
         if quota is not None:
-            need = blocks_for(len(req.prompt), self._page)
+            need = self._blocks_for(len(req.prompt))
             if self._tenant_block_usage(req.tenant) + need > quota:
                 return "skip"
         return "admit" if self._can_admit(req) else "wait"
 
     def _stage_prefill(self, slot: int, req: Request) -> _PrefillJob:
-        from lzy_tpu.serving.kv_cache import blocks_for
-
         prompt = req.prompt
         t0 = len(prompt)
         # tier promotion FIRST: chains that aged out of HBM (or arrived
@@ -2613,8 +2662,7 @@ class PagedInferenceEngine:
         # bucket_width/page blocks per short request
         evicted = self.kv.evictions
         try:
-            owned = self.kv.allocate(blocks_for(t0, self._page)
-                                     - len(blocks))
+            owned = self.kv.allocate(self._blocks_for(t0) - len(blocks))
         except Exception:
             self.kv.release(blocks)   # roll back the match refs
             raise
@@ -2707,6 +2755,8 @@ class PagedInferenceEngine:
             self.kv.insert(req.prompt[:n_full * self._page], table[:n_full])
         self._tables[slot, :len(table)] = table
         self._tables[slot, len(table):] = 0
+        if self._live is not None:
+            self._live[slot] = 1
         self._pt_dev = None
         self._slot_blocks[slot] = list(table)
         if job.window is not None:
@@ -2768,6 +2818,8 @@ class PagedInferenceEngine:
         a block some other in-flight request references."""
         from lzy_tpu.serving.kv_cache import NoFreeBlocks
 
+        if not self._pooled:
+            return              # a row grows no page: its state is its slot's
         # positions moved at the last dispatch, so this needs no token of
         # a round in flight; a row that ends with that token grows nothing
         # (its over-run write lands on the scratch block past its blocks)
@@ -2845,8 +2897,13 @@ class PagedInferenceEngine:
         ``_tables`` buffer and later host writes would mutate the
         device view mid-flight."""
         if self._pt_dev is None:
-            self._pt_dev = jnp.array(self._tables) if self._win is None \
-                else (jnp.array(self._tables), jnp.array(self._win_tables))
+            if not self._pooled:
+                self._pt_dev = jnp.array(self._live)    # no table: live rows
+            elif self._win is None:
+                self._pt_dev = jnp.array(self._tables)
+            else:
+                self._pt_dev = (jnp.array(self._tables),
+                                jnp.array(self._win_tables))
         return self._pt_dev
 
     def _count_dispatch(self, t: int) -> None:
@@ -2905,6 +2962,8 @@ class PagedInferenceEngine:
         pos)`` for verify."""
         pt = jax.ShapeDtypeStruct((self.slots, self._pages_per_seq),
                                   jnp.int32)
+        if not self._pooled:
+            pt = jax.ShapeDtypeStruct((self.slots,), jnp.int32)
         if self._win is not None:
             pt = (pt, pt)
         step.lower(payload, self.params, *mids, pt, mask, rng).compile()

@@ -111,7 +111,9 @@ class BlockPool:
     """
 
     def __init__(self, n_blocks: int, page_size: int):
-        if n_blocks < 2:
+        # 0: the pool of a model that keeps no paged leaf at all
+        # (models/serving.py): no block, not even the scratch one
+        if n_blocks < 2 and n_blocks != 0:
             raise ValueError(
                 f"pool needs >= 2 blocks (1 scratch + 1 usable), got "
                 f"{n_blocks}")
@@ -573,7 +575,7 @@ class RadixCache:
 
     def stats(self) -> KVCacheStats:
         return KVCacheStats(
-            blocks_total=self.pool.n_blocks - 1,    # scratch excluded
+            blocks_total=max(0, self.pool.n_blocks - 1),  # less scratch
             blocks_free=self.pool.free_count(),
             blocks_cached=self._cached,
             evictions=self.evictions,

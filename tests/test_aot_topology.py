@@ -1026,6 +1026,90 @@ def test_minicpm_sala_kernels_compile_at_the_cells_shapes(case, one_chip,
     assert _parameter_copies(text, params) == []
 
 
+@pytest.mark.parametrize("case", ["kernel", "decode", "prefill"])
+def test_brumby_programs_compile_at_the_cells_shapes(case, one_chip,
+                                                     monkeypatch, capsys):
+    """Brumby-14B-Base at its published widths as ``longgen-steady`` runs
+    it: the update kernel alone at 16 rows of 8 key-value heads of 8,256 x
+    128 (65 tiles of 128, thirteen a grid step), and two layers of the
+    model: the decode round of 16 slots (``power_retention_update``) and the
+    prefill chunk of 256 (two chunks of the scan). **The round holds one
+    copy of the state**: every ``S`` and ``z`` is aliased to its output
+    through the kernel, and the temporaries stay far under one layer's
+    state (550 MB)."""
+    from lzy_tpu.models import brumby
+    from lzy_tpu.ops import interpret
+    from lzy_tpu.ops import power_retention as pr
+
+    # the kernels as the chip compiles them, whatever conftest.py asked for
+    monkeypatch.setattr(interpret, "_process_wide", False)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slots, h, kv, d = 16, 40, 8, 128
+    s_shape, z_shape = pr.state_shapes(slots, kv, d)
+    assert s_shape == (16, 8, 65, 128, 128) and z_shape == (16, 8, 5, 13,
+                                                             128)
+    assert pr.n_features(d) == 8256
+    layer_state = 4 * (16 * 8 * 65 * 128 * 128 + 16 * 8 * 65 * 128)
+    if case == "kernel":
+        pr.lower_update_for_tpu(batch=slots, heads=h, kv_heads=kv,
+                                head_dim=d, dtype=jnp.bfloat16)
+        bf = jnp.bfloat16
+        compiled = jax.jit(
+            lambda s, z, q, k, v, g, live: pr.retention_state_update(
+                s, z, q, k, v, g, live, interpret=False),
+            donate_argnums=(0, 1)).lower(
+            sds(s_shape), sds(z_shape), sds((slots, h, d), bf),
+            sds((slots, kv, d), bf), sds((slots, kv, d), bf),
+            sds((slots, kv)), sds((slots,), jnp.bool_)).compile()
+        assert pr.UPDATE_KERNEL in compiled.as_text()
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == layer_state
+        assert memory.temp_size_in_bytes < 1 << 20
+        return
+    cfg = brumby.BrumbyConfig(n_layers=2)
+    batch, t = (slots, 1) if case == "decode" else (1, 256)
+    model = cfg.paged_model(page_size=64, kv_pages=0, kernel="pallas",
+                            kv_quant=None)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: sds(s.shape, s.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: brumby.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((batch, 1), jnp.int32)))["cache"])
+
+    def step(params, cache, toks, valid):
+        logits, upd = model.apply(
+            {"params": params, "cache": cache}, toks, valid_len=valid,
+            mutable=["cache", "stats"])
+        return jnp.argmax(logits[:, -1], -1), upd["cache"], \
+            upd.get("stats", {})
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((batch, t), jnp.int32),
+        sds((batch,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert (pr.UPDATE_KERNEL in text) == (case == "decode")
+    memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nbrumby {case} step at the cell's shapes, two layers: "
+              f"arguments {memory.argument_size_in_bytes:,} bytes, aliased "
+              f"{memory.alias_size_in_bytes:,}, temporaries "
+              f"{memory.temp_size_in_bytes:,} bytes")
+    state = 2 * layer_state * batch // slots
+    # both layers' S and z (and the two index leaves) come back in the
+    # buffers they arrived in
+    assert state <= memory.alias_size_in_bytes < state + 4096
+    if case == "decode":
+        assert memory.temp_size_in_bytes < layer_state // 4
+    assert _parameter_copies(text, params) == []
+
+
 # -- no serving program copies a weight matrix --------------------------------
 
 def _parameter_copies(text, params, least=1_000_000):

@@ -60,6 +60,8 @@ def registry_metrics():
     # a model that chooses its key blocks: blocks visible and read, rows
     # that chose and rows served densely, rows whose lightning state moved
     import lzy_tpu.models.minicpm_sala  # noqa: F401
+    # a model of power-retention layers: rows whose state a round moved
+    import lzy_tpu.models.brumby  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
