@@ -180,12 +180,15 @@ class BrumbyConfig:
                       page_size: Optional[int] = None,
                       pages_per_seq: Optional[int] = None,
                       kv_quant: Optional[str] = None) -> None:
-        """Lower the update kernel for a TPU at the decode step's shapes (no
-        device, no compile): refused here, not at the first request."""
+        """Lower both kernels for a TPU (no device, no compile), the update
+        at the decode step's shapes and the chunk scan at one row of the
+        widest program: refused here, not at the first request."""
         self._refuse_quant(kv_quant)
-        retention.lower_update_for_tpu(
-            batch=slots, heads=self.n_heads, kv_heads=self.n_kv_heads,
-            head_dim=self.head_dim, dtype=self.dtype)
+        heads = dict(heads=self.n_heads, kv_heads=self.n_kv_heads,
+                     head_dim=self.head_dim, dtype=self.dtype)
+        retention.lower_update_for_tpu(batch=slots, **heads)
+        retention.lower_chunk_for_tpu(
+            batch=1, t=self.widest_prefill, chunk=self.chunk_size, **heads)
 
     @staticmethod
     def tiny(vocab_size: int = 256) -> "BrumbyConfig":

@@ -26,12 +26,12 @@ circular distance ``m`` once). In the last tile (``m = d / 2``) the entries
 ``i >= d / 2`` would name their pairs a second time and **are zeros**: 64 of
 8,320 at ``d`` = 128, the padded tail. So a tile is the vector times a
 rotation of itself: a kernel makes it from ``d`` numbers with one lane
-rotation, and never reads ``phi`` from HBM. The state of a key-value head is
+rotation, and never reads ``phi`` from HBM (nor writes it). The state of a key-value head is
 ``S`` ``[tiles, d (of v), d (of the tile)]`` and ``z`` ``[tiles, d]``, both
 float32 whatever the activations are: they are carried over thousands of
 tokens, and a bfloat16 state is a different configuration
 (``ops/mamba2.py``). ``z`` is stored ``[steps, tiles a step, d]``
-(:func:`state_shapes`): the update kernel walks a head's tiles a step at a
+(:func:`state_shapes`): both kernels walk a head's tiles a step at a
 time.
 
 **The products** of ``phi(q)`` with ``S`` take both rounded to the type ``q``
@@ -41,11 +41,21 @@ product and every sum are float32 (a product of two bfloat16 numbers is exact
 there).
 
 - :func:`retention_chunk_scan`: ``T`` positions in chunks of ``chunk``
-  positions: inside a chunk the masked, decay-weighted squared scores against
-  the chunk's own keys; across chunks ``phi(q) S`` and ``phi(q) z`` scaled by
-  the decays since the chunk's start; then the state's own update. Plain
-  ``jax.numpy`` under the scope :data:`CHUNK_SCOPE`. A position that is not
-  ``real`` leaves ``S`` and ``z`` as they were.
+  positions, a Pallas kernel (:data:`CHUNK_KERNEL` in a device trace) that
+  takes :data:`CHUNKS_A_CALL` chunks a call: 256 positions, the widest
+  prefill program. Its grid walks (row, key-value head, step of
+  :func:`tiles_a_step` tiles); ``S`` and ``z`` pass through VMEM once a
+  call, in place, and while a tile is resident every chunk of the call is
+  served: the chunk's queries read the tile (``phi(q) S`` and ``phi(q) z``,
+  the group's query heads folded into the rows of one product), then its
+  keys move it (``exp(cs_C) S + phi(k)^T (tail v)``, one float32 product at
+  full precision whose last row is ``z``'s). ``phi`` of the queries and the
+  keys is made a tile at a time, the rows times a lane rotation of
+  themselves, and never written. The last step adds the chunk's own masked,
+  decay-weighted squared scores and divides. What a chunk's decays make
+  (``cs``, the mask, ``grow``, ``tail``, ``total``: a few numbers a
+  position) is plain ``jax.numpy`` before the kernel. A position that is
+  not ``real`` leaves ``S`` and ``z`` as they were.
 - :func:`retention_state_update`: one position for every row of a decode
   batch, a Pallas kernel (:data:`UPDATE_KERNEL` in a device trace) that reads
   and writes each live row's ``S`` and ``z`` once, in place, and returns the
@@ -72,12 +82,11 @@ from jax.experimental.pallas import tpu as pltpu
 from lzy_tpu.ops import interpret as _interpret
 
 #: ``lzy_kernel_dispatch_total{path}`` labels of the two programs
-SCAN_PATH = "retention_chunk_lax"
+SCAN_PATH = "retention_chunk_pallas"
 UPDATE_PATH = "retention_update_pallas"
-#: the update kernel's name in a device trace
+#: the two kernels' names in a device trace
 UPDATE_KERNEL = "power_retention_update"
-#: the scope the chunked scan's operations are traced under
-CHUNK_SCOPE = "power_retention_chunk"
+CHUNK_KERNEL = "power_retention_chunk"
 
 _HI = lax.Precision.HIGHEST
 _SQRT2 = math.sqrt(2.0)
@@ -94,7 +103,7 @@ def n_features(d: int) -> int:
 
 
 def tiles_a_step(d: int) -> int:
-    """Tiles the update kernel takes a grid step: the largest divisor of
+    """Tiles a kernel takes a grid step: the largest divisor of
     :func:`n_tiles` up to 16 (13 of 65 at ``d`` = 128: 832 KiB of ``S``)."""
     m = n_tiles(d)
     return max(t for t in range(1, min(m, 16) + 1) if m % t == 0)
@@ -117,92 +126,192 @@ def _weights(d: int) -> np.ndarray:
 
 
 def phi(x: jax.Array) -> jax.Array:
-    """``[..., d]`` -> ``[..., tiles, d]`` float32, in this file's layout.
-    Tile ``m`` is ``x`` times ``x`` rotated by ``m``. The rotations of all
-    tiles are ONE product with a constant 0/1 tensor on the matrix unit
-    (each entry of the result has one term, so it is exact in any type): a
-    gather along the lanes is slow on a TPU, and a stack of 65 slices was
-    65 operations a call, 25 of the 41 ms of a 256-wide prefill program
-    (PERF.md section 6, PR 56)."""
-    d = x.shape[-1]
-    shape = (d, n_tiles(d), d)
-    j, m, i = (lax.broadcasted_iota(jnp.int32, shape, n) for n in range(3))
-    turn = (j == (i - m + d) % d).astype(x.dtype)
-    turned = jnp.einsum(
-        "...j,jmi->...mi", x, turn, preferred_element_type=jnp.float32,
-        precision=_HI if x.dtype == jnp.float32 else None)
-    return x.astype(jnp.float32)[..., None, :] * turned * _weights(d)
+    """``[..., d]`` -> ``[..., tiles, d]`` float32, in this file's layout:
+    tile ``m`` is ``x`` times ``x`` rotated by ``m``, weighted. The layout
+    written out for the tests' oracles and the benchmark's reference to be
+    held to; the kernels make a tile from the rows they hold and never call
+    this."""
+    x = x.astype(jnp.float32)
+    return jnp.stack([x * jnp.roll(x, m, -1)
+                      for m in range(n_tiles(x.shape[-1]))],
+                     axis=-2) * _weights(x.shape[-1])
 
 
-# -- prefill: T positions in chunks -------------------------------------------
+# -- prefill: T positions in chunks, the state through VMEM once a call -------
+
+#: chunks one call of the chunk kernel serves while a tile of the state is
+#: resident (256 positions at chunks of 128: the widest prefill program)
+CHUNKS_A_CALL = 2
+#: rows under ``v^T`` in the update's left operand: the decays' own row (it
+#: makes ``z``'s update), up to a whole sublane tile
+_Z_ROWS = 8
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _chunk_kernel(q_ref, k_ref, v_ref, vt_ref, w_ref, grow_ref, total_ref,
+                  wt_ref, s_ref, z_ref, o_y, o_s, o_z, num_ref, den_ref, *,
+                  tiles: int, group: int, pd, eps: float):
+    """One (row, key-value head, step of ``tiles`` tiles), every chunk of the
+    call. ``q`` [chunks, G x C, d] (the group's heads folded into the rows,
+    head-major) and ``k`` [chunks, C, d], float32 copies of numbers of type
+    ``pd``: ``x * roll(x, m) * w_m`` is tile ``m`` of ``phi`` of every row at
+    once (``wt`` holds ``w_m``: 1, ``sqrt(2)``, and the zeros of the padded
+    tail). While a tile of ``S`` is resident each chunk's queries read it and
+    its keys then move it; ``num`` / ``den`` [chunks, G x C, d] carry the
+    queries' sums over the steps (``den`` a lane: summed at the end). The
+    last step adds the chunk's own masked, decay-weighted squared scores
+    (``w`` [chunks, C, C]) and divides."""
+    step = pl.program_id(2)
+    chunks, c, d = k_ref.shape[2:]
+    f32 = jnp.float32
+    prec = _HI if pd == f32 else None
+
+    @pl.when(step == 0)
+    def _():
+        num_ref[...] = jnp.zeros(num_ref.shape, f32)
+        den_ref[...] = jnp.zeros(den_ref.shape, f32)
+
+    for t in range(tiles):
+        m = step * tiles + t
+        w_m = wt_ref[0, t:t + 1, :]                    # [1, d]
+        s_m, z_m = s_ref[0, 0, t], z_ref[0, 0, 0, t:t + 1, :]
+        for ch in range(chunks):
+            q32, k32 = q_ref[0, 0, ch], k_ref[0, 0, ch]
+            pq = (q32 * pltpu.roll(q32, m, 1) * w_m).astype(pd)
+            num_ref[ch] += lax.dot_general(
+                pq, s_m.astype(pd), _NT, precision=prec,
+                preferred_element_type=f32)
+            # the normaliser's product reads the same rounded phi(q)
+            den_ref[ch] += pq.astype(f32) * z_m
+            pk = k32 * pltpu.roll(k32, m, 1) * w_m
+            moved = jnp.dot(vt_ref[0, 0, ch], pk, precision=_HI,
+                            preferred_element_type=f32)    # [d + 8, d]
+            total = total_ref[0, 0, ch]                # [1, d]
+            s_m = total * s_m + moved[:d]
+            z_m = total * z_m + moved[d:d + 1]
+        o_s[0, 0, t] = s_m
+        o_z[0, 0, 0, t:t + 1, :] = z_m
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _():
+        for ch in range(chunks):
+            keys, grow = k_ref[0, 0, ch].astype(pd), grow_ref[0, 0, ch]
+            for g in range(group):
+                rows = slice(g * c, (g + 1) * c)
+                sc = lax.dot_general(
+                    q_ref[0, 0, ch, rows, :].astype(pd), keys, _NT,
+                    precision=prec, preferred_element_type=f32)
+                a = w_ref[0, 0, ch] * sc * sc          # [C of t, C of s]
+                num = jnp.dot(a.astype(pd), v_ref[0, 0, ch], precision=prec,
+                              preferred_element_type=f32) \
+                    + grow * num_ref[ch, rows, :]
+                den = jnp.sum(a, -1, keepdims=True) + grow * jnp.sum(
+                    den_ref[ch, rows, :], -1, keepdims=True)
+                o_y[0, 0, ch, rows, :] = num / (den + d * eps)
+
+
+@functools.partial(jax.jit, static_argnames=("c", "eps", "interpret"))
+def _pallas_chunk(s, z, q, k, v, log_g, real, wt, *, c: int, eps: float,
+                  interpret: bool):
+    """Whole chunks of ``c`` positions, :data:`CHUNKS_A_CALL` at most:
+    ``q`` [B, chunks x c, H, d] and the rest as :func:`retention_chunk_scan`
+    takes them, ``wt`` the tiles' weights (:func:`_weights`). What a chunk's
+    decays make (its mask ``w``, ``grow``, ``tail`` and ``total`` of the
+    module's equations) is a few numbers a position and made here;
+    everything with a feature in it is the kernel's."""
+    b, t, h, d = q.shape
+    kv, g, chunks = k.shape[2], h // k.shape[2], t // c
+    steps, tiles = z.shape[2], z.shape[3]
+    f32, pd = jnp.float32, jnp.dtype(q.dtype)
+
+    def heads_first(x):                # [B, T, KV, ..] -> [B, KV, chunks, c, ..]
+        x = x.reshape(b, chunks, c, kv, *x.shape[3:])
+        return jnp.moveaxis(x, 3, 1)
+
+    on = real.reshape(b, 1, chunks, c)
+    cs = jnp.cumsum(heads_first(jnp.where(
+        real[..., None], log_g.astype(f32), 0.0)), axis=-1)    # through t
+    # a_ts carries exp(cs_t - cs_s), s <= t; the masked entries have a
+    # positive exponent: zero them before exp
+    keep = (jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]) \
+        & on[..., None, :]
+    w = jnp.where(keep, jnp.exp(jnp.where(
+        keep, cs[..., :, None] - cs[..., None, :], 0.0)), 0.0)
+    grow = jnp.exp(cs)[..., None]
+    tail = jnp.where(on, jnp.exp(cs[..., -1:] - cs), 0.0)[..., None, :]
+    total = jnp.broadcast_to(jnp.exp(cs[..., -1])[..., None, None],
+                             (b, kv, chunks, 1, d))
+    vs = heads_first(v)
+    vt = jnp.concatenate([
+        jnp.swapaxes(vs.astype(f32), -1, -2) * tail, tail,
+        jnp.zeros((b, kv, chunks, _Z_ROWS - 1, c), f32)], axis=-2)
+    qs = jnp.moveaxis(heads_first(q.astype(f32).reshape(b, t, kv, g, d)),
+                      4, 3).reshape(b, kv, chunks, g * c, d)
+    ks = heads_first(k.astype(f32))
+
+    def a_head(*block):
+        return pl.BlockSpec((1, 1) + block,
+                            lambda i, j, n: (i, j) + (0,) * len(block))
+
+    s_spec = pl.BlockSpec((1, 1, tiles, d, d), lambda i, j, n: (i, j, n, 0, 0))
+    z_spec = pl.BlockSpec((1, 1, 1, tiles, d), lambda i, j, n: (i, j, n, 0, 0))
+    y, s, z = pl.pallas_call(
+        functools.partial(_chunk_kernel, tiles=tiles, group=g, pd=pd,
+                          eps=eps),
+        grid=(b, kv, steps),
+        in_specs=[a_head(chunks, g * c, d), a_head(chunks, c, d),
+                  a_head(chunks, c, d), a_head(chunks, d + _Z_ROWS, c),
+                  a_head(chunks, c, c), a_head(chunks, c, 1),
+                  a_head(chunks, 1, d),
+                  pl.BlockSpec((1, tiles, d), lambda i, j, n: (n, 0, 0)),
+                  s_spec, z_spec],
+        out_specs=[a_head(chunks, g * c, d), s_spec, z_spec],
+        out_shape=[jax.ShapeDtypeStruct((b, kv, chunks, g * c, d), f32),
+                   jax.ShapeDtypeStruct(s.shape, f32),
+                   jax.ShapeDtypeStruct(z.shape, f32)],
+        scratch_shapes=[pltpu.VMEM((chunks, g * c, d), f32)] * 2,
+        input_output_aliases={8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=CHUNK_KERNEL,
+    )(qs, ks, vs, vt, w, grow, total, wt.reshape(steps, tiles, d), s, z)
+    y = jnp.moveaxis(y.reshape(b, kv, chunks, g, c, d), (1, 3), (3, 4))
+    return y.reshape(b, t, h, d), s, z
+
 
 def retention_chunk_scan(q: jax.Array, k: jax.Array, v: jax.Array,
                          log_g: jax.Array, s: jax.Array, z: jax.Array,
                          real: Optional[jax.Array] = None, *,
-                         chunk: int = 128, eps: float = 1e-6):
+                         chunk: int = 128, eps: float = 1e-6,
+                         interpret: Optional[bool] = None):
     """``q`` [B, T, H, d], ``k`` / ``v`` [B, T, KV, d] (the products take
     them in ``q``'s type), ``log_g`` [B, T, KV] (<= 0), ``s`` / ``z`` as
     :func:`state_shapes` says (float32), ``real`` [B, T] bool (a position
     that is not leaves the state as it was and adds nothing to a later
     query). Returns ``(y [B, T, H, d] float32, S, z)``. ``T`` is cut into
-    chunks of ``chunk`` positions (the last may be shorter)."""
-    b, t, h, d = q.shape
-    kv = k.shape[2]
+    chunks of ``chunk`` positions, :data:`CHUNKS_A_CALL` a call of the
+    kernel; fewer than ``chunk`` are one short chunk, and the last chunk is
+    filled up with positions that are not real."""
+    b, t = q.shape[:2]
     if real is None:
         real = jnp.ones((b, t), bool)
-    qg = q.reshape(b, t, kv, h // kv, d)
-    log_g = log_g.astype(jnp.float32)
-    flat = z.reshape(b, kv, n_tiles(d), d)
+    c = chunk if t > chunk else -(-t // 8) * 8
+    # made here: inside the jitted call the table would be a constant of
+    # its cached trace
+    wt = jnp.asarray(_weights(q.shape[-1]))
+    q, k, v, log_g, real = (
+        jnp.pad(x, ((0, 0), (0, -t % c)) + ((0, 0),) * (x.ndim - 2))
+        for x in (q, k, v, log_g, real))
     ys = []
-    with jax.named_scope(CHUNK_SCOPE):
-        for start in range(0, t, chunk):
-            sl = slice(start, min(start + chunk, t))
-            y, s, flat = _one_chunk(qg[:, sl], k[:, sl], v[:, sl],
-                                    log_g[:, sl], real[:, sl], s, flat, eps)
-            ys.append(y)
-        y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
-    return y.reshape(b, t, h, d), s, flat.reshape(z.shape)
-
-
-def _one_chunk(q, k, v, lg, real, s, z, eps):
-    c, d = q.shape[1], q.shape[-1]
-    f32, pd = jnp.float32, q.dtype
-    # float32 products (the CPU tests) at full precision
-    prec = _HI if pd == f32 else None
-    lg = jnp.where(real[..., None], lg, 0.0)           # [B, C, KV]
-    cs = jnp.cumsum(lg, axis=1)                        # through position t
-    # inside the chunk: a_ts = exp(cs_t - cs_s) (q_t . k_s)^2, s <= t
-    sc = jnp.einsum("btkgd,bskd->bkgts", q, k, precision=prec,
-                    preferred_element_type=f32)
-    cst = cs.transpose(0, 2, 1)
-    seg = cst[:, :, :, None] - cst[:, :, None, :]      # [B, KV, t, s]
-    keep = (jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]) \
-        & real[:, None, None, :]
-    # the masked entries have a positive exponent: zero them before exp
-    w = jnp.where(keep, jnp.exp(jnp.where(keep, seg, 0.0)), 0.0)
-    a = w[:, :, None] * sc * sc                        # [B, KV, G, t, s]
-    num = jnp.einsum("bkgts,bskd->btkgd", a.astype(pd), v, precision=prec,
-                     preferred_element_type=f32)
-    den = a.sum(-1).transpose(0, 3, 1, 2)              # [B, t, KV, G]
-    # what the carried state adds, decayed since the chunk's start
-    pq = phi(q).astype(pd)                             # [B, C, KV, G, M, d]
-    grow = jnp.exp(cs)
-    num = num + grow[..., None, None] * jnp.einsum(
-        "btkgmi,bkmvi->btkgv", pq, s.astype(pd), precision=prec,
-        preferred_element_type=f32)
-    # the normaliser's product reads the same rounded phi(q), in float32
-    den = den + grow[..., None] * jnp.einsum(
-        "btkgmi,bkmi->btkg", pq.astype(f32), z, precision=_HI)
-    # the state after the chunk
-    tail = jnp.where(real[..., None], jnp.exp(cs[:, -1:] - cs), 0.0)
-    pk = phi(k)                                        # [B, C, KV, M, d]
-    total = jnp.exp(cs[:, -1])                         # [B, KV]
-    s = total[:, :, None, None, None] * s + jnp.einsum(
-        "bskmi,bskv->bkmvi", pk, v.astype(f32) * tail[..., None],
-        precision=_HI)
-    z = total[:, :, None, None] * z + jnp.einsum(
-        "bskmi,bsk->bkmi", pk, tail, precision=_HI)
-    return num / (den[..., None] + d * eps), s, z
+    for start in range(0, q.shape[1], CHUNKS_A_CALL * c):
+        sl = slice(start, start + CHUNKS_A_CALL * c)
+        y, s, z = _pallas_chunk(
+            s, z, q[:, sl], k[:, sl], v[:, sl], log_g[:, sl], real[:, sl],
+            wt, c=c, eps=eps, interpret=_interpret.resolve(interpret))
+        ys.append(y)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
+    return y[:, :t], s, z
 
 
 # -- decode: one position a row, in place -------------------------------------
@@ -342,6 +451,23 @@ def retention_state_update(s: jax.Array, z: jax.Array, q: jax.Array,
     y = num[:, :, 2:2 + g] / (
         jnp.sum(den[:, :, 2:2 + g], axis=-1, keepdims=True) + d * eps)
     return jnp.where(on, y.reshape(b, h, d), 0.0), s, z
+
+
+def lower_chunk_for_tpu(*, batch: int, t: int, heads: int, kv_heads: int,
+                        head_dim: int, chunk: int, dtype) -> None:
+    """Lower the chunk kernel for a TPU at a program of ``t`` positions a
+    row with no device, and let the lowering's error out."""
+    sds = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    s_shape, z_shape = state_shapes(batch, kv_heads, head_dim)
+    jax.jit(functools.partial(retention_chunk_scan, chunk=chunk,
+                              interpret=False)).trace(
+        sds((batch, t, heads, head_dim), dtype),
+        sds((batch, t, kv_heads, head_dim), dtype),
+        sds((batch, t, kv_heads, head_dim), dtype),
+        sds((batch, t, kv_heads), f32), sds(s_shape, f32), sds(z_shape, f32),
+        sds((batch, t), jnp.bool_),
+    ).lower(lowering_platforms=("tpu",))
 
 
 def lower_update_for_tpu(*, batch: int, heads: int, kv_heads: int,

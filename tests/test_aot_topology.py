@@ -1030,13 +1030,15 @@ def test_minicpm_sala_kernels_compile_at_the_cells_shapes(case, one_chip,
 def test_brumby_programs_compile_at_the_cells_shapes(case, one_chip,
                                                      monkeypatch, capsys):
     """Brumby-14B-Base at its published widths as ``longgen-steady`` runs
-    it: the update kernel alone at 16 rows of 8 key-value heads of 8,256 x
-    128 (65 tiles of 128, thirteen a grid step), and two layers of the
+    it: the two kernels alone (the update at 16 rows of 8 key-value heads of
+    8,256 x 128: 65 tiles of 128, thirteen a grid step; the chunk scan at
+    every width the engine builds for one row), and two layers of the
     model: the decode round of 16 slots (``power_retention_update``) and the
-    prefill chunk of 256 (two chunks of the scan). **The round holds one
-    copy of the state**: every ``S`` and ``z`` is aliased to its output
-    through the kernel, and the temporaries stay far under one layer's
-    state (550 MB)."""
+    prefill chunk of 256 (``power_retention_chunk``, two chunks a call).
+    **A program holds one copy of the state**: every ``S`` and ``z`` is
+    aliased to its output through the kernel, and the temporaries stay far
+    under one layer's state (550 MB); ``phi`` of a prefill chunk (170 MB a
+    layer before the kernel) is never written."""
     from lzy_tpu.models import brumby
     from lzy_tpu.ops import interpret
     from lzy_tpu.ops import power_retention as pr
@@ -1068,6 +1070,20 @@ def test_brumby_programs_compile_at_the_cells_shapes(case, one_chip,
         memory = compiled.memory_analysis()
         assert memory.alias_size_in_bytes == layer_state
         assert memory.temp_size_in_bytes < 1 << 20
+        for t in (8, 16, 32, 64, 128, 256):
+            pr.lower_chunk_for_tpu(batch=1, t=t, heads=h, kv_heads=kv,
+                                   head_dim=d, chunk=128, dtype=bf)
+        one_s, one_z = pr.state_shapes(1, kv, d)
+        compiled = jax.jit(
+            lambda s, z, q, k, v, g, real: pr.retention_chunk_scan(
+                q, k, v, g, s, z, real, interpret=False),
+            donate_argnums=(0, 1)).lower(
+            sds(one_s), sds(one_z), sds((1, 256, h, d), bf),
+            sds((1, 256, kv, d), bf), sds((1, 256, kv, d), bf),
+            sds((1, 256, kv)), sds((1, 256), jnp.bool_)).compile()
+        assert pr.CHUNK_KERNEL in compiled.as_text()
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            == layer_state // slots
         return
     cfg = brumby.BrumbyConfig(n_layers=2)
     batch, t = (slots, 1) if case == "decode" else (1, 256)
@@ -1095,6 +1111,7 @@ def test_brumby_programs_compile_at_the_cells_shapes(case, one_chip,
         sds((batch,), jnp.int32)).compile()
     text = compiled.as_text()
     assert (pr.UPDATE_KERNEL in text) == (case == "decode")
+    assert (pr.CHUNK_KERNEL in text) == (case == "prefill")
     memory = compiled.memory_analysis()
     with capsys.disabled():
         print(f"\nbrumby {case} step at the cell's shapes, two layers: "
@@ -1107,6 +1124,12 @@ def test_brumby_programs_compile_at_the_cells_shapes(case, one_chip,
     assert state <= memory.alias_size_in_bytes < state + 4096
     if case == "decode":
         assert memory.temp_size_in_bytes < layer_state // 4
+    else:
+        # the chunk's float32 logits and 2 MB: phi(q) of one chunk alone
+        # (640 rows of 8,320 features a head) would be 170 MB a layer
+        # (219 MB of temporaries before the kernel: PERF.md section 6)
+        logits = t * cfg.vocab_size * 4
+        assert memory.temp_size_in_bytes < logits + (8 << 20)
     assert _parameter_copies(text, params) == []
 
 

@@ -1,8 +1,8 @@
 """Power retention (``ops/power_retention.py``) at tiny sizes on the CPU: the
 token-by-token recurrence, the chunked scan and the attention form are one
 function; the feature map's layout keeps ``phi(a) . phi(b) = (a . b)^2``; a
-padded chunk and an idle slot leave the state bit for bit; the update kernel
-(interpreted) against ``jax.numpy``."""
+padded chunk and an idle slot leave the state bit for bit; the chunk kernel
+and the update kernel (both interpreted) against ``jax.numpy``."""
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +134,55 @@ def test_a_padded_chunk_leaves_the_state_bit_for_bit():
     np.testing.assert_allclose(y2[:, :5], y3, rtol=1e-5, atol=1e-6)
 
 
+def _scan_counting_calls(q, k, v, log_g, s, z, chunk):
+    """The scan's results, and how many calls of the kernel it traced."""
+    scan = jax.jit(pr.retention_chunk_scan, static_argnames=("chunk",))
+    traced = scan.trace(q, k, v, log_g, s, z, chunk=chunk)
+    return scan(q, k, v, log_g, s, z, chunk=chunk), \
+        str(traced.jaxpr).count(pr.CHUNK_KERNEL)
+
+
+@pytest.mark.parametrize("d,h,kv,chunk", [(8, 4, 2, 128), (16, 10, 2, 16)])
+def test_two_chunks_in_one_call_are_two_calls_of_one_chunk(d, h, kv, chunk):
+    """A program of two chunks passes the state through the kernel once
+    (:data:`CHUNKS_A_CALL`), a tile serving both chunks while it is
+    resident: the same numbers as a call a chunk, to float32 rounding."""
+    x = _inputs(6, 2, 2 * chunk, h, kv, d)
+
+    def part(sl):
+        return [a[:, sl] for a in x]
+
+    # over a state that is not zeros
+    _, s0, z0 = pr.retention_chunk_scan(*part(slice(9)), *_zeros(2, kv, d),
+                                        chunk=chunk)
+    (y, s, z), calls = _scan_counting_calls(*x, s0, z0, chunk)
+    assert calls == 1
+    y1, s1, z1 = pr.retention_chunk_scan(*part(slice(chunk)), s0, z0,
+                                         chunk=chunk)
+    y2, s2, z2 = pr.retention_chunk_scan(*part(slice(chunk, None)), s1, z1,
+                                         chunk=chunk)
+    np.testing.assert_allclose(y, jnp.concatenate([y1, y2], 1), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(s, s2, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(z, z2, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("t,calls", [(3, 1), (21, 1), (37, 2), (70, 3)])
+def test_a_short_last_chunk_is_filled_with_positions_that_are_not_real(
+        t, calls):
+    """Fewer positions than a chunk are one short chunk, and a last chunk
+    that is short is filled up: the recurrence over the real positions."""
+    d, h, kv = 16, 10, 2                       # five query heads a state
+    q, k, v, log_g = _inputs(7, 1, t, h, kv, d)
+    s0, z0 = _zeros(1, kv, d)
+    (y, s, z), traced = _scan_counting_calls(q, k, v, log_g, s0, z0, 16)
+    assert traced == calls and y.shape == q.shape
+    y_r, s_r, z_r = recurrence(q, k, v, log_g, s0, z0)
+    np.testing.assert_allclose(y, y_r, rtol=1e-3, atol=1e-4)
+    np.testing.assert_allclose(s, s_r, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(z, z_r, rtol=1e-4, atol=1e-4)
+
+
 @pytest.mark.parametrize("d,h,kv", [(8, 4, 2), (16, 6, 2)])
 def test_update_kernel_interpreted_against_jax_numpy(d, h, kv):
     b = 3
@@ -195,3 +244,18 @@ def test_bfloat16_products_stay_near_the_float32_function():
             interpret=True)
         np.testing.assert_allclose(y_i, exact[:, i], rtol=0.05, atol=0.05)
     np.testing.assert_allclose(y, exact[:, :32], rtol=0.05, atol=0.05)
+
+
+def test_bfloat16_chunks_over_a_carried_state_stay_near_the_function():
+    """Two chunks a call and a short third over the state the first two
+    left, five query heads a state, every operand bfloat16."""
+    d, h, kv = 16, 10, 2
+    q, k, v, log_g = _inputs(8, 2, 40, h, kv, d, jnp.bfloat16)
+    s0, z0 = _zeros(2, kv, d)
+    exact = attention_form(q, k, v, log_g)
+    y, s, z = pr.retention_chunk_scan(q, k, v, log_g, s0, z0, chunk=16)
+    assert y.dtype == s.dtype == z.dtype == jnp.float32
+    np.testing.assert_allclose(y, exact, rtol=0.05, atol=0.05)
+    _, s_r, z_r = recurrence(q, k, v, log_g, s0, z0)
+    np.testing.assert_allclose(s, s_r, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(z, z_r, rtol=1e-4, atol=1e-4)

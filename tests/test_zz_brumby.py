@@ -232,6 +232,46 @@ def test_kernel_paths_are_counted(served):
     assert served["engine"].kernel_path == pr.UPDATE_PATH
 
 
+def test_a_prefill_program_counts_the_chunk_kernel_once(tiny):
+    """``retention_chunk_pallas`` a prefill program, whatever its width, and
+    the old label is gone: two programs of 32 and a tail of 8 for 70 tokens
+    under a budget of 32."""
+    cfg, _ = tiny
+    engine = _engine(tiny, slots=2)
+
+    assert pr.SCAN_PATH == "retention_chunk_pallas"
+
+    def chunks():
+        return _counter(f'lzy_kernel_dispatch_total{{path="{pr.SCAN_PATH}"}}')
+
+    before = chunks(), _counter("lzy_engine_prefill_programs_total")
+    engine.submit(_tokens(70, 70, cfg.vocab_size), max_new_tokens=2,
+                  greedy=True)
+    _drain(engine)
+    programs = _counter("lzy_engine_prefill_programs_total") - before[1]
+    assert programs == 3 and chunks() - before[0] == programs
+    assert "retention_chunk_lax" not in REGISTRY.exposition()
+    engine.close()
+
+
+def test_check_kernels_lowers_both_kernels_at_the_served_shapes(monkeypatch):
+    """The update over every slot, the chunk scan over one row of the widest
+    program: a kernel the chip refuses is refused at construction."""
+    asked = {}
+    cfg = brumby.BrumbyConfig.from_published(PUBLISHED)
+    with monkeypatch.context() as patched:
+        patched.setattr(pr, "lower_update_for_tpu",
+                        lambda **kw: asked.setdefault("update", kw))
+        patched.setattr(pr, "lower_chunk_for_tpu",
+                        lambda **kw: asked.setdefault("chunk", kw))
+        cfg.check_kernels(slots=16)
+    heads = dict(heads=40, kv_heads=8, head_dim=128, dtype=cfg.dtype)
+    assert asked["update"] == dict(batch=16, **heads)
+    assert asked["chunk"] == dict(batch=1, t=256, chunk=128, **heads)
+    # and for real at the tiny size (a lowering, no compile, no device)
+    brumby.BrumbyConfig.tiny().check_kernels(slots=3)
+
+
 def test_a_finished_requests_state_stays_in_its_slot(tiny):
     """``state_leaves()``: a freed slot keeps what its last round left, the
     reference's direct sum after the prompt and every served token but the
