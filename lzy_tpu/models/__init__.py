@@ -2,8 +2,8 @@
 directly. The families served through ``PagedInferenceEngine`` answer
 ``models/serving.py``'s protocol from modules of their own, imported where
 they are used: ``llama`` (Llama / Mistral), ``nemotron_h``, ``solar_open2``,
-``deepseek_v3``, ``cohere2_moe``, ``jamba``, ``zaya``, ``minicpm_sala`` and
-``brumby``."""
+``deepseek_v3``, ``cohere2_moe``, ``jamba``, ``zaya``, ``minicpm_sala``,
+``brumby`` and ``ouro``."""
 
 from lzy_tpu.models import bert, llama, resnet
 from lzy_tpu.models.common import (

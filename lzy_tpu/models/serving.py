@@ -8,8 +8,9 @@ the configuration object and by the module class it builds;
 ``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe``,
 ``models/jamba.py``'s ``JambaConfig`` / ``Jamba``, ``models/zaya.py``'s
 ``ZayaConfig`` / ``Zaya``, ``models/minicpm_sala.py``'s
-``MiniCPMSalaConfig`` / ``MiniCPMSala`` and ``models/brumby.py``'s
-``BrumbyConfig`` / ``Brumby`` all do: nine families.
+``MiniCPMSalaConfig`` / ``MiniCPMSala``, ``models/brumby.py``'s
+``BrumbyConfig`` / ``Brumby`` and ``models/ouro.py``'s ``OuroConfig`` /
+``Ouro`` all do: ten families.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -22,7 +23,10 @@ engine reads neither), and:
   the engine runs, for decode rounds and batch-1 prefill alike, reading its
   pool through the page table (``ops/paged_attention.py``, ``ops/mla.py``);
   a ``kv_quant`` its pool cannot take is refused here, by name;
-- ``kv_layers``: layers that keep a leaf in the paged pool. **0 (and no
+- ``kv_layers``: entries a token keeps in the paged pool, one a layer that
+  keeps a leaf there; **it may exceed the model's layers**
+  (``models/ouro.py`` runs its 48 layers four times a token with a cache a
+  (pass, layer): 192). **0 (and no
   ``kv_window``) is a model with no pool** (``models/brumby.py``: every cache
   leaf is ``state``): the engine builds no block for it, not even the scratch
   one (``RadixCache(0, ..)``), keeps and uploads no page table (a decode
@@ -89,7 +93,11 @@ answer in a ``state`` leaf, which is all a decode round is told).
   addressed through a page table; what follows the page axis is the
   model's (``[KV, D]`` keys or values, a latent vector, ``[KV, page, D]``
   with the page axis second and ``[page / 16, KV, D]`` compressed keys
-  beside them under the same table: ``models/minicpm_sala.py``). A batch-1 prefill
+  beside them under the same table: ``models/minicpm_sala.py``; **a block
+  that holds several passes**, ``[pages, T, page, KV, D]``, which the
+  model reads inside pass ``t`` as ``[pages x T, page, KV, D]`` through
+  ``page_table x T + t``: ``models/ouro.py``, where ``kv_layers`` counts the
+  ``T`` entries a layer and one page table a row serves every pass). A batch-1 prefill
   writes the same pool; a prefix can be shared, exported, demoted, and a
   speculated position rewound by moving an index: every mechanism moves
   pages by block id and reads no shape past the page axis, so a latent

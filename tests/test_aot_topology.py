@@ -27,6 +27,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -1130,6 +1131,76 @@ def test_brumby_programs_compile_at_the_cells_shapes(case, one_chip,
         # (219 MB of temporaries before the kernel: PERF.md section 6)
         logits = t * cfg.vocab_size * 4
         assert memory.temp_size_in_bytes < logits + (8 << 20)
+    assert _parameter_copies(text, params) == []
+
+
+# -- Ouro's loop over the pass: one trace of the stack, no copy of the pool ----
+
+@pytest.mark.parametrize("case", ["decode", "prefill"])
+def test_ouro_steps_loop_over_the_pass_and_copy_no_pool(case, one_chip,
+                                                        monkeypatch):
+    """Ouro-2.6B at its published widths as ``looped-steady`` runs it, four
+    layers of the 48 over the cell's pool leaves (``[384, 4, 16, 16, 128]``
+    bfloat16: 48 MiB a leaf, a pass's share 12 MiB): the decode round of 16
+    slots and the prefill chunk of 256. **The stack is compiled once and
+    looped**: one ``while``, one attention kernel a layer (not one a (pass,
+    layer)). **The pool is the loop's carry, written in place**: every leaf
+    is aliased to its output, the temporaries stay under one leaf, and no
+    ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` makes an array of
+    a pass's share of a leaf or more (a scanned-over cache would slice and
+    restack the pool every pass)."""
+    from lzy_tpu.models import ouro
+    from lzy_tpu.ops import interpret
+
+    # the kernels as the chip compiles them, whatever conftest.py asked for
+    monkeypatch.setattr(interpret, "_process_wide", False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: sds(s.shape, s.dtype), tree)
+
+    layers, page, kv_pages = 4, 16, 384
+    cfg = ouro.OuroConfig(n_layers=layers, max_seq_len=4096)
+    batch, t = (16, 1) if case == "decode" else (1, 256)
+    model = cfg.paged_model(page_size=page, kv_pages=kv_pages,
+                            kernel="pallas", kv_quant=None)
+    params = on_chip(jax.eval_shape(
+        lambda: ouro.init_params(cfg, jax.random.PRNGKey(0))))
+    pages = cfg.max_seq_len // page
+    cache = on_chip(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((batch, 1), jnp.int32),
+        page_table=jnp.zeros((batch, pages), jnp.int32)))["cache"])
+
+    def step(params, cache, toks, table, valid):
+        logits, upd = model.apply(
+            {"params": params, "cache": cache}, toks, page_table=table,
+            valid_len=valid, mutable=["cache", "stats"])
+        return jnp.argmax(logits[:, -1], -1), upd["cache"], \
+            upd.get("stats", {})
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((batch, t), jnp.int32),
+        sds((batch, pages), jnp.int32), sds((batch,), jnp.int32)).compile()
+    text = compiled.as_text()
+    kernel = "paged_decode_attention" if case == "decode" \
+        else "paged_chunk_attention"
+    assert text.count(" while(") == 1
+    assert text.count("tpu_custom_call") == layers
+    assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == layers
+    leaf = kv_pages * cfg.total_ut_steps * page * 16 * 128
+    pool_bytes = 2 * layers * leaf * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 2 * leaf            # one leaf's bytes
+    moved = [(op, dims) for dims, op in re.findall(
+        r"= \w+\[([\d,]+)\]\S* (copy|dynamic-slice|dynamic-update-slice)\(",
+        text)
+        if np.prod([int(d) for d in dims.split(",")])
+        >= leaf // cfg.total_ut_steps]
+    assert moved == []
     assert _parameter_copies(text, params) == []
 
 

@@ -62,6 +62,9 @@ def registry_metrics():
     import lzy_tpu.models.minicpm_sala  # noqa: F401
     # a model of power-retention layers: rows whose state a round moved
     import lzy_tpu.models.brumby  # noqa: F401
+    # a model that runs its layers several times a token: real rows of
+    # decode rounds and the pass the head read, summed (lzy_loop_*)
+    import lzy_tpu.models.ouro  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
