@@ -3,36 +3,65 @@
 The hot op of every transformer in this framework. FlashAttention-2 structure
 mapped to the TPU memory hierarchy (``/opt/skills/guides/pallas_guide.md``):
 
-- grid over (batch·heads, query blocks); K/V for one (b,h) live in VMEM and
-  are walked blockwise with the online-softmax recurrence — the T×T score
-  matrix never exists, activations are O(T·D);
-- matmuls hit the MXU with float32 accumulation (``preferred_element_type``),
-  inputs stay bfloat16;
+- grid over (batch x heads, query blocks); K/V for one (b,h) live in VMEM and
+  are walked blockwise with the online-softmax recurrence: the T x T score
+  matrix never exists, activations are O(T x D);
+- every product takes its operands in the input dtype (bfloat16 in,
+  bfloat16 on the matrix unit) and accumulates in float32
+  (``preferred_element_type``): blocks of Q, K, V and dO are multiplied as
+  they were loaded, ``p`` and ``ds`` are cast to the input dtype once, and the
+  softmax scale goes into the scores through Q alone, rounded to the input
+  dtype: once to the resident block in the forward and dQ kernels, to the
+  block of a turn in the dK/dV kernel, so that all three see the same
+  scores to the bit and ``p`` in the backward is the forward's softmax; dQ
+  and dK are made from the unscaled K and Q and scaled once in float32.
+  (At Mosaic's default precision a float32 operand is rounded to bfloat16
+  inside the product anyway: the casts make that rounding visible, they do
+  not add one.) Softmax statistics and accumulators are float32;
 - causal programs stop their KV loop at the diagonal (no wasted FLOPs on
   masked blocks);
 - packed documents (``segment_ids``) confine attention to equal ids AND
-  tighten the KV loop to the blocks the query block's documents span —
+  tighten the KV loop to the blocks the query block's documents span:
   data-dependent ``fori_loop`` bounds read from a precomputed per-position
   (id, doc start, doc end) slab, so cross-document blocks cost nothing
-  (for fully packed batches the FLOPs drop from O(T²/2) toward
-  O(sum_doc len²/2));
+  (for fully packed batches the FLOPs drop from O(T^2/2) toward
+  O(sum_doc len^2/2));
+- a visited (query block, key block) pair's mask is two compares against
+  its own block's (start, end) lanes, since documents are contiguous: one
+  iota a program, no read of the other side's ids. What a call cannot need
+  is not traced: no compare without ``causal`` or segments, no bias without
+  a ``kv_mask``. (A second, unmasked body for the pairs that lie wholly
+  under the diagonal and inside one document was measured and lost in the
+  train step: PERF.md section 6, PR 59.) :func:`block_pair_census` counts,
+  for a batch's segment ids, the pairs the documents need and the pairs
+  visited;
+- the forward and dK/dV kernels work on transposed tiles (``[bkv, bq]``):
+  the softmax's maximum and sum run down a tile's rows and the running
+  statistics are one value a lane, so nothing is reduced across lanes; the
+  dQ kernel's tiles are ``[bq, bkv]``;
 - backward is two Pallas kernels (dK/dV over KV blocks, dQ over Q blocks)
   using the saved per-row logsumexp, wrapped in ``jax.custom_vjp``.
 
-TPU tiling note: auxiliary row vectors (logsumexp, delta) cannot use
-``(1, block)`` blocks — the last two block dims must be (8k, 128k) or
-full-dim. Both directions therefore carry lse/delta broadcast across the head
-dim (the same layout jax's reference TPU flash kernel uses for l/m residuals).
-The segment slab likewise rides a 128-lane dim: lane 0 = segment id,
-lane 1 = document start, lane 2 = document end (exclusive).
+The row statistics (logsumexp, delta) are one float32 a row: the forward
+writes the logsumexp as ``[bh, 1, t]`` (a ``(1, 1, block_q)`` block is legal
+where the second-to-last dim is the whole of a dim of 1), the dQ kernel reads
+that array, turns a block's lanes into rows once a program, and writes delta
+= sum(dO * O) of its rows the same way (no XLA operation makes, cuts or
+broadcasts a statistic), and the dK/dV kernel reads both as
+``[bh, t / block_q, block_q]``, a query block a row.
+The segment slab rides a 128-lane dim: lane 0 = segment id,
+lane 1 = document start, lane 2 = document end (exclusive); every kernel
+reads its own block's rows of it.
 
-VMEM: every kernel keeps one head's full-length operands resident (K and V
-in the forward and dQ kernels; Q, dO and the two broadcast row vectors in the
-dK/dV kernel), so its footprint grows with T. Each ``pallas_call`` asks Mosaic
-for the scoped VMEM its shapes need (:func:`_vmem_limit`; the default 16 MiB
-runs out in the backward at T = 8192, head size 128), and a length whose
-backward would not fit :data:`VMEM_CAP_BYTES` is refused by
-:func:`flash_attention` before anything is traced.
+VMEM: every kernel keeps one head's full-length operands resident (K and V,
+and the ``kv_mask`` bias slab when there is one, in the forward and dQ
+kernels; Q, dO and the two statistics in the dK/dV kernel), so its footprint
+grows with T. Each ``pallas_call`` asks Mosaic for the scoped VMEM its shapes
+need (:func:`_vmem_limit`; the default 16 MiB runs out in the backward at
+T = 8192, head size 128), and a length whose kernels would not fit
+:data:`VMEM_CAP_BYTES` is refused by :func:`flash_attention` before anything
+is traced (:func:`max_seq_len`: 92,672 for bfloat16 at head size 128, 47,104
+with a ``kv_mask``).
 
 Callers without a TPU ask for the Pallas interpreter (``interpret=True``, or
 ``lzy_tpu.ops.interpret.set_interpret`` for the process); it is never chosen
@@ -42,10 +71,11 @@ from the device.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
@@ -83,30 +113,32 @@ def _vmem_limit(resident_bytes: int) -> pltpu.CompilerParams:
         _vmem_need(resident_bytes), _VMEM_DEFAULT_BYTES))
 
 
-def _aux_bytes(t: int, *aux) -> int:
-    """Bytes of the full-length float32 ``[t, LANE]`` slabs (bias, segments)
-    that are present."""
-    return sum(t * _LANE * 4 for a in aux if a is not None)
+def _kv_resident_bytes(t: int, d: int, itemsize: int, bias) -> int:
+    """What the forward and dQ kernels keep per head: K and V, and the
+    ``kv_mask`` bias as a float32 ``[t, LANE]`` slab when there is one."""
+    return 2 * t * d * itemsize + (0 if bias is None else t * _LANE * 4)
 
 
-def _kv_resident_bytes(t: int, d: int, itemsize: int, bias, seg) -> int:
-    """What the forward and dQ kernels keep per head: K and V."""
-    return 2 * t * d * itemsize + _aux_bytes(t, bias, seg)
-
-
-def _dkv_resident_bytes(t: int, d: int, itemsize: int, seg) -> int:
+def _dkv_resident_bytes(t: int, d: int, itemsize: int) -> int:
     """What the dK/dV kernel keeps per head: Q and dO in the input dtype,
-    logsumexp and delta broadcast to float32 ``[t, d]``, and the segment
-    slab. The largest footprint of the three kernels, so it decides which
-    lengths :func:`flash_attention` accepts."""
-    return t * d * (2 * itemsize + 8) + _aux_bytes(t, seg)
+    and logsumexp and delta, one float32 a row each."""
+    return 2 * t * d * itemsize + 2 * t * 4
 
 
-def max_seq_len(d: int, dtype, segmented: bool = False) -> int:
-    """Longest sequence (a multiple of 128) whose backward fits
-    :data:`VMEM_CAP_BYTES` at head size ``d``."""
-    per_pos = _vmem_need(_dkv_resident_bytes(
-        1, d, jnp.dtype(dtype).itemsize, segmented or None)) \
+def _resident_bytes(t: int, d: int, itemsize: int, bias) -> int:
+    """The largest footprint of the three kernels: it decides which lengths
+    :func:`flash_attention` accepts. (The segment slab is read a block at a
+    time by every kernel, and counts as a block.)"""
+    return max(_kv_resident_bytes(t, d, itemsize, bias),
+               _dkv_resident_bytes(t, d, itemsize))
+
+
+def max_seq_len(d: int, dtype, masked: bool = False) -> int:
+    """Longest sequence (a multiple of 128) whose kernels fit
+    :data:`VMEM_CAP_BYTES` at head size ``d``; ``masked`` when a
+    ``kv_mask`` is given."""
+    per_pos = _vmem_need(_resident_bytes(
+        1, d, jnp.dtype(dtype).itemsize, masked or None)) \
         - _VMEM_SLACK_BYTES
     return (VMEM_CAP_BYTES - _VMEM_SLACK_BYTES) // per_pos // _LANE * _LANE
 
@@ -135,124 +167,265 @@ def _split_in_refs(refs, masked, segmented, n_out):
     return base, bias_ref, seg_ref, outs
 
 
+# -- which pairs a block visits, and what masks them ---------------------------
+
+
+class _Ops(NamedTuple):
+    """The integer arithmetic :func:`_pair_ranges` is written in: traced
+    scalars inside a kernel, whole numpy arrays in :func:`block_pair_census`.
+    Every quantity is non-negative, so truncating and flooring division
+    agree."""
+    minimum: Callable
+    maximum: Callable
+    div: Callable
+
+
+_KERNEL_OPS = _Ops(jnp.minimum, jnp.maximum, lax.div)
+_HOST_OPS = _Ops(np.minimum, np.maximum, np.floor_divide)
+
+
+def _pair_ranges(own_start, own, other, n_other, docs, *, causal, q_major,
+                 ops):
+    """The blocks ``[lo, hi)`` of the other side that one block meets.
+
+    ``own_start`` is the first position of this block of ``own`` rows: a
+    query block when ``q_major`` (forward, dQ: the others are ``n_other``
+    key blocks of ``other`` rows), else a key block (dK/dV: the others are
+    query blocks). ``docs`` is None or (start of the first row's document,
+    end of the last row's): documents are contiguous, so the block's rows
+    keep nothing outside that span."""
+    def ceil_div(a):
+        return ops.div(a + (other - 1), other)
+
+    lo, hi = 0, n_other
+    if causal and q_major:
+        hi = ceil_div(own_start + own)        # keys up to the last query
+    elif causal:
+        lo = ops.div(own_start, other)        # queries from the first key on
+    if docs is not None:
+        first_start, last_end = docs
+        lo = ops.maximum(lo, ops.div(first_start, other))
+        hi = ops.minimum(hi, ceil_div(last_end))
+    return lo, ops.minimum(hi, n_other)
+
+
+class PairCensus(NamedTuple):
+    """(query block, key block) pairs of a batch, counted over its rows."""
+    needed: int     #: pairs that hold a (query, key) some document keeps
+    visited: int    #: pairs the kernels' loops run
+
+
+def block_pair_census(segment_ids, block_q: int, block_kv: int,
+                      causal: bool = True) -> PairCensus:
+    """What the kernels do with a batch's packing, counted on the host:
+    ``segment_ids`` [B, T] (a document is a contiguous run of equal ids, as
+    in :func:`flash_attention`; all zeros is one document a row). The
+    visited count comes from :func:`_pair_ranges` over the (start, end)
+    lanes of :func:`segment_slab`, the arithmetic and the numbers of the
+    kernels' loop bounds; the needed count from the documents alone. The
+    forward, dQ and dK/dV kernels visit the same pairs, so one count stands
+    for all three."""
+    slab = np.asarray(segment_slab(document_starts(jnp.asarray(segment_ids))))
+    start, end = (slab[:, :, lane].astype(np.int64) for lane in (1, 2))
+    b, t = start.shape
+    block_q, block_kv = _pick_block(t, block_q), _pick_block(t, block_kv)
+    n_q, n_kv = t // block_q, t // block_kv
+    q_first = np.arange(n_q) * block_q
+    q_last = q_first + block_q - 1
+    lo, hi = _pair_ranges(
+        q_first[None, :], block_q, block_kv, n_kv,
+        (start[:, q_first], end[:, q_last]), causal=causal, q_major=True,
+        ops=_HOST_OPS)
+    needed = np.zeros((b, n_q, n_kv), bool)
+    for row in range(b):
+        for s in np.flatnonzero(start[row] == np.arange(t)):
+            e = int(end[row, s])
+            for i in range(s // block_q, (e - 1) // block_q + 1):
+                reach = min(e, (i + 1) * block_q) if causal else e
+                needed[row, i, s // block_kv:(reach - 1) // block_kv + 1] = 1
+    return PairCensus(int(needed.sum()), int((hi - lo).sum()))
+
+
+def _kernel_ranges(seg_ref, own_start, own, other, n_other, *, causal,
+                   q_major):
+    """:func:`_pair_ranges` on the scalars of this block's slab rows."""
+    docs = None
+    if seg_ref is not None:
+        first, last = seg_ref[0, 0:1, :], seg_ref[0, own - 1:own, :]
+        docs = (first[0, 1].astype(jnp.int32), last[0, 2].astype(jnp.int32))
+    return _pair_ranges(own_start, own, other, n_other, docs, causal=causal,
+                        q_major=q_major, ops=_KERNEL_OPS)
+
+
+def _own_bounds(seg_ref, own_start, own, *, causal, q_major):
+    """What a pair's mask compares: int32 ``[own, 1]`` columns
+    (lo, hi), either of which may be None, such that a position ``x`` of the
+    other side is kept by this block's row when ``lo <= x < hi``. Documents
+    are contiguous, so a query keeps the keys from its document's start up
+    to itself, and a key the queries from itself up to its document's end:
+    one side's (start, end) lanes say it all, and the other side's ids are
+    never read."""
+    pos = own_start + lax.broadcasted_iota(jnp.int32, (own, 1), 0)
+    start = end = None
+    if seg_ref is not None:
+        start = seg_ref[0, :, 1:2].astype(jnp.int32)
+        end = seg_ref[0, :, 2:3].astype(jnp.int32)
+    if not causal:
+        return start, end
+    return (start, pos + 1) if q_major else (pos, end)
+
+
+def _keep(idx, lo, hi, other_start):
+    """The mask of one pair from :func:`_own_bounds`: ``idx`` is the
+    tile's index along the other side, ``other_start`` the first position
+    of the other side's block. None when nothing is masked."""
+    keep = None
+    if lo is not None:
+        keep = idx >= lo - other_start
+    if hi is not None:
+        below = idx < hi - other_start
+        keep = below if keep is None else jnp.logical_and(keep, below)
+    return keep
+
+
+def _to_lanes(col):
+    """float32 [n, 1] (a value a row) -> [1, n] (a value a lane)."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANE)).T[0:1, :]
+
+
+def _to_sublanes(row):
+    """float32 [1, n] -> [n, 1]."""
+    return jnp.broadcast_to(row, (_LANE, row.shape[1])).T[:, 0:1]
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scaled(x, scale):
+    """``x * scale`` in the dtype of ``x``: every kernel multiplies the
+    same rounded ``q * scale``, so all three see the same scores."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
 # -- forward --------------------------------------------------------------------
 
 
 def _fwd_kernel(*refs, scale, causal, masked, segmented, block_q, block_kv,
                 seq_len):
+    """A query block against the key blocks it reaches, transposed: the
+    tile is ``[bkv, bq]``, so the online softmax's maximum and sum run down
+    the sublanes (elementwise over the tile's rows, no reduction across
+    lanes), the running statistics are one value a lane (four registers a
+    512 rows, not 64), and the log-sum-exp leaves as it is stored."""
     (q_ref, k_ref, v_ref), bias_ref, seg_ref, (o_ref, lse_ref) = \
         _split_in_refs(refs, masked, segmented, 2)
-    iq = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale            # [bq, d]
-    q_start = iq * block_q
-    n_kv = seq_len // block_kv
-    hi = jnp.minimum(
-        lax.div(q_start + block_q + block_kv - 1, block_kv), n_kv
-    ) if causal else n_kv
-    lo = 0
-    seg_q = None
-    if seg_ref is not None:
-        seg_rows = seg_ref[0, pl.ds(q_start, block_q), :]   # [bq, LANE]
-        seg_q = seg_rows[:, 0]
-        # ids are non-decreasing (packed layout): the block's documents span
-        # [start of first row's doc, end of last row's doc) — KV blocks
-        # outside that range are entirely cross-document, skip them
-        lo = lax.div(seg_rows[0, 1].astype(jnp.int32), block_kv)
-        seg_hi = lax.div(
-            seg_rows[block_q - 1, 2].astype(jnp.int32) + block_kv - 1,
-            block_kv,
-        )
-        hi = jnp.minimum(hi, seg_hi)
-
-    d = q.shape[-1]
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
+    q_start = pl.program_id(1) * block_q
+    dtype = q_ref.dtype
+    q = _scaled(q_ref[0], scale)                          # [bq, d]
+    ranges = _kernel_ranges(seg_ref, q_start, block_q, block_kv,
+                            seq_len // block_kv, causal=causal, q_major=True)
+    # the queries' bounds, a value a lane: turned once a program
+    keep_lo, keep_hi = (
+        x if x is None else _to_lanes(x.astype(jnp.float32)).astype(jnp.int32)
+        for x in _own_bounds(seg_ref, q_start, block_q, causal=causal,
+                             q_major=True))
+    key = lax.broadcasted_iota(jnp.int32, (block_kv, block_q), 0)
 
     def body(j, carry):
         acc, m, l = carry
-        k_blk = k_ref[0, pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                # [bq, bkv]
+        kv_start = j * block_kv
+        k_blk = k_ref[0, pl.ds(kv_start, block_kv), :]
+        v_blk = v_ref[0, pl.ds(kv_start, block_kv), :]
+        st = _dot(k_blk, q, _NT)                          # [bkv, bq]
         if bias_ref is not None:
-            # additive KV bias (0 keep / -inf drop), one lane per position
-            b_col = bias_ref[0, pl.ds(j * block_kv, block_kv), 0]
-            s = s + b_col[None, :]
-        keep = None
-        if causal:
-            rows = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = j * block_kv + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            keep = rows >= cols
-        if seg_q is not None:
-            seg_kv = seg_ref[0, pl.ds(j * block_kv, block_kv), 0]
-            same = seg_q[:, None] == seg_kv[None, :]
-            keep = same if keep is None else jnp.logical_and(keep, same)
+            # additive KV bias (0 keep / -inf drop), a row a position
+            st = st + bias_ref[0, pl.ds(kv_start, block_kv), 0:1]
+        keep = _keep(key, keep_lo, keep_hi, kv_start)
         if keep is not None:
-            s = jnp.where(keep, s, _NEG_INF)
-        m_blk = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m, m_blk)
-        m_safe = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - m_safe[:, None])
-        if keep is not None:
-            p = jnp.where(keep, p, 0.0)
-        alpha = jnp.where(m <= _NEG_INF / 2, 0.0, jnp.exp(m - m_safe))
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            st = jnp.where(keep, st, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(st, axis=0, keepdims=True))
+        if bias_ref is not None:
+            # a query may see no key at all: its maximum stays at -inf, its
+            # sum at 0, and the output and the gradients come out as 0
+            m_ref = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
+            alpha = jnp.where(m <= _NEG_INF / 2, 0.0, jnp.exp(m - m_ref))
+        else:
+            # every query keeps itself (causal or not), so one that has
+            # seen only dropped keys so far (maximum -inf, p = 1 for each)
+            # is wiped by alpha = exp(-inf) = 0 when its own block comes
+            m_ref = m_new
+            alpha = jnp.exp(m - m_new)
+        pt = jnp.exp(st - m_ref)
+        l_new = l * alpha + jnp.sum(pt, axis=0, keepdims=True)
+        acc_new = acc * alpha + _dot(v_blk, pt.astype(dtype), _TN)
         return acc_new, m_new, l_new
 
-    acc, m, l = lax.fori_loop(lo, hi, body, (acc0, m0, l0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    lse = jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), _NEG_INF)
-    lse_ref[0] = jnp.broadcast_to(lse[:, None], (block_q, d))
+    d = q.shape[-1]
+    carry = (jnp.zeros((d, block_q), jnp.float32),
+             jnp.full((1, block_q), _NEG_INF, jnp.float32),
+             jnp.zeros((1, block_q), jnp.float32))
+    acc, m, l = lax.fori_loop(*ranges, body, carry)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).T.astype(o_ref.dtype)
+    lse_ref[0] = jnp.where(
+        l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), _NEG_INF)
+
+
+def _aux_specs(bias, seg, n_heads, *, bias_block=None, seg_block=None):
+    """(in_specs, operands) of the optional slabs, in bias, seg order. Both
+    are a batch row's, ``[b, t, LANE]``, where grid dim 0 walks batch x
+    heads; a slab is handed over whole, or ``block`` rows at grid dim 1."""
+    specs, operands = [], []
+    for slab, block in ((bias, bias_block), (seg, seg_block)):
+        if slab is None:
+            continue
+        if block is None:
+            specs.append(pl.BlockSpec(
+                (1, slab.shape[1], _LANE),
+                lambda b, i: (b // n_heads, 0, 0)))
+        else:
+            specs.append(pl.BlockSpec(
+                (1, block, _LANE), lambda b, i: (b // n_heads, i, 0)))
+        operands.append(slab)
+    return specs, operands
 
 
 def _fwd(q, k, v, bias, seg, *, scale, causal, block_q, block_kv, interpret,
          n_heads):
+    """The output and the log-sum-exp, one float32 a row as ``[bh, 1, t]``:
+    the backward kernels read that array as it is."""
     bh, t, d = q.shape
-    n_q = t // block_q
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, masked=bias is not None,
         segmented=seg is not None, block_q=block_q, block_kv=block_kv,
         seq_len=t,
     )
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
-    ]
-    operands = [q, k, v]
-    # bias/seg are per-BATCH [b, t, LANE]; grid dim 0 walks batch·heads
-    if bias is not None:
-        in_specs.append(pl.BlockSpec(
-            (1, t, _LANE), lambda b, i: (b // n_heads, 0, 0)))
-        operands.append(bias)
-    if seg is not None:
-        in_specs.append(pl.BlockSpec(
-            (1, t, _LANE), lambda b, i: (b // n_heads, 0, 0)))
-        operands.append(seg)
-    o, lse_bcast = pl.pallas_call(
+    aux_specs, aux = _aux_specs(bias, seg, n_heads, seg_block=block_q)
+    return pl.pallas_call(
         kernel,
-        grid=(bh, n_q),
-        in_specs=in_specs,
+        grid=(bh, t // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),
+        ] + aux_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_vmem_limit(
-            _kv_resident_bytes(t, d, q.dtype.itemsize, bias, seg)),
-    )(*operands)
-    return o, lse_bcast[:, :, 0]                          # [bh, t]
+            _kv_resident_bytes(t, d, q.dtype.itemsize, bias)),
+    )(q, k, v, *aux)
 
 
 # -- backward -------------------------------------------------------------------
@@ -260,225 +433,147 @@ def _fwd(q, k, v, bias, seg, *, scale, causal, block_q, block_kv, interpret,
 
 def _bwd_dq_kernel(*refs, scale, causal, masked, segmented, block_q,
                    block_kv, seq_len):
-    ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, seg_ref,
-     (dq_ref,)) = _split_in_refs(refs, masked, segmented, 1)
-    iq = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    q_start = iq * block_q
-    # lse/delta arrive broadcast over the head dim (TPU lane tiling); keep the
-    # per-row column as 2D [block_q, 1] for clean broadcasting
-    lse = lse_ref[0, :, 0:1]
-    delta = delta_ref[0, :, 0:1]
-    n_kv = seq_len // block_kv
-    hi = jnp.minimum(
-        lax.div(q_start + block_q + block_kv - 1, block_kv), n_kv
-    ) if causal else n_kv
-    lo = 0
-    seg_q = None
-    if seg_ref is not None:
-        seg_rows = seg_ref[0, pl.ds(q_start, block_q), :]
-        seg_q = seg_rows[:, 0]
-        lo = lax.div(seg_rows[0, 1].astype(jnp.int32), block_kv)
-        hi = jnp.minimum(hi, lax.div(
-            seg_rows[block_q - 1, 2].astype(jnp.int32) + block_kv - 1,
-            block_kv,
-        ))
+    """dQ of a query block, and its rows' delta = sum(dO * O) on the way:
+    the dK/dV kernel reads what this one wrote."""
+    ((q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref), bias_ref, seg_ref,
+     (dq_ref, delta_ref)) = _split_in_refs(refs, masked, segmented, 2)
+    q_start = pl.program_id(1) * block_q
+    dtype = q_ref.dtype
+    q = _scaled(q_ref[0], scale)
+    do = do_ref[0]
+    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                    axis=-1, keepdims=True)               # [bq, 1]
+    delta_ref[0] = _to_lanes(delta)
+    # the log-sum-exp arrives a value a lane; against a [bq, bkv] tile it
+    # is wanted a value a row: turned once a program
+    lse = _to_sublanes(lse_ref[0])                        # [bq, 1]
+    if bias_ref is not None:
+        # a fully masked row stored lse = -inf, which would cancel the -inf
+        # bias (s - (-inf) + (-inf) = s) and resurrect p; its softmax had
+        # no mass, so its gradient is exactly zero: exp(s - inf) = 0
+        lse = jnp.where(lse > _NEG_INF / 2, lse, -_NEG_INF)
+    ranges = _kernel_ranges(seg_ref, q_start, block_q, block_kv,
+                            seq_len // block_kv, causal=causal, q_major=True)
+    keep_lo, keep_hi = _own_bounds(seg_ref, q_start, block_q, causal=causal,
+                                   q_major=True)
+    lane = lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
 
     def body(j, dq):
-        k_blk = k_ref[0, pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(j * block_kv, block_kv), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q * scale, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        kv_start = j * block_kv
+        k_blk = k_ref[0, pl.ds(kv_start, block_kv), :]
+        v_blk = v_ref[0, pl.ds(kv_start, block_kv), :]
+        s = _dot(q, k_blk, _NT)
         if bias_ref is not None:
-            b_col = bias_ref[0, pl.ds(j * block_kv, block_kv), 0]
-            s = s + b_col[None, :]
-        rows = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = j * block_kv + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = s + bias_ref[0, pl.ds(kv_start, block_kv), 0][None, :]
+        keep = _keep(lane, keep_lo, keep_hi, kv_start)
+        if keep is not None:
+            s = jnp.where(keep, s, _NEG_INF)
         p = jnp.exp(s - lse)
-        # fully-masked rows store lse = -inf, which would cancel the -inf
-        # bias (s - (-inf) + (-inf) = s) and resurrect p; their softmax had
-        # no mass, so their gradient is exactly zero
-        p = jnp.where(lse > _NEG_INF / 2, p, 0.0)
-        if causal:
-            p = jnp.where(rows >= cols, p, 0.0)
-        if seg_q is not None:
-            seg_kv = seg_ref[0, pl.ds(j * block_kv, block_kv), 0]
-            p = jnp.where(seg_q[:, None] == seg_kv[None, :], p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dp = _dot(do, v_blk, _NT)
+        ds = p * (dp - delta)
+        return dq + _dot(ds.astype(dtype), k_blk, _NN)
 
-    dq = lax.fori_loop(lo, hi, body, jnp.zeros_like(q))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq = lax.fori_loop(*ranges, body, jnp.zeros(q.shape, jnp.float32))
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, masked, segmented, block_q,
                     block_kv, seq_len):
+    """A key block against the query blocks that reach it, transposed: the
+    tile is ``[bkv, bq]``, so the queries' statistics are read a value a
+    lane as they are stored, the bias and the segment bounds a value a row
+    from this block's own slab rows, and both accumulating products are
+    plain ``a @ b``."""
     ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, seg_ref,
      (dk_ref, dv_ref)) = _split_in_refs(refs, masked, segmented, 2)
-    jkv = pl.program_id(1)
-    k_blk = k_ref[0].astype(jnp.float32)                  # [bkv, d]
-    v_blk = v_ref[0].astype(jnp.float32)
-    kv_start = jkv * block_kv
-    n_q = seq_len // block_q
-    lo = lax.div(kv_start, block_q) if causal else 0
-    hi = n_q
-    seg_kv = None
-    if seg_ref is not None:
-        seg_rows = seg_ref[0, pl.ds(kv_start, block_kv), :]
-        seg_kv = seg_rows[:, 0]
-        # mirror of the forward skip: only q rows inside this KV block's
-        # documents can reach it
-        if not causal:
-            lo = jnp.maximum(
-                lo, lax.div(seg_rows[0, 1].astype(jnp.int32), block_q)
-            )
-        hi = jnp.minimum(hi, lax.div(
-            seg_rows[block_kv - 1, 2].astype(jnp.int32) + block_q - 1,
-            block_q,
-        ))
-
-    d = k_blk.shape[-1]
+    kv_start = pl.program_id(1) * block_kv
+    dtype = k_ref.dtype
+    k = k_ref[0]                                          # [bkv, d]
+    v = v_ref[0]
+    ranges = _kernel_ranges(seg_ref, kv_start, block_kv, block_q,
+                            seq_len // block_q, causal=causal, q_major=False)
+    keep_lo, keep_hi = _own_bounds(seg_ref, kv_start, block_kv,
+                                   causal=causal, q_major=False)
+    lane = lax.broadcasted_iota(jnp.int32, (block_kv, block_q), 1)
 
     def body(i, carry):
         dk, dv = carry
         q_start = i * block_q
-        q_blk = q_ref[0, pl.ds(q_start, block_q), :].astype(jnp.float32)
-        do_blk = do_ref[0, pl.ds(q_start, block_q), :].astype(jnp.float32)
-        lse_blk = lse_ref[0, pl.ds(q_start, block_q), 0:1]      # [bq, 1]
-        delta_blk = delta_ref[0, pl.ds(q_start, block_q), 0:1]  # [bq, 1]
-        s = jax.lax.dot_general(
-            q_blk * scale, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                 # [bq, bkv]
+        q_blk = q_ref[0, pl.ds(q_start, block_q), :]
+        do_blk = do_ref[0, pl.ds(q_start, block_q), :]
+        lse = lse_ref[0, pl.ds(i, 1), :]                  # [1, bq]
+        delta = delta_ref[0, pl.ds(i, 1), :]
+        # the forward's q * scale, to the bit: the scores here are its
+        # scores, and p its softmax
+        st = _dot(k, _scaled(q_blk, scale), _NT)          # [bkv, bq]
         if bias_ref is not None:
-            # this kernel's whole KV block shares one bias slice
-            s = s + bias_ref[0, :, 0][None, :]
-        rows = q_start + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = kv_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        p = jnp.exp(s - lse_blk)
-        # same empty-row guard as the dQ kernel (see comment there)
-        p = jnp.where(lse_blk > _NEG_INF / 2, p, 0.0)
-        if causal:
-            p = jnp.where(rows >= cols, p, 0.0)
-        if seg_kv is not None:
-            seg_q = seg_ref[0, pl.ds(q_start, block_q), 0]
-            p = jnp.where(seg_q[:, None] == seg_kv[None, :], p, 0.0)
-        dv_new = dv + jax.lax.dot_general(
-            p, do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_blk) * scale
-        dk_new = dk + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            st = st + bias_ref[0, :, 0:1]
+            # same empty-row guard as the dQ kernel (see comment there)
+            lse = jnp.where(lse > _NEG_INF / 2, lse, -_NEG_INF)
+        keep = _keep(lane, keep_lo, keep_hi, q_start)
+        if keep is not None:
+            st = jnp.where(keep, st, _NEG_INF)
+        pt = jnp.exp(st - lse)
+        dv_new = dv + _dot(pt.astype(dtype), do_blk, _NN)
+        dpt = _dot(v, do_blk, _NT)
+        dst = pt * (dpt - delta)
+        dk_new = dk + _dot(dst.astype(dtype), q_blk, _NN)
         return dk_new, dv_new
 
-    dk0 = jnp.zeros((block_kv, d), jnp.float32)
-    dv0 = jnp.zeros((block_kv, d), jnp.float32)
-    dk, dv = lax.fori_loop(lo, hi, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    d = k.shape[-1]
+    zeros = jnp.zeros((block_kv, d), jnp.float32)
+    dk, dv = lax.fori_loop(*ranges, body, (zeros, zeros))
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _bwd(q, k, v, bias, seg, o, lse, do, *, scale, causal, block_q, block_kv,
          interpret, n_heads):
     bh, t, d = q.shape
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-    )                                                     # [bh, t]
-    # broadcast row vectors over the head dim to satisfy TPU lane tiling
-    # (same layout jax's reference TPU flash kernel uses for l/m residuals)
-    lse_t = jnp.broadcast_to(lse[:, :, None], (bh, t, d))
-    delta_t = jnp.broadcast_to(delta[:, :, None], (bh, t, d))
+    n_q = t // block_q
     masked = bias is not None
     segmented = seg is not None
+    static = dict(scale=scale, causal=causal, masked=masked,
+                  segmented=segmented, block_q=block_q, block_kv=block_kv,
+                  seq_len=t)
 
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),   # q
-        pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),          # k
-        pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0)),          # v
-        pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),   # do
-        pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),   # lse
-        pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),   # delta
-    ]
-    dq_operands = [q, k, v, do, lse_t, delta_t]
-    if masked:
-        dq_specs.append(pl.BlockSpec(
-            (1, t, _LANE), lambda b, i: (b // n_heads, 0, 0)))
-        dq_operands.append(bias)
-    if segmented:
-        dq_specs.append(pl.BlockSpec(
-            (1, t, _LANE), lambda b, i: (b // n_heads, 0, 0)))
-        dq_operands.append(seg)
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal, masked=masked,
-            segmented=segmented, block_q=block_q, block_kv=block_kv,
-            seq_len=t,
-        ),
-        grid=(bh, t // block_q),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+    q_block = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    whole = pl.BlockSpec((1, t, d), lambda b, i: (b, 0, 0))
+    q_stat = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
+    aux_specs, aux = _aux_specs(bias, seg, n_heads, seg_block=block_q)
+    dq, delta = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, **static),
+        grid=(bh, n_q),
+        in_specs=[q_block, whole, whole, q_block, q_block, q_stat]
+        + aux_specs,
+        out_specs=[q_block, q_stat],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)],
         interpret=interpret,
         compiler_params=_vmem_limit(
-            _kv_resident_bytes(t, d, q.dtype.itemsize, bias, seg)),
-    )(*dq_operands)
+            _kv_resident_bytes(t, d, q.dtype.itemsize, bias)),
+    )(q, k, v, do, o, lse, *aux)
 
-    dkv_specs = [
-        pl.BlockSpec((1, t, d), lambda b, j: (b, 0, 0)),          # q
-        pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),  # k
-        pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),  # v
-        pl.BlockSpec((1, t, d), lambda b, j: (b, 0, 0)),          # do
-        pl.BlockSpec((1, t, d), lambda b, j: (b, 0, 0)),          # lse
-        pl.BlockSpec((1, t, d), lambda b, j: (b, 0, 0)),          # delta
-    ]
-    dkv_operands = [q, k, v, do, lse_t, delta_t]
-    if masked:
-        dkv_specs.append(pl.BlockSpec(
-            (1, block_kv, _LANE), lambda b, j: (b // n_heads, j, 0)))
-        dkv_operands.append(bias)
-    if segmented:
-        # the dKV kernel needs BOTH its own KV rows and arbitrary q rows of
-        # the slab: pass it full-length
-        dkv_specs.append(pl.BlockSpec(
-            (1, t, _LANE), lambda b, j: (b // n_heads, 0, 0)))
-        dkv_operands.append(seg)
+    kv_block = pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0))
+    # a head's statistics whole, a query block a row: the loop picks a row
+    stats = pl.BlockSpec((1, n_q, block_q), lambda b, j: (b, 0, 0))
+    aux_specs, aux = _aux_specs(bias, seg, n_heads, bias_block=block_kv,
+                                seg_block=block_kv)
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, masked=masked,
-            segmented=segmented, block_q=block_q, block_kv=block_kv,
-            seq_len=t,
-        ),
+        functools.partial(_bwd_dkv_kernel, **static),
         grid=(bh, t // block_kv),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
-        ],
+        in_specs=[whole, kv_block, kv_block, whole, stats, stats]
+        + aux_specs,
+        out_specs=[kv_block, kv_block],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), k.dtype),
             jax.ShapeDtypeStruct((bh, t, d), v.dtype),
         ],
         interpret=interpret,
         compiler_params=_vmem_limit(
-            _dkv_resident_bytes(t, d, q.dtype.itemsize, seg)),
-    )(*dkv_operands)
+            _dkv_resident_bytes(t, d, q.dtype.itemsize)),
+    )(q, k, v, do, lse.reshape(bh, n_q, block_q),
+      delta.reshape(bh, n_q, block_q), *aux)
     return dq, dk, dv
 
 
@@ -501,12 +596,6 @@ def _flash_fwd(q, k, v, bias, seg, scale, causal, block_q, block_kv,
     o, lse = _fwd(q, k, v, bias, seg, scale=scale, causal=causal,
                   block_q=block_q, block_kv=block_kv, interpret=interpret,
                   n_heads=n_heads)
-    # the kernel writes the log-sum-exp over all 128 lanes (the module's
-    # tiling note) and ``_fwd`` cuts one out. Left alone, XLA sinks that cut
-    # to the backward and keeps the float32 [bh, t, 128] block, twice the
-    # output's bytes, for as long as the residual lives; tied to the output,
-    # the cut is made before anything reads the output
-    o, lse = lax.optimization_barrier((o, lse))
     o = checkpoint_name(o, SAVED_NAMES[0])
     lse = checkpoint_name(lse, SAVED_NAMES[1])
     return o, (q, k, v, bias, seg, o, lse)
@@ -578,7 +667,8 @@ def flash_attention(
 ) -> jax.Array:
     """q/k/v: [B, H, T, D] → [B, H, T, D]. T must be a multiple of 128 (TPU
     lane tiling) and of the block sizes, and short enough for the backward
-    kernels' VMEM (:func:`max_seq_len`; 31,360 for bf16 at head size 128).
+    kernels' VMEM (:func:`max_seq_len`; 92,672 for bf16 at head size 128,
+    47,104 with a ``kv_mask``).
 
     ``kv_mask``: optional [B, T] boolean — True = attend to that KV position
     (padding masks for encoder models). Carried into the kernels as an
@@ -595,16 +685,15 @@ def flash_attention(
     scale = scale if scale is not None else d ** -0.5
     if t % _LANE:
         raise ValueError(f"seq len {t} must be divisible by {_LANE}")
-    need = _vmem_need(
-        _dkv_resident_bytes(t, d, q.dtype.itemsize, segment_ids))
+    need = _vmem_need(_resident_bytes(t, d, q.dtype.itemsize, kv_mask))
     if need > VMEM_CAP_BYTES:
         raise ValueError(
             f"flash_attention: seq len {t} at head size {d} needs "
-            f"~{need >> 20} MiB of VMEM in the backward (one head's Q, dO "
-            f"and row statistics stay resident), over the "
-            f"{VMEM_CAP_BYTES >> 20} MiB this kernel may ask for; the "
+            f"~{need >> 20} MiB of VMEM (every kernel keeps one head's "
+            f"full-length operands resident: K and V, or Q and dO), over "
+            f"the {VMEM_CAP_BYTES >> 20} MiB this kernel may ask for; the "
             f"longest accepted length is "
-            f"{max_seq_len(d, q.dtype, segment_ids is not None)}. Shard "
+            f"{max_seq_len(d, q.dtype, kv_mask is not None)}. Shard "
             f"the sequence (ring/Ulysses attention) or use "
             f"ops.attention.chunked_attention.")
     block_q = _pick_block(t, block_q)

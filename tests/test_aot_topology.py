@@ -410,29 +410,34 @@ _8B = llama.LlamaConfig.llama3_8b()
 _H, _KV, _D = _8B.n_heads, _8B.n_kv_heads, _8B.head_dim
 
 
-def _flash_fwd_bwd(t, one_chip):
+def _flash_fwd_bwd(t, one_chip, masked=False):
     from lzy_tpu.ops.flash_attention import flash_attention
 
     x = jax.ShapeDtypeStruct((1, _H, t, _D), jnp.bfloat16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, t), jnp.bool_, sharding=one_chip)
 
-    def loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True, interpret=False)
+    def loss(q, k, v, mask):
+        out = flash_attention(q, k, v, causal=True, interpret=False,
+                              kv_mask=mask if masked else None)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
-    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x, mask)
 
 
-@pytest.mark.parametrize("t", [2048, "longest"])
+@pytest.mark.parametrize("t", [2048, "longest", "longest_masked"])
 def test_flash_forward_and_backward_compile(t, one_chip):
     """T = 8192 (``LlamaConfig.max_seq_len``) used to fail in the backward:
     "Scoped allocation with size 21.00M and limit 16.00M". The longest
-    length the wrapper accepts must compile too, or the wrapper lies."""
+    length the wrapper accepts must compile too, with a ``kv_mask`` (whose
+    bias slab stays resident beside K and V) and without, or the wrapper
+    lies."""
     from lzy_tpu.ops.flash_attention import max_seq_len
 
-    if t == "longest":
-        t = max_seq_len(_D, jnp.bfloat16)
+    masked = t == "longest_masked"
+    if not isinstance(t, int):
+        t = max_seq_len(_D, jnp.bfloat16, masked)
         assert t >= _8B.max_seq_len
-    compiled = _flash_fwd_bwd(t, one_chip).compile()
+    compiled = _flash_fwd_bwd(t, one_chip, masked).compile()
     # forward, dQ, dK/dV: three Mosaic kernels, none interpreted
     assert compiled.as_text().count("tpu_custom_call") >= 3
 

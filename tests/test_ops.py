@@ -529,3 +529,234 @@ class TestSegmentedAttention:
                                    atol=2e-5, rtol=2e-5)
         np.testing.assert_allclose(np.asarray(out_chunk), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
+
+
+# -- the flash kernels, by what their code branches on -------------------------
+
+
+def _segments(t, cuts, b=1):
+    """[b, t] ids: a new document starts at each of ``cuts``."""
+    ids = np.searchsorted(np.asarray(cuts), np.arange(t), side="right")
+    return np.broadcast_to(ids.astype(np.int32), (b, t))
+
+
+def _dense_keep(t, causal, seg, kv_mask):
+    """[b, t, t] bool: which (query, key) the dense reference keeps."""
+    b = 1 if seg is None else seg.shape[0]
+    keep = np.ones((b, t, t), bool)
+    if causal:
+        keep &= np.tril(np.ones((t, t), bool))
+    if seg is not None:
+        runs = np.cumsum(np.concatenate(
+            [np.ones((b, 1), bool), seg[:, 1:] != seg[:, :-1]], 1), 1)
+        keep &= runs[:, :, None] == runs[:, None, :]
+    if kv_mask is not None:
+        keep = keep & np.asarray(kv_mask)[:, None, :]
+    return keep
+
+
+def _dense_attention(q, k, v, keep):
+    """float32 softmax attention over ``keep``; a query that keeps no key
+    gives zero (and takes no gradient)."""
+    keep = jnp.asarray(keep)[:, None]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * (q.shape[-1] ** -0.5)
+    p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
+    return jnp.where(keep.any(-1)[..., None], out, 0.0)
+
+
+#: name -> (causal, document cuts or None, kv_mask's dropped keys or None,
+#: block_q, block_kv), all at t = 512: 4 x 4 pairs of 128, or rectangles
+_T = 512
+FLASH_CASES = {
+    # a document edge inside a block: edge pairs on both sides of it
+    "edge_inside_a_block": (True, [200, 330], None, 128, 128),
+    # a document that spans several blocks: pairs that keep every element
+    "document_spans_blocks": (True, [400], None, 128, 128),
+    "edges_on_block_boundaries": (True, [128, 384], None, 128, 128),
+    "one_document_a_row": (True, [], None, 128, 128),
+    "causal_no_segments": (True, None, None, 128, 128),
+    "no_mask_at_all": (False, None, None, 128, 128),
+    "bidirectional_segments": (False, [200, 330], None, 128, 128),
+    "bidirectional_document_spans_blocks": (False, [60, 460], None,
+                                            128, 128),
+    # keys 0-2 dropped under a causal mask: queries 0-2 see nothing
+    "kv_mask_fully_masked_rows": (True, None, [0, 1, 2], 128, 128),
+    "kv_mask_bidirectional": (False, None, list(range(300, 512)), 128, 128),
+    "kv_mask_with_segments": (False, [200, 330], list(range(200, 330)),
+                              128, 128),
+    "wide_queries": (True, [100, 400], None, 256, 128),
+    "wide_keys": (True, [100, 400], None, 128, 256),
+    "wide_keys_bidirectional": (False, [100, 400], None, 128, 256),
+}
+
+
+class TestFlashBranches:
+    """Forward and all three gradients against the dense float32 reference,
+    over everything the kernels branch on: where the documents' edges fall
+    against the blocks (inside one, on their boundaries, blocks apart), the
+    causal flag, a ``kv_mask`` bias, rectangular blocks, the input dtype."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", sorted(FLASH_CASES))
+    def test_forward_and_gradients_match_dense(self, case, dtype):
+        causal, cuts, dropped, block_q, block_kv = FLASH_CASES[case]
+        q, k, v = make_qkv(b=1, h=2, t=_T, d=64, dtype=dtype, seed=3)
+        do = jax.random.normal(jax.random.PRNGKey(9), q.shape, dtype)
+        seg = None if cuts is None else _segments(_T, cuts)
+        kv_mask = None
+        if dropped is not None:
+            kv_mask = np.ones((1, _T), bool)
+            kv_mask[:, dropped] = False
+        keep = _dense_keep(_T, causal, seg, kv_mask)
+
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
+                segment_ids=None if seg is None else jnp.asarray(seg),
+                kv_mask=None if kv_mask is None else jnp.asarray(kv_mask)),
+            q, k, v)
+        ref, ref_vjp = jax.vjp(
+            lambda q, k, v: _dense_attention(q, k, v, keep), q, k, v)
+        assert out.dtype == dtype
+        # bfloat16: p and ds are rounded to 8 bits before their products,
+        # as the outputs are
+        tol = dict(atol=2e-5, rtol=2e-5) if dtype == jnp.float32 \
+            else dict(atol=4e-2, rtol=4e-2)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), **tol)
+        tol = dict(atol=1e-4, rtol=1e-4) if dtype == jnp.float32 \
+            else dict(atol=8e-2, rtol=4e-2)
+        got = vjp(do)
+        want = ref_vjp(do.astype(jnp.float32))
+        for name, a, b in zip("qkv", got, want):
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                err_msg=f"d{name}", **tol)
+        if case == "kv_mask_fully_masked_rows":
+            assert not np.asarray(out[:, :, :3], np.float32).any()
+            assert not np.asarray(got[0][:, :, :3], np.float32).any()
+
+    def test_products_take_their_operands_as_loaded(self):
+        """Every product of the three kernels multiplies operands of the
+        input dtype (bfloat16 in, bfloat16 on the matrix unit) and
+        accumulates in float32: nothing is widened before it is multiplied,
+        and ``p`` / ``ds`` are cast down once, where the parent's float32
+        product rounded them inside."""
+        from jax.extend import core as jex_core
+
+        q, k, v = make_qkv(b=1, h=2, t=256, d=64, dtype=jnp.bfloat16)
+        seg = jnp.asarray(_segments(256, [100]))
+
+        def fwd_bwd(q, k, v):
+            out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, segment_ids=seg, block_q=128,
+                block_kv=128), q, k, v)
+            return vjp(out)
+
+        def walk(jaxpr, inside_kernel, found):
+            for eqn in jaxpr.eqns:
+                kernel = inside_kernel or eqn.primitive.name == "pallas_call"
+                if eqn.primitive.name == "dot_general" and inside_kernel:
+                    found.append(([x.aval.dtype for x in eqn.invars],
+                                  eqn.outvars[0].aval.dtype))
+                for param in eqn.params.values():
+                    for sub in (param if isinstance(param, (list, tuple))
+                                else [param]):
+                        if isinstance(sub, jex_core.ClosedJaxpr):
+                            walk(sub.jaxpr, kernel, found)
+                        elif isinstance(sub, jex_core.Jaxpr):
+                            walk(sub, kernel, found)
+            return found
+
+        products = walk(jax.make_jaxpr(fwd_bwd)(q, k, v).jaxpr, False, [])
+        # 2 forward, 3 dQ, 4 dK/dV
+        assert len(products) == 9, len(products)
+        for operands, result in products:
+            assert operands == [jnp.bfloat16, jnp.bfloat16], operands
+            assert result == jnp.float32
+
+
+class TestBlockPairCensus:
+    """``block_pair_census`` and the loop bounds it shares with the kernels
+    (``_pair_ranges``) against brute force over the dense mask: a pair is
+    needed when the mask keeps anything of it, and the kernels visit exactly
+    the needed pairs, from either side."""
+
+    @staticmethod
+    def _cases():
+        rng = np.random.default_rng(5)
+        t = 1024
+        out = [("one_document", np.zeros((2, t), np.int32)),
+               ("on_boundaries", _segments(t, [256, 512, 768], b=2))]
+        for n in (1, 3, 9):
+            rows = [_segments(t, np.sort(rng.choice(
+                np.arange(1, t), size=n, replace=False)))[0]
+                for _ in range(3)]
+            out.append((f"{n}_cuts", np.stack(rows)))
+        return out
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (128, 256),
+                                        (512, 256)])
+    def test_census_matches_the_dense_mask(self, blocks, causal):
+        from lzy_tpu.ops.flash_attention import (
+            _HOST_OPS, _KERNEL_OPS, _pair_ranges, block_pair_census,
+            segment_slab)
+
+        bq, bkv = blocks
+        for name, seg in self._cases():
+            b, t = seg.shape
+            keep = _dense_keep(t, causal, seg, None)
+            tiles = keep.reshape(b, t // bq, bq, t // bkv, bkv)
+            needed = tiles.any((2, 4))
+            census = block_pair_census(seg, bq, bkv, causal)
+            assert census.needed == needed.sum(), name
+            assert census.visited == needed.sum(), name
+
+            # the same bounds as a kernel computes them, block by block and
+            # from the key side too (the dK/dV kernel's loop), over the
+            # lanes the kernels read
+            slab = np.asarray(segment_slab(jnp.asarray(seg)))
+            start, end = (slab[:, :, lane].astype(np.int64)
+                          for lane in (1, 2))
+            for q_major, own, other in ((True, bq, bkv), (False, bkv, bq)):
+                for row in range(b):
+                    for blk in range(t // own):
+                        lo_, hi_ = blk * own, (blk + 1) * own - 1
+                        docs = (start[row, lo_], end[row, hi_])
+                        lo, hi = (int(x) for x in _pair_ranges(
+                            lo_, own, other, t // other, docs, causal=causal,
+                            q_major=q_major, ops=_HOST_OPS))
+                        want_any = needed[row, blk] if q_major \
+                            else needed[row, :, blk]
+                        others = np.arange(t // other)
+                        assert ((others >= lo) & (others < hi)
+                                == want_any).all(), (name, q_major, blk)
+            # and in the kernels' own arithmetic (traced scalars)
+            docs = (start[0, 0], end[0, bq - 1])
+            traced = _pair_ranges(jnp.int32(0), bq, bkv, t // bkv,
+                                  tuple(jnp.int32(x) for x in docs),
+                                  causal=causal, q_major=True,
+                                  ops=_KERNEL_OPS)
+            host = _pair_ranges(0, bq, bkv, t // bkv, docs, causal=causal,
+                                q_major=True, ops=_HOST_OPS)
+            assert [int(x) for x in traced] == [int(x) for x in host]
+
+    def test_no_documents_leaves_the_diagonal_alone(self):
+        from lzy_tpu.ops.flash_attention import _HOST_OPS, _pair_ranges
+
+        assert _pair_ranges(512, 128, 128, 8, None, causal=True,
+                            q_major=True, ops=_HOST_OPS) == (0, 5)
+        assert _pair_ranges(512, 128, 128, 8, None, causal=True,
+                            q_major=False, ops=_HOST_OPS) == (4, 8)
+
+    def test_no_segments_is_one_document_a_row(self):
+        from lzy_tpu.ops.flash_attention import block_pair_census
+
+        # 8 x 8 blocks: the 36 on or under the diagonal
+        one = np.zeros((1, 1024), np.int32)
+        assert block_pair_census(one, 128, 128, True) == (36, 36)
+        assert block_pair_census(one, 128, 128, False) == (64, 64)
