@@ -276,7 +276,7 @@ def audit_engine(engine) -> None:
     # page-table row stays scratch until activation — decode rounds
     # interleaved with the prefill write garbage only to block 0)
     free_set = set(kv.pool._free)
-    for job in getattr(engine, "_prefill_jobs", ()):
+    for job in engine.prefill.jobs:
         if engine._active[job.slot] is not None:
             raise InvariantViolation(
                 f"prefill job for {job.req.id} reserves slot {job.slot} "

@@ -1288,11 +1288,10 @@ class GatewayService:
         """One tenant's own counters — what a non-operator subject gets
         from ``InferStats`` (fleet internals are the operator's; a
         tenant's numbers are its own)."""
-        rows = self.fleet.aggregate_tenants()
-        row = rows.get(tenant, {
-            "requests_finished": 0, "tokens_generated": 0,
-            "requests_cancelled": 0, "requests_preempted": 0,
-            "requests_error": 0, "queue_depth": 0})
+        from lzy_tpu.serving.tenancy import TENANT_ROW
+
+        row = self.fleet.aggregate_tenants().get(
+            tenant, dict(TENANT_ROW, queue_depth=0))
         return {"model": self.model_name, "gateway": True,
                 "tenant": tenant, **row}
 

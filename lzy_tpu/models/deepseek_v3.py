@@ -236,7 +236,7 @@ class DeepseekV3Config:
     def widest_prefill(self) -> int:
         """The widest prefill program this model's kernels take: 256. At
         the Moonlight-16B-A3B widths (27 layers, 16 of 64 experts held) the
-        engine's own ``_prefill_step`` of 16 / 64 / 128 / 256 positions,
+        engine's own ``prefill_step`` of 16 / 64 / 128 / 256 positions,
         continuing a prompt at position 2048, takes 30.0 / 33.3 / 34.7 /
         40.3 ms on a v5e chip (host clock around the dispatch, median of
         seven; at 6144: 30.6 / 35.3 / 38.8 / 47.2): 0.16 ms a position at

@@ -144,7 +144,7 @@ class SolarOpen2Config(HeadPool):
     def widest_prefill(self) -> int:
         """The widest prefill program this model's kernels take: 256. At
         the Solar-Open2-250B widths (8 layers, 40 of 320 experts held) the
-        engine's own ``_prefill_step`` of 16 / 64 / 128 / 256 positions,
+        engine's own ``prefill_step`` of 16 / 64 / 128 / 256 positions,
         continuing a prompt at position 2048, takes 9.4 / 14.9 / 19.8 /
         25.8 ms on a v5e chip (host clock around the dispatch, median of
         seven): 0.101 ms a position at 256 against 0.155 at 128. The

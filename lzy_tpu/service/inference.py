@@ -204,13 +204,11 @@ class InferenceService:
         subject = self._auth(token)
         if subject is not None:
             from lzy_tpu.iam import INTERNAL
+            from lzy_tpu.serving.tenancy import TENANT_ROW
 
             if subject.role != INTERNAL:
-                rows = self.engine.stats_by_tenant()
-                row = rows.get(subject.id, {
-                    "requests_finished": 0, "tokens_generated": 0,
-                    "requests_cancelled": 0, "requests_preempted": 0,
-                    "requests_error": 0, "queue_depth": 0})
+                row = self.engine.stats_by_tenant().get(
+                    subject.id, dict(TENANT_ROW, queue_depth=0))
                 return {"model": self.model_name, "tenant": subject.id,
                         **row}
         return {"model": self.model_name, **self.engine.stats().doc(),

@@ -23,19 +23,11 @@ in ``models/serving.py``): this file names no model.
   the TPU, the sharded
   gang's read, and the tests' bit-exact reference against
   ``models/generate.py``.
-- **Prefill on arrival, in budgeted chunks**: a prompt's suffix runs
-  through the model as batch-1 bucketed chunks against the same pool, at
-  most ``prefill_budget`` tokens a scheduling round, interleaved with the
-  resident rows' decode steps; the finished job's slot starts generating
-  on the very next step. A chunk is as wide as the round's budget allows
-  and the model says its kernels take (``models/generate.py``
-  ``prefill_width``): a program reads the weights once whatever its width,
-  so a budget of 256 is one program of 256 positions, not four of 64. A
-  model with per-slot ``state`` leaves carries the job's own batch-1 rows
-  between chunks. A round's prefill is ONE device call, as its decode is:
-  the jitted ``prefill_step`` builds its index leaves, cuts its chunk out
-  of the job's prompt buffer (uploaded once, in one shape for every
-  prompt) and picks the first token inside the program.
+- **Prefill on arrival, in budgeted chunks** (``serving/prefill.py``, one
+  object the engine owns): a prompt's suffix runs through the model as
+  batch-1 bucketed chunks against the same pool, at most ``prefill_budget``
+  tokens a scheduling round, interleaved with the resident rows' decode
+  steps; the finished job's slot starts generating on the very next step.
 - **One jitted step a round, one fence**: the decode hot loop is ONE jitted
   step over the ``[slots]`` rows, whose positions live in one ``[slots]``
   vector; a finished row leaves its slot immediately (its blocks go back to
@@ -66,18 +58,11 @@ occupancy are exported via ``lzy_tpu.utils.metrics.REGISTRY`` (scraped by
 ``/metrics`` on both the console and the metrics server).
 
 With ``spec_tokens > 0`` the engine runs **draft-free speculative
-decoding** (``lzy_tpu/serving/spec.py``): an n-gram prompt-lookup
-proposer drafts up to ``spec_tokens`` continuation tokens per greedy row,
-ONE multi-position verify forward scores all of them (``[slots,
-spec_tokens+1]`` query positions — a fixed width, so exactly one extra
-compiled program), and the longest proposal prefix matching the model's
-own argmax is accepted — up to ``spec_tokens+1`` tokens per decode step,
-bit-identical to non-speculative greedy decode by construction. Rejected
-positions are rolled back: the per-row cache index rewinds and any
-wholly-rejected growth block returns to the pool (refcounted/resident
-blocks are never touched), so a failed speculation is invisible to the
-radix cache. Sampled rows in the same batch decode one token per step
-from the same rng draw order as before.
+decoding** (``lzy_tpu/serving/spec.py`` has the design): proposals for the
+greedy rows are scored by ONE verify forward of a fixed width (exactly one
+extra compiled program), accepted on the device (``spec.accept``) and the
+rejected tail rolled back (``_decode_verify``, ``_post_verify_rollback``),
+so a failed speculation is invisible to the radix cache and to the tokens.
 
 ``serving/sharded`` (a gang over a mesh) and ``serving/disagg`` (prefill
 and decode pools) subclass the engine and change where arrays live or what
@@ -99,21 +84,25 @@ import numpy as np
 
 from lzy_tpu.chaos.faults import CHAOS, CRASH, DELAY, ERROR, SLOW
 from lzy_tpu.models import serving
-from lzy_tpu.models.generate import (
-    draw_token, init_cache, prefill_plan, prefill_width, sample_token)
+from lzy_tpu.models.generate import init_cache, prefill_width, sample_token
+from lzy_tpu.serving.kv_cache import (
+    NoFreeBlocks, RadixCache, WindowPages, blocks_for, divide_pool,
+    window_bound)
 from lzy_tpu.serving.kv_io import (  # noqa: F401 — the two errors are
     # what the engine's refusals raise, and are imported from here
     KvIO, StateLeavesUnsupported, WindowLeavesUnsupported, leaf_refusal)
+from lzy_tpu.serving.prefill import Job, Prefill, ProgramBuild
 from lzy_tpu.serving.scheduler import (
     AdmissionError, PromptTooLong, Request, RequestQueue)
 from lzy_tpu.serving.tenancy import (
-    TENANT_KV_BLOCKS, TENANT_REQUESTS, TENANT_TOKENS, TENANT_TTFT)
+    TENANT_KV_BLOCKS, TENANT_REQUESTS, TENANT_ROW, TENANT_TOKENS, TENANT_TTFT)
 from lzy_tpu.serving.spec import (
-    ACCEPT_RATE as _SPEC_RATE, ACCEPTED as _SPEC_ACCEPTED,
+    ACCEPT_RATE as _SPEC_RATE, ACCEPTED as _SPEC_ACCEPTED, accept,
     DRAFT_TRUNCATED as _SPEC_TRUNCATED, NgramProposer,
     PROPOSED as _SPEC_PROPOSED, TOKENS_PER_STEP as _SPEC_TPS,
     VERIFY_STEPS as _SPEC_STEPS)
 from lzy_tpu.utils import jaxenv, trace
+from lzy_tpu.utils.clock import SYSTEM_CLOCK
 from lzy_tpu.utils.log import get_logger
 from lzy_tpu.utils.metrics import REGISTRY
 
@@ -159,31 +148,6 @@ _FP_STEP = CHAOS.register(
 _FP_PREFILL = CHAOS.register(
     "engine.prefill", crash_ok=True, modes=(ERROR, DELAY, SLOW, CRASH),
     doc="paged prefill device section (pool donated -> engine-fatal)")
-
-_PREFILL_ROUNDS = REGISTRY.counter(
-    "lzy_inference_prefill_rounds_total",
-    "bounded prefill rounds run between decode steps (chunked prefill)")
-
-# what prefill programs carry: a program costs a read of the weights
-# whatever its width, so tokens / programs says how well a round's budget
-# is spent, and positions - tokens what the last chunk's pad costs
-_PREFILL_TOKENS = REGISTRY.counter(
-    "lzy_engine_prefill_tokens_total",
-    "prompt tokens forwarded by prefill programs (real tokens, no pads)")
-_PREFILL_PROGRAMS = REGISTRY.counter(
-    "lzy_engine_prefill_programs_total",
-    "prefill programs dispatched (one a chunk of a plan)")
-_PREFILL_POSITIONS = REGISTRY.counter(
-    "lzy_engine_prefill_positions_total",
-    "positions prefill programs ran over: their widths, pads included")
-# counted where the engine makes them, in the prefill phase: a round is one
-# (the program; a job's buffer rides in its first dispatch), a prompt's
-# last adds the rng's split and a state model's splice of its rows
-PREFILL_CALLS = REGISTRY.counter(
-    "lzy_engine_prefill_device_calls_total",
-    "device calls of the prefill phase: programs, the rng's split a "
-    "finished prompt, state rows made or spliced, an upload made apart "
-    "from a program (a gang's)")
 
 # decode-round scheduling (docs/serving.md "Decode-round scheduling"):
 # each round dispatches ONE fused device program and takes ONE
@@ -287,66 +251,6 @@ def _setup_phase(name: str, phase: str, site: Optional[str] = None):
                     jaxenv.thread_build_seconds() - built0, phase=phase)
         return timed
     return decorate
-
-
-# per-slot state (models/serving.py, cache-leaf kind ``state``): a prefill
-# job of a model with state leaves starts from a zeroed batch-1 row and the
-# engine splices it into the slot's row when the prompt is done
-_STATE_RESETS = REGISTRY.counter(
-    "lzy_state_slots_reset_total",
-    "per-slot state rows started from zero for a newly admitted request "
-    "(models with state cache leaves)")
-
-
-# what a prefill program is told about its chunk, one row of five int32 a
-# chunk of the job's plan, written into the job's buffer when it is staged:
-# where the chunk starts (the position of its first token in the prompt,
-# which is also the cache index it writes from), how many of its positions
-# are real, the row's sampling mode, whether the job's state rows start
-# from zero, and the length of the prompt the request was admitted with
-# (handed to a model that asks for it: ``TOLD_PROMPT_LEN``)
-_CTL_START, _CTL_TAKE, _CTL_GREEDY, _CTL_FRESH, _CTL_PROMPT, _CTL_LEN = \
-    range(6)
-
-
-@dataclasses.dataclass
-class _PrefillJob:
-    """One admitted request's in-progress prefill. With a
-    ``prefill_budget`` the engine advances jobs at most ``budget``
-    prompt tokens per scheduling round, interleaved with decode steps,
-    so a 32k-token prompt can never freeze resident rows' token streams.
-    The chunk *plan* is fixed at staging (identical to the one-shot
-    path at the same chunk width, which follows the budget), so pausing
-    between chunks changes scheduling, never numerics — greedy output
-    stays bit-identical to an uncontended run."""
-
-    req: Request
-    slot: int                       # reserved; activates on completion
-    plan: list                      # [(start, take, width)] over suffix
-    next_chunk: int = 0
-    done: int = 0                   # suffix tokens already prefilled
-    matched: int = 0                # radix-matched prompt prefix
-    table: list = dataclasses.field(default_factory=list)  # pool blocks
-    # everything the job's programs are told, in ONE int32 array of one
-    # shape whatever the prompt's length (a 32k prompt at budget 256 runs
-    # ~128 rounds, and no program or transfer may follow the length):
-    # ``[1, n]`` = the cursor (which chunk runs next), a ``_CTL_*`` row a
-    # chunk of the plan, the page table, and the whole prompt from
-    # position 0 followed by the pad id as far as ``max_seq_len`` plus the
-    # widest chunk (``_job_layout``). A host array until the job's first
-    # program, in whose dispatch it rides; every program hands it back on
-    # the device with the cursor moved on, so the rounds after the first
-    # upload nothing
-    inputs: Any = None
-    # state leaves (models with per-slot state): the job's
-    # own batch-1 rows, carried from program to program — the slot's rows
-    # in the decode tree are not touched until the prompt is done. None
-    # until the job's first program, which starts them from zero
-    state: Any = None
-    # window leaves (models/serving.py): the job's own row of window pages
-    # (``kv_cache.WindowRow``), grown and shed chunk by chunk; the slot's
-    # once the prompt is done
-    window: Any = None
 
 
 @dataclasses.dataclass
@@ -492,8 +396,6 @@ class PagedInferenceEngine:
         from lzy_tpu.ops.interpret import resolve as pallas_interpreted
         from lzy_tpu.ops.paged_attention import (
             DISPATCHES, QUANT_BLOCKS_RESIDENT, default_kernel)
-        from lzy_tpu.serving.kv_cache import (
-            RadixCache, WindowPages, window_bound)
 
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
@@ -562,8 +464,6 @@ class PagedInferenceEngine:
         # and the loop's idle park all run on it, so a virtual clock can
         # drive the whole engine deterministically; the system default
         # is bit-identical to the old time.monotonic()/sleep() calls
-        from lzy_tpu.utils.clock import SYSTEM_CLOCK
-
         self._clock = clock if clock is not None else SYSTEM_CLOCK
         self.eos_token = eos_token
         # the width of a prefill program follows the round's budget and
@@ -608,10 +508,9 @@ class PagedInferenceEngine:
         # between rounds and the host uploads nothing; only admission
         # (``_finish_prefill``) forces a re-upload, from host mirrors that
         # a round in flight has not reached yet: it is drained first. Idle
-        # rows drift in
-        # the device copies (stale token/position garbage) — harmless by
-        # construction: rows are independent, idle writes land on the
-        # scratch block, and idle outputs are never read.
+        # rows drift in the device copies (stale token/position garbage) —
+        # harmless by construction: rows are independent, idle writes land
+        # on the scratch block, and idle outputs are never read.
         self._cur_dev: Any = None        # [slots] int32 last tokens
         self._pos_dev: Any = None        # [slots] int32 cache positions
         self._mask_dev: Any = None       # [slots] bool greedy mask
@@ -662,7 +561,7 @@ class PagedInferenceEngine:
             most = slots * window_bound(window, self.prefill_chunk,
                                         page_size, self._pages_per_seq) + 1
             if kv_pool_bytes is not None and kv_window_blocks is None:
-                kv_window_blocks, kv_pool_bytes = self._divide_pool(
+                kv_window_blocks, kv_pool_bytes = divide_pool(
                     kv_pool_bytes, base, most,
                     slots * self._pages_per_seq + 1, page_size)
             elif kv_window_blocks is None:
@@ -760,27 +659,31 @@ class PagedInferenceEngine:
             storage_tier=kv_storage_tier,
             mesh_shape=getattr(self, "kv_mesh_shape", None))
 
-        # chunked-prefill interleaving: at most ``prefill_budget`` prompt
-        # tokens advance per scheduling round (None = whole prompt in one
-        # round, the pre-tenancy behavior), as one program where a bucket
-        # is as wide as the budget (``prefill_chunk`` above); jobs rotate
-        # round-robin so a short prompt staged behind a long one completes
-        # in O(1) rounds
         self.prefill_budget = (None if prefill_budget is None
                                else int(prefill_budget))
-        self._prefill_jobs: List[_PrefillJob] = []
-        # prompts staged at once at most (None: one a free slot). A job of a
-        # model with state leaves holds its own batch-1 row of every one
-        # until it is spliced in, so a burst of prompts holds as many copies
-        # of a slot's state as it has jobs: where a slot's state is large, a
-        # deployment bounds them here and the rest wait in the queue
-        self._max_prefill_jobs = max_prefill_jobs
-        self._next_prefill = 0
-        self.prefill_rounds = 0         # public: interleave observability
+        # a staged prompt from its admission to its first token
+        # (serving/prefill.py): the scheduler stages, advances, reaps and
+        # closes it, and is told how each job ended
+        self.prefill = Prefill(
+            base, self._model, self.params,
+            leaf_kinds=self._leaf_kinds, treedef=self._cache_treedef,
+            state_at=self._state_at, pool_at=self._pool_at,
+            build=self._programs, kv=self.kv, kv_io=self.kv_io,
+            win=self._win, page_size=page_size, pooled=self._pooled,
+            chunk=self.prefill_chunk, budget=self.prefill_budget,
+            max_jobs=max_prefill_jobs,
+            sampling=(temperature, top_k, top_p),
+            tells_real=self._tells_real, clock=self._clock,
+            payload=lambda: self._payload, rng=lambda: self._rng,
+            set_rng=lambda key: setattr(self, "_rng", key),
+            row_greedy=self._row_greedy, first=self._first,
+            count_dispatch=self._count_dispatch, drain=self._drain,
+            enter=lambda: CHAOS.hit("engine.prefill"), fatal=PoolCorruption,
+            finished=self._prompt_done, failed=self._fail_request,
+            cancelled=self._finish_cancelled)
         # what the round's decode half did, for the engine.round span
         self._round_kind: Optional[str] = None
         self._round_rows = self._round_emitted = 0
-        self._prefill_wait = 0.0        # this round's prefill fence
         # per-tenant SLO state: policy table (WFQ weights, queue caps, KV
         # quotas) and terminal accounting for the scoped stats surface
         self.tenants = tenants
@@ -816,26 +719,6 @@ class PagedInferenceEngine:
         _SLOTS.set(float(slots))
         _BUSY.set(0.0)
 
-
-    @staticmethod
-    def _divide_pool(pool_bytes: int, base: Any, most_window: int,
-                     most_paged: int, page_size: int) -> tuple:
-        """``kv_pool_bytes`` between the two kinds of page of a model with
-        ``window`` leaves: ``(window blocks, bytes for the paged kind)``.
-        Each kind gets what ``slots`` rows at ``max_seq_len`` come to at
-        their most (``most_window``, ``most_paged`` blocks: a row never
-        holds more, and with the prefix cache off nothing else would), if
-        the budget covers both; where it does not, each gets its share of
-        the budget in proportion to that."""
-        token = base.kv_token_bytes(None)
-        per_window = page_size * base.window_layers * token
-        want_window = most_window * per_window
-        want_paged = most_paged * page_size * base.kv_layers * token
-        if want_window + want_paged <= pool_bytes:
-            return most_window, want_paged
-        for_window = pool_bytes * want_window // (want_window + want_paged)
-        return max(2, for_window // per_window), pool_bytes - for_window
-
     # -- cache payload/treedef split ---------------------------------------
 
     def _adopt_cache(self, tree) -> None:
@@ -870,13 +753,6 @@ class PagedInferenceEngine:
         # a model with state leaves, or one that counts (``STATS``), is
         # told which positions of a program are real (``valid_len``)
         self._tells_real = self._has_state or bool(type(self._model).STATS)
-        # a model whose read of a request follows the length it was admitted
-        # with is told it in every prefill program (``prompt_len``)
-        self._tells_prompt_len = getattr(
-            type(self._model), "TOLD_PROMPT_LEN", False)
-        # the batch-1 state rows finished (or abandoned) prefill jobs no
-        # longer need: the next job's first program starts from them
-        self._spare_state: List[list] = []
 
     def state_leaves(self) -> Dict[str, Any]:
         """The per-slot state leaves of the decode tree as they stand, by
@@ -909,42 +785,6 @@ class PagedInferenceEngine:
                        in zip(leaves, self._leaf_is_index) if idx)
         return payload, new_pos
 
-    def _accept(self, prop, prop_len, greedy, nxt, pos):
-        """On-device speculative acceptance (traced inside verify_step).
-
-        Per row: the longest proposal prefix matching the model's own
-        argmax (``m``), the accepted tokens plus the bonus token after
-        them for speculating rows, or the single position-0 pick for
-        sampled/no-draft rows — bit-identical to the host loop it
-        replaces (``m`` via cumprod-of-matches is exactly the while-loop
-        prefix walk). Returns ``(packed [B, gamma+2], new_cur [B],
-        new_pos [B])`` where ``packed[:, :gamma+1]`` are emit tokens,
-        ``packed[:, gamma+1]`` the per-row emit count — ONE array, ONE
-        host transfer for the whole round."""
-        width = prop.shape[1] + 1            # gamma + 1
-        cols = jnp.arange(width - 1, dtype=jnp.int32)
-        ok = (prop == greedy[:, :-1]) & (cols[None, :] < prop_len[:, None])
-        m = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
-        spec = prop_len > 0                  # rows with a live draft
-        bonus = jnp.take_along_axis(greedy, m[:, None], axis=1)[:, 0]
-        allc = jnp.arange(width, dtype=jnp.int32)
-        prop_w = jnp.pad(prop, ((0, 0), (0, 1)))
-        emit = jnp.where(allc[None, :] < m[:, None], prop_w,
-                         jnp.where(allc[None, :] == m[:, None],
-                                   bonus[:, None], 0))
-        # non-speculating rows emit exactly the position-0 pick (sampled
-        # rows keep their draw; greedy no-draft rows get argmax — which
-        # equals the m=0 bonus, so the where is a no-op for them)
-        emit = emit.at[:, 0].set(jnp.where(spec, emit[:, 0], nxt))
-        count = jnp.where(spec, m + 1, 1).astype(jnp.int32)
-        new_cur = jnp.take_along_axis(emit, (count - 1)[:, None],
-                                      axis=1)[:, 0]
-        packed = jnp.concatenate([emit, count[:, None]], axis=1)
-        # rows advance by exactly what they emit — the rollback the host
-        # used to do by rewriting index leaves after the fact is now the
-        # step's own output, exact by construction
-        return packed, new_cur, pos + count
-
     # -- sampling helpers --------------------------------------------------
 
     def _pick_next(self, logits, greedy_mask, rng):
@@ -958,21 +798,6 @@ class PagedInferenceEngine:
         nxt = jnp.where(
             greedy_mask, jnp.argmax(logits, axis=-1).astype(jnp.int32), nxt)
         return nxt, rng
-
-    def _pick_first(self, logits, row_greedy, key):
-        """First-token pick after prefill, inside ``prefill_step``, over
-        the one row of a prefill program. ``key`` is the spent half of the
-        rng's one split a finished prompt (the same discipline as
-        :meth:`_pick_next`; the split itself is ``_split_rng``, outside
-        the program); ``row_greedy`` is the request's own sampling mode
-        (:meth:`_row_greedy`), a traced flag."""
-        tok = draw_token(logits, self._temperature, key,
-                         top_k=self._top_k, top_p=self._top_p)
-        if self._temperature > 0.0:
-            tok = jnp.where(
-                row_greedy, jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                tok)
-        return tok
 
     def _row_greedy(self, req: Request) -> bool:
         """Effective sampling mode for a request: its own override, else
@@ -1099,7 +924,6 @@ class PagedInferenceEngine:
         lag = threading.get_ident() == self._loop_ident
         self._round_kind = None
         self._round_rows = self._round_emitted = 0
-        self._prefill_wait = 0.0
         if self._round_builds:
             self._round_builds = []
         with trace.span(trace.ENGINE_ROUND) as rnd:
@@ -1109,7 +933,7 @@ class PagedInferenceEngine:
             t1 = now()
             kv_io_dt = self._less_drains(t1 - t0)
             if CHAOS.armed is not None and (
-                    self.queue.depth() or self._prefill_jobs
+                    self.queue.depth() or self.prefill.jobs
                     or any(r is not None for r in self._active)):
                 # chaos boundary, hit only on rounds with real work so a
                 # parked loop's idle spins don't consume the fault
@@ -1124,20 +948,21 @@ class PagedInferenceEngine:
                 admitted = self._admit()
             t3 = now()
             with trace.span(trace.ENGINE_PREFILL):
-                progressed = self._advance_prefill()
+                progressed = self.prefill.advance()
             t4 = now()
             # a finished prompt drains the round in flight before it waits
             # for its first token: that fence and emit are observed as
             # such, not as prefill
-            prefill_dt = self._less_drains(t4 - t3) - self._prefill_wait
+            fence_wait = self.prefill.fence_wait
+            prefill_dt = self._less_drains(t4 - t3) - fence_wait
             stepped = self._decode(lag)
             # observed after the round's fence, like the decode half's
             self._observe_phase("kv_io", kv_io_dt)
             self._observe_phase("reap", t2 - t1)
             self._observe_phase("admit", t3 - t2)
             self._observe_phase("prefill", prefill_dt)
-            if self._prefill_wait:  # only a round that finished a prompt
-                self._observe_phase("prefill_fence", self._prefill_wait)
+            if fence_wait:      # only a round that finished a prompt
+                self._observe_phase("prefill_fence", fence_wait)
             worked = serviced or admitted or progressed or stepped
             if rnd and stepped:
                 trace.note(kind=self._round_kind, rows=self._round_rows,
@@ -1165,16 +990,9 @@ class PagedInferenceEngine:
         tokens stay readable)."""
         for req in self.queue.reap_dead():
             self._finish_cancelled(req)
-        for job in list(self._prefill_jobs):
-            if job.req.reapable:
-                # a mid-prefill abandon releases everything staged (the
-                # job's blocks go back to the pool)
-                self._abort_prefill_job(job)
-                self._finish_cancelled(job.req)
+        self.prefill.reap()
         for slot, req in enumerate(self._active):
-            if req is None:
-                continue
-            if req.reapable:
+            if req is not None and req.reapable:
                 # free BEFORE finishing: finish() wakes the waiter, and a
                 # client that sees its request done must also see the
                 # slot/blocks released (stats read-your-writes)
@@ -1204,21 +1022,15 @@ class PagedInferenceEngine:
 
     def _tenant_count(self, tenant: str, key: str, n: int = 1) -> None:
         with self._tenant_counts_lock:
-            d = self._tenant_counts.get(tenant)
-            if d is None:
-                d = self._tenant_counts[tenant] = {
-                    "requests_finished": 0, "tokens_generated": 0,
-                    "requests_cancelled": 0, "requests_preempted": 0,
-                    "requests_error": 0}
-            d[key] += n
+            self._tenant_counts.setdefault(
+                tenant, dict(TENANT_ROW))[key] += n
 
     def _free_slot(self) -> Optional[int]:
         """A slot neither active nor reserved by a pending prefill job;
         none while ``max_prefill_jobs`` prompts are staged."""
-        if self._max_prefill_jobs is not None \
-                and len(self._prefill_jobs) >= self._max_prefill_jobs:
+        if self.prefill.full:
             return None
-        reserved = {job.slot for job in self._prefill_jobs}
+        reserved = self.prefill.slots()
         for slot, req in enumerate(self._active):
             if req is None and slot not in reserved:
                 return slot
@@ -1233,22 +1045,23 @@ class PagedInferenceEngine:
         if req.admitted_at is None:
             req.admitted_at = self._clock.now()
         try:
-            job = self._stage_prefill(slot, req)
-        except PoolCorruption:
-            raise        # engine-fatal: the shared pool was donated
+            job = self.prefill.stage(slot, req)
         except Exception as e:  # noqa: BLE001 — request-scoped
-            _LOG.warning("prefill staging failed for %s: %s", req.id, e)
-            _REQUESTS.inc(status="error")
-            TENANT_REQUESTS.inc(tenant=req.tenant, status="error")
-            self._tenant_count(req.tenant, "requests_error")
-            req.finish(error=f"{type(e).__name__}: {e}")
+            self._fail_request(req, e, "prefill staging")
             return False
-        self._prefill_jobs.append(job)
         if trace.ON:
             trace.note(request=req.id, prompt_tokens=len(req.prompt),
-                       prefix_hit_tokens=job.matched,
-                       blocks=len(job.table))
+                       prefix_hit_tokens=job.matched, blocks=len(job.table))
         return True
+
+    def _fail_request(self, req: Request, e: Exception, what: str) -> None:
+        """A request-scoped failure (staging, or a prefill round past its
+        device section): counted, and the request finished with it."""
+        _LOG.warning("%s failed for %s: %s", what, req.id, e)
+        _REQUESTS.inc(status="error")
+        TENANT_REQUESTS.inc(tenant=req.tenant, status="error")
+        self._tenant_count(req.tenant, "requests_error")
+        req.finish(error=f"{type(e).__name__}: {e}")
 
     def _commit_admission_plan(self) -> Optional[bool]:
         """Commit the admission choice precomputed in the previous
@@ -1269,8 +1082,7 @@ class PagedInferenceEngine:
             # queue state and found nothing admissible — skip the rescan
             _OVERLAP_COMMITS.inc(outcome="empty")
             return False
-        reserved = {job.slot for job in self._prefill_jobs}
-        if (self._active[slot] is not None or slot in reserved
+        if (self._active[slot] is not None or slot in self.prefill.slots()
                 or choice.reapable
                 or self._admit_verdict(choice) != "admit"):
             # admission state moved without a queue mutation (deadline
@@ -1318,180 +1130,33 @@ class PagedInferenceEngine:
         _BUSY.set(float(sum(r is not None for r in self._active)))
         return admitted
 
-    # -- chunked prefill (the _PrefillJob state machine) ---------------------
-
-    def _advance_prefill(self) -> bool:
-        """Advance ONE pending prefill job by at most ``prefill_budget``
-        prompt tokens (all of them when the budget is None), rotating
-        round-robin across jobs so a short prompt staged behind a long
-        one still reaches its first token in O(1) rounds."""
-        if not self._prefill_jobs:
-            return False
-        if self._next_prefill >= len(self._prefill_jobs):
-            self._next_prefill = 0
-        job = self._prefill_jobs[self._next_prefill]
-        req = job.req
-        if req.reapable:
-            self._abort_prefill_job(job)
-            self._finish_cancelled(req)
-            return True
-        chunks0, tokens0 = job.next_chunk, job.done
-        try:
-            finished = self._advance_prefill_round(job)
-        except PoolCorruption:
-            raise            # engine-fatal: the shared pool was donated
-        except Exception as e:  # noqa: BLE001 — request-scoped
-            _LOG.warning("prefill failed for %s: %s", req.id, e)
-            _REQUESTS.inc(status="error")
-            TENANT_REQUESTS.inc(tenant=req.tenant, status="error")
-            self._tenant_count(req.tenant, "requests_error")
-            self._drop_prefill_job(job)
-            req.finish(error=f"{type(e).__name__}: {e}")
-            return True
-        self.prefill_rounds += 1
-        _PREFILL_ROUNDS.inc()
-        if trace.ON:
-            trace.note(request=req.id, chunks=job.next_chunk - chunks0,
-                       start=job.matched + tokens0,
-                       tokens=job.done - tokens0, finished=finished,
-                       width=sum(w for _, _, w
-                                 in job.plan[chunks0:job.next_chunk]))
-        if finished:
-            self._drop_prefill_job(job)
-        else:
-            self._next_prefill += 1
-        return True
-
-    def _drop_prefill_job(self, job: _PrefillJob) -> None:
-        idx = self._prefill_jobs.index(job)
-        del self._prefill_jobs[idx]
-        if self._next_prefill > idx:
-            self._next_prefill -= 1
-
-    def _abort_prefill_job(self, job: _PrefillJob) -> None:
-        """Release a job's staged resources without finishing its
-        request (the caller decides the terminal status)."""
-        self._drop_prefill_job(job)
-        # drop the staged refs: matched prefix blocks fall back to
-        # cached, freshly-owned ones return to the free list (their
-        # half-written K/V is dead weight a future holder overwrites
-        # during its own prefill, same as any freed slot's blocks)
-        self.kv.release(job.table)
-        job.table = []
+    def _prompt_done(self, job: Job, first: int) -> None:
+        """What a finished prompt does to its slot (``Prefill``'s
+        ``finished``): the job's blocks and window row become the slot's,
+        and the slot starts generating from ``first``."""
+        req, slot, table = job.req, job.slot, job.table
+        # register the prompt's full blocks for future prefix hits (the
+        # matched prefix nodes already exist and are skipped; pad garbage
+        # only ever lands past the prompt, never inside a full block)
+        n_full = len(req.prompt) // self._page
+        if n_full:
+            self.kv.insert(req.prompt[:n_full * self._page], table[:n_full])
+        self._tables[slot, :len(table)] = table
+        self._tables[slot, len(table):] = 0
+        if self._live is not None:
+            self._live[slot] = 1
+        self._pt_dev = None
+        self._slot_blocks[slot] = list(table)
         if job.window is not None:
-            self._win.release(job.window)
-        self._leave_state_rows(job)
-
-    def _run_prefill_chunks(self, job: _PrefillJob) -> tuple:
-        """The budget loop: one program a chunk of ``job.plan`` until the
-        plan ends or the budget is spent (a budget of one bucket's width
-        is one program a round). Returns ``(finished, first)``; ``first``
-        is the device's pick of the first token once finished."""
-        budget = self.prefill_budget
-        spent = 0
-        first = None
-        while job.next_chunk < len(job.plan):
-            _, take, width = job.plan[job.next_chunk]
-            first = self._run_prefill_program(job, take, width)
-            job.next_chunk += 1
-            job.done += take
-            spent += take
-            if budget is not None and spent >= budget \
-                    and job.next_chunk < len(job.plan):
-                return False, None
-        return True, first
-
-    def _run_prefill_program(self, job: _PrefillJob, take: int, width: int):
-        """ONE device call: ``prefill_step`` over the next ``width``
-        positions of the job's prompt, ``take`` of them real. Where the
-        chunk starts and the rest of what the program is told stand in the
-        job's buffer, which rides in the dispatch of the job's first
-        program and stays on the device, so nothing is uploaded, sliced,
-        padded or picked by a call of its own. Everything the program is
-        handed but the parameters and the key is donated and comes back:
-        the pool leaves, the job's buffer, a state model's job rows (a
-        job's first program zeroes what it is given).
-
-        The chunk that finishes a prompt is preceded by the rng's one
-        split a finished prompt (``_split_rng``, compiled once), whose
-        spent half the program draws the first token from; the other
-        chunks are handed the rng as it is and their pick is dropped. The
-        split is not inside the program because a Threefry split is a
-        third of what a width costs to lower, at every width, whatever
-        the compile cache holds (PERF.md section 6, PR 38)."""
-        # one program dispatch per CHUNK (a budgeted round may run
-        # several) — the dispatch counter must agree with the
-        # decode/verify paths' one-inc-per-program rule
-        self._count_dispatch(width)
-        _PREFILL_PROGRAMS.inc()
-        _PREFILL_TOKENS.inc(take)
-        _PREFILL_POSITIONS.inc(width)
-        PREFILL_CALLS.inc()
-        if self._has_state and job.state is None:
-            job.state = self._spare_state.pop() if self._spare_state \
-                else self._new_state_rows()
-        key = self._rng
-        if job.next_chunk == len(job.plan) - 1:
-            PREFILL_CALLS.inc()
-            with self._first("split_rng", trace.SITE_AUX, phase="prefill"):
-                self._rng, key = self._split_rng(self._rng)
-        payload = self._payload
-        tables = ()
-        if job.window is not None:
-            # the chunk's own pages are taken and the pages wholly behind
-            # its first query's window go back, before the dispatch; the
-            # table rides in it (a copy: the row's array changes under the
-            # next chunk)
-            start = job.matched + job.plan[job.next_chunk][0]
-            self._win.cover(job.window, start - self._win.window,
-                            start + take)
-            tables = (job.window.table[None].copy(),)
-        # the first program of a width is traced, lowered and compiled (or
-        # read from the cache) here, while every resident row waits
-        with self._first(width, trace.SITE_PREFILL, phase="prefill",
-                         width=width):
-            pool, state, job.inputs, first = self._prefill_step(
-                [payload[i] for i in self._pool_at], job.state or [],
-                job.inputs, self.params, key, *tables, width=width)
-        for i, leaf in zip(self._pool_at, pool):
-            payload[i] = leaf
-        if self._has_state:
-            job.state = state
-        return first
-
-    def _leave_state_rows(self, job: _PrefillJob) -> None:
-        """A finished or abandoned job's state rows stay for the next job
-        to start from; no more than two sets are kept (a set is as large
-        as a slot's state), so a burst of prompts does not hold the
-        memory of its widest moment."""
-        if job.state is not None and len(self._spare_state) < 2:
-            self._spare_state.append(job.state)
-        job.state = None
-
-    def _new_state_rows(self) -> list:
-        """Batch-1 rows of every state leaf, for a prefill job when no
-        finished job has left its own behind."""
-        PREFILL_CALLS.inc(len(self._state_at))
-        with self._first("state_rows", trace.SITE_AUX, phase="prefill"):
-            return [jnp.zeros((1,) + self._payload[i].shape[1:],
-                              self._payload[i].dtype)
-                    for i in self._state_at]
-
-    def _prefill_fence(self, first) -> int:
-        """The prefill's one blocking transfer: the first token as the
-        finishing program picked it, and with it the wait for every chunk
-        still queued on the device. Timed apart from the ``prefill``
-        phase: here the loop waits for the device, not the device for the
-        loop. A decode round in flight was queued in front of the prompt's
-        programs and the slot is about to be activated from the host
-        mirrors, so it is drained first: its tokens go out now, not
-        behind the prompt's programs."""
-        self._drain("admission")
-        t0 = self._clock.now()
-        with trace.span(trace.ENGINE_PREFILL_FENCE):
-            token = int(np.asarray(first)[0])
-        self._prefill_wait = self._clock.now() - t0
-        return token
+            # the slot's row from here on; what was set aside for the
+            # prompt and not taken is anybody's again
+            self._win.unreserve(job.window)
+            self._win_rows[slot] = job.window
+            self._win_tables[slot] = job.window.table
+            job.window = None
+        self._admissions += 1
+        self._admit_seq[slot] = self._admissions
+        self._finish_prefill(slot, req, first)
 
     def _finish_prefill(self, slot: int, req: Request, first: int) -> None:
         """Shared prefill tail: record TTFT, emit the first token, and
@@ -1514,7 +1179,7 @@ class PagedInferenceEngine:
         # inputs must be rebuilt from the host mirrors (the ONLY event
         # that forces a re-upload — frees leave harmless idle-row
         # garbage in place instead). The mirror of tokens holds every
-        # round dispatched so far: ``_prefill_fence`` drained the one in
+        # round dispatched so far: the prefill's fence drained the one in
         # flight
         self._cur_dev = None
         self._pos_dev = None
@@ -1538,15 +1203,17 @@ class PagedInferenceEngine:
         host: it may still be in flight); after an admission they are
         rebuilt from the host mirrors, which is why ``_decode`` drains a
         round in flight before a round whose ``_cur_dev`` is stale.
-        ``jnp.array`` (an explicit copy), never
-        ``jnp.asarray``: asarray zero-copies the live numpy buffer, and
-        ``_emit``'s later host writes would mutate the device view."""
+        Uploaded by ``ProgramBuild.upload``: an explicit copy
+        (``jnp.array``), never ``jnp.asarray``: asarray zero-copies the
+        live numpy buffer, and ``_emit``'s later host writes would mutate
+        the device view."""
+        up = self._programs.upload
         if self._cur_dev is None:
-            self._cur_dev = jnp.array(self._cur)
+            self._cur_dev = up(self._cur)
         if self._pos_dev is None:
-            self._pos_dev = jnp.array(np.asarray(self._pos, np.int32))
+            self._pos_dev = up(np.asarray(self._pos, np.int32))
         if self._mask_dev is None:
-            self._mask_dev = jnp.array(self._greedy_mask())
+            self._mask_dev = up(self._greedy_mask())
         return self._cur_dev, self._pos_dev, self._mask_dev
 
     def _stale_inputs(self) -> int:
@@ -1637,8 +1304,9 @@ class PagedInferenceEngine:
             return drained
         t_plan = self._clock.now()
         with trace.span(trace.ENGINE_DECODE_PLAN):
-            if not self._pre_decode():
-                return drained
+            self._grow_for_decode()
+            if not any(r is not None for r in self._active):
+                return drained      # the squeeze preempted everyone
             plan = self._spec_plan()
         # with a proposer every round is fetched in its own turn (the
         # proposals read its tokens), which the loop thread counts
@@ -1679,8 +1347,14 @@ class PagedInferenceEngine:
         rng = self._rng
         # a model whose layers sow counts returns them packed behind the
         # tokens, as the one array the fence fetches
-        (self._payload, self._pos_dev, self._cur_dev, self._rng,
-         *packed) = self._run_decode_step()
+        (cur, pos, mask), tables = self._round_inputs()
+        self._count_dispatch(1)
+        # builds here only where warmup() was not called (after it jit
+        # finds what it traced, lowered and compiled there)
+        with self._first("decode", trace.SITE_DECODE, phase="dispatch"):
+            (self._payload, self._pos_dev, self._cur_dev, self._rng,
+             *packed) = self._decode_step(self._payload, self.params, cur,
+                                          pos, tables, mask, rng)
         rows = [(slot, req) for slot, req in enumerate(self._active)
                 if req is not None]
         for slot, _ in rows:
@@ -1844,7 +1518,7 @@ class PagedInferenceEngine:
                        drained: Optional[str] = None) -> bool:
         """One speculative round: a single fused verify program scores
         ``[slots, gamma+1]`` positions (last emitted token + each row's
-        padded proposal), computes acceptance ON DEVICE (:meth:`_accept`)
+        padded proposal), computes acceptance ON DEVICE (``spec.accept``)
         and returns one packed ``[slots, gamma+2]`` emit matrix — the
         round's only host transfer. Greedy rows emit 1..gamma+1 tokens;
         sampled/no-draft rows emit exactly one, drawn from the same
@@ -1870,9 +1544,13 @@ class PagedInferenceEngine:
             for slot, p in plan.items():
                 prop[slot, :len(p)] = p
                 plen[slot] = len(p)
-            (self._payload, packed, self._cur_dev, self._pos_dev,
-             self._rng) = self._run_verify_step(jnp.asarray(prop),
-                                                jnp.asarray(plen))
+            (cur, pos, mask), tables = self._round_inputs()
+            self._dispatches.inc(path=self._path_of(gamma + 1))
+            with self._first("verify", trace.SITE_VERIFY, phase="dispatch"):
+                (self._payload, packed, self._cur_dev, self._pos_dev,
+                 self._rng) = self._verify_step(
+                    self._payload, self.params, cur, jnp.asarray(prop),
+                    jnp.asarray(plen), pos, tables, mask, self._rng)
         t1 = self._clock.now()
         with trace.span(trace.ENGINE_DECODE_OVERLAP):
             self._overlap_window()
@@ -1886,8 +1564,15 @@ class PagedInferenceEngine:
         with trace.span(trace.ENGINE_DECODE_EMIT):
             _STEP.observe(dt)
             self._verify_emit(plan, packed, gamma, dt)
-            self._note_round_phases("verify", t0 - t_plan, t1 - t0, t2 - t1,
-                                    t3 - t2, self._clock.now() - t3)
+        t4 = self._clock.now()
+        # observed AFTER the fence (the device is already idle — these
+        # lock-taking observes never sit between dispatch and transfer)
+        _ROUNDS.inc(kind="verify")
+        self._round_kind = "verify"
+        for phase, took in (("plan", t0 - t_plan), ("dispatch", t1 - t0),
+                            ("overlap", t2 - t1), ("fence", t3 - t2),
+                            ("emit", t4 - t3)):
+            self._observe_phase(phase, took)
         return True
 
     def _verify_emit(self, plan: dict, packed, gamma: int,
@@ -1936,20 +1621,6 @@ class PagedInferenceEngine:
         _SPEC_STEPS.inc()
         self._note_decode_round(emitted, rows, dt)
         _BUSY.set(float(sum(r is not None for r in self._active)))
-
-    def _note_round_phases(self, kind: str, plan_dt: float,
-                           dispatch_dt: float, overlap_dt: float,
-                           fence_dt: float, emit_dt: float) -> None:
-        """Round anatomy telemetry, observed AFTER the fence (the device
-        is already idle — these lock-taking observes never sit between
-        dispatch and transfer)."""
-        _ROUNDS.inc(kind=kind)
-        self._round_kind = kind
-        self._observe_phase("plan", plan_dt)
-        self._observe_phase("dispatch", dispatch_dt)
-        self._observe_phase("overlap", overlap_dt)
-        self._observe_phase("fence", fence_dt)
-        self._observe_phase("emit", emit_dt)
 
     def _observe_phase(self, phase: str, dt: float) -> None:
         """One phase of this round into the histogram; a slow one is
@@ -2070,29 +1741,35 @@ class PagedInferenceEngine:
         never reach some of the six. ``prefill_step`` compiles a width
         when the first request reaches it; a server that wants that paid
         before it opens sends one request a width (docs/serving.md)."""
-        payload = [jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+        # a gang's avals carry the REAL shardings (the pool's placement,
+        # replicated round inputs), so the warmed executable is the one
+        # the first request dispatches
+        aval = self._programs.aval
+        payload = [aval(leaf.shape, leaf.dtype, leaf.sharding)
                    for leaf in self._payload]
-        vec = jax.ShapeDtypeStruct((self.slots,), jnp.int32)
-        mask = jax.ShapeDtypeStruct((self.slots,), jnp.bool_)
-        rng = jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype)
+        vec = aval((self.slots,), jnp.int32)
+        mask = aval((self.slots,), jnp.bool_)
+        rng = aval(self._rng.shape, self._rng.dtype)
+        # with no pool the live rows arrive where a page table would
+        pt = aval((self.slots, self._pages_per_seq), jnp.int32) \
+            if self._pooled else vec
+        if self._win is not None:
+            pt = (pt, pt)
+
+        def compiled(step, *mids):
+            # ``mids``: the step's own args between ``params`` and the
+            # page table
+            step.lower(payload, self.params, *mids, pt, mask, rng).compile()
+
         with self._build(trace.SITE_DECODE):
-            self._warm_compile(self._decode_step, payload, (vec, vec),
-                               mask, rng)
+            compiled(self._decode_step, vec, vec)
         if self._has_state:
-            # the splice of a finished prefill's state rows, one program
-            # for every slot
             with self._build(trace.SITE_SPLICE):
-                rows = [payload[i] for i in self._state_at]
-                self._splice_state.lower(
-                    rows, [jax.ShapeDtypeStruct((1,) + r.shape[1:], r.dtype)
-                           for r in rows],
-                    jax.ShapeDtypeStruct((), jnp.int32)).compile()
+                self.prefill.compile_splice(payload)
         if self.spec_tokens > 0:
-            prop = jax.ShapeDtypeStruct((self.slots, self.spec_tokens),
-                                        jnp.int32)
+            prop = aval((self.slots, self.spec_tokens), jnp.int32)
             with self._build(trace.SITE_VERIFY):
-                self._warm_compile(self._verify_step, payload,
-                                   (vec, prop, vec, vec), mask, rng)
+                compiled(self._verify_step, vec, prop, vec, vec)
 
     # -- a program's build has a site and a name ---------------------------
 
@@ -2173,22 +1850,7 @@ class PagedInferenceEngine:
                 # be what died, and a waiter must not wait on it. Its rows
                 # fail below with the tokens they had
                 self._inflight = None
-                for req in self.queue.drain():
-                    _REQUESTS.inc(status="error")
-                    req.finish(error="engine loop died")
-                for slot, req in enumerate(self._active):
-                    if req is not None:
-                        _REQUESTS.inc(status="error")
-                        req.finish(error="engine loop died")
-                        self._active[slot] = None
-                # a request popped from the queue but still mid-prefill
-                # when the loop died is in NEITHER structure — without
-                # this sweep its waiter would burn its whole timeout
-                # (found by the chaos soak, seed 23)
-                for req in self._fail_untracked():
-                    _REQUESTS.inc(status="error")
-                    req.finish(error="engine loop died")
-                _BUSY.set(0.0)
+                self._fail_outstanding("error", "engine loop died")
             finally:
                 self._loop_ident = None
 
@@ -2231,35 +1893,32 @@ class PagedInferenceEngine:
             self._thread = None
         # staged prefills release their resources (blocks back to the
         # pool); their requests are failed by the untracked sweep
-        for job in list(self._prefill_jobs):
-            self._abort_prefill_job(job)
-        for req in self.queue.drain():
-            _REQUESTS.inc(status="shed")
-            req.finish(error="engine shutting down")
-        for slot, req in enumerate(self._active):
-            if req is not None:
-                _REQUESTS.inc(status="shed")
-                req.finish(error="engine shutting down")
-                self._active[slot] = None
-        for req in self._fail_untracked():
-            _REQUESTS.inc(status="shed")
-            req.finish(error="engine shutting down")
-        _BUSY.set(0.0)
+        self.prefill.close()
+        self._fail_outstanding("shed", "engine shutting down")
         if self._kv_quant is not None:
             self._note_quant_resident(0)
         # the loop thread was joined above: the tier is closed, the waiters
         # woken and the parked pins released single-threaded by construction
         self.kv_io.close()
 
-    def _fail_untracked(self) -> List[Request]:
-        """Outstanding requests still unfinished after the queue and the
-        slots were swept — the mid-prefill window (popped, not yet
-        slot-resident). Only callable once the loop is stopped/dead:
+    def _fail_outstanding(self, status: str, error: str) -> None:
+        """Every request the engine still holds ends with ``error``: the
+        queue's, the slots', and those in NEITHER structure, the
+        mid-prefill window (popped, not yet slot-resident: without this
+        sweep a waiter would burn its whole timeout; found by the chaos
+        soak, seed 23). Only callable once the loop is stopped/dead:
         nothing else can finish them concurrently."""
+        held = self.queue.drain() + [r for r in self._active
+                                     if r is not None]
+        self._active[:] = [None] * self.slots
         with self._outstanding_lock:
-            leftovers = [r for r in self._outstanding if not r.done]
+            held += [r for r in self._outstanding if not r.done]
             self._outstanding.clear()
-        return leftovers
+        for req in held:
+            if not req.done:
+                _REQUESTS.inc(status=status)
+                req.finish(error=error)
+        _BUSY.set(0.0)
 
     def stats(self) -> EngineStats:
         ks = self.kv.stats()
@@ -2267,7 +1926,27 @@ class PagedInferenceEngine:
             # blocks currently holding int8 data: everything usable that
             # is not on the free list (slot-resident + radix-cached)
             self._note_quant_resident(ks.blocks_total - ks.blocks_free)
-        s = EngineStats(
+        extra = self.kv_io.stats()
+        if self._win is not None:
+            extra.update(
+                kv_window_blocks_total=self._win.pool.n_blocks - 1,
+                kv_window_blocks_free=self._win.pool.free_count(),
+                kv_window_blocks_live=self._win.live(),
+                kv_window_pages_released=self._win.released)
+        if self.spec_tokens > 0:
+            rate = (self.spec_accepted / self.spec_proposed
+                    if self.spec_proposed else 0.0)
+            tps = (self.decode_tokens / self.decode_rows
+                   if self.decode_rows else 0.0)
+            extra.update(
+                spec_tokens=self.spec_tokens,
+                spec_proposed_tokens=self.spec_proposed,
+                spec_accepted_tokens=self.spec_accepted,
+                spec_acceptance_rate=round(rate, 4),
+                spec_verify_steps=self.spec_steps,
+                spec_tokens_per_step=round(tps, 4),
+                spec_draft_truncated=self.spec_draft_truncated)
+        return EngineStats(
             slots=self.slots,
             busy=sum(r is not None for r in self._active),
             queue_depth=self.queue.depth(),
@@ -2284,33 +1963,7 @@ class PagedInferenceEngine:
             kernel_path=self.kernel_path,
             kv_quant=self._kv_quant,
             kv_token_bytes=self._kv_token_bytes,
-            **self.kv_io.stats(),
-        )
-        if self._win is not None:
-            s = dataclasses.replace(
-                s,
-                kv_window_blocks_total=self._win.pool.n_blocks - 1,
-                kv_window_blocks_free=self._win.pool.free_count(),
-                kv_window_blocks_live=self._win.live(),
-                kv_window_pages_released=self._win.released,
-            )
-        if self.spec_tokens > 0:
-            rate = (self.spec_accepted / self.spec_proposed
-                    if self.spec_proposed else 0.0)
-            tps = (self.decode_tokens / self.decode_rows
-                   if self.decode_rows else 0.0)
-            s = dataclasses.replace(
-                s,
-                spec_tokens=self.spec_tokens,
-                spec_proposed_tokens=self.spec_proposed,
-                spec_accepted_tokens=self.spec_accepted,
-                spec_acceptance_rate=round(rate, 4),
-                spec_verify_steps=self.spec_steps,
-                spec_tokens_per_step=round(tps, 4),
-                spec_draft_truncated=self.spec_draft_truncated,
-            )
-        return s
-
+            **extra)
 
     def stats_by_tenant(self) -> dict:
         """Per-tenant terminal counters plus live queue depth — the
@@ -2320,26 +1973,19 @@ class PagedInferenceEngine:
         with self._tenant_counts_lock:
             out = {t: dict(d) for t, d in self._tenant_counts.items()}
         for tenant in self.queue.tenants():
-            row = out.setdefault(tenant, {
-                "requests_finished": 0, "tokens_generated": 0,
-                "requests_cancelled": 0, "requests_preempted": 0,
-                "requests_error": 0})
+            row = out.setdefault(tenant, dict(TENANT_ROW))
             row["queue_depth"] = self.queue.depth_of(tenant)
         for row in out.values():
             row.setdefault("queue_depth", 0)
         tenants = set(out)
         tenants.update(r.tenant for r in self._active if r is not None)
-        tenants.update(j.req.tenant for j in self._prefill_jobs)
+        tenants.update(j.req.tenant for j in self.prefill.jobs)
         for tenant in tenants:
             held = self._tenant_block_usage(tenant)
-            row = out.setdefault(tenant, {
-                "requests_finished": 0, "tokens_generated": 0,
-                "requests_cancelled": 0, "requests_preempted": 0,
-                "requests_error": 0, "queue_depth": 0})
+            row = out.setdefault(tenant, dict(TENANT_ROW, queue_depth=0))
             row["kv_blocks"] = held
             TENANT_KV_BLOCKS.set(float(held), tenant=tenant)
         return out
-
 
     def _refuse_for_leaves(self, error, why: dict, tier_asked: bool) -> None:
         """A pool whose leaves are not all ``paged`` (``models/serving.py``;
@@ -2369,15 +2015,17 @@ class PagedInferenceEngine:
                                   kv_quant=self._kv_quant)
 
     def _build_decode_path(self, base: Any) -> None:
+        self._programs = build = self._program_build()
         slots, pages = self.slots, self._pages_per_seq
         # one module for decode rounds and batch-1 prefill: prefill reuses
         # the SAME pool arrays with a batch-1 index (and, where the model
         # has them, the job's own batch-1 state rows)
         second = {} if self._win is None \
             else {"window_pages": self._win.pool.n_blocks}
-        self._model = self._prefill_model = base.paged_model(
+        self._model = base.paged_model(
             page_size=self._page, kv_pages=self._kv_blocks,
-            kernel=self._paged_kernel, kv_quant=self._kv_quant, **second)
+            kernel=self._paged_kernel, kv_quant=self._kv_quant, **second,
+            **build.model_kw)
         dummy_pt = jnp.zeros((slots, pages), jnp.int32)
         tables = {"page_table": dummy_pt} if self._win is None \
             else {"page_table": dummy_pt, "window_table": dummy_pt}
@@ -2386,33 +2034,22 @@ class PagedInferenceEngine:
             **tables)))
         self._build_steps()
 
+    def _program_build(self) -> ProgramBuild:
+        """A gang says what its mesh changes (serving/sharded/engine.py)."""
+        return ProgramBuild()
+
     def _build_steps(self) -> None:
-        """The jitted prefill, decode and verify programs over
-        ``self._model``. A model with state leaves, or one that counts
+        """The jitted decode and verify programs over ``self._model``
+        (``serving/prefill.py`` builds the prefill's, from the same
+        ``ProgramBuild``). A model with state leaves, or one that counts
         (``STATS``), is also told which positions are real (``valid_len``):
-        a padded prefill chunk ends at its last real token, an idle slot
-        (zeroed page table: block 0 is the scratch block no row owns) has
-        none."""
+        an idle slot (zeroed page table: block 0 is the scratch block no
+        row owns) has none."""
+        build = self._programs
         self._stat_counters = tuple(type(self._model).STATS)
-        has_state, has_stats = self._has_state, bool(self._stat_counters)
+        has_stats = bool(self._stat_counters)
         tells_real, pooled = self._tells_real, self._pooled
         mutable = ["cache", "stats"] if has_stats else ["cache"]
-
-        def prefill_step(pool, state, job, params, key, window_table=None,
-                         *, width):
-            # ``window_table``: a model with window leaves only (the job's
-            # row of the second kind of page, as this chunk needs it)
-            second = {} if window_table is None \
-                else {"window_table": window_table}
-            return self._prefill_program(pool, state, job, params, key,
-                                         width, **second)
-
-        self._prefill_step = jax.jit(
-            prefill_step, static_argnames=("width",),
-            donate_argnums=(0, 1, 2))
-        # the rng's one split a finished prompt: (what the stream goes on
-        # from, what the first token's draw spends), as one program
-        self._split_rng = jax.jit(lambda rng: tuple(jax.random.split(rng)))
 
         def decode_step(payload, params, cur, pos, page_table,
                         greedy_mask, rng):
@@ -2433,7 +2070,7 @@ class PagedInferenceEngine:
                     if tells_real else {}
             logits, updated = self._model.apply(
                 {"params": params, "cache": cache}, cur[:, None],
-                mutable=mutable, **tables, **real)
+                mutable=mutable, **tables, **real, **build.apply_kw)
             nxt, rng = self._pick_next(logits[:, -1], greedy_mask, rng)
             payload, new_pos = self._split_cache(updated["cache"])
             if not has_stats:
@@ -2444,18 +2081,7 @@ class PagedInferenceEngine:
             return payload, new_pos, nxt, rng, jnp.concatenate(
                 [nxt, counts.astype(jnp.int32)])
 
-        self._decode_step = jax.jit(decode_step, donate_argnums=(0,))
-
-        if has_state:
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def splice_state(rows, job_rows, slot):
-                """Each state leaf's row ``slot`` becomes the job's
-                batch-1 row, in place."""
-                return [jax.lax.dynamic_update_slice_in_dim(
-                    big, small, slot, axis=0)
-                    for big, small in zip(rows, job_rows)]
-
-            self._splice_state = splice_state
+        self._decode_step = build.jit(decode_step, donate=(0,))
 
         def verify_step(payload, params, cur, prop, prop_len, pos,
                         page_table, greedy_mask, rng):
@@ -2466,119 +2092,21 @@ class PagedInferenceEngine:
             toks = jnp.concatenate([cur[:, None], prop], axis=1)
             logits, updated = self._model.apply(
                 {"params": params, "cache": cache}, toks,
-                page_table=page_table, mutable=["cache"])
+                page_table=page_table, mutable=["cache"], **build.apply_kw)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt, rng = self._pick_next(logits[:, 0], greedy_mask, rng)
             payload, _ = self._split_cache(updated["cache"])
-            packed, new_cur, new_pos = self._accept(prop, prop_len,
-                                                    greedy, nxt, pos)
+            packed, new_cur, new_pos = accept(prop, prop_len, greedy,
+                                              nxt, pos)
             return payload, packed, new_cur, new_pos, rng
 
-        self._verify_step = jax.jit(verify_step, donate_argnums=(0,))
+        self._verify_step = build.jit(verify_step, donate=(0,))
 
-    # -- cache-tree plumbing -------------------------------------------------
-
-    @property
-    def _job_layout(self) -> tuple:
-        """Where a prefill job's buffer (``_PrefillJob.inputs``) keeps what:
-        the offsets of its plan rows, its page table and its prompt, and
-        its length. A plan has at most ``max_seq_len / prefill_chunk``
-        chunks; the prompt's part runs a chunk past ``max_seq_len`` so
-        that a chunk cut anywhere inside the prompt never runs off the
-        end."""
-        chunk = max(1, self.prefill_chunk)
-        table = 1 + _CTL_LEN * -(-self.cfg.max_seq_len // chunk)
-        prompt = table + self._pages_per_seq
-        return 1, table, prompt, prompt + self.cfg.max_seq_len + chunk
-
-    def _prefill_program(self, pool, state, job, params, key, width,
-                         **apply_kw):
-        """The body of ``prefill_step`` (traced): one batch-1 chunk of
-        ``width`` positions against the shared pool, everything a round
-        needs computed here from the job's buffer, as ``decode_step``
-        computes its own from ``pos``.
-
-        - The chunk's ``_CTL_*`` row is the one the buffer's cursor
-          points at; the cursor moves on in the buffer handed back.
-        - The cache tree: the pool's paged leaves as they are, ONE index
-          value ``[start]`` placed at every index leaf, a state leaf the
-          JOB's own batch-1 row (a state row belongs to one slot, and the
-          decode rounds interleaved with this prefill see that slot as
-          idle), zeroed on the job's first program.
-        - The chunk: ``width`` ids of the job's prompt from ``start``,
-          positions at or past ``take`` set to the pad id 0 (the buffer
-          holds 0 there already; the program does not lean on it), so a
-          padded tail sees exactly what a padded upload held.
-        - The first token: picked from the logits of the last real
-          position with the engine's sampling parameters, the row's
-          greedy override and ``key``, which on the chunk that finishes a
-          prompt is the spent half of the rng's split; on the others the
-          pick is computed and dropped, the price of one program a width.
-
-        Returns the pool leaves, the job's state rows, its buffer (on the
-        device from here on) and the ``[1]`` token."""
-        plan_at, table_at, prompt_at, _ = self._job_layout
-        ctl = jax.lax.dynamic_slice_in_dim(
-            job[0], plan_at + _CTL_LEN * job[0, 0], _CTL_LEN)
-        page_table = job[:, table_at:prompt_at]
-        prompt = job[:, prompt_at:]
-        start, take = ctl[_CTL_START], ctl[_CTL_TAKE]
-        index = jnp.reshape(start, (1,))
-        leaves, paged, rows = [], iter(pool), iter(state)
-        for kind in self._leaf_kinds:
-            if kind == serving.INDEX:
-                leaves.append(index)
-            elif kind == serving.STATE:
-                row = next(rows)
-                leaves.append(jnp.where(ctl[_CTL_FRESH] != 0,
-                                        jnp.zeros_like(row), row))
-            else:
-                leaves.append(next(paged))
-        cache = jax.tree_util.tree_unflatten(self._cache_treedef, leaves)
-        chunk = jax.lax.dynamic_slice_in_dim(prompt, start, width, axis=1)
-        tokens = jnp.where(jnp.arange(width, dtype=jnp.int32) < take,
-                           chunk, 0)
-        real = {"valid_len": jnp.reshape(take, (1,))} \
-            if self._tells_real else {}
-        if self._tells_prompt_len:
-            real["prompt_len"] = jnp.reshape(ctl[_CTL_PROMPT], (1,))
-        logits, updated = self._prefill_model.apply(
-            {"params": params, "cache": cache}, tokens,
-            page_table=page_table, mutable=["cache"], **real, **apply_kw)
-        last = jax.lax.dynamic_index_in_dim(
-            logits, take - 1, axis=1, keepdims=False)
-        first = self._pick_first(last, ctl[_CTL_GREEDY] != 0, key)
-        out = jax.tree_util.tree_leaves(updated["cache"])
-        return ([leaf for leaf, kind in zip(out, self._leaf_kinds)
-                 if kind in serving.POOLS],
-                [leaf for leaf, kind in zip(out, self._leaf_kinds)
-                 if kind == serving.STATE],
-                job.at[0, 0].add(1), first)
-
-    def _splice_job_state(self, job: _PrefillJob) -> None:
-        """A finished prompt's state: the job's batch-1 rows are spliced
-        into the slot's rows of the decode tree (one device call), and
-        left for the next job to start from. Index state needs no splice
-        — the host ``_pos`` mirror (set by ``_finish_prefill``; 0 while
-        the job is mid-flight) is the single source of truth for
-        positions."""
-        with trace.span(trace.ENGINE_PREFILL_STATE), \
-                self._first("splice", trace.SITE_SPLICE, phase="prefill"):
-            PREFILL_CALLS.inc()
-            rows = self._splice_state(
-                [self._payload[i] for i in self._state_at], job.state,
-                np.int32(job.slot))
-        for i, row in zip(self._state_at, rows):
-            self._payload[i] = row
-        self._leave_state_rows(job)
-
-    # -- admission / prefill -------------------------------------------------
+    # -- admission -----------------------------------------------------------
 
     def _blocks_for(self, n_tokens: int) -> int:
         """Blocks of the paged pool that ``n_tokens`` cache positions
         take: none where the model keeps no pool."""
-        from lzy_tpu.serving.kv_cache import blocks_for
-
         return blocks_for(n_tokens, self._page) if self._pooled else 0
 
     def _tenant_quota(self, tenant: str) -> Optional[int]:
@@ -2594,10 +2122,8 @@ class PagedInferenceEngine:
         for slot, req in enumerate(self._active):
             if req is not None and req.tenant == tenant:
                 held += len(self._slot_blocks[slot])
-        for job in self._prefill_jobs:
-            if job.req.tenant == tenant:
-                held += len(job.table)
-        return held
+        return held + sum(len(job.table) for job in self.prefill.jobs
+                          if job.req.tenant == tenant)
 
     def _can_admit(self, req: Request) -> bool:
         """Admission is gated on the BLOCK budget, not the slot count: the
@@ -2635,142 +2161,6 @@ class PagedInferenceEngine:
                 return "skip"
         return "admit" if self._can_admit(req) else "wait"
 
-    def _stage_prefill(self, slot: int, req: Request) -> _PrefillJob:
-        prompt = req.prompt
-        t0 = len(prompt)
-        # tier promotion FIRST: chains that aged out of HBM (or arrived
-        # via the shared storage tier) re-enter the radix tree here, so
-        # the match below hits them like any locally-cached prefix — and
-        # counts them in prefill_tokens_saved, which is the honest
-        # accounting (the prefill really is skipped)
-        self.kv_io.promote(prompt[:-1])
-        # longest cached whole-block prefix; capped at prompt[:-1] so at
-        # least one real token remains to forward (logits for the first
-        # generated token must come from an actual prefill position)
-        blocks, matched = self.kv.match(prompt[:-1])
-        # provenance: if any matched block arrived via a KV import, the
-        # prefill pool that produced it really served this prefix — the
-        # disagg gateway reports it as `prefilled_by` (used, not staged)
-        req.kv_prefilled_by = (
-            self.kv.chain_origin(prompt[:matched]) if matched else None)
-        plan = prefill_plan(t0 - matched, self.prefill_chunk,
-                            self.cfg.max_seq_len - matched)
-        # blocks for the REAL prompt positions only: a padded final
-        # chunk's pad positions (>= t0) fall past the table's allocated
-        # prefix, map to the scratch block, and are masked garbage by
-        # construction — allocating coverage for them would waste up to
-        # bucket_width/page blocks per short request
-        evicted = self.kv.evictions
-        try:
-            owned = self.kv.allocate(self._blocks_for(t0) - len(blocks))
-        except Exception:
-            self.kv.release(blocks)   # roll back the match refs
-            raise
-        if trace.ON:
-            trace.note(evicted=self.kv.evictions - evicted)
-        # NOTE: the slot's row of self._tables stays scratch until the
-        # job completes — decode rounds interleaved with this prefill
-        # must see the reserved slot as idle (its garbage writes land on
-        # block 0), never on the job's half-written real blocks
-        if self._has_state:
-            # a reused slot starts from zero state: the job's first
-            # program zeroes the rows it is handed
-            _STATE_RESETS.inc()
-        row = None
-        if self._win is not None:
-            # the job's row of window pages: what it will hold at its most
-            # is set aside now, taken and shed chunk by chunk
-            row = self._win.row()
-            try:
-                self._win.reserve(row, t0)
-            except Exception:
-                self.kv.release(blocks + owned)
-                raise
-        return _PrefillJob(req=req, slot=slot, plan=plan, matched=matched,
-                           table=blocks + owned, window=row)
-
-    def _upload(self, array):
-        """A job's buffer as its first program takes it: the host array
-        as it is, so that it rides in that dispatch (a gang places it on
-        its mesh by a call of its own)."""
-        return array
-
-    def _stage_prefill_inputs(self, job: _PrefillJob) -> None:
-        """Write the job's buffer (``_job_layout``), once: the cursor at
-        0, a ``_CTL_*`` row a chunk of the plan, the page table, the
-        prompt."""
-        prompt = job.req.prompt
-        plan_at, table_at, prompt_at, length = self._job_layout
-        buf = np.zeros((1, length), np.int32)
-        greedy = self._row_greedy(job.req)
-        rows = [(job.matched + start, take, greedy,
-                 n == 0 and self._has_state,
-                 len(prompt))                      # in the order of _CTL_*
-                for n, (start, take, _) in enumerate(job.plan)]
-        buf[0, plan_at:plan_at + _CTL_LEN * len(rows)] = \
-            np.asarray(rows, np.int32).ravel()
-        buf[0, table_at:table_at + len(job.table)] = job.table
-        buf[0, prompt_at:prompt_at + len(prompt)] = prompt
-        job.inputs = self._upload(buf)
-
-    def _advance_prefill_round(self, job: _PrefillJob) -> bool:
-        """One budgeted round of a prefill; True when the job finished
-        (slot activated). A round is ONE device call a chunk (the job's
-        buffer rides in the dispatch of its first and stays on the device):
-        the jitted ``prefill_step`` takes the pool's leaves, advances
-        them by the chunk and hands them back, so decode steps between
-        rounds run against a fully consistent tree (the job's slot reads
-        as idle: index 0, scratch page table). Resuming at ``matched +
-        done`` reproduces the one-shot index exactly (interior chunks are
-        unpadded), so chunking never changes the device math — only its
-        interleaving. The round that finishes a prompt reads the first
-        token the program picked (the fence) and, on a model with state
-        leaves, splices the job's rows into the slot's."""
-        req = job.req
-        t0 = len(req.prompt)
-        # everything device-side below donates the SHARED pool: a failure
-        # here poisons every request, not just this one
-        try:
-            # chaos boundary: an injected error here is exactly a device
-            # call dying mid-prefill — engine-fatal by construction
-            CHAOS.hit("engine.prefill")
-            if job.inputs is None:
-                self._stage_prefill_inputs(job)
-            finished, first = self._run_prefill_chunks(job)
-            if not finished:
-                return False
-            if self._has_state:
-                self._splice_job_state(job)
-        except Exception as e:  # noqa: BLE001 — see PoolCorruption
-            raise PoolCorruption(
-                f"paged prefill died mid-flight for {req.id}: "
-                f"{type(e).__name__}: {e}") from e
-
-        # register the prompt's full blocks for future prefix hits (the
-        # matched prefix nodes already exist and are skipped; pad garbage
-        # only ever lands at positions >= t0, never inside a full block)
-        slot, table = job.slot, job.table
-        n_full = t0 // self._page
-        if n_full:
-            self.kv.insert(req.prompt[:n_full * self._page], table[:n_full])
-        self._tables[slot, :len(table)] = table
-        self._tables[slot, len(table):] = 0
-        if self._live is not None:
-            self._live[slot] = 1
-        self._pt_dev = None
-        self._slot_blocks[slot] = list(table)
-        if job.window is not None:
-            # the slot's row from here on; what was set aside for the
-            # prompt and not taken is anybody's again
-            self._win.unreserve(job.window)
-            self._win_rows[slot] = job.window
-            self._win_tables[slot] = job.window.table
-            job.window = None
-        self._admissions += 1
-        self._admit_seq[slot] = self._admissions
-        self._finish_prefill(slot, req, self._prefill_fence(first))
-        return True
-
     # -- KV I/O (serving/kv_io.py): the replica's surface ----------------------
     # what the gateway, the fleet and the tests call on a replica's engine;
     # each is the object's method or counter of (nearly) the same name
@@ -2800,6 +2190,7 @@ class PagedInferenceEngine:
         return self.kv_io.kv_chains(limit)
 
     kv_tier = property(lambda self: self.kv_io.tier)
+    prefill_rounds = property(lambda self: self.prefill.rounds)
     kv_tier_gather_ops = property(lambda self: self.kv_io.gather_ops)
     kv_tier_gather_rounds = property(lambda self: self.kv_io.gather_rounds)
     kv_imports = property(lambda self: self.kv_io.imports)
@@ -2816,8 +2207,6 @@ class PagedInferenceEngine:
         position; under a squeeze, evict cached blocks (allocate does)
         and as a last resort preempt the youngest active request — never
         a block some other in-flight request references."""
-        from lzy_tpu.serving.kv_cache import NoFreeBlocks
-
         if not self._pooled:
             return              # a row grows no page: its state is its slot's
         # positions moved at the last dispatch, so this needs no token of
@@ -2884,26 +2273,21 @@ class PagedInferenceEngine:
         req.finish(error="preempted: kv block pool exhausted")
         return victim
 
-    def _pre_decode(self) -> bool:
-        self._grow_for_decode()
-        # False when the squeeze preempted everyone
-        return any(r is not None for r in self._active)
-
     def _page_table_dev(self):
         """Device mirror of ``_tables``, uploaded once and reused until
         a table mutation dirties it — the per-round ``jnp.asarray`` of
         an unchanged page table was a textbook re-upload hot loop.
-        ``jnp.array`` (explicit copy): asarray would zero-copy the live
-        ``_tables`` buffer and later host writes would mutate the
-        device view mid-flight."""
+        An explicit copy (``ProgramBuild.upload``): asarray would
+        zero-copy the live ``_tables`` buffer and later host writes would
+        mutate the device view mid-flight."""
         if self._pt_dev is None:
+            up = self._programs.upload
             if not self._pooled:
-                self._pt_dev = jnp.array(self._live)    # no table: live rows
+                self._pt_dev = up(self._live)       # no table: live rows
             elif self._win is None:
-                self._pt_dev = jnp.array(self._tables)
+                self._pt_dev = up(self._tables)
             else:
-                self._pt_dev = (jnp.array(self._tables),
-                                jnp.array(self._win_tables))
+                self._pt_dev = (up(self._tables), up(self._win_tables))
         return self._pt_dev
 
     def _count_dispatch(self, t: int) -> None:
@@ -2921,15 +2305,6 @@ class PagedInferenceEngine:
         first uploads' small programs are ``engine.aux`` builds."""
         with self._first("inputs", trace.SITE_AUX, phase="dispatch"):
             return self._device_inputs(), self._page_table_dev()
-
-    def _run_decode_step(self):
-        (cur, pos, mask), tables = self._round_inputs()
-        self._count_dispatch(1)
-        # builds here only where warmup() was not called (after it jit
-        # finds what it traced, lowered and compiled there)
-        with self._first("decode", trace.SITE_DECODE, phase="dispatch"):
-            return self._decode_step(self._payload, self.params, cur, pos,
-                                     tables, mask, self._rng)
 
     def _note_model_stats(self, fetched: np.ndarray,
                           rec: _InFlight) -> np.ndarray:
@@ -2949,25 +2324,6 @@ class PagedInferenceEngine:
                            self._stat_counters, counts)})
         return fetched[:self.slots]
 
-    def _run_verify_step(self, prop, prop_len):
-        (cur, pos, mask), tables = self._round_inputs()
-        self._dispatches.inc(path=self._path_of(self.spec_tokens + 1))
-        with self._first("verify", trace.SITE_VERIFY, phase="dispatch"):
-            return self._verify_step(self._payload, self.params, cur, prop,
-                                     prop_len, pos, tables, mask, self._rng)
-
-    def _warm_compile(self, step, payload, mids, mask, rng):
-        """``mids`` are the step-specific args between ``params`` and the
-        page table: ``(cur, pos)`` for decode, ``(cur, prop, prop_len,
-        pos)`` for verify."""
-        pt = jax.ShapeDtypeStruct((self.slots, self._pages_per_seq),
-                                  jnp.int32)
-        if not self._pooled:
-            pt = jax.ShapeDtypeStruct((self.slots,), jnp.int32)
-        if self._win is not None:
-            pt = (pt, pt)
-        step.lower(payload, self.params, *mids, pt, mask, rng).compile()
-
     # -- speculative decode over the block pool -------------------------------
 
     def _grow_for_spec(self, slot: int, want: int) -> int:
@@ -2979,8 +2335,6 @@ class PagedInferenceEngine:
         rejected would have flushed the prefix cache for nothing (and
         re-flushed it every verify round on low-acceptance traffic);
         truncating the draft instead costs at most the speculation win."""
-        from lzy_tpu.serving.kv_cache import NoFreeBlocks
-
         page, pos = self._page, int(self._pos[slot])
         last = (pos + want) // page
         while len(self._slot_blocks[slot]) <= last:
@@ -3008,8 +2362,6 @@ class PagedInferenceEngine:
         position stays — releasing it on a page boundary would only make
         ``_grow_for_decode`` re-allocate it next round, possibly evicting
         a cached block for nothing."""
-        from lzy_tpu.serving.kv_cache import blocks_for
-
         for slot, req in enumerate(self._active):
             if req is None:
                 continue

@@ -673,6 +673,25 @@ def window_bound(window: int, chunk: int, page_size: int,
     return min(pages_per_seq, blocks_for(window + chunk, page_size) + 1)
 
 
+def divide_pool(pool_bytes: int, base, most_window: int,
+                most_paged: int, page_size: int) -> tuple:
+    """``kv_pool_bytes`` between the two kinds of page of a model with
+    ``window`` leaves: ``(window blocks, bytes for the paged kind)``.
+    Each kind gets what ``slots`` rows at ``max_seq_len`` come to at
+    their most (``most_window``, ``most_paged`` blocks: a row never
+    holds more, and with the prefix cache off nothing else would), if
+    the budget covers both; where it does not, each gets its share of
+    the budget in proportion to that."""
+    token = base.kv_token_bytes(None)
+    per_window = page_size * base.window_layers * token
+    want_window = most_window * per_window
+    want_paged = most_paged * page_size * base.kv_layers * token
+    if want_window + want_paged <= pool_bytes:
+        return most_window, want_paged
+    for_window = pool_bytes * want_window // (want_window + want_paged)
+    return max(2, for_window // per_window), pool_bytes - for_window
+
+
 class WindowPages:
     """The allocator of the paged leaves that lose their tokens behind a
     window (``models/serving.py``, kind ``window``): a query at position

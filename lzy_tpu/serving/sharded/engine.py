@@ -43,13 +43,11 @@ import threading
 from typing import Any, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from lzy_tpu.models.generate import init_cache
 from lzy_tpu.models.llama import LlamaConfig
-from lzy_tpu.serving.engine import PREFILL_CALLS, PagedInferenceEngine
+from lzy_tpu.serving.engine import PagedInferenceEngine
+from lzy_tpu.serving.prefill import ProgramBuild
 from lzy_tpu.serving.sharded import metrics as _m
 from lzy_tpu.serving.sharded.partition import (
     SERVE_RULES, pool_leaf_sharding, serve_mesh_for, shard_params)
@@ -138,128 +136,40 @@ class ShardedPagedInferenceEngine(PagedInferenceEngine):
 
     # -- construction --------------------------------------------------------
 
-    def _build_decode_path(self, base: LlamaConfig) -> None:
-        """The base build with three changes: rule overrides thread into
-        the model, params and pool leaves are device_put onto the mesh
-        (committed shardings make jit infer in_shardings), and every
-        ``apply`` passes ``mesh`` so the activation anchors engage."""
+    def _program_build(self) -> ProgramBuild:
+        """What the mesh changes about the engine's programs: rule
+        overrides thread into the model, every ``apply`` passes ``mesh`` so
+        the activation anchors engage, and everything that is not sharded
+        is COMMITTED replicated: the rng's halves as they leave their split
+        (the decode program was warmed to take the rng so), the round
+        inputs (the base discipline: upload once, the previous round's
+        outputs in the steady state; an uncommitted single-device array
+        among committed operands would make jit's device-set resolution
+        placement-dependent), a prefill job's buffer, and ``warmup()``'s
+        avals beside the pool's own placement.
+
+        Donating the pool payload through a collective-bearing program
+        corrupts it on the CPU host platform: once the process heap has
+        any history, the donated executable's all-gather path
+        intermittently reads recycled buffers (wrong from the first
+        token, varying run to run; a fresh process masks it with clean
+        pages). Donation only buys back HBM, so it stays TPU/GPU-only."""
+        return ProgramBuild(
+            model_kw={"rules": SERVE_RULES}, apply_kw={"mesh": self._mesh},
+            donate=self._mesh.devices.flat[0].platform != "cpu",
+            replicated=self._repl)
+
+    def _adopt_cache(self, tree) -> None:
+        """The cache was initialised meshless (anchors no-op without a
+        mesh); it is placed here: the pool shards on kv_heads, index leaves
+        and params replicate except the head/ff-sharded projection kernels
+        (committed shardings make jit infer in_shardings)."""
         mesh = self._mesh
-        # Donating the pool payload through a collective-bearing program
-        # corrupts it on the CPU host platform: once the process heap has
-        # any history, the donated executable's all-gather path
-        # intermittently reads recycled buffers (wrong from the first
-        # token, varying run to run; a fresh process masks it with clean
-        # pages). Donation only buys back HBM, so it stays TPU/GPU-only.
-        donate = {"donate_argnums": (0,)} \
-            if mesh.devices.flat[0].platform != "cpu" else {}
-        slots, pages = self.slots, self._pages_per_seq
-        self._model = self._prefill_model = base.paged_model(
-            page_size=self._page, kv_pages=self._kv_blocks,
-            kernel=self._paged_kernel, kv_quant=self._kv_quant,
-            rules=SERVE_RULES)
-        dummy_pt = jnp.zeros((slots, pages), jnp.int32)
-        # init meshless (anchors no-op without a mesh), THEN place: the
-        # pool shards on kv_heads, index leaves and params replicate
-        # except the head/ff-sharded projection kernels
-        cache = init_cache(lambda: self._model.init(
-            jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
-            page_table=dummy_pt))
-        cache = jax.tree_util.tree_map_with_path(
+        super()._adopt_cache(jax.tree_util.tree_map_with_path(
             lambda path, leaf: jax.device_put(
                 leaf, pool_leaf_sharding(mesh, path, leaf)),
-            cache)
-        self._adopt_cache(cache)
+            tree))
         self.params = shard_params(self.params, mesh)
-        self._payload_shardings = [leaf.sharding for leaf in self._payload]
-
-        def prefill_step(pool, state, job, params, key, width):
-            # the base program with the mesh on every apply; the index
-            # leaves are built inside it
-            return self._prefill_program(
-                pool, state, job, params, key, width, mesh=mesh)
-
-        self._prefill_step = jax.jit(
-            prefill_step, static_argnames=("width",),
-            **({"donate_argnums": (0, 1, 2)} if donate else {}))
-        # both halves leave replicated, as the decode program was warmed
-        # to take the rng
-        self._split_rng = jax.jit(
-            lambda rng: tuple(jax.random.split(rng)),
-            out_shardings=(self._repl, self._repl))
-
-        def decode_step(payload, params, cur, pos, page_table,
-                        greedy_mask, rng):
-            cache = self._assemble_cache(payload, pos)
-            logits, updated = self._model.apply(
-                {"params": params, "cache": cache}, cur[:, None], mesh=mesh,
-                page_table=page_table, mutable=["cache"])
-            nxt, rng = self._pick_next(logits[:, -1], greedy_mask, rng)
-            payload, new_pos = self._split_cache(updated["cache"])
-            return payload, new_pos, nxt, rng
-
-        self._decode_step = jax.jit(decode_step, **donate)
-
-        def verify_step(payload, params, cur, prop, prop_len, pos,
-                        page_table, greedy_mask, rng):
-            cache = self._assemble_cache(payload, pos)
-            toks = jnp.concatenate([cur[:, None], prop], axis=1)
-            logits, updated = self._model.apply(
-                {"params": params, "cache": cache}, toks, mesh=mesh,
-                page_table=page_table, mutable=["cache"])
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt, rng = self._pick_next(logits[:, 0], greedy_mask, rng)
-            payload, _ = self._split_cache(updated["cache"])
-            packed, new_cur, new_pos = self._accept(prop, prop_len,
-                                                    greedy, nxt, pos)
-            return payload, packed, new_cur, new_pos, rng
-
-        self._verify_step = jax.jit(verify_step, **donate)
-
-    def _warm_compile(self, step, payload, mids, mask, rng):
-        """AOT warm with the REAL shardings: abstract avals carry the
-        pool placement and replicated round inputs, so the warmed
-        executable is the one the first request dispatches."""
-        repl = self._repl
-        payload = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
-                   for s, sh in zip(payload, self._payload_shardings)]
-        mids = tuple(jax.ShapeDtypeStruct(m.shape, m.dtype, sharding=repl)
-                     for m in mids)
-        pt = jax.ShapeDtypeStruct((self.slots, self._pages_per_seq),
-                                  jnp.int32, sharding=repl)
-        mask = jax.ShapeDtypeStruct(mask.shape, mask.dtype, sharding=repl)
-        rng = jax.ShapeDtypeStruct(rng.shape, rng.dtype, sharding=repl)
-        step.lower(payload, self.params, *mids, pt, mask, rng).compile()
-
-    # -- round inputs: committed-replicated, upload-once ----------------------
-
-    def _device_inputs(self):
-        """Base discipline (upload once, previous round's outputs in the
-        steady state) with the uploads COMMITTED replicated on the mesh —
-        an uncommitted single-device array among committed operands
-        would make jit's device-set resolution placement-dependent."""
-        if self._cur_dev is None:
-            self._cur_dev = jax.device_put(np.array(self._cur), self._repl)
-        if self._pos_dev is None:
-            self._pos_dev = jax.device_put(
-                np.array(self._pos, np.int32), self._repl)
-        if self._mask_dev is None:
-            self._mask_dev = jax.device_put(
-                np.array(self._greedy_mask()), self._repl)
-        return self._cur_dev, self._pos_dev, self._mask_dev
-
-    def _page_table_dev(self):
-        if self._pt_dev is None:
-            self._pt_dev = jax.device_put(
-                np.array(self._tables), self._repl)
-        return self._pt_dev
-
-    def _upload(self, array):
-        """A prefill job's buffer, committed replicated like every other
-        round input: a host array would reach the job's first program
-        unplaced, and that is another program than the one its later
-        rounds compile."""
-        PREFILL_CALLS.inc()
-        return jax.device_put(array, self._repl)
 
     # -- gang liveness -------------------------------------------------------
 

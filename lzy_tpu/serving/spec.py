@@ -41,6 +41,8 @@ from __future__ import annotations
 
 from typing import List, Sequence
 
+import jax.numpy as jnp
+
 from lzy_tpu.utils.metrics import REGISTRY
 
 PROPOSED = REGISTRY.counter(
@@ -63,6 +65,43 @@ DRAFT_TRUNCATED = REGISTRY.counter(
 TOKENS_PER_STEP = REGISTRY.gauge(
     "lzy_spec_tokens_per_step",
     "mean generated tokens per decode step (1.0 = no speculation win)")
+
+
+def accept(prop, prop_len, greedy, nxt, pos):
+    """On-device speculative acceptance (traced inside verify_step).
+
+    Per row: the longest proposal prefix matching the model's own
+    argmax (``m``), the accepted tokens plus the bonus token after
+    them for speculating rows, or the single position-0 pick for
+    sampled/no-draft rows — bit-identical to the host loop it
+    replaces (``m`` via cumprod-of-matches is exactly the while-loop
+    prefix walk). Returns ``(packed [B, gamma+2], new_cur [B],
+    new_pos [B])`` where ``packed[:, :gamma+1]`` are emit tokens,
+    ``packed[:, gamma+1]`` the per-row emit count — ONE array, ONE
+    host transfer for the whole round."""
+    width = prop.shape[1] + 1            # gamma + 1
+    cols = jnp.arange(width - 1, dtype=jnp.int32)
+    ok = (prop == greedy[:, :-1]) & (cols[None, :] < prop_len[:, None])
+    m = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
+    spec = prop_len > 0                  # rows with a live draft
+    bonus = jnp.take_along_axis(greedy, m[:, None], axis=1)[:, 0]
+    allc = jnp.arange(width, dtype=jnp.int32)
+    prop_w = jnp.pad(prop, ((0, 0), (0, 1)))
+    emit = jnp.where(allc[None, :] < m[:, None], prop_w,
+                     jnp.where(allc[None, :] == m[:, None],
+                               bonus[:, None], 0))
+    # non-speculating rows emit exactly the position-0 pick (sampled
+    # rows keep their draw; greedy no-draft rows get argmax — which
+    # equals the m=0 bonus, so the where is a no-op for them)
+    emit = emit.at[:, 0].set(jnp.where(spec, emit[:, 0], nxt))
+    count = jnp.where(spec, m + 1, 1).astype(jnp.int32)
+    new_cur = jnp.take_along_axis(emit, (count - 1)[:, None],
+                                  axis=1)[:, 0]
+    packed = jnp.concatenate([emit, count[:, None]], axis=1)
+    # rows advance by exactly what they emit — the rollback the host
+    # used to do by rewriting index leaves after the fact is now the
+    # step's own output, exact by construction
+    return packed, new_cur, pos + count
 
 
 class NgramProposer:

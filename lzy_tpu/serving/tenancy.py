@@ -38,6 +38,10 @@ from lzy_tpu.serving.scheduler import (
 from lzy_tpu.utils.clock import SYSTEM_CLOCK
 from lzy_tpu.utils.metrics import REGISTRY
 
+# a tenant's terminal counters as the stats surface shows them, at zero
+TENANT_ROW = {"requests_finished": 0, "tokens_generated": 0,
+              "requests_cancelled": 0, "requests_preempted": 0,
+              "requests_error": 0}
 TENANT_REQUESTS = REGISTRY.counter(
     "lzy_tenant_requests_total",
     "finished requests by tenant and terminal status")

@@ -589,9 +589,9 @@ def test_paged_prefill_step_compiles_at_full_width(one_chip, monkeypatch):
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
         assert engine.prefill_chunk == 256
-        compiled = engine._prefill_step.lower(
+        compiled = engine.prefill.step.lower(
             [on_chip(leaf) for leaf in engine._payload], [],
-            jax.ShapeDtypeStruct((1, engine._job_layout[-1]), jnp.int32,
+            jax.ShapeDtypeStruct((1, engine.prefill.layout[-1]), jnp.int32,
                                  sharding=one_chip),
             jax.tree_util.tree_map(on_chip, params),
             on_chip(engine._rng), width=256).compile()

@@ -409,6 +409,29 @@ class TestInvariants:
         assert all(r.done for r in reqs)
         audit_engine(eng)
 
+    def test_auditor_catches_a_staged_jobs_lost_block(self, tiny_model):
+        """The jobs are read through ``engine.prefill.jobs``: a block given
+        back behind a staged job is the job walk's to catch (the pool
+        itself is consistent), and an engine without the object fails the
+        audit loudly, not silently."""
+        cfg, params = tiny_model
+        eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE,
+                                   prefill_budget=8)
+        eng.submit(list(range(1, 3 * PAGE)), max_new_tokens=2)
+        eng.step()
+        job, = eng.prefill.jobs
+        audit_engine(eng)
+        eng.kv.release(job.table[-1:])
+        with pytest.raises(InvariantViolation, match="prefill job"):
+            audit_engine(eng)
+        job.table.pop()
+        audit_engine(eng)
+        prefill = eng.__dict__.pop("prefill")
+        with pytest.raises(AttributeError, match="prefill"):
+            audit_engine(eng)
+        eng.prefill = prefill
+        eng.close()
+
     def test_auditor_catches_a_leaked_block(self):
         rc = RadixCache(8, PAGE)
         blocks = rc.allocate(2)

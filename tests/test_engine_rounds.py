@@ -68,7 +68,7 @@ def _reach_steady_decode(eng, reqs, rounds=200):
     queue empty) — from here on each step() is exactly one decode
     round."""
     for _ in range(rounds):
-        if (not eng._prefill_jobs and eng.queue.depth() == 0
+        if (not eng.prefill.jobs and eng.queue.depth() == 0
                 and sum(r is not None for r in eng._active) == len(reqs)):
             return
         eng.step()

@@ -17,7 +17,7 @@ from lzy_tpu.models.generate import (
     PREFILL_BUCKETS, generate, prefill_plan, prefill_width)
 from lzy_tpu.models.llama import LlamaConfig
 from lzy_tpu.serving import PagedInferenceEngine
-from lzy_tpu.serving import engine as engine_mod
+from lzy_tpu.serving import prefill as prefill_mod
 
 LENGTHS = (63, 64, 65, 255, 256, 257, 300, 700)
 NEW_TOKENS = 4
@@ -179,8 +179,8 @@ def test_a_long_prompt_takes_budgeted_rounds_between_decode_rounds(
         long = engine.submit(_tokens(2, 1024, cfg.vocab_size),
                              max_new_tokens=2, greedy=True)
         before = {c: _count(c) for c in (
-            engine_mod._PREFILL_PROGRAMS, engine_mod._PREFILL_TOKENS,
-            engine_mod._PREFILL_POSITIONS)}
+            prefill_mod._PREFILL_PROGRAMS, prefill_mod._PREFILL_TOKENS,
+            prefill_mod._PREFILL_POSITIONS)}
         rounds0 = engine.prefill_rounds
         for n in range(1, 5):
             emitted = len(resident.tokens)
@@ -190,9 +190,9 @@ def test_a_long_prompt_takes_budgeted_rounds_between_decode_rounds(
             assert len(resident.tokens) == emitted + 1
         assert len(long.tokens) >= 1
         moved = {c: _count(c) - v for c, v in before.items()}
-        assert moved[engine_mod._PREFILL_PROGRAMS] == programs
-        assert moved[engine_mod._PREFILL_TOKENS] == 1024
-        assert moved[engine_mod._PREFILL_POSITIONS] == programs * width
+        assert moved[prefill_mod._PREFILL_PROGRAMS] == programs
+        assert moved[prefill_mod._PREFILL_TOKENS] == 1024
+        assert moved[prefill_mod._PREFILL_POSITIONS] == programs * width
     finally:
         engine.close()
 
@@ -207,8 +207,8 @@ def test_the_counters_add_up_to_the_plans(llama_tiny):
     prompts = [shared + _tokens(4, 41, cfg.vocab_size),
                _tokens(5, 700, cfg.vocab_size),
                shared + _tokens(6, 200, cfg.vocab_size)]
-    counters = (engine_mod._PREFILL_TOKENS, engine_mod._PREFILL_POSITIONS,
-                engine_mod._PREFILL_PROGRAMS)
+    counters = (prefill_mod._PREFILL_TOKENS, prefill_mod._PREFILL_POSITIONS,
+                prefill_mod._PREFILL_PROGRAMS)
     before = [_count(c) for c in counters]
     try:
         for prompt in prompts:     # one after another: the third matches

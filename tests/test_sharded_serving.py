@@ -95,7 +95,7 @@ def _run(engine, prompt, n):
 
 def _reach_steady_decode(eng, reqs, rounds=200):
     for _ in range(rounds):
-        if (not eng._prefill_jobs and eng.queue.depth() == 0
+        if (not eng.prefill.jobs and eng.queue.depth() == 0
                 and sum(r is not None for r in eng._active) == len(reqs)):
             return
         eng.step()

@@ -851,13 +851,13 @@ class TestEngineBuilds:
             eng.step()
         serving = _samples("lzy_engine_serving_builds_total")
         stalled = _stalled_row_seconds()
-        step = eng._prefill_step
+        step = eng.prefill.step
 
         def slow_first_program(*args, **kwargs):
             clock.skew += 0.3          # the build, as the loop's clock sees
             return step(*args, **kwargs)
 
-        eng._prefill_step = slow_first_program
+        eng.prefill.step = slow_first_program
         caplog.clear()
         with trace.recording() as rec, caplog.at_level("WARNING"):
             wide = eng.submit(list(range(1, 41)), max_new_tokens=2)

@@ -461,11 +461,11 @@ class TestCancelPhases:
         req = engine.submit(prompt, max_new_tokens=8, greedy=True,
                             liveness=lambda: True)
         engine.step()                     # stages + first budget round
-        assert engine._prefill_jobs, "job should be staged"
+        assert engine.prefill.jobs, "job should be staged"
         req.cancel()
         engine.step()
         assert req.done and req.status == "cancelled"
-        assert not engine._prefill_jobs
+        assert not engine.prefill.jobs
         audit_engine(engine)
         after = self._deltas()
         assert after["prefill"] == before["prefill"] + 1

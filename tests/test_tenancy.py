@@ -376,18 +376,18 @@ class TestChunkedPrefill:
         assert len(r_short.tokens) >= 1
         r_long = engine.submit(long, max_new_tokens=8)
         engine.step()                       # stage + first budget round
-        assert engine._prefill_jobs
+        assert engine.prefill.jobs
         interleaved = 0
         rounds = 1
-        while engine._prefill_jobs and rounds < 50:
+        while engine.prefill.jobs and rounds < 50:
             before = len(r_short.tokens)
-            done_before = engine._prefill_jobs[0].done
+            done_before = engine.prefill.jobs[0].done
             engine.step()
             rounds += 1
-            if engine._prefill_jobs:
+            if engine.prefill.jobs:
                 # bounded advance per round: at most the budget (one
                 # chunk here) of prompt tokens moved
-                assert engine._prefill_jobs[0].done - done_before <= 16
+                assert engine.prefill.jobs[0].done - done_before <= 16
             if len(r_short.tokens) > before:
                 interleaved += 1
         # the 120-token prompt must have taken several rounds, and the
@@ -459,10 +459,10 @@ class TestChunkedPrefill:
         r = engine.submit([(5 * i) % 60 + 1 for i in range(120)],
                           max_new_tokens=4)
         engine.step()                      # staged, first chunk run
-        assert engine._prefill_jobs
+        assert engine.prefill.jobs
         r.cancel()
         engine.step()
-        assert not engine._prefill_jobs
+        assert not engine.prefill.jobs
         assert r.status == "cancelled"
         assert engine.kv.pool.free_count() == free0
         audit_engine(engine)

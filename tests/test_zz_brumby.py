@@ -356,9 +356,9 @@ def test_staged_prompts_are_bounded_and_the_rest_wait(tiny):
     for _ in range(3000):
         if not engine.step():
             break
-        most = max(most, len(engine._prefill_jobs))
+        most = max(most, len(engine.prefill.jobs))
     assert most == 2
-    assert len(engine._spare_state) <= 2
+    assert len(engine.prefill.spare_state) <= 2
     for req, prompt in zip(reqs, prompts):
         assert req.done and req.error is None
         assert _gap(tiny, prompt, req.tokens) < TOL

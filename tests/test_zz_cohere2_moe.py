@@ -542,7 +542,7 @@ def served(tiny):
                 break
             held.append(max(
                 [r.held for r in engine._win_rows]
-                + [j.window.held for j in engine._prefill_jobs]))
+                + [j.window.held for j in engine.prefill.jobs]))
         spans = rec.drain()
     after = {n: _counter(n) for n in before}
     yield {"engine": engine, "prompts": prompts, "reqs": reqs,

@@ -244,7 +244,7 @@ def _host_side_prefill(engine, prompt, blocks):
     module: the chunk sliced and padded on the host, a fresh ``[1]`` index
     leaf a layer at ``start``, the program told its last real index, the
     first token picked outside it."""
-    cfg, model = engine.cfg, engine._prefill_model
+    cfg, model = engine.cfg, engine._model
     tells_real = engine._tells_real
 
     @jax.jit
