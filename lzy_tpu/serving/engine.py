@@ -38,9 +38,13 @@ in ``models/serving.py``): this file names no model.
   round n's tokens are fetched. ``step()`` fetches the round it dispatched
   at once; the loop thread of ``start()`` fetches it one turn late, behind
   the next dispatch (``_InFlight``), so the fence's tail, ``emit`` and the
-  next turn's front half run under a device program. A turn that needs the
-  tokens first (a finished prompt, a proposer, a squeeze, the last row)
-  drains the round in flight and goes on as ``step()`` does.
+  next turn's front half run under a device program. A finished prompt
+  needs no token on the host either: its slot is activated on the device
+  (``_activate``: the first token as its last program picked it, scattered
+  into the round's inputs), the next round is dispatched behind the
+  prompt's programs, and the first token is fetched after that. A turn that
+  needs the tokens first (a proposer, a squeeze, the last row) drains the
+  round in flight and goes on as ``step()`` does.
 - **Deadlines, tenants, KV I/O**: per-request deadlines and dead clients
   evict mid-decode with a ``cancelled`` status; WFQ, queue caps and KV
   quotas come from a ``TenantTable``; cross-replica KV import / export,
@@ -157,8 +161,9 @@ _FP_PREFILL = CHAOS.register(
 # (cross-replica KV imports/exports, parked chains), ``reap`` (cancelled
 # requests), ``admit`` (pop, verdict, prefill staging: radix match, block
 # allocation, eviction), ``prefill`` (one budgeted prefill advance, less
-# ``prefill_fence``: the wait for the first token in the round that
-# finishes a prompt, observed in those rounds alone). The decode half:
+# ``prefill_fence``: the wait for the first token of a finished prompt,
+# taken behind the next round's dispatch and observed in the turns that
+# finish one alone). The decode half:
 # ``plan`` (host work before the dispatch), ``dispatch`` (input upload and
 # the program's enqueue), ``overlap`` (host work run while the device
 # computes), ``fence`` (the single blocking transfer), ``emit`` (token
@@ -195,6 +200,13 @@ _ROUND_DRAINS = REGISTRY.counter(
     "decode rounds the loop fetched with no later round dispatched over "
     "them, by what needed their tokens first "
     "(reason=admission|spec|squeeze|last_row|io|stop)")
+_ACTIVATIONS = REGISTRY.counter(
+    "lzy_engine_prompt_activations_total",
+    "finished prompts by how their slot began to decode (how=device: the "
+    "next round was dispatched with the first token still on the device, "
+    "nothing drained and nothing waited for; how=drained: the round in "
+    "flight was fetched and the first token waited for first: a proposer "
+    "reads it, or it is the request's only token)")
 _OVERRUN_ROWS = REGISTRY.counter(
     "lzy_engine_overrun_rows_total",
     "rows a decode round carried whose token was dropped at its fetch: the "
@@ -501,19 +513,24 @@ class PagedInferenceEngine:
         # It moves at a round's DISPATCH, so the next round's block growth
         # and page table need no token of the round in flight
         self._pos = np.zeros((slots,), np.int64)
-        # device-resident mirrors of the per-round jit inputs, uploaded
-        # once and reused until a host-side mutation invalidates them
-        # (None = stale). ``_cur_dev``/``_pos_dev`` are normally the
-        # PREVIOUS step's own outputs — the device keeps its own state
-        # between rounds and the host uploads nothing; only admission
-        # (``_finish_prefill``) forces a re-upload, from host mirrors that
-        # a round in flight has not reached yet: it is drained first. Idle
-        # rows drift in the device copies (stale token/position garbage) —
-        # harmless by construction: rows are independent, idle writes land
-        # on the scratch block, and idle outputs are never read.
+        # the per-round jit inputs, on the device. ``_cur_dev``/``_pos_dev``
+        # are uploaded once, below, and from then on are the PREVIOUS
+        # step's own outputs: the device keeps its own state between rounds
+        # and the host uploads nothing. A finished prompt's row is written
+        # into them on the device (``_activate``), never rebuilt from the
+        # host mirrors, which lack the round in flight. The greedy mask
+        # holds nothing the host does not know: it is uploaded again after
+        # an admission (None = stale). Idle rows drift in the device copies
+        # (stale token/position garbage) — harmless by construction: rows
+        # are independent, idle writes land on the scratch block, and idle
+        # outputs are never read.
         self._cur_dev: Any = None        # [slots] int32 last tokens
         self._pos_dev: Any = None        # [slots] int32 cache positions
         self._mask_dev: Any = None       # [slots] bool greedy mask
+        # finished prompts whose slot decodes already and whose first token
+        # the host has not fetched: ``(slot, request, [1] device array)``,
+        # settled in the turn that made them (``_settle_first``)
+        self._first_pending: List[tuple] = []
         # device->host fences taken by decode rounds — public so the
         # transfer-count regression test can pin the one-fence contract
         self.host_fetches = 0
@@ -631,6 +648,9 @@ class PagedInferenceEngine:
         self._stat_counters: tuple = ()
         self._dispatch_paths: dict = {}   # positions a row -> path labels
         self._build_decode_path(base)
+        self._cur_dev = self._programs.upload(self._cur)
+        self._pos_dev = self._programs.upload(
+            np.zeros((slots,), np.int32))
         if self._pooled != any(k in serving.POOLS for k in self._leaf_kinds):
             raise ValueError(
                 f"{type(base).__name__} says kv_layers {base.kv_layers} and "
@@ -677,7 +697,7 @@ class PagedInferenceEngine:
             payload=lambda: self._payload, rng=lambda: self._rng,
             set_rng=lambda key: setattr(self, "_rng", key),
             row_greedy=self._row_greedy, first=self._first,
-            count_dispatch=self._count_dispatch, drain=self._drain,
+            count_dispatch=self._count_dispatch,
             enter=lambda: CHAOS.hit("engine.prefill"), fatal=PoolCorruption,
             finished=self._prompt_done, failed=self._fail_request,
             cancelled=self._finish_cancelled)
@@ -919,7 +939,15 @@ class PagedInferenceEngine:
         one fence a round. Called by the loop thread of ``start()`` the
         fence lags the dispatch by one turn (``plan(n+1) -> dispatch(n+1)
         -> overlap -> fence(n) -> emit(n)``): still one fence a round,
-        taken while the device runs the next one."""
+        taken while the device runs the next one.
+
+        A turn that finishes a prompt waits for nothing before that
+        dispatch: the slot is activated on the device (``_prompt_done``),
+        round n+1 carries the new row and is queued behind the prompt's
+        last program, and the first token is fetched and emitted after it
+        (behind round n's fence on the loop thread), in the same turn: when
+        ``step()`` returns, from whichever thread, the first token of a
+        prompt it finished has been emitted."""
         now = self._clock.now
         lag = threading.get_ident() == self._loop_ident
         self._round_kind = None
@@ -950,18 +978,21 @@ class PagedInferenceEngine:
             with trace.span(trace.ENGINE_PREFILL):
                 progressed = self.prefill.advance()
             t4 = now()
-            # a finished prompt drains the round in flight before it waits
-            # for its first token: that fence and emit are observed as
-            # such, not as prefill
-            fence_wait = self.prefill.fence_wait
-            prefill_dt = self._less_drains(t4 - t3) - fence_wait
+            # a finished prompt that had to have its first token at once
+            # (``_prompt_done``) drained the round in flight and waited
+            # inside ``advance``: observed as fence, emit and prefill_fence,
+            # not as prefill
+            prefill_dt = self._less_drains(t4 - t3) - self.prefill.fence_wait
             stepped = self._decode(lag)
             # observed after the round's fence, like the decode half's
             self._observe_phase("kv_io", kv_io_dt)
             self._observe_phase("reap", t2 - t1)
             self._observe_phase("admit", t3 - t2)
             self._observe_phase("prefill", prefill_dt)
-            if fence_wait:      # only a round that finished a prompt
+            # the wait for a finished prompt's first token, wherever in the
+            # turn it fell (as a rule behind the decode half's dispatch)
+            fence_wait = self.prefill.fence_wait
+            if fence_wait:      # only a turn that finished a prompt
                 self._observe_phase("prefill_fence", fence_wait)
             worked = serviced or admitted or progressed or stepped
             if rnd and stepped:
@@ -1130,10 +1161,24 @@ class PagedInferenceEngine:
         _BUSY.set(float(sum(r is not None for r in self._active)))
         return admitted
 
-    def _prompt_done(self, job: Job, first: int) -> None:
+    def _prompt_done(self, job: Job, first) -> None:
         """What a finished prompt does to its slot (``Prefill``'s
         ``finished``): the job's blocks and window row become the slot's,
-        and the slot starts generating from ``first``."""
+        and the slot starts generating from ``first``, the ``[1]`` token on
+        the device as the prompt's last program picked it.
+
+        Nothing here waits for that program. The row is activated on the
+        device (``_activate``), so the turn's decode half dispatches the
+        next round with the new row in it, over the round in flight and
+        behind the prompt's programs in the device's queue; the token is
+        fetched after that, in the same turn (``_settle_first``). An EOS
+        first token is learnt one round late, as a decode row's is: the row
+        rides that round and its token is dropped. Two kinds of prompt take
+        the drained path instead (the round in flight fetched, then the
+        first token waited for, then the slot's fate decided): one whose
+        first token is its last, known before any fetch (it never activates
+        a slot), and any prompt of an engine with a proposer, whose next
+        turn reads the newest token."""
         req, slot, table = job.req, job.slot, job.table
         # register the prompt's full blocks for future prefix hits (the
         # matched prefix nodes already exist and are skipped; pad garbage
@@ -1156,34 +1201,58 @@ class PagedInferenceEngine:
             job.window = None
         self._admissions += 1
         self._admit_seq[slot] = self._admissions
-        self._finish_prefill(slot, req, first)
+        # the prompt is now cache-resident; the first generated token is
+        # not (the next decode step writes it at this position)
+        self._pos[slot] = len(req.prompt)
+        one_token = req.max_new_tokens == 1
+        if not one_token:
+            with self._first("activate", trace.SITE_AUX, phase="prefill"):
+                self._cur_dev, self._pos_dev = self._activate(
+                    self._cur_dev, self._pos_dev, first, np.int32(slot),
+                    np.int32(len(req.prompt)))
+            # the live row set changed, which the greedy mask follows: it
+            # holds nothing the host does not know, and is uploaded again
+            self._mask_dev = None
+        if one_token or self._proposer is not None:
+            _ACTIVATIONS.inc(how="drained")
+            # the round in flight was queued in front of the prompt's
+            # programs: its tokens go out now, not behind them
+            self._drain("admission")
+            self._finish_prefill(slot, req, self.prefill.fence(first))
+        else:
+            _ACTIVATIONS.inc(how="device")
+            self._active[slot] = req
+            self._first_pending.append((slot, req, first))
+
+    def _settle_first(self) -> None:
+        """Fetch and emit the first token of the prompt this turn finished
+        and activated on the device. Called behind the dispatch of the
+        round that carries the new row (and, on the loop thread, behind the
+        fence of the round before it, which left the device first), or by
+        whatever drains the turn before that: never later than the turn's
+        end, so no reap, preemption or admission falls between the
+        activation and the token."""
+        if not self._first_pending:
+            return
+        pending, self._first_pending = self._first_pending, []
+        for slot, req, first in pending:
+            self._finish_prefill(slot, req, self.prefill.fence(first))
 
     def _finish_prefill(self, slot: int, req: Request, first: int) -> None:
-        """Shared prefill tail: record TTFT, emit the first token, and
-        either free the slot (one-token request) or activate it."""
+        """Shared prefill tail, once the first token is on the host: record
+        TTFT, emit the token, and either free the slot (the token ended the
+        request) or leave it decoding."""
         req.phase = "decode"
         now = self._clock.now()
         req.first_token_at = now
         _TTFT.observe(now - req.submitted_at)
         TENANT_TTFT.observe(now - req.submitted_at, tenant=req.tenant)
-        # the prompt is now cache-resident; the first generated token is
-        # not (the next decode step writes it at this position)
-        self._pos[slot] = len(req.prompt)
         self._emit(slot, req, first, active=False)
         if req.done:
-            self._free(slot)      # one-token request: slot never activates
+            self._free(slot)
         else:
             self._active[slot] = req
             self._cur[slot] = first
-        # admission changed the live row set: the device-resident round
-        # inputs must be rebuilt from the host mirrors (the ONLY event
-        # that forces a re-upload — frees leave harmless idle-row
-        # garbage in place instead). The mirror of tokens holds every
-        # round dispatched so far: the prefill's fence drained the one in
-        # flight
-        self._cur_dev = None
-        self._pos_dev = None
-        self._mask_dev = None
         self._flush_token_accounting()
 
     def _fetch(self, arr) -> np.ndarray:
@@ -1198,30 +1267,23 @@ class PagedInferenceEngine:
 
     def _device_inputs(self):
         """The per-round jit inputs, device-resident across rounds.
-        ``_cur_dev``/``_pos_dev`` are normally the previous step's own
-        outputs (nothing uploaded, and no token of that step needed on the
-        host: it may still be in flight); after an admission they are
-        rebuilt from the host mirrors, which is why ``_decode`` drains a
-        round in flight before a round whose ``_cur_dev`` is stale.
-        Uploaded by ``ProgramBuild.upload``: an explicit copy
-        (``jnp.array``), never ``jnp.asarray``: asarray zero-copies the
-        live numpy buffer, and ``_emit``'s later host writes would mutate
-        the device view."""
-        up = self._programs.upload
-        if self._cur_dev is None:
-            self._cur_dev = up(self._cur)
-        if self._pos_dev is None:
-            self._pos_dev = up(np.asarray(self._pos, np.int32))
+        ``_cur_dev``/``_pos_dev`` are the previous step's own outputs, with
+        the rows of prompts finished since written in on the device
+        (``_activate``): nothing is uploaded, and no token of that step or
+        of those prompts is needed on the host (the step may still be in
+        flight, the first tokens unfetched). The greedy mask alone is
+        rebuilt from the host after an admission, by
+        ``ProgramBuild.upload``: an explicit copy (``jnp.array``), never
+        ``jnp.asarray``, which zero-copies the live numpy buffer."""
         if self._mask_dev is None:
-            self._mask_dev = up(self._greedy_mask())
+            self._mask_dev = self._programs.upload(self._greedy_mask())
         return self._cur_dev, self._pos_dev, self._mask_dev
 
     def _stale_inputs(self) -> int:
-        """How many of the round's inputs (``cur``, ``pos``, the greedy
-        mask, the page table) the dispatch is about to rebuild from the
-        host: 0 in a round that follows no admission."""
-        return sum(x is None for x in (self._cur_dev, self._pos_dev,
-                                       self._mask_dev, self._pt_dev))
+        """How many of the round's inputs (the greedy mask, the page
+        table) the dispatch is about to upload from the host: 0 in a round
+        that follows no admission and grew no page."""
+        return (self._mask_dev is None) + (self._pt_dev is None)
 
     def _overlap_window(self) -> None:
         """Host work run BETWEEN the round's dispatch and its fence —
@@ -1291,9 +1353,12 @@ class PagedInferenceEngine:
         one fetched is the round before it, so the fence's tail, the emit
         and the next turn's front half run under a device program. The
         round in flight is drained first where this turn needs its
-        tokens: the device inputs are to be rebuilt from the host mirrors
-        (a finished prompt), a proposer reads the newest token, or every
-        live row ends with the token in flight."""
+        tokens: a proposer reads the newest token, or every live row ends
+        with the token in flight. A prompt finished in this turn needs
+        none: its row is in the device inputs already, and its first token
+        is fetched behind the dispatch (and behind the fence of the round
+        before, which the device ran first), in front of this round's own
+        tokens."""
         drained = False
         if self._inflight is not None:
             why = self._needs_tokens()
@@ -1331,6 +1396,7 @@ class PagedInferenceEngine:
         if before is not None:
             _ROUNDS_OVERLAPPED.inc()
             self._fence_emit(before)
+        self._settle_first()
         if not lag:
             self._fence_emit(rec, spec)
         # observed after the turn's fence: these take locks
@@ -1377,14 +1443,15 @@ class PagedInferenceEngine:
             # a length finish is known before the fetch: no round is
             # dispatched past the end of the last live row
             return "last_row"
-        if self._cur_dev is None:
-            return "admission"
         return None
 
     def _riding(self) -> set:
         """Slots whose request ends by length with the token in flight:
         still active until that token is fetched, so they ride the next
-        round (its token for them is dropped) but grow no block for it."""
+        round (its token for them is dropped) but grow no block for it. A
+        first token is fetched in the turn that dispatched its row's first
+        round, so ``req.tokens`` holds it by the time the row is in
+        flight."""
         rec = self._inflight
         if rec is None:
             return set()
@@ -1393,14 +1460,19 @@ class PagedInferenceEngine:
                 and len(req.tokens) + 1 >= req.max_new_tokens}
 
     def _drain(self, reason: str) -> bool:
-        """Fetch and emit the round in flight with no later round queued
-        behind it; False when there is none. What it takes is observed as
-        ``fence`` and ``emit`` and kept out of the phase it fell in."""
+        """Fetch and emit what the device owes the host, with no later
+        round queued behind it: the round in flight, then the first token
+        of a prompt this turn finished (the device ran them in that order).
+        False when there is neither. What it takes is observed as ``fence``,
+        ``emit`` and ``prefill_fence`` and kept out of the phase it fell
+        in."""
         rec = self._inflight
-        if rec is None:
+        if rec is None and not self._first_pending:
             return False
         t0 = self._clock.now()
-        self._fence_emit(rec, reason)
+        if rec is not None:
+            self._fence_emit(rec, reason)
+        self._settle_first()
         self._drain_wait += self._clock.now() - t0
         return True
 
@@ -1694,8 +1766,8 @@ class PagedInferenceEngine:
         position, greedy-mask bit) is left stale on purpose — idle rows
         are garbage-tolerant (writes land on the scratch block, outputs
         are never read), and the re-admission that makes the slot matter
-        again rebuilds all three mirrors (``_finish_prefill``). The
-        slot's blocks go back to the pool (or stay cached in the tree)."""
+        again writes all three (``_prompt_done``). The slot's blocks go
+        back to the pool (or stay cached in the tree)."""
         self._active[slot] = None
         self._cur[slot] = 0
         self._pos[slot] = 0
@@ -1763,6 +1835,10 @@ class PagedInferenceEngine:
 
         with self._build(trace.SITE_DECODE):
             compiled(self._decode_step, vec, vec)
+        with self._build(trace.SITE_AUX, "activate"):
+            scalar = jax.ShapeDtypeStruct((), jnp.int32)
+            self._activate.lower(vec, vec, aval((1,), jnp.int32), scalar,
+                                 scalar).compile()
         if self._has_state:
             with self._build(trace.SITE_SPLICE):
                 self.prefill.compile_splice(payload)
@@ -1850,6 +1926,7 @@ class PagedInferenceEngine:
                 # be what died, and a waiter must not wait on it. Its rows
                 # fail below with the tokens they had
                 self._inflight = None
+                self._first_pending = []
                 self._fail_outstanding("error", "engine loop died")
             finally:
                 self._loop_ident = None
@@ -2082,6 +2159,21 @@ class PagedInferenceEngine:
                 [nxt, counts.astype(jnp.int32)])
 
         self._decode_step = build.jit(decode_step, donate=(0,))
+
+        def activate_slot(cur, pos, first, slot, at):
+            """A finished prompt's row enters the round's inputs where they
+            live: its first token (``[1]``, as ``prefill_step`` picked it)
+            and the position that token will be written at."""
+            return cur.at[slot].set(first[0]), pos.at[slot].set(at)
+
+        # nothing is donated: with a round in flight ``cur`` is the very
+        # array that round's fence will fetch, and ``[slots]`` int32 are not
+        # worth a buffer's reuse. A gang's come out replicated, as its
+        # decode program was warmed to take them
+        self._activate = build.jit(
+            activate_slot,
+            **({} if build.replicated is None else
+               {"out_shardings": (build.replicated, build.replicated)}))
 
         def verify_step(payload, params, cur, prop, prop_len, pos,
                         page_table, greedy_mask, rng):

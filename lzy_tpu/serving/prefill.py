@@ -6,11 +6,12 @@ alone. It holds the job (:class:`Job`), the one buffer a job's programs are
 told everything in (layout, host writer and traced reader side by side), the
 programs (``prefill_step``, the rng's split, the splice of a state model's
 rows), and the life of a job: staging, one budgeted round a scheduling round
-over the jobs in turn, the spare state rows, abort, and the fence that waits
-for the first token. The scheduler decides who is admitted and when, and is
-told how a job ended: ``finished(job, first_token)`` (what that does to the
-slot is the scheduler's), ``failed(request, error, what)``,
-``cancelled(request)``.
+over the jobs in turn, the spare state rows, abort, and the fence that
+fetches the first token. The scheduler decides who is admitted and when, and
+is told how a job ended: ``finished(job, first)``, with the first token still
+a ``[1]`` array on the device (what that does to the slot, and when
+``fence(first)`` brings the token to the host, is the scheduler's),
+``failed(request, error, what)``, ``cancelled(request)``.
 
 :class:`ProgramBuild` is where the engine's programs (these, and the decode
 and verify steps of ``serving/engine.py``) learn what a gang changes.
@@ -161,20 +162,19 @@ class Prefill:
     page) and ``kv_io`` are the pools a job's pages come from and the tiers
     a prompt's prefix is promoted through; ``first(key, site, **attrs)`` is
     the build context around a program's first call, ``count_dispatch`` the
-    kernel-path counter a program of a width, ``drain(reason)`` the fetch of
-    the decode round in flight; ``sampling`` is ``(temperature, top_k,
-    top_p)`` and ``row_greedy(request)`` a request's own mode. ``enter`` is
-    called at the head of a round's device section and ``fatal`` is the
-    error a failure inside it is raised as: the shared pool was donated
-    into it, so it fails the engine, not the request."""
+    kernel-path counter a program of a width; ``sampling`` is ``(temperature,
+    top_k, top_p)`` and ``row_greedy(request)`` a request's own mode.
+    ``enter`` is called at the head of a round's device section and
+    ``fatal`` is the error a failure inside it is raised as: the shared
+    pool was donated into it, so it fails the engine, not the request."""
 
     def __init__(self, cfg, model, params, *, leaf_kinds, treedef,
                  state_at, pool_at, build: ProgramBuild, kv, kv_io, win,
                  page_size: int, pooled: bool, chunk: int,
                  budget: Optional[int], max_jobs: Optional[int],
                  sampling: tuple, tells_real: bool, clock, payload, rng,
-                 set_rng, row_greedy, count_dispatch, first, drain, enter,
-                 finished: Callable[[Job, int], None],
+                 set_rng, row_greedy, count_dispatch, first, enter,
+                 finished: Callable[[Job, Any], None],
                  failed: Callable[[Request, Exception, str], None],
                  cancelled: Callable[[Request], None], fatal: type):
         self._cfg, self._model, self._params = cfg, model, params
@@ -196,13 +196,13 @@ class Prefill:
         self._clock = clock
         self._payload, self._rng, self._set_rng = payload, rng, set_rng
         self._row_greedy, self._count_dispatch = row_greedy, count_dispatch
-        self._first, self._drain, self._enter = first, drain, enter
+        self._first, self._enter = first, enter
         self._finished, self._failed = finished, failed
         self._cancelled, self._fatal = cancelled, fatal
         self.jobs: List[Job] = []
         self._next = 0                  # the round-robin cursor into jobs
         self.rounds = 0                 # public: interleave observability
-        self.fence_wait = 0.0           # this round's fence, in seconds
+        self.fence_wait = 0.0           # this turn's fences, in seconds
         # which payload leaves are per-slot state, by their place in the
         # payload; the rest are keys and values
         self._state_at, self._pool_at = list(state_at), list(pool_at)
@@ -548,8 +548,9 @@ class Prefill:
         exactly (interior chunks are unpadded), so chunking never changes
         the device math — only its interleaving. The round that finishes a
         prompt splices, on a model with state leaves, the job's rows into
-        the slot's, and reads the first token the program picked (the
-        fence)."""
+        the slot's, and hands the scheduler the first token as the program
+        left it, on the device: nothing here waits for the prompt's
+        programs (``fence`` does, when the scheduler asks)."""
         # everything device-side below donates the SHARED pool: a failure
         # here poisons every request, not just this one
         try:
@@ -575,7 +576,7 @@ class Prefill:
             raise self._fatal(
                 f"paged prefill died mid-flight for {job.req.id}: "
                 f"{type(e).__name__}: {e}") from e
-        self._finished(job, self._fence(first))
+        self._finished(job, first)
         return True
 
     def _run_program(self, job: Job, take: int, width: int):
@@ -609,7 +610,8 @@ class Prefill:
             job.state = self.spare_state.pop() if self.spare_state \
                 else self._new_state_rows(payload)
         key = self._rng()
-        if job.next_chunk == len(job.plan) - 1:
+        finishing = job.next_chunk == len(job.plan) - 1
+        if finishing:
             PREFILL_CALLS.inc()
             with self._first("split_rng", trace.SITE_AUX, phase="prefill"):
                 rng, key = self.split_rng(key)
@@ -635,6 +637,11 @@ class Prefill:
             payload[i] = leaf
         if self._has_state:
             job.state = state
+        if finishing:
+            # the finished prompt's first token is asked for now, in front
+            # of whatever is queued next: ``fence`` then waits for this
+            # program, not for a copy behind a later one
+            first.copy_to_host_async()
         return first
 
     def _new_state_rows(self, payload) -> list:
@@ -662,18 +669,19 @@ class Prefill:
             payload[i] = row
         self._leave_state(job)
 
-    def _fence(self, first) -> int:
-        """The prefill's one blocking transfer: the first token as the
-        finishing program picked it, and with it the wait for every chunk
-        still queued on the device. Timed apart from the ``prefill``
-        phase (``fence_wait``): here the loop waits for the device, not
-        the device for the loop. A decode round in flight was queued in
-        front of the prompt's programs and the slot is about to be
-        activated from the host mirrors, so it is drained first: its
-        tokens go out now, not behind the prompt's programs."""
-        self._drain("admission")
+    def fence(self, first) -> int:
+        """The prefill's one blocking transfer: the first token of a
+        finished prompt (``finished``'s ``first``, whose copy to the host
+        the finishing program's dispatch asked for), and with it the wait
+        for whichever of the prompt's programs the device has not run yet.
+        Called by the scheduler when it wants the token: behind the next
+        decode round's dispatch, so that the device has that round to run
+        while the loop waits here, or at once where the turn cannot go on
+        without it. Timed apart from the ``prefill`` phase (``fence_wait``,
+        summed over a turn): here the loop waits for the device, not the
+        device for the loop."""
         t0 = self._clock.now()
         with trace.span(trace.ENGINE_PREFILL_FENCE):
             token = int(np.asarray(first)[0])
-        self.fence_wait = self._clock.now() - t0
+        self.fence_wait += self._clock.now() - t0
         return token

@@ -71,7 +71,7 @@ class _Bench:
         self.rng = jax.random.PRNGKey(0)
         self.kv = RadixCache(32, PAGE)
         self.done, self.failed, self.cancelled = [], [], []
-        self.widths, self.drains, self.entered = [], [], []
+        self.widths, self.entered = [], []
         self.prefill = Prefill(
             types.SimpleNamespace(max_seq_len=SEQ), _Echo(), {},
             leaf_kinds=kinds, treedef=treedef, state_at=[0], pool_at=[1],
@@ -85,7 +85,7 @@ class _Bench:
             row_greedy=lambda req: bool(req.greedy),
             count_dispatch=self.widths.append,
             first=lambda *a, **kw: trace.NOOP,
-            drain=self.drains.append, enter=lambda: self.entered.append(1),
+            enter=lambda: self.entered.append(1),
             finished=finished or (lambda job, tok: self.done.append(
                 (job.req, job.slot, tok))),
             failed=lambda req, e, what: self.failed.append((req, e, what)),
@@ -136,10 +136,41 @@ def test_each_program_sees_the_row_that_was_written():
     assert table.shape == (1, prompt_at - table_at) == (1, SEQ // PAGE)
     chunk = p._read_chunk(prompt, ctl[0], ctl[1], 16)
     assert list(np.asarray(chunk)[0]) == req.prompt[32:40] + [0] * 8
-    # the prompt's last real token came back as the first token
-    assert bench.done == [(req, 2, req.prompt[-1])]
-    assert bench.widths == [16, 16, 8] and bench.drains == ["admission"]
+    # the scheduler was handed the first token where the program left it,
+    # and nothing waited for the program: the fence is the scheduler's call
+    (done, slot, first), = bench.done
+    assert done is req and slot == 2
+    assert isinstance(first, jax.Array) and first.shape == (1,)
+    assert p.fence_wait == 0.0
+    # the prompt's last real token comes back as the first token
+    assert p.fence(first) == req.prompt[-1] and p.fence_wait > 0.0
+    assert bench.widths == [16, 16, 8]
     assert p.rounds == 3 and not p.jobs and len(bench.entered) == 3
+
+
+def test_the_fence_is_timed_over_a_turn_and_spanned():
+    """``fence`` is where the loop waits for a prompt's programs: its
+    seconds add up over the fences of a turn (``advance`` opens the turn)
+    and each is an ``engine.prefill.fence`` record."""
+    bench = _Bench(budget=None)
+    p = bench.prefill
+    reqs = [bench.request(20, first=1), bench.request(9, first=30)]
+    for slot, req in enumerate(reqs):
+        p.stage(slot, req)
+    assert p.advance() and p.advance() and not p.jobs
+    assert p.fence_wait == 0.0
+    with trace.recording() as rec:
+        tokens = []
+        for _, _, first in bench.done:
+            before = p.fence_wait
+            tokens.append(p.fence(first))
+            assert p.fence_wait > before
+        recs = rec.drain()
+    assert tokens == [20, 38]
+    assert [r.name for r in recs if r.name.startswith("engine.")] == \
+        [trace.ENGINE_PREFILL_FENCE] * 2
+    p.stage(0, bench.request(5))
+    assert p.advance() and p.fence_wait == 0.0
 
 
 def test_a_sampled_rows_flag_is_written_too():

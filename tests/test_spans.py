@@ -403,11 +403,15 @@ class TestEngineLoop:
         for phase, name in span_of.items():
             spans = sum(r.end - r.start for r in recs[name])
             assert grown[phase] == pytest.approx(spans, abs=2e-3), phase
-        # the wait for the first token is a child of engine.prefill and a
-        # label of its own: ``prefill`` is the rest of the advance
+        # the wait for the first token is a label of its own and no part
+        # of the advance: it is taken behind the decode half's dispatch, a
+        # child of the round, and ``prefill`` is the advance whole
         fence, = recs[trace.ENGINE_PREFILL_FENCE]
-        assert fence.parent in {r.id for r in recs[trace.ENGINE_PREFILL]}
-        assert grown["prefill"] + grown["prefill_fence"] == pytest.approx(
+        assert fence.parent in {r.id for r in recs[trace.ENGINE_ROUND]}
+        dispatch = next(r for r in recs[trace.ENGINE_DECODE_DISPATCH]
+                        if r.parent == fence.parent)
+        assert dispatch.end <= fence.start
+        assert grown["prefill"] == pytest.approx(
             sum(r.end - r.start for r in recs[trace.ENGINE_PREFILL]),
             abs=2e-3)
         total = sum(after[p] - before.get(p, 0.0)
@@ -491,9 +495,10 @@ class TestEngineLoop:
 
     def test_a_round_says_what_it_moved(self, tiny_model):
         """``uploads`` on the dispatch span counts the inputs the round
-        rebuilt from the host (the round after an admission; none in the
-        rounds that follow), ``bytes`` on the fence span is the size of
-        the one array the round fetched."""
+        uploaded from the host (the round after an admission: the greedy
+        mask and the page table, never the tokens or positions, which the
+        device keeps; none in the rounds that follow), ``bytes`` on the
+        fence span is the size of the one array the round fetched."""
         cfg, params = tiny_model
         eng = PagedInferenceEngine(cfg, params, slots=2, page_size=PAGE)
         first = eng.submit([5, 9, 3], max_new_tokens=4)
@@ -508,7 +513,7 @@ class TestEngineLoop:
         for recs in (one, two):
             uploads = [r.attrs["uploads"]
                        for r in recs[trace.ENGINE_DECODE_DISPATCH]]
-            assert uploads[0] >= 3          # cur, pos and the mask at least
+            assert uploads[0] == 2          # the mask and the page table
             assert len(uploads) > 1 and set(uploads[1:]) == {0}
             assert {r.attrs["bytes"] for r in recs[
                 trace.ENGINE_DECODE_FENCE]} == {eng.slots * 4}

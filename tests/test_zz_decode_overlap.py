@@ -17,8 +17,14 @@ engine, which the other files hold to ``generate()``:
 - one fence a round still (``host_fetches == decode_steps``), no round past
   the end of the last length-limited row, the rng untouched by a round no
   row survives;
-- whatever needs the tokens first drains (a finished prompt, a proposer, a
-  squeeze, a parked chain, a stop), counted by reason;
+- a finished prompt needs no token on the host: its slot is activated on the
+  device, the next round is dispatched over the round in flight and behind
+  the prompt's programs, and the first token is fetched after that, in the
+  same turn (``lzy_engine_prompt_activations_total{how}``); an EOS first
+  token is learnt one round late, a request of one token never activates a
+  slot, a proposer keeps the drained path;
+- whatever needs the tokens first drains (a proposer, a squeeze, a parked
+  chain, a stop), counted by reason;
 - ``close()`` and a dead loop with a round in flight finish every waiter.
 
 Most cases drive the overlapped turn from the test's own thread, by naming
@@ -45,6 +51,7 @@ OVERLAPPED = "lzy_engine_rounds_overlapped_total"
 FENCES = "lzy_engine_round_fences_total"
 OVERRUN = "lzy_engine_overrun_rows_total"
 DRAINS = "lzy_engine_round_drains_total"
+ACTIVATIONS = "lzy_engine_prompt_activations_total"
 
 
 @pytest.fixture(scope="module")
@@ -99,6 +106,15 @@ def _counter(name, **labels):
 def _drains():
     return {r: _counter(DRAINS, reason=r) for r in
             ("admission", "spec", "squeeze", "last_row", "io", "stop")}
+
+
+def _activations():
+    return {how: _counter(ACTIVATIONS, how=how)
+            for how in ("device", "drained")}
+
+
+def _moved(after, before):
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
 
 
 def _lagging(engine):
@@ -451,8 +467,7 @@ def test_counters_and_span_attributes_pair_a_fence_with_its_dispatch(models):
     # all of them over an unfetched round: nothing drained but the last
     assert _counter(OVERLAPPED) - overlapped == rounds - 1
     after = _drains()
-    assert {r: after[r] - before[r] for r in after if after[r] != before[r]} \
-        == {"last_row": 1}
+    assert _moved(after, before) == {"last_row": 1}
     assert _counter(OVERRUN) - overrun == 1      # ``short``, by length
     dispatches = [r for r in recs if r.name == trace.ENGINE_DECODE_DISPATCH]
     fetched = [r for r in recs if r.name == trace.ENGINE_DECODE_FENCE]
@@ -527,4 +542,237 @@ def test_step_from_another_thread_keeps_its_contract(models):
         seen = len(req.tokens)
     assert engine.host_fetches == engine.decode_steps == 9
     assert _counter(OVERLAPPED) == overlapped and _drains() == before
+    engine.close()
+
+
+# -- a finished prompt activates its slot on the device -----------------------
+
+
+def _decoding(engine, prompt, budget, least=3):
+    """A request stepped until it has ``least`` tokens: a resident row,
+    with a round in flight where the engine lags."""
+    req = engine.submit(prompt, max_new_tokens=budget)
+    for _ in range(100):
+        engine.step()
+        if len(req.tokens) >= least:
+            return req
+    raise AssertionError("the row never got going")
+
+
+def _until_one_chunk_is_left(engine, req):
+    """Step until ``req``'s staged prompt has one program to go: the next
+    turn finishes it."""
+    for _ in range(200):
+        job = next((j for j in engine.prefill.jobs if j.req is req), None)
+        if job is not None and job.next_chunk == len(job.plan) - 1:
+            return job
+        assert not req.tokens, "the prompt finished before it was looked at"
+        engine.step()
+    raise AssertionError("the prompt was never staged")
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_finished_prompt_drains_nothing_and_waits_for_nothing(models, kind):
+    """A row decodes, a second prompt finishes with a round in flight. That
+    turn: no drain, the next round dispatched over the round in flight
+    with the new row in it before anything is fetched, then round n's
+    fence, then the first token's (the prefill's own, one a finished
+    prompt), and the first token out when the turn ends. And the tokens of
+    a ``step()``-driven engine in the end."""
+    model, gang = models(kind), kind == "gang"
+    vocab = model[0].vocab_size
+    prompts = [_tokens(101, 12, vocab), _tokens(102, 41, vocab)]
+
+    def submit(engine):
+        return [engine.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, (40, 12))]
+
+    ref = _engine(model, gang=gang)
+    want = submit(ref)
+    _run(ref, want)
+    ref.close()
+    engine = _lagging(_engine(model, gang=gang))
+    old = _decoding(engine, prompts[0], 40)
+    new = engine.submit(prompts[1], max_new_tokens=12)
+    _until_one_chunk_is_left(engine, new)
+    assert engine._inflight is not None and not new.tokens
+    drains, acts = _drains(), _activations()
+    overlapped, fetches = _counter(OVERLAPPED), engine.host_fetches
+    steps, had = engine.decode_steps, len(old.tokens)
+    with trace.recording() as rec:
+        engine.step()
+        recs = rec.drain()
+    assert _drains() == drains
+    assert _moved(_activations(), acts) == {"device": 1}
+    assert _counter(OVERLAPPED) - overlapped == 1
+    # one decode round fetched (the one that was in flight), one fence of
+    # the prefill's own, and the new row rides the round now in flight
+    assert engine.host_fetches - fetches == 1 == engine.decode_steps - steps
+    assert len(new.tokens) == 1 and len(old.tokens) == had + 1
+    assert new.first_token_at is not None
+    assert new in [req for _, req in engine._inflight.rows]
+    assert not engine._first_pending
+    dispatch, = [r for r in recs if r.name == trace.ENGINE_DECODE_DISPATCH]
+    fence, = [r for r in recs if r.name == trace.ENGINE_DECODE_FENCE]
+    first, = [r for r in recs if r.name == trace.ENGINE_PREFILL_FENCE]
+    assert dispatch.attrs["overlapped"] is True
+    assert dispatch.end <= fence.start and fence.end <= first.start
+    assert fence.attrs["round"] == dispatch.attrs["round"] - 1
+    _run(engine, [old, new])
+    assert [list(r.tokens) for r in (old, new)] == \
+        [list(r.tokens) for r in want]
+    assert engine.host_fetches == engine.decode_steps
+    assert _drains()["admission"] == drains["admission"]
+    engine.close()
+
+
+def test_an_eos_first_token_is_learnt_one_round_late(models):
+    """The first token is the EOS: it is emitted and ends the request. The
+    round dispatched before it was fetched carried the row once: it wrote
+    the prompt's own pages, the scratch page and the other row's pages and
+    no other, and its token for the row is dropped and counted."""
+    model = models("llama")
+    a_prompt, b_prompt = _tokens(111, 9, 64), _tokens(112, 37, 64)
+    probe = _engine(model)
+    b = probe.submit(b_prompt, max_new_tokens=4)
+    _run(probe, [b])
+    probe.close()
+    eos = b.tokens[0]
+    ref = _engine(model, eos_token=eos)
+    want_a = ref.submit(a_prompt, max_new_tokens=40)
+    _run(ref, [want_a])
+    ref.close()
+    assert len(want_a.tokens) > 12, "the resident row ends too early"
+    engine = _lagging(_engine(model, eos_token=eos))
+    a = _decoding(engine, a_prompt, 40)
+    b = engine.submit(b_prompt, max_new_tokens=30)
+    job = _until_one_chunk_is_left(engine, b)
+    slot_a, table = engine._active.index(a), list(job.table)
+    pages = [np.asarray(engine._payload[i]) for i in engine._pool_at]
+    overrun, acts, drains = _counter(OVERRUN), _activations(), _drains()
+    engine.step()
+    assert list(b.tokens) == [eos] and b.done and b.error is None
+    assert _moved(_activations(), acts) == {"device": 1}
+    assert _drains() == drains
+    assert b not in engine._active
+    assert b in [req for _, req in engine._inflight.rows]   # it rode along
+    held = set(table) | {0} | set(engine._slot_blocks[slot_a])
+    for before, i in zip(pages, engine._pool_at):
+        after = np.asarray(engine._payload[i])
+        changed = {int(p) for p in np.nonzero(
+            (before != after).reshape(before.shape[0], -1).any(axis=1))[0]}
+        assert changed <= held, (changed, held)
+    engine.step()
+    assert _counter(OVERRUN) - overrun == 1
+    _run(engine, [a])
+    assert list(a.tokens) == list(want_a.tokens)
+    assert engine.host_fetches == engine.decode_steps
+    s = engine.stats()
+    assert s.kv_blocks_free + s.kv_blocks_cached == s.kv_blocks_total
+    engine.close()
+
+
+def test_a_request_of_one_token_never_activates_a_slot(models):
+    engine = _lagging(_engine(models("llama")))
+    old = _decoding(engine, _tokens(121, 12, 64), 40)
+    one = engine.submit(_tokens(122, 20, 64), max_new_tokens=1)
+    _until_one_chunk_is_left(engine, one)
+    assert engine._inflight is not None
+    activated = []
+    activate = engine._activate
+    engine._activate = lambda *a: activated.append(a) or activate(*a)
+    acts, drains, seen = _activations(), _drains(), []
+    real = engine._finish_prefill
+    engine._finish_prefill = lambda slot, req, first: (
+        seen.append((engine._active[slot], engine._inflight)),
+        real(slot, req, first))
+    engine.step()
+    assert one.done and len(one.tokens) == 1 and one.error is None
+    assert not activated and one not in engine._active
+    # today's path: the round in flight fetched, then the token waited for
+    assert seen == [(None, None)]
+    assert _moved(_activations(), acts) == {"drained": 1}
+    assert _moved(_drains(), drains) == {"admission": 1}
+    _run(engine, [old])
+    assert len(old.tokens) == 40
+    s = engine.stats()
+    assert s.kv_blocks_free + s.kv_blocks_cached == s.kv_blocks_total
+    engine.close()
+
+
+def test_a_request_cancelled_with_its_first_token_pending_is_reaped(models):
+    """The client goes away between the activation and the first token's
+    fetch (here: while the host does its overlap work). The token was made
+    and is delivered, as a decode row's is; the next turn's reap frees slot
+    and pages, and the round that carried the row drops its token."""
+    model = models("llama")
+    ref = _engine(model)
+    want = ref.submit(_tokens(131, 12, 64), max_new_tokens=40)
+    _run(ref, [want])
+    ref.close()
+    engine = _lagging(_engine(model))
+    old = _decoding(engine, _tokens(131, 12, 64), 40)
+    gone = engine.submit(_tokens(132, 41, 64), max_new_tokens=30)
+    _until_one_chunk_is_left(engine, gone)
+    window = engine._overlap_window
+    engine._overlap_window = lambda: (gone.cancel(), window())
+    overrun, cancelled = _counter(OVERRUN), engine.stats().requests_cancelled
+    engine.step()
+    engine._overlap_window = window
+    assert len(gone.tokens) == 1 and not gone.done
+    assert not engine._first_pending
+    assert gone in [req for _, req in engine._inflight.rows]
+    engine.step()
+    assert gone.done and gone.status == "cancelled"
+    assert len(gone.tokens) == 1            # the token in flight: gone
+    assert gone not in engine._active
+    assert engine.stats().requests_cancelled - cancelled == 1
+    assert _counter(OVERRUN) - overrun == 1
+    _run(engine, [old])
+    assert list(old.tokens) == list(want.tokens)
+    s = engine.stats()
+    assert s.busy == 0
+    assert s.kv_blocks_free + s.kv_blocks_cached == s.kv_blocks_total
+    engine.close()
+
+
+def test_a_proposer_keeps_the_drained_path(models):
+    model = models("llama")
+    want = _reference(model)
+    engine = _lagging(_engine(model, spec_tokens=3))
+    acts = _activations()
+    activated = []
+    activate = engine._activate
+    engine._activate = lambda *a: activated.append(a) or activate(*a)
+    reqs = _submit_mix(engine, 64)
+    _run(engine, reqs)
+    assert [list(r.tokens) for r in reqs] == want
+    assert _moved(_activations(), acts) == {"drained": len(_MIX)}
+    # all but the request of one token entered the round's inputs there
+    assert len(activated) == len(_MIX) - 1
+    engine.close()
+
+
+def test_step_from_another_thread_emits_the_first_token_it_finished(models):
+    """Not the loop's thread: the same path (activated on the device, the
+    next round dispatched first), and everything the turn owes is out when
+    ``step()`` returns, the first token in front of the round's own."""
+    engine = _engine(models("llama"))
+    acts, drains = _activations(), _drains()
+    req = engine.submit(_tokens(141, 41, 64), max_new_tokens=10)
+    job = _until_one_chunk_is_left(engine, req)
+    with trace.recording() as rec:
+        engine.step()
+        recs = rec.drain()
+    assert len(req.tokens) == 2 and req.first_token_at is not None
+    assert engine._inflight is None and not engine._first_pending
+    assert engine.host_fetches == engine.decode_steps == 1
+    first, = [r for r in recs if r.name == trace.ENGINE_PREFILL_FENCE]
+    fence, = [r for r in recs if r.name == trace.ENGINE_DECODE_FENCE]
+    dispatch, = [r for r in recs if r.name == trace.ENGINE_DECODE_DISPATCH]
+    assert dispatch.end <= first.start and first.end <= fence.start
+    assert _moved(_activations(), acts) == {"device": 1}
+    assert _drains() == drains
+    _run(engine, [req])
+    assert len(req.tokens) == 10
     engine.close()
