@@ -9,8 +9,9 @@ the configuration object and by the module class it builds;
 ``models/jamba.py``'s ``JambaConfig`` / ``Jamba``, ``models/zaya.py``'s
 ``ZayaConfig`` / ``Zaya``, ``models/minicpm_sala.py``'s
 ``MiniCPMSalaConfig`` / ``MiniCPMSala``, ``models/brumby.py``'s
-``BrumbyConfig`` / ``Brumby`` and ``models/ouro.py``'s ``OuroConfig`` /
-``Ouro`` all do: ten families.
+``BrumbyConfig`` / ``Brumby``, ``models/ouro.py``'s ``OuroConfig`` /
+``Ouro`` and ``models/dots3_note.py``'s ``Dots3NoteConfig`` / ``Dots3Note``
+all do: eleven families.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -67,9 +68,15 @@ answers more, and the engine asks them of no other: ``kv_window``, the
 positions such a leaf keeps readable behind a row's newest (a query at
 ``p`` reads ``p - kv_window < j <= p`` there and nothing older, ever);
 ``window_layers``, the layers that keep one (``kv_layers`` counts the layers
-of ``paged`` leaves only, and ``kv_token_bytes`` is a layer's cost of either
-kind); and ``paged_model`` / ``check_kernels`` take ``window_pages=`` /
-``window_blocks=``, the second pool's size. Its module's ``__call__`` takes
+of ``paged`` leaves only); and ``paged_model`` / ``check_kernels`` take
+``window_pages=`` / ``window_blocks=``, the second pool's size. **The window
+kind has its own price**: ``window_token_bytes(kv_quant)``, optional, the
+bytes one cached token costs one ``window`` layer; absent, it is
+``kv_token_bytes`` (``models/cohere2_moe.py``: keys and values of the same
+heads in both kinds). ``models/dots3_note.py`` answers both: its window
+layers cache a latent vector of their own rank, wider than the full layers'
+(2,304 bytes against 1,536), and ``serving/kv_cache.py`` ``divide_pool``
+charges each kind its own. Its module's ``__call__`` takes
 ``window_table=`` beside ``page_table=``: the same shape, addressing the
 ``window`` leaves.
 
@@ -93,7 +100,10 @@ answer in a ``state`` leaf, which is all a decode round is told).
   addressed through a page table; what follows the page axis is the
   model's (``[KV, D]`` keys or values, a latent vector, ``[KV, page, D]``
   with the page axis second and ``[page / 16, KV, D]`` compressed keys
-  beside them under the same table: ``models/minicpm_sala.py``; **a block
+  beside them under the same table: ``models/minicpm_sala.py``; **two
+  leaves of different widths under one table**, a latent vector of 640
+  lanes and an indexer's key of 128, a layer: ``models/dots3_note.py``,
+  whose ``kv_token_bytes`` is the two together; **a block
   that holds several passes**, ``[pages, T, page, KV, D]``, which the
   model reads inside pass ``t`` as ``[pages x T, page, KV, D]`` through
   ``page_table x T + t``: ``models/ouro.py``, where ``kv_layers`` counts the
@@ -105,8 +115,9 @@ answer in a ``state`` leaf, which is all a decode round is told).
   Block 0 is the scratch block no row owns, so a row whose table starts
   with the scratch block has no real position: the decode read
   (``ops/paged_attention.py``) gives it 0 and reads nothing for it.
-- ``window``: a pool of pages like ``paged``, with a second lifetime: a
-  token's entry stops being read ``kv_window`` positions behind the row's
+- ``window``: a pool of pages like ``paged`` (keys and values a head, or
+  **a latent vector**: ``models/dots3_note.py``'s ``wlatent``), with a
+  second lifetime: a token's entry stops being read ``kv_window`` positions behind the row's
   newest, so the engine returns a page that lies wholly behind the window
   of everything it has dispatched (a round in flight, the slot's prefill
   job) to an allocator of its own (``serving/kv_cache.py``

@@ -681,9 +681,13 @@ def divide_pool(pool_bytes: int, base, most_window: int,
     their most (``most_window``, ``most_paged`` blocks: a row never
     holds more, and with the prefix cache off nothing else would), if
     the budget covers both; where it does not, each gets its share of
-    the budget in proportion to that."""
+    the budget in proportion to that. Each kind at its own price: a
+    model whose window layers cache another width answers
+    ``window_token_bytes`` (``models/serving.py``); absent, both cost
+    ``kv_token_bytes``."""
     token = base.kv_token_bytes(None)
-    per_window = page_size * base.window_layers * token
+    per_window = page_size * base.window_layers * getattr(
+        base, "window_token_bytes", base.kv_token_bytes)(None)
     want_window = most_window * per_window
     want_paged = most_paged * page_size * base.kv_layers * token
     if want_window + want_paged <= pool_bytes:

@@ -1,0 +1,321 @@
+"""``ops/latent_select.py``: the indexer's scores, the exact choice, the read
+of the chosen tokens and the read under a window, each against its ``lax``
+oracle or a float32 computation by hand; idle rows, rows that do not select,
+padded positions; the lowering for a TPU at the published widths. Tiny
+shapes, CPU, Pallas kernels interpreted (``tests/conftest.py``)."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from lzy_tpu.ops import latent_select as ls
+from lzy_tpu.ops import mla
+
+PAGE = 8
+
+
+def _pool(rng, blocks, width, dtype="float32"):
+    return jnp.asarray(rng.normal(size=(blocks, PAGE, width)), dtype)
+
+
+def _table(rng, rows, pages, blocks):
+    return jnp.asarray(np.stack([
+        rng.choice(np.arange(1, blocks), pages, False)
+        for _ in range(rows)]).astype(np.int32))
+
+
+# -- the index ------------------------------------------------------------------
+
+def _index_by_hand(q, w, pool, table, start):
+    keys = np.asarray(pool, np.float64)[np.asarray(table)]
+    keys = keys.reshape(len(start), -1, keys.shape[-1])
+    s = np.einsum("bjtd,bld->bjtl", np.asarray(q, np.float64), keys)
+    return np.einsum("bjtl,btj->btl", np.maximum(s, 0.0),
+                     np.asarray(w, np.float64))
+
+
+@pytest.mark.parametrize("kernel", ["lax", "pallas"])
+@pytest.mark.parametrize("t", [1, 16])
+def test_index_scores_are_the_sum_over_heads_by_hand(kernel, t):
+    """A decode round (a row that selects, one past it, a row under
+    ``topk``, an idle slot) and a chunk of 16 that starts past ``topk``:
+    every visible position of a selecting query scores ``sum_j w_j relu(q_j
+    . k)``."""
+    rng = np.random.default_rng(0)
+    heads, d, topk, pages = 3, 16, 8, 12
+    pool = _pool(rng, 40, d)
+    starts = [20, 70, 5, -1] if t == 1 else [33]
+    table = _table(rng, len(starts), pages, 40)
+    q = jnp.asarray(rng.normal(size=(len(starts), heads, t, d)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(len(starts), t, heads)), jnp.float32)
+    start = jnp.asarray(starts, jnp.int32)
+    got = np.asarray(ls.index_scores(q, w, pool, table, start, topk=topk,
+                                     kernel=kernel))
+    want = _index_by_hand(q, w, pool, table, starts)
+    assert got.shape == (len(starts), t, pages * PAGE)
+    for r, first in enumerate(starts):
+        for i in range(t):
+            p = first + i
+            if first < 0 or (kernel == "pallas" and first + t - 1 < topk):
+                continue       # an idle row; a tile the kernel skips
+            assert np.abs(got[r, i, :p + 1] - want[r, i, :p + 1]).max() \
+                < 1e-4, (r, i)
+            if kernel == "lax":
+                assert (got[r, i, p + 1:] == -1e30).all()
+
+
+def test_the_index_kernels_are_their_oracle_in_bfloat16():
+    """Products in the pool's dtype, sums in float32: kernel and oracle read
+    the same rounded operands, so they differ by the order of their sums."""
+    rng = np.random.default_rng(1)
+    pool = _pool(rng, 30, 16, "bfloat16")
+    table = _table(rng, 2, 10, 30)
+    for t, starts in ((1, [40, 61]), (8, [30, 50])):
+        q = jnp.asarray(rng.normal(size=(2, 4, t, 16)), jnp.bfloat16)
+        w = jnp.asarray(rng.normal(size=(2, t, 4)), jnp.float32)
+        start = jnp.asarray(starts, jnp.int32)
+        a = np.asarray(ls.index_scores(q, w, pool, table, start, topk=8,
+                                       kernel="pallas"))
+        b = np.asarray(ls.index_scores(q, w, pool, table, start, topk=8,
+                                       kernel="lax"))
+        for r, first in enumerate(starts):
+            for i in range(t):
+                n = first + i + 1
+                assert np.abs(a[r, i, :n] - b[r, i, :n]).max() < 2e-3
+
+
+def test_an_unknown_kernel_is_refused_by_name():
+    z = jnp.zeros
+    with pytest.raises(ValueError, match="mosaic"):
+        ls.index_scores(z((1, 1, 1, 8)), z((1, 1, 1)), z((2, PAGE, 8)),
+                        z((1, 2), jnp.int32), z((1,), jnp.int32), topk=4,
+                        kernel="mosaic")
+
+
+# -- the choice -----------------------------------------------------------------
+
+def _sorted_choice(scores, pos, k):
+    """By a sort: score descending, position ascending among equals."""
+    seen = scores[:pos + 1]
+    order = np.lexsort((np.arange(pos + 1), -seen))
+    return set(order[:k].tolist())
+
+
+@pytest.mark.parametrize("width,k", [(300, 8), (1000, 128), (640, 64),
+                                     (130, 128), (512, 1), (2304, 256)])
+def test_the_choice_is_a_sort_ties_to_the_lower_position(width, k):
+    """Scores rounded to quarters (many ties, both zeros among them), a row
+    of one value, queries past ``k``, at ``k``, under it and not real."""
+    rng = np.random.default_rng(width)
+    scores = np.round(rng.normal(size=(2, 5, width)) * 4).astype(
+        np.float32) / 4
+    scores[0, 0, :50] = -0.0
+    scores[0, 1, :] = 1.0
+    # what lies past a query's position is never read, whatever it holds
+    scores[1, 0, width // 2 + 1:] = np.nan
+    pos = np.array([[width - 1, width - 2, k, k - 1, -1],
+                    [width // 2, k + 1, 3, 0, width - 1]], np.int32)
+    idx, n = ls.latent_topk(jnp.asarray(scores), jnp.asarray(pos), k)
+    idx, n = np.asarray(idx), np.asarray(n)
+    assert idx.shape == (2, 5, k) and idx.dtype == np.int32
+    for b in range(2):
+        for t in range(5):
+            p = pos[b, t]
+            assert n[b, t] == max(0, min(p + 1, k))
+            if p < 0:
+                continue
+            got = idx[b, t, :n[b, t]].tolist()
+            assert len(set(got)) == len(got)
+            assert set(got) == _sorted_choice(scores[b, t], p, k), (b, t)
+
+
+@pytest.mark.parametrize("reach", [40, 100, 200, 500])
+def test_the_sort_runs_over_the_narrowest_width_that_holds_the_program(
+        reach, monkeypatch):
+    """``jax.lax.top_k`` is handed the narrowest of ``_TOPK_WIDTHS`` that
+    holds every position the program's queries see (one branch of a
+    switch, picked by the furthest query): the same choice at every reach,
+    an idle row beside the live ones."""
+    import jax
+
+    monkeypatch.setattr(ls, "_TOPK_WIDTHS", (64, 128, 256))
+    rng = np.random.default_rng(reach)
+    width, k = 600, 16
+    scores = np.round(rng.normal(size=(3, 4, width)) * 4).astype(
+        np.float32) / 4
+    pos = np.array([[reach - 1, reach - 3, k, -1]] * 3, np.int32)
+    pos[1] = -1
+    idx, n = jax.jit(lambda s, p: ls.latent_topk(s, p, k))(
+        jnp.asarray(scores), jnp.asarray(pos))
+    idx, n = np.asarray(idx), np.asarray(n)
+    for b in range(3):
+        for t in range(4):
+            p = pos[b, t]
+            assert n[b, t] == max(0, min(p + 1, k))
+            if p >= 0:
+                assert set(idx[b, t, :n[b, t]].tolist()) \
+                    == _sorted_choice(scores[b, t], p, k), (b, t)
+
+
+def test_a_choice_wider_than_the_scores_is_refused():
+    with pytest.raises(ValueError, match="16 among 8"):
+        ls.latent_topk(jnp.zeros((1, 1, 8)), jnp.zeros((1, 1), jnp.int32),
+                       16)
+
+
+# -- the read of the chosen -----------------------------------------------------
+
+def _attention_by_hand(q, vectors, value_dim, scale):
+    """``q`` [H, W] over ``vectors`` [S, W] in float64."""
+    s = np.asarray(q, np.float64) @ np.asarray(vectors, np.float64).T * scale
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return p @ np.asarray(vectors, np.float64)[:, :value_dim]
+
+
+@pytest.mark.parametrize("kernel", ["lax", "pallas"])
+@pytest.mark.parametrize("t", [1, 16])
+def test_the_chosen_read_is_attention_over_the_chosen_alone(kernel, t):
+    rng = np.random.default_rng(3)
+    h, w, r, k, pages = 4, 128, 96, 8, 12
+    pool = _pool(rng, 40, w)
+    starts = [20, 70, 5] if t == 1 else [33]
+    b = len(starts)
+    table = _table(rng, b, pages, 40)
+    q = jnp.asarray(rng.normal(size=(b, t, h, w)), jnp.float32)
+    pos = np.asarray(starts)[:, None] + np.arange(t)
+    scores = jnp.asarray(rng.normal(size=(b, t, pages * PAGE)), jnp.float32)
+    idx, n = ls.latent_topk(scores, jnp.asarray(pos, jnp.int32), k)
+    got = np.asarray(ls.latent_chosen_attention(
+        q, pool, table, idx, n, value_dim=r, scale=0.1, kernel=kernel))
+    flat = np.asarray(pool)[np.asarray(table)].reshape(b, -1, w)
+    for row in range(b):
+        for i in range(t):
+            chosen = np.asarray(idx)[row, i, :int(n[row, i])]
+            want = _attention_by_hand(q[row, i], flat[row, chosen], r, 0.1)
+            assert np.abs(got[row, i] - want).max() < 1e-5, (row, i)
+
+
+@pytest.mark.parametrize("kernel", ["lax", "pallas"])
+def test_a_row_under_topk_reads_everything_as_the_unselected_read(kernel):
+    """Positions 3 and 7 of a choice of 8: every visible position is chosen,
+    and the result is ``ops/mla.py``'s full read of the same pool."""
+    rng = np.random.default_rng(4)
+    h, w, r = 4, 128, 96
+    pool = _pool(rng, 20, w)
+    table = _table(rng, 2, 6, 20)
+    q = jnp.asarray(rng.normal(size=(2, 1, h, w)), jnp.float32)
+    start = jnp.asarray([3, 7], jnp.int32)
+    # garbage scores: a row that does not select never reads them
+    scores = jnp.full((2, 1, 6 * PAGE), jnp.nan, jnp.float32)
+    idx, n = ls.latent_topk(scores, start[:, None], 8)
+    assert list(np.asarray(n)[:, 0]) == [4, 8]
+    got = ls.latent_chosen_attention(q, pool, table, idx, n, value_dim=r,
+                                     scale=0.1, kernel=kernel)
+    want = mla.mla_attention(q, pool, table, start, value_dim=r, scale=0.1,
+                             kernel="lax")
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+
+
+@pytest.mark.parametrize("kernel", ["lax", "pallas"])
+def test_an_idle_slot_and_a_padded_position_read_nothing_and_give_zero(
+        kernel):
+    rng = np.random.default_rng(5)
+    pool = _pool(rng, 20, 128)
+    table = _table(rng, 2, 6, 20)
+    q = jnp.asarray(rng.normal(size=(2, 4, 4, 128)), jnp.float32)
+    # row 0: two real positions of four; row 1: idle
+    pos = jnp.asarray([[30, 31, -1, -1], [-1, -1, -1, -1]], jnp.int32)
+    scores = jnp.asarray(rng.normal(size=(2, 4, 6 * PAGE)), jnp.float32)
+    idx, n = ls.latent_topk(scores, pos, 8)
+    assert np.asarray(n).tolist() == [[8, 8, 0, 0], [0, 0, 0, 0]]
+    got = np.asarray(ls.latent_chosen_attention(
+        q, pool, table, idx, n, value_dim=96, scale=0.1, kernel=kernel))
+    assert np.abs(got[0, :2]).max() > 0
+    assert (got[0, 2:] == 0).all() and (got[1] == 0).all()
+
+
+# -- the read under a window ----------------------------------------------------
+
+@pytest.mark.parametrize("t", [1, 16])
+@pytest.mark.parametrize("window", [5, 24])
+def test_the_window_read_sees_the_window_and_no_page_behind_it(t, window):
+    """Rows at the sequence's start, across the window's edge and far past
+    it, an idle one; the table reads scratch behind the window, as the
+    engine leaves it, and block 0 holds NaN: a read that touched a page
+    behind the window would show it."""
+    rng = np.random.default_rng(6)
+    h, w, r, pages = 2, 128, 96, 16
+    pool = np.array(_pool(rng, 40, w))
+    pool[0] = np.nan
+    starts = [2, window - 1, 90, -1] if t == 1 else [0, 61]
+    b = len(starts)
+    table = np.zeros((b, pages), np.int32)
+    for row, first in enumerate(starts):
+        if first < 0:
+            continue
+        lo = max(0, first - window + 1) // PAGE
+        hi = (first + t - 1) // PAGE + 1
+        table[row, lo:hi] = rng.choice(np.arange(1, 40), hi - lo, False)
+    q = jnp.asarray(rng.normal(size=(b, t, h, w)), jnp.float32)
+    got = np.asarray(ls.latent_window_attention(
+        q, jnp.asarray(pool), jnp.asarray(table),
+        jnp.asarray(starts, jnp.int32), window=window, value_dim=r,
+        scale=0.1))
+    flat = pool[table].reshape(b, -1, w)
+    for row, first in enumerate(starts):
+        if first < 0:
+            assert (got[row] == 0).all()
+            continue
+        for i in range(t):
+            p = first + i
+            seen = np.arange(max(0, p - window + 1), p + 1)
+            want = _attention_by_hand(q[row, i], flat[row, seen], r, 0.1)
+            assert np.abs(got[row, i] - want).max() < 1e-5, (row, i)
+
+
+def test_the_uncached_forms_are_the_cached_ones():
+    """``causal_latent_attention`` over a chunk of its own is the window
+    read and the chosen read of a pool that holds the same vectors."""
+    rng = np.random.default_rng(7)
+    h, w, r, t, k = 2, 128, 96, 24, 8
+    lat = jnp.asarray(rng.normal(size=(1, t, w)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(1, t, h, w)), jnp.float32)
+    pool = jnp.concatenate([jnp.zeros((1, PAGE, w)),
+                            lat.reshape(t // PAGE, PAGE, w)])
+    table = jnp.arange(1, t // PAGE + 1, dtype=jnp.int32)[None]
+    start = jnp.zeros((1,), jnp.int32)
+    a = ls.causal_latent_attention(q, lat, value_dim=r, scale=0.1, window=5)
+    b = ls.latent_window_attention(q, pool, table, start, window=5,
+                                   value_dim=r, scale=0.1)
+    assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-5
+    scores = jnp.asarray(rng.normal(size=(1, t, t)), jnp.float32)
+    a = ls.causal_latent_attention(q, lat, value_dim=r, scale=0.1,
+                                   scores=scores, topk=k)
+    idx, n = ls.latent_topk(scores, jnp.arange(t, dtype=jnp.int32)[None], k)
+    b = ls.latent_chosen_attention(q, pool, table, idx, n, value_dim=r,
+                                   scale=0.1)
+    assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-5
+
+
+# -- labels, and the lowering at the published widths ---------------------------
+
+def test_the_labels_say_which_form_ran():
+    assert ls.index_path(1) == ls.INDEX_DECODE_PATH
+    assert ls.index_path(256) == ls.INDEX_PREFILL_PATH
+    assert ls.chosen_path("pallas", t=1) == ls.CHOSEN_DECODE_PATH
+    assert ls.chosen_path("pallas", t=256) == ls.CHOSEN_PREFILL_PATH
+    assert ls.chosen_path("lax", t=1) == ls.chosen_path("lax", t=256) \
+        == ls.CHOSEN_LAX_PATH
+
+
+@pytest.mark.parametrize("batch,t", [(16, 1), (1, 256)])
+def test_the_kernels_lower_for_a_tpu_at_published_widths(batch, t):
+    """No device and no compile: the index at 64 heads of 128 and the read
+    of 2,048 chosen tokens at 128 heads over 640 lanes, through a table of
+    784 pages of 64 (50,176 positions): the decode round of 16 slots and
+    the prefill chunk of 256."""
+    ls.lower_for_tpu(batch=batch, t=t, heads=128, index_heads=64,
+                     index_dim=128, width=640, value_dim=512, topk=2048,
+                     n_blocks=12545, page_size=64, pages_per_seq=784,
+                     dtype=jnp.bfloat16)

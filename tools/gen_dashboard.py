@@ -65,6 +65,9 @@ def registry_metrics():
     # a model that runs its layers several times a token: real rows of
     # decode rounds and the pass the head read, summed (lzy_loop_*)
     import lzy_tpu.models.ouro  # noqa: F401
+    # the latent indexer's counts (what the selecting layers saw, chose
+    # and read; the latent window's reads)
+    import lzy_tpu.models.dots3_note  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
