@@ -339,14 +339,17 @@ class Dots3NoteConfig:
         reads 1.7 GB of weights outside the routed experts and the experts
         its rows reach whatever its width; the index's and the chosen
         read's arithmetic grow with the rows as the dense products do, and
-        the choice (an exact top-k a query) costs a query the same at any
-        width (PERF.md section 6, PR 62)."""
+        the choice (a threshold and a compaction a query, eight queries a
+        grid step) costs a query the same at any width (PERF.md section 6,
+        PRs 62-63)."""
         return 256
 
     def kernel_paths(self, t: int) -> Tuple[str, ...]:
         """``lzy_kernel_dispatch_total{path}`` labels of a program over
-        ``t`` positions a row, beside the chosen read's own."""
-        return (lsel.index_path(t),) + (
+        ``t`` positions a row, beside the chosen read's own (asked of the
+        paged model's configuration, which knows its kernel)."""
+        return (lsel.index_path(t),
+                lsel.choice_path(self.paged_kernel, t=t)) + (
             (gexp.PATH,) if self.expert_layers else ())
 
     def check_kernels(self, *, slots: int, kv_blocks: Optional[int] = None,
@@ -583,7 +586,8 @@ class LatentAttention(nn.Module):
                         topk=cfg.index_topk, kernel=cfg.paged_kernel)
                 with jax.named_scope("latent_choice"):
                     idx, n = lsel.latent_topk(
-                        scores, jnp.where(real, pos, -1), cfg.index_topk)
+                        scores, jnp.where(real, pos, -1), cfg.index_topk,
+                        kernel=cfg.paged_kernel)
                 # for whoever asks (``mutable=["choices"]``): what every
                 # query chose, and how many of them
                 self.sow("choices", "chosen", (idx, n))

@@ -26,15 +26,23 @@ indexer's key ``k^I`` (``[pages, page, D_I]``). Four steps, each with a
    the scores of positions past a query's own are whatever memory held, and
    :func:`latent_topk` masks by position, not by value.
 2. **the choice** (:func:`latent_topk`): the exact ``k`` largest a query, a
-   tie to the lower position (``jax.lax.top_k``: XLA's, which breaks ties
-   so), as positions; a query that sees ``k`` positions or fewer takes them
-   all. Exact, never ``approx_max_k``: an approximate choice is another
-   function. ``jax.lax.top_k`` costs by the columns it is handed (16.9 ms a
-   chunk's 256 queries and 6.0 ms a decode round's 16 slots over the
-   table's 50,176 on a v5e chip, whatever the context), so it is run over
-   the narrowest of a few widths that holds the program's positions: one
-   path, no other method (a choice by bisection on the scores' bit
-   patterns was tried and taken out: PERF.md section 6, PR 62).
+   tie to the lower position (``jax.lax.top_k``'s contract), as positions; a
+   query that sees ``k`` positions or fewer takes them all. Exact, never
+   ``approx_max_k``: an approximate choice is another function.
+   ``kernel="pallas"``: ``latent_choice_decode`` (a grid step a slot) and
+   ``latent_choice_prefill`` (a grid step eight queries of a batch-1
+   chunk), one body: a row's scores come into VMEM as chunks of 128
+   positions, become int32 keys in float order masked by position, the
+   ``k``-th largest is found by bisection over the key's 32 bits (a compare
+   and a count a bit, over the groups of 1,024 positions the furthest query
+   reaches and no further) and the chosen are compacted in two levels
+   (counts a chunk, their running sum, and for every output place the chunk
+   that holds it and the lane within it): no sort, and a position is
+   ``chunk * 128 + lane`` in int32, never the result of a product. An idle
+   slot and a query under ``k`` cost a scalar compare. ``"lax"`` is
+   ``jax.lax.top_k`` over the whole width (it costs by the columns it is
+   handed: 17 ms a chunk's 256 queries, 6 ms a decode round's 16 slots over
+   50,176 on a v5e chip): the oracle, and what a CPU runs.
 3. **the read of the chosen** (:func:`latent_chosen_attention`): the chosen
    positions' latent vectors are gathered through the page table
    (``block = table[s // page]``, ``s % page``) into ``[queries, k, W]``,
@@ -81,6 +89,9 @@ INDEX_PREFILL_PATH = "latent_index_prefill"
 CHOSEN_DECODE_PATH = "latent_chosen_decode_pallas"
 CHOSEN_PREFILL_PATH = "latent_chosen_prefill_pallas"
 CHOSEN_LAX_PATH = "latent_chosen_lax"
+CHOICE_DECODE_PATH = "latent_choice_decode"
+CHOICE_PREFILL_PATH = "latent_choice_prefill"
+CHOICE_LAX_PATH = "latent_choice_lax"
 
 #: query positions a grid step of the prefill index scores (a head's product
 #: is ``[tile, D_I] x [D_I, block]``: 128 rows fill the matrix unit) and the
@@ -354,31 +365,292 @@ def index_scores(q: jax.Array, w: jax.Array, pool: jax.Array,
 
 # -- 2. the choice --------------------------------------------------------------
 
-#: the widths ``jax.lax.top_k`` is run over, by the positions the program's
-#: queries reach: its cost follows the columns it is handed, not the context
-#: (a chunk of 256 queries: 1.2 / 5.8 / 16.6 ms over 8,192 / 32,768 / 50,176
-#: columns on a v5e chip), so a program picks the narrowest that holds every
-#: position it can see
-_TOPK_WIDTHS = (8192, 16384, 32768)
+#: cached positions a chunk holds (the lanes of a vector register), the
+#: chunks a block of the compaction holds (one matrix-unit tile of chunks),
+#: the query rows a grid step of the prefill kernel bisects side by side
+_CHUNK = 128
+_CHUNK_BLOCK = 128
+_CHOICE_ROWS = 8
+_INT_MIN = -2 ** 31
 
 
-def _top_k_over_context(key, pos, k: int):
-    """``jax.lax.top_k(key, k)``'s positions, run over the narrowest of
-    ``_TOPK_WIDTHS`` (and the whole width) that holds every position the
-    program's queries see: one branch of a ``lax.switch`` runs."""
-    width = key.shape[-1]
-    widths = [w for w in _TOPK_WIDTHS if k <= w < width] + [width]
-    if len(widths) == 1:
-        return lax.top_k(key, k)[1]
-    reach = jnp.max(pos) + 1
-    branch = sum((reach > w).astype(jnp.int32) for w in widths[:-1])
-    return lax.switch(
-        branch,
-        [lambda key, w=w: lax.top_k(key[..., :w], k)[1] for w in widths],
-        key)
+def choice_path(kernel: str, *, t: int) -> str:
+    """The choice's label in a program with ``t`` positions a row."""
+    if kernel != "pallas":
+        return CHOICE_LAX_PATH
+    return CHOICE_DECODE_PATH if t == 1 else CHOICE_PREFILL_PATH
 
 
-def latent_topk(scores: jax.Array, pos: jax.Array, k: int):
+def _ordered_keys(x, seen):
+    """float32 ``x`` as int32 keys whose integer order is the float order
+    (``-0.0`` as ``+0.0``); the least key where ``seen`` is false, and
+    nowhere else."""
+    b = lax.bitcast_convert_type(x, jnp.int32)
+    b = jnp.where(b == _INT_MIN, 0, b)
+    key = b ^ ((b >> 31) & 0x7fffffff)
+    return jnp.where(seen, jnp.maximum(key, _INT_MIN + 1), _INT_MIN)
+
+
+def _splat_sum(x):
+    """The sum of a float32 ``[8, 128]`` in every element of one."""
+    s = jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
+    return jnp.broadcast_to(s, x.shape)
+
+
+def _choice_kernel(pos_ref, x_ref, o_ref, keys_ref, thr_ref, need_ref,
+                   sel_ref, s_ref, hi_ref, acc_ref, *, rows, chunks, k):
+    """One grid step: ``rows`` queries, each a row of ``chunks`` x 128
+    scores. Every step of it is a count or a compare; nothing is sorted.
+
+    1. the scores become ordered int32 keys, masked by position;
+    2. the ``k``-th largest key of each row by bisection over its 32 bits
+       (the rows side by side, over the groups of 1,024 positions that the
+       furthest query reaches);
+    3. a row at a time: what is above the threshold and the lowest
+       positions of what equals it are marked, counted a chunk, summed over
+       the chunks, and every output place finds its chunk (a compare against
+       the running sums) and its lane (a compare against the chunk's
+       running count, fetched by a product with the one-hot of the chunk);
+       the position is ``chunk * 128 + lane`` in int32.
+
+    Every product multiplies 0 / 1 (or -1) by counts of 128 or less and
+    sums in float32: exact under any pass of the matrix unit."""
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    padded = keys_ref.shape[1]
+    n_blocks = padded // _CHUNK_BLOCK
+    kp = o_ref.shape[-1]
+    r0 = pl.program_id(0) * rows
+    places = lax.broadcasted_iota(i32, (1, kp), 1)
+    at = [pos_ref[r0 + g] for g in range(rows)]
+    last = functools.reduce(jnp.maximum, at)
+
+    @pl.when(last < k)
+    def _():
+        for g in range(rows):
+            o_ref[g] = places
+
+    @pl.when(last >= k)
+    def _():
+        sub = lax.broadcasted_iota(i32, (8, _CHUNK), 0)
+        lane = lax.broadcasted_iota(i32, (8, _CHUNK), 1)
+        least = jnp.full((8, _CHUNK), _INT_MIN, i32)
+
+        # 1. keys, whole rows: what the bisection leaves out is still read
+        # by the compaction's blocks
+        def keys_of(i, _):
+            at_i = (i * 8 + sub) * _CHUNK + lane
+            rows8 = pl.ds(pl.multiple_of(i * 8, 8), 8)
+            for g in range(rows):
+                keys_ref[g, rows8, :] = _ordered_keys(
+                    x_ref[g, rows8, :], at_i <= at[g])
+            return 0
+
+        lax.fori_loop(0, chunks // 8, keys_of, 0)
+        if padded > chunks:
+            for g in range(rows):
+                keys_ref[g, chunks:, :] = jnp.full(
+                    (padded - chunks, _CHUNK), _INT_MIN, i32)
+
+        # 2. the threshold: the greatest t with k keys or more at or above it
+        groups = lax.div(last, 8 * _CHUNK) + 1
+
+        def count(test, bounds):
+            """How many keys of each row pass ``test`` against its bound,
+            in every element of an ``[8, 128]`` float32."""
+            def body(i, accs):
+                rows8 = pl.ds(pl.multiple_of(i * 8, 8), 8)
+                return tuple(
+                    acc + jnp.where(test(keys_ref[g, rows8, :], bound), 1, 0)
+                    for g, (acc, bound) in enumerate(zip(accs, bounds)))
+
+            accs = lax.fori_loop(
+                0, groups, body,
+                tuple(jnp.zeros((8, _CHUNK), i32) for _ in range(rows)))
+            return [_splat_sum(acc.astype(f32)) for acc in accs]
+
+        def bit(i, bases):
+            step = jnp.left_shift(jnp.int32(1), 31 - i)
+            tries = [base + step for base in bases]
+            got = count(lambda key, t: key >= t, tries)
+            return tuple(jnp.where(n >= k, t, base)
+                         for n, t, base in zip(got, tries, bases))
+
+        thrs = lax.fori_loop(0, 32, bit, (least,) * rows)
+        above = count(lambda key, t: key > t, thrs)
+        for g in range(rows):
+            thr_ref[g] = thrs[g]
+            need_ref[g] = k - above[g]
+
+        # constants of the compaction: 0 / 1 matrices over a tile
+        ri = lax.broadcasted_iota(i32, (_CHUNK, _CHUNK), 0)
+        ci = lax.broadcasted_iota(i32, (_CHUNK, _CHUNK), 1)
+
+        def one(mask):
+            return jnp.where(mask, 1.0, 0.0).astype(bf16)
+
+        ones = jnp.ones((_CHUNK, _CHUNK), bf16)
+        ones8 = jnp.ones((8, _CHUNK), bf16)
+        before = one(ri < ci)           # [l', l]: l' before l
+        upto_t = one(ci <= ri)          # [l, l']: l' at or before l
+        below = one(ci < ri)            # [m, m']: chunk m' before chunk m
+        places_f = lax.broadcasted_iota(i32, (_CHUNK, kp), 1).astype(f32)
+        nt = (((1,), (1,)), ((), ()))
+
+        def dot(a, b):
+            return jnp.dot(a, b, preferred_element_type=f32)
+
+        def chunks_of(b):
+            """The rows of block ``b`` in a ``[chunks, 128]`` scratch."""
+            return pl.ds(pl.multiple_of(b * _CHUNK_BLOCK, _CHUNK_BLOCK),
+                         _CHUNK_BLOCK)
+
+        def end_of(b):
+            """The chosen up to block ``b``'s end, ``[1, 128]`` alike."""
+            return hi_ref[pl.ds(pl.multiple_of(b * 8, 8), 8), :][:1]
+
+        def wide(x):
+            """``x`` [., 128] alike along its lanes, over the ``kp``
+            output places."""
+            return jnp.concatenate([x] * (kp // _CHUNK), axis=1)
+
+        def row(g, _):
+            reach = pos_ref[r0 + g]
+
+            @pl.when(reach < k)
+            def _():
+                o_ref[g] = places
+
+            @pl.when(reach >= k)
+            def _():
+                live = jnp.minimum(
+                    lax.div(reach, _CHUNK_BLOCK * _CHUNK) + 1, n_blocks)
+                thr = jnp.broadcast_to(thr_ref[g][:1], (_CHUNK, _CHUNK))
+                need = jnp.broadcast_to(need_ref[g][:1], (_CHUNK, _CHUNK))
+                hi_ref[...] = jnp.full(hi_ref.shape, 2.0 ** 30, f32)
+
+                # marks, a block of chunks at a time: above the threshold,
+                # and the first `need` of what equals it, in position order
+                def mark(b, carry):
+                    tied_before, chosen_before = carry
+                    blk = chunks_of(b)
+                    key = keys_ref[g, blk, :]
+                    tied = key == thr
+                    tied_b = one(tied)
+                    in_chunk = dot(tied_b, before)
+                    a_chunk = dot(tied_b, ones)
+                    rank = dot(below, a_chunk.astype(bf16)) + in_chunk \
+                        + tied_before
+                    chosen = (key > thr) | (tied & (rank < need))
+                    chosen_b = one(chosen)
+                    sel_ref[blk, :] = chosen_b.astype(f32)
+                    a_chunk_c = dot(chosen_b, ones).astype(bf16)
+                    s_ref[blk, :] = dot(below, a_chunk_c) + chosen_before
+                    chosen_before = chosen_before + dot(ones, a_chunk_c)
+                    hi_ref[pl.ds(pl.multiple_of(b * 8, 8), 8), :] = \
+                        chosen_before[:8]
+                    return (tied_before + dot(ones, a_chunk.astype(bf16)),
+                            chosen_before)
+
+                zero = jnp.zeros((_CHUNK, _CHUNK), f32)
+                lax.fori_loop(0, live, mark, (zero, zero))
+
+                # every output place against a block's chunks: the first 128
+                # rows the running count at each lane of the place's chunk,
+                # the next eight the chunks of the block up to it, the last
+                # eight the block's chosen before it
+                acc_ref[...] = jnp.zeros_like(acc_ref)
+
+                def place(b, _):
+                    chosen = sel_ref[chunks_of(b), :]
+                    prev = jnp.where(ri == 0, 0.0,
+                                     pltpu.roll(chosen, 1, 0))
+                    steps = lax.dot_general(
+                        upto_t, (chosen - prev).astype(bf16), nt,
+                        preferred_element_type=f32)          # [l, m]
+                    chosen_prev = lax.dot_general(
+                        ones8, prev.astype(bf16), nt,
+                        preferred_element_type=f32)          # [8, m]
+                    lhs = jnp.concatenate(
+                        [steps, jnp.ones((8, _CHUNK), f32), chosen_prev],
+                        axis=0).astype(bf16)
+                    holds = one((wide(s_ref[chunks_of(b), :]) <= places_f)
+                                & (places_f < wide(end_of(b))))
+                    acc_ref[...] += dot(lhs, holds)
+                    return 0
+
+                lax.fori_loop(0, live, place, 0)
+
+                # the block a place falls in and the chosen before it
+                pf = places.astype(f32)
+                block = jnp.zeros((1, kp), f32)
+                before_block = jnp.zeros((1, kp), f32)
+                for b in range(n_blocks):
+                    end = wide(end_of(b))
+                    block += jnp.where(end <= pf, 1.0, 0.0)
+                    before_block = jnp.maximum(
+                        before_block, jnp.where(end <= pf, end, 0.0))
+                chunk = block * _CHUNK_BLOCK \
+                    + acc_ref[_CHUNK:_CHUNK + 1, :] - 1.0
+                nth = pf - before_block - acc_ref[_CHUNK + 8:_CHUNK + 9, :]
+                lane_of = jnp.sum(
+                    jnp.where(acc_ref[:_CHUNK, :] <= nth, 1.0, 0.0),
+                    axis=0, keepdims=True)
+                o_ref[g] = chunk.astype(i32) * _CHUNK + lane_of.astype(i32)
+
+            return 0
+
+        lax.fori_loop(0, rows, row, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _pallas_latent_choice(scores, pos, *, k: int, interpret: bool):
+    """``scores`` [B, T, L] float32 and ``pos`` [B, T] as
+    :func:`latent_topk` takes them: ``[B, T, k]`` int32, a selecting
+    query's chosen positions in position order, ``0 .. k - 1`` for every
+    other query."""
+    b, t, width = scores.shape
+    n = b * t
+    rows = 1 if t == 1 else _CHOICE_ROWS
+    n_pad = -n % rows
+    # whole groups of eight chunks of 128 positions; a padded position lies
+    # past every query's own and is masked with the rest
+    w_pad = -width % (8 * _CHUNK)
+    chunks = (width + w_pad) // _CHUNK
+    padded = -(-chunks // _CHUNK_BLOCK) * _CHUNK_BLOCK
+    kp = -(-k // _CHUNK) * _CHUNK
+    x = jnp.pad(scores.reshape(n, width), ((0, n_pad), (0, w_pad)))
+    at = jnp.pad(pos.astype(jnp.int32).reshape(n), (0, n_pad),
+                 constant_values=-1)
+    kernel = functools.partial(_choice_kernel, rows=rows, chunks=chunks, k=k)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=((n + n_pad) // rows,),
+            in_specs=[pl.BlockSpec((rows, chunks, _CHUNK),
+                                   lambda i, *_: (i, 0, 0))],
+            out_specs=pl.BlockSpec((rows, 1, kp), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((rows, padded, _CHUNK), jnp.int32),    # keys
+                pltpu.VMEM((rows, 8, _CHUNK), jnp.int32),         # threshold
+                pltpu.VMEM((rows, 8, _CHUNK), jnp.float32),       # ties due
+                pltpu.VMEM((padded, _CHUNK), jnp.float32),        # marks
+                pltpu.VMEM((padded, _CHUNK), jnp.float32),        # starts
+                pltpu.VMEM((padded // _CHUNK_BLOCK * 8, _CHUNK),
+                           jnp.float32),                          # block ends
+                pltpu.VMEM((_CHUNK + 16, kp), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((n + n_pad, 1, kp), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret.tpu_params(interpret),
+        name="latent_choice_decode" if t == 1 else "latent_choice_prefill",
+    )(at, x.reshape(n + n_pad, chunks, _CHUNK))
+    return out[:n, 0, :k].reshape(b, t, k)
+
+
+def latent_topk(scores: jax.Array, pos: jax.Array, k: int, *,
+                kernel: str = "lax", interpret: Optional[bool] = None):
     """The exact ``k`` best cached positions a query: ``scores`` [B, T, L]
     float32 (read only at ``s <= pos``), ``pos`` [B, T] each query's own
     position, below 0 for a query that is not real. Returns ``(idx [B, T,
@@ -386,17 +658,29 @@ def latent_topk(scores: jax.Array, pos: jax.Array, k: int):
     chosen positions. A query that sees more than ``k`` positions takes the
     ``k`` of largest score, a tie to the lower position; one that sees ``k``
     or fewer takes them all (``0 .. pos``, whatever ``scores`` holds);
-    one that is not real takes none. The chosen come in order of score
-    (``jax.lax.top_k``'s)."""
+    one that is not real takes none.
+
+    **The chosen are a set**: ``kernel="pallas"`` hands them out in order
+    of position, ``"lax"`` (``jax.lax.top_k`` over the whole width: the
+    oracle, and what a CPU runs) in order of score, and every reader (a
+    softmax over them, a mask) takes either."""
+    if kernel not in ("lax", "pallas"):
+        raise ValueError(
+            f"unknown choice kernel {kernel!r}; known: lax, pallas")
     width = scores.shape[-1]
     if width < k:
         raise ValueError(f"a choice of {k} among {width} cached positions")
+    n = jnp.clip(pos + 1, 0, k).astype(jnp.int32)
+    if kernel == "pallas":
+        return _pallas_latent_choice(
+            scores.astype(jnp.float32), pos, k=int(k),
+            interpret=_interpret.resolve(interpret)), n
     selects = (pos >= k)[..., None]
     seen = jnp.arange(width, dtype=jnp.int32) <= pos[..., None]
     key = jnp.where(seen & selects, scores, -jnp.inf)
-    idx = jnp.where(selects, _top_k_over_context(key, pos, k),
+    idx = jnp.where(selects, lax.top_k(key, k)[1],
                     jnp.arange(k, dtype=jnp.int32))
-    return idx.astype(jnp.int32), jnp.clip(pos + 1, 0, k).astype(jnp.int32)
+    return idx.astype(jnp.int32), n
 
 
 # -- 3. the read of the chosen ---------------------------------------------------
@@ -494,16 +778,17 @@ def lower_for_tpu(*, batch: int, t: int, heads: int, index_heads: int,
                   index_dim: int, width: int, value_dim: int, topk: int,
                   n_blocks: int, page_size: int, pages_per_seq: int,
                   dtype) -> None:
-    """Lower the index kernel and the chosen read for a TPU at these
-    shapes, with no device and no compile, and let the lowering's error
-    out."""
+    """Lower the index kernel, the choice and the chosen read for a TPU at
+    these shapes, with no device and no compile, and let the lowering's
+    error out."""
     sds = jax.ShapeDtypeStruct
 
     def program(qi, w, ik, q, pool, page_table, start):
         scores = _pallas_index_scores(qi, w, ik, page_table, start,
                                       topk=topk, interpret=False)
         pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        idx, n = latent_topk(scores, pos, topk)
+        idx, n = latent_topk(scores, pos, topk, kernel="pallas",
+                             interpret=False)
         return latent_chosen_attention(
             q, pool, page_table, idx, n, value_dim=value_dim, scale=1.0,
             kernel="pallas", interpret=False)

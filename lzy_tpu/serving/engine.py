@@ -2388,7 +2388,7 @@ class PagedInferenceEngine:
         paths = self._dispatch_paths.get(t)
         if paths is None:
             paths = self._dispatch_paths[t] = (
-                self._path_of(t),) + tuple(self.cfg.kernel_paths(t))
+                self._path_of(t),) + tuple(self._model.cfg.kernel_paths(t))
         for path in paths:
             self._dispatches.inc(path=path)
 

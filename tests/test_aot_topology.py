@@ -762,6 +762,28 @@ def test_latent_reads_compile_at_published_widths(batch, t, one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
 
 
+@pytest.mark.parametrize("batch,t", [(16, 1), (1, 256)],
+                         ids=["decode", "chunk256"])
+def test_the_latent_choice_compiles_at_published_widths(batch, t, one_chip):
+    """The exact choice of 2,048 among a table of 50,176 positions (392
+    chunks of 128, four blocks of chunks): Mosaic takes the bisection's
+    counts, the 0 / 1 products and the sublane roll; the whole program is
+    the kernel and no sort."""
+    from lzy_tpu.ops import latent_select as ls
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda scores, pos: ls.latent_topk(
+        scores, pos, 2048, kernel="pallas", interpret=False)).lower(
+        sds((batch, t, 50176), jnp.float32),
+        sds((batch, t), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and " sort(" not in text
+    assert ("latent_choice_decode" if t == 1
+            else "latent_choice_prefill") in text
+
+
 @pytest.mark.parametrize("rows", [32, 256], ids=["decode", "chunk256"])
 def test_gated_experts_compile_at_a_width_of_eleven_lane_tiles(rows,
                                                                one_chip):

@@ -220,6 +220,58 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(
     assert row.held <= 2 and win.live() == row.held
 
 
+@pytest.mark.parametrize("program", ["prefill chunk", "decode round"])
+def test_the_kernels_choose_what_the_sort_chooses(tiny, program):
+    """Both full layers, through ``mutable=["choices"]``: the module with
+    ``paged_kernel="pallas"`` (``latent_choice_prefill`` /
+    ``latent_choice_decode``) chooses the sets it chooses with ``"lax"``
+    (``jax.lax.top_k``): a chunk of 16 whose queries cross ``index_topk``
+    behind 24 cached positions, and a round of three slots (one past
+    ``index_topk``, one idle, one under it)."""
+    cfg, params = tiny
+    pages = cfg.max_seq_len // PAGE
+    rows = 1 if program == "prefill chunk" else 3
+    table = np.zeros((rows, pages), np.int32)
+    table[0, :6] = [5, 2, 7, 1, 9, 3]
+    table[-1, :6] = [4, 6, 8, 10, 11, 12]
+    table = jnp.asarray(table)
+    toks = jnp.asarray([_tokens(5, 24, cfg.vocab_size)] * rows)
+    chosen = {}
+    for kernel in ("lax", "pallas"):
+        model = cfg.paged_model(page_size=PAGE, kv_pages=13, kernel=kernel,
+                                kv_quant=None, window_pages=13)
+        cache = jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(
+                lambda: model.init(
+                    jax.random.PRNGKey(0), jnp.zeros((rows, 1), jnp.int32),
+                    page_table=table, window_table=table))["cache"])
+        _, upd = model.apply(
+            {"params": params, "cache": cache}, toks, page_table=table,
+            window_table=table, mutable=["cache"])
+        if program == "prefill chunk":
+            ids, real = jnp.asarray([_tokens(6, 16, cfg.vocab_size)]), [16]
+            at = [24]
+        else:
+            ids, real, at = jnp.asarray([[7], [8], [9]]), [1, 0, 1], [24, 9, 3]
+        cache = jax.tree_util.tree_map_with_path(
+            lambda p, leaf: jnp.asarray(at, jnp.int32)
+            if p[-1].key == "index" else leaf, upd["cache"])
+        _, out = model.apply(
+            {"params": params, "cache": cache}, ids, page_table=table,
+            window_table=table, valid_len=jnp.asarray(real, jnp.int32),
+            mutable=["cache", "choices"])
+        chosen[kernel] = [
+            [np.asarray(x) for x in out["choices"][layer]["chosen"][0]]
+            for layer in ("layer_0", "layer_1")]
+    for (idx_a, n_a), (idx_b, n_b) in zip(chosen["lax"], chosen["pallas"]):
+        assert (n_a == n_b).all() and n_a.max() == cfg.index_topk
+        for r in range(idx_a.shape[0]):
+            for t in range(idx_a.shape[1]):
+                n = int(n_a[r, t])
+                assert set(idx_a[r, t, :n].tolist()) \
+                    == set(idx_b[r, t, :n].tolist()), (r, t)
+
+
 def test_an_idle_slot_and_a_padded_position_write_only_scratch(tiny):
     """A decode round of three slots, the middle one idle (a zeroed table,
     ``valid_len`` 0), and a chunk padded from 3 to 8: in all three kinds of
@@ -615,7 +667,8 @@ def test_one_fence_a_round_carries_the_counts(tiny, served):
 def test_kernel_paths_are_counted(served):
     text = REGISTRY.exposition()
     for path in (ls.CHOSEN_DECODE_PATH, ls.CHOSEN_PREFILL_PATH,
-                 ls.INDEX_DECODE_PATH, ls.INDEX_PREFILL_PATH, gexp.PATH):
+                 ls.INDEX_DECODE_PATH, ls.INDEX_PREFILL_PATH,
+                 ls.CHOICE_DECODE_PATH, ls.CHOICE_PREFILL_PATH, gexp.PATH):
         assert f'lzy_kernel_dispatch_total{{path="{path}"}}' in text
     assert served["engine"].stats().kernel_path == ls.CHOSEN_DECODE_PATH
 

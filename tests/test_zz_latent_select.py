@@ -101,11 +101,28 @@ def _sorted_choice(scores, pos, k):
     return set(order[:k].tolist())
 
 
+def _assert_the_sorted_choice(scores, pos, k, idx, n, in_order):
+    for b in range(pos.shape[0]):
+        for t in range(pos.shape[1]):
+            p = pos[b, t]
+            assert n[b, t] == max(0, min(p + 1, k))
+            if p < 0:
+                continue
+            got = idx[b, t, :n[b, t]].tolist()
+            assert len(set(got)) == len(got)
+            assert set(got) == _sorted_choice(scores[b, t], p, k), (b, t)
+            if in_order:
+                assert got == sorted(got), (b, t)
+
+
+@pytest.mark.parametrize("kernel", ["lax", "pallas"])
 @pytest.mark.parametrize("width,k", [(300, 8), (1000, 128), (640, 64),
                                      (130, 128), (512, 1), (2304, 256)])
-def test_the_choice_is_a_sort_ties_to_the_lower_position(width, k):
+def test_the_choice_is_a_sort_ties_to_the_lower_position(width, k, kernel):
     """Scores rounded to quarters (many ties, both zeros among them), a row
-    of one value, queries past ``k``, at ``k``, under it and not real."""
+    of one value, queries past ``k``, at ``k``, under it and not real;
+    widths that are not whole chunks of 128. The kernel hands the chosen
+    out in order of position."""
     rng = np.random.default_rng(width)
     scores = np.round(rng.normal(size=(2, 5, width)) * 4).astype(
         np.float32) / 4
@@ -115,46 +132,68 @@ def test_the_choice_is_a_sort_ties_to_the_lower_position(width, k):
     scores[1, 0, width // 2 + 1:] = np.nan
     pos = np.array([[width - 1, width - 2, k, k - 1, -1],
                     [width // 2, k + 1, 3, 0, width - 1]], np.int32)
-    idx, n = ls.latent_topk(jnp.asarray(scores), jnp.asarray(pos), k)
+    idx, n = ls.latent_topk(jnp.asarray(scores), jnp.asarray(pos), k,
+                            kernel=kernel)
     idx, n = np.asarray(idx), np.asarray(n)
     assert idx.shape == (2, 5, k) and idx.dtype == np.int32
-    for b in range(2):
-        for t in range(5):
-            p = pos[b, t]
-            assert n[b, t] == max(0, min(p + 1, k))
-            if p < 0:
-                continue
-            got = idx[b, t, :n[b, t]].tolist()
-            assert len(set(got)) == len(got)
-            assert set(got) == _sorted_choice(scores[b, t], p, k), (b, t)
+    _assert_the_sorted_choice(scores, pos, k, idx, n, kernel == "pallas")
 
 
-@pytest.mark.parametrize("reach", [40, 100, 200, 500])
-def test_the_sort_runs_over_the_narrowest_width_that_holds_the_program(
-        reach, monkeypatch):
-    """``jax.lax.top_k`` is handed the narrowest of ``_TOPK_WIDTHS`` that
-    holds every position the program's queries see (one branch of a
-    switch, picked by the furthest query): the same choice at every reach,
-    an idle row beside the live ones."""
-    import jax
+#: 260 chunks of 128: chunk numbers and running counts past what 8 bits hold
+_WIDE, _WIDE_K = 33280, 2048
 
-    monkeypatch.setattr(ls, "_TOPK_WIDTHS", (64, 128, 256))
+
+@pytest.mark.parametrize("reach", [
+    _WIDE,                # the table's last position
+    20 * 128 + 77,        # ends inside a chunk, inside the first block
+    128 * 128,            # on a chunk's edge, the first block's
+    200 * 128 + 1,        # the first lane of a chunk of the second block
+    256 * 128 + 5])       # past 256 chunks
+def test_the_kernel_chooses_as_a_sort_at_any_reach_of_a_wide_table(reach):
+    """A decode round over a table of more than 256 chunks, ``k`` 2,048: a
+    live row at ``reach``, an idle slot, a row far short of it and a row
+    under ``k``; each row's work stops at its own reach."""
     rng = np.random.default_rng(reach)
-    width, k = 600, 16
-    scores = np.round(rng.normal(size=(3, 4, width)) * 4).astype(
-        np.float32) / 4
-    pos = np.array([[reach - 1, reach - 3, k, -1]] * 3, np.int32)
-    pos[1] = -1
-    idx, n = jax.jit(lambda s, p: ls.latent_topk(s, p, k))(
-        jnp.asarray(scores), jnp.asarray(pos))
-    idx, n = np.asarray(idx), np.asarray(n)
-    for b in range(3):
-        for t in range(4):
-            p = pos[b, t]
-            assert n[b, t] == max(0, min(p + 1, k))
-            if p >= 0:
-                assert set(idx[b, t, :n[b, t]].tolist()) \
-                    == _sorted_choice(scores[b, t], p, k), (b, t)
+    scores = rng.normal(size=(4, 1, _WIDE)).astype(np.float32)
+    scores[:, :, reach:] = np.nan
+    pos = np.array([[reach - 1], [-1], [_WIDE_K + 300], [_WIDE_K - 1]],
+                   np.int32)
+    idx, n = ls.latent_topk(jnp.asarray(scores), jnp.asarray(pos), _WIDE_K,
+                            kernel="pallas")
+    _assert_the_sorted_choice(scores, pos, _WIDE_K, np.asarray(idx),
+                              np.asarray(n), True)
+
+
+@pytest.mark.parametrize("case", ["one value", "ties across a chunk edge"])
+def test_the_kernel_breaks_ties_by_position_across_chunks(case):
+    """A row that is one value throughout takes its first ``k`` positions;
+    a run of the threshold's value that straddles chunk boundaries gives
+    its lowest positions and no other. A chunk of 16 queries (two grid
+    steps of eight) with a padded query and queries short of ``k``."""
+    rng = np.random.default_rng(9)
+    width, k, t = 4096, 300, 16
+    scores = rng.normal(size=(1, t, width)).astype(np.float32)
+    if case == "one value":
+        scores[:] = -2.5
+    else:
+        # 200 above, then 250 equal ones from lane 100 of chunk 5 on
+        scores = np.minimum(scores, 0.5)
+        scores[:, :, 3000:3200] = 3.0
+        scores[:, :, 5 * 128 + 100:5 * 128 + 350] = 1.0
+    pos = (3600 + np.arange(t, dtype=np.int32))[None]
+    pos[0, 3], pos[0, 9], pos[0, 12] = -1, k - 1, k
+    idx, n = ls.latent_topk(jnp.asarray(scores), jnp.asarray(pos), k,
+                            kernel="pallas")
+    _assert_the_sorted_choice(scores, pos, k, np.asarray(idx),
+                              np.asarray(n), True)
+    if case == "one value":
+        assert (np.asarray(idx)[0, 0] == np.arange(k)).all()
+
+
+def test_an_unknown_choice_kernel_is_refused_by_name():
+    with pytest.raises(ValueError, match="mosaic"):
+        ls.latent_topk(jnp.zeros((1, 1, 8)), jnp.zeros((1, 1), jnp.int32),
+                       4, kernel="mosaic")
 
 
 def test_a_choice_wider_than_the_scores_is_refused():
@@ -307,14 +346,18 @@ def test_the_labels_say_which_form_ran():
     assert ls.chosen_path("pallas", t=256) == ls.CHOSEN_PREFILL_PATH
     assert ls.chosen_path("lax", t=1) == ls.chosen_path("lax", t=256) \
         == ls.CHOSEN_LAX_PATH
+    assert ls.choice_path("pallas", t=1) == ls.CHOICE_DECODE_PATH
+    assert ls.choice_path("pallas", t=256) == ls.CHOICE_PREFILL_PATH
+    assert ls.choice_path("lax", t=1) == ls.choice_path("lax", t=256) \
+        == ls.CHOICE_LAX_PATH
 
 
 @pytest.mark.parametrize("batch,t", [(16, 1), (1, 256)])
 def test_the_kernels_lower_for_a_tpu_at_published_widths(batch, t):
-    """No device and no compile: the index at 64 heads of 128 and the read
-    of 2,048 chosen tokens at 128 heads over 640 lanes, through a table of
-    784 pages of 64 (50,176 positions): the decode round of 16 slots and
-    the prefill chunk of 256."""
+    """No device and no compile: the index at 64 heads of 128, the choice
+    of 2,048 among 50,176 and the read of the chosen at 128 heads over 640
+    lanes, through a table of 784 pages of 64 (50,176 positions): the
+    decode round of 16 slots and the prefill chunk of 256."""
     ls.lower_for_tpu(batch=batch, t=t, heads=128, index_heads=64,
                      index_dim=128, width=640, value_dim=512, topk=2048,
                      n_blocks=12545, page_size=64, pages_per_seq=784,

@@ -1,9 +1,13 @@
 """Times ``ops/latent_select.py`` on the chip at the published widths: the
-choice (``jax.lax.top_k`` over the table's width and over a slice of the
-context's) with its compile seconds, the
-index, and the read of the chosen split into its gather and its kernel.
-``chiprun -- python tools/latent_select_bench.py``; writes
-``chiprun_out/latent_select_bench.json``."""
+choice (the kernel against ``jax.lax.top_k`` over the table's width, the
+same sets asserted; the kernel's device time from a trace beside the
+host's clock) with its compile seconds, the index, and the read of
+the chosen split into its gather and its kernel; and holds the five-layer
+paged program at the engine's table of 784 pages to the float32 reference
+(``program``: one sequence of 2,816 tokens, teacher-forced, the check that
+found PR 62's wrong choice).
+``chiprun -- python tools/latent_select_bench.py [topk] [chosen] [index]
+[program]``; writes ``chiprun_out/latent_select_bench.json``."""
 import json
 import os
 import sys
@@ -12,6 +16,7 @@ import time
 sys.path.insert(0, ".")
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from lzy_tpu.ops import latent_select as ls
 from lzy_tpu.ops import mla
@@ -29,6 +34,28 @@ def timeit(f, *a, n=10):
             "first_call_s": first}
 
 
+def device_us(f, *a, n=5):
+    """Device time a call of each operation of ``f``, from a trace of ``n``
+    calls: the host's clock over a kernel of tens of microseconds reads its
+    own dispatch."""
+    import shutil
+    import tempfile
+
+    from benchmark.harness import trace as tr
+
+    jax.block_until_ready(f(*a))
+    where = tempfile.mkdtemp(prefix="latent_select_")
+    tr.start(where)
+    for _ in range(n):
+        out = f(*a)
+    jax.block_until_ready(out)
+    tr.stop()
+    ops = tr.reduce(tr.load(tr.find_xplane(where)))["ops"]
+    shutil.rmtree(where, ignore_errors=True)
+    return {label.split(":", 1)[1]: round(total / calls * 1e6, 1)
+            for label, (total, calls) in ops.items()}
+
+
 page, P, K = 64, 784, 2048
 L, NB = page * P, 12545
 key = jax.random.PRNGKey(0)
@@ -42,7 +69,92 @@ def table(b):
     return 1 + (jnp.arange(b * P, dtype=jnp.int32).reshape(b, P) % (NB - 1))
 
 
-which = sys.argv[1:] or ["topk", "chosen", "index"]
+def program_gaps(kernel, seed=7, length=2816, prompt=2560, chunk=256,
+                 slots=16, pages_per_seq=784, page_size=64):
+    """Mean |logit - reference| of the five-layer paged module over one
+    teacher-forced sequence, in the engine's two programs' shapes: the
+    prefill chunks' positions past 2,048 and the decode rounds'; and what
+    each full layer chose there, ``[positions, k]`` sorted."""
+    from benchmark.models import dots3_note as ref
+
+    with open("benchmark/configs/dots3-note-prev-serve-l5-ep8.json") as f:
+        cfg = ref.program_config(json.load(f))
+    params = ref.init_params(cfg, seed)
+    tokens = np.random.default_rng(seed).integers(
+        0, cfg.vocab_size, (1, length)).astype(np.int32)
+    rows = np.arange(2048, length)
+    exact = np.asarray(ref.reference_logits(params, tokens, rows, cfg))
+    held = -(-length // page_size)
+    module = cfg.paged_model(page_size=page_size, kv_pages=held + 1,
+                             window_pages=held + 1, kv_quant=None,
+                             kernel=kernel)
+    row = np.zeros((pages_per_seq,), np.int32)
+    row[:held] = np.arange(1, held + 1)
+    one = jnp.asarray(row[None])
+    many = jnp.zeros((slots, pages_per_seq), jnp.int32).at[0].set(one[0])
+    pools = {layer: {name: jnp.zeros(leaf.shape, leaf.dtype)
+                     for name, leaf in leaves.items() if name != "index"}
+             for layer, leaves in jax.eval_shape(lambda: module.init(
+                 jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
+                 page_table=one, window_table=one))["cache"].items()}
+
+    @jax.jit
+    def step(params, pools, ids, real, at, table):
+        cache = {layer: dict(leaves, index=at)
+                 for layer, leaves in pools.items()}
+        logits, out = module.apply(
+            {"params": params, "cache": cache}, ids, page_table=table,
+            window_table=table, valid_len=real,
+            mutable=["cache", "choices"])
+        return {layer: {name: leaf for name, leaf in leaves.items()
+                        if name != "index"}
+                for layer, leaves in out["cache"].items()}, logits[0], [
+            jnp.sort(out["choices"][f"layer_{i}"]["chosen"][0][0][0], axis=-1)
+            for i, kind in enumerate(cfg.layer_types)
+            if kind == "full_attention"]
+
+    got, chose = [], []
+    for at in range(0, prompt, chunk):
+        pools, logits, chosen = step(
+            params, pools, jnp.asarray(tokens[:, at:at + chunk]),
+            jnp.asarray([chunk], jnp.int32), jnp.asarray([at], jnp.int32),
+            one)
+        if at >= 2048:
+            got.append(np.asarray(logits, np.float32))
+            chose.append([np.asarray(c) for c in chosen])
+    live = jnp.zeros((slots,), jnp.int32).at[0].set(1)
+    for at in range(prompt, length):
+        pools, logits, chosen = step(
+            params, pools, jnp.zeros((slots, 1), jnp.int32).at[0, 0].set(
+                int(tokens[0, at])), live, live * at, many)
+        got.append(np.asarray(logits, np.float32))
+        chose.append([np.asarray(c) for c in chosen])
+    gap = np.abs(np.concatenate(got) - exact).mean(axis=-1)
+    return {"prefill_past_2048": float(gap[:prompt - 2048].mean()),
+            "decode": float(gap[prompt - 2048:].mean())}, [
+        np.concatenate(layer) for layer in zip(*chose)]
+
+
+which = sys.argv[1:] or ["topk", "chosen", "index", "program"]
+if "program" in which:
+    chose = {}
+    for kernel in ("pallas", "lax"):
+        res[f"program_{kernel}"], chose[kernel] = program_gaps(kernel)
+        print(json.dumps(res), flush=True)
+    # the first full layer reads the embedding's rows in both programs, so
+    # inside the five-layer program the kernel must choose jax.lax.top_k's
+    # sets to the position; the second reads what the first's read left,
+    # whose sums the order of the chosen moves by a rounding
+    differ = [float(np.mean([np.isin(q, r, invert=True).mean()
+                             for q, r in zip(a, b)]))
+              for a, b in zip(chose["pallas"], chose["lax"])]
+    res["program_chosen_not_the_sorts_by_layer"] = differ
+    print(json.dumps(res), flush=True)
+    assert differ[0] == 0.0, differ
+    # PR 62's wrong choice stood 1.257 / 0.30 from the reference where
+    # jax.lax.top_k stood 0.0175 / 0.0153 (another sequence and seed)
+    for phase, gap in res["program_lax"].items():
+        assert res["program_pallas"][phase] < 1.25 * gap < 0.15, res
 for ctx in (8191, 32767, 49151):
     for b, t in ((16, 1), (1, 256)):
         tb = table(b)
@@ -60,16 +172,21 @@ for ctx in (8191, 32767, 49151):
         sc = f(q, w, ik, tb, st)
         pos = jnp.where((st >= 0)[:, None], st[:, None] + jnp.arange(t), -1)
         if "topk" in which:
-            g = jax.jit(lambda sc, pos: ls.latent_topk(sc, pos, K))
-            res[f"topk_{tag}"] = timeit(g, sc, pos, n=5)
-            width = -(-(ctx + 1) // 8192) * 8192
-            if width < L:
-                g = jax.jit(lambda sc, pos: jax.lax.top_k(
-                    jnp.where(jnp.arange(width) <= pos[..., None],
-                              sc[..., :width], -jnp.inf), K)[1])
-                res[f"topk_sliced{width}_{tag}"] = timeit(g, sc, pos, n=5)
+            sets = {}
+            for kernel in ("pallas", "lax"):
+                g = jax.jit(lambda sc, pos, kernel=kernel: ls.latent_topk(
+                    sc, pos, K, kernel=kernel))
+                res[f"topk_{kernel}_{tag}"] = timeit(g, sc, pos, n=5)
+                sets[kernel] = np.sort(np.asarray(g(sc, pos)[0]), axis=-1)
+                if kernel == "pallas":
+                    res[f"topk_pallas_device_us_{tag}"] = device_us(g, sc, pos)
+            assert (sets["pallas"] == sets["lax"]).all(), tag
+            # the kernel hands the positions out in order
+            assert (sets["pallas"] == np.asarray(ls.latent_topk(
+                sc, pos, K, kernel="pallas")[0])).all(), tag
+            res[f"topk_same_sets_{tag}"] = True
         if "chosen" in which and ctx == 32767:
-            idx, n = ls.latent_topk(sc, pos, K)
+            idx, n = ls.latent_topk(sc, pos, K, kernel="pallas")
             qf = jax.random.normal(key, (b, t, 128, 640), bf)
             h = jax.jit(lambda qf, lat, tb, idx, n: ls.latent_chosen_attention(
                 qf, lat, tb, idx, n, value_dim=512, scale=0.07,
