@@ -348,8 +348,10 @@ class Dots3NoteConfig:
         """``lzy_kernel_dispatch_total{path}`` labels of a program over
         ``t`` positions a row, beside the chosen read's own (asked of the
         paged model's configuration, which knows its kernel)."""
+        gather = lsel.gather_path(self.paged_kernel, t=t)
         return (lsel.index_path(t),
                 lsel.choice_path(self.paged_kernel, t=t)) + (
+            (gather,) if gather else ()) + (
             (gexp.PATH,) if self.expert_layers else ())
 
     def check_kernels(self, *, slots: int, kv_blocks: Optional[int] = None,

@@ -44,20 +44,48 @@ indexer's key ``k^I`` (``[pages, page, D_I]``). Four steps, each with a
    handed: 17 ms a chunk's 256 queries, 6 ms a decode round's 16 slots over
    50,176 on a v5e chip): the oracle, and what a CPU runs.
 3. **the read of the chosen** (:func:`latent_chosen_attention`): the chosen
-   positions' latent vectors are gathered through the page table
-   (``block = table[s // page]``, ``s % page``) into ``[queries, k, W]``,
-   each once a query a layer, and read by ``ops/mla.py``'s own kernel
+   positions' latent vectors are copied through the page table (``block =
+   table[s // page]``, ``s % page``) into pseudo-pages of 128 tokens,
+   ``[queries x k / 128, 128, W]``, each once a query a layer
+   (:func:`latent_gather`), and read by ``ops/mla.py``'s own kernel
    (``mla_paged_decode``: every query a row of one position over its own
-   ``k`` tokens, online softmax in float32), not a copy of it. **Why a
-   gather and not a kernel that walks the choices**: a chosen token is 1,280
-   bytes, and a DMA a token is issue-bound (2,048 a query, 524 thousand a
-   256-wide chunk a layer, some 25 ms at the 0.04 us a DMA ``ops/mla.py``
-   measured); **why not the union of a tile's choices**
-   (``ops/sparse_attention.py`` ``sparse_prefill_attention``'s design):
-   with random weights two neighbouring queries share ``k / visible`` of
-   their tokens and no more, so the union of 128 queries' choices is every
-   visible token and the read degenerates to the dense one, 16 times the
-   arithmetic at 32 thousand tokens.
+   ``k`` tokens, online softmax in float32), not a copy of it. **What
+   copies them where.** ``kernel="pallas"``, a program of up to
+   ``mla.MAX_DECODE_TOKENS`` positions a row (decode, the verify window):
+   ``latent_gather_decode``. A query with chosen tokens walks its row's
+   pages up to its furthest chosen position in blocks of 512 positions (a
+   page a DMA, a block in flight while the block before it is picked
+   from); a tile of 128 places against a block is the product of their 0 /
+   1 matrix with the block, summed in float32: a place's vector is 1 x
+   itself plus zeros, so the copy is exact (a ``-0.0`` comes out ``+0.0``).
+   In order of position, as the kernel choice hands them out, a tile meets
+   the one or two blocks its positions lie in; a query with none chosen
+   costs a scalar compare and nothing of it is written; a query under ``k``
+   gets zeros to the end of the last pseudo-page it touches (the read
+   multiplies what lies there by a weight of exactly 0). On a v5e chip, 16
+   slots, us a layer by the live rows and their context (PERF.md section
+   6, PR 64): 2.5 with none live, 36 / 87 / 122 with one at 8 / 32 / 49
+   thousand tokens, 2.1 ns a walked position and 19 us a live query, where
+   XLA's gather is 836 whatever is live (32,768 vectors at 105 GB/s and
+   their addresses). The walk costs by the reaches, XLA's gather by the
+   places, so the program takes the walk while the live queries' reaches,
+   summed, are under ``_GATHER_WALK_RATIO`` positions a place and
+   ``gather_tokens`` past that (16 rows at 32 thousand: 1.36 ms against
+   0.84), by a ``lax.cond`` on what ``idx`` and ``n`` say. **A prefill
+   chunk keeps** ``gather_tokens``: its 256 queries are one row's, and the
+   walk a query reads the row 256 times (8.5 / 21.8 / 30.6 ms at 8 / 32 /
+   49 thousand against 10.8). **Why not a DMA a chosen token**: Mosaic
+   refuses a one-row slice of a bfloat16 ``(page, 640)`` tile ("Slice shape
+   along dimension 1 must be aligned to tiling (8), but is 1": a row is
+   half a packed sublane), and at the 0.04 us a DMA's issue ``ops/mla.py``
+   measured 2,048 of them would be 82 us a live row besides; **why not the
+   union of a tile's choices** (``ops/sparse_attention.py``
+   ``sparse_prefill_attention``'s design): with random weights two
+   neighbouring queries share ``k / visible`` of their tokens and no more,
+   so the union of 128 queries' choices is every visible token and the
+   read degenerates to the dense one, 16 times the arithmetic at 32
+   thousand tokens. ``kernel="lax"`` is ``gather_tokens`` everywhere: the
+   oracle, and what a CPU runs.
 4. **the read under a window** (:func:`latent_window_attention`): the
    absorbed sum over the ``window`` newest positions of a pool whose pages
    behind the window have gone back (``serving/kv_cache.py``
@@ -89,6 +117,7 @@ INDEX_PREFILL_PATH = "latent_index_prefill"
 CHOSEN_DECODE_PATH = "latent_chosen_decode_pallas"
 CHOSEN_PREFILL_PATH = "latent_chosen_prefill_pallas"
 CHOSEN_LAX_PATH = "latent_chosen_lax"
+GATHER_DECODE_PATH = "latent_gather_decode"
 CHOICE_DECODE_PATH = "latent_choice_decode"
 CHOICE_PREFILL_PATH = "latent_choice_prefill"
 CHOICE_LAX_PATH = "latent_choice_lax"
@@ -685,6 +714,24 @@ def latent_topk(scores: jax.Array, pos: jax.Array, k: int, *,
 
 # -- 3. the read of the chosen ---------------------------------------------------
 
+#: cached positions a block of the gather's walk holds
+_GATHER_BLOCK = 512
+#: the walk is taken while the live queries' reaches, summed, stay under
+#: this many cached positions a gathered vector; past it XLA's gather, which
+#: costs by the vectors it is asked for and not by what is live, is the
+#: faster (PERF.md section 6, PR 64: the tool's table)
+_GATHER_WALK_RATIO = 8
+
+
+def gather_path(kernel: str, *, t: int) -> Optional[str]:
+    """The gather's label in a program with ``t`` positions a row: the
+    kernel's, or None where ``gather_tokens`` (plain XLA, no label of its
+    own) copies the chosen."""
+    if kernel == "pallas" and t <= mla.MAX_DECODE_TOKENS:
+        return GATHER_DECODE_PATH
+    return None
+
+
 def gather_tokens(pool, page_table, positions):
     """``pool`` [n_blocks, page, W] at ``positions`` [B, S] of each row,
     through ``page_table`` [B, P]: ``[B, S, W]``."""
@@ -692,6 +739,206 @@ def gather_tokens(pool, page_table, positions):
     blocks = jnp.take_along_axis(page_table, positions // page, axis=1)
     flat = blocks * page + positions % page
     return pool.reshape(-1, pool.shape[-1])[flat]
+
+
+def _gather_decode_kernel(n_ref, reach_ref, lo_ref, hi_ref, pt_ref, idx_ref,
+                          pool_hbm, out_hbm, col_ref, buf, stage, sems,
+                          out_sem, *, queries, t, tiles, pp, page,
+                          pages_per_seq, block_pages, precision):
+    """The one grid step: every query in turn. A query with chosen tokens
+    walks the pages of its row up to its furthest chosen position, a block
+    in flight while the block before it is picked from: a tile of ``pp``
+    output places against a block is a product of their 0 / 1 matrix
+    (place x position) with the block, summed in float32, so a place's
+    vector is 1 x itself plus zeros: a copy. A tile meets the blocks
+    between its lowest and its highest position and no other; a query with
+    none chosen costs a scalar compare."""
+    f32 = jnp.float32
+    cols = block_pages * page
+    dtype = buf.dtype
+    lane = lax.broadcasted_iota(jnp.int32, (pp, cols), 1)
+    sub = lax.broadcasted_iota(jnp.int32, (pp, 1), 0)
+    # a partial block leaves rows of the buffer unwritten: no place picks
+    # them, and 0 x whatever VMEM held must be 0
+    buf[...] = jnp.zeros_like(buf)
+
+    def query(q, _):
+        n = n_ref[q]
+
+        @pl.when(n > 0)
+        def _():
+            used = lax.div(n + pp - 1, pp)
+            # each tile's chosen positions down a column, -1 past ``n``:
+            # those places match no position and stay 0, to the end of the
+            # last pseudo-page the query touches
+            for j in range(tiles):
+                @pl.when(j < used)
+                def _():
+                    across = jnp.broadcast_to(idx_ref[q, j:j + 1, :],
+                                              (pp, pp))
+                    col_ref[j] = jnp.where(
+                        j * pp + sub < n, jnp.transpose(across)[:, :1], -1)
+                    stage[j] = jnp.zeros(stage.shape[1:], dtype)
+
+            n_pages = lax.div(reach_ref[q], page) + 1
+            n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
+
+            def copies(c, slot, op):
+                _page_copies(
+                    pt_ref, pool_hbm, buf.at[slot], sems.at[slot],
+                    base=lax.div(q, t) * pages_per_seq + c * block_pages,
+                    n_pages=n_pages - c * block_pages,
+                    block_pages=block_pages, page=page, op=op)
+
+            copies(0, 0, lambda c: c.start())
+
+            def block(c, _):
+                slot = lax.rem(c, 2)
+
+                @pl.when(c + 1 < n_blocks)
+                def _():
+                    copies(c + 1, 1 - slot, lambda c: c.start())
+
+                copies(c, slot, lambda c: c.wait())
+                base = c * cols
+
+                def tile(j, _):
+                    @pl.when((lo_ref[q * tiles + j] < base + cols)
+                             & (hi_ref[q * tiles + j] >= base))
+                    def _():
+                        picks = jnp.where(col_ref[j] - base == lane,
+                                          1.0, 0.0).astype(dtype)
+                        got = jnp.dot(picks, buf[slot], precision=precision,
+                                      preferred_element_type=f32)
+                        stage[j] = (stage[j].astype(f32) + got).astype(dtype)
+
+                    return 0
+
+                lax.fori_loop(0, used, tile, 0)
+                return 0
+
+            lax.fori_loop(0, n_blocks, block, 0)
+
+            def tiles_out(op):
+                for j in range(tiles):
+                    @pl.when(j < used)
+                    def _():
+                        op(pltpu.make_async_copy(
+                            stage.at[j], out_hbm.at[q * tiles + j],
+                            out_sem.at[0]))
+
+            tiles_out(lambda c: c.start())
+            tiles_out(lambda c: c.wait())
+
+        return 0
+
+    lax.fori_loop(0, queries, query, 0)
+
+
+def _tile_bounds(idx, n):
+    """``lo`` / ``hi`` [B x T, tiles] the lowest and the highest chosen
+    position of each tile of ``pp`` places (an empty tile: ``lo`` above
+    ``hi``) and ``reach`` [B x T] the highest of a query, -1 for none."""
+    b, t, k = idx.shape
+    pp = min(k, _CHOSEN_PAGE)
+    taken = jnp.arange(k, dtype=jnp.int32) < n[..., None]
+    by_tile = (b * t, k // pp, pp)
+    lo = jnp.min(jnp.where(taken, idx, 2 ** 30).reshape(by_tile), axis=-1)
+    hi = jnp.max(jnp.where(taken, idx, -1).reshape(by_tile), axis=-1)
+    return lo, hi, jnp.max(hi, axis=-1)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _pallas_latent_gather(pool, page_table, idx, n, lo, hi, reach, *,
+                          block: int = _GATHER_BLOCK, interpret: bool):
+    """The kernel's call, bounds as :func:`_tile_bounds` gives them."""
+    b, t, k = idx.shape
+    _, page, w = pool.shape
+    pages = page_table.shape[1]
+    pp = min(k, _CHOSEN_PAGE)
+    tiles = k // pp
+    queries = b * t
+    block_pages = _block_pages(pages, page, block)
+    kernel = functools.partial(
+        _gather_decode_kernel, queries=queries, t=t, tiles=tiles, pp=pp,
+        page=page, pages_per_seq=pages, block_pages=block_pages,
+        precision=(lax.Precision.HIGHEST if pool.dtype == jnp.float32
+                   else None))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(1,),
+            in_specs=[
+                pl.BlockSpec((queries, tiles, pp), lambda g, *_: (0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((tiles, pp, 1), jnp.int32),
+                pltpu.VMEM((2, block_pages * page, w), pool.dtype),
+                pltpu.VMEM((tiles, pp, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((1,)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((queries * tiles, pp, w), pool.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 * 1024 * 1024),
+        interpret=_interpret.tpu_params(interpret),
+        name="latent_gather_decode",
+    )(n.astype(jnp.int32).reshape(-1), reach.reshape(-1), lo.reshape(-1),
+      hi.reshape(-1), page_table.astype(jnp.int32).reshape(-1),
+      idx.astype(jnp.int32).reshape(queries, tiles, pp), pool)
+
+
+def latent_gather(pool: jax.Array, page_table: jax.Array, idx: jax.Array,
+                  n: jax.Array, *, kernel: str = "lax",
+                  interpret: Optional[bool] = None) -> jax.Array:
+    """Each query's chosen latent vectors as a pool of their own: ``pool``
+    ``[n_blocks, page, W]`` through ``page_table`` ``[B, P]`` at ``idx``
+    ``[B, T, k]``, the first ``n`` ``[B, T]`` of them: ``[B x T x k / pp,
+    pp, W]`` in pseudo-pages of ``pp = min(k, 128)`` tokens, query ``q``'s
+    place ``i`` at ``[q x k / pp + i // pp, i % pp]``.
+
+    ``kernel="lax"`` (and any program of more than
+    ``mla.MAX_DECODE_TOKENS`` positions a row) is :func:`gather_tokens`:
+    every place of every query, live or not. ``kernel="pallas"`` is
+    ``latent_gather_decode``: a query's first ``n`` places hold
+    ``gather_tokens``'s vectors bit for bit (a ``-0.0`` comes out ``+0.0``:
+    the copy is a sum with zeros), the places from ``n`` to the end of that
+    pseudo-page hold 0, and **everything past it, and every place of a
+    query with ``n`` 0, is whatever memory held**: ``ops/mla.py``'s kernel
+    reads the pages its query sees and no more. The chosen may come in any
+    order; in order of position (the kernel choice's) a tile of places
+    meets the one or two blocks of pages its positions lie in, in order of
+    score (the ``lax`` choice's) every block its query walks, sixteen times
+    the products.
+
+    The walk costs by the live queries' reaches, XLA's gather by the
+    places: the program takes the walk while the reaches, summed, are
+    under ``_GATHER_WALK_RATIO`` cached positions a place
+    (``lax.cond`` on what ``idx`` and ``n`` say, both branches compiled)."""
+    b, t, k = idx.shape
+    pp = min(k, _CHOSEN_PAGE)
+    if k % pp:
+        raise ValueError(f"{k} chosen tokens are not whole pages of {pp}")
+    w = pool.shape[-1]
+
+    def plain(pool, page_table, idx):
+        return gather_tokens(pool, page_table, idx.reshape(b, t * k)) \
+            .reshape(b * t * k // pp, pp, w)
+
+    if gather_path(kernel, t=t) is None:
+        return plain(pool, page_table, idx)
+    lo, hi, reach = _tile_bounds(idx, n)
+
+    def walk(pool, page_table, idx):
+        return _pallas_latent_gather(
+            pool, page_table, idx, n, lo, hi, reach,
+            interpret=_interpret.resolve(interpret))
+
+    return lax.cond(jnp.sum(reach + 1) <= _GATHER_WALK_RATIO * b * t * k,
+                    walk, plain, pool, page_table, idx)
 
 
 def latent_chosen_attention(q: jax.Array, pool: jax.Array,
@@ -703,19 +950,18 @@ def latent_chosen_attention(q: jax.Array, pool: jax.Array,
 
     ``q`` ``[B, T, H, W]`` absorbed queries, ``pool`` ``[n_blocks, page,
     W]``, ``page_table`` ``[B, P]``, ``idx`` / ``n`` as :func:`latent_topk`
-    gives them. The chosen vectors are gathered once a query and handed to
-    ``ops/mla.py`` as a pool of their own: every query a row of one position
-    that sees the first ``n`` of its ``k`` tokens (a query with ``n`` 0 is
-    an idle row there: 0, nothing read). Returns ``[B, T, H, value_dim]``."""
+    gives them. The chosen vectors are copied once a query
+    (:func:`latent_gather`) and handed to ``ops/mla.py`` as a pool of their
+    own: every query a row of one position that sees the first ``n`` of its
+    ``k`` tokens (a query with ``n`` 0 is an idle row there: 0, nothing
+    read). Returns ``[B, T, H, value_dim]``."""
     b, t, h, w = q.shape
     k = idx.shape[-1]
-    pp = min(k, _CHOSEN_PAGE)
-    if k % pp:
-        raise ValueError(f"{k} chosen tokens are not whole pages of {pp}")
-    got = gather_tokens(pool, page_table, idx.reshape(b, t * k))
+    got = latent_gather(pool, page_table, idx, n, kernel=kernel,
+                        interpret=interpret)
     out = mla.mla_attention(
-        q.reshape(b * t, 1, h, w), got.reshape(b * t * k // pp, pp, w),
-        jnp.arange(b * t * k // pp, dtype=jnp.int32).reshape(b * t, k // pp),
+        q.reshape(b * t, 1, h, w), got,
+        jnp.arange(got.shape[0], dtype=jnp.int32).reshape(b * t, -1),
         n.reshape(b * t) - 1, value_dim=value_dim, scale=scale,
         kernel=kernel, interpret=interpret)
     return out.reshape(b, t, h, value_dim)
@@ -778,9 +1024,9 @@ def lower_for_tpu(*, batch: int, t: int, heads: int, index_heads: int,
                   index_dim: int, width: int, value_dim: int, topk: int,
                   n_blocks: int, page_size: int, pages_per_seq: int,
                   dtype) -> None:
-    """Lower the index kernel, the choice and the chosen read for a TPU at
-    these shapes, with no device and no compile, and let the lowering's
-    error out."""
+    """Lower the index kernel, the choice, the gather (a decode program's)
+    and the chosen read for a TPU at these shapes, with no device and no
+    compile, and let the lowering's error out."""
     sds = jax.ShapeDtypeStruct
 
     def program(qi, w, ik, q, pool, page_table, start):

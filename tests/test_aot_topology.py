@@ -784,6 +784,35 @@ def test_the_latent_choice_compiles_at_published_widths(batch, t, one_chip):
             else "latent_choice_prefill") in text
 
 
+@pytest.mark.parametrize("batch,t", [(16, 1), (2, 8), (1, 256)],
+                         ids=["decode", "verify", "chunk256"])
+def test_the_latent_gather_compiles_at_published_widths(batch, t, one_chip):
+    """The copy of the 2,048 chosen latent vectors a query out of a pool of
+    12,545 pages of 64 through a table of 784: Mosaic takes the walk (page
+    DMAs into blocks of 512 positions, the transpose that lays a tile's
+    positions down a column, the 0 / 1 products) for a decode round and a
+    verify window, both branches of the ``lax.cond`` are in the program,
+    and a prefill chunk keeps XLA's gather alone."""
+    from lzy_tpu.ops import latent_select as ls
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda pool, table, idx, n: ls.latent_gather(
+        pool, table, idx, n, kernel="pallas", interpret=False)).lower(
+        sds((12545, 64, 640), jnp.bfloat16), sds((batch, 784), jnp.int32),
+        sds((batch, t, 2048), jnp.int32), sds((batch, t), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    if t > 8:
+        assert "tpu_custom_call" not in text and "conditional" not in text
+        return
+    assert "latent_gather_decode" in text and "conditional(" in text
+    # no copy of the pool on either branch
+    pool_bytes = 12545 * 64 * 640 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
 @pytest.mark.parametrize("rows", [32, 256], ids=["decode", "chunk256"])
 def test_gated_experts_compile_at_a_width_of_eleven_lane_tiles(rows,
                                                                one_chip):

@@ -668,9 +668,24 @@ def test_kernel_paths_are_counted(served):
     text = REGISTRY.exposition()
     for path in (ls.CHOSEN_DECODE_PATH, ls.CHOSEN_PREFILL_PATH,
                  ls.INDEX_DECODE_PATH, ls.INDEX_PREFILL_PATH,
-                 ls.CHOICE_DECODE_PATH, ls.CHOICE_PREFILL_PATH, gexp.PATH):
+                 ls.CHOICE_DECODE_PATH, ls.CHOICE_PREFILL_PATH,
+                 ls.GATHER_DECODE_PATH, gexp.PATH):
         assert f'lzy_kernel_dispatch_total{{path="{path}"}}' in text
     assert served["engine"].stats().kernel_path == ls.CHOSEN_DECODE_PATH
+
+
+@pytest.mark.parametrize("kernel,t,there", [
+    ("pallas", 1, True), ("pallas", 8, True), ("pallas", 256, False),
+    ("lax", 1, False)])
+def test_the_gathers_label_is_a_decode_programs_under_the_kernel(
+        tiny, kernel, t, there):
+    """``latent_gather_decode`` copies the chosen in programs of up to
+    ``mla.MAX_DECODE_TOKENS`` positions a row; a prefill chunk and the
+    ``lax`` form keep ``gather_tokens``, which has no label."""
+    cfg, _ = tiny
+    paths = dataclasses.replace(cfg, paged_kernel=kernel).kernel_paths(t)
+    assert (ls.GATHER_DECODE_PATH in paths) == there
+    assert ls.index_path(t) in paths and gexp.PATH in paths
 
 
 def test_cache_leaves_are_declared_by_kind(served):

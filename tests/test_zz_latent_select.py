@@ -274,6 +274,167 @@ def test_an_idle_slot_and_a_padded_position_read_nothing_and_give_zero(
     assert (got[0, 2:] == 0).all() and (got[1] == 0).all()
 
 
+# -- the gather of the chosen ---------------------------------------------------
+
+def _chosen(rng, at, k, in_order=True):
+    """``idx`` [Q, k] and ``n`` [Q] for queries at positions ``at`` (below
+    0: not real): ``k`` random positions of ``0 .. p``, every position
+    while there are ``k`` or fewer; in order of position or shuffled."""
+    idx = np.tile(np.arange(k, dtype=np.int32), (len(at), 1))
+    for q, p in enumerate(at):
+        if p >= k:
+            idx[q] = np.sort(rng.choice(p + 1, k, replace=False))
+            if not in_order:
+                rng.shuffle(idx[q])
+    return idx, np.clip(np.asarray(at) + 1, 0, k).astype(np.int32)
+
+
+def _assert_the_gather_is_gather_tokens(pool, table, idx, n, t, block=None):
+    """Every live query's first ``n`` places bit for bit, zeros from there
+    to the end of the pseudo-page it touches last."""
+    b, k = table.shape[0], idx.shape[-1]
+    idx = jnp.asarray(idx.reshape(b, t, k))
+    n = jnp.asarray(n.reshape(b, t))
+    want = np.asarray(ls.gather_tokens(
+        pool, table, idx.reshape(b, t * k))).reshape(b * t, k, -1)
+    if block is None:
+        got = ls.latent_gather(pool, table, idx, n, kernel="pallas")
+    else:
+        got = ls._pallas_latent_gather(
+            pool, table, idx, n, *ls._tile_bounds(idx, n), block=block,
+            interpret=True)
+    pp = min(k, 128)
+    assert got.shape == (b * t * k // pp, pp, pool.shape[-1])
+    got = np.asarray(got).reshape(b * t, k, -1)
+    bits = {2: np.uint16, 4: np.uint32}[want.dtype.itemsize]
+    for q, m in enumerate(np.asarray(n).reshape(-1)):
+        assert (got[q, :m].view(bits) == want[q, :m].view(bits)).all(), q
+        assert (got[q, m:-(-m // pp) * pp] == 0).all(), q
+    return got
+
+
+_GATHER_CASES = {
+    # an idle slot between live ones, a query at position 700 of k 256 and
+    # one under k; pages of 8, blocks of 128 positions
+    "idle between live": dict(at=[900, -1, 700, 100, -1, 1023], k=256,
+                              pages=128),
+    "n == k at the table's end": dict(at=[1023, 1023], k=128, pages=128),
+    # a query at position 700 of k 2,048: 701 places, the last pseudo-page
+    # a part of one
+    "n < k": dict(at=[700, 5, 0], k=2048, pages=384),
+    # the chosen of one tile on both sides of a page's edge (8) and of a
+    # block's (128 positions a block here)
+    "across page and block edges": dict(
+        at=[300], k=8, pages=64, block=128,
+        chosen=[[7, 8, 127, 128, 129, 255, 256, 300]]),
+    "16 slots, one live": dict(at=[-1] * 9 + [2000] + [-1] * 6, k=128,
+                               pages=256),
+    "the verify window": dict(at=[500 + i for i in range(8)]
+                              + [-1] * 8 + [90 + i for i in range(5)]
+                              + [-1] * 3, k=128, pages=128, t=8),
+    "in order of score": dict(at=[900, -1, 333], k=128, pages=128,
+                              in_order=False),
+    "bfloat16": dict(at=[900, 40], k=128, pages=128, dtype="bfloat16"),
+    "a block that is not the default": dict(at=[1000, -1, 513], k=128,
+                                            pages=128, block=64),
+}
+
+
+@pytest.mark.parametrize("case", list(_GATHER_CASES))
+def test_the_gather_kernel_copies_what_gather_tokens_gives(case):
+    """``latent_gather_decode`` against ``gather_tokens``: the first ``n``
+    places of every live query bit for bit, in either order of the chosen;
+    a query with none chosen is not written at all."""
+    spec = dict(_GATHER_CASES[case])
+    rng = np.random.default_rng(len(case))
+    t, k, pages = spec.get("t", 1), spec["k"], spec["pages"]
+    at = spec["at"]
+    b = len(at) // t
+    blocks = b * pages + 1
+    pool = np.array(_pool(rng, blocks, 128, spec.get("dtype", "float32")))
+    pool[0] = np.nan                       # the scratch block is never read
+    table = jnp.asarray(1 + rng.permutation(b * pages).reshape(
+        b, pages).astype(np.int32))
+    idx, n = _chosen(rng, at, k, spec.get("in_order", True))
+    if "chosen" in spec:
+        idx = np.asarray(spec["chosen"], np.int32)
+    got = _assert_the_gather_is_gather_tokens(
+        jnp.asarray(pool), table, idx, n, t, spec.get("block"))
+    # what no query chose is not written: the interpreter's memory starts
+    # as NaN, as the chip's may
+    for q, m in enumerate(n):
+        if m == 0:
+            assert np.isnan(got[q].astype(np.float32)).all(), q
+
+
+def test_the_gather_walks_a_table_of_260_chunks():
+    """A table of 33,280 positions (520 pages of 64, blocks of 512): a live
+    row at its end, an idle slot, a row far short of it."""
+    rng = np.random.default_rng(11)
+    pages, k = 520, 2048
+    pool = jnp.asarray(rng.normal(size=(2 * pages + 1, 64, 128)),
+                       jnp.bfloat16)
+    table = jnp.asarray(1 + rng.permutation(3 * pages).reshape(
+        3, pages).astype(np.int32) % (2 * pages))
+    idx, n = _chosen(rng, [33279, -1, 2348], k)
+    _assert_the_gather_is_gather_tokens(pool, table, idx, n, 1)
+
+
+def test_the_gather_takes_xlas_when_the_reaches_are_long(monkeypatch):
+    """The program adapts on what ``idx`` and ``n`` say: past
+    ``_GATHER_WALK_RATIO`` cached positions a gathered place the branch is
+    ``gather_tokens``, which writes every place of every query."""
+    rng = np.random.default_rng(12)
+    pool = _pool(rng, 129, 128)
+    table = jnp.asarray(1 + rng.permutation(128).reshape(1, 128).astype(
+        np.int32))
+    idx, n = _chosen(rng, [1000], 128)
+    idx, n = jnp.asarray(idx.reshape(1, 1, 128)), jnp.asarray([[100]])
+    walked = np.asarray(ls.latent_gather(pool, table, idx, n,
+                                         kernel="pallas"))
+    assert (walked[0, 100:] == 0).all()
+    monkeypatch.setattr(ls, "_GATHER_WALK_RATIO", 0)
+    plain = np.asarray(ls.latent_gather(pool, table, idx, n,
+                                        kernel="pallas"))
+    assert (plain[0, :100] == walked[0, :100]).all()
+    assert np.abs(plain[0, 100:]).min() > 0
+    # and a program wider than the decode kernel's, or the lax form, is
+    # gather_tokens outright
+    assert ls.gather_path("pallas", t=1) == ls.gather_path("pallas", t=8) \
+        == ls.GATHER_DECODE_PATH
+    assert ls.gather_path("pallas", t=16) is None
+    assert ls.gather_path("lax", t=1) is None
+
+
+def test_nothing_unwritten_reaches_the_chosen_read():
+    """The gather's buffer and the pool's scratch block hold NaN (the
+    interpreter's memory starts so; block 0 is poisoned here) and idle
+    slots' tables read scratch: the read of the chosen is finite and the
+    ``lax`` path's, a query at 700 of ``k`` 256 (its last pseudo-page a
+    part of one), one past ``k`` and idle slots between."""
+    rng = np.random.default_rng(13)
+    h, w, r, k, pages = 4, 128, 96, 256, 128
+    pool = np.array(_pool(rng, 2 * pages + 1, w))
+    pool[0] = np.nan
+    table = np.zeros((4, pages), np.int32)
+    table[0] = 1 + rng.permutation(pages)
+    table[2] = 1 + pages + rng.permutation(pages)
+    at = [300, -1, 1000, -1]
+    idx, n = _chosen(rng, at, k)
+    idx, n = jnp.asarray(idx.reshape(4, 1, k)), jnp.asarray(n.reshape(4, 1))
+    q = jnp.asarray(rng.normal(size=(4, 1, h, w)), jnp.float32)
+    got = np.asarray(ls.latent_chosen_attention(
+        q, jnp.asarray(pool), jnp.asarray(table), idx, n, value_dim=r,
+        scale=0.1, kernel="pallas"))
+    assert np.isfinite(got).all()
+    clean = np.where(np.isnan(pool), 0, pool)
+    want = np.asarray(ls.latent_chosen_attention(
+        q, jnp.asarray(clean), jnp.asarray(table), idx, n, value_dim=r,
+        scale=0.1, kernel="lax"))
+    assert np.abs(got - want).max() < 1e-5
+    assert (got[1] == 0).all() and (got[3] == 0).all()
+
+
 # -- the read under a window ----------------------------------------------------
 
 @pytest.mark.parametrize("t", [1, 16])
@@ -350,6 +511,7 @@ def test_the_labels_say_which_form_ran():
     assert ls.choice_path("pallas", t=256) == ls.CHOICE_PREFILL_PATH
     assert ls.choice_path("lax", t=1) == ls.choice_path("lax", t=256) \
         == ls.CHOICE_LAX_PATH
+    assert ls.GATHER_DECODE_PATH == "latent_gather_decode"
 
 
 @pytest.mark.parametrize("batch,t", [(16, 1), (1, 256)])

@@ -2,12 +2,18 @@
 choice (the kernel against ``jax.lax.top_k`` over the table's width, the
 same sets asserted; the kernel's device time from a trace beside the
 host's clock) with its compile seconds, the index, and the read of
-the chosen split into its gather and its kernel; and holds the five-layer
+the chosen split into its gather and its kernel, the gather alone by device
+time (``latent_gather_decode`` against ``gather_tokens`` at 0 / 1 / 3 / 16
+live rows of 16 slots and as the prefill candidate at one row of 256
+queries, the same vectors asserted in every shape); and holds the five-layer
 paged program at the engine's table of 784 pages to the float32 reference
 (``program``: one sequence of 2,816 tokens, teacher-forced, the check that
-found PR 62's wrong choice).
+found PR 62's wrong choice); ``round`` names the operations of that
+program's decode round by device time, each HLO instruction with the
+``op_name`` its metadata holds (a trace labels a fusion by its kind and its
+shape alone).
 ``chiprun -- python tools/latent_select_bench.py [topk] [chosen] [index]
-[program]``; writes ``chiprun_out/latent_select_bench.json``."""
+[program] [round]``; writes ``chiprun_out/latent_select_bench.json``."""
 import json
 import os
 import sys
@@ -34,10 +40,12 @@ def timeit(f, *a, n=10):
             "first_call_s": first}
 
 
-def device_us(f, *a, n=5):
+def device_us(f, *a, n=5, whole=False):
     """Device time a call of each operation of ``f``, from a trace of ``n``
     calls: the host's clock over a kernel of tens of microseconds reads its
-    own dispatch."""
+    own dispatch. With ``whole``, the program's own time a call beside
+    them, under ``program`` (an operation inside a ``conditional`` is
+    counted in it too: the operations do not add up to the program)."""
     import shutil
     import tempfile
 
@@ -50,10 +58,14 @@ def device_us(f, *a, n=5):
         out = f(*a)
     jax.block_until_ready(out)
     tr.stop()
-    ops = tr.reduce(tr.load(tr.find_xplane(where)))["ops"]
+    reduced = tr.reduce(tr.load(tr.find_xplane(where)))
     shutil.rmtree(where, ignore_errors=True)
-    return {label.split(":", 1)[1]: round(total / calls * 1e6, 1)
-            for label, (total, calls) in ops.items()}
+    out = {label.split(":", 1)[1]: round(total / n * 1e6, 1)
+           for label, (total, calls) in reduced["ops"].items()}
+    if whole:
+        out["program"] = round(sum(
+            sum(times) for times in reduced["modules"].values()) / n * 1e6, 1)
+    return out
 
 
 page, P, K = 64, 784, 2048
@@ -135,7 +147,72 @@ def program_gaps(kernel, seed=7, length=2816, prompt=2560, chunk=256,
         np.concatenate(layer) for layer in zip(*chose)]
 
 
+def decode_round_ops(at=16384, rounds=5, least_us=5.0, slots=16,
+                     pages_per_seq=784, page_size=64):
+    """Device time a round of the five-layer decode program's operations at
+    the engine's shapes (16 slots, one live at position ``at``, pools of
+    zeros), an HLO instruction each: ``[us, instruction, the label a trace's
+    breakdown gives it, op_name]``, the longest first, those of ``least_us``
+    or more."""
+    import re
+    import shutil
+    import tempfile
+
+    from benchmark.harness import trace as tr
+    from benchmark.models import dots3_note as ref
+
+    with open("benchmark/configs/dots3-note-prev-serve-l5-ep8.json") as f:
+        cfg = ref.program_config(json.load(f))
+    params = ref.init_params(cfg, 7)
+    held = at // page_size + 1
+    module = cfg.paged_model(page_size=page_size, kv_pages=held + 1,
+                             window_pages=held + 1, kv_quant=None,
+                             kernel="pallas")
+    many = jnp.zeros((slots, pages_per_seq), jnp.int32).at[0, :held].set(
+        jnp.arange(1, held + 1))
+    cache = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((slots, 1), jnp.int32),
+            page_table=many, window_table=many))["cache"])
+    live = jnp.zeros((slots,), jnp.int32).at[0].set(1)
+    cache = {layer: dict(leaves, index=live * at)
+             for layer, leaves in cache.items()}
+
+    @jax.jit
+    def decode_step(params, cache, ids, real, table):
+        logits, out = module.apply(
+            {"params": params, "cache": cache}, ids, page_table=table,
+            window_table=table, valid_len=real, mutable=["cache", "stats"])
+        return jnp.argmax(logits[:, -1], -1)
+
+    args = (params, cache, jnp.zeros((slots, 1), jnp.int32), live, many)
+    text = decode_step.lower(*args).compile().as_text()
+    named = dict(re.findall(
+        r"%([\w.\-]+) = [^\n]*?op_name=\"([^\"]*)\"", text))
+    jax.block_until_ready(decode_step(*args))
+    where = tempfile.mkdtemp(prefix="latent_round_")
+    tr.start(where)
+    for _ in range(rounds):
+        out = decode_step(*args)
+    jax.block_until_ready(out)
+    tr.stop()
+    events = tr.reduce(tr.load(tr.find_xplane(where)))["op_events"]
+    shutil.rmtree(where, ignore_errors=True)
+    took, label = {}, {}
+    for _, ns, hlo in events:
+        name = re.match(r"%?([\w.\-]+)", hlo).group(1)
+        took[name] = took.get(name, 0.0) + ns / rounds / 1e3
+        label[name] = tr.op_label(hlo)
+    return [[round(us, 1), name, label[name], named.get(name, "")]
+            for name, us in sorted(took.items(), key=lambda kv: -kv[1])
+            if us >= least_us]
+
+
 which = sys.argv[1:] or ["topk", "chosen", "index", "program"]
+if "round" in which:
+    res["decode_round_ops_at_16384"] = decode_round_ops()
+    print(json.dumps(res), flush=True)
 if "program" in which:
     chose = {}
     for kernel in ("pallas", "lax"):
@@ -155,7 +232,8 @@ if "program" in which:
     # jax.lax.top_k stood 0.0175 / 0.0153 (another sequence and seed)
     for phase, gap in res["program_lax"].items():
         assert res["program_pallas"][phase] < 1.25 * gap < 0.15, res
-for ctx in (8191, 32767, 49151):
+for ctx in (8191, 32767, 49151) if {"topk", "chosen", "index"} & set(
+        which) else ():
     for b, t in ((16, 1), (1, 256)):
         tb = table(b)
         live = 3 if t == 1 else 1
@@ -192,20 +270,77 @@ for ctx in (8191, 32767, 49151):
                 qf, lat, tb, idx, n, value_dim=512, scale=0.07,
                 kernel="pallas"))
             res[f"chosen_{tag}"] = timeit(h, qf, lat, tb, idx, n, n=5)
-            gat = jax.jit(lambda lat, tb, idx: ls.gather_tokens(
-                lat, tb, idx.reshape(idx.shape[0], -1)))
-            res[f"chosen_gather_{tag}"] = timeit(gat, lat, tb, idx, n=5)
-            got = gat(lat, tb, idx).reshape(b * t * K // 128, 128, 640)
+            got = ls.gather_tokens(lat, tb, idx.reshape(b, -1)).reshape(
+                b * t * K // 128, 128, 640)
             kern = jax.jit(lambda qf, got, n: mla.mla_attention(
                 qf.reshape(b * t, 1, 128, 640), got,
                 jnp.arange(b * t * K // 128, dtype=jnp.int32).reshape(
                     b * t, K // 128), n.reshape(-1) - 1, value_dim=512,
                 scale=0.07, kernel="pallas"))
             res[f"chosen_kernel_{tag}"] = timeit(kern, qf, got, n, n=5)
-            # sorted by position: does the gather like neighbours?
-            res[f"chosen_gather_sorted_{tag}"] = timeit(
-                gat, lat, tb, jnp.sort(idx, axis=-1), n=5)
         print(json.dumps(res), flush=True)
+
+
+def chosen_sets(rng, at):
+    """``idx`` [len(at), K] and ``n`` [len(at)] as the kernel choice hands
+    them out for queries at positions ``at`` (below 0: not real): K random
+    positions of ``0 .. p`` in order, every position while there are K or
+    fewer."""
+    idx = np.tile(np.arange(K, dtype=np.int32), (len(at), 1))
+    for q, p in enumerate(at):
+        if p >= K:
+            idx[q] = np.sort(rng.choice(p + 1, K, replace=False))
+    return idx, np.clip(np.asarray(at) + 1, 0, K).astype(np.int32)
+
+
+def same_vectors(a, b, n):
+    """The first ``n`` places of every query, bit for bit."""
+    a = np.asarray(a).view(np.uint16).reshape(len(n), K, -1)
+    b = np.asarray(b).view(np.uint16).reshape(len(n), K, -1)
+    return all((a[q, :m] == b[q, :m]).all() for q, m in enumerate(n))
+
+
+if "chosen" in which:
+    rng = np.random.default_rng(0)
+    plain = jax.jit(lambda lat, tb, idx: ls.gather_tokens(
+        lat, tb, idx.reshape(idx.shape[0], -1)))
+
+    def walk(block):
+        return jax.jit(lambda lat, tb, idx, n: ls._pallas_latent_gather(
+            lat, tb, idx, n, *ls._tile_bounds(idx, n), block=block,
+            interpret=False))
+
+    adapt = jax.jit(lambda lat, tb, idx, n: ls.latent_gather(
+        lat, tb, idx, n, kernel="pallas"))
+    for ctx in (8191, 32767, 49151):
+        shapes = [(16, 1, live) for live in (0, 1, 3, 16)] + [(1, 256, 1)]
+        for b, t, live in shapes:
+            tb = table(b)
+            at = [ctx] * live + [-1] * (b - live) if t == 1 \
+                else list(range(ctx - t + 1, ctx + 1))
+            idx, n = chosen_sets(rng, at)
+            idx = jnp.asarray(idx.reshape(b, t, K))
+            n_dev = jnp.asarray(n.reshape(b, t))
+            tag = f"b{b}_t{t}_live{live}_ctx{ctx}"
+            want = plain(lat, tb, idx)
+            res[f"chosen_gather_lax_device_us_{tag}"] = device_us(
+                plain, lat, tb, idx, whole=True)
+            blocks = (256, 512, 1024) if ctx == 32767 and t == 1 \
+                and live in (1, 16) else (ls._GATHER_BLOCK,)
+            for block in blocks:
+                f = walk(block)
+                assert same_vectors(f(lat, tb, idx, n_dev), want, n), tag
+                name = "" if block == ls._GATHER_BLOCK else f"block{block}_"
+                res[f"chosen_gather_pallas_{name}device_us_{tag}"] = \
+                    device_us(f, lat, tb, idx, n_dev, whole=True)
+            if t == 1:
+                # what a decode program runs: the walk or XLA's gather by
+                # the reaches, summed (``lax.cond``)
+                assert same_vectors(adapt(lat, tb, idx, n_dev), want, n), tag
+                res[f"chosen_gather_adapt_device_us_{tag}"] = device_us(
+                    adapt, lat, tb, idx, n_dev, whole=True)
+            del want
+            print(json.dumps(res), flush=True)
 os.makedirs("chiprun_out", exist_ok=True)
 with open("chiprun_out/latent_select_bench.json", "w") as f:
     json.dump(res, f, indent=1)
