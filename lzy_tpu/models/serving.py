@@ -10,8 +10,15 @@ the configuration object and by the module class it builds;
 ``ZayaConfig`` / ``Zaya``, ``models/minicpm_sala.py``'s
 ``MiniCPMSalaConfig`` / ``MiniCPMSala``, ``models/brumby.py``'s
 ``BrumbyConfig`` / ``Brumby``, ``models/ouro.py``'s ``OuroConfig`` /
-``Ouro`` and ``models/dots3_note.py``'s ``Dots3NoteConfig`` / ``Dots3Note``
-all do: eleven families.
+``Ouro``, ``models/dots3_note.py``'s ``Dots3NoteConfig`` / ``Dots3Note`` and
+``models/motif.py``'s ``MotifConfig`` / ``Motif`` all do: twelve families.
+
+**What a layer carries between its blocks is the model's own.** The engine
+hands token ids in and takes logits out; ``models/motif.py`` carries **four
+residual streams a token** (``[B, T, 4 x 4096]`` float32, mixed into and out
+of every sublayer by matrices computed from the token: ``ops/mhc.py``) and
+no cache leaf holds one: a carry of several streams asks nothing of the
+protocol.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -76,7 +83,10 @@ bytes one cached token costs one ``window`` layer; absent, it is
 heads in both kinds). ``models/dots3_note.py`` answers both: its window
 layers cache a latent vector of their own rank, wider than the full layers'
 (2,304 bytes against 1,536), and ``serving/kv_cache.py`` ``divide_pool``
-charges each kind its own. Its module's ``__call__`` takes
+charges each kind its own; ``models/motif.py`` keeps **two latent leaves of
+one price in two kinds** (``latent``, kind ``paged``, in its full layers and
+``wlatent``, kind ``window``, in its window layers, 1,280 bytes a token a
+layer each) and answers ``kv_token_bytes`` alone. Such a module's ``__call__`` takes
 ``window_table=`` beside ``page_table=``: the same shape, addressing the
 ``window`` leaves.
 

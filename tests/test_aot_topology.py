@@ -1372,3 +1372,76 @@ def test_serving_steps_copy_no_parameter(name, case, one_chip, monkeypatch):
     ).compile().as_text()
     assert "tpu_custom_call" in text
     assert _parameter_copies(text, params) == []
+
+
+@pytest.mark.parametrize("rows", [8, 64, 256])
+def test_polynorm_experts_compile_at_published_widths(rows, one_chip):
+    """The dropless product under PolyNorm at Motif-3-Beta's 4096 x 1280, 48
+    experts held, a decode round's rows and the widest chunk's: two phases
+    an expert over five tiles of 256, the gate's product kept in a float32
+    scratch a row; at 256 rows the kernel asks for its VMEM by name."""
+    from lzy_tpu.ops import polynorm_experts as pne
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = sds((48, 4096, 1280), jnp.bfloat16)
+    text = jax.jit(lambda x, g, u, d, p, w: pne.polynorm_experts(
+        x, g, u, d, p, w, scale=0.5, clamp=0.5, kernel="pallas",
+        interpret=False)).lower(
+        sds((rows, 4096), jnp.bfloat16), up, up,
+        sds((48, 1280, 4096), jnp.bfloat16), sds((48, 4), jnp.float32),
+        sds((rows, 48), jnp.float32)).compile().as_text()
+    assert "tpu_custom_call" in text and "polynorm_experts" in text
+
+
+@pytest.mark.parametrize("rows", [40, 64, 256])
+def test_the_stream_mix_compiles_at_published_widths(rows, one_chip):
+    """``mhc_pre`` and ``mhc_post`` at four float32 streams of 4096: the
+    projection with a token a lane, twenty sweeps, the transposition of the
+    mix and the mixing, a tile of 64 rows a grid step; the new streams take
+    the old ones' place."""
+    from lzy_tpu.ops import mhc
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(x, phi, alpha, b, y):
+        h, mix = mhc.mhc_pre(x, phi, alpha, b, streams=4, sweeps=20,
+                             kernel="pallas", interpret=False)
+        return h, mhc.mhc_post(x, y, mix, streams=4, kernel="pallas",
+                               interpret=False)
+
+    text = jax.jit(both, donate_argnums=(0,)).lower(
+        sds((rows, 16384), jnp.float32), sds((24, 16384), jnp.float32),
+        sds((3,), jnp.float32), sds((24,), jnp.float32),
+        sds((rows, 4096), jnp.bfloat16)).compile().as_text()
+    assert "mhc_pre" in text and "mhc_post" in text
+    # in place: the program copies no stream
+    assert not re.search(r"\[\d+,16384\][^ ]* copy\(", text)
+
+
+@pytest.mark.parametrize("batch,t,heads", [(64, 1, 80), (1, 256, 20)],
+                         ids=["decode", "chunk256"])
+def test_latent_reads_compile_at_eighty_heads(batch, t, heads, one_chip):
+    """``ops/mla.py``'s read as it is at Motif-3-Beta's heads: all 80 in a
+    decode round of 64 slots (four slots a grid cell), 20 a call in a chunk
+    of 256 (a tile of 64 positions x 40 heads and more does not fit a core's
+    VMEM: ``MotifConfig.prefill_read_heads``); a pool of 12,289 pages of 64,
+    a table of 192."""
+    from lzy_tpu.models.motif import MotifConfig
+    from lzy_tpu.ops import mla
+
+    assert MotifConfig().prefill_read_heads == 20
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda q, pool, table, start: mla.mla_attention(
+        q, pool, table, start, value_dim=512, scale=192 ** -0.5,
+        kernel="pallas", interpret=False)).lower(
+        sds((batch, t, heads, 640), jnp.bfloat16),
+        sds((12289, 64, 640), jnp.bfloat16),
+        sds((batch, 192), jnp.int32), sds((batch,), jnp.int32)
+    ).compile().as_text()
+    assert ("mla_paged_decode" if t <= 8 else "mla_paged_prefill") in text

@@ -68,6 +68,9 @@ def registry_metrics():
     # the latent indexer's counts (what the selecting layers saw, chose
     # and read; the latent window's reads)
     import lzy_tpu.models.dots3_note  # noqa: F401
+    # a model of several residual streams and differential heads: rows x
+    # sublayers mixed, the noise heads' weight and the reads it weighs
+    import lzy_tpu.models.motif  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
