@@ -348,11 +348,14 @@ class Dots3NoteConfig:
         """``lzy_kernel_dispatch_total{path}`` labels of a program over
         ``t`` positions a row, beside the chosen read's own (asked of the
         paged model's configuration, which knows its kernel)."""
-        gather = lsel.gather_path(self.paged_kernel, t=t)
-        return (lsel.index_path(t),
-                lsel.choice_path(self.paged_kernel, t=t)) + (
-            (gather,) if gather else ()) + (
-            (gexp.PATH,) if self.expert_layers else ())
+        paths = [lsel.index_path(t),
+                 lsel.choice_path(self.paged_kernel, t=t),
+                 lsel.gather_path(self.paged_kernel, t=t)]
+        if SLIDING in self.layer_types:
+            paths.append(lsel.window_path(self.paged_kernel, t=t))
+        if self.expert_layers:
+            paths.append(gexp.PATH)
+        return tuple(path for path in paths if path)
 
     def check_kernels(self, *, slots: int, kv_blocks: Optional[int] = None,
                       page_size: Optional[int] = None,
@@ -363,9 +366,17 @@ class Dots3NoteConfig:
         expert product at the decode step's rows and at the widest chunk's
         and, with pools named, the index and the read of the chosen at both
         (the decode step's page table is the widest the scalar prefetch
-        carries). The read under the window is plain XLA."""
+        carries) and, with ``window_blocks`` named, the read under the
+        window at the decode step's rows (a chunk's is plain XLA)."""
         self._refuse_quant(kv_quant)
         full = self.kind(False)
+        if window_blocks is not None and SLIDING in self.layer_types:
+            swa = self.kind(True)
+            lsel.lower_window_for_tpu(
+                batch=slots, t=1, heads=swa.heads, width=swa.latent_width,
+                value_dim=swa.rank, window=self.window,
+                n_blocks=window_blocks, page_size=page_size,
+                pages_per_seq=pages_per_seq, dtype=self.dtype)
         if kv_blocks is not None:
             for batch, t in ((slots, 1), (1, self.widest_prefill)):
                 lsel.lower_for_tpu(
@@ -568,7 +579,7 @@ class LatentAttention(nn.Module):
                     summed = lsel.latent_window_attention(
                         q_full, pool.value, page_table, live,
                         window=cfg.window, value_dim=r,
-                        scale=k.softmax_scale)
+                        scale=k.softmax_scale, kernel=cfg.paged_kernel)
                 counts = [0, 0, 0, 0,
                           jnp.sum(jnp.minimum(seen, cfg.window)), 0]
             else:

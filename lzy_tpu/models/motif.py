@@ -386,8 +386,12 @@ class MotifConfig:
         """``lzy_kernel_dispatch_total{path}`` labels of a program over
         ``t`` positions a row, beside the full layers' read's own (asked of
         the paged model's configuration, which knows its kernel)."""
-        return (mhc.path(self.paged_kernel),) + (
-            (pne.path(self.paged_kernel),) if self.expert_layers else ())
+        paths = [mhc.path(self.paged_kernel)]
+        if SLIDING in self.layer_types:
+            paths.append(lsel.window_path(self.paged_kernel, t=t))
+        if self.expert_layers:
+            paths.append(pne.path(self.paged_kernel))
+        return tuple(path for path in paths if path)
 
     def check_kernels(self, *, slots: int, kv_blocks: Optional[int] = None,
                       page_size: Optional[int] = None,
@@ -398,9 +402,17 @@ class MotifConfig:
         the decode step's rows and at the widest chunk's: the connections'
         two, the expert product and, with a pool named, ``ops/mla.py``'s
         read at this model's heads (all of them in a decode round,
-        ``prefill_read_heads`` a call in a chunk). The read under the window
-        is plain XLA."""
+        ``prefill_read_heads`` a call in a chunk) and, with
+        ``window_blocks`` named, the read under the window at the decode
+        step's rows (a chunk's is plain XLA)."""
         self._refuse_quant(kv_quant)
+        if window_blocks is not None and SLIDING in self.layer_types:
+            lsel.lower_window_for_tpu(
+                batch=slots, t=1, heads=self.n_heads,
+                width=self.latent_width, value_dim=self.kv_lora_rank,
+                window=self.window, n_blocks=window_blocks,
+                page_size=page_size, pages_per_seq=pages_per_seq,
+                dtype=self.dtype)
         if kv_blocks is not None:
             for batch, t, heads in ((slots, 1, self.n_heads),
                                     (1, self.widest_prefill,
@@ -581,7 +593,7 @@ class DifferentialLatentAttention(nn.Module):
                     summed = lsel.latent_window_attention(
                         q_full, pool.value, page_table, live,
                         window=cfg.window, value_dim=r,
-                        scale=cfg.softmax_scale)
+                        scale=cfg.softmax_scale, kernel=cfg.paged_kernel)
                     read = [0, 0, jnp.sum(jnp.minimum(seen, cfg.window))]
                 else:
                     summed = self._full_read(q_full, pool.value, page_table,

@@ -89,10 +89,22 @@ indexer's key ``k^I`` (``[pages, page, D_I]``). Four steps, each with a
 4. **the read under a window** (:func:`latent_window_attention`): the
    absorbed sum over the ``window`` newest positions of a pool whose pages
    behind the window have gone back (``serving/kv_cache.py``
-   ``WindowPages``: the table reads scratch there), gathered from the first
+   ``WindowPages``: the table reads scratch there), read from the first
    position a query of the program can see and no earlier: ``window - 1 +
-   T`` vectors a row, whatever the context. Plain XLA on the chip too: 513
-   keys are a product of a few GFLOP a layer.
+   T`` positions a row, whatever the context. ``kernel="pallas"``, a
+   program of up to ``mla.MAX_DECODE_TOKENS`` positions a row (decode, the
+   verify window): ``ops/mla.py``'s kernel under the name
+   ``latent_window_decode``, its walk of a live row begun at the window's
+   first page, one more compare in its mask, a block no wider than a window
+   can fill (three pages of 64 under a window of 128: one block; nine
+   under 513: two of five); an idle slot is a scalar compare. A prefill
+   chunk and ``kernel="lax"`` gather the ``window - 1 + T`` vectors of
+   every row, live or not, by two XLA gathers and score them in plain XLA:
+   the oracle, what a CPU runs, and until PR 66 what a decode round ran
+   too (on a v5e chip, 16 slots under a window of 513 at 1,152 lanes: 167
+   us a layer whatever is live, where the kernel is 12 with one row live
+   and 66 with all sixteen; ``ops/mla.py`` has the table beside
+   ``_CELL_ROWS``).
 """
 
 from __future__ import annotations
@@ -121,6 +133,7 @@ GATHER_DECODE_PATH = "latent_gather_decode"
 CHOICE_DECODE_PATH = "latent_choice_decode"
 CHOICE_PREFILL_PATH = "latent_choice_prefill"
 CHOICE_LAX_PATH = "latent_choice_lax"
+WINDOW_DECODE_PATH = "latent_window_decode"
 
 #: query positions a grid step of the prefill index scores (a head's product
 #: is ``[tile, D_I] x [D_I, block]``: 128 rows fill the matrix unit) and the
@@ -969,18 +982,37 @@ def latent_chosen_attention(q: jax.Array, pool: jax.Array,
 
 # -- 4. the read under a window -------------------------------------------------
 
+def window_path(kernel: str, *, t: int) -> Optional[str]:
+    """The window read's label in a program with ``t`` positions a row: the
+    kernel's, or None where the gathers and the sums are plain XLA (no
+    label of their own)."""
+    if kernel == "pallas" and t <= mla.MAX_DECODE_TOKENS:
+        return WINDOW_DECODE_PATH
+    return None
+
+
 def latent_window_attention(q: jax.Array, pool: jax.Array,
                             window_table: jax.Array, start: jax.Array, *,
-                            window: int, value_dim: int,
-                            scale: float) -> jax.Array:
+                            window: int, value_dim: int, scale: float,
+                            kernel: str = "lax",
+                            interpret: Optional[bool] = None) -> jax.Array:
     """The absorbed latent read of the ``window`` newest positions: ``q``
     ``[B, T, H, W]`` at positions ``start + t`` (``start`` below 0: an idle
     row, result 0) against ``pool`` ``[n_blocks, page, W]`` through
     ``window_table`` ``[B, P]``. A query at ``p`` sees ``p - window < s <=
-    p``; the program gathers positions ``start - window + 1 .. start + T -
-    1`` and nothing before them, so the table may read scratch behind the
-    window. Returns ``[B, T, H, value_dim]``."""
+    p``; the program reads positions ``start - window + 1 .. start + T -
+    1`` and no page before theirs, so the table may read scratch behind the
+    window. ``kernel="pallas"`` in a program of up to
+    ``mla.MAX_DECODE_TOKENS`` positions a row is ``ops/mla.py``'s kernel
+    under the name ``latent_window_decode``: live rows' pages alone. A
+    wider program and ``kernel="lax"`` gather every row's positions.
+    Returns ``[B, T, H, value_dim]``."""
     b, t, _, _ = q.shape
+    if window_path(kernel, t=t) is not None:
+        return mla.mla_attention(
+            q, pool, window_table, start, value_dim=value_dim, scale=scale,
+            kernel=kernel, interpret=interpret, window=window,
+            name=WINDOW_DECODE_PATH)
     page = pool.shape[1]
     span = window - 1 + t
     held = start[:, None] - (window - 1) + jnp.arange(span, dtype=jnp.int32)
@@ -1043,6 +1075,28 @@ def lower_for_tpu(*, batch: int, t: int, heads: int, index_heads: int,
         sds((batch, index_heads, t, index_dim), dtype),
         sds((batch, t, index_heads), jnp.float32),
         sds((n_blocks, page_size, index_dim), dtype),
+        sds((batch, t, heads, width), dtype),
+        sds((n_blocks, page_size, width), dtype),
+        sds((batch, pages_per_seq), jnp.int32), sds((batch,), jnp.int32),
+    ).lower(lowering_platforms=("tpu",))
+
+
+def lower_window_for_tpu(*, batch: int, t: int, heads: int, width: int,
+                         value_dim: int, window: int, n_blocks: int,
+                         page_size: int, pages_per_seq: int, dtype):
+    """Lower the read under a window as a program of ``t`` positions a row
+    takes it under ``kernel="pallas"`` (``latent_window_decode`` up to
+    ``mla.MAX_DECODE_TOKENS``) for a TPU at these shapes, with no device
+    and no compile, and let the lowering's error out. Returns what was
+    lowered, for whoever wants its text."""
+    sds = jax.ShapeDtypeStruct
+
+    def read(q, pool, window_table, start):
+        return latent_window_attention(
+            q, pool, window_table, start, window=window,
+            value_dim=value_dim, scale=1.0, kernel="pallas", interpret=False)
+
+    return jax.jit(read).trace(
         sds((batch, t, heads, width), dtype),
         sds((n_blocks, page_size, width), dtype),
         sds((batch, pages_per_seq), jnp.int32), sds((batch,), jnp.int32),

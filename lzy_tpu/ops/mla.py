@@ -42,6 +42,15 @@ whose ``start`` is below 0 is idle** (a slot with no request in it; position
 0 is a live row with one visible key): under both kernels its result is 0,
 and the Pallas read spends a scalar compare on it, no page, no block of
 scores, no wait.
+
+**Under a window** (a static ``window``: ``ops/latent_select.py``
+``latent_window_attention``, the sliding layers of ``models/dots3_note.py``
+and ``models/motif.py``) a query sees its ``window`` newest positions. It is
+the same walk begun at the window's first page instead of page 0, with one
+more compare in the mask and a block no wider than a window can fill; the
+table may read scratch behind the window, and those pages are not touched.
+``window=None`` traces the program this module traced before it knew of
+windows: the window is a Python branch.
 """
 
 from __future__ import annotations
@@ -104,6 +113,33 @@ _BLOCK_POSITIONS = 512
 #: 32: the loop body that can start another row's pages is 0.07 us a block
 #: slower, which is more than the wait it saves).
 _CELL_ROWS = 512
+#: **Under a window** (``latent_window_decode``: ``ops/latent_select.py``
+#: ``latent_window_attention``) a live row's walk begins at its window's
+#: first page and a block is no wider than a window can fill
+#: (:func:`window_block_pages`). On a v5e chip, pages of 64, us a call (a
+#: layer) by the rows that are live, the rest idle (PERF.md section 6, PR
+#: 66; device time, 5 calls; "XLA": the two gathers of every slot's window
+#: and the plain sums, what a decode round ran before):
+#:
+#:     16 slots, 64 heads, 1,152 lanes, window 513, rows at 16,384
+#:     live rows               0      1      4     16
+#:     XLA                   167.1  167.1  167.1  167.1
+#:     two blocks of 5 pages   9.0   12.1   22.9   66.2
+#:     blocks of 8 and 1       8.8   13.0   26.2   79.2
+#:     one block of 9          8.6   12.2   23.3   67.4
+#:
+#:     64 slots, 80 heads, 640 lanes, window 128, rows at 3,000
+#:     live rows               0      8     34     64
+#:     XLA                   115.8  115.9  115.9  115.8
+#:     one block of 3 pages   20.8   33.2   74.5  121.5
+#:     blocks of 2 and 1      20.8   37.0   90.0  150.8
+#:     a block of 8 (512)     21.0   35.2   82.3  136.1
+#:
+#: 3.6 and 1.6 us a live row; with none live the call moves its q and result
+#: blocks (11.7 MB at 64 slots of 80 heads: 20 us). A full engine is within
+#: a tenth of XLA's (and XLA's copies the 37 MB pool besides: 61 us a layer
+#: in a traced round), so a decode program takes the kernel whatever is
+#: live: no ``lax.cond``.
 
 
 def read_path(kernel: str, *, t: int) -> str:
@@ -149,12 +185,17 @@ def causal_mla_attention(q, lat, *, value_dim: int, scale: float):
 
 def _kernel(start_ref, pt_ref, q_ref, pool_hbm, o_ref, buf, sems, m_ref,
             l_ref, acc_ref, *, group, tq, heads, page, pages_per_seq,
-            block_pages, value_dim, scale):
+            block_pages, value_dim, scale, window):
     """One grid cell: ``group`` batch rows at tile ``i`` of their query
     window, walked in order. A live row is ``tq`` consecutive query
     positions (``tq * heads`` rows of q, position-major) against the pages
     the last of them can see; an idle row (``start`` below 0) is given 0
     and costs a scalar compare: no page, no block of scores, no wait.
+    Under a ``window`` the walk begins at the page that holds the first
+    position the tile's first query sees (the table may read scratch before
+    it), column 0 of the first block is that page's first position, and a
+    query sees the ``window`` newest positions up to its own; ``None``
+    traces what the kernel traced before it knew of windows.
     Numerics: scores, the running max and sum and the accumulator in
     float32, scaled after the dot; probabilities cast to the pool's dtype
     before the value contraction; the sum of the float32 probabilities
@@ -186,6 +227,13 @@ def _kernel(start_ref, pt_ref, q_ref, pool_hbm, o_ref, buf, sems, m_ref,
             # per q row: the last pooled position it sees
             row_pos = first + row_off
             n_pages = lax.div(first + (i + 1) * tq - 1 + page, page)
+            if window is not None:
+                # the pages to walk and the positions, counted from the
+                # window's first page
+                page0 = lax.div(
+                    jnp.maximum(first + i * tq - (window - 1), 0), page)
+                n_pages = n_pages - page0
+                row_pos = row_pos - page0 * page
             n_blocks = lax.div(n_pages + block_pages - 1, block_pages)
             m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
@@ -195,7 +243,8 @@ def _kernel(start_ref, pt_ref, q_ref, pool_hbm, o_ref, buf, sems, m_ref,
                 for k in range(block_pages):
                     @pl.when(j * block_pages + k < n_pages)
                     def _():
-                        pid = pt_ref[r * pages_per_seq + j * block_pages + k]
+                        at = r * pages_per_seq + j * block_pages + k
+                        pid = pt_ref[at if window is None else at + page0]
                         op(pltpu.make_async_copy(
                             pool_hbm.at[pid],
                             buf.at[slot, pl.ds(k * page, page)],
@@ -215,7 +264,10 @@ def _kernel(start_ref, pt_ref, q_ref, pool_hbm, o_ref, buf, sems, m_ref,
                 s = lax.dot_general(
                     q_ref[rl], lat, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
-                visible = col <= row_pos - j * cols           # [rows, cols]
+                last = row_pos - j * cols
+                visible = col <= last                         # [rows, cols]
+                if window is not None:
+                    visible &= col > last - window
                 s = jnp.where(visible, s, _NEG_INF)
                 m = m_ref[...]
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -239,12 +291,27 @@ def _kernel(start_ref, pt_ref, q_ref, pool_hbm, o_ref, buf, sems, m_ref,
     lax.fori_loop(0, group, row, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("value_dim", "scale",
-                                             "interpret"))
+def window_block_pages(window: int, tq: int, page: int) -> int:
+    """Pages a block of a read under ``window`` holds. The ``window + tq -
+    1`` positions a tile of ``tq`` queries sees touch ``touch`` pages at
+    most; they go in as few blocks as ``_BLOCK_POSITIONS`` allows, of equal
+    size, so no block is wider than a window can fill."""
+    touch = -(-(window + tq - 2) // page) + 1
+    blocks = -(-touch // max(1, _BLOCK_POSITIONS // page))
+    return -(-touch // blocks)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "value_dim", "scale", "interpret", "window", "name", "block_pages"))
 def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
-                          scale: float, interpret: bool):
+                          scale: float, interpret: bool,
+                          window: Optional[int] = None,
+                          name: Optional[str] = None,
+                          block_pages: Optional[int] = None):
     """jitted so that the layers, which all make this call at one shape,
-    trace and lower the kernel once a program."""
+    trace and lower the kernel once a program. ``name``: what a device
+    trace calls the kernel (a read under a window runs this body under a
+    name of its own); ``block_pages``: a tool's override of the block."""
     b, t, h, w = q.shape
     n, page, _ = pool.shape
     pages = page_table.shape[1]
@@ -255,10 +322,15 @@ def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
             f"a prefill chunk of {t} positions is not whole tiles of {tq}")
     rows = tq * h
     group = cell_group(b, rows, _CELL_ROWS)
-    block_pages = max(1, min(pages, _BLOCK_POSITIONS // page))
+    if block_pages is None:
+        block_pages = max(1, min(pages, _BLOCK_POSITIONS // page))
+        if window is not None:
+            block_pages = min(block_pages,
+                              window_block_pages(window, tq, page))
     kernel = functools.partial(
         _kernel, group=group, tq=tq, heads=h, page=page, pages_per_seq=pages,
-        block_pages=block_pages, value_dim=value_dim, scale=scale)
+        block_pages=block_pages, value_dim=value_dim, scale=scale,
+        window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -282,7 +354,7 @@ def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret.tpu_params(interpret),
-        name="mla_paged_decode" if decode else "mla_paged_prefill",
+        name=name or ("mla_paged_decode" if decode else "mla_paged_prefill"),
     )(start.astype(jnp.int32).reshape(-1),
       page_table.astype(jnp.int32).reshape(-1),
       q.astype(pool.dtype).reshape(b, t * h, w), pool)
@@ -291,8 +363,9 @@ def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
 
 def mla_attention(q: jax.Array, pool: jax.Array, page_table: jax.Array,
                   start: jax.Array, *, value_dim: int, scale: float,
-                  kernel: str = "lax",
-                  interpret: Optional[bool] = None) -> jax.Array:
+                  kernel: str = "lax", interpret: Optional[bool] = None,
+                  window: Optional[int] = None,
+                  name: Optional[str] = None) -> jax.Array:
     """The absorbed latent read through the page table.
 
     - ``q``: ``[B, T, H, W]`` absorbed queries ``[q~ ; q_rope]`` (rotary
@@ -305,7 +378,14 @@ def mla_attention(q: jax.Array, pool: jax.Array, page_table: jax.Array,
       below 0 for an idle row, whose result is 0 and whose pages (the
       kernel's) are not read;
     - ``kernel``: ``"lax"`` or ``"pallas"`` (``interpret=None`` takes the
-      process's ``ops.interpret`` setting).
+      process's ``ops.interpret`` setting);
+    - ``window`` (static; ``kernel="pallas"`` alone: the ``lax`` form of a
+      read under a window is ``latent_select.latent_window_attention``'s):
+      a query sees its ``window`` newest positions, and the walk begins at
+      the window's first page: the table may read scratch behind it.
+      ``None``: the whole prefix;
+    - ``name`` (static): the kernel's name in a device trace, where a caller
+      wants its read told from ``mla_paged_decode`` / ``mla_paged_prefill``.
 
     Returns ``[B, T, H, value_dim]`` in the pool's dtype: each head's
     weighted sum of ``c``, for the value up-projection to finish."""
@@ -315,7 +395,12 @@ def mla_attention(q: jax.Array, pool: jax.Array, page_table: jax.Array,
     if kernel == "pallas":
         return _pallas_mla_attention(
             q, pool, page_table, start, value_dim=value_dim,
-            scale=float(scale), interpret=_interpret.resolve(interpret))
+            scale=float(scale), interpret=_interpret.resolve(interpret),
+            window=window, name=name)
+    if window is not None:
+        raise ValueError(
+            "the lax read under a window gathers the window alone: "
+            "latent_select.latent_window_attention")
     return lax_mla_attention(q, pool, page_table, start,
                              value_dim=value_dim, scale=scale)
 

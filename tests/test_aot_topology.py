@@ -1445,3 +1445,31 @@ def test_latent_reads_compile_at_eighty_heads(batch, t, heads, one_chip):
         sds((batch, 192), jnp.int32), sds((batch,), jnp.int32)
     ).compile().as_text()
     assert ("mla_paged_decode" if t <= 8 else "mla_paged_prefill") in text
+
+
+@pytest.mark.parametrize("batch,heads,width,value,window,blocks,pages", [
+    (16, 64, 1152, 1024, 513, 225, 784), (64, 80, 640, 512, 128, 449, 192)],
+    ids=["dots3", "motif"])
+def test_the_latent_window_read_compiles_at_both_cells_shapes(
+        batch, heads, width, value, window, blocks, pages, one_chip):
+    """``latent_window_decode`` (``ops/mla.py``'s kernel begun at the
+    window's first page) at ``indexed-steady``'s decode round (16 slots, 64
+    heads over 1,152 lanes under a window of 513: two blocks of five pages
+    of 64) and at ``hyper-steady``'s (64 slots, 80 heads over 640 lanes
+    under 128: one block of three): Mosaic takes the blocks and the scratch
+    fits a core's VMEM."""
+    from lzy_tpu.ops import latent_select
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda q, pool, table, start:
+                   latent_select.latent_window_attention(
+                       q, pool, table, start, window=window, value_dim=value,
+                       scale=192 ** -0.5, kernel="pallas",
+                       interpret=False)).lower(
+        sds((batch, 1, heads, width), jnp.bfloat16),
+        sds((blocks, 64, width), jnp.bfloat16),
+        sds((batch, pages), jnp.int32), sds((batch,), jnp.int32)
+    ).compile().as_text()
+    assert "latent_window_decode" in text and "mla_paged" not in text

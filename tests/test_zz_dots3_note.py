@@ -484,8 +484,9 @@ def test_every_documented_name_is_answered():
 
 def test_kernels_lower_for_a_tpu_at_published_widths():
     """No device and no compile: the index and the read of the chosen at
-    the decode round's shapes and the widest chunk's, the gated experts at
-    5120 x 1536 at 16 and at 256 rows."""
+    the decode round's shapes and the widest chunk's, the read under the
+    window at the decode round's over the 225-page window pool, the gated
+    experts at 5120 x 1536 at 16 and at 256 rows."""
     cfg = dataclasses.replace(
         dn.Dots3NoteConfig(), n_layers=5,
         layer_types=(dn.FULL, dn.FULL) + (dn.SLIDING,) * 3,
@@ -669,7 +670,7 @@ def test_kernel_paths_are_counted(served):
     for path in (ls.CHOSEN_DECODE_PATH, ls.CHOSEN_PREFILL_PATH,
                  ls.INDEX_DECODE_PATH, ls.INDEX_PREFILL_PATH,
                  ls.CHOICE_DECODE_PATH, ls.CHOICE_PREFILL_PATH,
-                 ls.GATHER_DECODE_PATH, gexp.PATH):
+                 ls.GATHER_DECODE_PATH, ls.WINDOW_DECODE_PATH, gexp.PATH):
         assert f'lzy_kernel_dispatch_total{{path="{path}"}}' in text
     assert served["engine"].stats().kernel_path == ls.CHOSEN_DECODE_PATH
 
@@ -679,12 +680,14 @@ def test_kernel_paths_are_counted(served):
     ("lax", 1, False)])
 def test_the_gathers_label_is_a_decode_programs_under_the_kernel(
         tiny, kernel, t, there):
-    """``latent_gather_decode`` copies the chosen in programs of up to
+    """``latent_gather_decode`` copies the chosen, and
+    ``latent_window_decode`` reads the window layers, in programs of up to
     ``mla.MAX_DECODE_TOKENS`` positions a row; a prefill chunk and the
-    ``lax`` form keep ``gather_tokens``, which has no label."""
+    ``lax`` form keep XLA's gathers, which have no label."""
     cfg, _ = tiny
     paths = dataclasses.replace(cfg, paged_kernel=kernel).kernel_paths(t)
     assert (ls.GATHER_DECODE_PATH in paths) == there
+    assert (ls.WINDOW_DECODE_PATH in paths) == there
     assert ls.index_path(t) in paths and gexp.PATH in paths
 
 

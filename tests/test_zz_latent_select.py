@@ -4,6 +4,7 @@ oracle or a float32 computation by hand; idle rows, rows that do not select,
 padded positions; the lowering for a TPU at the published widths. Tiny
 shapes, CPU, Pallas kernels interpreted (``tests/conftest.py``)."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -437,31 +438,42 @@ def test_nothing_unwritten_reaches_the_chosen_read():
 
 # -- the read under a window ----------------------------------------------------
 
+def _window_table(rng, starts, t, window, page, pages, blocks):
+    """Each live row's pages from its window's first to its last query's,
+    drawn without a repeat; block 0, the scratch, everywhere else."""
+    table = np.zeros((len(starts), pages), np.int32)
+    free = list(rng.permutation(np.arange(1, blocks)))
+    for row, first in enumerate(starts):
+        if first < 0:
+            continue
+        lo = max(0, first - window + 1) // page
+        hi = (first + t - 1) // page + 1
+        table[row, lo:hi] = [free.pop() for _ in range(hi - lo)]
+    return table
+
+
+@pytest.mark.parametrize("kernel", ["lax", "pallas"])
 @pytest.mark.parametrize("t", [1, 16])
 @pytest.mark.parametrize("window", [5, 24])
-def test_the_window_read_sees_the_window_and_no_page_behind_it(t, window):
+def test_the_window_read_sees_the_window_and_no_page_behind_it(
+        t, window, kernel):
     """Rows at the sequence's start, across the window's edge and far past
     it, an idle one; the table reads scratch behind the window, as the
     engine leaves it, and block 0 holds NaN: a read that touched a page
-    behind the window would show it."""
+    behind the window would show it. Under ``kernel="pallas"`` the round
+    of one position is the kernel's, the chunk of 16 the ``lax`` body's."""
     rng = np.random.default_rng(6)
     h, w, r, pages = 2, 128, 96, 16
     pool = np.array(_pool(rng, 40, w))
     pool[0] = np.nan
     starts = [2, window - 1, 90, -1] if t == 1 else [0, 61]
     b = len(starts)
-    table = np.zeros((b, pages), np.int32)
-    for row, first in enumerate(starts):
-        if first < 0:
-            continue
-        lo = max(0, first - window + 1) // PAGE
-        hi = (first + t - 1) // PAGE + 1
-        table[row, lo:hi] = rng.choice(np.arange(1, 40), hi - lo, False)
+    table = _window_table(rng, starts, t, window, PAGE, pages, 40)
     q = jnp.asarray(rng.normal(size=(b, t, h, w)), jnp.float32)
     got = np.asarray(ls.latent_window_attention(
         q, jnp.asarray(pool), jnp.asarray(table),
         jnp.asarray(starts, jnp.int32), window=window, value_dim=r,
-        scale=0.1))
+        scale=0.1, kernel=kernel))
     flat = pool[table].reshape(b, -1, w)
     for row, first in enumerate(starts):
         if first < 0:
@@ -472,6 +484,82 @@ def test_the_window_read_sees_the_window_and_no_page_behind_it(t, window):
             seen = np.arange(max(0, p - window + 1), p + 1)
             want = _attention_by_hand(q[row, i], flat[row, seen], r, 0.1)
             assert np.abs(got[row, i] - want).max() < 1e-5, (row, i)
+
+
+@pytest.mark.parametrize("t", [1, 4, 8])
+@pytest.mark.parametrize("page", [16, 64])
+@pytest.mark.parametrize("window", [5, 24, 128, 513])
+def test_the_window_kernel_is_the_lax_body(window, page, t):
+    """``latent_window_decode`` (interpreted) against the ``lax`` body: rows
+    at the sequence's start, on both sides of the window's edge, far past
+    it, with the last query on a page's last position and on the next
+    page's first (a window that straddles page edges, a verify window that
+    crosses one), idle rows between live ones; the table reads scratch
+    behind every window, and the scratch holds NaN."""
+    rng = np.random.default_rng(window * page + t)
+    h, w, r = 2, 128, 96
+    far = 3 * window + 7 * page
+    edge = (far // page + 2) * page
+    starts = [0, -1, window - 1, window, -1, far, edge - t, edge]
+    b = len(starts)
+    pages = (edge + t) // page + 2
+    blocks = 1 + b * (-(-(window + t) // page) + 2)
+    pool = np.array(rng.normal(size=(blocks, page, w)), np.float32)
+    pool[0] = np.nan
+    table = _window_table(rng, starts, t, window, page, pages, blocks)
+    args = (jnp.asarray(rng.normal(size=(b, t, h, w)), jnp.float32),
+            jnp.asarray(pool), jnp.asarray(table),
+            jnp.asarray(starts, jnp.int32))
+    got, want = (np.asarray(ls.latent_window_attention(
+        *args, window=window, value_dim=r, scale=0.1, kernel=kernel))
+        for kernel in ("pallas", "lax"))
+    live = np.asarray(starts) >= 0
+    assert np.isfinite(got).all()
+    assert np.abs(got[live] - want[live]).max() < 1e-5
+    assert (got[~live] == 0).all() and (want[~live] == 0).all()
+
+
+def test_the_window_kernel_is_the_lax_body_in_bfloat16():
+    """bfloat16 operands, float32 scores and sums, probabilities cast to
+    the pool's dtype before the value product: both forms read the same
+    rounded operands, so they differ by the order of their sums."""
+    rng = np.random.default_rng(11)
+    window, page, t, h, w, r = 24, 16, 2, 4, 128, 96
+    starts = [5, -1, 100, 31]
+    pool = jnp.asarray(rng.normal(size=(20, page, w)), jnp.bfloat16)
+    table = _window_table(rng, starts, t, window, page, 8, 20)
+    q = jnp.asarray(rng.normal(size=(4, t, h, w)), jnp.bfloat16)
+    a, b = (np.asarray(ls.latent_window_attention(
+        q, pool, jnp.asarray(table), jnp.asarray(starts, jnp.int32),
+        window=window, value_dim=r, scale=0.1, kernel=kernel), np.float32)
+        for kernel in ("pallas", "lax"))
+    assert a.dtype == b.dtype and np.abs(a - b).max() < 2e-2
+    assert (a[1] == 0).all()
+
+
+@pytest.mark.parametrize("t", [16, 128])
+def test_mlas_own_read_takes_a_window_at_any_width(t):
+    """``mla.mla_attention(window=)`` itself past the decode read's widths,
+    as tiles of its prefill read (one of 16 positions, two of 64: the
+    second tile's walk begins at its own first query's window), against
+    the window read's ``lax`` body; the ``lax`` form of ``ops/mla.py``
+    gathers every page and knows no window."""
+    rng = np.random.default_rng(12)
+    h, w, r, window = 2, 128, 96, 24
+    starts = [3, 70]
+    pool = np.array(_pool(rng, 60, w))
+    pool[0] = np.nan
+    table = jnp.asarray(_window_table(rng, starts, t, window, PAGE, 26, 60))
+    q = jnp.asarray(rng.normal(size=(2, t, h, w)), jnp.float32)
+    start = jnp.asarray(starts, jnp.int32)
+    got = mla.mla_attention(q, jnp.asarray(pool), table, start, value_dim=r,
+                            scale=0.1, kernel="pallas", window=window)
+    want = ls.latent_window_attention(q, jnp.asarray(pool), table, start,
+                                      window=window, value_dim=r, scale=0.1)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+    with pytest.raises(ValueError, match="latent_window_attention"):
+        mla.mla_attention(q, jnp.asarray(pool), table, start, value_dim=r,
+                          scale=0.1, kernel="lax", window=window)
 
 
 def test_the_uncached_forms_are_the_cached_ones():
@@ -512,6 +600,10 @@ def test_the_labels_say_which_form_ran():
     assert ls.choice_path("lax", t=1) == ls.choice_path("lax", t=256) \
         == ls.CHOICE_LAX_PATH
     assert ls.GATHER_DECODE_PATH == "latent_gather_decode"
+    assert ls.window_path("pallas", t=1) == ls.window_path("pallas", t=8) \
+        == ls.WINDOW_DECODE_PATH == "latent_window_decode"
+    assert ls.window_path("pallas", t=256) is None
+    assert ls.window_path("lax", t=1) is None
 
 
 @pytest.mark.parametrize("batch,t", [(16, 1), (1, 256)])
@@ -524,3 +616,83 @@ def test_the_kernels_lower_for_a_tpu_at_published_widths(batch, t):
                      index_dim=128, width=640, value_dim=512, topk=2048,
                      n_blocks=12545, page_size=64, pages_per_seq=784,
                      dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("window,t,page,block", [
+    (128, 1, 64, 3), (513, 1, 64, 5), (513, 8, 64, 5), (513, 1, 16, 17),
+    (5, 1, 8, 2), (24, 8, 8, 5)])
+def test_a_windows_block_is_no_wider_than_it_can_fill(window, t, page,
+                                                      block):
+    """``window + t - 1`` positions touch at most one page more than they
+    fill; they go in the fewest blocks of 512 positions, of equal size:
+    one block of three pages of 64 under a window of 128, two of five under
+    513 (nine pages: eight and one would score 1,024 columns for 513)."""
+    assert mla.window_block_pages(window, t, page) == block
+
+
+@pytest.mark.parametrize("t", [1, 5])
+@pytest.mark.parametrize("shape", [
+    dict(batch=16, heads=64, width=1152, value_dim=1024, window=513,
+         n_blocks=225, pages_per_seq=784),
+    dict(batch=64, heads=80, width=640, value_dim=512, window=128,
+         n_blocks=449, pages_per_seq=192)], ids=["dots3", "motif"])
+def test_the_window_read_lowers_for_a_tpu_at_both_cells_shapes(shape, t):
+    """No device and no compile: ``latent_window_decode`` at
+    ``indexed-steady``'s decode round (16 slots, 64 heads over 1,152 lanes
+    under a window of 513, a table of 784 pages of 64) and at
+    ``hyper-steady``'s (64 slots, 80 heads over 640 lanes under 128, a
+    pool of 449 pages), and at a verify window of 5; under its own name,
+    not the full layers' read's."""
+    text = ls.lower_window_for_tpu(t=t, page_size=64, dtype=jnp.bfloat16,
+                                   **shape).as_text()
+    assert 'kernel_name = "latent_window_decode"' in text
+    assert "mla_paged" not in text
+
+
+def _mosaic_modules(lowered_text):
+    """The Mosaic modules of a text lowered for a TPU, each printed with
+    no locations: a custom call carries its module as MLIR bytecode, whose
+    debug information holds the line every operation was traced from."""
+    import base64
+    import json
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for config in re.findall(r'backend_config = "((?:[^"\\]|\\.)*)"',
+                             lowered_text):
+        config = json.loads(config.replace("\\22", '"'))
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            out.append(ir.Module.parse(base64.b64decode(
+                config["custom_call_config"]["body"])).operation.get_asm(
+                    enable_debug_info=False))
+    return out
+
+
+@pytest.mark.parametrize("shape,digest", [
+    ((4, 1, 16, 8), "4154c920b57e90dbbf4e4db1b38f04f26ac198f18f76b8fa6462f8"
+                     "0be62a9586"),
+    ((1, 128, 16, 24), "f5f8c4c43eee93791f7fc14fbf4d9a5f7ff8102c82965a815e01"
+                       "ea63c1b929ad")], ids=["decode", "prefill"])
+def test_no_window_lowers_to_what_it_lowered_to_before(shape, digest):
+    """``mla_attention(window=None)`` is the program the kernel was before
+    it knew of windows: the window is a Python branch, not a traced one.
+    The digests are of the Mosaic module lowered for a TPU from PR 65's
+    ``ops/mla.py`` at these shapes (16 heads over 640 lanes, 33 pages of
+    16), printed with no locations."""
+    import hashlib
+
+    b, t, h, pages = shape
+    sds = jax.ShapeDtypeStruct
+    text = jax.jit(lambda q, pool, table, start: mla.mla_attention(
+        q, pool, table, start, value_dim=512, scale=0.125, kernel="pallas",
+        interpret=False)).trace(
+        sds((b, t, h, 640), jnp.bfloat16), sds((33, 16, 640), jnp.bfloat16),
+        sds((b, pages), jnp.int32), sds((b,), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    module, = _mosaic_modules(text)
+    assert hashlib.sha256(module.encode()).hexdigest() == digest

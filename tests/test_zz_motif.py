@@ -22,6 +22,7 @@ import pytest
 from benchmark.models import motif as ref
 from lzy_tpu.models import motif as mt
 from lzy_tpu.models import serving
+from lzy_tpu.ops import latent_select as lsel
 from lzy_tpu.ops import mhc, mla
 from lzy_tpu.ops import polynorm_experts as pne
 from lzy_tpu.serving import PagedInferenceEngine
@@ -496,8 +497,10 @@ def test_every_documented_name_is_answered():
 
 def test_kernels_lower_for_a_tpu_at_published_widths():
     """No device and no compile: ``ops/mla.py``'s read at 80 heads at the
-    decode round's shapes and at 20 a call at the widest chunk's, both
-    connections' kernels and the expert product at 64 and at 256 rows."""
+    decode round's shapes and at 20 a call at the widest chunk's, the read
+    under the window at the decode round's over the 449-page window pool,
+    both connections' kernels and the expert product at 64 and at 256
+    rows."""
     with open(CONFIG) as f:
         cfg = ref.program_config(json.load(f))
     cfg.check_kernels(slots=64, kv_blocks=12289, page_size=64,
@@ -505,10 +508,15 @@ def test_kernels_lower_for_a_tpu_at_published_widths():
 
 
 @pytest.mark.parametrize("kernel,t,paths", [
-    ("pallas", 1, (mhc.PATH, pne.PATH)),
+    ("pallas", 1, (mhc.PATH, lsel.WINDOW_DECODE_PATH, pne.PATH)),
+    ("pallas", 8, (mhc.PATH, lsel.WINDOW_DECODE_PATH, pne.PATH)),
     ("pallas", 256, (mhc.PATH, pne.PATH)),
     ("lax", 1, (mhc.LAX_PATH, pne.LAX_PATH))])
 def test_a_programs_kernel_labels(tiny, kernel, t, paths):
+    """``latent_window_decode`` reads the window layers in programs of up
+    to ``mla.MAX_DECODE_TOKENS`` positions a row under the kernel; a
+    prefill chunk and the ``lax`` form keep the gathers, which have no
+    label."""
     cfg, _ = tiny
     assert dataclasses.replace(cfg, paged_kernel=kernel).kernel_paths(t) \
         == paths
@@ -666,7 +674,8 @@ def test_one_fence_a_round_carries_the_counts(tiny, served):
 
 def test_kernel_paths_are_counted(served):
     text = REGISTRY.exposition()
-    for path in (mla.DECODE_PATH, mla.PREFILL_PATH, mhc.PATH, pne.PATH):
+    for path in (mla.DECODE_PATH, mla.PREFILL_PATH, mhc.PATH,
+                 lsel.WINDOW_DECODE_PATH, pne.PATH):
         assert f'lzy_kernel_dispatch_total{{path="{path}"}}' in text
     assert served["engine"].stats().kernel_path == mla.DECODE_PATH
 

@@ -11,9 +11,12 @@ paged program at the engine's table of 784 pages to the float32 reference
 found PR 62's wrong choice); ``round`` names the operations of that
 program's decode round by device time, each HLO instruction with the
 ``op_name`` its metadata holds (a trace labels a fusion by its kind and its
-shape alone).
+shape alone); ``window`` times the window layers' read alone, the kernel
+``latent_window_decode`` against the ``lax`` body at 0 / 1 / 4 / 16 live
+rows of 16 slots (64 heads over 1,152 lanes under a window of 513).
 ``chiprun -- python tools/latent_select_bench.py [topk] [chosen] [index]
-[program] [round]``; writes ``chiprun_out/latent_select_bench.json``."""
+[program] [round] [window]``; writes
+``chiprun_out/latent_select_bench.json``."""
 import json
 import os
 import sys
@@ -210,6 +213,14 @@ def decode_round_ops(at=16384, rounds=5, least_us=5.0, slots=16,
 
 
 which = sys.argv[1:] or ["topk", "chosen", "index", "program"]
+if "window" in which:
+    from tools.window_read import window_read_table
+
+    res["window_read_16_slots_at_16384"] = window_read_table(
+        lambda f, *a: device_us(f, *a, whole=True), slots=16, heads=64,
+        width=1152, value_dim=1024, window=513, page=page, pages_per_seq=P,
+        blocks=225, lives=(0, 1, 4, 16), at=16384, block_pages=(8, 9))
+    print(json.dumps(res), flush=True)
 if "round" in which:
     res["decode_round_ops_at_16384"] = decode_round_ops()
     print(json.dumps(res), flush=True)

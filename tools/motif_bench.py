@@ -10,13 +10,16 @@ of microseconds reads its own dispatch):
 - ``reads``: ``ops/mla.py``'s read at 80 heads, a decode round of 64 slots
   with 40 live at 3,000 positions, and a prefill chunk of 256 behind 2,048
   in calls of 20 heads;
+- ``window``: the window layers' read alone, the kernel
+  ``latent_window_decode`` against the ``lax`` body at 0 / 8 / 34 / 64 live
+  rows of 64 slots (80 heads over 640 lanes under a window of 128);
 - ``round`` / ``chunk``: the five-layer program's decode round (64 slots, 40
   live at 3,000) and prefill program (256 positions behind 2,048), an HLO
   instruction each with the ``op_name`` its metadata holds (a trace labels a
   fusion by its kind and its shape alone), summed by named scope.
 
-``chiprun -- python tools/motif_bench.py [experts] [mhc] [reads] [round]
-[chunk]``; writes ``chiprun_out/motif_bench.json``."""
+``chiprun -- python tools/motif_bench.py [experts] [mhc] [reads] [window]
+[round] [chunk]``; writes ``chiprun_out/motif_bench.json``."""
 import json
 import os
 import re
@@ -226,9 +229,20 @@ def program_ops(decode: bool, rounds=5, least_us=3.0):
                     if us >= least_us]}
 
 
-which = sys.argv[1:] or ["experts", "mhc", "reads", "round", "chunk"]
+def window():
+    from tools.window_read import window_read_table
+
+    res["window_read_64_slots_at_3000"] = window_read_table(
+        device_us, slots=64, heads=80, width=640, value_dim=512, window=128,
+        page=64, pages_per_seq=192, blocks=449, lives=(0, 8, 34, 64),
+        at=3000, block_pages=(2, 8))
+    print(json.dumps(res), flush=True)
+
+
+which = sys.argv[1:] or ["experts", "mhc", "reads", "window", "round",
+                         "chunk"]
 for mode, run in (("experts", experts), ("mhc", connections),
-                  ("reads", reads)):
+                  ("reads", reads), ("window", window)):
     if mode in which:
         run()
 if "round" in which:
