@@ -1,24 +1,24 @@
 """What a serving engine asks of a model family, so that
 ``serving/engine.py`` names no model (ROADMAP D1). One protocol, answered by
 the configuration object and by the module class it builds;
-``models/llama.py``'s ``LlamaConfig`` / ``Llama``,
-``models/nemotron_h.py``'s ``NemotronHConfig`` / ``NemotronH``,
-``models/solar_open2.py``'s ``SolarOpen2Config`` / ``SolarOpen2``,
-``models/deepseek_v3.py``'s ``DeepseekV3Config`` / ``DeepseekV3``,
-``models/cohere2_moe.py``'s ``Cohere2MoeConfig`` / ``Cohere2Moe``,
-``models/jamba.py``'s ``JambaConfig`` / ``Jamba``, ``models/zaya.py``'s
-``ZayaConfig`` / ``Zaya``, ``models/minicpm_sala.py``'s
-``MiniCPMSalaConfig`` / ``MiniCPMSala``, ``models/brumby.py``'s
-``BrumbyConfig`` / ``Brumby``, ``models/ouro.py``'s ``OuroConfig`` /
-``Ouro``, ``models/dots3_note.py``'s ``Dots3NoteConfig`` / ``Dots3Note`` and
-``models/motif.py``'s ``MotifConfig`` / ``Motif`` all do: twelve families.
+``models/llama.py``'s ``LlamaConfig`` / ``Llama``, ``models/nemotron_h.py``'s
+``NemotronHConfig`` / ``NemotronH``, ``models/solar_open2.py``'s
+``SolarOpen2Config`` / ``SolarOpen2``, ``models/deepseek_v3.py``'s
+``DeepseekV3Config`` / ``DeepseekV3``, ``models/cohere2_moe.py``'s
+``Cohere2MoeConfig`` / ``Cohere2Moe``, ``models/jamba.py``'s ``JambaConfig`` /
+``Jamba``, ``models/zaya.py``'s ``ZayaConfig`` / ``Zaya``,
+``models/minicpm_sala.py``'s ``MiniCPMSalaConfig`` / ``MiniCPMSala``,
+``models/brumby.py``'s ``BrumbyConfig`` / ``Brumby``, ``models/ouro.py``'s
+``OuroConfig`` / ``Ouro``, ``models/dots3_note.py``'s ``Dots3NoteConfig`` /
+``Dots3Note``, ``models/motif.py``'s ``MotifConfig`` / ``Motif`` and
+``models/longcat_flash.py``'s ``LongcatFlashConfig`` / ``LongcatFlash`` all
+do: thirteen families.
 
 **What a layer carries between its blocks is the model's own.** The engine
 hands token ids in and takes logits out; ``models/motif.py`` carries **four
-residual streams a token** (``[B, T, 4 x 4096]`` float32, mixed into and out
-of every sublayer by matrices computed from the token: ``ops/mhc.py``) and
-no cache leaf holds one: a carry of several streams asks nothing of the
-protocol.
+residual streams a token** (``[B, T, 4 x 4096]`` float32, mixed into and out of
+every sublayer by matrices computed from the token: ``ops/mhc.py``) and no
+cache leaf holds one: a carry of several streams asks nothing of the protocol.
 
 **The configuration object** gives ``max_seq_len``, ``vocab_size``,
 ``dtype``, ``n_heads`` and, a model whose pages hold keys and values a head,
@@ -32,19 +32,20 @@ engine reads neither), and:
   pool through the page table (``ops/paged_attention.py``, ``ops/mla.py``);
   a ``kv_quant`` its pool cannot take is refused here, by name;
 - ``kv_layers``: entries a token keeps in the paged pool, one a layer that
-  keeps a leaf there; **it may exceed the model's layers**
-  (``models/ouro.py`` runs its 48 layers four times a token with a cache a
-  (pass, layer): 192). **0 (and no
+  keeps a leaf there; **it may exceed the model's layers** (``models/ouro.py``
+  runs its 48 layers four times a token with a cache a (pass, layer): 192;
+  ``models/longcat_flash.py`` holds **two attentions of different weights a
+  layer, each with a latent leaf of its own**: twice its layers). **0 (and no
   ``kv_window``) is a model with no pool** (``models/brumby.py``: every cache
   leaf is ``state``): the engine builds no block for it, not even the scratch
-  one (``RadixCache(0, ..)``), keeps and uploads no page table (a decode
-  round is handed the ``[slots]`` vector of live rows in the table's place,
-  as ``valid_len``), admits a request by a free slot and ``max_seq_len``
-  alone, grows nothing in decode, reports ``kv_blocks_*`` 0, and refuses
+  one (``RadixCache(0, ..)``), keeps and uploads no page table (a decode round
+  is handed the ``[slots]`` vector of live rows in the table's place, as
+  ``valid_len``), admits a request by a free slot and ``max_seq_len`` alone,
+  grows nothing in decode, reports ``kv_blocks_*`` 0, and refuses
   ``kv_blocks`` / ``kv_pool_bytes`` by name; its module is still handed
-  ``page_table=`` (``[1, 0]`` in a prefill program, nothing in a decode
-  round) and does not read it. The engine checks the answer against the
-  module's leaf kinds and refuses a model whose two answers disagree;
+  ``page_table=`` (``[1, 0]`` in a prefill program, nothing in a decode round)
+  and does not read it. The engine checks the answer against the module's leaf
+  kinds and refuses a model whose two answers disagree;
 - ``kv_token_bytes(kv_quant)``: the bytes one cached token costs one such
   layer. The engine divides a byte budget for the pool by
   ``page_size x kv_layers x kv_token_bytes`` and asks nothing about what a
@@ -70,25 +71,24 @@ engine reads neither), and:
   the engine names no kernel), so that what the lowering refuses is refused
   at construction; with no pool named, the kernels beside the read only.
 
-A configuration whose module has ``window`` leaves (below) gives three
-answers more, and the engine asks them of no other: ``kv_window``, the
-positions such a leaf keeps readable behind a row's newest (a query at
-``p`` reads ``p - kv_window < j <= p`` there and nothing older, ever);
-``window_layers``, the layers that keep one (``kv_layers`` counts the layers
-of ``paged`` leaves only); and ``paged_model`` / ``check_kernels`` take
-``window_pages=`` / ``window_blocks=``, the second pool's size. **The window
-kind has its own price**: ``window_token_bytes(kv_quant)``, optional, the
-bytes one cached token costs one ``window`` layer; absent, it is
-``kv_token_bytes`` (``models/cohere2_moe.py``: keys and values of the same
-heads in both kinds). ``models/dots3_note.py`` answers both: its window
-layers cache a latent vector of their own rank, wider than the full layers'
-(2,304 bytes against 1,536), and ``serving/kv_cache.py`` ``divide_pool``
-charges each kind its own; ``models/motif.py`` keeps **two latent leaves of
-one price in two kinds** (``latent``, kind ``paged``, in its full layers and
-``wlatent``, kind ``window``, in its window layers, 1,280 bytes a token a
-layer each) and answers ``kv_token_bytes`` alone. Such a module's ``__call__`` takes
-``window_table=`` beside ``page_table=``: the same shape, addressing the
-``window`` leaves.
+A configuration whose module has ``window`` leaves (below) gives three answers
+more, and the engine asks them of no other: ``kv_window``, the positions such a
+leaf keeps readable behind a row's newest (a query at ``p`` reads ``p -
+kv_window < j <= p`` there and nothing older, ever); ``window_layers``, the
+layers that keep one (``kv_layers`` counts the layers of ``paged`` leaves
+only); and ``paged_model`` / ``check_kernels`` take ``window_pages=`` /
+``window_blocks=``, the second pool's size. **The window kind has its own
+price**: ``window_token_bytes(kv_quant)``, optional, the bytes one cached token
+costs one ``window`` layer; absent, it is ``kv_token_bytes``
+(``models/cohere2_moe.py``: keys and values of the same heads in both kinds).
+``models/dots3_note.py`` answers both: its window layers cache a latent vector
+of their own rank, wider than the full layers' (2,304 bytes against 1,536), and
+``serving/kv_cache.py`` ``divide_pool`` charges each kind its own;
+``models/motif.py`` keeps **two latent leaves of one price in two kinds**
+(``latent``, kind ``paged``, in its full layers and ``wlatent``, kind
+``window``, in its window layers, 1,280 bytes a token a layer each) and answers
+``kv_token_bytes`` alone. Such a module's ``__call__`` takes ``window_table=``
+beside ``page_table=``: the same shape, addressing the ``window`` leaves.
 
 **The module class** declares ``CACHE_KINDS`` (cache leaf name -> kind; a
 leaf it does not name is ``paged``) and ``STATS``: the counters its

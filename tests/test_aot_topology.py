@@ -1473,3 +1473,52 @@ def test_the_latent_window_read_compiles_at_both_cells_shapes(
         sds((batch, pages), jnp.int32), sds((batch,), jnp.int32)
     ).compile().as_text()
     assert "latent_window_decode" in text and "mla_paged" not in text
+
+
+# -- LongCat-Flash's two kernels at its widths (the benchmark's cut) ----------
+
+@pytest.mark.parametrize("rows", [128, 256], ids=["decode", "chunk256"])
+def test_gated_experts_compile_at_a_hidden_width_of_6144(rows, one_chip):
+    """16 held experts of three 6144 x 2048 matrices (LongCat-Flash): weight
+    tiles of 6144 x 128 lanes, the narrowest ``_tile`` gives (a tile of 256
+    lanes is 3 MB, over ``_TILE_BYTES``); the rows' input and float32 result
+    of 256 rows ask for VMEM by name."""
+    from lzy_tpu.ops import grouped_experts as gexp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert gexp._tile(2048, 6144, 2) == 128
+    up = sds((16, 6144, 2048), jnp.bfloat16)
+    compiled = jax.jit(lambda x, g, a, b, w: gexp.grouped_experts(
+        x, a, b, w, gate=g, interpret=False)).lower(
+        sds((rows, 6144), jnp.bfloat16), up, up,
+        sds((16, 2048, 6144), jnp.bfloat16),
+        sds((rows, 16), jnp.float32)).compile()
+    assert "grouped_experts" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch,t,heads", [(128, 1, 64), (1, 256, 16)],
+                         ids=["decode", "chunk256"])
+def test_latent_reads_compile_at_sixty_four_heads(batch, t, heads, one_chip):
+    """``ops/mla.py``'s read as it is at LongCat-Flash's heads: all 64 in a
+    decode round of 128 slots (8 slots a grid cell under ``_CELL_ROWS``), 16
+    a call in a chunk of 256 (``LongcatFlashConfig.prefill_read_heads``: a
+    tile of 64 positions x 64 heads does not fit a core's VMEM); a pool of
+    4,096 pages of 64, a table of 128."""
+    from lzy_tpu.models.longcat_flash import LongcatFlashConfig
+    from lzy_tpu.ops import mla
+
+    assert LongcatFlashConfig().prefill_read_heads == 16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda q, pool, table, start: mla.mla_attention(
+        q, pool, table, start, value_dim=512, scale=192 ** -0.5,
+        kernel="pallas", interpret=False)).lower(
+        sds((batch, t, heads, 640), jnp.bfloat16),
+        sds((4096, 64, 640), jnp.bfloat16),
+        sds((batch, 128), jnp.int32), sds((batch,), jnp.int32)
+    ).compile().as_text()
+    assert ("mla_paged_decode" if t <= 8 else "mla_paged_prefill") in text

@@ -479,7 +479,7 @@ def test_every_documented_name_is_answered():
         assert hasattr(cfg, name), name
     assert (cfg.kv_layers, cfg.window_layers, cfg.kv_window) == (2, 2, 5)
     assert "window_token_bytes" in serving.__doc__
-    assert "twelve families" in serving.__doc__
+    assert "thirteen families" in serving.__doc__
 
 
 def test_kernels_lower_for_a_tpu_at_published_widths():

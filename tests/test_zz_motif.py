@@ -489,7 +489,7 @@ def test_every_documented_name_is_answered():
                          "kv_window", "window_layers"]:
         assert hasattr(cfg, name), name
     assert (cfg.kv_layers, cfg.window_layers, cfg.kv_window) == (1, 4, 5)
-    assert "twelve families" in serving.__doc__
+    assert "thirteen families" in serving.__doc__
     assert "models/motif.py" in serving.__doc__
     assert mt.Motif.CACHE_KINDS == {"latent": "paged", "wlatent": "window",
                                     "index": "index"}

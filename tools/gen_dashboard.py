@@ -71,6 +71,9 @@ def registry_metrics():
     # a model of several residual streams and differential heads: rows x
     # sublayers mixed, the noise heads' weight and the reads it weighs
     import lzy_tpu.models.motif  # noqa: F401
+    # a router wider than its experts with weights: the choices that fell
+    # on identity experts and the weight they carried
+    import lzy_tpu.models.longcat_flash  # noqa: F401
     # sharded gang replicas: gang size by mesh, per-shard KV blocks,
     # shard-skew tripwire, whole-gang failovers (lzy_sharded_*)
     import lzy_tpu.serving.sharded.metrics  # noqa: F401
