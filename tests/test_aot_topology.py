@@ -1009,18 +1009,11 @@ def test_zaya_kernels_compile_at_published_widths(case, one_chip,
 
 # -- MiniCPM-SALA's serving kernels at the cell's shapes ----------------------
 
-@pytest.mark.parametrize("case", ["decode", "prefill"])
-def test_minicpm_sala_kernels_compile_at_the_cells_shapes(case, one_chip,
-                                                          monkeypatch,
-                                                          capsys):
+def _minicpm_sala_step(case, one_chip, monkeypatch):
     """MiniCPM-SALA at its published widths, a sparse layer and a lightning
-    layer, as ``sparse-steady`` runs them: 16 slots, pages of 64, a page
-    table 528 wide (33,792 positions), a pool of 3,855 pages. The decode
-    round (``sparse_select_decode``, the read of the chosen pages under the
-    name ``sparse_decode_attention``, ``lightning_state_update`` at a state
-    of 32 x 128 x 128 a slot) and the prefill chunk of 256
-    (``sparse_select_prefill``, ``sparse_prefill_attention``, the shared
-    scan). The compiled step's memory is printed."""
+    layer, as ``sparse-steady`` runs them (16 slots, pages of 64, a page
+    table 528 wide, a pool of 3,855 pages), compiled for v5e: the decode
+    round or the prefill chunk of 256. Returns ``(compiled, params)``."""
     from lzy_tpu.models import minicpm_sala as sala
     from lzy_tpu.ops import interpret
 
@@ -1060,6 +1053,22 @@ def test_minicpm_sala_kernels_compile_at_the_cells_shapes(case, one_chip,
         params, cache, sds((batch, t), jnp.int32),
         sds((batch, pages), jnp.int32), sds((batch,), jnp.int32),
         sds((batch,), jnp.int32)).compile()
+    return compiled, params
+
+
+@pytest.mark.parametrize("case", ["decode", "prefill"])
+def test_minicpm_sala_kernels_compile_at_the_cells_shapes(case, one_chip,
+                                                          monkeypatch,
+                                                          capsys):
+    """MiniCPM-SALA at its published widths, a sparse layer and a lightning
+    layer, as ``sparse-steady`` runs them: 16 slots, pages of 64, a page
+    table 528 wide (33,792 positions), a pool of 3,855 pages. The decode
+    round (``sparse_select_decode``, the read of the chosen pages under the
+    name ``sparse_decode_attention``, ``lightning_state_update`` at a state
+    of 32 x 128 x 128 a slot) and the prefill chunk of 256
+    (``sparse_select_prefill``, ``sparse_prefill_attention``, the shared
+    scan). The compiled step's memory is printed."""
+    compiled, params = _minicpm_sala_step(case, one_chip, monkeypatch)
     text = compiled.as_text()
     for kernel in (("sparse_select_decode", "sparse_decode_attention",
                     "lightning_state_update") if case == "decode"
@@ -1081,6 +1090,29 @@ def test_minicpm_sala_kernels_compile_at_the_cells_shapes(case, one_chip,
     # nor a projection's weight transposed for its heads (four copies of
     # bf16[4096,4096] a layer pair before ``paged_blocks.into_heads``)
     assert _parameter_copies(text, params) == []
+
+
+def test_minicpm_sala_decode_round_sorts_and_gathers_no_table(one_chip,
+                                                              monkeypatch):
+    """The decode round of a sparse layer packs its chosen pages by a
+    running count (``ops/sparse_attention.py`` ``pack_chosen``): the
+    compiled step at the cell's 16 slots, pages of 64 and 528 pages a row
+    holds no ``sort`` instruction over the mask (``pred[16,2,528]``) and no
+    gather whose result is the packed table, ``s32[16,2,528]`` or that
+    flattened (``s32[16896]``). Until PR 68 it held one of each a sparse
+    layer, a stable ``argsort`` of the mask and a ``take_along_axis`` by its
+    order, 215 us a layer on the chip between the selector's result and
+    the read's start. The one sort that stays is the lightning layer's, of
+    its 16 rows' live flags (``ops/mamba2.py``: the update walks live rows
+    first)."""
+    compiled, _ = _minicpm_sala_step("decode", one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert "sparse_decode_attention" in text
+    assert [line.split("metadata=")[0]
+            for line in re.findall(r"^.* sort\(.*$", text, re.M)
+            if "528" in line.split(" sort(")[0]] == []
+    assert not re.search(
+        r"= s32\[(16,2,528|16896)\]\S* gather\(", text)
 
 
 @pytest.mark.parametrize("case", ["kernel", "decode", "prefill"])

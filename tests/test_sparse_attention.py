@@ -170,6 +170,67 @@ def test_fewer_candidates_than_topk_are_all_read_and_a_tie_goes_low():
     assert np.flatnonzero(at(16 * 8)).tolist() == [0, 1, 2, 5, 7, 8]
 
 
+def _sorted_table(chosen, page_table):
+    """The table as a stable sort and a gather make it (what
+    ``sparse_decode_attention`` ran before :func:`sp.pack_chosen`): the
+    chosen pages' ids in position order, zeros behind their count."""
+    order = np.argsort(~chosen, axis=-1, kind="stable")
+    count = chosen.sum(axis=-1).astype(np.int32)
+    table = np.take_along_axis(
+        np.broadcast_to(page_table[:, None, :], chosen.shape), order,
+        axis=-1)
+    return np.where(np.arange(chosen.shape[-1]) < count[..., None], table,
+                    0), count
+
+
+def _mask(case):
+    """``chosen`` ``[B, KV, pages]`` of a named case; the two seeded ones
+    are ``sparse-steady``'s shape, 16 slots of 528 pages."""
+    b, pages = (16, 528) if case.startswith("seeded") else (3, 41)
+    chosen = np.zeros((b, KV, pages), bool)
+    if case == "everything":
+        chosen[:] = True
+    elif case == "the_first_page":
+        chosen[..., 0] = True
+    elif case == "the_last_page":
+        chosen[..., -1] = True
+    elif case == "alternating":
+        chosen[..., ::2] = True
+        chosen[1] = ~chosen[1]
+    elif case == "a_group_its_own":
+        chosen[:, 0, 3:9] = True
+        chosen[:, 1, [0, 7, 40]] = True
+    elif case == "an_idle_row_beside_a_live_one":
+        chosen[1, :, [0, 5, 6, 30]] = True
+    elif case == "a_dense_row_beside_a_selecting_one":
+        chosen[0, :, :29] = True                  # everything visible
+        chosen[2, :, [0, 4, 9, 17, 18, 19]] = True
+    elif case.startswith("seeded"):
+        rng = np.random.default_rng(int(case[-1]))
+        chosen = rng.random((b, KV, pages)) < rng.random((b, 1, 1))
+    else:
+        assert case == "nothing"
+    return chosen
+
+
+@pytest.mark.parametrize("case", [
+    "nothing", "everything", "the_first_page", "the_last_page",
+    "alternating", "a_group_its_own", "an_idle_row_beside_a_live_one",
+    "a_dense_row_beside_a_selecting_one", "seeded_0", "seeded_1"])
+def test_the_running_count_packs_the_table_a_stable_sort_would(case):
+    chosen = _mask(case)
+    b, _, pages = chosen.shape
+    # ids as a pool's: shuffled, none of them the scratch block 0
+    page_table = (np.random.default_rng(5).permutation(b * pages) + 1
+                  ).astype(np.int32).reshape(b, pages)
+    want_table, want_count = _sorted_table(chosen, page_table)
+    table, count = sp.pack_chosen(jnp.asarray(chosen),
+                                  jnp.asarray(page_table))
+    assert table.dtype == jnp.int32 and count.dtype == jnp.int32
+    assert (np.asarray(count) == want_count).all()
+    assert (np.asarray(table) == want_table).all()
+
+
 @pytest.mark.parametrize("kernel", ["lax", "pallas"])
 def test_the_decode_read_reads_the_chosen_pages_and_no_other(kernel):
     pos = 175
