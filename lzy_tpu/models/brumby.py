@@ -49,6 +49,7 @@ from lzy_tpu.models.llama import RMSNorm, _rope
 from lzy_tpu.models.minicpm_sala import GatedMlp, HeadNorm, _sow_counts
 from lzy_tpu.models.paged_blocks import dense, into_heads, normal
 from lzy_tpu.ops import power_retention as retention
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 RETENTION_ROWS = REGISTRY.counter(
@@ -215,14 +216,16 @@ class PowerRetention(nn.Module):
         h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         f32 = jnp.float32
         # float32 out of the accumulator: the norms a head read it
-        q = into_heads(dense(h * d, "q_proj", cfg, f32)(u), b, t, h, d)
-        k = into_heads(dense(kv * d, "k_proj", cfg, f32)(u), b, t, kv, d)
-        v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
-        # one decay a key-value head: the group's query heads read one state
-        log_g = jax.nn.log_sigmoid(
-            dense(kv, "g_proj", cfg, f32)(u) + cfg.gate_bias)
-        q = HeadNorm(cfg.norm_eps, name="q_norm")(q)
-        k = HeadNorm(cfg.norm_eps, name="k_norm")(k)
+        with trace.part(trace.PROJ):
+            q = into_heads(dense(h * d, "q_proj", cfg, f32)(u), b, t, h, d)
+            k = into_heads(dense(kv * d, "k_proj", cfg, f32)(u), b, t, kv, d)
+            v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
+            # one decay a key-value head: the group's query heads read one
+            # state
+            log_g = jax.nn.log_sigmoid(
+                dense(kv, "g_proj", cfg, f32)(u) + cfg.gate_bias)
+            q = HeadNorm(cfg.norm_eps, name="q_norm")(q)
+            k = HeadNorm(cfg.norm_eps, name="k_norm")(k)
         cached = cfg.decode_paged
         s_shape, z_shape = retention.state_shapes(b, kv, d)
         if cached:
@@ -236,27 +239,30 @@ class PowerRetention(nn.Module):
         else:
             start = jnp.zeros((b,), jnp.int32)
             carried = (jnp.zeros(s_shape, f32), jnp.zeros(z_shape, f32))
-        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        # the products take q, k and v rounded to the activations' type
-        q = _rope(q, pos, cfg.rope_theta).astype(cfg.dtype)
-        k = _rope(k, pos, cfg.rope_theta).astype(cfg.dtype)
-        real = row_mask(valid_len, b, t)                         # [B, T]
-        if cached and t == 1 and not self.is_initializing():
-            y, *new = retention.retention_state_update(
-                *carried, q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], real[:, 0],
-                eps=cfg.retention_eps)
-            y = y[:, None]
-            self._count(real[:, 0])
-        else:
-            y, *new = retention.retention_chunk_scan(
-                q, k, v, log_g, *carried, real, chunk=cfg.chunk_size,
-                eps=cfg.retention_eps)
-        if cached and not self.is_initializing():
-            s.value, z.value = new
-            index.value = index.value + t
+        with trace.part(trace.PROJ):
+            pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+            # the products take q, k and v rounded to the activations' type
+            q = _rope(q, pos, cfg.rope_theta).astype(cfg.dtype)
+            k = _rope(k, pos, cfg.rope_theta).astype(cfg.dtype)
+        with trace.part(trace.STATE):
+            real = row_mask(valid_len, b, t)                         # [B, T]
+            if cached and t == 1 and not self.is_initializing():
+                y, *new = retention.retention_state_update(
+                    *carried, q[:, 0], k[:, 0], v[:, 0], log_g[:, 0],
+                    real[:, 0], eps=cfg.retention_eps)
+                y = y[:, None]
+                self._count(real[:, 0])
+            else:
+                y, *new = retention.retention_chunk_scan(
+                    q, k, v, log_g, *carried, real, chunk=cfg.chunk_size,
+                    eps=cfg.retention_eps)
+            if cached and not self.is_initializing():
+                s.value, z.value = new
+                index.value = index.value + t
         # float32 out of the accumulator: it joins the residual stream
-        return dense(cfg.d_model, "o_proj", cfg, f32)(
-            y.astype(cfg.dtype).reshape(b, t, h * d))
+        with trace.part(trace.PROJ):
+            return dense(cfg.d_model, "o_proj", cfg, f32)(
+                y.astype(cfg.dtype).reshape(b, t, h * d))
 
     def _count(self, live):
         at, of = self.stats
@@ -281,21 +287,27 @@ class Brumby(nn.Module):
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
         # the stream is float32: 2 x layers sums in bfloat16 would round it
         # as many times (the products take it rounded to their type)
-        x = emb.astype(cfg.dtype)[tokens].astype(jnp.float32)
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens].astype(jnp.float32)
         for i in range(cfg.n_layers):
             u = RMSNorm(cfg.norm_eps, cfg.param_dtype,
                         name=f"layer_{i}_norm")(x).astype(cfg.dtype)
-            x = x + PowerRetention(cfg, (0, len(self.STATS)),
-                                   name=f"layer_{i}")(u, valid_len)
+            y = PowerRetention(cfg, (0, len(self.STATS)),
+                               name=f"layer_{i}")(u, valid_len)
+            # a residual sum is filed with the block it closes
+            with trace.part(trace.PROJ):
+                x = x + y
             u = RMSNorm(cfg.norm_eps, cfg.param_dtype,
                         name=f"layer_{i}_mlp_norm")(x).astype(cfg.dtype)
-            x = x + GatedMlp(cfg, name=f"layer_{i}_mlp")(u)
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
-        head = self.param("lm_head", normal(0.02),
-                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                          head.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+            with trace.part(trace.FFN):
+                x = x + GatedMlp(cfg, name=f"layer_{i}_mlp")(u)
+        with trace.part(trace.HEAD):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+            head = self.param("lm_head", normal(0.02),
+                              (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: BrumbyConfig, rng: jax.Array):

@@ -64,6 +64,7 @@ from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops.paged_attention import (
     group_path, lower_group_for_tpu, paged_group_attention,
     paged_scatter_index)
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 ATTN_WINDOW_KEYS = REGISTRY.counter(
@@ -297,6 +298,7 @@ class LayerNorm(nn.Module):
     param_dtype: Any
 
     @nn.compact
+    @trace.part(trace.NORM)
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            self.param_dtype)
@@ -341,9 +343,11 @@ class GroupAttention(nn.Module):
         b, t, _ = u.shape
         h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         window = cfg.window if self.windowed else None
-        q = HeadMajorLinear(h * d, cfg, name="q_proj")(u).reshape(b, t, h, d)
-        k = dense(kv * d, "k_proj", cfg)(u).reshape(b, t, kv, d)
-        v = dense(kv * d, "v_proj", cfg)(u)
+        with trace.part(trace.PROJ):
+            q = HeadMajorLinear(h * d, cfg, name="q_proj")(u).reshape(
+                b, t, h, d)
+            k = dense(kv * d, "k_proj", cfg)(u).reshape(b, t, kv, d)
+            v = dense(kv * d, "v_proj", cfg)(u)
         if cfg.decode_paged:
             pages = cfg.window_pages if self.windowed else cfg.kv_pages
             names = ("wk", "wv") if self.windowed else ("k", "v")
@@ -357,55 +361,60 @@ class GroupAttention(nn.Module):
             start = index.value
         else:
             start = jnp.zeros((b,), jnp.int32)
-        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        if self.windowed:
-            q, k = _rope(q, pos, cfg.rope_theta), _rope(k, pos,
-                                                        cfg.rope_theta)
+        with trace.part(trace.PROJ):
+            pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+            if self.windowed:
+                q, k = _rope(q, pos, cfg.rope_theta), _rope(k, pos,
+                                                            cfg.rope_theta)
         if not cfg.decode_paged:
-            qg = q.reshape(b, t, kv, h // kv, d)
-            s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
-                           preferred_element_type=jnp.float32) * d ** -0.5
-            at = jnp.arange(t)
-            keep = at[:, None] >= at[None, :]
-            if window is not None:
-                keep &= at[None, :] > at[:, None] - window
-            pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
-            out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype),
-                             v.reshape(b, t, kv, d))
+            with trace.part(trace.ATTN_READ):
+                qg = q.reshape(b, t, kv, h // kv, d)
+                s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
+                               preferred_element_type=jnp.float32) * d ** -0.5
+                at = jnp.arange(t)
+                keep = at[:, None] >= at[None, :]
+                if window is not None:
+                    keep &= at[None, :] > at[:, None] - window
+                pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+                out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype),
+                                 v.reshape(b, t, kv, d))
         else:
             real = row_mask(valid_len, b, t)
             if not self.is_initializing():
                 if page_table is None:
                     raise ValueError("a paged forward needs its page table")
-                rows, offs = paged_scatter_index(page_table, pos,
-                                                 cfg.kv_page_size)
-                pool_k.value = pool_k.value.at[rows, offs].set(
-                    k.astype(cfg.dtype).reshape(b * t, kv * d))
-                pool_v.value = pool_v.value.at[rows, offs].set(
-                    v.astype(cfg.dtype).reshape(b * t, kv * d))
-                index.value = index.value + t
+                with trace.part(trace.CACHE_WRITE):
+                    rows, offs = paged_scatter_index(page_table, pos,
+                                                     cfg.kv_page_size)
+                    pool_k.value = pool_k.value.at[rows, offs].set(
+                        k.astype(cfg.dtype).reshape(b * t, kv * d))
+                    pool_v.value = pool_v.value.at[rows, offs].set(
+                        v.astype(cfg.dtype).reshape(b * t, kv * d))
+                    index.value = index.value + t
             # an idle slot (no real position) reads one page, whatever its
             # stale position says
             out = paged_group_attention(
                 q, pool_k.value, pool_v.value, page_table,
                 jnp.where(real[:, 0], start, 0), window=window,
                 kernel=cfg.paged_kernel)
-            # the last real query of a row at position p reads p + 1 keys,
-            # or the window's worth of them
-            seen = jnp.where(real[:, 0], start + jnp.sum(real, axis=1), 0)
-            if window is not None:
-                seen = jnp.minimum(seen, window)
-            keys = jnp.sum(seen)
-            by_kind = [keys, 0] if self.windowed else [0, keys]
-            other = len(experts.STATS)
-            self.sow("stats", "attn", jnp.concatenate([
-                jnp.zeros((other,), jnp.int32),
-                jnp.stack([*map(jnp.asarray, by_kind),
-                           jnp.sum(real[:, 0])]).astype(jnp.int32)]),
-                reduce_fn=lambda a, x: a + x,
-                init_fn=lambda: jnp.zeros((other + 3,), jnp.int32))
-        return dense(cfg.d_model, "o_proj", cfg)(
-            out.astype(cfg.dtype).reshape(b, t, h * d))
+            with trace.part(trace.ATTN_READ):
+                # the last real query of a row at position p reads p + 1 keys,
+                # or the window's worth of them
+                seen = jnp.where(real[:, 0], start + jnp.sum(real, axis=1), 0)
+                if window is not None:
+                    seen = jnp.minimum(seen, window)
+                keys = jnp.sum(seen)
+                by_kind = [keys, 0] if self.windowed else [0, keys]
+                other = len(experts.STATS)
+                self.sow("stats", "attn", jnp.concatenate([
+                    jnp.zeros((other,), jnp.int32),
+                    jnp.stack([*map(jnp.asarray, by_kind),
+                               jnp.sum(real[:, 0])]).astype(jnp.int32)]),
+                    reduce_fn=lambda a, x: a + x,
+                    init_fn=lambda: jnp.zeros((other + 3,), jnp.int32))
+        with trace.part(trace.PROJ):
+            return dense(cfg.d_model, "o_proj", cfg)(
+                out.astype(cfg.dtype).reshape(b, t, h * d))
 
 
 class Cohere2Moe(nn.Module):
@@ -423,7 +432,8 @@ class Cohere2Moe(nn.Module):
         cfg = self.cfg
         emb = self.param("embed_tokens", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens]
         for i, kind in enumerate(cfg.layer_types):
             windowed = kind == SLIDING
             u = LayerNorm(cfg.norm_eps, cfg.param_dtype,
@@ -432,13 +442,16 @@ class Cohere2Moe(nn.Module):
                 u, window_table if windowed else page_table, valid_len)
             m = GatedExperts(cfg, other_stats=3, name=f"layer_{i}_moe")(
                 u, valid_len)
-            x = x + a + m
-        x = LayerNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
-        logits = jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                            emb.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        return logits if cfg.logit_scale == 1.0 \
-            else logits * cfg.logit_scale
+            # the parallel block's sum is filed with the larger summand
+            with trace.part(trace.EXPERTS):
+                x = x + a + m
+        with trace.part(trace.HEAD):
+            x = LayerNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+            logits = jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                                emb.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+            return logits if cfg.logit_scale == 1.0 \
+                else logits * cfg.logit_scale
 
 
 def init_params(cfg: Cohere2MoeConfig, rng: jax.Array):

@@ -8,7 +8,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from lzy_tpu.utils import trace
 
+
+@trace.part(trace.LOSS)
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        mask: Optional[jax.Array] = None) -> jax.Array:
     """Token-level CE in float32 regardless of compute dtype (numerics)."""

@@ -64,6 +64,7 @@ from lzy_tpu.models.llama import RMSNorm, _rope
 from lzy_tpu.models.paged_blocks import dense, into_heads, normal
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import mla
+from lzy_tpu.utils import trace
 from lzy_tpu.ops.paged_attention import paged_scatter_index
 from lzy_tpu.utils.metrics import REGISTRY
 
@@ -300,14 +301,16 @@ class LatentAttention(nn.Module):
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
         w = cfg.latent_width
-        q = into_heads(dense(h * (dn + dr), "q_proj", cfg)(u),
-                       b, t, h, dn + dr)
-        kva = dense(r + dr, "kv_a_proj", cfg)(u)
-        c = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="kv_a_norm")(
-            kva[..., :r])
-        # [rank, head, nope + value]: the keys' and the values' up-projection
-        w_kvb = self.param("kv_b_proj", normal(), (r, h, dn + dv),
-                           cfg.param_dtype).astype(cfg.dtype)
+        with trace.part(trace.PROJ):
+            q = into_heads(dense(h * (dn + dr), "q_proj", cfg)(u),
+                           b, t, h, dn + dr)
+            kva = dense(r + dr, "kv_a_proj", cfg)(u)
+            c = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="kv_a_norm")(
+                kva[..., :r])
+            # [rank, head, nope + value]: the keys' and the values'
+            # up-projection
+            w_kvb = self.param("kv_b_proj", normal(), (r, h, dn + dv),
+                               cfg.param_dtype).astype(cfg.dtype)
 
         cached = cfg.decode_paged
         if cached:
@@ -319,19 +322,20 @@ class LatentAttention(nn.Module):
             start = index.value
         else:
             start = jnp.zeros((b,), jnp.int32)
-        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
-        k_rope = _rope(kva[:, :, None, r:], pos, cfg.rope_theta)[:, :, 0]
-        # absorb the keys' up-projection into the query
-        q_abs = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_kvb[..., :dn],
-                           preferred_element_type=jnp.float32)
-        pad = w - r - dr
-        q_full = jnp.concatenate(
-            [q_abs.astype(cfg.dtype), q_rope.astype(cfg.dtype),
-             jnp.zeros((b, t, h, pad), cfg.dtype)], axis=-1)
-        lat = jnp.concatenate(
-            [c.astype(cfg.dtype), k_rope.astype(cfg.dtype),
-             jnp.zeros((b, t, pad), cfg.dtype)], axis=-1)         # [B, T, W]
+        with trace.part(trace.PROJ):
+            pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+            q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
+            k_rope = _rope(kva[:, :, None, r:], pos, cfg.rope_theta)[:, :, 0]
+            # absorb the keys' up-projection into the query
+            q_abs = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_kvb[..., :dn],
+                               preferred_element_type=jnp.float32)
+            pad = w - r - dr
+            q_full = jnp.concatenate(
+                [q_abs.astype(cfg.dtype), q_rope.astype(cfg.dtype),
+                 jnp.zeros((b, t, h, pad), cfg.dtype)], axis=-1)
+            lat = jnp.concatenate(
+                [c.astype(cfg.dtype), k_rope.astype(cfg.dtype),
+                 jnp.zeros((b, t, pad), cfg.dtype)], axis=-1)       # [B, T, W]
 
         if not cached:
             summed = mla.causal_mla_attention(
@@ -341,37 +345,41 @@ class LatentAttention(nn.Module):
             if not self.is_initializing():
                 if page_table is None:
                     raise ValueError("a paged forward needs page_table")
-                rows, offs = paged_scatter_index(page_table, pos,
-                                                 cfg.kv_page_size)
-                pool.value = pool.value.at[rows, offs].set(
-                    lat.reshape(b * t, w))
-                index.value = index.value + t
+                with trace.part(trace.CACHE_WRITE):
+                    rows, offs = paged_scatter_index(page_table, pos,
+                                                     cfg.kv_page_size)
+                    pool.value = pool.value.at[rows, offs].set(
+                        lat.reshape(b * t, w))
+                    index.value = index.value + t
             # an idle slot (no real position) is told so, whatever its stale
             # position says: the read skips it and gives it 0
             summed = mla.mla_attention(
                 q_full, pool.value, page_table,
                 jnp.where(real[:, 0], start, -1), value_dim=r,
                 scale=cfg.softmax_scale, kernel=cfg.paged_kernel)
-            # the last real query of a row at position p reads p + 1
-            last = pos[:, 0] + jnp.sum(real, axis=1)
-            other = len(experts.STATS)
-            self.sow("stats", "mla", jnp.concatenate([
-                jnp.zeros((other,), jnp.int32),
-                jnp.stack([jnp.sum(jnp.where(real[:, 0], last, 0)),
-                           jnp.sum(real[:, 0])]).astype(jnp.int32)]),
-                reduce_fn=lambda a, x: a + x,
-                init_fn=lambda: jnp.zeros((other + 2,), jnp.int32))
-        out = jnp.einsum("bthr,rhv->bthv", summed.astype(cfg.dtype),
-                         w_kvb[..., dn:],
-                         preferred_element_type=jnp.float32)
-        return dense(cfg.d_model, "o_proj", cfg)(
-            out.astype(cfg.dtype).reshape(b, t, h * dv))
+            with trace.part(trace.ATTN_READ):
+                # the last real query of a row at position p reads p + 1
+                last = pos[:, 0] + jnp.sum(real, axis=1)
+                other = len(experts.STATS)
+                self.sow("stats", "mla", jnp.concatenate([
+                    jnp.zeros((other,), jnp.int32),
+                    jnp.stack([jnp.sum(jnp.where(real[:, 0], last, 0)),
+                               jnp.sum(real[:, 0])]).astype(jnp.int32)]),
+                    reduce_fn=lambda a, x: a + x,
+                    init_fn=lambda: jnp.zeros((other + 2,), jnp.int32))
+        with trace.part(trace.PROJ):
+            out = jnp.einsum("bthr,rhv->bthv", summed.astype(cfg.dtype),
+                             w_kvb[..., dn:],
+                             preferred_element_type=jnp.float32)
+            return dense(cfg.d_model, "o_proj", cfg)(
+                out.astype(cfg.dtype).reshape(b, t, h * dv))
 
 
 class GatedMlp(nn.Module):
     cfg: DeepseekV3Config
 
     @nn.compact
+    @trace.part(trace.FFN)
     def __call__(self, u):
         cfg = self.cfg
         f32 = jnp.float32
@@ -393,26 +401,34 @@ class DeepseekV3(nn.Module):
         cfg = self.cfg
         emb = self.param("embed_tokens", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens]
 
         def norm(name):
             return RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)
 
         for i in range(cfg.n_layers):
-            x = x + LatentAttention(cfg, name=f"layer_{i}")(
+            y = LatentAttention(cfg, name=f"layer_{i}")(
                 norm(f"layer_{i}_norm")(x), page_table, valid_len)
+            # a residual sum is filed with the block it closes
+            with trace.part(trace.PROJ):
+                x = x + y
             u = norm(f"layer_{i}_ffn_norm")(x)
             if i < cfg.first_dense:
-                x = x + GatedMlp(cfg, name=f"layer_{i}_mlp")(u)
+                with trace.part(trace.FFN):
+                    x = x + GatedMlp(cfg, name=f"layer_{i}_mlp")(u)
             else:
-                x = x + GatedExperts(cfg, other_stats=2,
-                                     name=f"layer_{i}_moe")(u, valid_len)
-        x = norm("final_norm")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                          head.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+                y = GatedExperts(cfg, other_stats=2,
+                                 name=f"layer_{i}_moe")(u, valid_len)
+                with trace.part(trace.EXPERTS):
+                    x = x + y
+        with trace.part(trace.HEAD):
+            x = norm("final_norm")(x)
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: DeepseekV3Config, rng: jax.Array):

@@ -74,6 +74,7 @@ from lzy_tpu.models.paged_blocks import dense, into_heads, normal
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import latent_select as lsel
 from lzy_tpu.ops.paged_attention import paged_scatter_index
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 LATENT_VISIBLE = REGISTRY.counter(
@@ -494,16 +495,18 @@ class LatentAttention(nn.Module):
                 y = y * (cfg.d_model / rank) ** 0.5
             return y.astype(cfg.dtype)
 
-        c_q = latent(dense(k.q_rank, "q_a_proj", cfg, f32)(u), "q_a_norm",
-                     k.q_rank)
-        q = into_heads(dense(h * (dn + dr), "q_b_proj", cfg)(c_q),
-                       b, t, h, dn + dr)
-        kva = dense(r + dr, "kv_a_proj", cfg, f32)(u)
-        c = latent(kva[..., :r], "kv_a_norm", r)
-        # [rank, head, nope + value]: the keys' and the values' up-projection
-        w_kvb = self.param("kv_b_proj", normal(), (r, h, dn + dv),
-                           cfg.param_dtype).astype(cfg.dtype)
-        gate = jax.nn.sigmoid(dense(h, "gate_proj", cfg, f32)(u))
+        with trace.part(trace.PROJ):
+            c_q = latent(dense(k.q_rank, "q_a_proj", cfg, f32)(u), "q_a_norm",
+                         k.q_rank)
+            q = into_heads(dense(h * (dn + dr), "q_b_proj", cfg)(c_q),
+                           b, t, h, dn + dr)
+            kva = dense(r + dr, "kv_a_proj", cfg, f32)(u)
+            c = latent(kva[..., :r], "kv_a_norm", r)
+            # [rank, head, nope + value]: the keys' and the values'
+            # up-projection
+            w_kvb = self.param("kv_b_proj", normal(), (r, h, dn + dv),
+                               cfg.param_dtype).astype(cfg.dtype)
+            gate = jax.nn.sigmoid(dense(h, "gate_proj", cfg, f32)(u))
 
         cached = cfg.decode_paged
         if cached:
@@ -516,31 +519,34 @@ class LatentAttention(nn.Module):
             start = index.value
         else:
             start = jnp.zeros((b,), jnp.int32)
-        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        q_rope = _rope(q[..., dn:], pos, k.theta)
-        k_rope = _rope(kva[:, :, None, r:], pos, k.theta)[:, :, 0]
-        # absorb the keys' up-projection into the query
-        q_abs = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_kvb[..., :dn],
-                           preferred_element_type=f32)
-        pad = w - r - dr
-        q_full = jnp.concatenate(
-            [q_abs.astype(cfg.dtype), q_rope.astype(cfg.dtype),
-             jnp.zeros((b, t, h, pad), cfg.dtype)], axis=-1)
-        lat = jnp.concatenate(
-            [c.astype(cfg.dtype), k_rope.astype(cfg.dtype),
-             jnp.zeros((b, t, pad), cfg.dtype)], axis=-1)         # [B, T, W]
+        with trace.part(trace.PROJ):
+            pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+            q_rope = _rope(q[..., dn:], pos, k.theta)
+            k_rope = _rope(kva[:, :, None, r:], pos, k.theta)[:, :, 0]
+            # absorb the keys' up-projection into the query
+            q_abs = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_kvb[..., :dn],
+                               preferred_element_type=f32)
+            pad = w - r - dr
+            q_full = jnp.concatenate(
+                [q_abs.astype(cfg.dtype), q_rope.astype(cfg.dtype),
+                 jnp.zeros((b, t, h, pad), cfg.dtype)], axis=-1)
+            lat = jnp.concatenate(
+                [c.astype(cfg.dtype), k_rope.astype(cfg.dtype),
+                 jnp.zeros((b, t, pad), cfg.dtype)], axis=-1)       # [B, T, W]
 
         if not self.windowed:
             # the indexer: queries from c_q, one key a token from u, head
             # weights from u; head-major queries, as the kernels take them
-            j, di = cfg.index_n_heads, cfg.index_head_dim
-            qi = into_heads(dense(j * di, "index_q_proj", cfg)(c_q),
-                            b, t, j, di)
-            qi = _rope_head(qi, pos, k.theta, dr).transpose(0, 2, 1, 3)
-            ki = BiasedLayerNorm(1e-6, cfg.param_dtype, name="index_k_norm")(
-                dense(di, "index_k_proj", cfg)(u))
-            ki = _rope_head(ki[:, :, None], pos, k.theta, dr)[:, :, 0]
-            wi = dense(j, "index_w_proj", cfg, f32)(u)
+            with trace.part(trace.PROJ):
+                j, di = cfg.index_n_heads, cfg.index_head_dim
+                qi = into_heads(dense(j * di, "index_q_proj", cfg)(c_q),
+                                b, t, j, di)
+                qi = _rope_head(qi, pos, k.theta, dr).transpose(0, 2, 1, 3)
+                ki = BiasedLayerNorm(1e-6, cfg.param_dtype,
+                                     name="index_k_norm")(
+                    dense(di, "index_k_proj", cfg)(u))
+                ki = _rope_head(ki[:, :, None], pos, k.theta, dr)[:, :, 0]
+                wi = dense(j, "index_w_proj", cfg, f32)(u)
 
         if not cached:
             if self.windowed:
@@ -550,12 +556,13 @@ class LatentAttention(nn.Module):
             else:
                 scores = None
                 if t > cfg.index_topk:
-                    scores = jnp.einsum(
-                        "bjtl,btj->btl", jnp.maximum(jnp.einsum(
-                            "bjtd,bld->bjtl", qi.astype(cfg.dtype),
-                            ki.astype(cfg.dtype),
-                            preferred_element_type=f32), 0.0), wi,
-                        precision=jax.lax.Precision.HIGHEST)
+                    with trace.part(trace.ATTN_READ):
+                        scores = jnp.einsum(
+                            "bjtl,btj->btl", jnp.maximum(jnp.einsum(
+                                "bjtd,bld->bjtl", qi.astype(cfg.dtype),
+                                ki.astype(cfg.dtype),
+                                preferred_element_type=f32), 0.0), wi,
+                            precision=jax.lax.Precision.HIGHEST)
                 summed = lsel.causal_latent_attention(
                     q_full, lat, value_dim=r, scale=k.softmax_scale,
                     scores=scores, topk=cfg.index_topk)
@@ -564,22 +571,22 @@ class LatentAttention(nn.Module):
             if not self.is_initializing():
                 if page_table is None:
                     raise ValueError("a paged forward needs its page table")
-                rows, offs = paged_scatter_index(page_table, pos,
-                                                 cfg.kv_page_size)
-                pool.value = pool.value.at[rows, offs].set(
-                    lat.reshape(b * t, w))
-                index.value = index.value + t
+                with trace.part(trace.CACHE_WRITE):
+                    rows, offs = paged_scatter_index(page_table, pos,
+                                                     cfg.kv_page_size)
+                    pool.value = pool.value.at[rows, offs].set(
+                        lat.reshape(b * t, w))
+                    index.value = index.value + t
             # an idle slot (no real position) is told so, whatever its stale
             # position says: the reads skip it and give it 0
             live = jnp.where(real[:, 0], start, -1)
             seen = jnp.where(real[:, 0], start + jnp.sum(real, axis=1), 0)
             n_rows = jnp.sum(real[:, 0])
             if self.windowed:
-                with jax.named_scope("latent_window_read"):
-                    summed = lsel.latent_window_attention(
-                        q_full, pool.value, page_table, live,
-                        window=cfg.window, value_dim=r,
-                        scale=k.softmax_scale, kernel=cfg.paged_kernel)
+                summed = lsel.latent_window_attention(
+                    q_full, pool.value, page_table, live,
+                    window=cfg.window, value_dim=r,
+                    scale=k.softmax_scale, kernel=cfg.paged_kernel)
                 counts = [0, 0, 0, 0,
                           jnp.sum(jnp.minimum(seen, cfg.window)), 0]
             else:
@@ -589,42 +596,47 @@ class LatentAttention(nn.Module):
                     "cache", "ik", jnp.zeros,
                     (cfg.kv_pages, cfg.kv_page_size, di + pad_i), cfg.dtype)
                 if not self.is_initializing():
-                    ik_pool.value = ik_pool.value.at[rows, offs].set(
-                        jnp.pad(ki.astype(cfg.dtype).reshape(b * t, di),
-                                ((0, 0), (0, pad_i))))
-                qi = jnp.pad(qi, ((0, 0),) * 3 + ((0, pad_i),))
-                with jax.named_scope("latent_index"):
+                    with trace.part(trace.CACHE_WRITE):
+                        ik_pool.value = ik_pool.value.at[rows, offs].set(
+                            jnp.pad(ki.astype(cfg.dtype).reshape(b * t, di),
+                                    ((0, 0), (0, pad_i))))
+                # the four steps name themselves (``ops/latent_select.py``:
+                # latent_index, latent_choice, latent_gather and
+                # latent_chosen_read; latent_window_read above)
+                with trace.part(trace.LATENT_INDEX):
+                    qi = jnp.pad(qi, ((0, 0),) * 3 + ((0, pad_i),))
                     scores = lsel.index_scores(
                         qi, wi, ik_pool.value, page_table, live,
                         topk=cfg.index_topk, kernel=cfg.paged_kernel)
-                with jax.named_scope("latent_choice"):
-                    idx, n = lsel.latent_topk(
-                        scores, jnp.where(real, pos, -1), cfg.index_topk,
-                        kernel=cfg.paged_kernel)
+                idx, n = lsel.latent_topk(
+                    scores, jnp.where(real, pos, -1), cfg.index_topk,
+                    kernel=cfg.paged_kernel)
                 # for whoever asks (``mutable=["choices"]``): what every
                 # query chose, and how many of them
                 self.sow("choices", "chosen", (idx, n))
-                with jax.named_scope("latent_chosen_read"):
-                    summed = lsel.latent_chosen_attention(
-                        q_full, pool.value, page_table, idx, n,
-                        value_dim=r, scale=k.softmax_scale,
-                        kernel=cfg.paged_kernel)
+                summed = lsel.latent_chosen_attention(
+                    q_full, pool.value, page_table, idx, n,
+                    value_dim=r, scale=k.softmax_scale,
+                    kernel=cfg.paged_kernel)
                 selects = real[:, 0] & (seen > cfg.index_topk)
                 counts = [jnp.sum(seen),
                           jnp.sum(jnp.minimum(seen, cfg.index_topk)),
                           jnp.sum(selects), n_rows - jnp.sum(selects), 0,
                           n_rows]
-            other = len(experts.STATS)
-            self.sow("stats", "latent", jnp.concatenate([
-                jnp.zeros((other,), jnp.int32),
-                jnp.stack([*map(jnp.asarray, counts)]).astype(jnp.int32)]),
-                reduce_fn=lambda a, x: a + x,
-                init_fn=lambda: jnp.zeros((other + _OWN_STATS,), jnp.int32))
-        out = jnp.einsum("bthr,rhv->bthv", summed.astype(cfg.dtype),
-                         w_kvb[..., dn:], preferred_element_type=f32)
-        out = out * gate[..., None]
-        return dense(cfg.d_model, "o_proj", cfg)(
-            out.astype(cfg.dtype).reshape(b, t, h * dv))
+            with trace.part(trace.ATTN_READ):
+                other = len(experts.STATS)
+                self.sow("stats", "latent", jnp.concatenate([
+                    jnp.zeros((other,), jnp.int32),
+                    jnp.stack([*map(jnp.asarray, counts)]).astype(jnp.int32)]),
+                    reduce_fn=lambda a, x: a + x,
+                    init_fn=lambda: jnp.zeros((other + _OWN_STATS,),
+                                              jnp.int32))
+        with trace.part(trace.PROJ):
+            out = jnp.einsum("bthr,rhv->bthv", summed.astype(cfg.dtype),
+                             w_kvb[..., dn:], preferred_element_type=f32)
+            out = out * gate[..., None]
+            return dense(cfg.d_model, "o_proj", cfg)(
+                out.astype(cfg.dtype).reshape(b, t, h * dv))
 
 
 class Dots3Note(nn.Module):
@@ -644,28 +656,36 @@ class Dots3Note(nn.Module):
         cfg = self.cfg
         emb = self.param("embed_tokens", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens]
 
         def norm(name):
             return RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)
 
         for i, kind in enumerate(cfg.layer_types):
             windowed = kind == SLIDING
-            x = x + LatentAttention(cfg, windowed, name=f"layer_{i}")(
+            y = LatentAttention(cfg, windowed, name=f"layer_{i}")(
                 norm(f"layer_{i}_norm")(x),
                 window_table if windowed else page_table, valid_len)
+            # a residual sum is filed with the block it closes
+            with trace.part(trace.PROJ):
+                x = x + y
             u = norm(f"layer_{i}_ffn_norm")(x)
             if i < cfg.first_dense:
-                x = x + GatedMlp(cfg, name=f"layer_{i}_mlp")(u)
+                with trace.part(trace.FFN):
+                    x = x + GatedMlp(cfg, name=f"layer_{i}_mlp")(u)
             else:
-                x = x + GatedExperts(cfg, other_stats=_OWN_STATS,
-                                     name=f"layer_{i}_moe")(u, valid_len)
-        x = norm("final_norm")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                          head.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+                y = GatedExperts(cfg, other_stats=_OWN_STATS,
+                                 name=f"layer_{i}_moe")(u, valid_len)
+                with trace.part(trace.EXPERTS):
+                    x = x + y
+        with trace.part(trace.HEAD):
+            x = norm("final_norm")(x)
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: Dots3NoteConfig, rng: jax.Array):

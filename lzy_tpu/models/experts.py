@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from lzy_tpu.models.paged_blocks import dense, normal
 from lzy_tpu.ops import grouped_experts as gexp
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 MOE_ASSIGNMENTS = REGISTRY.counter(
@@ -140,29 +141,32 @@ class GatedExperts(nn.Module):
         m = b * t
         f32 = jnp.float32
         um = u.reshape(m, dm)
-        real = row_mask(valid_len, b, t).reshape(m)
-        scores, bias = sigmoid_scores(
-            self, um, cfg.n_routed_experts,
-            choice_bias=getattr(cfg, "router_bias", True))
-        weights = held_weights(
-            self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
-            bias=bias, scaling=cfg.routed_scaling,
-            other_stats=self.other_stats)
+        with trace.part(trace.ROUTER):
+            real = row_mask(valid_len, b, t).reshape(m)
+            scores, bias = sigmoid_scores(
+                self, um, cfg.n_routed_experts,
+                choice_bias=getattr(cfg, "router_bias", True))
+            weights = held_weights(
+                self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
+                bias=bias, scaling=cfg.routed_scaling,
+                other_stats=self.other_stats)
         up_shape = (cfg.n_held, dm, cfg.expert_width)
         wg = self.param("experts_gate", normal(), up_shape, cfg.param_dtype)
         wu = self.param("experts_up", normal(), up_shape, cfg.param_dtype)
         wd = self.param("experts_down", normal(),
                         (cfg.n_held, cfg.expert_width, dm), cfg.param_dtype)
-        if self.is_initializing():
-            routed = jnp.zeros((m, dm), f32)            # no kernel at init
-        else:
-            routed = gexp.grouped_experts(
-                um, wu.astype(cfg.dtype), wd.astype(cfg.dtype), weights,
-                gate=wg.astype(cfg.dtype))
-        hid = jax.nn.silu(dense(cfg.shared_width, "shared_gate", cfg,
-                                 f32)(um)) \
-            * dense(cfg.shared_width, "shared_up", cfg, f32)(um)
-        shared = dense(dm, "shared_down", cfg, f32)(hid.astype(cfg.dtype))
-        scale = getattr(cfg, "shared_scale", 1.0)
-        out = routed + (shared if scale == 1.0 else shared * scale)
-        return out.astype(cfg.dtype).reshape(b, t, dm)
+        with trace.part(trace.EXPERTS):
+            if self.is_initializing():
+                routed = jnp.zeros((m, dm), f32)        # no kernel at init
+            else:
+                routed = gexp.grouped_experts(
+                    um, wu.astype(cfg.dtype), wd.astype(cfg.dtype), weights,
+                    gate=wg.astype(cfg.dtype))
+            hid = jax.nn.silu(dense(cfg.shared_width, "shared_gate", cfg,
+                                     f32)(um)) \
+                * dense(cfg.shared_width, "shared_up", cfg, f32)(um)
+            shared = dense(dm, "shared_down", cfg, f32)(
+                hid.astype(cfg.dtype))
+            scale = getattr(cfg, "shared_scale", 1.0)
+            out = routed + (shared if scale == 1.0 else shared * scale)
+            return out.astype(cfg.dtype).reshape(b, t, dm)

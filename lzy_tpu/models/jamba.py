@@ -61,6 +61,7 @@ from lzy_tpu.models.paged_blocks import (
 from lzy_tpu.models.serving import HeadPool
 from lzy_tpu.ops import mamba1
 from lzy_tpu.ops.paged_attention import group_path, lower_group_for_tpu
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 SSM_ROWS = REGISTRY.counter(
@@ -270,11 +271,12 @@ class Mamba1Mixer(nn.Module):
                        cfg.conv_kernel)
         f32 = jnp.float32
 
-        xz = dense(2 * di, "in_proj", cfg)(u)
-        # the convolution's inputs are kept a row (the conv state), in the
-        # activations' dtype: round them before use, in prefill and decode
-        xs = xz[..., :di].astype(cfg.dtype)
-        z = xz[..., di:].astype(f32)
+        with trace.part(trace.PROJ):
+            xz = dense(2 * di, "in_proj", cfg)(u)
+            # the convolution's inputs are kept a row (the conv state), in the
+            # activations' dtype: round them before use, in prefill and decode
+            xs = xz[..., :di].astype(cfg.dtype)
+            z = xz[..., di:].astype(f32)
 
         # a depthwise convolution of K taps starts uniform in
         # +-1 / sqrt(K), weight and bias (the published Mamba's Conv1d)
@@ -304,52 +306,59 @@ class Mamba1Mixer(nn.Module):
             prev = jnp.zeros((b, k - 1, di), cfg.dtype)
             state = jnp.zeros((b, n, di), f32)
 
-        real = row_mask(valid_len, b, t)                         # [B, T]
-        seq = jnp.concatenate([prev, xs], axis=1)                # [B, T+k-1]
-        xc = jax.nn.silu(conv_b + sum(
-            conv_w[i] * seq[:, i:i + t].astype(f32) for i in range(k)))
+        with trace.part(trace.STATE):
+            real = row_mask(valid_len, b, t)                         # [B, T]
+            seq = jnp.concatenate([prev, xs], axis=1)              # [B, T+k-1]
+            xc = jax.nn.silu(conv_b + sum(
+                conv_w[i] * seq[:, i:i + t].astype(f32) for i in range(k)))
 
         # float32 out of the accumulator: dt steers an exponential, and B
         # and C weigh a state carried over thousands of positions
-        dbc = dense(r + 2 * n, "x_proj", cfg, f32)(xc)
-        norm = lambda name: RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)
-        dt_r = norm("dt_norm")(dbc[..., :r])
-        bm = norm("b_norm")(dbc[..., r:r + n])
-        cm = norm("c_norm")(dbc[..., r + n:])
-        dt = jnp.where(
-            real[..., None],
-            jax.nn.softplus(dense(di, "dt_proj", cfg, f32)(dt_r) + dt_bias),
-            0.0)                                                 # [B, T, Di]
-        a = -jnp.exp(a_log)
+        with trace.part(trace.PROJ):
+            dbc = dense(r + 2 * n, "x_proj", cfg, f32)(xc)
+            norm = lambda name: RMSNorm(cfg.norm_eps, cfg.param_dtype,
+                                        name=name)
+            dt_r = norm("dt_norm")(dbc[..., :r])
+            bm = norm("b_norm")(dbc[..., r:r + n])
+            cm = norm("c_norm")(dbc[..., r + n:])
+            dt = jnp.where(
+                real[..., None],
+                jax.nn.softplus(
+                    dense(di, "dt_proj", cfg, f32)(dt_r) + dt_bias),
+                0.0)                                               # [B, T, Di]
+            a = -jnp.exp(a_log)
 
-        if cached and t == 1:
-            y, new_state = mamba1.selective_state_update(
-                state, xc[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
-            y = y[:, None]
-        else:
-            # a served chunk through the kernel (on the CPU under the
-            # interpreter, as the update is); the uncached forward by lax
-            y, new_state = mamba1.selective_scan(
-                xc, dt, a, bm, cm, state,
-                kernel="pallas" if cached else "lax")
-        if cached and not self.is_initializing():
-            ssm_state.value = new_state
-            # the window that ends at the last real position
-            ends = jnp.full((b,), t, jnp.int32) if valid_len is None \
-                else valid_len.astype(jnp.int32)
-            conv_state.value = jax.vmap(
-                lambda s, e: jax.lax.dynamic_slice_in_dim(s, e, k - 1, 0)
-            )(seq, ends)
-            at, of = self.stats
-            self.sow("stats", "ssm",
-                     jnp.zeros((of,), jnp.int32).at[at].set(
-                         jnp.sum(real[:, 0])),
-                     reduce_fn=lambda acc, x: acc + x,
-                     init_fn=lambda: jnp.zeros((of,), jnp.int32))
+        with trace.part(trace.STATE):
+            if cached and t == 1:
+                y, new_state = mamba1.selective_state_update(
+                    state, xc[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
+                y = y[:, None]
+            else:
+                # a served chunk through the kernel (on the CPU under the
+                # interpreter, as the update is); the uncached forward by lax
+                y, new_state = mamba1.selective_scan(
+                    xc, dt, a, bm, cm, state,
+                    kernel="pallas" if cached else "lax")
+            if cached and not self.is_initializing():
+                ssm_state.value = new_state
+                # the window that ends at the last real position
+                ends = jnp.full((b,), t, jnp.int32) if valid_len is None \
+                    else valid_len.astype(jnp.int32)
+                conv_state.value = jax.vmap(
+                    lambda s, e: jax.lax.dynamic_slice_in_dim(s, e, k - 1, 0)
+                )(seq, ends)
+                at, of = self.stats
+                self.sow("stats", "ssm",
+                         jnp.zeros((of,), jnp.int32).at[at].set(
+                             jnp.sum(real[:, 0])),
+                         reduce_fn=lambda acc, x: acc + x,
+                         init_fn=lambda: jnp.zeros((of,), jnp.int32))
 
-        y = (y + d_skip * xc) * jax.nn.silu(z)
+            y = (y + d_skip * xc) * jax.nn.silu(z)
         # float32 out of the accumulator: it joins the residual stream
-        return dense(cfg.d_model, "out_proj", cfg, f32)(y.astype(cfg.dtype))
+        with trace.part(trace.PROJ):
+            return dense(cfg.d_model, "out_proj", cfg, f32)(
+                y.astype(cfg.dtype))
 
 
 class GatedMlp(nn.Module):
@@ -357,6 +366,7 @@ class GatedMlp(nn.Module):
     cfg: JambaConfig
 
     @nn.compact
+    @trace.part(trace.FFN)
     def __call__(self, h):
         cfg = self.cfg
         f32 = jnp.float32
@@ -385,7 +395,8 @@ class Jamba(nn.Module):
         # ``residual_in_fp32``): 56 sums in bfloat16 would round a stream
         # that grows with depth 56 times, more error than everything else
         # the activations' type costs; the products take it rounded once
-        x = emb.astype(cfg.dtype)[tokens].astype(jnp.float32)
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens].astype(jnp.float32)
         for i, kind in enumerate(cfg.layer_kinds):
             u = RMSNorm(cfg.norm_eps, cfg.param_dtype,
                         name=f"layer_{i}_norm")(x)
@@ -395,14 +406,18 @@ class Jamba(nn.Module):
             else:
                 y = PagedAttention(cfg, (1, of), name=f"layer_{i}")(
                     u, page_table, valid_len)
-            x = x + y.astype(jnp.float32)
+            # a residual sum is filed with the block it closes
+            with trace.part(trace.PROJ):
+                x = x + y.astype(jnp.float32)
             h = RMSNorm(cfg.norm_eps, cfg.param_dtype,
                         name=f"layer_{i}_mlp_norm")(x)
-            x = x + GatedMlp(cfg, name=f"layer_{i}_mlp")(h)
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
-        return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                          emb.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+            with trace.part(trace.FFN):
+                x = x + GatedMlp(cfg, name=f"layer_{i}_mlp")(h)
+        with trace.part(trace.HEAD):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+            return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                              emb.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: JambaConfig, rng: jax.Array):

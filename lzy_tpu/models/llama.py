@@ -32,6 +32,7 @@ from jax.sharding import Mesh
 
 from lzy_tpu.models.common import cross_entropy_loss
 from lzy_tpu.models.serving import HeadPool
+from lzy_tpu.utils import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,6 +237,7 @@ class RMSNorm(nn.Module):
     param_dtype: Any
 
     @nn.compact
+    @trace.part(trace.NORM)
     def __call__(self, x):
         scale = self.param(
             "scale",
@@ -270,46 +272,59 @@ class Attention(nn.Module):
         )
         b, t, _ = x.shape
         h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = dense((h, d), "q_proj", ("embed", "heads", "head_dim"))(x)
-        k = dense((kv, d), "k_proj", ("embed", "kv", "head_dim"))(x)
-        v = dense((kv, d), "v_proj", ("embed", "kv", "head_dim"))(x)
-        # in-layer anchors (see Mlp): keep batch sharded through the
-        # projections so fsdp gathers weights, not [D,T,B] activations
-        q = _anchor(q, self.anchor_mesh, "batch", "seq", "act_heads", None,
-                    rules=self.rules)
-        k = _anchor(k, self.anchor_mesh, "batch", "seq", None, None,
-                    rules=self.rules)
-        v = _anchor(v, self.anchor_mesh, "batch", "seq", None, None,
-                    rules=self.rules)
+        with trace.part(trace.PROJ):
+            q = dense((h, d), "q_proj", ("embed", "heads", "head_dim"))(x)
+            k = dense((kv, d), "k_proj", ("embed", "kv", "head_dim"))(x)
+            v = dense((kv, d), "v_proj", ("embed", "kv", "head_dim"))(x)
+            # in-layer anchors (see Mlp): keep batch sharded through the
+            # projections so fsdp gathers weights, not [D,T,B] activations
+            q = _anchor(q, self.anchor_mesh, "batch", "seq", "act_heads",
+                        None, rules=self.rules)
+            k = _anchor(k, self.anchor_mesh, "batch", "seq", None, None,
+                        rules=self.rules)
+            v = _anchor(v, self.anchor_mesh, "batch", "seq", None, None,
+                        rules=self.rules)
 
         if cfg.decode:
             return self._decode_step(q, k, v, b, page_table)
 
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        with trace.part(trace.PROJ):
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
 
-        # GQA: repeat kv groups up to full heads
-        reps = h // kv
-        k = jnp.repeat(k, reps, axis=2)
-        v = jnp.repeat(v, reps, axis=2)
+            # GQA: repeat kv groups up to full heads
+            reps = h // kv
+            k = jnp.repeat(k, reps, axis=2)
+            v = jnp.repeat(v, reps, axis=2)
 
-        # [B, H, T, D] layout for attention
-        q, k, v = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
+            # [B, H, T, D] layout for attention
+            q, k, v = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
 
+        with trace.part(trace.ATTN_READ):
+            out = self._attend(q, k, v, mesh, segments, t)
+        with trace.part(trace.PROJ):
+            out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, t, h * d)
+            return _anchor(self._o_proj(out), self.anchor_mesh,
+                           "batch", "seq", "act_embed", rules=self.rules)
+
+    def _attend(self, q, k, v, mesh, segments, t):
+        """The uncached causal attention over ``[B, H, T, D]``, by the
+        path the configuration asks for."""
+        cfg = self.cfg
         if cfg.use_ring_attention and mesh is not None:
             from lzy_tpu.parallel.ring import ring_attention
 
-            out = ring_attention(q, k, v, mesh=mesh, causal=True,
-                                 segment_ids=segments)
-        elif cfg.use_ulysses_attention and mesh is not None:
+            return ring_attention(q, k, v, mesh=mesh, causal=True,
+                                  segment_ids=segments)
+        if cfg.use_ulysses_attention and mesh is not None:
             # all-to-all SP: reshard seq→heads so each device sees the FULL
             # sequence for its head slice (better when heads ≥ sp and the
             # ring's ppermute latency dominates)
             from lzy_tpu.parallel.ulysses import ulysses_attention
 
-            out = ulysses_attention(q, k, v, mesh=mesh, causal=True,
-                                    segment_ids=segments)
-        elif cfg.use_flash_kernel and not (
+            return ulysses_attention(q, k, v, mesh=mesh, causal=True,
+                                     segment_ids=segments)
+        if cfg.use_flash_kernel and not (
                 self.is_initializing() and t % 128):
             # asked for, so taken: a length the kernel cannot serve
             # (t % 128, VMEM) is refused by flash_attention itself, never
@@ -317,21 +332,16 @@ class Attention(nn.Module):
             # short dummy trace, whose output is thrown away, goes below.
             from lzy_tpu.ops.flash_attention import flash_attention
 
-            out = _batch_sharded_attention(
+            return _batch_sharded_attention(
                 flash_attention, q, k, v, segments, self.anchor_mesh,
                 rules=self.rules)
-        else:
-            # portable fallback: chunked online-softmax attention — O(T·block)
-            # activations, never the T×T score matrix (lzy_tpu/ops/attention)
-            from lzy_tpu.ops.attention import chunked_attention
+        # portable fallback: chunked online-softmax attention — O(T·block)
+        # activations, never the T×T score matrix (lzy_tpu/ops/attention)
+        from lzy_tpu.ops.attention import chunked_attention
 
-            out = _batch_sharded_attention(
-                chunked_attention, q, k, v, segments, self.anchor_mesh,
-                rules=self.rules)
-
-        out = jnp.transpose(out, (0, 2, 1, 3)).reshape(b, t, h * d)
-        return _anchor(self._o_proj(out), self.anchor_mesh,
-                       "batch", "seq", "act_embed", rules=self.rules)
+        return _batch_sharded_attention(
+            chunked_attention, q, k, v, segments, self.anchor_mesh,
+            rules=self.rules)
 
     def _o_proj(self, out):
         cfg = self.cfg
@@ -423,45 +433,51 @@ class Attention(nn.Module):
         i = index.value
         starts = i if i.ndim else jnp.broadcast_to(i, (b,))      # [B]
         pos = starts[:, None] + jnp.arange(t, dtype=jnp.int32)   # [B, T]
-        q = _rope(q, pos, cfg.rope_theta)
-        k = _rope(k, pos, cfg.rope_theta)
+        with trace.part(trace.PROJ):
+            q = _rope(q, pos, cfg.rope_theta)
+            k = _rope(k, pos, cfg.rope_theta)
         if not self.is_initializing():
             # init() RUNS the module; writing during init would pre-populate
             # the cache with the dummy token and shift every real position
-            if cfg.decode_paged:
-                if page_table is None:
-                    raise ValueError("decode_paged forward needs page_table")
-                from lzy_tpu.ops.paged_attention import paged_scatter_index
+            with trace.part(trace.CACHE_WRITE):
+                if cfg.decode_paged:
+                    if page_table is None:
+                        raise ValueError(
+                            "decode_paged forward needs page_table")
+                    from lzy_tpu.ops.paged_attention import (
+                        paged_scatter_index)
 
-                # scatter each (row, position) into its pool block
-                rows, offs = paged_scatter_index(page_table, pos,
-                                                 cfg.kv_page_size)
-                flat_k = k.astype(cfg.dtype).reshape(b * t, kv_heads, d)
-                flat_v = v.astype(cfg.dtype).reshape(b * t, kv_heads, d)
-                if quant:
-                    # quantize on scatter-write: the pool stores int8 of
-                    # EXACTLY what the fp path would have stored (the
-                    # cfg.dtype-rounded K/V), so divergence is purely the
-                    # int8 step, never a dtype-path difference
-                    from lzy_tpu.ops.paged_attention import quantize_kv
+                    # scatter each (row, position) into its pool block
+                    rows, offs = paged_scatter_index(page_table, pos,
+                                                     cfg.kv_page_size)
+                    flat_k = k.astype(cfg.dtype).reshape(b * t, kv_heads, d)
+                    flat_v = v.astype(cfg.dtype).reshape(b * t, kv_heads, d)
+                    if quant:
+                        # quantize on scatter-write: the pool stores int8 of
+                        # EXACTLY what the fp path would have stored (the
+                        # cfg.dtype-rounded K/V), so divergence is purely the
+                        # int8 step, never a dtype-path difference
+                        from lzy_tpu.ops.paged_attention import quantize_kv
 
-                    qk, sk, zk = quantize_kv(flat_k)
-                    qv, sv, zv = quantize_kv(flat_v)
-                    cache_k.value = cache_k.value.at[rows, offs].set(qk)
-                    cache_v.value = cache_v.value.at[rows, offs].set(qv)
-                    for var, vals in zip(quant_side, (sk, zk, sv, zv)):
-                        var.value = var.value.at[rows, offs].set(vals)
+                        qk, sk, zk = quantize_kv(flat_k)
+                        qv, sv, zv = quantize_kv(flat_v)
+                        cache_k.value = cache_k.value.at[rows, offs].set(qk)
+                        cache_v.value = cache_v.value.at[rows, offs].set(qv)
+                        for var, vals in zip(quant_side, (sk, zk, sv, zv)):
+                            var.value = var.value.at[rows, offs].set(vals)
+                    else:
+                        cache_k.value = cache_k.value.at[rows, offs].set(
+                            flat_k)
+                        cache_v.value = cache_v.value.at[rows, offs].set(
+                            flat_v)
                 else:
-                    cache_k.value = cache_k.value.at[rows, offs].set(flat_k)
-                    cache_v.value = cache_v.value.at[rows, offs].set(flat_v)
-            else:
-                cache_k.value = jax.lax.dynamic_update_slice(
-                    cache_k.value, k.astype(cfg.dtype), (0, i, 0, 0)
-                )
-                cache_v.value = jax.lax.dynamic_update_slice(
-                    cache_v.value, v.astype(cfg.dtype), (0, i, 0, 0)
-                )
-            index.value = i + t
+                    cache_k.value = jax.lax.dynamic_update_slice(
+                        cache_k.value, k.astype(cfg.dtype), (0, i, 0, 0)
+                    )
+                    cache_v.value = jax.lax.dynamic_update_slice(
+                        cache_v.value, v.astype(cfg.dtype), (0, i, 0, 0)
+                    )
+                index.value = i + t
 
         if cfg.decode_paged:
             from lzy_tpu.ops.paged_attention import (
@@ -486,38 +502,41 @@ class Attention(nn.Module):
             # the partitioner keep it sharded would psum partial
             # matmul products (a float reduction-order change — the
             # sharded engine's bit-identity contract forbids it)
-            out = _anchor(out.reshape(b, t, h * d), self.anchor_mesh,
-                          "batch", "seq", "act_attn_out",
-                          rules=self.rules)
-            return self._o_proj(out)
+            with trace.part(trace.PROJ):
+                out = _anchor(out.reshape(b, t, h * d), self.anchor_mesh,
+                              "batch", "seq", "act_attn_out",
+                              rules=self.rules)
+                return self._o_proj(out)
         keys, vals = cache_k.value, cache_v.value
 
         # GQA without jnp.repeat: grouping q as [B, T, KV, G, D] lets the
         # einsum broadcast the shared KV head instead of materializing a
         # G-times larger cache copy every step — decode is HBM-bound, and
         # the repeat was pure wasted bandwidth
-        reps = h // kv_heads
-        qg = q.reshape(b, t, kv_heads, reps, d)
-        s = jnp.einsum(
-            "btkgd,blkd->bkgtl", qg, keys,
-            preferred_element_type=jnp.float32,
-        ) * (d ** -0.5)                                   # [B, KV, G, T, L]
-        # query at (row, chunk offset tq) sees cache slots l <= start + tq:
-        # everything already cached plus the chunk's own causal prefix (the
-        # chunk was written above, so "future" chunk positions ARE in the
-        # cache and must be masked; -1e30 underflows to exactly 0 after
-        # softmax, so masked garbage contributes nothing)
-        visible = (jnp.arange(L)[None, None, None, None, :]
-                   <= pos[:, None, None, :, None])
-        s = jnp.where(visible, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
-        out = jnp.einsum("bkgtl,blkd->btkgd", p, vals)
+        with trace.part(trace.ATTN_READ):
+            reps = h // kv_heads
+            qg = q.reshape(b, t, kv_heads, reps, d)
+            s = jnp.einsum(
+                "btkgd,blkd->bkgtl", qg, keys,
+                preferred_element_type=jnp.float32,
+            ) * (d ** -0.5)                               # [B, KV, G, T, L]
+            # query at (row, chunk offset tq) sees cache slots l <= start + tq:
+            # everything already cached plus the chunk's own causal prefix (the
+            # chunk was written above, so "future" chunk positions ARE in the
+            # cache and must be masked; -1e30 underflows to exactly 0 after
+            # softmax, so masked garbage contributes nothing)
+            visible = (jnp.arange(L)[None, None, None, None, :]
+                       <= pos[:, None, None, :, None])
+            s = jnp.where(visible, s, -1e30)
+            p = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
+            out = jnp.einsum("bkgtl,blkd->btkgd", p, vals)
         # same contraction-dim gather as the paged read above: replicate
         # the merged head dim before o_proj so no psum-of-partials ever
         # enters the decode forward
-        out = _anchor(out.reshape(b, t, h * d), self.anchor_mesh,
-                      "batch", "seq", "act_attn_out", rules=self.rules)
-        return self._o_proj(out)
+        with trace.part(trace.PROJ):
+            out = _anchor(out.reshape(b, t, h * d), self.anchor_mesh,
+                          "batch", "seq", "act_attn_out", rules=self.rules)
+            return self._o_proj(out)
 
 
 class Mlp(nn.Module):
@@ -526,6 +545,7 @@ class Mlp(nn.Module):
     rules: Any = None
 
     @nn.compact
+    @trace.part(trace.FFN)
     def __call__(self, x):
         cfg = self.cfg
 
@@ -572,11 +592,14 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, positions, segments=None, page_table=None):
         cfg, mesh = self.cfg, self.mesh
         amesh = mesh if self.anchor else None
-        x = x + Attention(cfg, anchor_mesh=amesh, rules=self.rules,
-                          name="attn")(
+        attn = Attention(cfg, anchor_mesh=amesh, rules=self.rules,
+                         name="attn")(
             RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attn_norm")(x),
             positions, mesh, segments, page_table,
         )
+        # a residual sum is filed with the block it closes
+        with trace.part(trace.PROJ):
+            x = x + attn
         h = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="mlp_norm")(x)
         if cfg.n_experts > 0:
             from lzy_tpu.models.moe import MoeConfig, MoeMlp
@@ -587,8 +610,10 @@ class DecoderLayer(nn.Module):
                 param_dtype=cfg.param_dtype,
             ), name="moe")(h)
             self.sow("losses", "moe_aux", aux)
-            return x + moe_out
-        return x + Mlp(cfg, mesh=amesh, rules=self.rules, name="mlp")(h)
+            with trace.part(trace.EXPERTS):
+                return x + moe_out
+        with trace.part(trace.FFN):
+            return x + Mlp(cfg, mesh=amesh, rules=self.rules, name="mlp")(h)
 
 
 def _mesh_axes_for(rules, name, mesh):
@@ -743,9 +768,11 @@ class Llama(nn.Module):
             ),
             (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
         )
-        x = _embed_lookup(emb.astype(cfg.dtype), tokens,
-                          one_hot=mesh is not None)
-        x = _anchor(x, mesh, "batch", "seq", "act_embed", rules=self.rules)
+        with trace.part(trace.EMBED):
+            x = _embed_lookup(emb.astype(cfg.dtype), tokens,
+                              one_hot=mesh is not None)
+            x = _anchor(x, mesh, "batch", "seq", "act_embed",
+                        rules=self.rules)
         if segments is None:
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1]), tokens.shape
@@ -774,30 +801,32 @@ class Llama(nn.Module):
                       name=f"layer_{i}")(x, positions, segments, page_table)
             x = _anchor(x, mesh, "batch", "seq", "act_embed",
                         rules=self.rules)
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
-        if cfg.tie_embeddings:
-            head = emb
-        else:
-            head = self.param(
-                "lm_head",
-                nn.with_logical_partitioning(
-                    nn.initializers.normal(0.02), ("vocab", "embed")
-                ),
-                (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
+        with trace.part(trace.HEAD):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+            if cfg.tie_embeddings:
+                head = emb
+            else:
+                head = self.param(
+                    "lm_head",
+                    nn.with_logical_partitioning(
+                        nn.initializers.normal(0.02), ("vocab", "embed")
+                    ),
+                    (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
+                )
+            if cfg.fused_ce and not cfg.decode:
+                # the loss computes chunked CE straight from features + head
+                # and never materializes [B,T,V] logits (decode always needs
+                # real logits for sampling, whatever the training config said)
+                return x.astype(cfg.dtype), head.astype(cfg.dtype)
+            # bf16 operands on the MXU, f32 accumulation — an f32×f32 head
+            # matmul would run ~4x slower for no useful precision (loss is
+            # f32 anyway)
+            logits = jnp.einsum(
+                "bte,ve->btv", x.astype(cfg.dtype), head.astype(cfg.dtype),
+                preferred_element_type=jnp.float32,
             )
-        if cfg.fused_ce and not cfg.decode:
-            # the loss computes chunked CE straight from features + head and
-            # never materializes [B,T,V] logits (decode always needs real
-            # logits for sampling, whatever the training config said)
-            return x.astype(cfg.dtype), head.astype(cfg.dtype)
-        # bf16 operands on the MXU, f32 accumulation — an f32×f32 head matmul
-        # would run ~4x slower for no useful precision (loss is f32 anyway)
-        logits = jnp.einsum(
-            "bte,ve->btv", x.astype(cfg.dtype), head.astype(cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        return _anchor(logits, mesh, "batch", "seq", "act_vocab",
-                       rules=self.rules)
+            return _anchor(logits, mesh, "batch", "seq", "act_vocab",
+                           rules=self.rules)
 
 
 class LlamaStage(nn.Module):
@@ -1112,6 +1141,7 @@ def _segment_shift_mask(segments, shifted_mask):
         else jnp.logical_and(shifted_mask, same_doc)
 
 
+@trace.part(trace.LOSS)
 def _lm_loss(cfg: LlamaConfig, out, tokens, shifted_mask, mesh=None,
              rules=None):
     """Shared next-token loss tail: ``out`` is logits, or (features, head)

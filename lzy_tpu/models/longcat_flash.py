@@ -79,6 +79,7 @@ from lzy_tpu.models.paged_blocks import dense, into_heads, normal
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import mla
 from lzy_tpu.ops.paged_attention import paged_scatter_index
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 MOE_ZERO_ASSIGNMENTS = REGISTRY.counter(
@@ -354,15 +355,17 @@ class LatentAttention(nn.Module):
             y = RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)(x)
             return (y * (cfg.d_model / rank) ** 0.5).astype(cfg.dtype)
 
-        c_q = latent(dense(cfg.q_lora_rank, "q_a_proj", cfg, f32)(u),
-                     "q_a_norm", cfg.q_lora_rank)
-        q = into_heads(dense(h * (dn + dr), "q_b_proj", cfg)(c_q),
-                       b, t, h, dn + dr)
-        kva = dense(r + dr, "kv_a_proj", cfg, f32)(u)
-        c = latent(kva[..., :r], "kv_a_norm", r)
-        # [rank, head, nope + value]: the keys' and the values' up-projection
-        w_kvb = self.param("kv_b_proj", normal(), (r, h, dn + dv),
-                           cfg.param_dtype).astype(cfg.dtype)
+        with trace.part(trace.PROJ):
+            c_q = latent(dense(cfg.q_lora_rank, "q_a_proj", cfg, f32)(u),
+                         "q_a_norm", cfg.q_lora_rank)
+            q = into_heads(dense(h * (dn + dr), "q_b_proj", cfg)(c_q),
+                           b, t, h, dn + dr)
+            kva = dense(r + dr, "kv_a_proj", cfg, f32)(u)
+            c = latent(kva[..., :r], "kv_a_norm", r)
+            # [rank, head, nope + value]: the keys' and the values'
+            # up-projection
+            w_kvb = self.param("kv_b_proj", normal(), (r, h, dn + dv),
+                               cfg.param_dtype).astype(cfg.dtype)
 
         cached = cfg.decode_paged
         if cached:
@@ -374,19 +377,20 @@ class LatentAttention(nn.Module):
             start = index.value
         else:
             start = jnp.zeros((b,), jnp.int32)
-        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
-        k_rope = _rope(kva[:, :, None, r:], pos, cfg.rope_theta)[:, :, 0]
-        # absorb the keys' up-projection into the query
-        q_abs = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_kvb[..., :dn],
-                           preferred_element_type=f32)
-        pad = w - r - dr
-        q_full = jnp.concatenate(
-            [q_abs.astype(cfg.dtype), q_rope.astype(cfg.dtype),
-             jnp.zeros((b, t, h, pad), cfg.dtype)], axis=-1)
-        lat = jnp.concatenate(
-            [c, k_rope.astype(cfg.dtype),
-             jnp.zeros((b, t, pad), cfg.dtype)], axis=-1)         # [B, T, W]
+        with trace.part(trace.PROJ):
+            pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+            q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
+            k_rope = _rope(kva[:, :, None, r:], pos, cfg.rope_theta)[:, :, 0]
+            # absorb the keys' up-projection into the query
+            q_abs = jnp.einsum("bthn,rhn->bthr", q[..., :dn], w_kvb[..., :dn],
+                               preferred_element_type=f32)
+            pad = w - r - dr
+            q_full = jnp.concatenate(
+                [q_abs.astype(cfg.dtype), q_rope.astype(cfg.dtype),
+                 jnp.zeros((b, t, h, pad), cfg.dtype)], axis=-1)
+            lat = jnp.concatenate(
+                [c, k_rope.astype(cfg.dtype),
+                 jnp.zeros((b, t, pad), cfg.dtype)], axis=-1)       # [B, T, W]
 
         if not cached:
             summed = mla.causal_mla_attention(
@@ -396,11 +400,12 @@ class LatentAttention(nn.Module):
             if not self.is_initializing():
                 if page_table is None:
                     raise ValueError("a paged forward needs page_table")
-                rows, offs = paged_scatter_index(page_table, pos,
-                                                 cfg.kv_page_size)
-                pool.value = pool.value.at[rows, offs].set(
-                    lat.reshape(b * t, w))
-                index.value = index.value + t
+                with trace.part(trace.CACHE_WRITE):
+                    rows, offs = paged_scatter_index(page_table, pos,
+                                                     cfg.kv_page_size)
+                    pool.value = pool.value.at[rows, offs].set(
+                        lat.reshape(b * t, w))
+                    index.value = index.value + t
             # an idle slot (no real position) is told so, whatever its stale
             # position says: the read skips it and gives it 0
             live = jnp.where(real[:, 0], start, -1)
@@ -408,26 +413,28 @@ class LatentAttention(nn.Module):
             # one call, a prefill chunk's ``prefill_read_heads`` a call (the
             # kernel's tile of 64 positions x 64 heads does not fit a core's
             # VMEM)
-            step = h if t <= mla.MAX_DECODE_TOKENS \
-                or cfg.paged_kernel != "pallas" else cfg.prefill_read_heads
-            summed = jnp.concatenate([
-                mla.mla_attention(
-                    q_full[:, :, at:at + step], pool.value, page_table, live,
-                    value_dim=r, scale=cfg.softmax_scale,
-                    kernel=cfg.paged_kernel)
-                for at in range(0, h, step)], axis=2)
-            # the last real query of a row at position p reads p + 1
-            last = pos[:, 0] + jnp.sum(real, axis=1)
-            self.sow("stats", "mla", jnp.zeros((_N_STATS,), jnp.int32).at[
-                _MLA_AT:_ZERO_AT].set(jnp.stack([
-                    jnp.sum(jnp.where(real[:, 0], last, 0)),
-                    jnp.sum(real[:, 0])]).astype(jnp.int32)),
-                reduce_fn=lambda a, x: a + x,
-                init_fn=lambda: jnp.zeros((_N_STATS,), jnp.int32))
-        out = jnp.einsum("bthr,rhv->bthv", summed.astype(cfg.dtype),
-                         w_kvb[..., dn:], preferred_element_type=f32)
-        return dense(cfg.d_model, "o_proj", cfg)(
-            out.astype(cfg.dtype).reshape(b, t, h * dv))
+            with trace.part(trace.ATTN_READ):
+                step = h if t <= mla.MAX_DECODE_TOKENS \
+                    or cfg.paged_kernel != "pallas" else cfg.prefill_read_heads
+                summed = jnp.concatenate([
+                    mla.mla_attention(
+                        q_full[:, :, at:at + step], pool.value, page_table,
+                        live, value_dim=r, scale=cfg.softmax_scale,
+                        kernel=cfg.paged_kernel)
+                    for at in range(0, h, step)], axis=2)
+                # the last real query of a row at position p reads p + 1
+                last = pos[:, 0] + jnp.sum(real, axis=1)
+                self.sow("stats", "mla", jnp.zeros((_N_STATS,), jnp.int32).at[
+                    _MLA_AT:_ZERO_AT].set(jnp.stack([
+                        jnp.sum(jnp.where(real[:, 0], last, 0)),
+                        jnp.sum(real[:, 0])]).astype(jnp.int32)),
+                    reduce_fn=lambda a, x: a + x,
+                    init_fn=lambda: jnp.zeros((_N_STATS,), jnp.int32))
+        with trace.part(trace.PROJ):
+            out = jnp.einsum("bthr,rhv->bthv", summed.astype(cfg.dtype),
+                             w_kvb[..., dn:], preferred_element_type=f32)
+            return dense(cfg.d_model, "o_proj", cfg)(
+                out.astype(cfg.dtype).reshape(b, t, h * dv))
 
 
 def softmax_scores(layer: nn.Module, um, n_routed: int):
@@ -484,8 +491,8 @@ class ShortcutExperts(nn.Module):
         b, t, dm = u.shape
         m = b * t
         f32 = jnp.float32
-        with jax.named_scope("shortcut_experts"):
-            um = u.reshape(m, dm)
+        um = u.reshape(m, dm)
+        with trace.part(trace.ROUTER):
             real = row_mask(valid_len, b, t).reshape(m)
             scores, bias = softmax_scores(self, um, cfg.n_routed_experts)
             weights = experts.held_weights(
@@ -497,14 +504,15 @@ class ShortcutExperts(nn.Module):
                 _ZERO_AT:].set(counts),
                 reduce_fn=lambda a, c: a + c,
                 init_fn=lambda: jnp.zeros((_N_STATS,), jnp.int32))
-            up_shape = (cfg.n_held, dm, cfg.expert_width)
-            wg = self.param("experts_gate", normal(), up_shape,
-                            cfg.param_dtype)
-            wu = self.param("experts_up", normal(), up_shape,
-                            cfg.param_dtype)
-            wd = self.param("experts_down", normal(),
-                            (cfg.n_held, cfg.expert_width, dm),
-                            cfg.param_dtype)
+        up_shape = (cfg.n_held, dm, cfg.expert_width)
+        wg = self.param("experts_gate", normal(), up_shape,
+                        cfg.param_dtype)
+        wu = self.param("experts_up", normal(), up_shape,
+                        cfg.param_dtype)
+        wd = self.param("experts_down", normal(),
+                        (cfg.n_held, cfg.expert_width, dm),
+                        cfg.param_dtype)
+        with trace.part(trace.EXPERTS):
             if self.is_initializing():
                 routed = jnp.zeros((m, dm), f32)        # no kernel at init
             else:
@@ -529,29 +537,35 @@ class LongcatFlash(nn.Module):
         cfg = self.cfg
         emb = self.param("embed_tokens", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens]
 
         def norm(name):
             return RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)
 
         for i in range(cfg.n_layers):
             for j in (0, 1):
-                x = x + LatentAttention(cfg, name=f"layer_{i}_attn_{j}")(
+                y = LatentAttention(cfg, name=f"layer_{i}_attn_{j}")(
                     norm(f"layer_{i}_norm_{j}")(x), page_table, valid_len)
+                # a residual sum is filed with the block it closes
+                with trace.part(trace.PROJ):
+                    x = x + y
                 u = norm(f"layer_{i}_ffn_norm_{j}")(x)
                 if j == 0:
                     # the shortcut: computed here, added three sublayers on
                     s = ShortcutExperts(cfg, name=f"layer_{i}_moe")(
                         u, valid_len)
-                with jax.named_scope("dense_ffn"):
+                with trace.part(trace.FFN):
                     x = x + GatedMlp(cfg, name=f"layer_{i}_mlp_{j}")(u)
-            x = x + s
-        x = norm("final_norm")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                          head.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+            with trace.part(trace.EXPERTS):
+                x = x + s
+        with trace.part(trace.HEAD):
+            x = norm("final_norm")(x)
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: LongcatFlashConfig, rng: jax.Array):

@@ -64,6 +64,7 @@ from lzy_tpu.models.paged_blocks import dense, into_heads, normal
 from lzy_tpu.ops import mamba2
 from lzy_tpu.ops import sparse_attention as sparse
 from lzy_tpu.ops.sparse_attention import SparseSpec
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 SPARSE_VISIBLE = REGISTRY.counter(
@@ -330,6 +331,7 @@ def _head_norm(cfg, name: str):
     return HeadNorm(cfg.norm_eps, name=name)
 
 
+@trace.part(trace.PROJ)
 def _gated_out(cfg, out, u):
     """``o * sigmoid(gate(u))``, then ``o_proj``; float32 out of the
     accumulator: it joins the residual stream."""
@@ -358,27 +360,29 @@ class SparseAttention(nn.Module):
         b, t, _ = u.shape
         h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         # float32 out of the accumulator: the norms a head read it
-        q = into_heads(dense(h * d, "q_proj", cfg, jnp.float32)(u),
-                       b, t, h, d)
-        k = into_heads(dense(kv * d, "k_proj", cfg, jnp.float32)(u),
-                       b, t, kv, d)
-        v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
-        # the selector reads the query as the norm leaves it: rounded to the
-        # products' type it flips near-ties between block scores
-        q32 = _head_norm(cfg, "q_norm")(q)
-        q = q32.astype(cfg.dtype)
-        k = _head_norm(cfg, "k_norm")(k).astype(cfg.dtype)
+        with trace.part(trace.PROJ):
+            q = into_heads(dense(h * d, "q_proj", cfg, jnp.float32)(u),
+                           b, t, h, d)
+            k = into_heads(dense(kv * d, "k_proj", cfg, jnp.float32)(u),
+                           b, t, kv, d)
+            v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
+            # the selector reads the query as the norm leaves it: rounded to
+            # the products' type it flips near-ties between block scores
+            q32 = _head_norm(cfg, "q_norm")(q)
+            q = q32.astype(cfg.dtype)
+            k = _head_norm(cfg, "k_norm")(k).astype(cfg.dtype)
         if not cfg.decode_paged:
             if t >= cfg.dense_len:
                 raise ValueError(
                     f"the uncached forward reads everything: {t} positions "
                     f"reach dense_len {cfg.dense_len}")
-            qg = q.reshape(b, t, kv, h // kv, d)
-            s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
-                           preferred_element_type=jnp.float32) * d ** -0.5
-            keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-            pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
-            out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype), v)
+            with trace.part(trace.ATTN_READ):
+                qg = q.reshape(b, t, kv, h // kv, d)
+                s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
+                               preferred_element_type=jnp.float32) * d ** -0.5
+                keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+                pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+                out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype), v)
             return _gated_out(cfg, out.reshape(b, t, h * d), u)
 
         kv_shape, ck_shape = sparse.pool_shapes(cfg.kv_pages, kv, d, spec)
@@ -395,17 +399,18 @@ class SparseAttention(nn.Module):
                               u)
         if page_table is None:
             raise ValueError("a paged forward needs page_table")
-        n_real = jnp.full((b,), t, jnp.int32) if valid_len is None \
-            else valid_len.astype(jnp.int32)
-        live = n_real > 0
-        selects = jnp.zeros((b,), bool) if selects is None \
-            else selects & live
-        pool_k.value = sparse.scatter_kv(pool_k.value, page_table, pos, k)
-        pool_v.value = sparse.scatter_kv(pool_v.value, page_table, pos, v)
-        pool_ck.value = sparse.compress_keys(
-            pool_k.value, pool_ck.value, page_table, start, n_real, t=t,
-            spec=spec)
-        index.value = index.value + t
+        with trace.part(trace.CACHE_WRITE):
+            n_real = jnp.full((b,), t, jnp.int32) if valid_len is None \
+                else valid_len.astype(jnp.int32)
+            live = n_real > 0
+            selects = jnp.zeros((b,), bool) if selects is None \
+                else selects & live
+            pool_k.value = sparse.scatter_kv(pool_k.value, page_table, pos, k)
+            pool_v.value = sparse.scatter_kv(pool_v.value, page_table, pos, v)
+            pool_ck.value = sparse.compress_keys(
+                pool_k.value, pool_ck.value, page_table, start, n_real, t=t,
+                spec=spec)
+            index.value = index.value + t
         chosen = sparse.select_blocks(
             q32, pool_ck.value, page_table, pos, selects, spec=spec,
             kernel=cfg.paged_kernel)
@@ -416,7 +421,8 @@ class SparseAttention(nn.Module):
                 q, pool_k.value, pool_v.value, page_table, pos,
                 chosen[:, :, 0], live, kernel=cfg.paged_kernel,
                 dtype=cfg.dtype)
-            self._count(chosen[:, :, 0], pos[:, 0], selects, live)
+            with trace.part(trace.ATTN_READ):
+                self._count(chosen[:, :, 0], pos[:, 0], selects, live)
         else:
             out = sparse.sparse_prefill_attention(
                 q, pool_k.value, pool_v.value, page_table, start, chosen,
@@ -450,11 +456,12 @@ class LightningAttention(nn.Module):
         b, t, _ = u.shape
         h, d = cfg.lightning_heads, cfg.lightning_head_dim
         f32 = jnp.float32
-        q = into_heads(dense(h * d, "q_proj", cfg, f32)(u), b, t, h, d)
-        k = into_heads(dense(h * d, "k_proj", cfg, f32)(u), b, t, h, d)
-        v = into_heads(dense(h * d, "v_proj", cfg)(u), b, t, h, d)
-        q = _head_norm(cfg, "q_norm")(q)
-        k = _head_norm(cfg, "k_norm")(k)
+        with trace.part(trace.PROJ):
+            q = into_heads(dense(h * d, "q_proj", cfg, f32)(u), b, t, h, d)
+            k = into_heads(dense(h * d, "k_proj", cfg, f32)(u), b, t, h, d)
+            v = into_heads(dense(h * d, "v_proj", cfg)(u), b, t, h, d)
+            q = _head_norm(cfg, "q_norm")(q)
+            k = _head_norm(cfg, "k_norm")(k)
         cached = cfg.decode_paged
         if cached:
             state = self.variable("cache", "state", jnp.zeros, (b, h, d, d),
@@ -465,26 +472,28 @@ class LightningAttention(nn.Module):
         else:
             start, carried = jnp.zeros((b,), jnp.int32), \
                 jnp.zeros((b, h, d, d), f32)
-        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        # the products take q, k and v rounded to the activations' type
-        q = _rope(q, pos, cfg.rope_theta).astype(cfg.dtype)
-        k = _rope(k, pos, cfg.rope_theta).astype(cfg.dtype)
-        real = row_mask(valid_len, b, t)                         # [B, T]
-        dt = jnp.broadcast_to(real[..., None].astype(f32), (b, t, h))
-        decay = lightning_decay(h)
-        if cached and t == 1 and not self.is_initializing():
-            y, new = mamba2.ssm_state_update(
-                carried, v[:, 0], dt[:, 0], decay, k[:, 0], q[:, 0])
-            y = y[:, None]
-            self._count(real[:, 0])
-        else:
-            y, new = mamba2.ssd_chunk_scan(
-                v, dt, decay, k, q, carried, chunk=cfg.chunk_size)
-        if cached and not self.is_initializing():
-            state.value = new.astype(cfg.state_dtype)
-            index.value = index.value + t
-        y = y * d ** -0.5
-        y = _head_norm(cfg, "o_norm")(y).astype(cfg.dtype)
+        with trace.part(trace.PROJ):
+            pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+            # the products take q, k and v rounded to the activations' type
+            q = _rope(q, pos, cfg.rope_theta).astype(cfg.dtype)
+            k = _rope(k, pos, cfg.rope_theta).astype(cfg.dtype)
+        with trace.part(trace.STATE):
+            real = row_mask(valid_len, b, t)                         # [B, T]
+            dt = jnp.broadcast_to(real[..., None].astype(f32), (b, t, h))
+            decay = lightning_decay(h)
+            if cached and t == 1 and not self.is_initializing():
+                y, new = mamba2.ssm_state_update(
+                    carried, v[:, 0], dt[:, 0], decay, k[:, 0], q[:, 0])
+                y = y[:, None]
+                self._count(real[:, 0])
+            else:
+                y, new = mamba2.ssd_chunk_scan(
+                    v, dt, decay, k, q, carried, chunk=cfg.chunk_size)
+            if cached and not self.is_initializing():
+                state.value = new.astype(cfg.state_dtype)
+                index.value = index.value + t
+            y = y * d ** -0.5
+            y = _head_norm(cfg, "o_norm")(y).astype(cfg.dtype)
         return _gated_out(cfg, y.reshape(b, t, h * d), u)
 
     def _count(self, live):
@@ -497,6 +506,7 @@ class GatedMlp(nn.Module):
     cfg: MiniCPMSalaConfig
 
     @nn.compact
+    @trace.part(trace.FFN)
     def __call__(self, u):
         cfg = self.cfg
         gate = dense(cfg.d_ff, "gate_proj", cfg, jnp.float32)(u)
@@ -526,17 +536,20 @@ class MiniCPMSala(nn.Module):
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
         # the stream is float32: 2 x layers sums in bfloat16 would round it
         # as many times (the products take it rounded to their type)
-        x = emb.astype(cfg.dtype)[tokens].astype(jnp.float32) * cfg.scale_emb
-        selects = None
-        if cfg.decode_paged:
-            # a request's mode is fixed when it is admitted, by its prompt's
-            # length: a prefill program is told it and writes the slot's
-            # row, a decode round reads the row
-            mode = self.variable("cache", "sparse",
-                                 lambda: jnp.zeros((b,), jnp.int32))
-            if prompt_len is not None and not self.is_initializing():
-                mode.value = (prompt_len >= cfg.dense_len).astype(jnp.int32)
-            selects = mode.value != 0
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens].astype(jnp.float32) \
+                * cfg.scale_emb
+            selects = None
+            if cfg.decode_paged:
+                # a request's mode is fixed when it is admitted, by its
+                # prompt's length: a prefill program is told it and writes the
+                # slot's row, a decode round reads the row
+                mode = self.variable("cache", "sparse",
+                                     lambda: jnp.zeros((b,), jnp.int32))
+                if prompt_len is not None and not self.is_initializing():
+                    mode.value = (prompt_len >= cfg.dense_len).astype(
+                        jnp.int32)
+                selects = mode.value != 0
         s = cfg.residual_scale
         of = len(self.STATS)
         for i, kind in enumerate(cfg.mixer_types):
@@ -548,20 +561,24 @@ class MiniCPMSala(nn.Module):
             else:
                 y = LightningAttention(cfg, (of - 1, of), name=f"layer_{i}")(
                     u, valid_len)
-            x = x + s * y
+            # a residual sum is filed with the block it closes
+            with trace.part(trace.PROJ):
+                x = x + s * y
             u = RMSNorm(cfg.norm_eps, cfg.param_dtype,
                         name=f"layer_{i}_mlp_norm")(x).astype(cfg.dtype)
-            x = x + s * GatedMlp(cfg, name=f"layer_{i}_mlp")(u)
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
-        # drawn wider by what the logits are divided by, so that a random
-        # model's logits spread as the other families' do
-        head = self.param(
-            "lm_head", normal(0.02 * cfg.d_model / cfg.dim_model_base),
-            (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        logits = jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                            head.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        return logits / (cfg.d_model / cfg.dim_model_base)
+            with trace.part(trace.FFN):
+                x = x + s * GatedMlp(cfg, name=f"layer_{i}_mlp")(u)
+        with trace.part(trace.HEAD):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+            # drawn wider by what the logits are divided by, so that a random
+            # model's logits spread as the other families' do
+            head = self.param(
+                "lm_head", normal(0.02 * cfg.d_model / cfg.dim_model_base),
+                (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            logits = jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                                head.astype(cfg.dtype),
+                                preferred_element_type=jnp.float32)
+            return logits / (cfg.d_model / cfg.dim_model_base)
 
 
 def init_params(cfg: MiniCPMSalaConfig, rng: jax.Array):

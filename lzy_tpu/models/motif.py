@@ -93,6 +93,7 @@ from lzy_tpu.ops import latent_select as lsel
 from lzy_tpu.ops import mhc, mla
 from lzy_tpu.ops import polynorm_experts as pne
 from lzy_tpu.ops.paged_attention import paged_scatter_index
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 MHC_MIXED_ROWS = REGISTRY.counter(
@@ -524,17 +525,20 @@ class DifferentialLatentAttention(nn.Module):
         def norm(name):
             return RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)
 
-        c_q = norm("q_a_norm")(
-            dense(cfg.q_lora_rank, "q_a_proj", cfg, f32)(u)).astype(cfg.dtype)
-        q = into_heads(dense(h * (dn + dr), "q_b_proj", cfg)(c_q),
-                       b, t, h, dn + dr)
-        kva = dense(r + dr, "kv_a_proj", cfg, f32)(u)
-        c = norm("kv_a_norm")(kva[..., :r]).astype(cfg.dtype)
-        # [rank, key-value head, nope + value]: the 16 heads' keys and values
-        w_kvb = self.param("kv_b_proj", normal(), (r, g, dn + dv),
-                           cfg.param_dtype).astype(cfg.dtype)
-        lam = jax.nn.sigmoid(dense(hs, "lambda_proj", cfg, f32)(u))
-        gate = jax.nn.sigmoid(dense(hs * dv, "gate_proj", cfg, f32)(u))
+        with trace.part(trace.PROJ):
+            c_q = norm("q_a_norm")(
+                dense(cfg.q_lora_rank, "q_a_proj", cfg, f32)(u)).astype(
+                cfg.dtype)
+            q = into_heads(dense(h * (dn + dr), "q_b_proj", cfg)(c_q),
+                           b, t, h, dn + dr)
+            kva = dense(r + dr, "kv_a_proj", cfg, f32)(u)
+            c = norm("kv_a_norm")(kva[..., :r]).astype(cfg.dtype)
+            # [rank, key-value head, nope + value]: the 16 heads' keys and
+            # values
+            w_kvb = self.param("kv_b_proj", normal(), (r, g, dn + dv),
+                               cfg.param_dtype).astype(cfg.dtype)
+            lam = jax.nn.sigmoid(dense(hs, "lambda_proj", cfg, f32)(u))
+            gate = jax.nn.sigmoid(dense(hs * dv, "gate_proj", cfg, f32)(u))
 
         cached = cfg.decode_paged
         if cached:
@@ -547,61 +551,63 @@ class DifferentialLatentAttention(nn.Module):
             start = index.value
         else:
             start = jnp.zeros((b,), jnp.int32)
-        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        q_rope = _rope(q[..., dn:], pos, theta)
-        k_rope = _rope(kva[:, :, None, r:], pos, theta)[:, :, 0]
-        # absorb the keys' up-projection into the queries of its group:
-        # the signal heads (group-major), then the noise heads
-        w_k = w_kvb[..., :dn]
-        q_abs = jnp.concatenate([
-            jnp.einsum("btgin,rgn->btgir",
-                       q[:, :, :hs, :dn].reshape(b, t, g, per, dn), w_k,
-                       preferred_element_type=f32).reshape(b, t, hs, r),
-            jnp.einsum("btgn,rgn->btgr", q[:, :, hs:, :dn], w_k,
-                       preferred_element_type=f32)], axis=2)
-        pad = w - r - dr
-        q_full = jnp.concatenate(
-            [q_abs.astype(cfg.dtype), q_rope.astype(cfg.dtype),
-             jnp.zeros((b, t, h, pad), cfg.dtype)], axis=-1)
-        lat = jnp.concatenate(
-            [c, k_rope.astype(cfg.dtype),
-             jnp.zeros((b, t, pad), cfg.dtype)], axis=-1)         # [B, T, W]
+        with trace.part(trace.PROJ):
+            pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+            q_rope = _rope(q[..., dn:], pos, theta)
+            k_rope = _rope(kva[:, :, None, r:], pos, theta)[:, :, 0]
+            # absorb the keys' up-projection into the queries of its group:
+            # the signal heads (group-major), then the noise heads
+            w_k = w_kvb[..., :dn]
+            q_abs = jnp.concatenate([
+                jnp.einsum("btgin,rgn->btgir",
+                           q[:, :, :hs, :dn].reshape(b, t, g, per, dn), w_k,
+                           preferred_element_type=f32).reshape(b, t, hs, r),
+                jnp.einsum("btgn,rgn->btgr", q[:, :, hs:, :dn], w_k,
+                           preferred_element_type=f32)], axis=2)
+            pad = w - r - dr
+            q_full = jnp.concatenate(
+                [q_abs.astype(cfg.dtype), q_rope.astype(cfg.dtype),
+                 jnp.zeros((b, t, h, pad), cfg.dtype)], axis=-1)
+            lat = jnp.concatenate(
+                [c, k_rope.astype(cfg.dtype),
+                 jnp.zeros((b, t, pad), cfg.dtype)], axis=-1)       # [B, T, W]
 
-        with jax.named_scope("diff_read"):
-            if not cached:
-                summed = lsel.causal_latent_attention(
-                    q_full, lat, value_dim=r, scale=cfg.softmax_scale,
-                    window=cfg.window if self.windowed else None)
-            else:
-                real = row_mask(valid_len, b, t)
-                if not self.is_initializing():
-                    if page_table is None:
-                        raise ValueError(
-                            "a paged forward needs its page table")
+        if not cached:
+            summed = lsel.causal_latent_attention(
+                q_full, lat, value_dim=r, scale=cfg.softmax_scale,
+                window=cfg.window if self.windowed else None)
+        else:
+            real = row_mask(valid_len, b, t)
+            if not self.is_initializing():
+                if page_table is None:
+                    raise ValueError("a paged forward needs its page table")
+                with trace.part(trace.CACHE_WRITE):
                     rows, offs = paged_scatter_index(page_table, pos,
                                                      cfg.kv_page_size)
                     pool.value = pool.value.at[rows, offs].set(
                         lat.reshape(b * t, w))
                     index.value = index.value + t
-                # an idle slot (no real position) is told so, whatever its
-                # stale position says: the reads skip it and give it 0
-                live = jnp.where(real[:, 0], start, -1)
-                seen = jnp.where(real[:, 0], start + jnp.sum(real, axis=1),
-                                 0)
-                n_rows = jnp.sum(real[:, 0])
-                if self.windowed:
-                    summed = lsel.latent_window_attention(
-                        q_full, pool.value, page_table, live,
-                        window=cfg.window, value_dim=r,
-                        scale=cfg.softmax_scale, kernel=cfg.paged_kernel)
-                    read = [0, 0, jnp.sum(jnp.minimum(seen, cfg.window))]
-                else:
+            # an idle slot (no real position) is told so, whatever its
+            # stale position says: the reads skip it and give it 0
+            live = jnp.where(real[:, 0], start, -1)
+            seen = jnp.where(real[:, 0], start + jnp.sum(real, axis=1), 0)
+            n_rows = jnp.sum(real[:, 0])
+            if self.windowed:
+                summed = lsel.latent_window_attention(
+                    q_full, pool.value, page_table, live,
+                    window=cfg.window, value_dim=r,
+                    scale=cfg.softmax_scale, kernel=cfg.paged_kernel)
+                read = [0, 0, jnp.sum(jnp.minimum(seen, cfg.window))]
+            else:
+                with trace.part(trace.ATTN_READ):
                     summed = self._full_read(q_full, pool.value, page_table,
                                              live)
-                    read = [jnp.sum(seen), n_rows, 0]
+                read = [jnp.sum(seen), n_rows, 0]
+            with trace.part(trace.ATTN_READ):
                 noise = jnp.sum(jnp.where(real[:, 0, None], lam[:, 0], 0.0))
                 _own_stats(self, "diff", read + [
                     0, jnp.round(1000.0 * noise), n_rows * hs])
+        with trace.part(trace.DIFF_EPILOGUE):
             # the difference, in the latent: a signal head's read less lam
             # times its group's noise head's
             a = summed.astype(f32)
@@ -610,7 +616,8 @@ class DifferentialLatentAttention(nn.Module):
             out = jnp.einsum("btgir,rgv->btgiv", o_lat.astype(cfg.dtype),
                              w_kvb[..., dn:], preferred_element_type=f32)
             out = out.reshape(b, t, hs * dv) * gate
-        return dense(cfg.d_model, "o_proj", cfg)(out.astype(cfg.dtype))
+        with trace.part(trace.PROJ):
+            return dense(cfg.d_model, "o_proj", cfg)(out.astype(cfg.dtype))
 
     def _full_read(self, q_full, pool, page_table, live):
         """``ops/mla.py``'s read as it is: a decode program's heads in one
@@ -635,6 +642,7 @@ class PolyNormMlp(nn.Module):
     width: int
 
     @nn.compact
+    @trace.part(trace.FFN)
     def __call__(self, u):
         cfg = self.cfg
         f32 = jnp.float32
@@ -667,12 +675,13 @@ class PolyNormExperts(nn.Module):
         b, t, dm = u.shape
         m = b * t
         um = u.reshape(m, dm)
-        real = row_mask(valid_len, b, t).reshape(m)
-        scores, _ = sigmoid_scores(self, um, cfg.n_routed_experts,
-                                   choice_bias=False)
-        weights = held_weights(
-            self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
-            scaling=cfg.routed_scaling, other_stats=_OWN_STATS)
+        with trace.part(trace.ROUTER):
+            real = row_mask(valid_len, b, t).reshape(m)
+            scores, _ = sigmoid_scores(self, um, cfg.n_routed_experts,
+                                       choice_bias=False)
+            weights = held_weights(
+                self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
+                scaling=cfg.routed_scaling, other_stats=_OWN_STATS)
         up_shape = (cfg.n_held, dm, cfg.expert_width)
         wg = self.param("experts_gate", normal(), up_shape, cfg.param_dtype)
         wu = self.param("experts_up", normal(), up_shape, cfg.param_dtype)
@@ -680,17 +689,17 @@ class PolyNormExperts(nn.Module):
                         (cfg.n_held, cfg.expert_width, dm), cfg.param_dtype)
         pn = self.param("experts_polynorm", _polynorm_init, (cfg.n_held, 4),
                         jnp.float32)
-        if self.is_initializing():
-            routed = jnp.zeros((m, dm), jnp.float32)    # no kernel at init
-        else:
-            with jax.named_scope("polynorm_experts"):
+        with trace.part(trace.EXPERTS):
+            if self.is_initializing():
+                routed = jnp.zeros((m, dm), jnp.float32)    # no kernel at init
+            else:
                 routed = pne.polynorm_experts(
                     um, wg.astype(cfg.dtype), wu.astype(cfg.dtype),
                     wd.astype(cfg.dtype), pn, weights,
                     scale=cfg.polynorm_scale, clamp=cfg.polynorm_clamp,
                     kernel=cfg.paged_kernel if cfg.decode_paged else "lax")
-        shared = PolyNormMlp(cfg, cfg.shared_width, name="shared")(um)
-        return (routed + shared).astype(cfg.dtype).reshape(b, t, dm)
+            shared = PolyNormMlp(cfg, cfg.shared_width, name="shared")(um)
+            return (routed + shared).astype(cfg.dtype).reshape(b, t, dm)
 
 
 class Motif(nn.Module):
@@ -713,8 +722,9 @@ class Motif(nn.Module):
         # the embedding, copied into the streams, which lie side by side:
         # [B, T, n x D] (a [.., 4, 4096] array pads its 4 to 8 sublanes on
         # the chip, and every reshape of it is a copy)
-        x = jnp.tile(emb[tokens].astype(STREAM_DTYPE),
-                     (1, 1, cfg.mhc_streams))
+        with trace.part(trace.EMBED):
+            x = jnp.tile(emb[tokens].astype(STREAM_DTYPE),
+                         (1, 1, cfg.mhc_streams))
         n_rows = jnp.sum(row_mask(valid_len, b, t)[:, 0])
 
         def norm(name):
@@ -724,11 +734,13 @@ class Motif(nn.Module):
             """``fn`` behind its connection: the mix in, the sublayer on
             ``N(h)`` in the weights' type, the mix out."""
             hc = HyperConnection(cfg, name=name)
-            with jax.named_scope("mhc_mix"):
+            with trace.part(trace.MIX):
                 h, mix = hc.pre(x)
-            y = fn(norm(norm_name)(h).astype(cfg.dtype)).astype(cfg.dtype)
-            with jax.named_scope("mhc_mix"):
-                x = hc.post(x, y, mix)
+            with trace.part(trace.NORM):
+                u = norm(norm_name)(h).astype(cfg.dtype)
+            y = fn(u)
+            with trace.part(trace.MIX):
+                x = hc.post(x, y.astype(cfg.dtype), mix)
             if cfg.decode_paged:
                 _own_stats(self, name + "_rows", [0, 0, 0, n_rows, 0, 0])
             return x
@@ -749,14 +761,15 @@ class Motif(nn.Module):
                     valid_len=valid_len)
             x = sublayer(x, f"layer_{i}_ffn_hc", f"layer_{i}_ffn_norm", ffn)
         # the streams, summed out
-        d = cfg.d_model
-        out = norm("final_norm")(sum(
-            x[..., i * d:(i + 1) * d] for i in range(cfg.mhc_streams)))
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        return jnp.einsum("bte,ve->btv", out.astype(cfg.dtype),
-                          head.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+        with trace.part(trace.HEAD):
+            d = cfg.d_model
+            out = norm("final_norm")(sum(
+                x[..., i * d:(i + 1) * d] for i in range(cfg.mhc_streams)))
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            return jnp.einsum("bte,ve->btv", out.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: MotifConfig, rng: jax.Array):

@@ -52,6 +52,7 @@ from lzy_tpu.models.paged_blocks import (
 from lzy_tpu.models.serving import HeadPool
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import mamba2
+from lzy_tpu.utils import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,12 +216,14 @@ class Mamba2Mixer(nn.Module):
 
         # [z, xBC, dt] in one projection; float32 out of the accumulator:
         # dt steers an exponential
-        zxbcdt = dense(di + cd + h, "in_proj", cfg, f32)(u)
-        z = zxbcdt[..., :di].astype(f32)
-        # the convolution's inputs are kept a row (the conv state), in the
-        # activations' dtype: round them before use, in prefill and decode
-        xbc = zxbcdt[..., di:di + cd].astype(cfg.dtype)
-        dt_raw = zxbcdt[..., di + cd:].astype(f32)
+        with trace.part(trace.PROJ):
+            zxbcdt = dense(di + cd + h, "in_proj", cfg, f32)(u)
+            z = zxbcdt[..., :di].astype(f32)
+            # the convolution's inputs are kept a row (the conv state), in
+            # the activations' dtype: round them before use, in prefill and
+            # decode
+            xbc = zxbcdt[..., di:di + cd].astype(cfg.dtype)
+            dt_raw = zxbcdt[..., di + cd:].astype(f32)
 
         conv_w = self.param("conv_kernel", nn.initializers.normal(0.3),
                             (k, cd), f32)
@@ -248,43 +251,46 @@ class Mamba2Mixer(nn.Module):
             prev = jnp.zeros((b, k - 1, cd), cfg.dtype)
             state = jnp.zeros((b, h, p, n), f32)
 
-        real = row_mask(valid_len, b, t)                         # [B, T]
-        seq = jnp.concatenate([prev, xbc], axis=1)               # [B, T+k-1]
-        conv = conv_b + sum(conv_w[i] * seq[:, i:i + t].astype(f32)
-                            for i in range(k))
-        xbc_act = jax.nn.silu(conv)
-        x = xbc_act[..., :di].reshape(b, t, h, p)
-        bm = xbc_act[..., di:di + g * n].reshape(b, t, g, n)
-        cm = xbc_act[..., di + g * n:].reshape(b, t, g, n)
-        dt = jnp.where(real[..., None],
-                       jax.nn.softplus(dt_raw + dt_bias), 0.0)   # [B, T, H]
-        a = -jnp.exp(a_log)
+        with trace.part(trace.STATE):
+            real = row_mask(valid_len, b, t)                         # [B, T]
+            seq = jnp.concatenate([prev, xbc], axis=1)             # [B, T+k-1]
+            conv = conv_b + sum(conv_w[i] * seq[:, i:i + t].astype(f32)
+                                for i in range(k))
+            xbc_act = jax.nn.silu(conv)
+            x = xbc_act[..., :di].reshape(b, t, h, p)
+            bm = xbc_act[..., di:di + g * n].reshape(b, t, g, n)
+            cm = xbc_act[..., di + g * n:].reshape(b, t, g, n)
+            dt = jnp.where(real[..., None],
+                           jax.nn.softplus(dt_raw + dt_bias), 0.0)  # [B, T, H]
+            a = -jnp.exp(a_log)
 
-        if cached and t == 1:
-            y, new_state = mamba2.ssm_state_update(
-                state, x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
-            y = y[:, None]
-        else:
-            y, new_state = mamba2.ssd_chunk_scan(
-                x, dt, a, bm, cm, state, chunk=cfg.chunk_size)
-        if cached and not self.is_initializing():
-            ssm_state.value = new_state
-            # the window that ends at the last real position
-            ends = jnp.full((b,), t, jnp.int32) if valid_len is None \
-                else valid_len.astype(jnp.int32)
-            conv_state.value = jax.vmap(
-                lambda s, e: jax.lax.dynamic_slice_in_dim(s, e, k - 1, 0)
-            )(seq, ends)
+            if cached and t == 1:
+                y, new_state = mamba2.ssm_state_update(
+                    state, x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
+                y = y[:, None]
+            else:
+                y, new_state = mamba2.ssd_chunk_scan(
+                    x, dt, a, bm, cm, state, chunk=cfg.chunk_size)
+            if cached and not self.is_initializing():
+                ssm_state.value = new_state
+                # the window that ends at the last real position
+                ends = jnp.full((b,), t, jnp.int32) if valid_len is None \
+                    else valid_len.astype(jnp.int32)
+                conv_state.value = jax.vmap(
+                    lambda s, e: jax.lax.dynamic_slice_in_dim(s, e, k - 1, 0)
+                )(seq, ends)
 
-        y = y + d_skip[:, None] * x
-        y = y.reshape(b, t, di) * jax.nn.silu(z)
-        # RMSNorm over each group's channels, with weight
-        gate_w = self.param("gate_norm", nn.initializers.ones, (di,), f32)
-        yg = y.reshape(b, t, g, di // g)
-        yg = yg * jax.lax.rsqrt(
-            jnp.mean(jnp.square(yg), axis=-1, keepdims=True) + cfg.norm_eps)
-        y = (yg.reshape(b, t, di) * gate_w).astype(cfg.dtype)
-        return dense(cfg.d_model, "out_proj", cfg)(y)
+            y = y + d_skip[:, None] * x
+            y = y.reshape(b, t, di) * jax.nn.silu(z)
+            # RMSNorm over each group's channels, with weight
+            gate_w = self.param("gate_norm", nn.initializers.ones, (di,), f32)
+            yg = y.reshape(b, t, g, di // g)
+            yg = yg * jax.lax.rsqrt(
+                jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
+                + cfg.norm_eps)
+            y = (yg.reshape(b, t, di) * gate_w).astype(cfg.dtype)
+        with trace.part(trace.PROJ):
+            return dense(cfg.d_model, "out_proj", cfg)(y)
 
 
 class LatentExperts(nn.Module):
@@ -299,29 +305,31 @@ class LatentExperts(nn.Module):
         m = b * t
         f32 = jnp.float32
         um = u.reshape(m, dm)
-        real = row_mask(valid_len, b, t).reshape(m)
-
-        scores, bias = sigmoid_scores(self, um, cfg.n_routed_experts)
-        weights = held_weights(
-            self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
-            bias=bias, scaling=cfg.routed_scaling)
-        v = dense(cfg.latent, "latent_down", cfg)(um)
+        with trace.part(trace.ROUTER):
+            real = row_mask(valid_len, b, t).reshape(m)
+            scores, bias = sigmoid_scores(self, um, cfg.n_routed_experts)
+            weights = held_weights(
+                self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
+                bias=bias, scaling=cfg.routed_scaling)
+        with trace.part(trace.EXPERTS):
+            v = dense(cfg.latent, "latent_down", cfg)(um)
         w1 = self.param("experts_w1", nn.initializers.normal(0.02),
                         (cfg.n_held, cfg.latent, cfg.expert_width),
                         cfg.param_dtype)
         w2 = self.param("experts_w2", normal(),
                         (cfg.n_held, cfg.expert_width, cfg.latent),
                         cfg.param_dtype)
-        if self.is_initializing():
-            routed = jnp.zeros((m, cfg.latent), f32)    # no kernel at init
-        else:
-            routed = gexp.grouped_experts(v, w1.astype(cfg.dtype),
-                                          w2.astype(cfg.dtype), weights)
-        out = dense(dm, "latent_up", cfg)(routed.astype(cfg.dtype))
-        hid = dense(cfg.shared_width, "shared_w1", cfg)(um)
-        hid = jnp.square(jax.nn.relu(hid.astype(f32))).astype(cfg.dtype)
-        out = out + dense(dm, "shared_w2", cfg)(hid)
-        return out.reshape(b, t, dm)
+        with trace.part(trace.EXPERTS):
+            if self.is_initializing():
+                routed = jnp.zeros((m, cfg.latent), f32)  # no kernel at init
+            else:
+                routed = gexp.grouped_experts(v, w1.astype(cfg.dtype),
+                                              w2.astype(cfg.dtype), weights)
+            out = dense(dm, "latent_up", cfg)(routed.astype(cfg.dtype))
+            hid = dense(cfg.shared_width, "shared_w1", cfg)(um)
+            hid = jnp.square(jax.nn.relu(hid.astype(f32))).astype(cfg.dtype)
+            out = out + dense(dm, "shared_w2", cfg)(hid)
+            return out.reshape(b, t, dm)
 
 
 class NemotronH(nn.Module):
@@ -338,7 +346,8 @@ class NemotronH(nn.Module):
         cfg = self.cfg
         emb = self.param("embed_tokens", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens]
         for i, kind in enumerate(cfg.pattern):
             u = RMSNorm(cfg.norm_eps, cfg.param_dtype,
                         name=f"layer_{i}_norm")(x)
@@ -348,13 +357,16 @@ class NemotronH(nn.Module):
                 y = LatentExperts(cfg, name=f"layer_{i}")(u, valid_len)
             else:
                 y = PagedAttention(cfg, name=f"layer_{i}")(u, page_table)
-            x = x + y
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                          head.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+            # a residual sum is filed with the block it closes
+            with trace.part(trace.EXPERTS if kind == "E" else trace.PROJ):
+                x = x + y
+        with trace.part(trace.HEAD):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: NemotronHConfig, rng: jax.Array):

@@ -66,6 +66,7 @@ from lzy_tpu.models.paged_blocks import (
     ATTN_FULL_KEYS, ATTN_ROWS, dense, into_heads, normal)
 from lzy_tpu.models.serving import HeadPool
 from lzy_tpu.ops.paged_attention import MAX_Q_TOKENS
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 LOOP_ROWS = REGISTRY.counter(
@@ -243,18 +244,20 @@ class OuroAttention(nn.Module):
         b, t, _ = u.shape
         h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         passes = cfg.total_ut_steps
-        q = into_heads(dense(h * d, "q_proj", cfg)(u), b, t, h, d)
-        k = into_heads(dense(kv * d, "k_proj", cfg)(u), b, t, kv, d)
-        v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
-        q = _rope(q, at.pos, cfg.rope_theta)
-        k = _rope(k, at.pos, cfg.rope_theta)
+        with trace.part(trace.PROJ):
+            q = into_heads(dense(h * d, "q_proj", cfg)(u), b, t, h, d)
+            k = into_heads(dense(kv * d, "k_proj", cfg)(u), b, t, kv, d)
+            v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
+            q = _rope(q, at.pos, cfg.rope_theta)
+            k = _rope(k, at.pos, cfg.rope_theta)
         if not cfg.decode_paged:
-            qg = q.reshape(b, t, kv, h // kv, d)
-            s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
-                           preferred_element_type=jnp.float32) * d ** -0.5
-            keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-            pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
-            out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype), v)
+            with trace.part(trace.ATTN_READ):
+                qg = q.reshape(b, t, kv, h // kv, d)
+                s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
+                               preferred_element_type=jnp.float32) * d ** -0.5
+                keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+                pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+                out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype), v)
         else:
             # a block holds the passes side by side; a pass's share of it is
             # a block of the pool the kernels see (the same memory)
@@ -268,21 +271,23 @@ class OuroAttention(nn.Module):
             keys, values = pool_k.value.reshape(flat), \
                 pool_v.value.reshape(flat)
             if not self.is_initializing():
-                blocks, offs = paged_scatter_index(table, at.pos,
-                                                   cfg.kv_page_size)
-                # a pad and an idle slot write the pass's scratch block
-                blocks = jnp.where(at.real.reshape(-1), blocks, step)
-                keys = keys.at[blocks, offs].set(
-                    k.astype(cfg.dtype).reshape(b * t, kv, d))
-                values = values.at[blocks, offs].set(
-                    v.astype(cfg.dtype).reshape(b * t, kv, d))
-                pool_k.value = keys.reshape(shape)
-                pool_v.value = values.reshape(shape)
+                with trace.part(trace.CACHE_WRITE):
+                    blocks, offs = paged_scatter_index(table, at.pos,
+                                                       cfg.kv_page_size)
+                    # a pad and an idle slot write the pass's scratch block
+                    blocks = jnp.where(at.real.reshape(-1), blocks, step)
+                    keys = keys.at[blocks, offs].set(
+                        k.astype(cfg.dtype).reshape(b * t, kv, d))
+                    values = values.at[blocks, offs].set(
+                        v.astype(cfg.dtype).reshape(b * t, kv, d))
+                    pool_k.value = keys.reshape(shape)
+                    pool_v.value = values.reshape(shape)
             out = paged_attention(q, keys, values, table, at.read,
                                   kernel=cfg.paged_kernel, dtype=cfg.dtype)
         # float32 out of the accumulator: it is normed and joins the stream
-        return dense(cfg.d_model, "o_proj", cfg, jnp.float32)(
-            out.reshape(b, t, h * d).astype(cfg.dtype))
+        with trace.part(trace.PROJ):
+            return dense(cfg.d_model, "o_proj", cfg, jnp.float32)(
+                out.reshape(b, t, h * d).astype(cfg.dtype))
 
 
 class OuroLayer(nn.Module):
@@ -299,12 +304,16 @@ class OuroLayer(nn.Module):
 
         y = OuroAttention(cfg, name="attn")(
             norm("attn_norm")(x).astype(cfg.dtype), step, at)
-        a = x + norm("attn_post_norm")(y)
+        # a residual sum (and the norm in front of it) is filed with the
+        # block it closes
+        with trace.part(trace.PROJ):
+            a = x + norm("attn_post_norm")(y)
         n = norm("mlp_norm")(a).astype(cfg.dtype)
-        hid = jax.nn.silu(dense(cfg.d_ff, "gate_proj", cfg)(n)) \
-            * dense(cfg.d_ff, "up_proj", cfg)(n)
-        y = dense(cfg.d_model, "down_proj", cfg, jnp.float32)(hid)
-        return a + norm("mlp_post_norm")(y)
+        with trace.part(trace.FFN):
+            hid = jax.nn.silu(dense(cfg.d_ff, "gate_proj", cfg)(n)) \
+                * dense(cfg.d_ff, "up_proj", cfg)(n)
+            y = dense(cfg.d_model, "down_proj", cfg, jnp.float32)(hid)
+            return a + norm("mlp_post_norm")(y)
 
 
 class OuroPass(nn.Module):
@@ -319,12 +328,13 @@ class OuroPass(nn.Module):
     def __call__(self, carry, step, at: _Places):
         cfg = self.cfg
         x, chosen, survive, mass, exit_pass = carry
+        # a plain scope, not a part: a pass holds every layer's parts
         with jax.named_scope("loop_pass"):
             for i in range(cfg.n_layers):
                 x = OuroLayer(cfg, name=f"layer_{i}")(x, step, at)
             hidden = RMSNorm(cfg.norm_eps, cfg.param_dtype,
                              name="final_norm")(x)
-        with jax.named_scope("loop_exit"):
+        with trace.part(trace.LOOP_EXIT):
             w = self.param("exit_gate", normal(), (cfg.d_model,),
                            jnp.float32)
             bias = self.param("exit_gate_bias", normal(), (), jnp.float32)
@@ -376,9 +386,10 @@ class Ouro(nn.Module):
             else jnp.where(page_table[:, :1] != 0, pos, -1)
         at = _Places(pos, read, page_table, row_mask(valid_len, b, t))
 
-        x = emb.astype(cfg.dtype)[tokens].astype(f32)
-        carry = (x, jnp.zeros_like(x), jnp.ones((b, t), f32),
-                 jnp.zeros((b, t), f32), jnp.zeros((b, t), jnp.int32))
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens].astype(f32)
+            carry = (x, jnp.zeros_like(x), jnp.ones((b, t), f32),
+                     jnp.zeros((b, t), f32), jnp.zeros((b, t), jnp.int32))
         if self.is_initializing():
             # the loop's body once: the parameters and the cache are the
             # same tree whatever the trip count
@@ -392,13 +403,15 @@ class Ouro(nn.Module):
                 carry, jnp.arange(cfg.total_ut_steps, dtype=jnp.int32), at)
         _, chosen, _, _, exit_pass = carry
         self.sow("intermediates", "exit_pass", exit_pass)
-        if cached and not self.is_initializing():
-            index.value = start + t
-            if valid_len is not None:
-                self._count(start, valid_len.astype(jnp.int32), exit_pass)
-        return jnp.einsum("bte,ve->btv", chosen.astype(cfg.dtype),
-                          head.astype(cfg.dtype),
-                          preferred_element_type=f32)
+        with trace.part(trace.HEAD):
+            if cached and not self.is_initializing():
+                index.value = start + t
+                if valid_len is not None:
+                    self._count(start, valid_len.astype(jnp.int32),
+                                exit_pass)
+            return jnp.einsum("bte,ve->btv", chosen.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
+                              preferred_element_type=f32)
 
     def _count(self, start, ends, exit_pass):
         """The round's counts: real rows, the pass each was read from (at

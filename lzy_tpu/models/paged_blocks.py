@@ -20,6 +20,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 ATTN_FULL_KEYS = REGISTRY.counter(
@@ -109,16 +110,19 @@ class PagedAttention(nn.Module):
         cfg = self.cfg
         b, t, _ = u.shape
         h, kv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = into_heads(dense(h * d, "q_proj", cfg)(u), b, t, h, d)
-        k = into_heads(dense(kv * d, "k_proj", cfg)(u), b, t, kv, d)
-        v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
+        with trace.part(trace.PROJ):
+            q = into_heads(dense(h * d, "q_proj", cfg)(u), b, t, h, d)
+            k = into_heads(dense(kv * d, "k_proj", cfg)(u), b, t, kv, d)
+            v = into_heads(dense(kv * d, "v_proj", cfg)(u), b, t, kv, d)
         if not cfg.decode_paged:
-            qg = q.reshape(b, t, kv, h // kv, d)
-            s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
-                           preferred_element_type=jnp.float32) * d ** -0.5
-            keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-            pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
-            out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype), v)
+            with trace.part(trace.ATTN_READ):
+                qg = q.reshape(b, t, kv, h // kv, d)
+                s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
+                               preferred_element_type=jnp.float32) * d ** -0.5
+                keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+                pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+                out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype),
+                                 v)
             return self._project(out, u)
         shape = (cfg.kv_pages, cfg.kv_page_size, kv, d)
         pool_k = self.variable("cache", "k", jnp.zeros, shape, cfg.dtype)
@@ -130,20 +134,23 @@ class PagedAttention(nn.Module):
         if not self.is_initializing():
             if page_table is None:
                 raise ValueError("a paged forward needs page_table")
-            rows, offs = paged_scatter_index(page_table, pos,
-                                             cfg.kv_page_size)
-            pool_k.value = pool_k.value.at[rows, offs].set(
-                k.astype(cfg.dtype).reshape(b * t, kv, d))
-            pool_v.value = pool_v.value.at[rows, offs].set(
-                v.astype(cfg.dtype).reshape(b * t, kv, d))
-            index.value = index.value + t
-        if getattr(cfg, "group_read", False):
-            out = self._group_read(q, pool_k.value, pool_v.value, page_table,
-                                   start, valid_len)
-        else:
-            out = paged_attention(q, pool_k.value, pool_v.value, page_table,
-                                  pos, kernel=cfg.paged_kernel,
-                                  dtype=cfg.dtype)
+            with trace.part(trace.CACHE_WRITE):
+                rows, offs = paged_scatter_index(page_table, pos,
+                                                 cfg.kv_page_size)
+                pool_k.value = pool_k.value.at[rows, offs].set(
+                    k.astype(cfg.dtype).reshape(b * t, kv, d))
+                pool_v.value = pool_v.value.at[rows, offs].set(
+                    v.astype(cfg.dtype).reshape(b * t, kv, d))
+                index.value = index.value + t
+        with trace.part(trace.ATTN_READ):
+            if getattr(cfg, "group_read", False):
+                out = self._group_read(q, pool_k.value, pool_v.value,
+                                       page_table, start, valid_len)
+            else:
+                out = paged_attention(q, pool_k.value, pool_v.value,
+                                      page_table, pos,
+                                      kernel=cfg.paged_kernel,
+                                      dtype=cfg.dtype)
         return self._project(out, u)
 
     def _group_read(self, q, pool_k, pool_v, page_table, start, valid_len):
@@ -173,6 +180,7 @@ class PagedAttention(nn.Module):
                      init_fn=lambda: jnp.zeros((of,), jnp.int32))
         return out.astype(self.cfg.dtype)
 
+    @trace.part(trace.PROJ)
     def _project(self, out, u):
         cfg = self.cfg
         b, t, _ = u.shape

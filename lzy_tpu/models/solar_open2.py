@@ -56,6 +56,7 @@ from lzy_tpu.models.paged_blocks import (
 from lzy_tpu.models.serving import HeadPool
 from lzy_tpu.ops import grouped_experts as gexp
 from lzy_tpu.ops import kda
+from lzy_tpu.utils import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,12 +212,14 @@ class KdaMixer(nn.Module):
 
         # the convolution's inputs are kept a row (the conv state), in the
         # activations' dtype: round them before use, in prefill and decode
-        qkv = dense(3 * hd, "qkv_proj", cfg)(u).astype(cfg.dtype)
+        with trace.part(trace.PROJ):
+            qkv = dense(3 * hd, "qkv_proj", cfg)(u).astype(cfg.dtype)
         conv_w = self.param("conv_kernel", nn.initializers.normal(0.3),
                             (k, 3 * hd), f32)
         # float32 out of the accumulator: these steer an exponential
-        decay_in = dense(hd, "decay_up", cfg, f32)(
-            dense(r, "decay_down", cfg)(u))
+        with trace.part(trace.PROJ):
+            decay_in = dense(hd, "decay_up", cfg, f32)(
+                dense(r, "decay_down", cfg)(u))
         # alpha = exp(-exp(A_log) softplus(. + dt_bias)) starts with a step
         # log-uniform in [0.001, 0.1] and a rate uniform in [1, 16]
         dt_bias = self.param(
@@ -226,8 +229,10 @@ class KdaMixer(nn.Module):
         a_log = self.param(
             "A_log", lambda key, shape: jnp.log(
                 jax.random.uniform(key, shape, f32, 1.0, 16.0)), (h,))
-        beta = 2.0 * jax.nn.sigmoid(dense(h, "beta_proj", cfg, f32)(u))
-        gate = dense(hd, "gate_up", cfg, f32)(dense(r, "gate_down", cfg)(u))
+        with trace.part(trace.PROJ):
+            beta = 2.0 * jax.nn.sigmoid(dense(h, "beta_proj", cfg, f32)(u))
+            gate = dense(hd, "gate_up", cfg, f32)(
+                dense(r, "gate_down", cfg)(u))
 
         cached = cfg.decode_paged
         if cached:
@@ -240,43 +245,45 @@ class KdaMixer(nn.Module):
             prev = jnp.zeros((b, k - 1, 3 * hd), cfg.dtype)
             state = jnp.zeros((b, h, d, d), f32)
 
-        real = row_mask(valid_len, b, t)                         # [B, T]
-        seq = jnp.concatenate([prev, qkv], axis=1)               # [B, T+k-1]
-        conv = jax.nn.silu(sum(conv_w[i] * seq[:, i:i + t].astype(f32)
-                               for i in range(k)))
-        q, kk, v = (conv[..., i * hd:(i + 1) * hd].reshape(b, t, h, d)
-                    for i in range(3))
-        q = _l2_normalise(q) * d ** -0.5
-        kk = _l2_normalise(kk)
-        log_alpha = (-jnp.exp(a_log)[:, None] * jax.nn.softplus(
-            decay_in + dt_bias).reshape(b, t, h, d))
-        # a pad position and an idle slot: decay 1, no write
-        log_alpha = jnp.where(real[:, :, None, None], log_alpha, 0.0)
-        beta = jnp.where(real[:, :, None], beta, 0.0)
+        with trace.part(trace.STATE):
+            real = row_mask(valid_len, b, t)                         # [B, T]
+            seq = jnp.concatenate([prev, qkv], axis=1)             # [B, T+k-1]
+            conv = jax.nn.silu(sum(conv_w[i] * seq[:, i:i + t].astype(f32)
+                                   for i in range(k)))
+            q, kk, v = (conv[..., i * hd:(i + 1) * hd].reshape(b, t, h, d)
+                        for i in range(3))
+            q = _l2_normalise(q) * d ** -0.5
+            kk = _l2_normalise(kk)
+            log_alpha = (-jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                decay_in + dt_bias).reshape(b, t, h, d))
+            # a pad position and an idle slot: decay 1, no write
+            log_alpha = jnp.where(real[:, :, None, None], log_alpha, 0.0)
+            beta = jnp.where(real[:, :, None], beta, 0.0)
 
-        if cached and t == 1:
-            o, new_state = kda.kda_state_update(
-                state, q[:, 0], kk[:, 0], v[:, 0], jnp.exp(log_alpha[:, 0]),
-                beta[:, 0], real[:, 0])
-            o = o[:, None]
-        else:
-            o, new_state = kda.kda_chunk_scan(
-                q, kk, v, log_alpha, beta, state, chunk=cfg.chunk_size)
-        if cached and not self.is_initializing():
-            kda_state.value = new_state
-            # the window that ends at the last real position
-            ends = jnp.full((b,), t, jnp.int32) if valid_len is None \
-                else valid_len.astype(jnp.int32)
-            conv_state.value = jax.vmap(
-                lambda s, e: jax.lax.dynamic_slice_in_dim(s, e, k - 1, 0)
-            )(seq, ends)
+            if cached and t == 1:
+                o, new_state = kda.kda_state_update(
+                    state, q[:, 0], kk[:, 0], v[:, 0],
+                    jnp.exp(log_alpha[:, 0]), beta[:, 0], real[:, 0])
+                o = o[:, None]
+            else:
+                o, new_state = kda.kda_chunk_scan(
+                    q, kk, v, log_alpha, beta, state, chunk=cfg.chunk_size)
+            if cached and not self.is_initializing():
+                kda_state.value = new_state
+                # the window that ends at the last real position
+                ends = jnp.full((b,), t, jnp.int32) if valid_len is None \
+                    else valid_len.astype(jnp.int32)
+                conv_state.value = jax.vmap(
+                    lambda s, e: jax.lax.dynamic_slice_in_dim(s, e, k - 1, 0)
+                )(seq, ends)
 
-        # RMSNorm over each head's channels, with weight, then the gate
-        norm_w = self.param("out_norm", nn.initializers.ones, (d,), f32)
-        o = o * jax.lax.rsqrt(
-            jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
-        o = (o * norm_w).reshape(b, t, hd) * jax.nn.sigmoid(gate)
-        return dense(cfg.d_model, "o_proj", cfg)(o.astype(cfg.dtype))
+            # RMSNorm over each head's channels, with weight, then the gate
+            norm_w = self.param("out_norm", nn.initializers.ones, (d,), f32)
+            o = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
+            o = (o * norm_w).reshape(b, t, hd) * jax.nn.sigmoid(gate)
+        with trace.part(trace.PROJ):
+            return dense(cfg.d_model, "o_proj", cfg)(o.astype(cfg.dtype))
 
 
 class SolarOpen2(nn.Module):
@@ -293,7 +300,8 @@ class SolarOpen2(nn.Module):
         cfg = self.cfg
         emb = self.param("embed_tokens", nn.initializers.normal(0.02),
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        x = emb.astype(cfg.dtype)[tokens]
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens]
 
         def norm(name):
             return RMSNorm(cfg.norm_eps, cfg.param_dtype, name=name)
@@ -304,15 +312,18 @@ class SolarOpen2(nn.Module):
                 y = PagedAttention(cfg, name=f"layer_{i}")(u, page_table)
             else:
                 y = KdaMixer(cfg, name=f"layer_{i}")(u, valid_len)
-            x = x + y
-            x = x + GatedExperts(cfg, name=f"layer_{i}_moe")(
-                norm(f"layer_{i}_moe_norm")(x), valid_len)
-        x = norm("final_norm")(x)
-        head = self.param("lm_head", nn.initializers.normal(0.02),
-                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                          head.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+            with trace.part(trace.PROJ):
+                x = x + y
+            with trace.part(trace.EXPERTS):
+                x = x + GatedExperts(cfg, name=f"layer_{i}_moe")(
+                    norm(f"layer_{i}_moe_norm")(x), valid_len)
+        with trace.part(trace.HEAD):
+            x = norm("final_norm")(x)
+            head = self.param("lm_head", nn.initializers.normal(0.02),
+                              (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
+            return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                              head.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: SolarOpen2Config, rng: jax.Array):

@@ -67,6 +67,7 @@ from lzy_tpu.models.paged_blocks import (
 from lzy_tpu.models.serving import HeadPool
 from lzy_tpu.ops import cca
 from lzy_tpu.ops import grouped_experts as gexp
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 CCA_ROWS = REGISTRY.counter(
@@ -284,8 +285,9 @@ class CcaAttention(nn.Module):
 
         # float32 out of the accumulator: two positions of it are carried,
         # and it is summed, multiplied and normalised before it is rounded
-        mixed = dense(width + lk // 2, "mix_proj", cfg, f32)(u)
-        new, v1 = mixed[..., :width], mixed[..., width:]
+        with trace.part(trace.PROJ):
+            mixed = dense(width + lk // 2, "mix_proj", cfg, f32)(u)
+            new, v1 = mixed[..., :width], mixed[..., width:]
         # a convolution's taps and bias start uniform in +-1 / sqrt(fan in)
         # (torch's Conv1d): 2 taps a channel, then 2 x d inputs a head
         mixer = cca.Mixer(
@@ -321,21 +323,23 @@ class CcaAttention(nn.Module):
             start = index.value
         else:
             start = jnp.zeros((b,), jnp.int32)
-        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
-        rot = int(d * cfg.rotary_fraction)
-        q = _partial_rope(q.reshape(b, t, h, d), pos, cfg.rope_theta,
-                          rot).astype(cfg.dtype)
-        k = _partial_rope(k.reshape(b, t, kv, d), pos, cfg.rope_theta,
-                          rot).astype(cfg.dtype)
-        v = v.reshape(b, t, kv, d).astype(cfg.dtype)
+        with trace.part(trace.PROJ):
+            pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)
+            rot = int(d * cfg.rotary_fraction)
+            q = _partial_rope(q.reshape(b, t, h, d), pos, cfg.rope_theta,
+                              rot).astype(cfg.dtype)
+            k = _partial_rope(k.reshape(b, t, kv, d), pos, cfg.rope_theta,
+                              rot).astype(cfg.dtype)
+            v = v.reshape(b, t, kv, d).astype(cfg.dtype)
 
         if not cached:
-            qg = q.reshape(b, t, kv, h // kv, d)
-            s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
-                           preferred_element_type=f32) * d ** -0.5
-            keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
-            pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
-            out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype), v)
+            with trace.part(trace.ATTN_READ):
+                qg = q.reshape(b, t, kv, h // kv, d)
+                s = jnp.einsum("btkgd,blkd->bkgtl", qg, k,
+                               preferred_element_type=f32) * d ** -0.5
+                keep = jnp.arange(t)[:, None] >= jnp.arange(t)[None, :]
+                pr = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+                out = jnp.einsum("bkgtl,blkd->btkgd", pr.astype(cfg.dtype), v)
         else:
             shape = (cfg.kv_pages, cfg.kv_page_size, kv, d)
             pool_k = self.variable("cache", "k", jnp.zeros, shape, cfg.dtype)
@@ -343,21 +347,23 @@ class CcaAttention(nn.Module):
             if not self.is_initializing():
                 if page_table is None:
                     raise ValueError("a paged forward needs page_table")
-                rows, offs = paged_scatter_index(page_table, pos,
-                                                 cfg.kv_page_size)
-                pool_k.value = pool_k.value.at[rows, offs].set(
-                    k.reshape(b * t, kv, d))
-                pool_v.value = pool_v.value.at[rows, offs].set(
-                    v.reshape(b * t, kv, d))
-                index.value = index.value + t
-                window.value = moved.astype(cfg.window_dtype)
-                self._count(real, start, valid_len)
+                with trace.part(trace.CACHE_WRITE):
+                    rows, offs = paged_scatter_index(page_table, pos,
+                                                     cfg.kv_page_size)
+                    pool_k.value = pool_k.value.at[rows, offs].set(
+                        k.reshape(b * t, kv, d))
+                    pool_v.value = pool_v.value.at[rows, offs].set(
+                        v.reshape(b * t, kv, d))
+                    index.value = index.value + t
+                    window.value = moved.astype(cfg.window_dtype)
+                    self._count(real, start, valid_len)
             out = paged_attention(q, pool_k.value, pool_v.value, page_table,
                                   pos, kernel=cfg.paged_kernel,
                                   dtype=cfg.dtype)
         # float32 out of the accumulator: it joins the residual stream
-        return dense(cfg.d_model, "o_proj", cfg, f32)(
-            out.reshape(b, t, lq).astype(cfg.dtype))
+        with trace.part(trace.PROJ):
+            return dense(cfg.d_model, "o_proj", cfg, f32)(
+                out.reshape(b, t, lq).astype(cfg.dtype))
 
     def _count(self, real, start, valid_len):
         """The layer's counts: the cached keys its real rows read (a row at
@@ -389,49 +395,53 @@ class RoutedExperts(nn.Module):
         b, t, dm = u.shape
         m, rw, f32 = b * t, cfg.router_width, jnp.float32
         um = u.reshape(m, dm)
-        real = row_mask(valid_len, b, t).reshape(m)
+        with trace.part(trace.ROUTER):
+            real = row_mask(valid_len, b, t).reshape(m)
 
-        def weight(name, shape, std):
-            return self.param(name, nn.initializers.normal(std), shape, f32)
+            def weight(name, shape, std):
+                return self.param(name, nn.initializers.normal(std), shape,
+                                  f32)
 
-        def product(x, w):
-            return jnp.dot(x, w, precision=_HIGHEST)
+            def product(x, w):
+                return jnp.dot(x, w, precision=_HIGHEST)
 
-        r = product(um.astype(f32), weight("router_down", (dm, rw), 0.02)) \
-            + weight("router_down_bias", (rw,), 0.02)
-        if carry is not None:
-            r = r + self.param("carry_scale", _around_one(0.1), (rw,),
-                               f32) * carry
-        hid = RMSNorm(cfg.norm_eps, f32, name="router_norm")(r)
-        # the MLP's weights keep a unit input at unit scale (1 / sqrt(fan
-        # in), and twice that behind a GELU, which halves it), so that the
-        # probabilities differ by tenths and not by thousandths
-        for i, gain in ((0, 1.0), (1, 2.0)):
-            hid = jax.nn.gelu(
-                product(hid, weight(f"router_mlp_{i}", (rw, rw),
-                                    gain * rw ** -0.5))
-                + weight(f"router_mlp_{i}_bias", (rw,), 0.02),
-                approximate=False)
-        scores = jax.nn.softmax(product(hid, weight(
-            "router_out", (rw, cfg.n_routed_experts), 2.0 * rw ** -0.5)),
-            axis=-1)
-        weights = held_weights(
-            self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
-            bias=weight("router_bias", (cfg.n_routed_experts,), 0.02),
-            renormalise=False, other_stats=self.other_stats)
+            r = product(um.astype(f32),
+                        weight("router_down", (dm, rw), 0.02)) \
+                + weight("router_down_bias", (rw,), 0.02)
+            if carry is not None:
+                r = r + self.param("carry_scale", _around_one(0.1), (rw,),
+                                   f32) * carry
+            hid = RMSNorm(cfg.norm_eps, f32, name="router_norm")(r)
+            # the MLP's weights keep a unit input at unit scale (1 / sqrt(fan
+            # in), and twice that behind a GELU, which halves it), so that the
+            # probabilities differ by tenths and not by thousandths
+            for i, gain in ((0, 1.0), (1, 2.0)):
+                hid = jax.nn.gelu(
+                    product(hid, weight(f"router_mlp_{i}", (rw, rw),
+                                        gain * rw ** -0.5))
+                    + weight(f"router_mlp_{i}_bias", (rw,), 0.02),
+                    approximate=False)
+            scores = jax.nn.softmax(product(hid, weight(
+                "router_out", (rw, cfg.n_routed_experts), 2.0 * rw ** -0.5)),
+                axis=-1)
+            weights = held_weights(
+                self, scores, real, top_k=cfg.top_k, held=cfg.experts_held,
+                bias=weight("router_bias", (cfg.n_routed_experts,), 0.02),
+                renormalise=False, other_stats=self.other_stats)
 
         up_shape = (cfg.n_held, dm, cfg.expert_width)
         wg = self.param("experts_gate", normal(), up_shape, cfg.param_dtype)
         wu = self.param("experts_up", normal(), up_shape, cfg.param_dtype)
         wd = self.param("experts_down", normal(),
                         (cfg.n_held, cfg.expert_width, dm), cfg.param_dtype)
-        if self.is_initializing():
-            routed = jnp.zeros((m, dm), f32)            # no kernel at init
-        else:
-            routed = gexp.grouped_experts(
-                um, wu.astype(cfg.dtype), wd.astype(cfg.dtype), weights,
-                gate=wg.astype(cfg.dtype))
-        return routed.reshape(b, t, dm), r
+        with trace.part(trace.EXPERTS):
+            if self.is_initializing():
+                routed = jnp.zeros((m, dm), f32)            # no kernel at init
+            else:
+                routed = gexp.grouped_experts(
+                    um, wu.astype(cfg.dtype), wd.astype(cfg.dtype), weights,
+                    gate=wg.astype(cfg.dtype))
+            return routed.reshape(b, t, dm), r
 
 
 class ZayaLayer(nn.Module):
@@ -465,14 +475,17 @@ class ZayaLayer(nn.Module):
 
         y = CcaAttention(cfg, (len(experts.STATS), of), name="attn")(
             norm("attn_norm")(x).astype(cfg.dtype), page_table, valid_len)
-        x = (x if self.first else scaled("attn_stream", x)) \
-            + scaled("attn_out", y)
+        # a sublayer's join is filed with the block it closes
+        with trace.part(trace.PROJ):
+            x = (x if self.first else scaled("attn_stream", x)) \
+                + scaled("attn_out", y)
         # float32 as the norm leaves it: the router reads it unrounded (a
         # rounded input flips near-ties), the experts' products round it
         y, carry = RoutedExperts(
             cfg, of - len(experts.STATS), name="moe")(
             norm("moe_norm")(x), carry, valid_len)
-        return scaled("moe_stream", x) + scaled("moe_out", y), carry
+        with trace.part(trace.EXPERTS):
+            return scaled("moe_stream", x) + scaled("moe_out", y), carry
 
 
 class Zaya(nn.Module):
@@ -491,15 +504,17 @@ class Zaya(nn.Module):
                          (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
         # the stream is float32: 2 x layers sums, each behind a scale and a
         # bias, in bfloat16 would round it as many times
-        x = emb.astype(cfg.dtype)[tokens].astype(jnp.float32)
+        with trace.part(trace.EMBED):
+            x = emb.astype(cfg.dtype)[tokens].astype(jnp.float32)
         carry = None
         for i in range(cfg.n_layers):
             x, carry = ZayaLayer(cfg, i == 0, name=f"layer_{i}")(
                 x, carry, page_table, valid_len)
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
-        return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
-                          emb.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+        with trace.part(trace.HEAD):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="final_norm")(x)
+            return jnp.einsum("bte,ve->btv", x.astype(cfg.dtype),
+                              emb.astype(cfg.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def init_params(cfg: ZayaConfig, rng: jax.Array):

@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from lzy_tpu.utils import trace
 
 _NEG_INF = -1e30
 
@@ -42,6 +43,7 @@ def auto_block(t: int, requested: int = 512) -> int:
     return b
 
 
+@trace.part(trace.ATTN_READ)
 def chunked_attention(
     q: jax.Array,
     k: jax.Array,

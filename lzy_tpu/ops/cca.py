@@ -58,6 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` labels of the programs
 UPDATE_PATH = "cca_update_pallas"
@@ -229,6 +230,7 @@ def _pallas_update(window, new, v1, live, w0, b0, w1, b1, tau_wide, *, heads,
     return q, k, v, out_win
 
 
+@trace.part(trace.STATE)
 def cca_mix_update(window: jax.Array, new: jax.Array, v1: jax.Array,
                    live: jax.Array, mixer: Mixer, *, heads: int, groups: int,
                    dtype: Any, interpret: Optional[bool] = None):
@@ -254,6 +256,7 @@ def cca_mix_update(window: jax.Array, new: jax.Array, v1: jax.Array,
     return q, k, v, out
 
 
+@trace.part(trace.STATE)
 def lax_mix_update(window, new, v1, live, mixer: Mixer, *, heads: int,
                    groups: int, dtype: Any):
     """:func:`cca_mix_update` in plain ``jax.numpy``: its oracle."""
@@ -291,6 +294,7 @@ def lower_update_for_tpu(*, batch: int, heads: int, groups: int,
 
 # -- prefill: a chunk's positions, the window carried in and out --------------
 
+@trace.part(trace.STATE)
 def cca_mix(window: jax.Array, new: jax.Array, v1: jax.Array,
             valid_len: Optional[jax.Array], mixer: Mixer, *, heads: int,
             groups: int, dtype: Any):

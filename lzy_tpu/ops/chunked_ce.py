@@ -41,6 +41,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from lzy_tpu.utils import trace
+
 #: temp memory a block's f32 logits may take: what fixes the rows a block
 _LOGITS_BYTES = 128 << 20
 
@@ -158,6 +160,7 @@ def _bwd(rows, shard, residuals, g):
 _fused_nll.defvjp(_fwd, _bwd)
 
 
+@trace.part(trace.LOSS)
 def chunked_cross_entropy(
     features: jax.Array,            # [B, T, D] or [N, D] (bf16 ok)
     head: jax.Array,                # [V, D]

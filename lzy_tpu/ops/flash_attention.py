@@ -82,6 +82,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 
 _NEG_INF = -1e30
 _LANE = 128
@@ -652,6 +653,7 @@ def segment_slab(segment_ids: jax.Array, lane: int = _LANE) -> jax.Array:
     return jnp.pad(aux, ((0, 0), (0, 0), (0, lane - 3)))
 
 
+@trace.part(trace.ATTN_READ)
 def flash_attention(
     q: jax.Array,
     k: jax.Array,

@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` label of a program that holds the kernel
 PATH = "experts_pallas"
@@ -141,6 +142,7 @@ def _pallas_grouped(x, w1, w2, weights, gate=None, *, interpret: bool):
       weights.astype(jnp.float32).T[:, :, None])
 
 
+@trace.part(trace.EXPERTS)
 def grouped_experts(x: jax.Array, w1: jax.Array, w2: jax.Array,
                     weights: jax.Array, *, gate: Optional[jax.Array] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
@@ -153,6 +155,7 @@ def grouped_experts(x: jax.Array, w1: jax.Array, w2: jax.Array,
                            interpret=_interpret.resolve(interpret))
 
 
+@trace.part(trace.EXPERTS)
 def lax_grouped_experts(x, w1, w2, weights, gate=None):
     """The same sum over every held expert, one after another."""
     x = x.astype(w1.dtype)

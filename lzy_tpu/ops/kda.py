@@ -62,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` labels of the two programs
 SCAN_PATH = "kda_chunk_lax"
@@ -72,6 +73,7 @@ _HI = lax.Precision.HIGHEST
 _HEAD_BLOCK = 8
 
 
+@trace.part(trace.STATE)
 def kda_chunk_scan(q: jax.Array, k: jax.Array, v: jax.Array,
                    log_alpha: jax.Array, beta: jax.Array, state: jax.Array,
                    *, chunk: int = 32):
@@ -137,6 +139,7 @@ def kda_chunk_scan(q: jax.Array, k: jax.Array, v: jax.Array,
     return o[:, :t], state
 
 
+@trace.part(trace.STATE)
 def kda_step(state, q, k, v, alpha, beta):
     """One position of the recurrence in plain ``jax.numpy``: ``state``
     [B, H, V, K], ``q``/``k``/``alpha`` [B, H, K], ``v`` [B, H, V], ``beta``
@@ -216,6 +219,7 @@ def _pallas_update(state, rowvecs, v, live, *, interpret: bool):
     return new, jnp.where(asked[:, None, None], y, 0.0)
 
 
+@trace.part(trace.STATE)
 def kda_state_update(state: jax.Array, q: jax.Array, k: jax.Array,
                      v: jax.Array, alpha: jax.Array, beta: jax.Array,
                      live: Optional[jax.Array] = None, *,

@@ -120,6 +120,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
 from lzy_tpu.ops import mla
+from lzy_tpu.utils import trace
 
 _NEG_INF = -1e30
 
@@ -174,6 +175,7 @@ def _block_pages(pages: int, page: int, positions: int) -> int:
 
 # -- 1. the index --------------------------------------------------------------
 
+@trace.part(trace.LATENT_INDEX)
 def lax_index_scores(q, w, pool, page_table, start):
     """The oracle: ``q`` [B, J, T, D], ``w`` [B, T, J] float32, ``pool``
     [n_blocks, page, D] gathered through ``page_table`` [B, P], every page
@@ -375,6 +377,7 @@ def _pallas_index_scores(q, w, pool, page_table, start, *, topk: int,
     )(start, table, q, w, pool)
 
 
+@trace.part(trace.LATENT_INDEX)
 def index_scores(q: jax.Array, w: jax.Array, pool: jax.Array,
                  page_table: jax.Array, start: jax.Array, *, topk: int,
                  kernel: str = "lax",
@@ -691,6 +694,7 @@ def _pallas_latent_choice(scores, pos, *, k: int, interpret: bool):
     return out[:n, 0, :k].reshape(b, t, k)
 
 
+@trace.part(trace.LATENT_CHOICE)
 def latent_topk(scores: jax.Array, pos: jax.Array, k: int, *,
                 kernel: str = "lax", interpret: Optional[bool] = None):
     """The exact ``k`` best cached positions a query: ``scores`` [B, T, L]
@@ -745,6 +749,7 @@ def gather_path(kernel: str, *, t: int) -> Optional[str]:
     return None
 
 
+@trace.part(trace.LATENT_GATHER)
 def gather_tokens(pool, page_table, positions):
     """``pool`` [n_blocks, page, W] at ``positions`` [B, S] of each row,
     through ``page_table`` [B, P]: ``[B, S, W]``."""
@@ -904,6 +909,7 @@ def _pallas_latent_gather(pool, page_table, idx, n, lo, hi, reach, *,
       idx.astype(jnp.int32).reshape(queries, tiles, pp), pool)
 
 
+@trace.part(trace.LATENT_GATHER)
 def latent_gather(pool: jax.Array, page_table: jax.Array, idx: jax.Array,
                   n: jax.Array, *, kernel: str = "lax",
                   interpret: Optional[bool] = None) -> jax.Array:
@@ -972,12 +978,14 @@ def latent_chosen_attention(q: jax.Array, pool: jax.Array,
     k = idx.shape[-1]
     got = latent_gather(pool, page_table, idx, n, kernel=kernel,
                         interpret=interpret)
-    out = mla.mla_attention(
-        q.reshape(b * t, 1, h, w), got,
-        jnp.arange(got.shape[0], dtype=jnp.int32).reshape(b * t, -1),
-        n.reshape(b * t) - 1, value_dim=value_dim, scale=scale,
-        kernel=kernel, interpret=interpret)
-    return out.reshape(b, t, h, value_dim)
+    # two parts: the copy is ``latent_gather``'s, the read this one
+    with trace.part(trace.LATENT_CHOSEN_READ):
+        out = mla.mla_attention(
+            q.reshape(b * t, 1, h, w), got,
+            jnp.arange(got.shape[0], dtype=jnp.int32).reshape(b * t, -1),
+            n.reshape(b * t) - 1, value_dim=value_dim, scale=scale,
+            kernel=kernel, interpret=interpret)
+        return out.reshape(b, t, h, value_dim)
 
 
 # -- 4. the read under a window -------------------------------------------------
@@ -991,6 +999,7 @@ def window_path(kernel: str, *, t: int) -> Optional[str]:
     return None
 
 
+@trace.part(trace.LATENT_WINDOW_READ)
 def latent_window_attention(q: jax.Array, pool: jax.Array,
                             window_table: jax.Array, start: jax.Array, *,
                             window: int, value_dim: int, scale: float,
@@ -1029,6 +1038,7 @@ def latent_window_attention(q: jax.Array, pool: jax.Array,
     return jnp.where((start >= 0)[:, None, None, None], out, 0)
 
 
+@trace.part(trace.ATTN_READ)
 def causal_latent_attention(q, lat, *, value_dim: int, scale: float,
                             window: Optional[int] = None, scores=None,
                             topk: Optional[int] = None):

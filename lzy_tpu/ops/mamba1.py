@@ -53,6 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` labels of the programs
 SCAN_PATH = "ssm1_scan_pallas"
@@ -171,6 +172,7 @@ def _lax_scan(x, dt, a, b, c, state):
     return jnp.swapaxes(y, 0, 1), new
 
 
+@trace.part(trace.STATE)
 def selective_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                    c: jax.Array, state: jax.Array, *, kernel: str = "pallas",
                    interpret: Optional[bool] = None):
@@ -281,6 +283,7 @@ def _pallas_update(state, x, dt, a, b, c, live, *, interpret: bool):
     return new, jnp.where(live[:, None], y[:, 0], 0.0)
 
 
+@trace.part(trace.STATE)
 def selective_state_update(state: jax.Array, x: jax.Array, dt: jax.Array,
                            a: jax.Array, b: jax.Array, c: jax.Array, *,
                            interpret: Optional[bool] = None):

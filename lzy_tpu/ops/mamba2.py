@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` labels of the two programs
 SCAN_PATH = "ssm_scan_lax"
@@ -60,6 +61,7 @@ def _expand_groups(m: jax.Array, heads: int) -> jax.Array:
     return jnp.repeat(m, heads // m.shape[-2], axis=-2)
 
 
+@trace.part(trace.STATE)
 def ssd_chunk_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                    c: jax.Array, state: jax.Array, *, chunk: int = 128):
     """``x`` [B, T, H, P], ``dt`` [B, T, H] (after softplus; 0 freezes the
@@ -170,6 +172,7 @@ def _pallas_update(state, x, da, dtb, c, live, *, interpret: bool):
     return new, jnp.where(live[:, None, None], y, 0.0)
 
 
+@trace.part(trace.STATE)
 def ssm_state_update(state: jax.Array, x: jax.Array, dt: jax.Array,
                      a: jax.Array, b: jax.Array, c: jax.Array, *,
                      interpret: Optional[bool] = None):

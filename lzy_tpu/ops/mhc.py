@@ -57,6 +57,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` labels of a program that mixes streams
 PATH = "mhc_pallas"
@@ -127,6 +128,7 @@ def _scales(alpha, n: int):
 
 # -- the lax forms ------------------------------------------------------------
 
+@trace.part(trace.MIX)
 def lax_mhc_pre(x, phi, alpha, b, *, streams: int, sweeps: int, eps: float):
     n, (m, nd) = streams, x.shape
     flat = x.astype(jnp.float32)
@@ -141,6 +143,7 @@ def lax_mhc_pre(x, phi, alpha, b, *, streams: int, sweeps: int, eps: float):
     return h, mix
 
 
+@trace.part(trace.MIX)
 def lax_mhc_post(x, y, mix, *, streams: int):
     n, (m, nd) = streams, x.shape
     _, post, res = split_mix(mix, n)
@@ -276,6 +279,7 @@ def _check_kernel(kernel: str) -> None:
             f"unknown stream-mix kernel {kernel!r}; known: lax, pallas")
 
 
+@trace.part(trace.MIX)
 def mhc_pre(x: jax.Array, phi: jax.Array, alpha: jax.Array, b: jax.Array, *,
             streams: int, sweeps: int, eps: float = 1e-5,
             kernel: str = "lax",
@@ -294,6 +298,7 @@ def mhc_pre(x: jax.Array, phi: jax.Array, alpha: jax.Array, b: jax.Array, *,
                        eps=eps)
 
 
+@trace.part(trace.MIX)
 def mhc_post(x: jax.Array, y: jax.Array, mix: jax.Array, *, streams: int,
              kernel: str = "lax",
              interpret: Optional[bool] = None) -> jax.Array:

@@ -66,6 +66,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
 from lzy_tpu.ops.paged_attention import cell_group
+from lzy_tpu.utils import trace
 
 _NEG_INF = -1e30
 
@@ -163,6 +164,7 @@ def _absorbed(q, lat, pos, *, value_dim: int, scale: float):
                       lat[..., :value_dim])
 
 
+@trace.part(trace.ATTN_READ)
 def lax_mla_attention(q, pool, page_table, start, *, value_dim: int,
                       scale: float):
     """The absorbed sum over the whole table: ``pool`` [n_blocks, page, W]
@@ -175,6 +177,7 @@ def lax_mla_attention(q, pool, page_table, start, *, value_dim: int,
     return jnp.where((start >= 0)[:, None, None, None], out, 0)
 
 
+@trace.part(trace.ATTN_READ)
 def causal_mla_attention(q, lat, *, value_dim: int, scale: float):
     """The absorbed sum with no cache: ``q`` [B, T, H, W] against the chunk's
     own ``lat`` [B, T, W], causal."""
@@ -361,6 +364,7 @@ def _pallas_mla_attention(q, pool, page_table, start, *, value_dim: int,
     return out.reshape(b, t, h, value_dim)
 
 
+@trace.part(trace.ATTN_READ)
 def mla_attention(q: jax.Array, pool: jax.Array, page_table: jax.Array,
                   start: jax.Array, *, value_dim: int, scale: float,
                   kernel: str = "lax", interpret: Optional[bool] = None,

@@ -91,6 +91,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 from lzy_tpu.utils.metrics import REGISTRY
 
 _NEG_INF = -1e30
@@ -885,6 +886,7 @@ def _pallas_group_attention(q, k_pool, v_pool, page_table, start, *,
     return out.reshape(b, kv_heads, t, group, d).transpose(0, 2, 1, 3, 4)
 
 
+@trace.part(trace.ATTN_READ)
 def paged_group_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                           page_table: jax.Array, start: jax.Array, *,
                           window: Optional[int] = None, kernel: str = "lax",
@@ -951,6 +953,7 @@ def lower_group_for_tpu(*, batch: int, t: int, n_heads: int, n_kv_heads: int,
 # -- public op -------------------------------------------------------------------
 
 
+@trace.part(trace.ATTN_READ)
 def paged_attention(
     q: jax.Array,
     k_pool: jax.Array,

@@ -48,6 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
 from lzy_tpu.ops.grouped_experts import _DEFAULT_VMEM, _tile
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` labels of a program with the product
 PATH = "polynorm_experts_pallas"
@@ -189,6 +190,7 @@ def _pallas_polynorm(x, gate, up, down, params, weights, *, scale: float,
       weights.astype(jnp.float32).T[:, :, None])
 
 
+@trace.part(trace.EXPERTS)
 def polynorm_experts(x: jax.Array, gate: jax.Array, up: jax.Array,
                      down: jax.Array, params: jax.Array, weights: jax.Array,
                      *, scale: float, clamp: float, kernel: str = "pallas",
@@ -209,6 +211,7 @@ def polynorm_experts(x: jax.Array, gate: jax.Array, up: jax.Array,
         interpret=_interpret.resolve(interpret))
 
 
+@trace.part(trace.EXPERTS)
 def lax_polynorm_experts(x, gate, up, down, params, weights, *,
                          scale: float, clamp: float):
     """The same sum over every held expert, one after another."""

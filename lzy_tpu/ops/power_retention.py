@@ -80,6 +80,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lzy_tpu.ops import interpret as _interpret
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` labels of the two programs
 SCAN_PATH = "retention_chunk_pallas"
@@ -280,6 +281,7 @@ def _pallas_chunk(s, z, q, k, v, log_g, real, wt, *, c: int, eps: float,
     return y.reshape(b, t, h, d), s, z
 
 
+@trace.part(trace.STATE)
 def retention_chunk_scan(q: jax.Array, k: jax.Array, v: jax.Array,
                          log_g: jax.Array, s: jax.Array, z: jax.Array,
                          real: Optional[jax.Array] = None, *,
@@ -422,6 +424,7 @@ def _pallas_update(s, z, side, vcol, live, *, pd, interpret: bool):
     )(rows, count, s, z, side, vcol)
 
 
+@trace.part(trace.STATE)
 def retention_state_update(s: jax.Array, z: jax.Array, q: jax.Array,
                            k: jax.Array, v: jax.Array, log_g: jax.Array,
                            live: jax.Array, *, eps: float = 1e-6,

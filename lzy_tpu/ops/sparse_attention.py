@@ -63,6 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from lzy_tpu.ops import interpret as _interpret
 from lzy_tpu.ops.paged_attention import (
     _pallas_paged_attention, paged_attention, paged_scatter_index)
+from lzy_tpu.utils import trace
 
 #: ``lzy_kernel_dispatch_total{path}`` labels
 SELECT_DECODE_PATH = "sparse_select_decode"
@@ -136,6 +137,7 @@ def _rows(blocks, offsets, kv: int, page: int):
         + offsets[..., None]
 
 
+@trace.part(trace.CACHE_WRITE)
 def scatter_kv(pool, page_table, positions, values):
     """``values`` ``[B, T, KV, D]`` written at ``positions`` ``[B, T]`` of a
     ``[pages, KV, page, D]`` pool (an idle row and a position past a row's
@@ -151,6 +153,7 @@ def scatter_kv(pool, page_table, positions, values):
         values.astype(pool.dtype).reshape(-1, d)).reshape(pool.shape)
 
 
+@trace.part(trace.CACHE_WRITE)
 def compress_keys(k_pool, ck_pool, page_table, start, n_real, *, t: int,
                   spec: SparseSpec):
     """The compressed keys that a chunk of ``t`` positions from ``start``
@@ -220,6 +223,7 @@ def forced_blocks(positions, pages: int, spec: SparseSpec):
                          | (cur - blk < spec.window_blocks))
 
 
+@trace.part(trace.LATENT_CHOICE)
 def choose(scores, positions, spec: SparseSpec):
     """``scores`` ``[B, KV, T, pages]`` -> the chosen blocks, bool."""
     pages = scores.shape[-1]
@@ -407,6 +411,7 @@ def _pallas_select(q, ck_pool, page_table, first, *, spec: SparseSpec,
     return out[..., :pages] != 0
 
 
+@trace.part(trace.LATENT_CHOICE)
 def select_blocks(q, ck_pool, page_table, positions, selects, *,
                   spec: SparseSpec, kernel: str = "lax",
                   interpret: Optional[bool] = None):
@@ -430,6 +435,7 @@ def select_blocks(q, ck_pool, page_table, positions, selects, *,
 
 # -- the reads ----------------------------------------------------------------
 
+@trace.part(trace.LATENT_CHOICE)
 def pack_chosen(chosen, page_table):
     """The chosen pages first, in position order, a table of their own:
     ``chosen`` ``[B, KV, pages]`` bool, ``page_table`` ``[B, pages]``.
@@ -448,6 +454,7 @@ def pack_chosen(chosen, page_table):
     return table, cum[..., -1]
 
 
+@trace.part(trace.ATTN_READ)
 def sparse_decode_attention(q, k_pool, v_pool, page_table, positions,
                             chosen, live, *, kernel: str = "lax",
                             dtype: Any = None,
@@ -680,6 +687,7 @@ def _pallas_prefill(q, k_pool, v_pool, page_table, start, chosen, *,
         0, 2, 4, 1, 3, 5).reshape(b, t, kv, group, d)
 
 
+@trace.part(trace.ATTN_READ)
 def sparse_prefill_attention(q, k_pool, v_pool, page_table, start, chosen, *,
                              kernel: str = "lax", dtype: Any = None,
                              interpret: Optional[bool] = None):

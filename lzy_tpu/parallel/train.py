@@ -134,21 +134,26 @@ def make_train_step(
             def acc(carry, mb):
                 loss_sum, grad_sum = carry
                 loss, grads = grads_of(state.params, mb)
-                return (
-                    loss_sum + loss,
-                    jax.tree_util.tree_map(jnp.add, grad_sum, grads),
-                ), None
+                with trace.part(trace.OPTIMIZER):
+                    return (
+                        loss_sum + loss,
+                        jax.tree_util.tree_map(jnp.add, grad_sum, grads),
+                    ), None
 
             zeros = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params
             )
             (loss, grads), _ = jax.lax.scan(acc, (jnp.zeros((), jnp.float32), zeros), micro)
-            loss = loss / accum_steps
-            grads = jax.tree_util.tree_map(lambda g: g / accum_steps, grads)
+            with trace.part(trace.OPTIMIZER):
+                loss = loss / accum_steps
+                grads = jax.tree_util.tree_map(lambda g: g / accum_steps,
+                                               grads)
 
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        grad_norm = optax.global_norm(grads)
+        with trace.part(trace.OPTIMIZER):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            grad_norm = optax.global_norm(grads)
         new_state = TrainState(
             step=state.step + 1, params=new_params, opt_state=new_opt
         )

@@ -2148,15 +2148,17 @@ class PagedInferenceEngine:
             logits, updated = self._model.apply(
                 {"params": params, "cache": cache}, cur[:, None],
                 mutable=mutable, **tables, **real, **build.apply_kw)
-            nxt, rng = self._pick_next(logits[:, -1], greedy_mask, rng)
+            with trace.part(trace.SAMPLE):
+                nxt, rng = self._pick_next(logits[:, -1], greedy_mask, rng)
             payload, new_pos = self._split_cache(updated["cache"])
             if not has_stats:
                 return payload, new_pos, nxt, rng
             # the layers' counts ride behind the tokens in the one array
             # the round's fence fetches
-            counts = sum(jax.tree_util.tree_leaves(updated["stats"]))
-            return payload, new_pos, nxt, rng, jnp.concatenate(
-                [nxt, counts.astype(jnp.int32)])
+            with trace.part(trace.SAMPLE):
+                counts = sum(jax.tree_util.tree_leaves(updated["stats"]))
+                return payload, new_pos, nxt, rng, jnp.concatenate(
+                    [nxt, counts.astype(jnp.int32)])
 
         self._decode_step = build.jit(decode_step, donate=(0,))
 
@@ -2185,11 +2187,13 @@ class PagedInferenceEngine:
             logits, updated = self._model.apply(
                 {"params": params, "cache": cache}, toks,
                 page_table=page_table, mutable=["cache"], **build.apply_kw)
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt, rng = self._pick_next(logits[:, 0], greedy_mask, rng)
+            with trace.part(trace.SAMPLE):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                nxt, rng = self._pick_next(logits[:, 0], greedy_mask, rng)
             payload, _ = self._split_cache(updated["cache"])
-            packed, new_cur, new_pos = accept(prop, prop_len, greedy,
-                                              nxt, pos)
+            with trace.part(trace.SAMPLE):
+                packed, new_cur, new_pos = accept(prop, prop_len, greedy,
+                                                  nxt, pos)
             return payload, packed, new_cur, new_pos, rng
 
         self._verify_step = build.jit(verify_step, donate=(0,))

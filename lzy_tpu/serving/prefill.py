@@ -326,8 +326,9 @@ class Prefill:
         device from here on) and the ``[1]`` token."""
         second = {} if window_table is None \
             else {"window_table": window_table}
-        ctl, page_table, prompt = self._read_row(job)
-        start, take = ctl[_CTL_START], ctl[_CTL_TAKE]
+        with trace.part(trace.EMBED):
+            ctl, page_table, prompt = self._read_row(job)
+            start, take = ctl[_CTL_START], ctl[_CTL_TAKE]
         index = jnp.reshape(start, (1,))
         leaves, paged, rows = [], iter(pool), iter(state)
         for kind in self._leaf_kinds:
@@ -335,12 +336,14 @@ class Prefill:
                 leaves.append(index)
             elif kind == serving.STATE:
                 row = next(rows)
-                leaves.append(jnp.where(ctl[_CTL_FRESH] != 0,
-                                        jnp.zeros_like(row), row))
+                with trace.part(trace.STATE):
+                    leaves.append(jnp.where(ctl[_CTL_FRESH] != 0,
+                                            jnp.zeros_like(row), row))
             else:
                 leaves.append(next(paged))
         cache = jax.tree_util.tree_unflatten(self._treedef, leaves)
-        tokens = self._read_chunk(prompt, start, take, width)
+        with trace.part(trace.EMBED):
+            tokens = self._read_chunk(prompt, start, take, width)
         real = {"valid_len": jnp.reshape(take, (1,))} \
             if self._tells_real else {}
         if self._tells_prompt_len:
@@ -349,9 +352,10 @@ class Prefill:
             {"params": params, "cache": cache}, tokens,
             page_table=page_table, mutable=["cache"], **real, **second,
             **self._build.apply_kw)
-        last = jax.lax.dynamic_index_in_dim(
-            logits, take - 1, axis=1, keepdims=False)
-        first = self._pick_first(last, ctl[_CTL_GREEDY] != 0, key)
+        with trace.part(trace.SAMPLE):
+            last = jax.lax.dynamic_index_in_dim(
+                logits, take - 1, axis=1, keepdims=False)
+            first = self._pick_first(last, ctl[_CTL_GREEDY] != 0, key)
         out = jax.tree_util.tree_leaves(updated["cache"])
         return ([leaf for leaf, kind in zip(out, self._leaf_kinds)
                  if kind in serving.POOLS],

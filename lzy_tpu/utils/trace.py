@@ -1,4 +1,5 @@
-"""Tracing: one span recorder for the serving path, and the JAX profiler.
+"""Tracing: one span recorder for the serving path, the names of the device's
+work, and the JAX profiler.
 
 Two questions, one module. "Where did the step time go on the chip" is
 answered by the XLA profiler; "what was the host doing meanwhile, and where
@@ -52,6 +53,23 @@ counters ``lzy_program_build_seconds{site,stage}`` and
 on it is also the span ``program.build`` (an annotation too, so a device
 gap during a build has a name), whose attributes are the same seconds the
 counters took; a context in which JAX built nothing leaves no record.
+
+**The device's side: parts.** A span names what the host was doing; what
+the *device* was doing is named by :func:`part`: ``with part(PROJ):`` (or
+``@part(STATE)`` on an op's public entry point) puts every operation traced
+inside under ``part.<name>`` in its ``op_name``. The names are the constants
+of ``PARTS`` below, a closed set beside the span names (``[a-z0-9_]``, no
+ids, no sizes): ``embed``, ``norm``, ``proj``, ``attn_read``,
+``cache_write``, ``ffn``, ``router``, ``experts``, ``state``, ``mix``,
+``head``, ``sample``, ``loss``, ``optimizer`` and seven model-specific ones.
+Every served program, the engine's step functions and the train step carry
+them (``tests/test_zz_parts.py`` holds each product, custom call, gather,
+scatter, sort and loop to exactly one). A device trace's ``XLA Ops`` events
+hold the ``op_name`` as ``tf_op``, so XProf's trace viewer and op profile
+group by them, and ``benchmark/readers/part_share.py`` and
+``tools/part_table.py`` file a program's device time under them. Always on:
+a scope is metadata of the compiled program (the persistent compile cache
+keys a program without it) and costs the device nothing.
 
 **The profiler.**
 
@@ -145,6 +163,43 @@ SITE_TRAIN_STEP = "train.step"
 # no context open: eager stragglers, and whatever the library's caller
 # compiles for itself
 SITE_OTHER = "other"
+
+# -- device parts: what :func:`part` may name a program's work ---------------
+# the device-side counterpart of the span names above: constants of
+# ``[a-z0-9_]``, no ids, no sizes. A closed set: a trace reader, XProf's op
+# profile and PERF.md's tables file a step's device time under these
+
+EMBED = "embed"
+NORM = "norm"
+# the products around a mixer: q/k/v/o, a latent's down- and up-projections
+# with their relayouts, rotary embeddings, head norms, gates
+PROJ = "proj"
+ATTN_READ = "attn_read"           # a read over a cache of any kind
+CACHE_WRITE = "cache_write"
+FFN = "ffn"                       # a dense feed-forward: gate, up and down
+ROUTER = "router"
+EXPERTS = "experts"               # routed and shared experts' products
+# a recurrent mixer's scan or update: Mamba, KDA, lightning, retention, CCA
+STATE = "state"
+MIX = "mix"                       # residual-stream mixing (mHC)
+HEAD = "head"                     # final norm and logits
+SAMPLE = "sample"
+LOSS = "loss"
+OPTIMIZER = "optimizer"
+LATENT_INDEX = "latent_index"
+LATENT_CHOICE = "latent_choice"
+LATENT_GATHER = "latent_gather"
+LATENT_CHOSEN_READ = "latent_chosen_read"
+LATENT_WINDOW_READ = "latent_window_read"
+DIFF_EPILOGUE = "diff_epilogue"   # Motif: the difference, W_vb, the gate
+LOOP_EXIT = "loop_exit"           # Ouro's exit gate
+PARTS = frozenset({
+    EMBED, NORM, PROJ, ATTN_READ, CACHE_WRITE, FFN, ROUTER, EXPERTS, STATE,
+    MIX, HEAD, SAMPLE, LOSS, OPTIMIZER, LATENT_INDEX, LATENT_CHOICE,
+    LATENT_GATHER, LATENT_CHOSEN_READ, LATENT_WINDOW_READ, DIFF_EPILOGUE,
+    LOOP_EXIT})
+#: how a part is spelt inside an operation's ``op_name``: ``part.<name>``
+PART_PREFIX = "part."
 
 CLOCK = "lzy.clock"                         # event: one for each anchor
 CLOCK_ANCHOR = CLOCK + "."
@@ -397,6 +452,43 @@ def span(name: str, parent: Optional[Tuple[int, Any]] = None,
     if not ON:
         return NOOP
     return _Span(name, parent, start, attrs)
+
+
+def part(name: str):
+    """``with part(PROJ):`` (or ``@part(PROJ)`` on a function whose whole
+    body is one part): the device-side counterpart of :func:`span`. Every
+    operation traced inside carries ``part.<name>`` in its ``op_name`` (a
+    ``jax.named_scope``), forward and under ``transpose(jvp(...))``, which is
+    what a device trace's ``tf_op``, XProf's trace viewer and op profile, and
+    ``benchmark/readers/part_share.py`` file its device time by. ``name`` is
+    one of ``PARTS``; any other is refused here, before anything is traced.
+
+    Always on: a scope is metadata of the compiled program and costs the
+    device nothing, so there is no switch. **Parts do not nest**: inside an
+    open part a second one names nothing (the outermost wins), so an op's
+    public entry point can name itself and still be called from a block its
+    caller has named, and an operation's ``op_name`` holds one ``part.``
+    component. A fusion goes to the part XLA's ``op_name`` gives it: a norm's
+    reduction fused into the product that follows is the product's."""
+    if name not in PARTS:
+        raise ValueError(f"no device part {name!r}; known: {sorted(PARTS)} "
+                         f"(lzy_tpu/utils/trace.py)")
+    return _part(name)
+
+
+@contextlib.contextmanager
+def _part(name: str) -> Iterator[None]:
+    if getattr(_tls, "part", None) is not None:
+        yield
+        return
+    import jax
+
+    _tls.part = name
+    try:
+        with jax.named_scope(PART_PREFIX + name):
+            yield
+    finally:
+        _tls.part = None
 
 
 def _here() -> Tuple[Optional[int], Any]:

@@ -154,15 +154,11 @@ def decode_round_ops(at=16384, rounds=5, least_us=5.0, slots=16,
                      pages_per_seq=784, page_size=64):
     """Device time a round of the five-layer decode program's operations at
     the engine's shapes (16 slots, one live at position ``at``, pools of
-    zeros), an HLO instruction each: ``[us, instruction, the label a trace's
-    breakdown gives it, op_name]``, the longest first, those of ``least_us``
-    or more."""
-    import re
-    import shutil
-    import tempfile
-
-    from benchmark.harness import trace as tr
+    zeros), an HLO instruction each (``tools/part_table.py``
+    ``traced_ops``): ``[us, instruction, the label a trace's breakdown gives
+    it, tf_op, part]``, the longest first, those of ``least_us`` or more."""
     from benchmark.models import dots3_note as ref
+    from tools.part_table import traced_ops
 
     with open("benchmark/configs/dots3-note-prev-serve-l5-ep8.json") as f:
         cfg = ref.program_config(json.load(f))
@@ -189,27 +185,10 @@ def decode_round_ops(at=16384, rounds=5, least_us=5.0, slots=16,
             window_table=table, valid_len=real, mutable=["cache", "stats"])
         return jnp.argmax(logits[:, -1], -1)
 
-    args = (params, cache, jnp.zeros((slots, 1), jnp.int32), live, many)
-    text = decode_step.lower(*args).compile().as_text()
-    named = dict(re.findall(
-        r"%([\w.\-]+) = [^\n]*?op_name=\"([^\"]*)\"", text))
-    jax.block_until_ready(decode_step(*args))
-    where = tempfile.mkdtemp(prefix="latent_round_")
-    tr.start(where)
-    for _ in range(rounds):
-        out = decode_step(*args)
-    jax.block_until_ready(out)
-    tr.stop()
-    events = tr.reduce(tr.load(tr.find_xplane(where)))["op_events"]
-    shutil.rmtree(where, ignore_errors=True)
-    took, label = {}, {}
-    for _, ns, hlo in events:
-        name = re.match(r"%?([\w.\-]+)", hlo).group(1)
-        took[name] = took.get(name, 0.0) + ns / rounds / 1e3
-        label[name] = tr.op_label(hlo)
-    return [[round(us, 1), name, label[name], named.get(name, "")]
-            for name, us in sorted(took.items(), key=lambda kv: -kv[1])
-            if us >= least_us]
+    return traced_ops(
+        decode_step, params, cache, jnp.zeros((slots, 1), jnp.int32), live,
+        many, module="jit_decode_step", rounds=rounds,
+        least_us=least_us)["ops"]
 
 
 which = sys.argv[1:] or ["topk", "chosen", "index", "program"]

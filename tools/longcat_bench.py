@@ -10,18 +10,16 @@ of microseconds reads its own dispatch):
   prefill chunk of 256 behind 2,048 in calls of 16 heads;
 - ``round`` / ``chunk``: the four-layer program's decode round (128 slots,
   96 live at 1,750) and prefill programs (64 / 128 / 256 positions behind
-  1,024), an HLO instruction each with the ``op_name`` its metadata holds,
-  summed by named scope (``dense_ffn``, ``shortcut_experts``, the latent
-  reads, the rest), and where in a round ``grouped_experts`` runs: the
-  order of the round's kernels and the operations between a layer's expert
-  product and the next attention's read.
+  1,024), an HLO instruction each with the ``op_name`` the trace's own
+  metadata holds, summed by ``part`` (``tools/part_table.py``; where in a
+  round ``grouped_experts`` runs is what XProf's trace viewer shows, its
+  rows grouped by the same parts).
 
 ``chiprun -- python tools/longcat_bench.py [experts] [reads] [round]
 [chunk]``; writes ``chiprun_out/longcat_bench.json``."""
 import functools
 import json
 import os
-import re
 import shutil
 import sys
 import tempfile
@@ -39,7 +37,6 @@ bf, f32 = jnp.bfloat16, jnp.float32
 key = jax.random.PRNGKey(0)
 res = {}
 CONFIG = "benchmark/configs/longcat-flash-omni-serve-l4-ep32.json"
-SCOPES = ("dense_ffn", "shortcut_experts")
 
 
 def traced(f, *a, n=5):
@@ -179,57 +176,14 @@ def _program(t: int, slots: int, live_rows: int, at: int):
 
 def program_ops(t, slots, live_rows, at, rounds=5, least_us=20.0):
     """Device time a call of the program's operations, an HLO instruction
-    each, the longest first; the time by named scope; the order in which a
-    round's kernels ran."""
+    each, the longest first, and the time by part (``tools/part_table.py``
+    ``traced_ops``); the round's counts."""
+    from tools.part_table import traced_ops
+
     step, args = _program(t, slots, live_rows, at)
-    text = step.lower(*args).compile().as_text()
-    named = dict(re.findall(
-        r"%([\w.\-]+) = [^\n]*?op_name=\"([^\"]*)\"", text))
     counts = np.asarray(step(*args)[1])
-    reduced, n = traced(step, *args, n=rounds), rounds
-    took, label, first = {}, {}, {}
-    for start, ns, hlo in reduced["op_events"]:
-        name = re.match(r"%?([\w.\-]+)", hlo).group(1)
-        took[name] = took.get(name, 0.0) + ns / n / 1e3
-        label[name] = tr.op_label(hlo)
-        first.setdefault(name, start)
-    scopes = {}
-    for name, us in took.items():
-        path = named.get(name, "")
-        scope = next((s for s in SCOPES if f"/{s}/" in path), None)
-        if label[name].startswith("mla_paged"):
-            scope = "latent_reads"
-        elif scope is None and re.search(r"/layer_\d+_attn_\d/", path):
-            scope = "attention_projections"
-        elif scope == "shortcut_experts" \
-                and not label[name].startswith("grouped_experts"):
-            scope = "shortcut_experts_rest"
-        scopes[scope or "other"] = round(
-            scopes.get(scope or "other", 0.0) + us, 1)
-    # the first round's kernels and the long operations, in start order:
-    # where the expert product ran among the sublayers it skips
-    t0 = min(first.values())
-    order = [[round((first[name] - t0) / 1e3, 1), round(took[name], 1),
-              label[name], re.sub(r"^jit\(step\)/LongcatFlash/", "",
-                                  named.get(name, ""))[:70]]
-             for name in sorted(first, key=first.get)
-             if took[name] >= 60.0
-             or label[name].startswith("grouped_experts")
-             or label[name].startswith("mla_paged")]
-    by_label = {}
-    for name, us in took.items():
-        by_label[label[name]] = round(by_label.get(label[name], 0.0) + us, 1)
-    return {"program_us": round(sum(sum(x) for x in
-                                    reduced["modules"].values()) / n * 1e6, 1),
-            "counts": counts.tolist(),
-            "by_scope_us": scopes,
-            "by_label_us": dict(sorted(by_label.items(),
-                                       key=lambda kv: -kv[1])[:30]),
-            "order": order,
-            "ops": [[round(us, 1), name, label[name], named.get(name, "")]
-                    for name, us in sorted(took.items(),
-                                           key=lambda kv: -kv[1])
-                    if us >= least_us][:60]}
+    got = traced_ops(step, *args, rounds=rounds, least_us=least_us)
+    return dict(got, counts=counts.tolist(), ops=got["ops"][:60])
 
 
 which = sys.argv[1:] or ["experts", "reads", "round", "chunk"]
@@ -238,15 +192,15 @@ for mode, run in (("experts", experts), ("reads", reads)):
         run()
 if "round" in which:
     res["round_128_slots_96_live_at_1750"] = program_ops(1, 128, 96, 1750)
-    print(json.dumps(res["round_128_slots_96_live_at_1750"]["by_scope_us"]),
+    print(json.dumps(res["round_128_slots_96_live_at_1750"]["by_part_us"]),
           flush=True)
 if "chunk" in which:
     for width in (64, 128, 256):
         got = program_ops(width, 1, 1, 1024)
         if width != 256:
-            got = {k: got[k] for k in ("program_us", "by_scope_us")}
+            got = {k: got[k] for k in ("program_us", "by_part_us")}
         res[f"chunk_{width}_behind_1024"] = got
-        print(width, json.dumps(got["by_scope_us"]), got["program_us"],
+        print(width, json.dumps(got["by_part_us"]), got["program_us"],
               flush=True)
 os.makedirs("chiprun_out", exist_ok=True)
 with open("chiprun_out/longcat_bench.json", "w") as f:

@@ -15,14 +15,14 @@ of microseconds reads its own dispatch):
   rows of 64 slots (80 heads over 640 lanes under a window of 128);
 - ``round`` / ``chunk``: the five-layer program's decode round (64 slots, 40
   live at 3,000) and prefill program (256 positions behind 2,048), an HLO
-  instruction each with the ``op_name`` its metadata holds (a trace labels a
-  fusion by its kind and its shape alone), summed by named scope.
+  instruction each with the ``op_name`` the trace's own metadata holds (a
+  breakdown labels a fusion by its kind and its shape alone), summed by
+  ``part`` (``tools/part_table.py``).
 
 ``chiprun -- python tools/motif_bench.py [experts] [mhc] [reads] [window]
 [round] [chunk]``; writes ``chiprun_out/motif_bench.json``."""
 import json
 import os
-import re
 import shutil
 import sys
 import tempfile
@@ -164,9 +164,11 @@ def reads():
 
 def program_ops(decode: bool, rounds=5, least_us=3.0):
     """Device time a call of the five-layer program's operations, an HLO
-    instruction each: ``[us, instruction, the label a trace's breakdown
-    gives it, op_name]``, the longest first; and the time by named scope."""
+    instruction each (``tools/part_table.py`` ``traced_ops``: ``[us,
+    instruction, the label a trace's breakdown gives it, tf_op, part]``, the
+    longest first), and the time by part."""
     from benchmark.models import motif as ref
+    from tools.part_table import traced_ops
 
     with open("benchmark/configs/motif-3-beta-serve-l5-ep8.json") as f:
         cfg = ref.program_config(json.load(f))
@@ -199,34 +201,8 @@ def program_ops(decode: bool, rounds=5, least_us=3.0):
         return jnp.argmax(logits[:, -1], -1)
 
     ids = jax.random.randint(key, (slots, t), 0, cfg.vocab_size)
-    args = (params, cache, ids, live, table)
-    text = step.lower(*args).compile().as_text()
-    named = dict(re.findall(
-        r"%([\w.\-]+) = [^\n]*?op_name=\"([^\"]*)\"", text))
-    reduced, n = traced(step, *args, n=rounds), rounds
-    took, label = {}, {}
-    for _, ns, hlo in reduced["op_events"]:
-        name = re.match(r"%?([\w.\-]+)", hlo).group(1)
-        took[name] = took.get(name, 0.0) + ns / n / 1e3
-        label[name] = tr.op_label(hlo)
-    scopes = {}
-    for name, us in took.items():
-        path = named.get(name, "")
-        scope = next((s for s in ("mhc_mix", "diff_read", "polynorm_experts")
-                      if f"/{s}/" in path), "other")
-        scopes[scope] = round(scopes.get(scope, 0.0) + us, 1)
-    by_label = {}
-    for name, us in took.items():
-        by_label[label[name]] = round(by_label.get(label[name], 0.0) + us, 1)
-    return {"program_us": round(sum(sum(x) for x in
-                                    reduced["modules"].values()) / n * 1e6, 1),
-            "by_scope_us": scopes,
-            "by_label_us": dict(sorted(by_label.items(),
-                                       key=lambda kv: -kv[1])[:40]),
-            "ops": [[round(us, 1), name, label[name], named.get(name, "")]
-                    for name, us in sorted(took.items(),
-                                           key=lambda kv: -kv[1])
-                    if us >= least_us]}
+    return traced_ops(step, params, cache, ids, live, table, rounds=rounds,
+                      least_us=least_us)
 
 
 def window():
@@ -256,4 +232,4 @@ print(json.dumps({k: v for k, v in res.items()
                   if not isinstance(v, dict) or "ops" not in v}))
 for k, v in res.items():
     if isinstance(v, dict) and "ops" in v:
-        print(k, json.dumps({x: v[x] for x in ("program_us", "by_scope_us")}))
+        print(k, json.dumps({x: v[x] for x in ("program_us", "by_part_us")}))
